@@ -21,7 +21,7 @@ main1.c: run / monitor / keys / configure / version, and fddev's bench):
     bench      quick pipeline throughput measurement (bench.py has the
                full headline benchmark)
     warmup     AOT-compile the sharded serving step for a mesh shape
-               through the persistent serve cache (leader boot-time
+               through the persistent compile cache (leader boot-time
                obligation; `bench.py --multichip-serve` is the ladder)
     genesis    create | show a genesis blob (+ faucet key)
     snapshot   inspect a snapshot archive
@@ -45,6 +45,13 @@ import time
 
 __version__ = "0.7.0"  # round 7: sharded serving plane
 
+# `run` streams --txns transfers over this many funded payers: pack puts at
+# most one transaction per payer into a microblock, and at the configured
+# device batch a stream over the generator's default 8 drains slower than
+# verify feeds it, so pack's pool overflows and sheds (fd_benchg rotates a
+# bounded funded account set the same way)
+RUN_PAYERS = 64
+
 
 def _load_cfg(args):
     from firedancer_tpu.utils.config import load_config
@@ -53,22 +60,18 @@ def _load_cfg(args):
 
 
 def cmd_run(args) -> int:
-    from firedancer_tpu.utils.platform import enable_compile_cache, force_cpu_backend
-
-    if args.cpu:
-        force_cpu_backend()
-    enable_compile_cache()
-    if getattr(args, "processes", False):
+    if args.processes:
+        # the launching parent never initialises a backend: the verify
+        # child owns the chip (models/leader_topo.build_verify)
         return _run_processes(args)
+    from firedancer_tpu.utils.platform import select_device
+
+    platform, kind, count = select_device(args.cpu)
     from firedancer_tpu.models.leader import build_leader_pipeline_from_config
 
     cfg = _load_cfg(args)
     pipe = build_leader_pipeline_from_config(
-        cfg,
-        pool_size=args.txns,
-        gen_limit=args.txns,
-        batch=min(cfg.verify.batch, 256),
-        max_msg_len=256,
+        cfg, pool_size=args.txns, gen_limit=args.txns, n_payers=RUN_PAYERS
     )
     rpc_srv = None
     try:  # the pipeline must close even if the RPC bind fails (EADDRINUSE)
@@ -79,8 +82,13 @@ def cmd_run(args) -> int:
                 PipelineView(pipeline=pipe), port=args.rpc_port
             )
             print(f"# rpc listening on {rpc_srv.addr}", file=sys.stderr)
-        print(f"# leader pipeline: {len(pipe.verifies)} verify, "
-              f"{len(pipe.banks)} bank stages; {args.txns} txns", file=sys.stderr)
+        print(f"# leader pipeline on {platform}:{kind} x{count}: "
+              f"{len(pipe.verifies)} verify (batch {cfg.verify.batch}), "
+              f"{len(pipe.banks)} bank stages; {args.txns} txns",
+              file=sys.stderr)
+        # compile (or load) before the clock starts: set-up, not rate
+        warm_s = sum(v.warmup() for v in pipe.verifies)
+        print(f"# verify program ready in {warm_s:.1f}s", file=sys.stderr)
         t0 = time.time()
         pipe.run(until_txns=args.txns, max_iters=2_000_000)
         dt = time.time() - t0
@@ -106,28 +114,47 @@ def cmd_run(args) -> int:
 
 def _run_processes(args) -> int:
     """The fdctl-run model: every stage its own supervised OS process
-    over shm links, optional per-stage jail, monitor table at exit."""
+    over shm links, optional per-stage jail, monitor table at exit.
+    Done means what it means on the cooperative path: the bank executed
+    every generated transaction."""
     from firedancer_tpu.models.leader_topo import build_leader_topology
     from firedancer_tpu.runtime import topo as ft
-    from firedancer_tpu.runtime.stage import Stage
 
+    cfg = _load_cfg(args)
+    if cfg.layout.bank_stage_count != 1:
+        # each bank process owns its funk (leader_topo.build_bank)
+        print(f"# the process topology runs 1 bank stage (config asks "
+              f"{cfg.layout.bank_stage_count})", file=sys.stderr)
     sandbox = {"rlimits": {"nofile": 512}} if args.sandbox else None
     topo = build_leader_topology(
-        n_txns=args.txns, pool_size=args.txns, batch=16, sandbox=sandbox,
+        n_txns=args.txns, pool_size=args.txns, batch=cfg.verify.batch,
+        max_msg_len=cfg.verify.max_msg_len, verify_cpu=args.cpu,
+        n_payers=RUN_PAYERS, sandbox=sandbox,
     )
     h = ft.launch(topo)
     try:
         print(f"# {len(h.procs)} stage processes; descriptor "
               f"fdtpu_run_{h.uid}.json"
               + (" (sandboxed)" if sandbox else ""), file=sys.stderr)
+        def executed() -> int:
+            return h.met_views["bank0"][0].get("txn_exec")
+
+        t0 = time.time()
         ok = h.supervise(
-            until=lambda h: h.cncs["store"].diag(Stage.DIAG_FRAGS_IN) > 0,
-            timeout_s=600,
+            until=lambda h: executed() >= args.txns,
+            # the verify child compiles (or loads) its program in its
+            # builder, before its first heartbeat: boot is bounded by
+            # timeout_s, a wedged running stage by the heartbeat
+            timeout_s=1200,
             heartbeat_timeout_s=300,
         )
+        dt = time.time() - t0
         print(h.format_monitor())
+        n_exec = executed()
+        print(f"# {n_exec} txns committed in {dt:.2f}s (boot and "
+              f"compile included)")
         h.halt()
-        return 0 if ok else 1
+        return 0 if ok and n_exec == args.txns else 1
     finally:
         h.close()
 
@@ -153,36 +180,28 @@ def cmd_keys(args) -> int:
 
 
 def cmd_bench(args) -> int:
-    from firedancer_tpu.utils.platform import enable_compile_cache, force_cpu_backend
+    from firedancer_tpu.utils.platform import select_device
 
-    if args.cpu:
-        force_cpu_backend()
-    enable_compile_cache()
+    platform, kind, count = select_device(args.cpu)
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import bench as bench_mod
 
-    import jax
-
-    out = bench_mod.run_pipeline_bench(jax.devices()[0].platform)
+    out = bench_mod.run_pipeline_bench(platform)
+    out["device"] = {"platform": platform, "kind": kind, "count": count}
     print(json.dumps(out))
     return 0
 
 
 def cmd_warmup(args) -> int:
     """AOT-compile the sharded serving step for a mesh shape, through the
-    repo-local persistent serve cache (utils/platform.enable_serve_cache):
-    the leader's boot-time obligation, run BEFORE a slot, so traffic never
+    persistent compile cache (utils/platform.enable_compile_cache): the
+    leader's boot-time obligation, run BEFORE a slot, so traffic never
     waits on XLA.  Second runs load from cache in seconds — pass
     --assert-warm S to fail (exit 2) when the compile/load took longer,
     which is how CI proves the cache-hit path works."""
-    from firedancer_tpu.utils.platform import (
-        enable_serve_cache,
-        force_cpu_backend,
-    )
+    from firedancer_tpu.utils import platform as fp
 
-    if not args.real:
-        force_cpu_backend(device_count=max(args.devices, 8))
-    cache_dir = enable_serve_cache()
+    fp.select_device(args.cpu, device_count=max(args.devices, 8))
     from firedancer_tpu.parallel.serve import ServeConfig, ServePlane
 
     cfg = ServeConfig(
@@ -196,9 +215,10 @@ def cmd_warmup(args) -> int:
     print(json.dumps({
         "serve_step": cfg.cache_key(),
         "devices": args.devices,
+        "platform": plane._mesh_platform(),
         "batch": cfg.batch,
         "compile_s": round(compile_s, 2),
-        "cache_dir": cache_dir,
+        "cache_dir": fp.compile_cache_dir(),
     }))
     if args.assert_warm is not None and compile_s > args.assert_warm:
         print(f"warmup: compile/load took {compile_s:.1f}s "
@@ -481,7 +501,10 @@ def main(argv=None) -> int:
     runp = sub.add_parser("run", help="drive the leader pipeline")
     runp.add_argument("--config", default=None)
     runp.add_argument("--txns", type=int, default=256)
-    runp.add_argument("--cpu", action="store_true", help="force CPU backend")
+    runp.add_argument(
+        "--cpu", action="store_true",
+        help="run on the CPU backend (default: the TPU, and fail without one)",
+    )
     runp.add_argument(
         "--rpc-port", type=int, default=None,
         help="serve JSON-RPC (getTransactionCount/getSlot/...) during the run",
@@ -489,7 +512,8 @@ def main(argv=None) -> int:
     runp.add_argument(
         "--processes", action="store_true",
         help="run every stage as its own supervised OS process "
-             "(the fdctl run model); implies --cpu in the children",
+             "(the fdctl run model); the verify child owns the chip, "
+             "every other child is pinned to the CPU",
     )
     runp.add_argument(
         "--sandbox", action="store_true",
@@ -502,7 +526,8 @@ def main(argv=None) -> int:
     keysp.add_argument("path")
 
     benchp = sub.add_parser("bench", help="pipeline throughput bench")
-    benchp.add_argument("--cpu", action="store_true")
+    benchp.add_argument("--cpu", action="store_true",
+                        help="explicit CPU run (default: require the TPU)")
 
     wup = sub.add_parser(
         "warmup",
@@ -513,8 +538,9 @@ def main(argv=None) -> int:
     wup.add_argument("--batch-per-shard", type=int, default=32)
     wup.add_argument("--max-msg-len", type=int, default=256)
     wup.add_argument("--poh-iters", type=int, default=64)
-    wup.add_argument("--real", action="store_true",
-                     help="use real devices (default: forced CPU mesh)")
+    wup.add_argument("--cpu", action="store_true",
+                     help="compile for a virtual CPU mesh (default: "
+                          "require the TPU)")
     wup.add_argument("--assert-warm", type=float, default=None, metavar="S",
                      help="exit 2 unless compile/load finished within S "
                           "seconds (the CI cache-hit proof)")
@@ -635,6 +661,16 @@ def main(argv=None) -> int:
     sub.add_parser("version", help="print version")
 
     args = p.parse_args(argv)
+    from firedancer_tpu.utils.platform import NoChipError
+
+    try:
+        return _dispatch(args)
+    except NoChipError as e:
+        print(f"firedancer_tpu {args.cmd}: {e}", file=sys.stderr)
+        return 1
+
+
+def _dispatch(args) -> int:
     if args.cmd == "run":
         return cmd_run(args)
     if args.cmd == "keys":
@@ -673,7 +709,7 @@ def main(argv=None) -> int:
     if args.cmd == "slotreport":
         from firedancer_tpu.utils.platform import force_cpu_backend
 
-        force_cpu_backend()  # cluster mode must never cold-init a device
+        force_cpu_backend()  # cluster mode never takes the chip
         return cmd_slotreport(args)
     if args.cmd == "chaos":
         from firedancer_tpu.utils.platform import (
@@ -681,7 +717,7 @@ def main(argv=None) -> int:
             force_cpu_backend,
         )
 
-        force_cpu_backend()  # scenarios must never cold-init a device
+        force_cpu_backend()  # scenarios never take the chip
         enable_compile_cache()
         from firedancer_tpu.chaos import scenario as _chaos
 

@@ -189,8 +189,14 @@ def build_leader_pipeline(
     shed_keep: int | None = None,
     fuse_poh_shred: bool = False,
     udp_ingress: bool = False,
+    n_payers: int = 8,
 ) -> LeaderPipeline:
-    """keep_sets=False releases the shred stage from materializing
+    """n_payers: the generator's funded payer set (and the default bank
+    ctx's genesis).  Pack schedules at most one transaction per payer
+    into a microblock, so a long stream over few payers drains slower
+    than verify feeds it and pack sheds what its pool cannot hold.
+
+    keep_sets=False releases the shred stage from materializing
     FecSets in Python, which lets it adopt the zero-Python sweep lane
     (bench uses this; tests that read pipe.shred.sets keep the
     default).
@@ -247,7 +253,7 @@ def build_leader_pipeline(
             "net", outs=[shm.make_producer(gen_verify)], rx_burst=64
         )
     else:
-        pool = gen_transfer_pool(pool_size)
+        pool = gen_transfer_pool(pool_size, n_payers=n_payers)
         benchg = BenchGStage(
             pool, "benchg", outs=[shm.make_producer(gen_verify)],
             limit=gen_limit
@@ -297,7 +303,7 @@ def build_leader_pipeline(
     # ONE live bank shared by every bank stage (the Frankendancer shape:
     # all bank tiles commit into the same Agave bank over the FFI)
     if bank_ctx is None:
-        bank_ctx = default_bank_ctx(slot=slot)
+        bank_ctx = default_bank_ctx(slot=slot, n_payers=n_payers)
     banks = [
         BankStage(
             f"bank{b}",

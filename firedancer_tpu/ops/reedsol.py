@@ -83,7 +83,7 @@ def encode(data, parity_cnt: int):
 
 # -- host lane (native/fd_reedsol.cpp) ----------------------------------------
 # The leader's shredder encodes one-to-few FEC sets per entry batch, where
-# the device dispatch (+ fetch on tunneled backends) dwarfs the GF work.
+# the device dispatch and fetch dwarf the GF work.
 # The native kernel applies the SAME generator submatrix, so parity bytes
 # are identical; no toolchain -> numpy ground truth (gf256_ref).
 

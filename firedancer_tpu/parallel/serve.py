@@ -26,8 +26,8 @@ counts, and the frag->shard assignment is deterministic (the router's
 Cold-start is a production concern (a leader that compiles for 2 minutes
 misses its slot — MULTICHIP_r05's 2m15s jit_step): the plane supports
 AOT warmup (`warmup()` lowers+compiles before traffic arrives) and the
-repo-local persistent compilation cache (utils/platform.enable_serve_cache)
-so a warmed host's next process boots the step from cache in seconds.
+persistent compilation cache (utils/platform.enable_compile_cache) so a
+warmed host's next process boots the step from cache in seconds.
 """
 
 from __future__ import annotations
@@ -109,7 +109,7 @@ class Pending:
     poh_real: int
 
     def ready(self) -> bool:
-        return getattr(self.ok, "is_ready", lambda: True)()
+        return self.ok.is_ready()
 
 
 class ServePlane:
@@ -133,6 +133,8 @@ class ServePlane:
         self._aot = None  # AOT-compiled executable (warmup path)
         self._placeholder = None  # device-resident zero fec/poh args
         self.compile_s: float | None = None  # measured by warmup()
+        # warmup() booted from a serialized executable (no trace/compile)
+        self.loaded_blob = False
         # rider queue: PoH spans other stages park for the next step call
         self._poh_spans: list[tuple[bytes, bytes]] = []
         self._jax = jax
@@ -224,14 +226,13 @@ class ServePlane:
         return self.mesh.devices.flat[0].platform
 
     def _use_serialized_executable(self) -> bool:
-        """Warm-boot lane choice: serialize_executable on accelerator
-        backends (deserialization is seconds — the 10 s warm_cold_start
-        budget's path), jax.export + persistent cache on CPU where the
-        executable round trip is known to fail (utils/platform
-        .serialize_executable_ok)."""
-        from firedancer_tpu.utils.platform import serialize_executable_ok
-
-        return serialize_executable_ok(self._mesh_platform())
+        """Warm-boot lane choice, from the platform the step runs on: on
+        an accelerator the serialized executable IS machine code, so a
+        warm boot deserializes in seconds (the 10 s warm_cold_start
+        budget's path); on XLA:CPU the round trip fails ("Symbols not
+        found" at load — the executable references process-local
+        symbols), so CPU keeps the jax.export StableHLO lane."""
+        return self._mesh_platform() != "cpu"
 
     def _exec_blob_path(self, cache_dir: str | None) -> str | None:
         if not cache_dir:
@@ -292,12 +293,12 @@ class ServePlane:
                     payload, in_tree, out_tree = pickle.load(f)
                 self._aot = se.deserialize_and_load(payload, in_tree,
                                                     out_tree)
+                self.loaded_blob = True
                 return True
             except Exception as e:
-                # a stale/incompatible blob (jaxlib upgrade, runtime
-                # change) must cost ONE slow recompile, not the boot:
-                # drop it and fall through to the export lane, which
-                # rewrites a fresh blob below
+                # an unreadable or incompatible blob must cost ONE slow
+                # recompile, not the boot: drop it and fall through to
+                # the export lane, which rewrites a fresh blob below
                 print(f"# warm-boot blob unusable ({type(e).__name__}: "
                       f"{e}); recompiling", file=sys.stderr)
                 try:
@@ -375,14 +376,16 @@ class ServePlane:
         the step's OWN input shardings (pre-partitioned, per the pjit
         exemplar note: matching placement skips the implicit reshard)."""
         import jax
-        import jax.numpy as jnp
 
+        # host arrays go straight to their shards: wrapping them in
+        # jnp.asarray first would commit the whole batch to device 0 and
+        # then reshard it across the mesh
         dp = jax.device_put
         return (
-            dp(jnp.asarray(msg), self.s_rows),
-            dp(jnp.asarray(msg_len), self.s_vec),
-            dp(jnp.asarray(sig), self.s_rows),
-            dp(jnp.asarray(pk), self.s_rows),
+            dp(msg, self.s_rows),
+            dp(msg_len, self.s_vec),
+            dp(sig, self.s_rows),
+            dp(pk, self.s_rows),
         )
 
     # -- rider queues (shredder / poh park work for the next step) ----------
@@ -398,7 +401,6 @@ class ServePlane:
 
     def _take_poh(self):
         import jax
-        import jax.numpy as jnp
 
         cfg = self.cfg
         if not self._poh_spans:
@@ -418,9 +420,9 @@ class ServePlane:
         )
         dp = jax.device_put
         return (
-            dp(jnp.asarray(starts), self.s_rows),
-            dp(jnp.asarray(ends), self.s_rows),
-            dp(jnp.asarray(real), self.s_repl),
+            dp(starts, self.s_rows),
+            dp(ends, self.s_rows),
+            dp(real, self.s_repl),
             len(take),
         )
 
@@ -434,7 +436,6 @@ class ServePlane:
         callers that return only the verify mask and would otherwise
         consume the self-audit results without reporting them."""
         import jax
-        import jax.numpy as jnp
 
         self._placeholders()
         fec, _, _ = self._placeholder
@@ -447,7 +448,7 @@ class ServePlane:
         n_real = np.asarray(n_real_per_shard, dtype=np.int32)
         fn = self._aot if self._aot is not None else self._get_step()
         ok, n_ok, par, poh_ok = fn(
-            *args, jax.device_put(jnp.asarray(n_real), self.s_repl),
+            *args, jax.device_put(n_real, self.s_repl),
             self._rs_bits, fec, self._zero_real,
             p_start, p_end, p_real,
         )
@@ -477,7 +478,6 @@ class ServePlane:
         set shardings.  Shapes outside the plane's compiled (d, p) fall
         back to the unsharded encoder."""
         import jax
-        import jax.numpy as jnp
 
         from firedancer_tpu.ops import reedsol as rs
 
@@ -491,7 +491,7 @@ class ServePlane:
         pad_sets = pad_to_multiple(nsets, cfg.n_devices)
         buf = np.zeros((pad_sets, d, cfg.fec_shred_sz), dtype=np.uint8)
         buf[:nsets, :, :sz] = data
-        fec = jax.device_put(jnp.asarray(buf), self.s_sets)
+        fec = jax.device_put(buf, self.s_sets)
         # the sharded path only fires at the compiled (d, p), whose bit
         # matrix _placeholders() already committed once — reuse it
         self._placeholders()
@@ -520,7 +520,6 @@ class ServePlane:
         masked.  Off-shape iter counts fall back to the host verifier's
         device path (runtime/poh.verify_segments_tpu)."""
         import jax
-        import jax.numpy as jnp
 
         cfg = self.cfg
         if iters != cfg.poh_iters:
@@ -537,9 +536,7 @@ class ServePlane:
         eb = np.zeros((32, pad), dtype=np.int32)
         sb[:, :n] = starts
         eb[:, :n] = ends
-        got = self._sharded_poh()(
-            jax.device_put(jnp.asarray(sb), self.s_rows)
-        )
+        got = self._sharded_poh()(jax.device_put(sb, self.s_rows))
         return np.asarray((np.asarray(got) == eb).all(axis=0))[:n]
 
     def real_mask(self, n_real_per_shard) -> np.ndarray:
@@ -549,7 +546,6 @@ class ServePlane:
         import functools
 
         import jax
-        import jax.numpy as jnp
 
         if getattr(self, "_mask_step", None) is None:
             self._mask_step = jax.jit(
@@ -559,7 +555,7 @@ class ServePlane:
                 in_shardings=(self.s_repl,),
                 out_shardings=self.s_vec,
             )
-        n_real = jnp.asarray(np.asarray(n_real_per_shard, dtype=np.int32))
+        n_real = np.asarray(n_real_per_shard, dtype=np.int32)
         return np.asarray(
             self._mask_step(jax.device_put(n_real, self.s_repl))
         )

@@ -216,6 +216,11 @@ def _stage_main(spec: StageSpec, link_names: dict, uid: str,
     stage = None
     try:
         stage = spec.builder(links, cnc, **spec.kwargs)
+        from firedancer_tpu.utils.platform import process_jax_state
+
+        # one process per chip: the run's log shows which children can
+        # reach a device at all (leader_topo: only verify is "default")
+        _log.notice(f"stage {spec.name} jax={process_jax_state()}")
         if resume:
             # counters continue monotonically across the respawn (a
             # fresh zeroed stage would go BACKWARD in the scrape the

@@ -111,8 +111,8 @@ COMB_FILL_BATCH = 32  # pubkeys per comb_fill dispatch (fixed jit shape)
 
 # the generic-lane kernel ladder (ops/sigverify.KERNEL_LADDER): fused is
 # the default — ONE compiled module per batch (validate + sha512 + dsm +
-# compare + pad mask + ok-count); split stays available for tunneled
-# remote-compile backends, baseline for A/B reference
+# compare + pad mask + ok-count); split and baseline are A/B references
+# only, nothing falls back to them
 VERIFY_KERNELS = ("fused", "baseline", "split")
 DEFAULT_KERNEL = os.environ.get("FDTPU_VERIFY_KERNEL", "fused")
 
@@ -572,6 +572,45 @@ class VerifyStage(Stage):
         self._comb_lane_on = rec.comb_split
         self.metrics.inc("retunes")
 
+    # -- device readiness ----------------------------------------------------
+
+    def warmup(self) -> float:
+        """Compile (or load from the persistent cache) the generic-lane
+        program at this stage's exact dispatch shape and dtypes, before
+        traffic: one all-pad batch through the same call the dispatch
+        paths make.  Returns seconds; 0.0 when the stage dispatches
+        nothing itself (precomputed mask, or a serving plane that has
+        its own warmup())."""
+        if self.precomputed_ok or self.plane is not None:
+            return 0.0
+        import jax.numpy as jnp
+
+        from firedancer_tpu.ops import sigverify as sv
+
+        t0 = time.monotonic()
+        b = self.batch
+        mask, _ = sv.verify_dispatch(
+            self.kernel,
+            jnp.asarray(np.zeros((self.max_msg_len, b), dtype=np.uint8)),
+            jnp.asarray(np.zeros((b,), dtype=np.int32)),
+            jnp.asarray(np.zeros((64, b), dtype=np.uint8)),
+            jnp.asarray(np.zeros((32, b), dtype=np.uint8)),
+            0,
+            max_msg_len=self.max_msg_len,
+        )
+        mask.block_until_ready()
+        return time.monotonic() - t0
+
+    def _mask_ready(self, result) -> bool:
+        """Whether a dispatched batch's mask can be fetched without
+        blocking.  The precomputed bench bypass is the ONE case that
+        hands back a host array; anything else must be a device future
+        with is_ready(), so a device result arriving as the wrong type
+        fails here instead of passing as ready."""
+        if self.precomputed_ok and isinstance(result, np.ndarray):
+            return True
+        return result.is_ready()
+
     # -- native sweep-client plumbing ---------------------------------------
 
     def _native_sweep(self, drainer) -> bool:
@@ -630,8 +669,7 @@ class VerifyStage(Stage):
         c = self._sweep_client
         while self._nv_inflight:
             slot, n_elems, n_txn, result, n_ok = self._nv_inflight[0]
-            ready = getattr(result, "is_ready", lambda: True)()
-            if not block and not ready:
+            if not block and not self._mask_ready(result):
                 return
             mask = np.asarray(result)
             self._nv_inflight.pop(0)
@@ -903,9 +941,7 @@ class VerifyStage(Stage):
     # only overrides how a pending entry exposes readiness and its mask.
 
     def _result_ready(self, head) -> bool:
-        # jax arrays expose readiness via is_ready() on committed
-        # arrays; fall back to treating it as ready.
-        return getattr(head.result, "is_ready", lambda: True)()
+        return self._mask_ready(head.result)
 
     def _result_mask(self, head) -> np.ndarray:
         return np.asarray(head.result)

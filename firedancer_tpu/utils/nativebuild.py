@@ -116,20 +116,47 @@ def san_env(san: str) -> dict[str, str]:
     return env
 
 
-def build_so(src: str, so: str) -> str:
+def _source_key(src: str, flags: list[str]) -> str:
+    """Content hash of everything the .so is built from: the flags, the
+    source, and every header beside it (fd_bank/fd_net/fd_ring/fd_shred
+    include fd_metrics.h).  Keyed on content, not mtime: a checkout or a
+    copied tree carries no trustworthy timestamps, and what loads must
+    be built from the files git would commit."""
+    import glob
+    import hashlib
+
+    h = hashlib.sha256(" ".join(flags).encode())
+    d = os.path.dirname(os.path.abspath(src))
+    for path in [src, *sorted(glob.glob(os.path.join(d, "*.h")))]:
+        with open(path, "rb") as f:
+            h.update(b"\0" + os.path.basename(path).encode() + b"\0")
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def build_so(src: str, so: str, *, force: bool = False) -> str:
     """Compile `src` -> `so` if missing/stale and return the path to
-    load.  Under FDTPU_NATIVE_SAN the build lands in the san/<san>/
-    twin with instrumentation flags — the RETURN VALUE is the loadable
-    path, which differs from `so` on that lane.  Raises
-    NativeUnavailable when no toolchain exists or the compile fails."""
+    load.  Stale means the `<so>.key` sidecar does not match the content
+    hash of the sources (_source_key); force=True rebuilds regardless.
+    Under FDTPU_NATIVE_SAN the build lands in the san/<san>/ twin with
+    instrumentation flags — the RETURN VALUE is the loadable path, which
+    differs from `so` on that lane.  Raises NativeUnavailable when no
+    toolchain exists or the compile fails."""
     san = san_mode()
     flags = _BASE_FLAGS
     if san:
         so = san_so_path(so, san)
         flags = _SAN_FLAGS[san]
         os.makedirs(os.path.dirname(so), exist_ok=True)
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(src):
-        return so
+    key = _source_key(src, flags)
+    key_path = f"{so}.key"
+    if not force and os.path.exists(so):
+        try:
+            with open(key_path) as f:
+                if f.read() == key:
+                    return so
+        except OSError:
+            pass
     tmp = f"{so}.{os.getpid()}"
     try:
         subprocess.run(
@@ -139,7 +166,15 @@ def build_so(src: str, so: str) -> str:
             text=True,
         )
         os.replace(tmp, so)
-    except (OSError, subprocess.CalledProcessError) as e:
+        # the key lands after the library: a crash between the two
+        # leaves a mismatch, i.e. one more rebuild, never a stale load
+        with open(f"{key_path}.{os.getpid()}", "w") as f:
+            f.write(key)
+        os.replace(f"{key_path}.{os.getpid()}", key_path)
+    except subprocess.CalledProcessError as e:
+        raise NativeUnavailable(
+            f"cannot build {os.path.basename(so)}: {e}\n{e.stderr}") from e
+    except OSError as e:
         raise NativeUnavailable(f"cannot build {os.path.basename(so)}: {e}") from e
     finally:
         if os.path.exists(tmp):  # failed/interrupted compile leftovers
@@ -148,3 +183,29 @@ def build_so(src: str, so: str) -> str:
             except OSError:
                 pass
     return so
+
+
+NATIVE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    "native",
+)
+
+
+def build_all(*, force: bool = False) -> list[str]:
+    """Build every native/*.cpp (the explicit form of what each loader
+    does on demand: CI, baked containers, chip_smoke.py's from-source
+    rebuild).  Returns the built paths; raises NativeUnavailable on the
+    first failure."""
+    import glob
+
+    return [
+        build_so(src, src[: -len(".cpp")] + ".so", force=force)
+        for src in sorted(glob.glob(os.path.join(NATIVE_DIR, "*.cpp")))
+    ]
+
+
+if __name__ == "__main__":
+    import sys
+
+    for _so in build_all(force="--force" in sys.argv[1:]):
+        print(f"nativebuild: {os.path.relpath(_so, NATIVE_DIR)} ok")

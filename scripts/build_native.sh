@@ -1,17 +1,18 @@
 #!/usr/bin/env bash
-# Build every native (C++) hot-path component with consistent flags.
+# Build every native (C++) hot-path component.
 #
-# The runtime builds these on demand (utils/nativebuild.build_so uses the
-# same flags + an atomic rename, so concurrent stage processes never see a
-# half-written .so); this script is the explicit form for CI, containers
-# baked ahead of time, and clean rebuilds.  Hosts without a toolchain are
-# fine: every loader raises NativeUnavailable and its caller falls back to
-# the Python lane, and the tests SKIP (never fail).
+# The runtime builds these on demand (utils/nativebuild.build_so: one set
+# of flags, staleness keyed on a content hash of the .cpp AND the headers
+# beside it, atomic rename so concurrent stage processes never see a
+# half-written .so); this script is the explicit form of the same builder
+# for CI, containers baked ahead of time, and clean rebuilds.  Hosts
+# without a toolchain are fine: every loader raises NativeUnavailable and
+# its caller falls back to the Python lane, and the tests SKIP (never
+# fail).
 #
-# Sanitizer lane (ISSUE 15): `--san asan|ubsan|tsan` builds instrumented twins
-# into native/san/<san>/ — the same flags utils/nativebuild uses when
-# FDTPU_NATIVE_SAN is set, so a prebuilt CI lane and the on-demand lane
-# produce interchangeable artifacts.  Run the suites against them with
+# Sanitizer lane (ISSUE 15): `--san asan|ubsan|tsan` builds instrumented
+# twins into native/san/<san>/ (FDTPU_NATIVE_SAN selects the same lane at
+# run time).  Run the suites against them with
 #   FDTPU_NATIVE_SAN=asan LD_PRELOAD="$(g++ -print-file-name=libasan.so)" \
 #     ASAN_OPTIONS=detect_leaks=0 python -m pytest tests/test_native_san.py
 # (docs/OPERATIONS.md has the full runbook).
@@ -19,24 +20,17 @@
 # Usage: scripts/build_native.sh [--force] [--san asan|ubsan|tsan]
 
 set -euo pipefail
-cd "$(dirname "$0")/../native"
+cd "$(dirname "$0")/.."
 
-CXX=${CXX:-g++}
-CXXFLAGS=${CXXFLAGS:--O2 -shared -fPIC}
-
-force=0
-san=""
+args=()
 while [ $# -gt 0 ]; do
     case "$1" in
-        --force) force=1 ;;
+        --force) args+=(--force) ;;
         --san)
             shift
-            san="${1:-}"
-            case "$san" in
-                asan)  CXXFLAGS="-O1 -shared -fPIC -g -fno-omit-frame-pointer -fsanitize=address" ;;
-                ubsan) CXXFLAGS="-O1 -shared -fPIC -g -fsanitize=undefined -fno-sanitize-recover=undefined" ;;
-                tsan)  CXXFLAGS="-O1 -shared -fPIC -g -fno-omit-frame-pointer -fsanitize=thread" ;;
-                *) echo "build_native: --san expects asan|ubsan|tsan (got '$san')" >&2; exit 2 ;;
+            case "${1:-}" in
+                asan|ubsan|tsan) export FDTPU_NATIVE_SAN="$1" ;;
+                *) echo "build_native: --san expects asan|ubsan|tsan (got '${1:-}')" >&2; exit 2 ;;
             esac
             ;;
         *) echo "build_native: unknown arg '$1'" >&2; exit 2 ;;
@@ -44,26 +38,9 @@ while [ $# -gt 0 ]; do
     shift
 done
 
-if ! command -v "$CXX" >/dev/null 2>&1; then
-    echo "build_native: no $CXX on this host; runtime falls back to python lanes" >&2
+if ! command -v g++ >/dev/null 2>&1; then
+    echo "build_native: no g++ on this host; runtime falls back to python lanes" >&2
     exit 0
 fi
 
-outdir="."
-if [ -n "$san" ]; then
-    outdir="san/$san"
-    mkdir -p "$outdir"
-fi
-
-for src in *.cpp; do
-    so="$outdir/${src%.cpp}.so"
-    if [ "$force" = 0 ] && [ -f "$so" ] && [ "$so" -nt "$src" ]; then
-        echo "build_native: $so up to date"
-        continue
-    fi
-    tmp="$so.$$"
-    # shellcheck disable=SC2086
-    "$CXX" $CXXFLAGS -o "$tmp" "$src"
-    mv -f "$tmp" "$so"
-    echo "build_native: built $so"
-done
+exec "${PYTHON:-python3}" -m firedancer_tpu.utils.nativebuild "${args[@]}"

@@ -1,6 +1,6 @@
 """Probe WHY composed point ops run ~5x slower than raw fe_mul chains.
 
-perf_fe.py measured (TPU v5e, batch 16384):
+An earlier field-op microbenchmark measured (TPU v5e, batch 16384):
     jnp13 (one fe_mul chained)   0.024 ms/iter
     pdbl13 (point_dbl chained)   0.757 ms/iter  (~6.4 fe_mul-equiv of work)
 The gap means the kernel's cost is NOT the multiply count.  Decompose:

@@ -36,18 +36,3 @@ def test_configure_cli(capsys):
     rc = main(["configure", "check"])
     out = capsys.readouterr().out
     assert "shm" in out and rc in (0, 1)
-
-
-def test_compile_cache_partitioned_by_configuration(monkeypatch):
-    """AOT entries from different XLA configurations must never share a
-    directory (mixed entries segfault at cache load)."""
-    from firedancer_tpu.utils import platform as P
-
-    monkeypatch.setenv("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8")
-    a = P.default_cache_dir()
-    monkeypatch.setenv("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=1")
-    b = P.default_cache_dir()
-    assert a != b
-    assert a.startswith(str(P.default_cache_dir().rsplit("/", 1)[0]).rsplit("/", 1)[0])

@@ -15,17 +15,18 @@ N_TXNS = 32
 
 @pytest.mark.timeout(1800)
 def test_leader_pipeline_as_processes():
-    # no parent warm-up: CPU compile-cache persistence is disabled
-    # (AOT serialization segfaults — utils/platform.py), so children
-    # compile their own kernels; the supervision windows below allow it
-    topo = build_leader_topology(n_txns=N_TXNS, pool_size=N_TXNS, batch=16)
+    # verify_cpu: the verify child owns the chip by default; here it is
+    # asked to run its kernel on the CPU.  It compiles (or loads from the
+    # shared cache) in its builder; the supervision windows allow that.
+    topo = build_leader_topology(n_txns=N_TXNS, pool_size=N_TXNS, batch=16,
+                                 verify_cpu=True)
     h = ft.launch(topo)
     try:
         ok = h.supervise(
             until=lambda h: h.cncs["store"].diag(Stage.DIAG_FRAGS_IN) > 0
             and h.cncs["bank0"].diag(Stage.DIAG_FRAGS_IN) > 0,
             timeout_s=1200,
-            heartbeat_timeout_s=900,  # children COLD-compile their kernels now
+            heartbeat_timeout_s=900,
         )
         mon = h.format_monitor()
         assert ok, f"process pipeline stalled:\n{mon}"

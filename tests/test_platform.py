@@ -1,0 +1,140 @@
+"""The device rule and the compile-cache rule (utils/platform.py): the
+chip is required unless the caller asks for the CPU, and the cache can
+be placed from outside."""
+
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+from firedancer_tpu.utils import platform as fp
+
+REPO = fp.REPO_ROOT
+
+
+@pytest.fixture
+def restore_cache_dir():
+    import jax
+
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_cache_dir_env_is_left_to_jax(monkeypatch, restore_cache_dir):
+    """With JAX_COMPILATION_CACHE_DIR set the code performs no cache-dir
+    update; a fresh interpreter shows JAX picked the env value itself."""
+    import jax
+
+    calls = []
+    real_update = jax.config.update
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append(k) or real_update(k, v))
+    monkeypatch.setenv(fp.CACHE_DIR_ENV, "/some/dir")
+    assert fp.enable_compile_cache() == "/some/dir"
+    assert "jax_compilation_cache_dir" not in calls
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from firedancer_tpu.utils.platform import enable_compile_cache;"
+         "enable_compile_cache(); import jax;"
+         "print(jax.config.jax_compilation_cache_dir)"],
+        cwd=REPO, capture_output=True, text=True, timeout=120,
+        env={**os.environ, fp.CACHE_DIR_ENV: "/some/dir",
+             "JAX_PLATFORMS": "cpu"},
+    )
+    assert out.stdout.strip() == "/some/dir", out.stderr[-2000:]
+
+
+def test_cache_dir_default_is_one_fixed_path(monkeypatch, restore_cache_dir):
+    import jax
+
+    monkeypatch.delenv(fp.CACHE_DIR_ENV, raising=False)
+    monkeypatch.setenv("XLA_FLAGS",
+                       "--xla_force_host_platform_device_count=8")
+    a = fp.enable_compile_cache()
+    monkeypatch.setenv("XLA_FLAGS",
+                       "--xla_force_host_platform_device_count=1")
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    b = fp.enable_compile_cache()
+    assert a == b == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == a
+
+
+def test_require_chip_raises_on_cpu():
+    with pytest.raises(fp.NoChipError, match="no TPU"):
+        fp.require_chip()
+
+
+def test_process_jax_state_reports_the_pin():
+    assert fp.process_jax_state() == "cpu"  # conftest pinned this process
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from firedancer_tpu.utils.platform import process_jax_state as p;"
+         "print(p()); import jax; print(p())"],
+        cwd=REPO, capture_output=True, text=True, timeout=120,
+        env={k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"},
+    )
+    assert out.stdout.split() == ["none", "default"], out.stderr[-2000:]
+
+
+def test_sharded_process_topology_never_races_for_the_chip():
+    """N verify processes cannot share a chip: unless the shard children
+    are kept off it, building the topology is an error, not a hang."""
+    from firedancer_tpu.models.leader_topo import (
+        build_sharded_leader_topology,
+    )
+
+    with pytest.raises(ValueError, match="a chip belongs to one process"):
+        build_sharded_leader_topology(n_shards=2)
+    build_sharded_leader_topology(n_shards=2, verify_cpu=True)
+    build_sharded_leader_topology(n_shards=2, verify_precomputed=True)
+
+
+@pytest.mark.parametrize("cmd", [
+    ["chip_smoke.py"],
+    ["bench.py"],
+    ["-m", "firedancer_tpu", "run", "--txns", "8"],
+])
+def test_entry_points_refuse_to_run_without_a_chip(cmd):
+    t0 = time.monotonic()
+    out = subprocess.run(
+        [sys.executable, *cmd], cwd=REPO, capture_output=True, text=True,
+        timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert out.returncode != 0
+    assert "no TPU" in out.stderr
+    assert not out.stdout.strip()  # no result of any kind
+    assert time.monotonic() - t0 < 60  # before any compile
+
+
+@pytest.mark.slow
+def test_chip_smoke_phase_a_on_cpu_at_tiny_size(tmp_path, capsys):
+    """Phase A's own function — programs compiled and held to the
+    reference, a clean stream and a corrupted one end to end — at batch
+    16 / 64 transactions / 3 corrupted on the CPU.  Called as a function:
+    the script itself has no way to pass without a chip."""
+    import json
+
+    import jax
+
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    cfg = tmp_path / "tiny.toml"
+    cfg.write_text("[layout]\nverify_stage_count = 1\nbank_stage_count = 2\n"
+                   "[verify]\nbatch = 16\nmax_msg_len = 256\n")
+    d = jax.devices()
+    chip_smoke.phase_a(
+        (d[0].platform, d[0].device_kind, len(d)), config=str(cfg),
+        n_stream=64, n_bad_stream=64, n_bad=3, extra_shapes=(),
+        comb_slots=16,
+    )
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    assert [ln["step"] for ln in lines] == [
+        "compile", "stream", "corrupted_stream"]
+    assert all(ln["ok"] and ln["platform"] == "cpu" for ln in lines)
+    assert lines[1]["txn_exec"] == 64 and lines[1]["verify_fail"] == 0
+    assert lines[2]["txn_exec"] == 61 and lines[2]["verify_fail"] == 3

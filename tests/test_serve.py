@@ -298,14 +298,11 @@ def test_serving_step_byte_identical_to_single_device():
 # serialization itself, which cannot run here) is what these pin.
 
 
-def test_warmboot_lane_selection_cpu_vs_accel(tmp_path, monkeypatch):
-    from firedancer_tpu.utils import platform as fp
-
-    assert not fp.serialize_executable_ok("cpu")
-    assert fp.serialize_executable_ok("tpu")
-    assert fp.serialize_executable_ok("gpu")
-    monkeypatch.setenv("FDTPU_FORCE_SERIALIZE_EXEC", "1")
-    assert fp.serialize_executable_ok("cpu")  # debug override
+def test_warmboot_lane_selection_cpu_vs_accel(monkeypatch):
+    plane = ServePlane(TINY)
+    assert not plane._use_serialized_executable()  # the CPU test mesh
+    monkeypatch.setattr(type(plane), "_mesh_platform", lambda self: "tpu")
+    assert plane._use_serialized_executable()
 
 
 def test_plane_selects_export_lane_on_cpu(tiny_plane):
