@@ -320,6 +320,7 @@ class BankStage(Stage):
         c = self._sweep_client
         if c is not None:
             self.metrics.counters.update(c.counters())
+            self._copy_sweep_counters()
 
     def flush(self) -> None:
         """Settle any pending stash (end-of-run: the harness stops

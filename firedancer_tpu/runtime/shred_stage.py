@@ -139,6 +139,7 @@ class ShredStage(Stage):
             # absolute values into the schema metrics at the same lazy
             # cadence every other stage metric has
             self.metrics.counters.update(c.counters())
+            self._copy_sweep_counters()
 
     def _room(self) -> bool:
         """A batch bursts ~2 sets x ~65 shreds; don't start shredding unless

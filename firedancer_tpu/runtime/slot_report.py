@@ -112,6 +112,9 @@ def _stage_block(mets: dict, records: list) -> dict:
     # flight ring (fdm_flight release-stores survive any kill).
     flight = {"nsweep_drain": 0, "nsweep_publish": 0,
               "last_drain_ts": None, "last_publish_ts": None}
+    # which phase of which device batch held the stage's thread for
+    # 100 ms or more (verify): the answer to "where did the pause go"
+    stalls = []
     for ts, ev, arg in records:
         if ev == fm.EV_NSWEEP_DRAIN:
             flight["nsweep_drain"] += 1
@@ -119,7 +122,11 @@ def _stage_block(mets: dict, records: list) -> dict:
         elif ev == fm.EV_NSWEEP_PUBLISH:
             flight["nsweep_publish"] += 1
             flight["last_publish_ts"] = ts
+        elif ev == fm.EV_BATCH_STALL:
+            stalls.append({"ts": ts, **fm.batch_stall_fields(arg)})
     block["flight"] = flight
+    if stalls:
+        block["batch_stalls"] = stalls
     return block
 
 

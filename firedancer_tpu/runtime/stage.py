@@ -108,11 +108,11 @@ class Metrics:
         if value > 0:
             self._hsums[name] += value
 
-    def observe_batch(self, name: str, values) -> None:
+    def observe_batch(self, name: str, values):
         """Vectorized observe() over a 1-D ndarray — the native
         burst-drain path observes a whole sweep's frag latencies from the
         returned meta table in one searchsorted+bincount instead of a
-        clock read + bisect per frag."""
+        clock read + bisect per frag.  -> what it added to the sum."""
         edges = self._hedges_np.get(name)
         if edges is None:
             edges = self._hedges_np[name] = np.asarray(
@@ -124,7 +124,9 @@ class Metrics:
         )
         for j in np.flatnonzero(bc):
             c[j] += int(bc[j])
-        self._hsums[name] += float(values[values > 0].sum())
+        total = values[values > 0].sum()
+        self._hsums[name] += float(total)
+        return total
 
     def hist(self, name: str) -> dict:
         return {
@@ -320,6 +322,25 @@ class Stage:
             set_metrics = getattr(client, "set_metrics", None)
             if set_metrics is not None:
                 set_metrics(None)  # C drops its raw pointer too
+
+    def _copy_sweep_counters(self) -> None:
+        """Time inside this stage's non-empty native crossings, for a
+        reader of `metrics.counters`: the sums of the four nsweep_*_ns
+        phase histograms and the nsweep_crossings count, which C writes
+        into the registry, copied into the local-only counters
+        `sweep_busy_ns` and `sweep_crossings` (not schema names: the
+        registry holds the originals).  Natively swept stages call it
+        from during_housekeeping, beside their C-side counter copy."""
+        cached = self._nplane
+        if cached is None or cached[1] is None:
+            return
+        reg = cached[1].registry
+        if "nsweep_crossings" not in reg._off:
+            return  # a schema without the native-sweep block
+        c = self.metrics.counters
+        c["sweep_busy_ns"] = int(sum(reg.hist_sum(f"nsweep_{ph}_ns")
+                                     for ph in fm.NSWEEP_PHASES))
+        c["sweep_crossings"] = reg.get("nsweep_crossings")
 
     # -- in-place restart (supervisor respawn) -------------------------------
 
@@ -541,7 +562,10 @@ class Stage:
                     if ts:
                         lat = shm.now_ns() - ts
                         if lat >= 0:
-                            self.metrics.observe("frag_latency_ns", lat)
+                            m = self.metrics
+                            m.observe("frag_latency_ns", lat)
+                            m.inc("frag_wait_ns", lat)
+                            m.inc("frag_wait_n")
                 self._in_rr = (idx + 1) % n_in
                 break
             if not got:
@@ -610,12 +634,21 @@ class Stage:
         if n == 0:
             return d_ovr > 0
         m.inc("frags_in", n)
-        ts_col = drainer.meta[:n, 5].astype(np.int64)
+        self._observe_waits(drainer.meta[:n, 5].astype(np.int64))
+        return True
+
+    def _observe_waits(self, ts_col: np.ndarray) -> None:
+        """One sweep's frag latencies off its tsorig column, one clock
+        read for all of them: into the frag_latency_ns histogram, and
+        the same values summed into frag_wait_ns / frag_wait_n (the
+        histogram's sum and count as counters, which is the form a
+        reader of `metrics.counters` can take a window's mean from)."""
         lat = shm.now_ns() - ts_col
         ok = lat[(ts_col > 0) & (lat >= 0)]
         if ok.size:
-            m.observe_batch("frag_latency_ns", ok)
-        return True
+            m = self.metrics
+            m.inc("frag_wait_ns", int(m.observe_batch("frag_latency_ns", ok)))
+            m.inc("frag_wait_n", ok.size)
 
     # drain-table batch hook: a stage may process a whole drained sweep
     # from the meta table + joined payload buffer in ONE call instead of
@@ -670,11 +703,7 @@ class Stage:
             n_done, ts_done = sweep_frags(rows, buf)
             if n_done:
                 m.inc("frags_in", n_done)
-                ts_col = np.asarray(ts_done, dtype=np.int64)
-                lat = shm.now_ns() - ts_col
-                ok = lat[(ts_col > 0) & (lat >= 0)]
-                if ok.size:
-                    m.observe_batch("frag_latency_ns", ok)
+                self._observe_waits(np.asarray(ts_done, dtype=np.int64))
             return True
         before_frag = self.before_frag
         during_frag = self.during_frag
@@ -695,11 +724,7 @@ class Stage:
         if n_done:
             m.inc("frags_in", n_done)
             # batch latency observation: one clock read for the sweep
-            ts_col = np.asarray(ts_done, dtype=np.int64)
-            lat = shm.now_ns() - ts_col
-            ok = lat[(ts_col > 0) & (lat >= 0)]
-            if ok.size:
-                m.observe_batch("frag_latency_ns", ok)
+            self._observe_waits(np.asarray(ts_done, dtype=np.int64))
         return True
 
     def run(

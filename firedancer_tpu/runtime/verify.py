@@ -39,6 +39,7 @@ without the retry).
 
 from __future__ import annotations
 
+import contextlib
 import os
 import time
 from dataclasses import dataclass, field
@@ -127,6 +128,29 @@ DEFAULT_MAX_INFLIGHT = int(os.environ.get("FDTPU_VERIFY_INFLIGHT", "8"))
 _NATIVE_FRAME_MTU = 1232 + 2048 + 2
 
 
+# a batch's life (utils/metrics.BATCH_PHASES), phase ids
+(PH_OPEN, PH_SEALED_WAIT, PH_H2D, PH_LAUNCH, PH_INFLIGHT, PH_REAP,
+ PH_PUBLISH) = range(len(fm.BATCH_PHASES))
+_PHASE_COUNTERS = tuple(f"batch_{p}_ns" for p in fm.BATCH_PHASES)
+
+_now_ns = time.monotonic_ns
+
+_trace_annotation = None
+_NO_SPAN = contextlib.nullcontext()
+
+
+class _Life:
+    """One batch's stamps on time.monotonic_ns(): t[0] is when its first
+    element entered the slot, t[k + 1] when phase k ended (BATCH_PHASES
+    order).  `seq` is its dispatch order, from 1."""
+
+    __slots__ = ("seq", "t")
+
+    def __init__(self, opened_ns: int):
+        self.seq = 0
+        self.t = [opened_ns]
+
+
 def sig_tag(sig: bytes) -> int:
     """64-bit dedup tag: low 8 bytes of the (uniformly distributed) sig."""
     return int.from_bytes(sig[:8], "little") or 1
@@ -146,6 +170,8 @@ class _Pending:
     # the baseline/split/cached/plane lanes — the reap falls back to
     # host mask arithmetic)
     n_ok: object = None
+    # the batch's stamps (None where a subclass builds its own pending)
+    life: _Life | None = None
 
 
 @dataclass
@@ -159,6 +185,7 @@ class _Acc:
     tsorigs: list[int] = field(default_factory=list)
     slots: list[int] = field(default_factory=list)  # cached path only
     opened_at: float = 0.0
+    life: _Life | None = None  # stamped when the first element enters
 
     def clear(self) -> None:
         self.payloads, self.descs = [], []
@@ -250,6 +277,13 @@ class VerifyStage(Stage):
         # the queue without limit
         self._emit_queue: list = []
         self._emit_queue_max = 8192
+        # whose frames the emit queue holds, in order: [life, frames
+        # still queued] per reaped batch — a batch's publish phase ends
+        # when its last frame has left the queue
+        self._emit_marks: list = []
+        for name in _PHASE_COUNTERS:
+            self.metrics.counters[name] = 0
+        self.metrics.counters["batch_stalls"] = 0
         # sweep-granularity parser (drain-table path), built on first use
         self._burst_parser = None
         # -- native sweep client (ISSUE 13) -----------------------------------
@@ -260,8 +294,9 @@ class VerifyStage(Stage):
         # frame size.  native_client: None = auto-arm for exact
         # VerifyStage instances, False = never, True = required.
         self._sweep_client = None
-        self._nv_inflight: list = []  # (slot, n_elems, n_txn, result, n_ok)
-        self._nv_emit: list = []  # [slot, frame table, published idx]
+        # (slot, n_elems, n_txn, result, n_ok, life)
+        self._nv_inflight: list = []
+        self._nv_emit: list = []  # [slot, frame table, published idx, life]
         self._nv_opened_at = 0.0
         want_native = (native_client if native_client is not None
                        else type(self) is VerifyStage)
@@ -331,6 +366,31 @@ class VerifyStage(Stage):
                      "frags dropped after the native intake stash"
                      " overflowed (dead/wedged consumer)")
             .counter("retunes", "autotuner geometry changes applied")
+            # the life of a batch, summed over batches as each phase
+            # ends (ns; divide a window's delta by its delta of batches)
+            .counter("batch_open_ns",
+                     "first element in the slot -> the slot sealed")
+            .counter("batch_sealed_wait_ns",
+                     "sealed -> the dispatch call begins (waiting for a"
+                     " window slot, or for the thread to come back)")
+            .counter("batch_h2d_ns",
+                     "the host->device copies of the dispatch (on the"
+                     " Python lane the byte-row assembly before them too)")
+            .counter("batch_launch_ns",
+                     "the kernel dispatch call, copies excluded")
+            .counter("batch_inflight_ns",
+                     "dispatch returned -> the loop first sees the mask"
+                     " ready (device queue + execution + how late the"
+                     " thread looked)")
+            .counter("batch_reap_ns",
+                     "mask and ok-count fetched, per-txn reduction")
+            .counter("batch_publish_ns",
+                     "reaped -> the batch's last frame out and its slot"
+                     " released (credit waits included)")
+            .counter("batch_stalls",
+                     "thread-blocking batch phases (h2d, launch, reap,"
+                     " publish) of 100 ms or more; each is an"
+                     " EV_BATCH_STALL flight event")
             .histogram(
                 "batch_fill",
                 fm.exp_buckets(1, 4096, 13),
@@ -392,6 +452,11 @@ class VerifyStage(Stage):
         acc = self._comb if slots is not None else self._gen
         if acc.elems and len(acc.elems) + len(sigs) > self.batch:
             self._close_batch(acc)
+            acc = self._comb if slots is not None else self._gen
+        if not acc.elems:
+            # the batch opens here: one clock read a batch (not the
+            # deadline's clock, which before_credit stamps)
+            acc.life = _Life(_now_ns())
         start = len(acc.elems)
         for i, (s, pk) in enumerate(zip(sigs, signers)):
             acc.elems.append((msg, s, pk))
@@ -518,7 +583,7 @@ class VerifyStage(Stage):
         # credits are available again: retry frames a full out ring
         # parked on the emit queue before touching new work
         if self._emit_queue:
-            self._emit_burst([])
+            self._emit_reaped([])
         # deadline-based batch close (p99 latency at low occupancy)
         now = time.monotonic()
         for acc in (self._gen, self._comb):
@@ -536,6 +601,7 @@ class VerifyStage(Stage):
             # (the shred-client discipline): absolute values copied at
             # the same lazy cadence every other stage metric has
             self.metrics.counters.update(c.counters())
+            self._copy_sweep_counters()
             return
         self._pump_submits()
         self._drain(block=False)
@@ -611,6 +677,72 @@ class VerifyStage(Stage):
             return True
         return result.is_ready()
 
+    # -- the life of a batch -------------------------------------------------
+
+    def _span(self, name: str, life: _Life | None):
+        """A blocking point of one batch as a span on the profiler's
+        host plane, so on the device trace's clock:
+        jax.profiler.TraceAnnotation, imported on first use like the
+        stage's other JAX imports.  With no profiler session it costs a
+        flag test.  The all-pass mask has nothing on the device to line
+        up with, and stays free of JAX."""
+        if self.precomputed_ok:
+            return _NO_SPAN
+        global _trace_annotation
+        if _trace_annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _trace_annotation = TraceAnnotation
+        return _trace_annotation(name, batch=life.seq if life else 0)
+
+    def _phase_end(self, life: _Life | None, phase: int,
+                   now: int | None = None) -> None:
+        """End `phase` of one batch's life (it began where the phase
+        before it ended): add its nanoseconds to the phase's counter.
+        The ONE place a batch is stamped — the native lane and the
+        Python lane both come through here, so they cannot stamp
+        differently.  A thread-blocking phase of BATCH_STALL_NS or more
+        is a flight event that names the phase."""
+        if life is None:  # a subclass's own pending (parallel/serve)
+            return
+        if now is None:
+            now = _now_ns()
+        t = life.t
+        while len(t) <= phase:  # a phase that was skipped reads 0 (the
+            t.append(t[-1])     # all-pass mask copies and launches nothing)
+        ns = now - t[phase]
+        t.append(now)
+        c = self.metrics.counters
+        c[_PHASE_COUNTERS[phase]] += ns
+        if ns >= fm.BATCH_STALL_NS and phase in fm.BATCH_BLOCKING_PHASES:
+            c["batch_stalls"] += 1
+            self.trace(fm.EV_BATCH_STALL, fm.batch_stall_arg(phase, ns))
+
+    def _dispatch_begins(self, life: _Life | None) -> None:
+        """The sealed batch's wait is over: it takes its place in
+        dispatch order (the annotations' `batch`)."""
+        self._phase_end(life, PH_SEALED_WAIT)
+        if life is not None:
+            life.seq = self.metrics.get("batches") + 1
+
+    def _device_verify(self, life: _Life | None, msg, ln, sig, pk, n: int):
+        """The kernel-ladder dispatch of one batch's byte rows: the four
+        host->device copies (the end of the batch's h2d phase), then the
+        kernel call (fused by default: one compiled module per batch,
+        pad lanes masked + ok-count computed on device); the caller ends
+        the launch phase.  -> (mask future, ok-count future | None)."""
+        import jax.numpy as jnp
+
+        from firedancer_tpu.ops import sigverify as sv
+
+        # uint8 byte rows: 4x less host->device transfer; the kernel
+        # widens to int32 on-device
+        dev = (jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
+               jnp.asarray(pk))
+        self._phase_end(life, PH_H2D)
+        return sv.verify_dispatch(self.kernel, *dev, n,
+                                  max_msg_len=self.max_msg_len)
+
     # -- native sweep-client plumbing ---------------------------------------
 
     def _native_sweep(self, drainer) -> bool:
@@ -635,30 +767,26 @@ class VerifyStage(Stage):
         self._nv_drain(block=False)
         self._nv_publish()
 
-    def _nv_dispatch(self, slot: int, n_elems: int, n_txn: int) -> None:
+    def _nv_dispatch(self, slot: int, n_elems: int, n_txn: int,
+                     opened_ns: int, sealed_ns: int) -> None:
         c = self._sweep_client
         views = c.slots[slot]
         # per-txn msg lengths for the autotuner: one vectorized observe
         # off the ln column at the txns' first elements
         starts = views.ranges[:n_txn, 0].astype(np.int64)
         self.metrics.observe_batch("msg_len", views.ln[starts])
+        life = _Life(opened_ns)
+        self._phase_end(life, PH_OPEN, sealed_ns)
+        self._dispatch_begins(life)
         if self.precomputed_ok:
             result, n_ok = np.ones((n_elems,), dtype=bool), None
         else:
-            import jax.numpy as jnp
-
-            from firedancer_tpu.ops import sigverify as sv
-
-            result, n_ok = sv.verify_dispatch(
-                self.kernel,
-                jnp.asarray(views.msg.T),
-                jnp.asarray(views.ln),
-                jnp.asarray(views.sig.T),
-                jnp.asarray(views.pk.T),
-                n_elems,
-                max_msg_len=self.max_msg_len,
-            )
-        self._nv_inflight.append((slot, n_elems, n_txn, result, n_ok))
+            with self._span("verify.dispatch", life):
+                result, n_ok = self._device_verify(
+                    life, views.msg.T, views.ln, views.sig.T, views.pk.T,
+                    n_elems)
+        self._phase_end(life, PH_LAUNCH)
+        self._nv_inflight.append((slot, n_elems, n_txn, result, n_ok, life))
         self.metrics.inc("batches", 1)
         self.metrics.inc("batch_elems", n_elems)
         self.metrics.observe("batch_fill", n_elems)
@@ -668,34 +796,38 @@ class VerifyStage(Stage):
     def _nv_drain(self, block: bool) -> None:
         c = self._sweep_client
         while self._nv_inflight:
-            slot, n_elems, n_txn, result, n_ok = self._nv_inflight[0]
+            slot, n_elems, n_txn, result, n_ok, life = self._nv_inflight[0]
             if not block and not self._mask_ready(result):
                 return
-            mask = np.asarray(result)
-            self._nv_inflight.pop(0)
-            self.trace(fm.EV_BATCH_COMPLETE, n_elems)
-            views = c.slots[slot]
-            frames = views.frames[:n_txn]
-            if n_ok is not None:
-                all_ok = int(n_ok) == n_elems
-            else:
-                all_ok = bool(mask[:n_elems].all())
-            if all_ok:
-                tbl = frames
-                kept = n_txn
-            else:
-                starts = views.ranges[:n_txn, 0].astype(np.int64)
-                ok_txn = np.minimum.reduceat(
-                    mask[:n_elems].astype(np.uint8), starts
-                ).astype(bool)
-                tbl = np.ascontiguousarray(frames[ok_txn])
-                kept = int(ok_txn.sum())
-                self.metrics.inc("verify_fail", n_txn - kept)
+            self._phase_end(life, PH_INFLIGHT)
+            with self._span("verify.reap", life):
+                mask = np.asarray(result)
+                self._nv_inflight.pop(0)
+                self.trace(fm.EV_BATCH_COMPLETE, n_elems)
+                views = c.slots[slot]
+                frames = views.frames[:n_txn]
+                if n_ok is not None:
+                    all_ok = int(n_ok) == n_elems
+                else:
+                    all_ok = bool(mask[:n_elems].all())
+                if all_ok:
+                    tbl = frames
+                    kept = n_txn
+                else:
+                    starts = views.ranges[:n_txn, 0].astype(np.int64)
+                    ok_txn = np.minimum.reduceat(
+                        mask[:n_elems].astype(np.uint8), starts
+                    ).astype(bool)
+                    tbl = np.ascontiguousarray(frames[ok_txn])
+                    kept = int(ok_txn.sum())
+                    self.metrics.inc("verify_fail", n_txn - kept)
+            self._phase_end(life, PH_REAP)
             if kept:
                 self.metrics.inc("txn_verified", kept)
-                self._nv_emit.append([slot, tbl, 0])
+                self._nv_emit.append([slot, tbl, 0, life])
             else:
                 c.release(slot)
+                self._phase_end(life, PH_PUBLISH)
             if block:
                 break
 
@@ -716,22 +848,24 @@ class VerifyStage(Stage):
         plane = self._native_plane()
         while self._nv_emit:
             ent = self._nv_emit[0]
-            slot, tbl, pos = ent
+            slot, tbl, pos, life = ent
             sub = tbl[pos:]
-            if self.ring_clock:
-                _t = pc()
-                done = p.publish_burst_raw(c.slots[slot].arena_ptr, sub,
-                                           len(sub), plane)
-                self.ring_publish_s += pc() - _t
-            else:
-                done = p.publish_burst_raw(c.slots[slot].arena_ptr, sub,
-                                           len(sub), plane)
+            with self._span("verify.publish", life):
+                if self.ring_clock:
+                    _t = pc()
+                    done = p.publish_burst_raw(c.slots[slot].arena_ptr,
+                                               sub, len(sub), plane)
+                    self.ring_publish_s += pc() - _t
+                else:
+                    done = p.publish_burst_raw(c.slots[slot].arena_ptr,
+                                               sub, len(sub), plane)
             if done:
                 self.metrics.inc("frags_out", done)
             ent[2] = pos + done
             if ent[2] == len(tbl):
                 self._nv_emit.pop(0)
                 c.release(slot)
+                self._phase_end(life, PH_PUBLISH)
             else:
                 self.metrics.inc("backpressure", len(sub) - done)
                 break
@@ -828,6 +962,7 @@ class VerifyStage(Stage):
             self._comb = _Acc()
         else:
             self._gen = _Acc()
+        self._phase_end(acc.life, PH_OPEN)
         self._submit_queue.append((acc, cached))
         self._pump_submits()
         if self._submit_queue:
@@ -846,10 +981,14 @@ class VerifyStage(Stage):
 
     def _submit(self, acc: _Acc, cached: bool) -> None:
         n = len(acc.elems)
+        life = acc.life
+        self._dispatch_begins(life)
         if self.precomputed_ok:
             result, n_ok = np.ones((n,), dtype=bool), None
         else:
-            result, n_ok = self._dispatch(acc, cached)
+            with self._span("verify.dispatch", life):
+                result, n_ok = self._dispatch(acc, cached)
+        self._phase_end(life, PH_LAUNCH)
         self._inflight.append(
             _Pending(
                 payloads=acc.payloads,
@@ -859,6 +998,7 @@ class VerifyStage(Stage):
                 n_elems=n,
                 result=result,
                 n_ok=n_ok,
+                life=life,
             )
         )
         self.metrics.inc("batches", 1)
@@ -897,43 +1037,33 @@ class VerifyStage(Stage):
         return msg.T, ln, sig.T, pk.T
 
     def _dispatch(self, acc: _Acc, cached: bool):
-        """-> (mask future, ok-count future | None)."""
+        """-> (mask future, ok-count future | None).  Ends the batch's
+        h2d phase once its arrays are on the device; the byte-row
+        assembly counts into it on this lane (the native lane assembles
+        in C, at intake)."""
+        n = len(acc.elems)
+        b = self.batch
+        life = acc.life
+        msg, ln, sig, pk = self._assemble(acc)
+        if not cached and self.plane is None:
+            return self._device_verify(life, msg, ln, sig, pk, n)
+        if not cached:
+            # mesh route: the sharded serving step (pad lanes beyond n
+            # are masked by the step itself via the per-shard fills),
+            # which makes its own copies
+            self._phase_end(life, PH_H2D)
+            return self.plane.verify_batch(msg, ln, sig, pk), None
         import jax.numpy as jnp
 
         from firedancer_tpu.ops import sigverify as sv
 
-        n = len(acc.elems)
-        b = self.batch
-        # uint8 byte rows: 4x less host->device transfer; the kernel
-        # widens to int32 on-device
-        msg, ln, sig, pk = self._assemble(acc)
-        if self.plane is not None and not cached:
-            # mesh route: the sharded serving step (pad lanes beyond n
-            # are masked by the step itself via the per-shard fills)
-            return self.plane.verify_batch(msg, ln, sig, pk), None
-        if cached:
-            slots = np.zeros((b,), dtype=np.int32)
-            slots[:n] = acc.slots
-            return sv.ed25519_verify_batch_cached(
-                jnp.asarray(msg),
-                jnp.asarray(ln),
-                jnp.asarray(sig),
-                jnp.asarray(pk),
-                self._bank,
-                jnp.asarray(slots),
-                max_msg_len=self.max_msg_len,
-            ), None
-        # the kernel-ladder lane (fused by default: one compiled module
-        # per batch, pad lanes masked + ok-count computed on device)
-        return sv.verify_dispatch(
-            self.kernel,
-            jnp.asarray(msg),
-            jnp.asarray(ln),
-            jnp.asarray(sig),
-            jnp.asarray(pk),
-            n,
-            max_msg_len=self.max_msg_len,
-        )
+        slots = np.zeros((b,), dtype=np.int32)
+        slots[:n] = acc.slots
+        dev = (jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
+               jnp.asarray(pk), self._bank, jnp.asarray(slots))
+        self._phase_end(life, PH_H2D)
+        return sv.ed25519_verify_batch_cached(
+            *dev, max_msg_len=self.max_msg_len), None
 
     # result-extraction hooks: the sharded serving stage (parallel/serve.
     # ShardedVerifyStage) reuses THIS drain loop — the txn-level
@@ -951,32 +1081,43 @@ class VerifyStage(Stage):
             head = self._inflight[0]
             if not block and not self._result_ready(head):
                 return
-            mask = self._result_mask(head)
-            self._inflight.pop(0)
-            # a window slot freed: submit parked batches before reaping
-            # (keeps the device fed while the host walks the mask)
-            self._pump_submits()
-            self.trace(fm.EV_BATCH_COMPLETE, head.n_elems)
-            # honest traffic overwhelmingly passes whole batches: one
-            # all-reduce decides the common case instead of a numpy
-            # slice + reduction per txn (~1.5us/txn of the host path).
-            # The fused lane computed the count on device — the reap
-            # reads one scalar instead of scanning the mask.
-            if head.n_ok is not None:
-                all_ok = int(head.n_ok) == head.n_elems
-            else:
-                all_ok = bool(mask[: head.n_elems].all())
-            emits = []
-            for payload, desc, (a, b), tsorig in zip(
-                head.payloads, head.descs, head.elem_ranges, head.tsorigs
-            ):
-                if all_ok or bool(mask[a:b].all()):
-                    emits.append(self._encode_emit(payload, desc, tsorig))
-                else:
-                    self.metrics.inc("verify_fail")
-            self._emit_burst(emits)
+            life = head.life
+            self._phase_end(life, PH_INFLIGHT)
+            with self._span("verify.reap", life):
+                emits = self._reap(head)
+            self._phase_end(life, PH_REAP)
+            self._emit_reaped(emits, life)
             if block:
                 break
+
+    def _reap(self, head) -> list:
+        """Fetch the head batch's mask, free its window slot, and encode
+        the frames of the transactions that passed."""
+        mask = self._result_mask(head)
+        self._inflight.pop(0)
+        # a window slot freed: submit parked batches before reaping
+        # (keeps the device fed while the host walks the mask); their
+        # dispatch falls inside this batch's reap phase
+        self._pump_submits()
+        self.trace(fm.EV_BATCH_COMPLETE, head.n_elems)
+        # honest traffic overwhelmingly passes whole batches: one
+        # all-reduce decides the common case instead of a numpy
+        # slice + reduction per txn (~1.5us/txn of the host path).
+        # The fused lane computed the count on device — the reap
+        # reads one scalar instead of scanning the mask.
+        if head.n_ok is not None:
+            all_ok = int(head.n_ok) == head.n_elems
+        else:
+            all_ok = bool(mask[: head.n_elems].all())
+        emits = []
+        for payload, desc, (a, b), tsorig in zip(
+            head.payloads, head.descs, head.elem_ranges, head.tsorigs
+        ):
+            if all_ok or bool(mask[a:b].all()):
+                emits.append(self._encode_emit(payload, desc, tsorig))
+            else:
+                self.metrics.inc("verify_fail")
+        return emits
 
     def _encode_emit(self, payload: bytes, desc_pair, tsorig: int):
         desc, packed = desc_pair
@@ -1010,9 +1151,27 @@ class VerifyStage(Stage):
                 del q[:drop]
                 self.metrics.inc("emit_dropped", drop)
 
+    def _emit_reaped(self, emits: list, life: _Life | None = None) -> None:
+        """_emit_burst with the books of whose frames the queue holds:
+        `life` is the reaped batch `emits` are of (none on a retry).  A
+        batch's publish phase ends when its last frame has left the
+        queue, published or dropped."""
+        marks = self._emit_marks
+        if emits or life is not None:
+            marks.append([life, len(emits)])
+        with self._span("verify.publish", marks[0][0] if marks else None):
+            self._emit_burst(emits)
+        left = len(self._emit_queue) if self.outs else 0
+        gone = sum(m[1] for m in marks) - left
+        while marks and marks[0][1] <= gone:
+            gone -= marks[0][1]
+            self._phase_end(marks.pop(0)[0], PH_PUBLISH)
+        if marks:
+            marks[0][1] -= gone
+
     def _emit(self, payload: bytes, desc_pair, tsorig: int = 0) -> None:
         """Single-frag emit (compat surface for tests/subclasses)."""
-        self._emit_burst([self._encode_emit(payload, desc_pair, tsorig)])
+        self._emit_reaped([self._encode_emit(payload, desc_pair, tsorig)])
 
     def flush(self) -> None:
         """Close and drain everything (test/shutdown path)."""
@@ -1042,7 +1201,7 @@ class VerifyStage(Stage):
             self._drain(block=True)
             self._pump_submits()
         if self._emit_queue:
-            self._emit_burst([])
+            self._emit_reaped([])
 
 
 def encode_verified_packed(payload: bytes, packed: bytes) -> bytes:

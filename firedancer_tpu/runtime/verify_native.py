@@ -107,7 +107,8 @@ _TAIL_FLAGS = 0
 _TAIL_OPEN_ELEMS = 1
 _TAIL_COUNTERS = 2
 
-_META_NCOL = 4  # (state, n_elems, n_txn, arena_off) per slot
+# (state, n_elems, n_txn, arena_off, opened_ns, sealed_ns) per slot
+_META_NCOL = 6
 
 
 class _SlotViews:
@@ -215,17 +216,19 @@ class StageClient:
     def pump(self) -> None:
         self._lib.fdv_pump(self._h)
 
-    def take_sealed(self) -> tuple[int, int, int] | None:
-        """Next sealed slot in ring order as (slot idx, n_elems, n_txn),
-        marked INFLIGHT (python-owned until release); None when the next
-        slot in order is not sealed — dispatch stays in submission
-        order by construction."""
+    def take_sealed(self) -> tuple[int, int, int, int, int] | None:
+        """Next sealed slot in ring order as (slot idx, n_elems, n_txn,
+        opened ns, sealed ns) — the two stamps are the C side's, on
+        time.monotonic_ns()'s clock — marked INFLIGHT (python-owned
+        until release); None when the next slot in order is not sealed —
+        dispatch stays in submission order by construction."""
         i = self._next_dispatch
-        if self.meta[i, 0] != SLOT_SEALED:
+        row = self.meta[i]
+        if row[0] != SLOT_SEALED:
             return None
-        self.meta[i, 0] = SLOT_INFLIGHT
+        row[0] = SLOT_INFLIGHT
         self._next_dispatch = (i + 1) % self.n_slots
-        return i, int(self.meta[i, 1]), int(self.meta[i, 2])
+        return i, int(row[1]), int(row[2]), int(row[4]), int(row[5])
 
     def release(self, slot: int) -> None:
         self._lib.fdv_slot_release(self._h, slot)

@@ -196,13 +196,18 @@ class MetricsRegistry:
             raise TypeError("use hist() for histograms")
         return int(self.words[off])
 
+    def hist_sum(self, name: str) -> float:
+        """A histogram's sum alone (one word: no walk over its buckets)."""
+        d, off = self._off[name]
+        return int(self.words[off + len(d.buckets) + 1]) / SUM_SCALE
+
     def hist(self, name: str) -> dict:
         d, off = self._off[name]
         counts = [int(self.words[off + i]) for i in range(len(d.buckets) + 1)]
         return {
             "buckets": list(d.buckets),
             "counts": counts,
-            "sum": int(self.words[off + len(d.buckets) + 1]) / SUM_SCALE,
+            "sum": self.hist_sum(name),
             "count": sum(counts),
         }
 
@@ -438,6 +443,8 @@ EV_RESTART = 17        # stage resumed in place after a supervisor respawn
 EV_NSWEEP_DRAIN = 18   # native sweep crossing drained (arg = frags; C-side,
                        # decimated — every FDM_FLIGHT_DECIMATE crossings)
 EV_NSWEEP_PUBLISH = 19  # native sweep crossing published (arg = frags; C-side)
+EV_BATCH_STALL = 20    # one thread-blocking phase of one device batch took
+                       # BATCH_STALL_NS or more (arg = batch_stall_arg)
 
 EVENT_NAMES = {
     EV_BOOT: "boot",
@@ -459,7 +466,29 @@ EVENT_NAMES = {
     EV_RESTART: "restart",
     EV_NSWEEP_DRAIN: "nsweep_drain",
     EV_NSWEEP_PUBLISH: "nsweep_publish",
+    EV_BATCH_STALL: "batch_stall",
 }
+
+# The life of a device batch, in order.  Phase k ends where phase k+1
+# begins; the verify stage adds each phase's nanoseconds to the counter
+# `batch_<phase>_ns` when the phase ENDS (runtime/verify.py).
+BATCH_PHASES = ("open", "sealed_wait", "h2d", "launch", "inflight", "reap",
+                "publish")
+# the phases in which the stage's one thread is inside a call
+BATCH_BLOCKING_PHASES = frozenset(
+    BATCH_PHASES.index(p) for p in ("h2d", "launch", "reap", "publish"))
+BATCH_STALL_NS = 100_000_000
+
+
+def batch_stall_arg(phase: int, ns: int) -> int:
+    """EV_BATCH_STALL's arg: phase id in the high half, whole ms below."""
+    return (phase << 32) | min(ns // 1_000_000, 0xFFFFFFFF)
+
+
+def batch_stall_fields(arg: int) -> dict:
+    phase = arg >> 32
+    name = BATCH_PHASES[phase] if phase < len(BATCH_PHASES) else str(phase)
+    return {"phase": name, "ms": arg & 0xFFFFFFFF}
 
 FLIGHT_DEPTH = 512  # records per stage ring (fixed, small: ~12 KiB)
 
@@ -654,9 +683,11 @@ def flight_to_chrome_trace(dump: dict) -> dict:
                                "id": bid, "pid": 1, "tid": tid, "ts": us,
                                "args": {"elems": arg}})
             else:
+                args = batch_stall_fields(arg) if ev == EV_BATCH_STALL \
+                    else {"arg": arg}
                 events.append({"name": ev_name, "ph": "i", "pid": 1,
                                "tid": tid, "ts": us, "s": "t",
-                               "args": {"arg": arg}})
+                               "args": args})
         # close dangling batch spans (crash mid-flight) at the last ts
         # so the JSON stays well-formed for strict importers
         for bid in open_ids:
@@ -687,6 +718,10 @@ def stage_schema() -> MetricsSchema:
         .counter("restart_dedup",
                  "replayed frags suppressed by the in-place-restart"
                  " publish guard (exactly-once resume)")
+        .counter("frag_wait_ns",
+                 "sum of tsorig->consume ns over the frags observed into"
+                 " frag_latency_ns (the histogram's sum, as a counter)")
+        .counter("frag_wait_n", "frags in frag_wait_ns")
         .histogram(
             "frag_latency_ns",
             exp_buckets(1e3, 1e10, 24),

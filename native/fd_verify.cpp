@@ -34,6 +34,8 @@
 #include <cstdlib>
 #include <cstring>
 
+#include "fd_metrics.h"  // fdm_now_ns: the clock of time.monotonic_ns()
+
 namespace {
 
 typedef int64_t (*fdv_parse_fn)(const uint8_t*, uint64_t, uint8_t*, uint64_t);
@@ -46,13 +48,22 @@ constexpr int STASH_CAP = 8;
 
 enum { SLOT_FREE = 0, SLOT_OPEN = 1, SLOT_SEALED = 2, SLOT_INFLIGHT = 3 };
 
-// one row per slot, viewed zero-FFI from Python (u64 x 4)
+// one row per slot, viewed zero-FFI from Python (u64 x META_NCOL;
+// runtime/verify_native._META_NCOL mirrors it, fdlint FD305)
+constexpr uint64_t META_NCOL = 6;
 struct fdv_slot_meta {
   uint64_t state;
   uint64_t n_elems;
   uint64_t n_txn;
   uint64_t arena_off;
+  // the batch's first two stamps (CLOCK_MONOTONIC ns, the clock of
+  // time.monotonic_ns() and of tsorig): when its first element entered
+  // the slot, and when the slot was sealed.  Two clock reads a batch.
+  uint64_t opened_ns;
+  uint64_t sealed_ns;
 };
+static_assert(sizeof(fdv_slot_meta) == META_NCOL * sizeof(uint64_t),
+              "slot-meta row is META_NCOL u64s");
 
 struct fdv_slot {
   uint8_t* msg;      // batch x mml, row-major (elem e at msg + e*mml)
@@ -117,6 +128,8 @@ bool acquire_open(fdv_stage* s) {
   m->n_elems = 0;
   m->n_txn = 0;
   m->arena_off = 0;
+  m->opened_ns = fdm_now_ns();
+  m->sealed_ns = 0;
   s->open = (int64_t)s->next_open;
   s->next_open = (s->next_open + 1) % s->n_slots;
   return true;
@@ -126,6 +139,7 @@ void seal_open(fdv_stage* s) {
   if (s->open < 0) return;
   fdv_slot_meta* m = &s->meta[s->open];
   if (!m->n_txn) return;  // nothing accumulated: stay open
+  m->sealed_ns = fdm_now_ns();
   m->state = SLOT_SEALED;
   s->open = -1;
   s->c_sealed_batches++;

@@ -1,0 +1,387 @@
+"""The life of a verify batch as counters (ISSUE 24): seven cumulative-ns
+phase counters stamped through one helper on both lanes, a flight event
+when a thread-blocking phase stalls, every hop's wait as a counter beside
+the latency histogram, and the time inside the native crossings.
+
+Everything runs on the CPU with the all-pass mask or a stubbed dispatch:
+the lanes under test are the host's, and nothing compiles.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import hashlib
+import os
+import time
+
+import numpy as np
+import pytest
+
+from firedancer_tpu.protocol import txn as ft
+from firedancer_tpu.runtime import slot_report
+from firedancer_tpu.runtime import verify as rv
+from firedancer_tpu.runtime import verify_native as vn
+from firedancer_tpu.runtime.benchg import gen_transfer_pool
+from firedancer_tpu.runtime.stage import Stage
+from firedancer_tpu.runtime.verify import VerifyStage
+from firedancer_tpu.tango import shm
+from firedancer_tpu.utils import metrics as fm
+
+LANES = ["native", "python"]
+PHASE_COUNTERS = [f"batch_{p}_ns" for p in fm.BATCH_PHASES]
+
+
+@pytest.fixture(scope="module")
+def pool():
+    return gen_transfer_pool(96, n_payers=12, n_dests=64)
+
+
+@contextlib.contextmanager
+def _tile(lane: str, **stage_kw):
+    """One VerifyStage over real (native) rings -> (stage, producer into
+    it, consumer behind it)."""
+    if lane == "native" and not vn.available():
+        pytest.skip("native verify client unavailable")
+    prev = os.environ.get(vn.ENV_SWITCH)
+    os.environ[vn.ENV_SWITCH] = "1" if lane == "native" else "0"
+    uid = shm.fresh_uid()
+    lin = shm.ShmLink.create(f"tbl_i_{uid}", depth=256, mtu=1232, n_fseq=1)
+    lout = shm.ShmLink.create(f"tbl_o_{uid}", depth=256, mtu=4096, n_fseq=1)
+    st = None
+    try:
+        kw = dict(batch=16, max_msg_len=256, batch_deadline_s=0.001,
+                  precomputed_ok=True)
+        kw.update(stage_kw)
+        st = VerifyStage("v0", ins=[shm.make_consumer(lin, lazy=8)],
+                         outs=[shm.make_producer(lout)], **kw)
+        assert (st._sweep_client is not None) == (lane == "native")
+        yield st, shm.make_producer(lin), shm.make_consumer(lout, lazy=4)
+    finally:
+        if prev is None:
+            os.environ.pop(vn.ENV_SWITCH, None)
+        else:
+            os.environ[vn.ENV_SWITCH] = prev
+        if st is not None:
+            st.ins, st.outs = [], []
+            st.drop_native_views()
+        lin.close()
+        lout.close()
+
+
+def _drain(cons) -> int:
+    n = 0
+    while cons.poll() not in (shm.POLL_EMPTY, shm.POLL_OVERRUN):
+        n += 1
+    return n
+
+
+def _record_lives(st) -> list:
+    """Every batch whose life completes, in order."""
+    lives = []
+    inner = st._phase_end
+
+    def record(life, phase, now=None):
+        inner(life, phase, now)
+        if phase == rv.PH_PUBLISH and life is not None:
+            lives.append(life)
+
+    st._phase_end = record
+    return lives
+
+
+def _trickle(st, prod, cons, pool, *, per_loop=3, every=64, loops=20000):
+    """Offer a few transactions every few loops, so batches close on the
+    deadline one at a time.  -> (frames out, counter samples)."""
+    fed = out = 0
+    samples = []
+    for it in range(loops):
+        if it % every == 0:
+            for _ in range(per_loop):
+                if fed < len(pool) and prod.try_publish(
+                        pool[fed], sig=fed, tsorig=0):
+                    fed += 1
+        st.run_once()
+        out += _drain(cons)
+        if it % 64 == 0:
+            samples.append([st.metrics.get(k) for k in PHASE_COUNTERS])
+        if fed == len(pool) and out == fed:
+            break
+    st.flush()
+    out += _drain(cons)
+    samples.append([st.metrics.get(k) for k in PHASE_COUNTERS])
+    return out, samples
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_phase_counters_present_before_any_batch(lane):
+    with _tile(lane) as (st, _prod, _cons):
+        for k in PHASE_COUNTERS + ["batch_stalls"]:
+            assert st.metrics.counters[k] == 0
+        assert set(PHASE_COUNTERS) <= st.metrics_schema().names()
+        assert {"frag_wait_ns", "frag_wait_n"} <= st.metrics_schema().names()
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_phase_counters_monotone_ordered_and_exact(lane, pool):
+    with _tile(lane) as (st, prod, cons):
+        lives = _record_lives(st)
+        t0 = time.monotonic_ns()
+        out, samples = _trickle(st, prod, cons, pool)
+        wall = time.monotonic_ns() - t0
+        assert out == len(pool)
+        n_batches = st.metrics.get("batches")
+        assert n_batches >= 6 and len(lives) == n_batches
+        # monotone, sample to sample
+        for a, b in zip(samples, samples[1:]):
+            assert all(y >= x for x, y in zip(a, b))
+        # a batch's stamps are ordered: open <= sealed <= dispatch begin
+        # <= copies done <= dispatch end <= ready <= reaped <= published
+        for life in lives:
+            assert len(life.t) == len(fm.BATCH_PHASES) + 1
+            assert life.t == sorted(life.t)
+            assert t0 <= life.t[0] and life.t[-1] <= t0 + wall
+        assert [life.seq for life in lives] == list(range(1, n_batches + 1))
+        # the counters are the sums of the stamps' differences, exactly
+        for k, name in enumerate(PHASE_COUNTERS):
+            assert st.metrics.get(name) == sum(
+                life.t[k + 1] - life.t[k] for life in lives)
+        # batches went one at a time, so what blocked the thread fits in
+        # the loop's wall time
+        blocking = sum(st.metrics.get(PHASE_COUNTERS[k])
+                       for k in fm.BATCH_BLOCKING_PHASES)
+        assert 0 < blocking <= wall
+        # every batch was open for about its deadline, none for a loop's age
+        open_ms = st.metrics.get("batch_open_ns") / n_batches / 1e6
+        assert 0.5 <= open_ms < 50
+        assert st.metrics.get("batch_stalls") == 0
+
+
+def test_native_open_and_seal_stamps_are_on_the_python_clock(pool):
+    """The C side stamps open and seal with CLOCK_MONOTONIC, which is
+    time.monotonic_ns(): no offset between the crossing and the loop."""
+    with _tile("native") as (st, prod, _cons):
+        c = st._sweep_client
+        t0 = time.monotonic_ns()
+        for i in range(5):
+            assert prod.try_publish(pool[i], sig=i, tsorig=0)
+        st.run_once()
+        assert c.open_elems() == 5
+        t1 = time.monotonic_ns()
+        c.seal()
+        t2 = time.monotonic_ns()
+        slot, n_elems, n_txn, opened, sealed = c.take_sealed()
+        assert (n_elems, n_txn) == (5, 5)
+        assert t0 <= opened <= t1 <= sealed <= t2
+        c.release(slot)
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_a_full_batch_closes_without_the_deadline(lane, pool):
+    """Sealed by filling (in C on the native lane): open is short."""
+    with _tile(lane, batch_deadline_s=10.0) as (st, prod, cons):
+        lives = _record_lives(st)
+        for i in range(16):
+            assert prod.try_publish(pool[i], sig=i, tsorig=0)
+        out = 0
+        for _ in range(200):
+            st.run_once()
+            out += _drain(cons)
+        assert out == 16 and len(lives) == 1
+        assert lives[0].t[1] - lives[0].t[0] < 1e9  # not the 10 s deadline
+
+
+class _SlowResult:
+    """A device future's surface, for a stubbed dispatch."""
+
+    def __init__(self, n):
+        self.mask = np.ones((n,), dtype=bool)
+
+    def is_ready(self):
+        return True
+
+    def __array__(self, dtype=None, copy=None):
+        return self.mask
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_a_slow_dispatch_is_one_stall_event(lane, pool):
+    import jax.profiler  # noqa: F401  (the span's import, off the clock)
+
+    with _tile(lane, precomputed_ok=False) as (st, prod, cons):
+        def slow(life, msg, ln, sig, pk, n):
+            st._phase_end(life, rv.PH_H2D)
+            time.sleep(0.12)
+            return _SlowResult(n), None
+
+        st._device_verify = slow
+        for i in range(5):
+            assert prod.try_publish(pool[i], sig=i, tsorig=0)
+        out = 0
+        for _ in range(400):
+            st.run_once()
+            out += _drain(cons)
+            if out == 5:
+                break
+        assert out == 5
+        assert st.metrics.get("batches") == 1
+        assert st.metrics.get("batch_stalls") == 1
+        assert st.metrics.get("batch_launch_ns") >= 120e6
+        stalls = [(ev, arg) for _ts, ev, arg in st.recorder.records()
+                  if ev == fm.EV_BATCH_STALL]
+        assert len(stalls) == 1
+        got = fm.batch_stall_fields(stalls[0][1])
+        assert got["phase"] == "launch" and 120 <= got["ms"] < 1000
+        # submit and complete keep their rate: two events for the batch
+        evs = [ev for _ts, ev, _arg in st.recorder.records()]
+        assert evs.count(fm.EV_BATCH_SUBMIT) == 1
+        assert evs.count(fm.EV_BATCH_COMPLETE) == 1
+        # the flight dump's readers name the phase
+        dump = fm.flight_dump_obj("t", {"v0": (None, st.recorder)})
+        chrome = fm.flight_to_chrome_trace(dump)["traceEvents"]
+        hit = [e for e in chrome if e["name"] == "batch_stall"]
+        assert len(hit) == 1 and hit[0]["args"] == got
+        block = slot_report.build_report(dump)["stages"]["v0"]
+        assert [s["phase"] for s in block["batch_stalls"]] == ["launch"]
+
+
+def test_stall_event_wire_value_and_arg():
+    assert fm.EV_BATCH_SUBMIT == 8 and fm.EV_BATCH_COMPLETE == 9
+    assert fm.EV_BATCH_STALL == 20
+    assert fm.EVENT_NAMES[fm.EV_BATCH_STALL] == "batch_stall"
+    arg = fm.batch_stall_arg(rv.PH_REAP, 1_910_000_000)
+    assert fm.batch_stall_fields(arg) == {"phase": "reap", "ms": 1910}
+    assert fm.BATCH_STALL_NS == 100_000_000
+
+
+# -- every hop's wait ----------------------------------------------------------
+
+
+class _Count(Stage):
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.got = 0
+
+    def after_frag(self, in_idx, meta, payload):
+        self.got += 1
+
+
+class _CountTable(_Count):
+    def sweep_frags(self, rows, buf):
+        self.got += len(rows)
+        return len(rows), [r[5] for r in rows]
+
+
+def _wait_stage(path: str, lin):
+    """-> a stage that observes frag latency on the named path."""
+    if path == "sweep":
+        if not vn.available():
+            pytest.skip("native verify client unavailable")
+        lout = shm.ShmLink.create(f"tbl_w_{shm.fresh_uid()}", depth=256,
+                                  mtu=4096, n_fseq=1)
+        st = VerifyStage("w", ins=[shm.make_consumer(lin, lazy=8)],
+                         outs=[shm.make_producer(lout)], batch=16,
+                         max_msg_len=256, precomputed_ok=True)
+        assert st._sweep_client is not None
+        return st, lout
+    cons = shm.make_consumer(lin, lazy=8)
+    if path == "poll":
+        from firedancer_tpu.tango.lossy import LossyConsumer
+        from firedancer_tpu.utils.rng import Rng
+
+        return _Count("w", ins=[LossyConsumer(cons, Rng(7))]), None
+    cls = _CountTable if path == "burst_table" else _Count
+    return cls("w", ins=[cons]), None
+
+
+@pytest.mark.parametrize("path", ["poll", "sweep", "burst_table",
+                                  "burst_frag"])
+def test_frag_wait_counters_are_the_histograms_sum_and_count(path, pool):
+    lin = shm.ShmLink.create(f"tbl_q_{shm.fresh_uid()}", depth=256,
+                             mtu=1232, n_fseq=1)
+    st = lout = None
+    try:
+        prod = shm.make_producer(lin)
+        st, lout = _wait_stage(path, lin)
+        drainer = st._native_drainer()
+        assert (drainer is None) == (path == "poll")
+        due = time.monotonic_ns() - 5_000_000
+        for i in range(40):
+            assert prod.try_publish(pool[i], sig=i, tsorig=due + i)
+        for _ in range(50):
+            st.run_once()
+        h = st.metrics.hist("frag_latency_ns")
+        assert h["count"] == 40
+        assert st.metrics.get("frag_wait_n") == 40
+        assert st.metrics.get("frag_wait_ns") == int(h["sum"])
+        mean_ms = st.metrics.get("frag_wait_ns") / 40 / 1e6
+        assert 5.0 <= mean_ms < 5000
+    finally:
+        if st is not None:
+            st.ins, st.outs = [], []
+            st.drop_native_views()
+        lin.close()
+        if lout is not None:
+            lout.close()
+
+
+# -- time inside the crossings ---------------------------------------------------
+
+
+def test_sweep_busy_is_the_sum_of_the_registrys_phase_sums(pool):
+    with _tile("native") as (st, prod, cons):
+        assert "sweep_busy_ns" not in st.metrics.counters
+        _trickle(st, prod, cons, pool[:40])
+        st.during_housekeeping()
+        reg = st.metrics.registry
+        want = sum(reg.hist(f"nsweep_{ph}_ns")["sum"]
+                   for ph in fm.NSWEEP_PHASES)
+        assert want > 0
+        assert st.metrics.get("sweep_busy_ns") == int(want)
+        assert st.metrics.get("sweep_crossings") \
+            == reg.get("nsweep_crossings") > 0
+        # local-only: the registry holds the originals
+        assert "sweep_busy_ns" not in st.metrics_schema().names()
+
+
+def test_sweep_counters_absent_where_the_stage_does_not_sweep_natively(pool):
+    with _tile("python") as (st, prod, cons):
+        _trickle(st, prod, cons, pool[:20])
+        st.during_housekeeping()
+        assert "sweep_busy_ns" not in st.metrics.counters
+    assert "sweep_busy_ns" not in Stage("s").metrics.counters
+    Stage("s")._copy_sweep_counters()  # no plane: nothing to copy
+
+
+# -- a batch that overflows opens a new one ---------------------------------------
+
+
+def _three_sig_txn(i: int) -> bytes:
+    keys = [hashlib.sha256(b"k%d-%d" % (i, j)).digest() for j in range(3)]
+    msg = ft.message_build(
+        version=ft.VLEGACY, signature_cnt=3, readonly_signed_cnt=1,
+        readonly_unsigned_cnt=1, acct_addrs=keys + [ft.SYSTEM_PROGRAM],
+        recent_blockhash=bytes(32),
+        instrs=[ft.InstrSpec(program_id=3, accounts=bytes([0, 1, 2]),
+                             data=b"hi")])
+    sigs = [hashlib.sha512(b"s%d-%d" % (i, j)).digest() for j in range(3)]
+    return ft.txn_assemble(sigs, msg)
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_a_txn_that_does_not_fit_opens_the_next_batch(lane):
+    """3 + 3 signatures into 4 lanes: the second transaction's elements
+    belong to a batch of their own, with stamps of its own."""
+    with _tile(lane, batch=4) as (st, prod, cons):
+        lives = _record_lives(st)
+        for i in range(2):
+            assert prod.try_publish(_three_sig_txn(i), sig=i, tsorig=0)
+        out = 0
+        for _ in range(400):
+            st.run_once()
+            out += _drain(cons)
+        st.flush()
+        out += _drain(cons)
+        assert out == 2
+        assert st.metrics.get("batches") == 2
+        assert st.metrics.get("batch_elems") == 6
+        assert len(lives) == 2 and lives[0] is not lives[1]

@@ -78,7 +78,7 @@ def test_attach_newest_run_discovery():
         h.close()
 
 
-def test_ready_cli_exit_codes():
+def test_ready_cli_exit_codes(monkeypatch, tmp_path):
     from firedancer_tpu.__main__ import main
 
     topo = _mini_topology()
@@ -90,7 +90,10 @@ def test_ready_cli_exit_codes():
         h.halt()
     finally:
         h.close()
-    # no live runs -> attach fails -> exit 1
+    # no live runs -> attach fails -> exit 1 (asked of a run directory of
+    # its own: under several test workers /tmp holds the live topologies
+    # of the other workers' tests, and the newest of those is a live run)
+    monkeypatch.setattr(mon, "RUN_DIR", str(tmp_path))
     assert main(["ready", "--timeout", "1"]) == 1
 
 
