@@ -580,6 +580,9 @@ class ServePlane:
 
 
 from firedancer_tpu.runtime.verify import (  # noqa: E402
+    _CLOSE_COUNTERS,
+    CLOSE_DEADLINE,
+    CLOSE_FULL,
     MCACHE_COL_TSORIG,
     VerifyStage,
     _Acc,
@@ -598,9 +601,11 @@ class ShardedVerifyStage(VerifyStage):
     deterministic `seq % n_shards` assignment carries through to device
     placement (ring i -> mesh device i) with no host-side reshuffle.
 
-    The batch closes when any shard's lane range fills or the deadline
-    passes (the VerifyStage deadline-close discipline); uneven fills pad
-    and the step masks pad lanes on device from the per-shard counts.
+    The batch closes when any shard's lane range fills, or when the
+    deadline has passed and the in-flight window has room (the
+    VerifyStage close rule, `_deadline_close`: this stage only names its
+    accumulators); uneven fills pad and the step masks pad lanes on
+    device from the per-shard counts.
     """
 
     def __init__(self, *args, plane: ServePlane, **kwargs):
@@ -616,7 +621,9 @@ class ShardedVerifyStage(VerifyStage):
         # one accumulator per shard (per input ring); VerifyStage's _gen
         # acc is unused on this subclass
         self._shards = [_Acc() for _ in range(self.n_shards)]
+        zeroed = self.metrics.counters  # VerifyStage's start-at-0 counters
         self.metrics = type(self.metrics)(self.metrics_schema_n(self.n_shards))
+        self.metrics.counters.update(zeroed)
 
     # -- observability ------------------------------------------------------
 
@@ -671,33 +678,27 @@ class ShardedVerifyStage(VerifyStage):
         if len(acc.elems) >= self.batch:
             self._close_batch()
 
-    def before_credit(self) -> None:
-        for acc in self._shards:
-            if acc.elems and acc.opened_at == 0.0:
-                acc.opened_at = time.monotonic()
-
-    def after_credit(self) -> None:
-        now = time.monotonic()
-        if any(
-            acc.elems and acc.opened_at
-            and now - acc.opened_at >= self.batch_deadline_s
-            for acc in self._shards
-        ):
-            self._close_batch()
-        self._drain(block=False)
+    def _open_accs(self):
+        return self._shards
 
     def during_housekeeping(self) -> None:
         self._drain(block=False)
 
     # -- the sharded dispatch ------------------------------------------------
 
-    def _close_batch(self, acc=None) -> None:
+    def _close_batch(self, acc=None, why: int = CLOSE_FULL) -> None:
+        """Close the WHOLE step (every shard's partial fill) and dispatch
+        it.  A shard that filled closes it whatever the window holds
+        and waits for the head; the reap it waits for may itself close
+        the step (a shard held past its deadline), so the fills are
+        read after it."""
         accs = self._shards
+        if len(self._inflight) >= self.max_inflight \
+                and any(a.elems for a in accs):
+            self._drain(block=True)
         n_elems = sum(len(a.elems) for a in accs)
         if n_elems == 0:
             return
-        if len(self._inflight) >= self.max_inflight:
-            self._drain(block=True)
         cfg = self.plane.cfg
         per = cfg.batch_per_shard
         b = cfg.batch
@@ -739,6 +740,7 @@ class ShardedVerifyStage(VerifyStage):
             )
         )
         self.metrics.inc("batches", 1)
+        self.metrics.inc(_CLOSE_COUNTERS[why])
         self.metrics.inc("batch_elems", n_elems)
         self.metrics.observe("batch_fill", n_elems)
         self.trace(fmet.EV_BATCH_SUBMIT, n_elems)
@@ -762,7 +764,7 @@ class ShardedVerifyStage(VerifyStage):
         return np.asarray(pend.ok)
 
     def flush(self) -> None:
-        self._close_batch()
+        self._close_batch(why=CLOSE_DEADLINE)
         while self._inflight:
             self._drain(block=True)
 

@@ -320,6 +320,7 @@ class MonitorSession:
             }
             row.update(fm.latency_row(j.registry))
             row["sweep_phases"] = fm.nsweep_phase_row([j.registry])
+            row["batch_closes"] = fm.batch_close_row([j.registry])
             out.append(row)
         for logical, js in groups.items():
             sigs = [j.cnc.signal for j in js]
@@ -343,6 +344,8 @@ class MonitorSession:
             }
             row.update(fm.latency_row_merged([j.registry for j in js]))
             row["sweep_phases"] = fm.nsweep_phase_row(
+                [j.registry for j in js])
+            row["batch_closes"] = fm.batch_close_row(
                 [j.registry for j in js])
             out.append(row)
         return out
@@ -404,6 +407,15 @@ class MonitorSession:
                 f"{fm.format_latency_ms(r.get('lat_p99_ms')):>9}"
                 f"{fm.format_phase_cell(r.get('sweep_phases') or {}):>16}"
             )
+        # under the table: what closed each verify stage's batches, and
+        # its stalls (cumulative)
+        for r in rows:
+            bc = r.get("batch_closes")
+            if bc:
+                lines.append(
+                    f"{r['stage']}: batches closed "
+                    + " ".join(f"{k}={bc[k]:,}" for k in fm.BATCH_CLOSES)
+                    + f"  batch_stalls={bc['stalls']:,}")
         return "\n".join(lines)
 
     def run(self, *, interval_s: float = 1.0, iterations: int | None = None,

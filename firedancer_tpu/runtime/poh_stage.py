@@ -15,6 +15,11 @@ Entry frame: u32 num_hashes | 32B poh_hash | u16 txn_cnt |
 (u16 len || raw txn payload)* — the Solana entry triple (num_hashes since
 the previous entry, the chain hash after this entry, the txns).  Ticks are
 entries with txn_cnt = 0.
+
+Frag sig: the chain's hashcnt; under the slot clock `poh_sig` — every
+entry names the slot it belongs to and the slot's last tick says so (the
+reference's fd_disco_poh_sig + the entry batch's block_complete), which
+is how the shred stage follows poh's slot across the ring.
 """
 
 from __future__ import annotations
@@ -22,8 +27,28 @@ from __future__ import annotations
 from firedancer_tpu.tango.rings import MCache
 from firedancer_tpu.utils import metrics as fm
 from .poh import PohChain
+from .shred_native import (POH_SIG_BLOCK_COMPLETE, POH_SIG_SLOT,
+                           POH_SIG_SLOT_MASK, POH_SIG_SLOT_SHIFT)
 from .slot_clock import resolve_clock
 from .stage import Stage
+
+
+def poh_sig(slot: int, hashcnt: int, block_complete: bool = False) -> int:
+    """The poh -> shred frag sig under the slot clock (the layout is
+    shred_native.POH_SIG_*, one copy beside its C mirror)."""
+    return (POH_SIG_SLOT
+            | (POH_SIG_BLOCK_COMPLETE if block_complete else 0)
+            | ((slot & POH_SIG_SLOT_MASK) << POH_SIG_SLOT_SHIFT)
+            | (hashcnt & ((1 << POH_SIG_SLOT_SHIFT) - 1)))
+
+
+def poh_sig_fields(sig: int) -> tuple[int | None, bool]:
+    """-> (slot, block complete) of an entry frag's sig; (None, False)
+    where poh runs without the slot clock and the sig names no slot."""
+    if not sig & POH_SIG_SLOT:
+        return None, False
+    return ((sig >> POH_SIG_SLOT_SHIFT) & POH_SIG_SLOT_MASK,
+            bool(sig & POH_SIG_BLOCK_COMPLETE))
 
 
 def build_entry(num_hashes: int, poh_hash: bytes, txns: list[bytes]) -> bytes:
@@ -263,11 +288,16 @@ class PohStage(Stage):
         self.publish(
             0,
             build_entry(num_hashes, self.chain.hash, txns),
-            sig=self.chain.hashcnt,
+            sig=self._entry_sig(),
             tsorig=int(meta[MCache.COL_TSORIG]),
         )
 
     # -- internals ----------------------------------------------------------
+
+    def _entry_sig(self, block_complete: bool = False) -> int:
+        if self._clock is None:
+            return self.chain.hashcnt
+        return poh_sig(self.slot, self.chain.hashcnt, block_complete)
 
     def _emit_tick(self) -> None:
         self.chain.tick()
@@ -286,9 +316,11 @@ class PohStage(Stage):
         self.last_entry_hash = self.chain.hash
         if self.entries is not None:
             self.entries.append((num_hashes, self.chain.hash, []))
-        self.publish(
-            0, build_entry(num_hashes, self.chain.hash, []), sig=self.chain.hashcnt
-        )
+        # under the slot clock the slot's last tick closes its block
+        last = self._clock is not None \
+            and self._tick_cnt == self.ticks_per_slot
+        self.publish(0, build_entry(num_hashes, self.chain.hash, []),
+                     sig=self._entry_sig(last))
 
     def slot_complete(self) -> bool:
         return self._tick_cnt >= self.ticks_per_slot

@@ -28,13 +28,15 @@ from __future__ import annotations
 
 import ctypes
 import os
+from typing import TYPE_CHECKING
 
 import numpy as np
 
 from firedancer_tpu.ops.ref import ed25519_ref as ref
 from firedancer_tpu.utils.nativebuild import NativeUnavailable, build_so
 
-from .shredder import FecSet, count_fec_sets
+if TYPE_CHECKING:
+    from .shredder import FecSet
 
 _SRC = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -48,6 +50,17 @@ ENV_SWITCH = "FDTPU_NATIVE_SHRED"
 _MIN_SZ = 1203
 _MAX_SZ = 1228
 _MAX_D = 67
+
+# The poh -> shred frag sig under the slot clock (poh_stage.poh_sig
+# writes it, ShredStage and fd_shred.cpp stage_entry read it; mirrored
+# from native/fd_shred.cpp, fdlint FD305).  Bit 63: the entry names its
+# slot; bit 62: it is the slot's last tick (the block is complete);
+# bits 24..61: the slot; bits 0..23: hashcnt's low bits, which keep sigs
+# distinct within a ring depth (the restart publish guard's contract).
+POH_SIG_SLOT = 1 << 63
+POH_SIG_BLOCK_COMPLETE = 1 << 62
+POH_SIG_SLOT_SHIFT = 24
+POH_SIG_SLOT_MASK = (1 << 38) - 1
 
 _lib = None
 
@@ -76,8 +89,10 @@ def _load():
         lib.fds_stage_delete.argtypes = [vp]
         lib.fds_stage_flags_off.restype = u64
         lib.fds_stage_set_slot.argtypes = [vp, u64]
+        lib.fds_stage_slot.argtypes = [vp]
+        lib.fds_stage_slot.restype = u64
         lib.fds_stage_set_metrics.argtypes = [vp, vp]
-        lib.fds_stage_append.argtypes = [vp, cp, u64, u64]
+        lib.fds_stage_append.argtypes = [vp, cp, u64, u64, u64]
         lib.fds_stage_flush.argtypes = [vp, ctypes.c_int]
         lib.fds_stage_flush.restype = ctypes.c_int
         # fds_frag_cb is resolved by ADDRESS for fdr_sweep, never called
@@ -163,7 +178,9 @@ class NativeShredder:
 
     def entry_batch_to_fec_sets(self, entry_batch: bytes, *, slot: int,
                                 meta=None) -> list[FecSet]:
-        from .shredder import EntryBatchMeta
+        # here, not at the top: the shredder pulls in the JAX ops, and
+        # poh_stage reads this module's POH_SIG_* in a jax-free child
+        from .shredder import EntryBatchMeta, FecSet, count_fec_sets
 
         if not entry_batch:
             raise ValueError("empty entry batch")
@@ -282,10 +299,13 @@ class StageClient:
         return {name: int(self._tail[1 + i])
                 for i, name in enumerate(_COUNTERS)}
 
-    def append(self, payload: bytes, tsorig: int) -> None:
+    def append(self, payload: bytes, tsorig: int, sig: int = 0) -> None:
         """Per-frag fallback (mixed-lane / lossy splice): forward into
-        the SAME C-side buffer the sweep callback fills."""
-        self._lib.fds_stage_append(self._h, payload, len(payload), tsorig)
+        the SAME C-side buffer the sweep callback fills.  `sig` is the
+        frag's (poh_stage.poh_sig under the slot clock: the C side
+        follows poh's slot off it, exactly as in the sweep)."""
+        self._lib.fds_stage_append(self._h, payload, len(payload), tsorig,
+                                   sig)
 
     def flush(self, *, block_complete: bool) -> bool:
         return bool(self._lib.fds_stage_flush(
@@ -299,6 +319,11 @@ class StageClient:
 
     def set_slot(self, slot: int) -> None:
         self._lib.fds_stage_set_slot(self._h, slot)
+
+    def slot(self) -> int | None:
+        """The slot the next batch is shredded under (the C side
+        follows poh's inside the crossing); None once closed."""
+        return int(self._lib.fds_stage_slot(self._h)) if self._h else None
 
     def set_metrics(self, plane) -> None:
         """Arm the shm metrics plane (ISSUE 20): shred/encode bursts

@@ -14,6 +14,16 @@ Entry batches close when the accumulated serialized entries reach
 `batch_target_sz` (the reference bounds batches by pending shred budget)
 or on flush at slot end.
 
+Under the slot clock the stage follows poh's slot (poh_stage.poh_sig on
+every entry frag): the slot's last tick finishes the block (flush with
+block-complete, credit-gated like a size close); an entry of another
+slot — poh sealed or missed one — first sends what is buffered out as the
+end of the old block, then the stage moves to that slot, its shred
+index restarts and its parent is the block left behind.  A missed
+slot has no last tick: its block ends with whatever was buffered when
+the next slot's first entry came.  Without the clock the sig names no
+slot and the stage stays where it was put.
+
 Native lanes (ISSUE 11), chosen at construction when `secret` is given:
 
   - sweep mode: with the native shredder built, a native out producer,
@@ -35,7 +45,7 @@ Python shredder end to end.
 from __future__ import annotations
 
 from firedancer_tpu.tango.rings import MCache
-from .poh_stage import PohStage
+from .poh_stage import PohStage, poh_sig_fields
 from .shredder import EntryBatchMeta, FecSet, Shredder
 from .stage import Stage
 
@@ -60,6 +70,10 @@ class ShredStage(Stage):
         self.sets: list[FecSet] = []  # retained for tests/observers
         self._buf = bytearray()
         self._buf_tsorig = 0
+        # slot follow (Python lane; the sweep client keeps its own): the
+        # parent's distance, and a last tick's flush waiting for credits
+        self._parent_off = 1
+        self._pending_bc = False
         # -- lane selection ---------------------------------------------------
         # the mesh-sharded parity path (plane) is the Python shredder's;
         # keep_sets needs materialized FecSets, so sweep mode is out
@@ -94,7 +108,9 @@ class ShredStage(Stage):
     # the Python Shredder's `if slot != self.slot` check does per batch
     @property
     def slot(self) -> int:
-        return self._slot
+        c = self._sweep_client
+        followed = c.slot() if c is not None else None
+        return self._slot if followed is None else followed
 
     @slot.setter
     def slot(self, v: int) -> None:
@@ -107,8 +123,22 @@ class ShredStage(Stage):
         if c is not None:
             # fallback surface (mixed-lane / lossy splice): forward into
             # the C-side buffer the sweep callback fills — one state
-            c.append(payload, int(meta[MCache.COL_TSORIG]))
+            c.append(payload, int(meta[MCache.COL_TSORIG]),
+                     int(meta[MCache.COL_SIG]))
             return
+        slot, last = poh_sig_fields(int(meta[MCache.COL_SIG]))
+        if slot is not None and slot != self._slot:
+            # poh moved on (a slot sealed, or missed): what is buffered
+            # ends the old block, forced like an explicit flush
+            if self._buf:
+                self._shred_batch(block_complete=True)
+            self._parent_off = min(max(slot - self._slot, 1), 0xFFFF)
+            self._slot = slot
+        if last:
+            # the slot's last tick ends its block: flagged before the
+            # append, so whichever close takes the tick carries it
+            # (fd_shred.cpp stage_entry, same order)
+            self._pending_bc = True
         # entries are appended verbatim: the entry frame IS this build's
         # entry-batch serialization (the reference ships bincode entries)
         self._buf += len(payload).to_bytes(4, "little")
@@ -117,8 +147,14 @@ class ShredStage(Stage):
         if ts and (self._buf_tsorig == 0 or ts < self._buf_tsorig):
             self._buf_tsorig = ts
         self.metrics.inc("entries_in")
-        if len(self._buf) >= self.batch_target_sz and self._room():
-            self._shred_batch(block_complete=False)
+        self._close_if_due()
+
+    def _close_if_due(self) -> None:
+        """Size close, or a last tick's block-complete close: both wait
+        for credits (`_room`), the latter keeping its flag meanwhile."""
+        if (len(self._buf) >= self.batch_target_sz or self._pending_bc) \
+                and self._room():
+            self._shred_batch(block_complete=self._pending_bc)
 
     def after_credit(self) -> None:
         c = self._sweep_client
@@ -129,8 +165,7 @@ class ShredStage(Stage):
                 c.retry_flush()
             return
         # batch closed for size but deferred for credits: retry here
-        if len(self._buf) >= self.batch_target_sz and self._room():
-            self._shred_batch(block_complete=False)
+        self._close_if_due()
 
     def during_housekeeping(self) -> None:
         c = self._sweep_client
@@ -144,7 +179,12 @@ class ShredStage(Stage):
     def _room(self) -> bool:
         """A batch bursts ~2 sets x ~65 shreds; don't start shredding unless
         the out ring can absorb it (dropping shreds mid-set wastes the set)."""
-        return not self.outs or self.outs[0].cr_avail >= 256
+        if not self.outs:
+            return True
+        p = self.outs[0]
+        if p.cr_avail < 256:
+            p.refresh_credits()  # the cached count only ever falls
+        return p.cr_avail >= 256
 
     def flush(self, *, block_complete: bool = True) -> None:
         c = self._sweep_client
@@ -153,17 +193,20 @@ class ShredStage(Stage):
             self.metrics.counters.update(c.counters())
             return
         if self._buf:
-            self._shred_batch(block_complete=block_complete)
+            self._shred_batch(
+                block_complete=block_complete or self._pending_bc)
 
     def _shred_batch(self, *, block_complete: bool) -> None:
         batch = bytes(self._buf)
         self._buf = bytearray()
         tsorig = self._buf_tsorig
         self._buf_tsorig = 0
+        self._pending_bc = False
         sets = self.shredder.entry_batch_to_fec_sets(
             batch,
-            slot=self.slot,
-            meta=EntryBatchMeta(block_complete=block_complete),
+            slot=self._slot,
+            meta=EntryBatchMeta(parent_offset=self._parent_off,
+                                block_complete=block_complete),
         )
         self.metrics.inc("entry_batches")
         for st in sets:
@@ -221,6 +264,7 @@ class FusedPohShredStage(PohStage):
         """The collapsed hop: every entry the PoH half emits feeds the
         shredder in-process instead of crossing a ring."""
         meta = [0] * 8
+        meta[MCache.COL_SIG] = sig
         meta[MCache.COL_TSORIG] = tsorig
         self.shred_half.after_frag(0, meta, payload)
         self.metrics.inc("frags_out")  # unfused-poh metric parity

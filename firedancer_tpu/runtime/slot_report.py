@@ -127,6 +127,12 @@ def _stage_block(mets: dict, records: list) -> dict:
     block["flight"] = flight
     if stalls:
         block["batch_stalls"] = stalls
+    # beside them, what closed the verify stage's batches (full /
+    # deadline / window: the three add up to the `batches` counter)
+    if fm.BATCH_CLOSE_COUNTERS[0] in mets:
+        block["batch_closes"] = {
+            c: int(mets.get(name, 0) or 0)
+            for c, name in zip(fm.BATCH_CLOSES, fm.BATCH_CLOSE_COUNTERS)}
     return block
 
 

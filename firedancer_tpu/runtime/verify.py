@@ -14,10 +14,25 @@ Pipeline position and semantics mirror the reference's verify tile
     reparses (the parsed-txn trailer convention, fd_verify.c:93-100).
 
 TPU-native twist (the wiredancer async-offload shape, SURVEY §7.1): txns
-accumulate into fixed-shape device batches; a batch closes when full or when
-`after_credit` sees the deadline passed; 2+ batches stay in flight so host
-streaming overlaps device compute.  Fixed shapes mean partial batches are
-padded and the pad lanes' results ignored.
+accumulate into fixed-shape device batches; 2+ batches stay in flight so
+host streaming overlaps device compute.  Fixed shapes mean partial batches
+are padded and the pad lanes' results ignored.
+
+When a batch closes (one rule, `_deadline_close` + `_window_open`, on the
+native and the Python lane alike):
+
+  - when it is full (in C, inside the crossing, on the native lane);
+  - when its deadline (`batch_deadline_s`) has passed AND it could be
+    dispatched now: the in-flight window has room and no sealed batch
+    waits ahead of it.  While the window is full an open batch past its
+    deadline stays open and keeps taking frags — sealed, it would only
+    wait for a slot while the frags waited in the ring in front; the
+    pump that reaps the head seals and dispatches it in the same pass
+    (reap -> seal -> dispatch);
+  - on `flush()`, whatever the window holds.
+
+`batch_close_full + batch_close_deadline + batch_close_window == batches`
+says which of the three closed each dispatched batch.
 
 Repeated-signer fast path (round 4): real ingress repeats signers heavily
 (one vote key per validator), so the stage keeps a device-resident comb
@@ -50,6 +65,7 @@ from firedancer_tpu.protocol import txn as ft
 from firedancer_tpu.tango.rings import MCache, TCache
 from firedancer_tpu.utils import metrics as fm
 from .stage import Stage
+from .verify_native import CLOSE_DEADLINE, CLOSE_FULL, CLOSE_WINDOW
 
 # the per-packet parse is this stage's host hot path: prefer the native
 # (C++) parser — differentially proven byte-identical — and fall back to
@@ -133,6 +149,13 @@ _NATIVE_FRAME_MTU = 1232 + 2048 + 2
  PH_PUBLISH) = range(len(fm.BATCH_PHASES))
 _PHASE_COUNTERS = tuple(f"batch_{p}_ns" for p in fm.BATCH_PHASES)
 
+# what closed a batch (the ids are the binding's, held to
+# native/fd_verify.cpp by fdlint FD305): it filled; its deadline passed
+# with room in the window; or it was held past its deadline by a full
+# window and sealed at a freed slot.  Counted at dispatch, so the three
+# add up to `batches`.
+_CLOSE_COUNTERS = fm.BATCH_CLOSE_COUNTERS
+
 _now_ns = time.monotonic_ns
 
 _trace_annotation = None
@@ -186,11 +209,14 @@ class _Acc:
     slots: list[int] = field(default_factory=list)  # cached path only
     opened_at: float = 0.0
     life: _Life | None = None  # stamped when the first element enters
+    held: bool = False  # seen past its deadline while the window was full
+    close: int = CLOSE_FULL  # what sealed it (CLOSE_*)
 
     def clear(self) -> None:
         self.payloads, self.descs = [], []
         self.elems, self.ranges, self.tsorigs, self.slots = [], [], [], []
         self.opened_at = 0.0  # re-stamped by before_credit when reopened
+        self.held = False
 
 
 class VerifyStage(Stage):
@@ -281,7 +307,7 @@ class VerifyStage(Stage):
         # still queued] per reaped batch — a batch's publish phase ends
         # when its last frame has left the queue
         self._emit_marks: list = []
-        for name in _PHASE_COUNTERS:
+        for name in _PHASE_COUNTERS + _CLOSE_COUNTERS:
             self.metrics.counters[name] = 0
         self.metrics.counters["batch_stalls"] = 0
         # sweep-granularity parser (drain-table path), built on first use
@@ -297,7 +323,9 @@ class VerifyStage(Stage):
         # (slot, n_elems, n_txn, result, n_ok, life)
         self._nv_inflight: list = []
         self._nv_emit: list = []  # [slot, frame table, published idx, life]
-        self._nv_opened_at = 0.0
+        # the open batch (named by its C-side open stamp) that was seen
+        # past its deadline while the window was full
+        self._nv_held_ns = 0
         want_native = (native_client if native_client is not None
                        else type(self) is VerifyStage)
         if want_native:
@@ -391,6 +419,17 @@ class VerifyStage(Stage):
                      "thread-blocking batch phases (h2d, launch, reap,"
                      " publish) of 100 ms or more; each is an"
                      " EV_BATCH_STALL flight event")
+            # what closed each dispatched batch: the three add up to
+            # `batches`
+            .counter("batch_close_full",
+                     "batches sealed because they filled (or the next"
+                     " txn's signatures did not fit)")
+            .counter("batch_close_deadline",
+                     "batches sealed at their deadline with room in the"
+                     " in-flight window (flush() counts here)")
+            .counter("batch_close_window",
+                     "batches held open past their deadline by a full"
+                     " in-flight window, sealed when a reap freed a slot")
             .histogram(
                 "batch_fill",
                 fm.exp_buckets(1, 4096, 13),
@@ -561,37 +600,75 @@ class VerifyStage(Stage):
         # backpressure.  The clock is only read when a batch newly
         # opened — idle spins stay syscall-free.  (clear() resets
         # opened_at, so a stale stamp can never survive a close.)
-        c = self._sweep_client
-        if c is not None:
-            # native lane: ONE u64 read probes the C-side open batch
-            if self._nv_opened_at == 0.0 and c.open_elems():
-                self._nv_opened_at = time.monotonic()
+        if self._sweep_client is not None:
+            # native lane: the C side stamps a batch as it opens, in
+            # the crossing (one clock read a batch; open_since_ns)
             return
-        for acc in (self._gen, self._comb):
+        for acc in self._open_accs():
             if acc.elems and acc.opened_at == 0.0:
                 acc.opened_at = time.monotonic()
 
     def after_credit(self) -> None:
         if self._sweep_client is not None:
-            # deadline-based batch close, then dispatch/reap/publish
-            if self._nv_opened_at and time.monotonic() \
-                    - self._nv_opened_at >= self.batch_deadline_s:
-                self._sweep_client.seal()
-                self._nv_opened_at = 0.0
+            # reap, deadline-based batch close, dispatch, publish
             self._nv_pump()
             return
         # credits are available again: retry frames a full out ring
         # parked on the emit queue before touching new work
         if self._emit_queue:
             self._emit_reaped([])
-        # deadline-based batch close (p99 latency at low occupancy)
-        now = time.monotonic()
-        for acc in (self._gen, self._comb):
-            if acc.elems and acc.opened_at \
-                    and now - acc.opened_at >= self.batch_deadline_s:
-                self._close_batch(acc)
+        self._deadline_close()
         self._pump_submits()
         self._drain(block=False)
+
+    # -- when a batch closes -------------------------------------------------
+
+    def _open_accs(self):
+        """The accumulating batches a deadline runs for (the sharded
+        serving stage keeps one per shard)."""
+        return (self._gen, self._comb)
+
+    def _window_open(self) -> bool:
+        """A batch sealed now would be dispatched now: the in-flight
+        window has room and no sealed batch waits ahead of it.  The ONE
+        predicate of the close rule; it reads nothing but the stage's
+        own window."""
+        c = self._sweep_client
+        if c is not None:
+            return (len(self._nv_inflight) < self.max_inflight
+                    and not c.sealed_waiting())
+        return (len(self._inflight) < self.max_inflight
+                and not self._submit_queue)
+
+    def _deadline_close(self) -> None:
+        """The deadline's half of the close rule (p99 latency at low
+        occupancy): an open batch past its deadline seals if it could
+        be dispatched now, and otherwise stays open, taking frags,
+        until the reap that frees a slot comes through here again.
+        Sealing it into a full window would only move its wait from
+        the batch (where lanes fill) to a parked slot (where the ring
+        in front backs up instead)."""
+        c = self._sweep_client
+        if c is not None:
+            t = c.open_since_ns()  # ONE u64 read; 0 = nothing open
+            if not t or _now_ns() - t < self.batch_deadline_s * 1e9:
+                return
+            if self._window_open():
+                c.seal(CLOSE_WINDOW if self._nv_held_ns == t
+                       else CLOSE_DEADLINE)
+            else:
+                self._nv_held_ns = t
+            return
+        now = time.monotonic()
+        for acc in self._open_accs():
+            if not (acc.elems and acc.opened_at
+                    and now - acc.opened_at >= self.batch_deadline_s):
+                continue
+            if self._window_open():
+                self._close_batch(
+                    acc, CLOSE_WINDOW if acc.held else CLOSE_DEADLINE)
+            else:
+                acc.held = True
 
     def during_housekeeping(self) -> None:
         c = self._sweep_client
@@ -755,20 +832,27 @@ class VerifyStage(Stage):
         return super()._native_sweep(drainer)
 
     def _nv_pump(self) -> None:
-        """The native lane's batch-granular loop: submit sealed slots
-        into the in-flight window (in seal order), reap completed heads
-        (in order), publish reaped frames from the slot arenas."""
+        """The native lane's batch-granular loop: reap completed heads
+        (in order), publish reaped frames from the slot arenas, seal
+        the open batch if its deadline has passed and it can go now,
+        submit sealed slots into the in-flight window (in seal order).
+        Reap -> seal -> dispatch, so a freed window slot is used in the
+        pass that freed it; the publish goes before the dispatch
+        because it is a tenth of a ms and the dispatch nearly two, and
+        the window still holds the device's next batches while the
+        reaped transactions have nowhere else to wait."""
         c = self._sweep_client
+        self._nv_drain(block=False)
+        self._nv_publish()
+        self._deadline_close()
         while len(self._nv_inflight) < self.max_inflight:
             got = c.take_sealed()
             if got is None:
                 break
             self._nv_dispatch(*got)
-        self._nv_drain(block=False)
-        self._nv_publish()
 
     def _nv_dispatch(self, slot: int, n_elems: int, n_txn: int,
-                     opened_ns: int, sealed_ns: int) -> None:
+                     opened_ns: int, sealed_ns: int, close: int) -> None:
         c = self._sweep_client
         views = c.slots[slot]
         # per-txn msg lengths for the autotuner: one vectorized observe
@@ -788,6 +872,7 @@ class VerifyStage(Stage):
         self._phase_end(life, PH_LAUNCH)
         self._nv_inflight.append((slot, n_elems, n_txn, result, n_ok, life))
         self.metrics.inc("batches", 1)
+        self.metrics.inc(_CLOSE_COUNTERS[close])
         self.metrics.inc("batch_elems", n_elems)
         self.metrics.observe("batch_fill", n_elems)
         self.metrics.observe("inflight_occupancy", len(self._nv_inflight))
@@ -944,17 +1029,21 @@ class VerifyStage(Stage):
 
     # -- device batching ----------------------------------------------------
 
-    def _close_batch(self, acc: _Acc | None = None) -> None:
+    def _close_batch(self, acc: _Acc | None = None,
+                     why: int = CLOSE_FULL) -> None:
         """Seal the accumulating batch and submit it if the in-flight
         window has room; a full window PARKS the sealed batch (submit is
         backpressure-aware — the loop never blocks on the oldest device
         future just to close a batch) until reaping frees a slot.  Only
         a deep submit queue (the memory bound) falls back to the
-        blocking drain."""
+        blocking drain.  `why` is what closed it (CLOSE_*): a batch that
+        filled, the default, seals whatever the window holds; the
+        deadline comes through _deadline_close, which asks first."""
         if acc is None:  # legacy single-lane callers (tests)
             acc = self._gen
         if not acc.elems:
             return
+        acc.close = why
         cached = acc is self._comb
         # take the accumulator object itself as the sealed snapshot and
         # open a fresh one (clear() would free the lists we still need)
@@ -1002,6 +1091,7 @@ class VerifyStage(Stage):
             )
         )
         self.metrics.inc("batches", 1)
+        self.metrics.inc(_CLOSE_COUNTERS[acc.close])
         self.metrics.inc("batch_elems", n)
         self.metrics.observe("batch_fill", n)
         self.metrics.observe("inflight_occupancy", len(self._inflight))
@@ -1095,9 +1185,11 @@ class VerifyStage(Stage):
         the frames of the transactions that passed."""
         mask = self._result_mask(head)
         self._inflight.pop(0)
-        # a window slot freed: submit parked batches before reaping
-        # (keeps the device fed while the host walks the mask); their
-        # dispatch falls inside this batch's reap phase
+        # a window slot freed: seal the batch the full window held past
+        # its deadline, and submit it and any parked ones before walking
+        # the mask (keeps the device fed meanwhile); their dispatch
+        # falls inside this batch's reap phase
+        self._deadline_close()
         self._pump_submits()
         self.trace(fm.EV_BATCH_COMPLETE, head.n_elems)
         # honest traffic overwhelmingly passes whole batches: one
@@ -1181,8 +1273,7 @@ class VerifyStage(Stage):
             # posture the Python lane's emit queue keeps at shutdown)
             for _ in range(4 * c.n_slots):
                 c.pump()
-                c.seal()
-                self._nv_opened_at = 0.0
+                c.seal(CLOSE_DEADLINE)
                 self._nv_pump()
                 if self._nv_inflight:
                     self._nv_drain(block=True)
@@ -1195,7 +1286,7 @@ class VerifyStage(Stage):
         self._fill_bank()
         for acc in (self._gen, self._comb):
             if acc.elems:
-                self._close_batch(acc)
+                self._close_batch(acc, CLOSE_DEADLINE)
         self._pump_submits()
         while self._inflight or self._submit_queue:
             self._drain(block=True)

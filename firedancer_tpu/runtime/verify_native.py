@@ -40,6 +40,12 @@ SLOT_OPEN = 1
 SLOT_SEALED = 2
 SLOT_INFLIGHT = 3
 
+# why a slot was sealed (fd_verify.cpp enum; the order of
+# utils/metrics.BATCH_CLOSES, whose counters the stage indexes with them)
+CLOSE_FULL = 0
+CLOSE_DEADLINE = 1
+CLOSE_WINDOW = 2
+
 _lib = None
 
 
@@ -55,7 +61,7 @@ def _load():
         lib.fdv_frag_cb.restype = ctypes.c_int  # resolved by ADDRESS only
         lib.fdv_append.argtypes = [vp, ctypes.c_char_p, u64, u64]
         lib.fdv_append.restype = ctypes.c_int
-        lib.fdv_seal.argtypes = [vp]
+        lib.fdv_seal.argtypes = [vp, u64]
         lib.fdv_pump.argtypes = [vp]
         lib.fdv_slot_release.argtypes = [vp, u64]
         for name in ("fdv_meta_ptr", "fdv_counters_ptr"):
@@ -97,18 +103,19 @@ def available() -> bool:
         return False
 
 
-# counter tail, in fd_verify.cpp declaration order after `flags` and
-# `open_elems`; names match the stage's schema metrics so housekeeping
+# counter tail, in fd_verify.cpp declaration order after `flags`,
+# `open_elems` and `open_ns`; names match the stage's schema metrics so housekeeping
 # copies them verbatim
 _COUNTERS = ("filtered", "frags_in", "parse_fail", "dedup_dup",
              "msg_too_long", "too_many_sigs", "txn_in", "elems_in",
              "intake_dropped", "sealed_batches")
 _TAIL_FLAGS = 0
 _TAIL_OPEN_ELEMS = 1
-_TAIL_COUNTERS = 2
+_TAIL_OPEN_NS = 2
+_TAIL_COUNTERS = 3
 
-# (state, n_elems, n_txn, arena_off, opened_ns, sealed_ns) per slot
-_META_NCOL = 6
+# (state, n_elems, n_txn, arena_off, opened_ns, sealed_ns, close) per slot
+_META_NCOL = 7
 
 
 class _SlotViews:
@@ -205,21 +212,37 @@ class StageClient:
     # -- batch surface -------------------------------------------------------
 
     def open_elems(self) -> int:
-        """Elements accumulated in the currently-open slot (0 = none) —
-        the deadline-close probe.  ONE u64 read (the C side maintains
-        the word), cheap enough for before_credit every iteration."""
+        """Elements accumulated in the currently-open slot (0 = none).
+        ONE u64 read (the C side maintains the word)."""
         return int(self._tail[_TAIL_OPEN_ELEMS])
 
-    def seal(self) -> None:
-        self._lib.fdv_seal(self._h)
+    def open_since_ns(self) -> int:
+        """When the open slot's first element entered it, on
+        time.monotonic_ns()'s clock (the C side's own stamp, which the
+        batch's open phase starts from); 0 while no slot holds elements
+        — the deadline-close probe.  ONE u64 read, cheap enough for
+        every pump; the stamp also names the batch, so a note about it
+        cannot outlive a seal inside the crossing."""
+        return int(self._tail[_TAIL_OPEN_NS])
+
+    def seal(self, why: int) -> None:
+        """Seal the open slot (no-op when it is empty); `why` is the
+        stage's CLOSE_DEADLINE or CLOSE_WINDOW, handed back by
+        take_sealed (a slot that filled says CLOSE_FULL itself)."""
+        self._lib.fdv_seal(self._h, why)
+
+    def sealed_waiting(self) -> bool:
+        """A sealed slot is waiting for its dispatch (the next in ring
+        order, so the oldest).  ONE u64 read."""
+        return bool(self.meta[self._next_dispatch, 0] == SLOT_SEALED)
 
     def pump(self) -> None:
         self._lib.fdv_pump(self._h)
 
-    def take_sealed(self) -> tuple[int, int, int, int, int] | None:
+    def take_sealed(self) -> tuple[int, int, int, int, int, int] | None:
         """Next sealed slot in ring order as (slot idx, n_elems, n_txn,
-        opened ns, sealed ns) — the two stamps are the C side's, on
-        time.monotonic_ns()'s clock — marked INFLIGHT (python-owned
+        opened ns, sealed ns, close reason) — the two stamps are the C
+        side's, on time.monotonic_ns()'s clock — marked INFLIGHT (python-owned
         until release); None when the next slot in order is not sealed —
         dispatch stays in submission order by construction."""
         i = self._next_dispatch
@@ -228,7 +251,8 @@ class StageClient:
             return None
         row[0] = SLOT_INFLIGHT
         self._next_dispatch = (i + 1) % self.n_slots
-        return i, int(row[1]), int(row[2]), int(row[4]), int(row[5])
+        return (i, int(row[1]), int(row[2]), int(row[4]), int(row[5]),
+                int(row[6]))
 
     def release(self, slot: int) -> None:
         self._lib.fdv_slot_release(self._h, slot)

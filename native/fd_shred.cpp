@@ -1176,6 +1176,9 @@ void fds_stage_set_slot(void* p, u64 slot) {
   }
 }
 
+// the slot the next batch is shredded under (it follows poh's, below)
+u64 fds_stage_slot(void* p) { return ((ShredStageCtx*)p)->slot; }
+
 // shred + publish the accumulated batch.  Returns 1 on success, 0 when
 // deferred (credits below min_credits AND !force — pending_flush stays
 // set and the stage retries from after_credit).  An EXPLICIT flush
@@ -1188,9 +1191,14 @@ void fds_stage_set_slot(void* p, u64 slot) {
 // by design).
 static int stage_flush(ShredStageCtx* st, int block_complete, int force) {
   // block_complete < 0 = "retry a deferred flush with its original
-  // flag" (the after_credit path must not downgrade a pending flush)
-  if (block_complete < 0) block_complete = (int)st->pending_bc;
-  if (!st->buf_sz) { st->pending_flush = 0; return 1; }
+  // flag" (the after_credit path must not downgrade a pending flush);
+  // a waiting block-complete survives any close that takes its bytes
+  // (the Python lane's `block_complete or self._pending_bc`)
+  block_complete = block_complete > 0 || st->pending_bc;
+  if (!st->buf_sz) {
+    st->pending_flush = st->pending_bc = 0;
+    return 1;
+  }
   u64 cr = st->refresh(st->out_link, st->out_prod);
   if (!force && cr < st->min_credits) {
     st->pending_flush = 1;
@@ -1237,7 +1245,7 @@ static int stage_flush(ShredStageCtx* st, int block_complete, int force) {
   u64 tsorig = st->tsorig_min;
   st->buf_sz = 0;
   st->tsorig_min = 0;
-  st->pending_flush = 0;
+  st->pending_flush = st->pending_bc = 0;
   if (nsets < 0) {  // arena bound / OOM fallback: dropped, counted
     st->batches_dropped++;
     if (heap_blk) std::free(heap_blk);
@@ -1290,25 +1298,64 @@ static void stage_append(ShredStageCtx* st, const u8* payload, u64 sz,
   if (tsorig && (!st->tsorig_min || tsorig < st->tsorig_min))
     st->tsorig_min = tsorig;
   st->entries_in++;
-  // size-triggered close: credit-gated (deferral is harmless here), and
-  // a flush already pending keeps ITS flag — a clobber to 0 would drop
-  // a deferred slot-end's block_complete on the wire
-  if (st->buf_sz >= st->batch_target)
-    stage_flush(st, st->pending_flush ? -1 : 0, 0);
+  // size-triggered close: credit-gated (deferral is harmless here); a
+  // block-complete already waiting rides it (stage_flush keeps the flag)
+  if (st->buf_sz >= st->batch_target) stage_flush(st, 0, 0);
+}
+
+// The poh->shred frag sig under the slot clock (runtime/poh_stage.py
+// poh_sig; the reference's fd_disco_poh_sig): bit 63 says the entry
+// names its slot, bit 62 that it is the slot's last tick, bits 24..61
+// are the slot, the low 24 bits keep sigs distinct within a ring depth.
+// runtime/shred_native.py POH_SIG_* mirrors these (fdlint FD305) for
+// poh_stage.py, which writes the sig, and shred_stage.py's Python lane.
+constexpr u64 POH_SIG_SLOT = 1ull << 63;
+constexpr u64 POH_SIG_BLOCK_COMPLETE = 1ull << 62;
+constexpr int POH_SIG_SLOT_SHIFT = 24;
+constexpr u64 POH_SIG_SLOT_MASK = (1ull << 38) - 1;
+
+// one entry frag, in ShredStage.after_frag's order: follow poh's slot,
+// flag the block's end on the slot's last tick, append, close if due.
+// When poh has moved on (a slot sealed, or missed: then no last tick
+// came) what is buffered belongs to the old slot and goes out now,
+// forced, as the end of its block; the shred index restarts and the
+// parent is the block left behind.  The last tick's flag is set BEFORE
+// its append, so whichever close takes the tick's bytes — the append's
+// own size close, a deferred close it releases, or the close below —
+// carries block-complete.  That close stays credit-gated like a size
+// close (the flag waits with it) and is forced only when the next
+// slot's first entry finds it still waiting.
+static void stage_entry(ShredStageCtx* st, const u8* payload, u64 sz,
+                        u64 tsorig, u64 sig) {
+  if (sig & POH_SIG_SLOT) {
+    u64 slot = (sig >> POH_SIG_SLOT_SHIFT) & POH_SIG_SLOT_MASK;
+    if (slot != st->slot) {
+      stage_flush(st, 1, 1);
+      u64 off = slot > st->slot ? slot - st->slot : 1;
+      st->parent_off = (unsigned)(off < 0xFFFF ? off : 0xFFFF);
+      st->idx[0] = st->idx[1] = 0;
+      st->slot = slot;
+    }
+  }
+  if ((sig & POH_SIG_SLOT) && (sig & POH_SIG_BLOCK_COMPLETE))
+    st->pending_bc = 1;
+  stage_append(st, payload, sz, tsorig);
+  if (st->pending_bc) stage_flush(st, -1, 0);
 }
 
 // the fdr_sweep frag callback (meta8 = one drain-table row: seq, sig,
 // arena off, sz, ctl, tsorig, tspub, in_idx)
 int fds_frag_cb(void* ctx, const u64* meta8, const u8* payload) {
   ShredStageCtx* st = (ShredStageCtx*)ctx;
-  stage_append(st, payload, meta8[3], meta8[5]);
+  stage_entry(st, payload, meta8[3], meta8[5], meta8[1]);
   return 0;
 }
 
 // per-frag fallback entry (mixed-lane/lossy path: Python's after_frag
 // forwards into the SAME C-side buffer, so the two paths never diverge)
-void fds_stage_append(void* ctx, const u8* payload, u64 sz, u64 tsorig) {
-  stage_append((ShredStageCtx*)ctx, payload, sz, tsorig);
+void fds_stage_append(void* ctx, const u8* payload, u64 sz, u64 tsorig,
+                      u64 sig) {
+  stage_entry((ShredStageCtx*)ctx, payload, sz, tsorig, sig);
 }
 
 // flush entry point for Python (after_credit retry / slot-end flush)

@@ -48,9 +48,14 @@ constexpr int STASH_CAP = 8;
 
 enum { SLOT_FREE = 0, SLOT_OPEN = 1, SLOT_SEALED = 2, SLOT_INFLIGHT = 3 };
 
+// why a batch was sealed (runtime/verify.py CLOSE_*, the ids of its
+// batch_close_{full,deadline,window} counters): full is decided here,
+// in the crossing; the other two are the caller's, through fdv_seal
+enum { CLOSE_FULL = 0, CLOSE_DEADLINE = 1, CLOSE_WINDOW = 2 };
+
 // one row per slot, viewed zero-FFI from Python (u64 x META_NCOL;
 // runtime/verify_native._META_NCOL mirrors it, fdlint FD305)
-constexpr uint64_t META_NCOL = 6;
+constexpr uint64_t META_NCOL = 7;
 struct fdv_slot_meta {
   uint64_t state;
   uint64_t n_elems;
@@ -61,6 +66,7 @@ struct fdv_slot_meta {
   // the slot, and when the slot was sealed.  Two clock reads a batch.
   uint64_t opened_ns;
   uint64_t sealed_ns;
+  uint64_t close;  // CLOSE_*: what sealed it
 };
 static_assert(sizeof(fdv_slot_meta) == META_NCOL * sizeof(uint64_t),
               "slot-meta row is META_NCOL u64s");
@@ -94,12 +100,14 @@ struct fdv_stage {
   fdv_stash_ent stash[STASH_CAP];
   uint64_t stash_head, stash_n;
   uint8_t desc[DESC_CAP];
-  // tail: flags + open_elems + counters, contiguous u64s for the
-  // Python view — keep declaration order in sync with
+  // tail: flags + open_elems + open_ns + counters, contiguous u64s for
+  // the Python view — keep declaration order in sync with
   // runtime/verify_native._COUNTERS
   uint64_t flags;       // bit0: stash nonempty
-  uint64_t open_elems;  // elements in the open slot (deadline probe:
-                        // Python reads ONE word per loop iteration)
+  uint64_t open_elems;  // elements in the open slot
+  uint64_t open_ns;     // the open slot's opened_ns while it holds
+                        // elements, else 0 (deadline probe: Python reads
+                        // ONE word per pump; the stamp names the batch)
   uint64_t c_filtered, c_frags_in, c_parse_fail, c_dedup_dup,
       c_msg_too_long, c_too_many_sigs, c_txn_in, c_elems_in,
       c_intake_dropped, c_sealed_batches;
@@ -119,6 +127,7 @@ inline void set_flags(fdv_stage* s) {
   }
   s->flags = (s->stash_n ? 1u : 0u) | ((!s->stash_n && room) ? 2u : 0u);
   s->open_elems = s->open >= 0 ? s->meta[s->open].n_elems : 0;
+  s->open_ns = s->open_elems ? s->meta[s->open].opened_ns : 0;
 }
 
 bool acquire_open(fdv_stage* s) {
@@ -130,16 +139,18 @@ bool acquire_open(fdv_stage* s) {
   m->arena_off = 0;
   m->opened_ns = fdm_now_ns();
   m->sealed_ns = 0;
+  m->close = CLOSE_FULL;
   s->open = (int64_t)s->next_open;
   s->next_open = (s->next_open + 1) % s->n_slots;
   return true;
 }
 
-void seal_open(fdv_stage* s) {
+void seal_open(fdv_stage* s, uint64_t why) {
   if (s->open < 0) return;
   fdv_slot_meta* m = &s->meta[s->open];
   if (!m->n_txn) return;  // nothing accumulated: stay open
   m->sealed_ns = fdm_now_ns();
+  m->close = why;
   m->state = SLOT_SEALED;
   s->open = -1;
   s->c_sealed_batches++;
@@ -195,7 +206,7 @@ int ingest(fdv_stage* s, const uint8_t* payload, uint64_t sz,
   if (s->open < 0) acquire_open(s);  // cannot fail: probed above
   fdv_slot_meta* m = &s->meta[s->open];
   if (m->n_elems + sig_cnt > s->batch) {
-    seal_open(s);
+    seal_open(s, CLOSE_FULL);
     acquire_open(s);  // cannot fail: probed above
     m = &s->meta[s->open];
   }
@@ -225,7 +236,7 @@ int ingest(fdv_stage* s, const uint8_t* payload, uint64_t sz,
   m->n_elems += sig_cnt;
   s->c_txn_in++;
   s->c_elems_in += sig_cnt;
-  if (m->n_elems >= s->batch) seal_open(s);
+  if (m->n_elems >= s->batch) seal_open(s, CLOSE_FULL);
   return 0;
 }
 
@@ -351,10 +362,12 @@ int fdv_append(void* ctx, const uint8_t* payload, uint64_t sz,
   return append_one((fdv_stage*)ctx, payload, sz, tsorig);
 }
 
-// Deadline close: seal the open slot (no-op when nothing accumulated).
-void fdv_seal(void* ctx) {
+// The caller's close (deadline passed and the batch can be dispatched,
+// or flush): seal the open slot (no-op when nothing accumulated) and
+// record why (CLOSE_DEADLINE or CLOSE_WINDOW).
+void fdv_seal(void* ctx, uint64_t why) {
   fdv_stage* s = (fdv_stage*)ctx;
-  seal_open(s);
+  seal_open(s, why);
   set_flags(s);
 }
 

@@ -28,6 +28,10 @@ from firedancer_tpu.tango import shm
 from firedancer_tpu.utils import metrics as fm
 
 LANES = ["native", "python"]
+# the close rule (ISSUE 25) also runs in parallel/serve.ShardedVerifyStage,
+# which names its accumulators and inherits the rest; it stamps no lives
+# (PR 24 left it out), so only the close tests take it
+CLOSE_LANES = LANES + ["sharded"]
 PHASE_COUNTERS = [f"batch_{p}_ns" for p in fm.BATCH_PHASES]
 
 
@@ -47,15 +51,31 @@ def _tile(lane: str, **stage_kw):
     uid = shm.fresh_uid()
     lin = shm.ShmLink.create(f"tbl_i_{uid}", depth=256, mtu=1232, n_fseq=1)
     lout = shm.ShmLink.create(f"tbl_o_{uid}", depth=256, mtu=4096, n_fseq=1)
-    st = None
+    lin1 = st = None
     try:
         kw = dict(batch=16, max_msg_len=256, batch_deadline_s=0.001,
                   precomputed_ok=True)
         kw.update(stage_kw)
-        st = VerifyStage("v0", ins=[shm.make_consumer(lin, lazy=8)],
-                         outs=[shm.make_producer(lout)], **kw)
+        ins = [shm.make_consumer(lin, lazy=8)]
+        prod = shm.make_producer(lin)
+        if lane == "sharded":
+            # two shards of 16 lanes, a ring each; `prod` feeds shard 0
+            # and carries shard 1's producer as `prod.shard1`
+            from firedancer_tpu.parallel import serve
+
+            lin1 = shm.ShmLink.create(f"tbl_j_{uid}", depth=256, mtu=1232,
+                                      n_fseq=1)
+            ins.append(shm.make_consumer(lin1, lazy=8))
+            prod = _Shard0(prod, shm.make_producer(lin1))
+            kw["plane"] = _Plane(serve.ServeConfig(
+                n_devices=2, batch_per_shard=kw["batch"],
+                max_msg_len=kw.pop("max_msg_len")))
+            cls = serve.ShardedVerifyStage
+        else:
+            cls = VerifyStage
+        st = cls("v0", ins=ins, outs=[shm.make_producer(lout)], **kw)
         assert (st._sweep_client is not None) == (lane == "native")
-        yield st, shm.make_producer(lin), shm.make_consumer(lout, lazy=4)
+        yield st, prod, shm.make_consumer(lout, lazy=4)
     finally:
         if prev is None:
             os.environ.pop(vn.ENV_SWITCH, None)
@@ -66,6 +86,38 @@ def _tile(lane: str, **stage_kw):
             st.drop_native_views()
         lin.close()
         lout.close()
+        if lin1 is not None:
+            lin1.close()
+
+
+class _Shard0:
+    """The sharded tile's feeder: shard 0's ring, with shard 1's beside."""
+
+    def __init__(self, prod0, prod1):
+        self.try_publish = prod0.try_publish
+        self.shard1 = prod1
+
+
+class _Plane:
+    """What ShardedVerifyStage asks of a ServePlane: its geometry, and a
+    step that hands back a pending the test makes ready (`sent`)."""
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+        self.sent: list = []
+
+    def submit(self, msg, ln, sig, pk, n_real):
+        from firedancer_tpu.parallel.serve import _PrecomputedPending
+
+        class _GatedPending(_PrecomputedPending):
+            n = int(n_real.sum())
+            done = False
+
+            def ready(self) -> bool:
+                return self.done
+
+        self.sent.append(_GatedPending(self.cfg.batch))
+        return self.sent[-1]
 
 
 def _drain(cons) -> int:
@@ -167,9 +219,9 @@ def test_native_open_and_seal_stamps_are_on_the_python_clock(pool):
         st.run_once()
         assert c.open_elems() == 5
         t1 = time.monotonic_ns()
-        c.seal()
+        c.seal(rv.CLOSE_DEADLINE)
         t2 = time.monotonic_ns()
-        slot, n_elems, n_txn, opened, sealed = c.take_sealed()
+        slot, n_elems, n_txn, opened, sealed, why = c.take_sealed()
         assert (n_elems, n_txn) == (5, 5)
         assert t0 <= opened <= t1 <= sealed <= t2
         c.release(slot)
@@ -385,3 +437,307 @@ def test_a_txn_that_does_not_fit_opens_the_next_batch(lane):
         assert st.metrics.get("batches") == 2
         assert st.metrics.get("batch_elems") == 6
         assert len(lives) == 2 and lives[0] is not lives[1]
+
+
+# -- when a batch closes (ISSUE 25) ------------------------------------------------
+#
+# Full, or past its deadline AND dispatchable now, or flush(): the same
+# rule on both lanes, driven with a result that is not ready until the
+# test says so.
+
+CLOSE_COUNTERS = list(rv._CLOSE_COUNTERS)
+
+
+class _Gated:
+    """A device future that is ready when the test says."""
+
+    def __init__(self, n):
+        self.n = n
+        self.mask = np.ones((n,), dtype=bool)
+        self.done = False
+
+    def is_ready(self):
+        return self.done
+
+    def __array__(self, dtype=None, copy=None):
+        return self.mask
+
+
+@contextlib.contextmanager
+def _gated_tile(lane: str, **kw):
+    """A tile whose dispatches hand back _Gated results, in `sent`."""
+    import jax.profiler  # noqa: F401  (the span's import, off the clock)
+
+    kw.setdefault("max_inflight", 2)
+    with _tile(lane, precomputed_ok=False, **kw) as (st, prod, cons):
+        if lane == "sharded":      # its dispatch is the plane's step
+            yield st, prod, cons, st.plane.sent
+            return
+        sent: list[_Gated] = []
+
+        def dispatch(life, msg, ln, sig, pk, n):
+            st._phase_end(life, rv.PH_H2D)
+            sent.append(_Gated(n))
+            return sent[-1], None
+
+        st._device_verify = dispatch
+        yield st, prod, cons, sent
+
+
+def _open_elems(st) -> int:
+    c = st._sweep_client
+    if c is not None:
+        return c.open_elems()
+    return sum(len(a.elems) for a in st._open_accs())
+
+
+def _sealed_waiting(st) -> bool:
+    c = st._sweep_client
+    return bool(c.sealed_waiting() if c is not None else st._submit_queue)
+
+
+def _feed(prod, pool, lo: int, hi: int) -> None:
+    for i in range(lo, hi):
+        assert prod.try_publish(pool[i], sig=i, tsorig=0)
+
+
+def _spin(st, cons, got: list, loops: int = 60, until=None) -> None:
+    """Run the stage, collecting the transaction bytes that come out."""
+    for _ in range(loops):
+        st.run_once()
+        while True:
+            res = cons.poll()
+            if res in (shm.POLL_EMPTY, shm.POLL_OVERRUN):
+                break
+            payload = bytes(res[1])
+            got.append(payload[:int.from_bytes(payload[-2:], "little")])
+        if until is not None and until():
+            return
+
+
+def _past_deadline(st, cons, got) -> None:
+    time.sleep(st.batch_deadline_s * 3)
+    _spin(st, cons, got, loops=20)
+
+
+def _closes(st) -> list[int]:
+    return [st.metrics.get(k) for k in CLOSE_COUNTERS]
+
+
+def _fill_window(st, prod, cons, pool, got) -> int:
+    """Two small batches, each sealed on its deadline with room in the
+    window: the window (2) is then full.  -> transactions fed."""
+    for k in range(2):
+        _feed(prod, pool, 3 * k, 3 * k + 3)
+        _spin(st, cons, got)
+        _past_deadline(st, cons, got)
+        assert st.metrics.get("batches") == k + 1
+    assert _closes(st) == [0, 2, 0] and _open_elems(st) == 0
+    return 6
+
+
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_close_counters_are_in_the_schema_and_start_at_zero(lane):
+    with _tile(lane) as (st, _prod, _cons):
+        for k in CLOSE_COUNTERS:
+            assert st.metrics.counters[k] == 0
+        assert set(CLOSE_COUNTERS) <= st.metrics_schema().names()
+
+
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_window_with_room_seals_at_the_deadline_as_before(lane, pool):
+    with _gated_tile(lane, batch_deadline_s=0.05) as (st, prod, cons, sent):
+        got: list = []
+        _feed(prod, pool, 0, 5)
+        t0 = time.monotonic()
+        _spin(st, cons, got, loops=40)
+        if time.monotonic() - t0 < 0.04:   # a slow machine proves nothing
+            assert st.metrics.get("batches") == 0 and _open_elems(st) == 5
+        _spin(st, cons, got, loops=100000,
+              until=lambda: st.metrics.get("batches") == 1)
+        assert 0.05 <= time.monotonic() - t0 < 5
+        assert _closes(st) == [0, 1, 0] and [g.n for g in sent] == [5]
+        if lane != "sharded":      # which stamps no lives
+            open_ms = st.metrics.get("batch_open_ns") / 1e6
+            assert 50 <= open_ms < 1000
+
+
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_full_window_holds_the_batch_open_until_a_reap_frees_a_slot(
+        lane, pool):
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        got: list = []
+        n = _fill_window(st, prod, cons, pool, got)
+        # deadline passed, window full: the batch stays open ...
+        _feed(prod, pool, n, n + 3)
+        _spin(st, cons, got)
+        _past_deadline(st, cons, got)
+        assert st.metrics.get("batches") == 2 and len(sent) == 2
+        assert _open_elems(st) == 3 and not _sealed_waiting(st)
+        # ... and takes later frags
+        _feed(prod, pool, n + 3, n + 5)
+        _spin(st, cons, got)
+        assert _open_elems(st) == 5 and not _sealed_waiting(st)
+        assert st.metrics.get("batches") == 2 and got == []
+        # the reap that frees a slot seals and dispatches it, in one pump
+        sent[0].done = True
+        st.after_credit()
+        assert st.metrics.get("batches") == 3
+        assert [g.n for g in sent] == [3, 3, 5]
+        assert _open_elems(st) == 0 and not _sealed_waiting(st)
+        assert _closes(st) == [0, 2, 1]
+        # nothing lost, nothing reordered across the hold
+        for g in sent:
+            g.done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n + 5])
+        assert sum(_closes(st)) == st.metrics.get("batches") == 3
+        assert st.metrics.get("txn_verified") == n + 5
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_a_full_batch_seals_whatever_the_window_holds(lane, pool):
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        got: list = []
+        n = _fill_window(st, prod, cons, pool, got)
+        _feed(prod, pool, n, n + 16)           # one batch's worth
+        _spin(st, cons, got)
+        assert _sealed_waiting(st) and st.metrics.get("batches") == 2
+        assert _open_elems(st) == 0
+        # a batch behind a sealed one is held too, even past its deadline
+        _feed(prod, pool, n + 16, n + 20)
+        _spin(st, cons, got)
+        _past_deadline(st, cons, got)
+        assert _open_elems(st) == 4 and st.metrics.get("batches") == 2
+        # one freed slot goes to the sealed batch; the open one stays
+        sent[0].done = True
+        st.after_credit()
+        assert [g.n for g in sent] == [3, 3, 16]
+        assert _open_elems(st) == 4 and not _sealed_waiting(st)
+        # the next freed slot is the open batch's
+        sent[1].done = True
+        st.after_credit()
+        assert [g.n for g in sent] == [3, 3, 16, 4]
+        assert _closes(st) == [1, 2, 1]
+        for g in sent:
+            g.done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n + 20])
+        assert sum(_closes(st)) == st.metrics.get("batches") == 4
+
+
+def test_a_full_shard_closes_a_held_step_through_the_reap_it_waits_for(pool):
+    """The sharded stage closes the WHOLE step when one shard fills, and
+    with a full window it blocks on the head first.  With shard 0 held
+    past its deadline that reap comes back through the close rule
+    (_close_batch -> _drain -> _reap -> _deadline_close -> _close_batch):
+    the step goes out once, at the freed slot, every shard's fill in it."""
+    with _gated_tile("sharded") as (st, prod, cons, sent):
+        got: list = []
+        n = _fill_window(st, prod, cons, pool, got)
+        _feed(prod, pool, n, n + 3)            # shard 0: held
+        _spin(st, cons, got)
+        _past_deadline(st, cons, got)
+        assert _open_elems(st) == 3 and st.metrics.get("batches") == 2
+        assert st._shards[0].held and got == []
+        _feed(prod.shard1, pool, n + 3, n + 19)   # shard 1 fills
+        _spin(st, cons, got, loops=4)
+        assert [g.n for g in sent] == [3, 3, 19]
+        assert st.metrics.get("batches") == 3 and _open_elems(st) == 0
+        assert _closes(st) == [0, 2, 1]
+        assert len(st._inflight) == 2 and got == list(pool[:3])
+        assert not any(a.held or a.opened_at for a in st._shards)
+        # the next step opens with its own deadline, and room closes it
+        for g in sent:
+            g.done = True
+        _feed(prod.shard1, pool, n + 19, n + 21)
+        _spin(st, cons, got)
+        _past_deadline(st, cons, got)
+        assert [g.n for g in sent] == [3, 3, 19, 2]
+        assert _closes(st) == [0, 3, 1]
+        sent[-1].done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n + 21])
+        assert sum(_closes(st)) == st.metrics.get("batches") == 4
+        assert st.metrics.get("shard_elems_s0") == 9
+        assert st.metrics.get("shard_elems_s1") == 18
+
+
+@pytest.mark.parametrize("held", [False, True])
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_flush_seals_whatever_the_window_holds(lane, held, pool):
+    with _gated_tile(lane, batch_deadline_s=0.001 if held else 10.0) \
+            as (st, prod, cons, sent):
+        got: list = []
+        if held:
+            n = _fill_window(st, prod, cons, pool, got)
+        else:
+            n = 0
+        _feed(prod, pool, n, n + 4)
+        _spin(st, cons, got)
+        if held:
+            _past_deadline(st, cons, got)
+        assert _open_elems(st) == 4 and len(sent) == (2 if held else 0)
+        st.flush()                  # blocks on the heads: no gate needed
+        _spin(st, cons, got)
+        assert got == list(pool[:n + 4])
+        assert [g.n for g in sent] == ([3, 3, 4] if held else [4])
+        if held and lane == "sharded":
+            # its flush closes by blocking on the head, and that reap
+            # seals the held step at the slot it frees
+            assert _closes(st) == [0, 2, 1]
+        else:
+            assert _closes(st) == [0, 3 if held else 1, 0]
+        assert sum(_closes(st)) == st.metrics.get("batches")
+
+
+def test_the_native_seal_hands_its_reason_back(pool):
+    with _tile("native") as (st, prod, _cons):
+        c = st._sweep_client
+        assert c.open_since_ns() == 0 and not c.sealed_waiting()
+        t0 = time.monotonic_ns()
+        _feed(prod, pool, 0, 20)               # 16 fill a slot, 4 open
+        for _ in range(4):
+            st._native_sweep(st._native_drainer())
+        assert c.sealed_waiting() and c.open_elems() == 4
+        assert t0 <= c.open_since_ns() <= time.monotonic_ns()
+        c.seal(rv.CLOSE_WINDOW)
+        assert c.open_since_ns() == 0
+        first, second = c.take_sealed(), c.take_sealed()
+        assert (first[1], first[5]) == (16, rv.CLOSE_FULL)
+        assert (second[1], second[5]) == (4, rv.CLOSE_WINDOW)
+        assert second[3] >= first[3]
+        c.release(first[0])
+        c.release(second[0])
+
+
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
+    """Beside the stalls: the three close counters, through the registry
+    a scraper reads (schema -> Prometheus), the monitor's table and
+    slotreport's stage block."""
+    from firedancer_tpu.runtime import monitor as mon
+
+    with _tile(lane) as (st, prod, cons):
+        _trickle(st, prod, cons, pool[:20])
+        st.metrics.flush()
+        reg = st.metrics.registry
+        assert reg is not None
+        n = st.metrics.get("batches")
+        row = fm.batch_close_row([reg])
+        assert row is not None and row["stalls"] == 0
+        assert sum(row[c] for c in fm.BATCH_CLOSES) == n > 0
+        text = fm.render_prometheus({"v0": reg})
+        for k in CLOSE_COUNTERS:
+            assert f"{k}{{" in text or f"{k} " in text
+        rendered = mon.MonitorSession.render(
+            [{"stage": "v0", "signal": 1, "heartbeat_age_ms": 1.0, "in": 0,
+              "out": 0, "overrun": 0, "backpressure": 0, "iters": 1,
+              "batch_closes": row}], None, 1.0)
+        assert f"v0: batches closed full={row['full']:,} " \
+               f"deadline={row['deadline']:,} window={row['window']:,}" \
+               f"  batch_stalls=0" in rendered
+        dump = fm.flight_dump_obj("t", {"v0": (reg, st.recorder)})
+        block = slot_report.build_report(dump)["stages"]["v0"]
+        assert block["batch_closes"] == {c: row[c] for c in fm.BATCH_CLOSES}
+    assert fm.batch_close_row([Stage("s").metrics.registry]) is None

@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import hashlib
 
+import pytest
+
 from firedancer_tpu.ops.ref import ed25519_ref as ref
 from firedancer_tpu.runtime.poh_stage import PohStage
 from firedancer_tpu.runtime.shred_stage import FusedPohShredStage, ShredStage
@@ -215,3 +217,151 @@ def test_fused_leader_pipeline_end_to_end():
         assert len(res.bank_hash) == 32
     finally:
         pipe.close()
+
+
+# -- the shred stage follows poh's slot (ISSUE 25) ------------------------------
+#
+# Under the slot clock every entry frag names its slot (poh_stage.poh_sig)
+# and the slot's last tick says so: each slot is a block of its own, with
+# a shred index of its own, whether poh sealed the slot or missed it.
+
+
+def _run_slots(fused: bool, native: bool):
+    """The scripted clock of _run_clocked with microblocks large enough
+    for a size close inside a slot: slot 1 seals, slot 2 is cut short by
+    the jump (missed, with 3 and 4), slots 5 and 6 seal, the window
+    closes.  -> (shreds, {slot: entry frames poh published under it})."""
+    import os
+
+    from firedancer_tpu.runtime import shred_native as sd
+    from firedancer_tpu.runtime.poh_stage import poh_sig_fields
+
+    prev = os.environ.get(sd.ENV_SWITCH)
+    os.environ[sd.ENV_SWITCH] = "1" if native else "0"
+    t = [0]
+    clock = SlotClockCfg(
+        slot_ms=100.0, slot0=1, ticks_per_slot=4, n_slots=6, t0_ns=0,
+    ).build(now_fn=lambda: t[0])
+    topo = None
+    try:
+        topo = _Topo(fused=fused, clock=clock)
+        assert (topo.shred._sweep_client is not None) == native
+        by_slot: dict[int, list[bytes]] = {}
+        inner = topo.poh.publish
+
+        def publish(out_idx, payload, sig=0, tsorig=0):
+            slot, _last = poh_sig_fields(sig)
+            by_slot.setdefault(slot, []).append(bytes(payload))
+            return inner(out_idx, payload, sig=sig, tsorig=tsorig)
+
+        topo.poh.publish = publish
+        mbs = [_mb(i, n_txn=6) for i in range(40)]
+        fed = 0
+        for it in range(200):
+            t[0] += 260 * MS if it == 80 else 2 * MS
+            if it % 3 == 0 and fed < len(mbs):
+                if topo.prod.try_publish(mbs[fed], sig=fed,
+                                         tsorig=1000 + fed):
+                    fed += 1
+            topo.step()
+            topo.drain()
+        assert fed == len(mbs) and topo.poh.window_closed
+        assert topo.shred.slot == 6
+        topo.shred.flush(block_complete=True)   # nothing left: a no-op
+        for _ in range(10):
+            topo.step()
+        topo.drain()
+        m = topo.poh.metrics
+        assert m.get("slots_sealed") == 3 and m.get("slot_missed") == 3
+        return [s for s, _sig in topo.shreds], by_slot
+    finally:
+        if topo is not None:
+            topo.close()
+        if prev is None:
+            os.environ.pop(sd.ENV_SWITCH, None)
+        else:
+            os.environ[sd.ENV_SWITCH] = prev
+
+
+_SLOT_RUNS: dict = {}
+
+
+def _slot_run(fused: bool, native: bool):
+    from firedancer_tpu.runtime import shred_native as sd
+
+    if native and not sd.available():
+        pytest.skip("native shredder unavailable")
+    key = (fused, native)
+    if key not in _SLOT_RUNS:
+        _SLOT_RUNS[key] = _run_slots(fused, native)
+    return _SLOT_RUNS[key]
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["native", "python"])
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+def test_each_slot_is_a_block_of_its_own(fused, native):
+    from firedancer_tpu.protocol import shred as fs
+    from firedancer_tpu.runtime.shred_stage import deshred_entry_batch
+    from firedancer_tpu.runtime.store import StoreStage
+
+    shreds, by_slot = _slot_run(fused, native)
+    # sealed 1, missed 2 (3 and 4 with it: nothing was produced), sealed 5, 6
+    assert sorted(by_slot) == [1, 2, 5, 6]
+    store = StoreStage("store", trust_membership=True)
+    for buf in shreds:
+        store.after_frag(0, None, buf)
+    assert sorted(store.sets_by_slot) == [1, 2, 5, 6]
+    parent = 0
+    n_sets = 0
+    for slot in sorted(by_slot):
+        # every slot reassembles to exactly the entries poh gave it
+        frames = deshred_entry_batch(store.entry_batch_bytes(slot))
+        assert frames == by_slot[slot]
+        sets = sorted(store.sets_by_slot[slot], key=lambda s: s.fec_set_idx)
+        n_sets += len(sets)
+        data = [fs.parse(b) for st in sets for b in st.data_shreds]
+        assert all(d.slot == slot for d in data)
+        # the shred index restarts with the slot
+        assert [d.idx for d in data] == list(range(len(data)))
+        assert sets[0].fec_set_idx == 0
+        # the block's last data shred, and only it, says block-complete
+        done = [bool(d.flags & fs.DATA_FLAG_SLOT_COMPLETE) for d in data]
+        assert done == [False] * (len(data) - 1) + [True]
+        # the parent is the block left behind
+        assert {d.parent_off for d in data} == {slot - parent}
+        parent = slot
+    assert len(by_slot[1]) > 16 and n_sets > 4     # a size close happened
+
+
+def test_slot_follow_unfused_fused_native_python_agree():
+    runs = [_slot_run(f, n) for f in (False, True) for n in (True, False)]
+    for shreds, by_slot in runs[1:]:
+        assert by_slot == runs[0][1]
+        assert shreds == runs[0][0]
+
+
+def test_poh_sig_names_the_slot_only_under_the_clock():
+    from firedancer_tpu.runtime.poh_stage import (
+        POH_SIG_SLOT, poh_sig, poh_sig_fields,
+    )
+
+    assert poh_sig_fields(12345) == (None, False)      # a bare hashcnt
+    s = poh_sig(7, 0x1234567, block_complete=True)
+    assert s & POH_SIG_SLOT and s < 1 << 64
+    assert poh_sig_fields(s) == (7, True)
+    assert poh_sig_fields(poh_sig(300_000_000, 9)) == (300_000_000, False)
+    # distinct within a ring depth: consecutive hashcnts never collide
+    assert len({poh_sig(7, h) for h in range(100_000, 104_096)}) == 4096
+    # free-running poh keeps hashcnt as the sig, and shred stays put
+    topo = _Topo(fused=False)
+    try:
+        sigs = []
+        inner = topo.poh.publish
+        topo.poh.publish = lambda o, p, sig=0, tsorig=0: (
+            sigs.append(sig), inner(o, p, sig=sig, tsorig=tsorig))[1]
+        for _ in range(40):
+            topo.step()
+        assert sigs and all(not s & POH_SIG_SLOT for s in sigs)
+        assert topo.shred.slot == 1
+    finally:
+        topo.close()

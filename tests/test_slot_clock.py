@@ -15,6 +15,7 @@ from firedancer_tpu.runtime.slot_clock import (
     resolve_clock,
 )
 from firedancer_tpu.tango import shm
+from firedancer_tpu.tango.rings import MCache
 from firedancer_tpu.utils import metrics as fm
 
 MS = 1_000_000  # ns
@@ -218,6 +219,47 @@ def test_poh_backpressure_past_grace_becomes_a_miss():
         assert poh.slots_done() == 3
         assert poh.chain.hashcnt > hashcnt_at_miss
     finally:
+        link.close()
+        link.unlink()
+
+
+
+def test_poh_entries_name_their_slot_and_the_last_tick_closes_it():
+    """Under the clock every entry frag's sig is poh_sig: the slot it
+    belongs to, and block-complete on a sealed slot's last tick only — a
+    missed slot never gets one."""
+    from firedancer_tpu.runtime.poh_stage import poh_sig_fields
+
+    t = [0]
+    poh, link, clock = make_poh(t, n_slots=None)
+    sink = shm.Consumer(link, lazy=4)
+    try:
+        drive(poh, t, 130)              # slot 1 sealed, into slot 2
+        t[0] = 380 * MS                 # 2 and 3 pass unsealed: missed
+        for _ in range(30):
+            poh.run_once()
+        drive(poh, t, 560)              # 4 and 5 sealed, into slot 6
+        got = []
+        while True:
+            r = sink.poll()
+            if r in (shm.POLL_EMPTY, shm.POLL_OVERRUN):
+                break
+            got.append(poh_sig_fields(int(r[0][MCache.COL_SIG])))
+        assert poh.metrics.get("slots_sealed") == 3
+        assert poh.metrics.get("slot_missed") == 2
+        slots = [sl for sl, _ in got]
+        assert slots == sorted(slots)
+        by_slot: dict = {}
+        for sl, last in got:
+            by_slot.setdefault(sl, []).append(last)
+        assert sorted(by_slot) == [1, 2, 4, 5, 6]
+        # four ticks a slot; only the sealed slots' fourth says complete
+        for sealed in (1, 4, 5):
+            assert by_slot[sealed] == [False, False, False, True]
+        assert not any(by_slot[2]) and len(by_slot[2]) < 4
+        assert by_slot[6] == [False, False]
+    finally:
+        poh.outs = []
         link.close()
         link.unlink()
 
@@ -452,3 +494,64 @@ def test_leader_pipeline_under_compressed_cadence_zero_loss():
     free, _ = run(clocked=False)
     assert clocked["landed"] == free["landed"] == N
     assert clocked["rejected"] == free["rejected"] == 0
+
+
+# -- the shred stage follows poh's slot through the whole pipeline (ISSUE 25) --
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+def test_leader_pipeline_stores_each_slot_under_its_own_slot(fused):
+    """The cooperative leader pipeline on scripted time, 8 slots with one
+    jump that misses slot 3: what the banks commit in a slot is stored as
+    that slot's block — its shred index from 0, block-complete on its
+    last data shred, its parent the block before — and every stored slot
+    reassembles."""
+    from firedancer_tpu.models.leader import build_leader_pipeline
+    from firedancer_tpu.protocol import shred as fs
+    from firedancer_tpu.runtime.shred_stage import deshred_entry_batch
+
+    N = 400
+    t = [0]
+    clock = SlotClockCfg(slot_ms=100.0, slot0=1, ticks_per_slot=4,
+                         n_slots=8, t0_ns=0).build(now_fn=lambda: t[0])
+    pipe = build_leader_pipeline(
+        n_verify=1, n_bank=2, pool_size=N, gen_limit=N, batch=32,
+        verify_precomputed=True, slot_clock=clock, keep_sets=False,
+        fuse_poh_shred=fused)
+    try:
+        for it in range(10_000):
+            t[0] += 154 * MS if it == 40 else 4 * MS
+            for s in pipe.stages:
+                s.run_once()
+            if pipe.poh.window_closed:
+                break
+        assert pipe.poh.window_closed
+        pipe.finish()
+        assert pipe.poh.metrics.get("slots_sealed") >= 5
+        assert pipe.poh.metrics.get("slot_missed") >= 1
+        assert sum(b.metrics.get("txn_exec") for b in pipe.banks) == N
+        store = pipe.store
+        assert store.metrics.get("sets_stored") \
+            == pipe.shred.metrics.get("fec_sets") > 0
+        stored = sorted(store.sets_by_slot)
+        assert 3 not in stored and len(stored) >= 6
+        assert pipe.shred.slot == stored[-1] == 8
+        n_txn = {}
+        parent = 0
+        for slot in stored:
+            frames = deshred_entry_batch(store.entry_batch_bytes(slot))
+            n_txn[slot] = sum(int.from_bytes(f[36:38], "little")
+                              for f in frames)
+            sets = sorted(store.sets_by_slot[slot],
+                          key=lambda st: st.fec_set_idx)
+            data = [fs.parse(b) for st in sets for b in st.data_shreds]
+            assert all(d.slot == slot for d in data)
+            assert [d.idx for d in data] == list(range(len(data)))
+            done = [bool(d.flags & fs.DATA_FLAG_SLOT_COMPLETE) for d in data]
+            assert done == [False] * (len(data) - 1) + [True]
+            assert {d.parent_off for d in data} == {slot - parent}
+            parent = slot
+        assert sum(n_txn.values()) == N
+        assert sum(1 for v in n_txn.values() if v) >= 3   # not one block
+    finally:
+        pipe.close()
