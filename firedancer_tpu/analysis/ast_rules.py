@@ -114,10 +114,11 @@ _BANK_PATH_FILES = frozenset({"bank.py", "bank_native.py"})
 # allowlist.  Frag callbacks are excluded here — FD201 already owns
 # them.
 _FD214_FILES = frozenset({"verify.py", "serve.py", "verify_native.py"})
-# warmup() syncs by design, at boot, before any batch is in flight
+# warmup() syncs by design, at boot, before any batch is in flight;
+# _mask_of is the fetch both lanes' reap (_nv_drain, _result_mask) share
 _FD214_REAP_METHODS = frozenset({
-    "_drain", "_nv_drain", "_result_mask", "_result_ready", "flush",
-    "warmup",
+    "_drain", "_nv_drain", "_result_mask", "_result_ready", "_mask_of",
+    "flush", "warmup",
 })
 _FD214_SYNC_CALLS = frozenset({
     ("np", "asarray"), ("np", "array"), ("jax", "device_get"),

@@ -162,6 +162,7 @@ def build_leader_pipeline_from_config(cfg, **overrides) -> "LeaderPipeline":
         max_msg_len=cfg.verify.max_msg_len,
         depth=cfg.verify.receive_buffer_depth,
         batch_deadline_s=cfg.verify.batch_deadline_ms / 1e3,
+        verify_devices=cfg.verify.devices,
     )
     kw.update(overrides)
     return build_leader_pipeline(**kw)
@@ -181,6 +182,7 @@ def build_leader_pipeline(
     leader_seed: bytes = b"leader",
     verify_precomputed: bool = False,
     verify_comb_slots: int = 0,
+    verify_devices: int = 1,
     bank_ctx: BankCtx | None = None,
     keep_entries: bool = False,
     keep_sets: bool = True,
@@ -191,7 +193,11 @@ def build_leader_pipeline(
     udp_ingress: bool = False,
     n_payers: int = 8,
 ) -> LeaderPipeline:
-    """n_payers: the generator's funded payer set (and the default bank
+    """verify_devices: chips behind each verify stage (1 = the default
+    device; n > 1 = a mesh of the first n local devices, `batch` lanes
+    over all of them).
+
+    n_payers: the generator's funded payer set (and the default bank
     ctx's genesis).  Pack schedules at most one transaction per payer
     into a microblock, so a long stream over few payers drains slower
     than verify feeds it and pack sheds what its pool cannot hold.
@@ -270,6 +276,7 @@ def build_leader_pipeline(
             batch_deadline_s=batch_deadline_s,
             precomputed_ok=verify_precomputed,
             comb_slots=verify_comb_slots,
+            devices=verify_devices,
         )
         for i in range(n_verify)
     ]

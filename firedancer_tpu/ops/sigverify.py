@@ -116,8 +116,9 @@ def ed25519_verify_batch_fused(
     without scanning the mask.
 
     n_real: scalar int32 — lanes >= n_real are padding and come back
-    False.  Returns ((B,) bool mask, scalar int32 ok-count over the real
-    lanes).
+    False (or a (B,) int32 vector of such limits, one a lane: lane p is
+    real where n_real[p] > p).  Returns ((B,) bool mask, scalar int32
+    ok-count over the real lanes).
     """
     ok = _verify_ok(msg, msg_len, sig, pubkey, max_msg_len=max_msg_len)
     lane = jnp.arange(ok.shape[0], dtype=jnp.int32)
@@ -296,9 +297,13 @@ def kernel_clear_caches(kernel: str) -> None:
         f.clear_cache()
 
 
-def verify_dispatch(kernel: str, msg, msg_len, sig, pubkey, n_real: int,
+def verify_dispatch(kernel: str, msg, msg_len, sig, pubkey, n_real,
                     *, max_msg_len: int):
     """Dispatch one batch on the chosen ladder lane.
+
+    n_real: how many leading lanes are real (an int), or, where the real
+    lanes are no prefix (a mesh dealt round-robin), a placed (B,) int32
+    lane vector with a value above p in lane p where it is real.
 
     Returns (mask future, ok-count future | None): only the fused lane
     computes the count on device; callers fall back to host mask
@@ -308,9 +313,10 @@ def verify_dispatch(kernel: str, msg, msg_len, sig, pubkey, n_real: int,
     if kernel == "fused":
         import jax.numpy as _jnp
 
+        if getattr(n_real, "ndim", 0) == 0:
+            n_real = _jnp.int32(n_real)
         return ed25519_verify_batch_fused(
-            msg, msg_len, sig, pubkey, _jnp.int32(n_real),
-            max_msg_len=max_msg_len,
+            msg, msg_len, sig, pubkey, n_real, max_msg_len=max_msg_len,
         )
     if kernel == "baseline":
         return (

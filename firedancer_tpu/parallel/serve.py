@@ -621,9 +621,8 @@ class ShardedVerifyStage(VerifyStage):
         # one accumulator per shard (per input ring); VerifyStage's _gen
         # acc is unused on this subclass
         self._shards = [_Acc() for _ in range(self.n_shards)]
-        zeroed = self.metrics.counters  # VerifyStage's start-at-0 counters
-        self.metrics = type(self.metrics)(self.metrics_schema_n(self.n_shards))
-        self.metrics.counters.update(zeroed)
+        self._use_shard_schema(self.n_shards)
+        self.metrics.counters["mesh_devices"] = self.n_shards
 
     # -- observability ------------------------------------------------------
 
@@ -632,16 +631,6 @@ class ShardedVerifyStage(VerifyStage):
         s = VerifyStage.extra_schema()
         s.counter("poh_spans_ok", "PoH self-audit spans verified on-mesh")
         s.counter("poh_spans_fail", "PoH self-audit spans that FAILED")
-        return s
-
-    @classmethod
-    def metrics_schema_n(cls, n_shards: int) -> fmet.MetricsSchema:
-        """The class schema + per-shard element counters (the per-shard
-        metrics the scrape surface labels by shard)."""
-        s = cls.metrics_schema()
-        for i in range(n_shards):
-            s.counter(f"shard_elems_s{i}",
-                      f"signature elements dispatched on shard {i}")
         return s
 
     # -- mux callbacks -------------------------------------------------------

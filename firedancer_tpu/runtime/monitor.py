@@ -321,6 +321,7 @@ class MonitorSession:
             row.update(fm.latency_row(j.registry))
             row["sweep_phases"] = fm.nsweep_phase_row([j.registry])
             row["batch_closes"] = fm.batch_close_row([j.registry])
+            row["mesh"] = fm.mesh_row(j.registry)
             out.append(row)
         for logical, js in groups.items():
             sigs = [j.cnc.signal for j in js]
@@ -416,6 +417,13 @@ class MonitorSession:
                     f"{r['stage']}: batches closed "
                     + " ".join(f"{k}={bc[k]:,}" for k in fm.BATCH_CLOSES)
                     + f"  batch_stalls={bc['stalls']:,}")
+            mesh = r.get("mesh")
+            if mesh:
+                lines.append(
+                    f"{r['stage']}: mesh of {mesh['devices']} chips,"
+                    " useful lanes " + " ".join(
+                        f"s{i}={v:,}"
+                        for i, v in enumerate(mesh["shard_elems"])))
         return "\n".join(lines)
 
     def run(self, *, interval_s: float = 1.0, iterations: int | None = None,

@@ -133,6 +133,11 @@ def _stage_block(mets: dict, records: list) -> dict:
         block["batch_closes"] = {
             c: int(mets.get(name, 0) or 0)
             for c, name in zip(fm.BATCH_CLOSES, fm.BATCH_CLOSE_COUNTERS)}
+    # a verify stage over a mesh: how many chips, and the useful lanes
+    # each was dealt
+    mesh = fm.mesh_row(mets)
+    if mesh:
+        block["mesh"] = mesh
     return block
 
 

@@ -34,6 +34,17 @@ native and the Python lane alike):
 `batch_close_full + batch_close_deadline + batch_close_window == batches`
 says which of the three closed each dispatched batch.
 
+More than one chip behind one intake (`devices=n`): the stage builds a
+one-axis mesh over the first n local devices, the native intake seals
+slots of the whole fixed shape (n x batch // n lanes), and the dispatch
+deals the slot's elements round-robin — element e of a step goes to
+chip e % n, as the reference's N verify tiles each take seq % N — by
+placing each of the slot's four arrays straight onto its shards, and
+runs the same program over the mesh: one module a step, the ok-count's
+sum its only collective.  Reap, publish, the close rule, the stamps and
+the counters are the one-device lane's; `shard_elems_s{i}` counts the
+useful lanes chip i was given.
+
 Repeated-signer fast path (round 4): real ingress repeats signers heavily
 (one vote key per validator), so the stage keeps a device-resident comb
 bank (ops/sigverify.py comb_fill / ed25519_verify_batch_cached).  A pubkey
@@ -232,7 +243,7 @@ class VerifyStage(Stage):
         kernel: str | None = None,
         autotune_after: int = 0,
         native_client: bool | None = None,
-        devices=None,
+        devices: int | None = None,
         precomputed_ok: bool = False,
         comb_slots: int = 0,
         promote_threshold: int = 2,
@@ -258,6 +269,37 @@ class VerifyStage(Stage):
         # parse, dedup, pack, bank, poh, shred) is measured net of
         # accelerator round trips.  Never use outside bench.
         self.precomputed_ok = precomputed_ok
+        # devices: how many chips are behind this stage.  None or 1 is
+        # the default device.  n > 1 is a one-axis mesh over the first n
+        # local devices: device i owns lanes [i, i + 1) * batch // n of
+        # the fixed-shape batch and is dealt elements i, i + n, i + 2n,
+        # ... of every batch (the reference's N verify tiles each take
+        # seq % N), so the chips fill evenly at any fill; every generic
+        # batch is placed straight onto its shards and verified by the
+        # SAME program (ops/sigverify.verify_dispatch), one compiled
+        # module a step over the mesh.  The lane shardings are the
+        # serving plane's (parallel/mesh.batch_sharding): P(None, axis)
+        # byte rows, P(axis) lane vectors.
+        self._lane_shardings = None
+        self.mesh_devices = 1
+        if devices is not None and devices != 1:
+            if plane is not None or comb_slots:
+                raise ValueError(
+                    "devices= places generic batches itself: a serving"
+                    " plane or a comb bank dispatches elsewhere")
+            if batch % devices:
+                raise ValueError(
+                    f"verify batch {batch} does not divide over"
+                    f" {devices} devices")
+            from firedancer_tpu.parallel import mesh as pm
+
+            self.mesh_devices = devices
+            self._lane_shardings = pm.batch_sharding(pm.make_mesh(devices))
+            self._use_shard_schema(devices)
+            # lane p = i * per + a (chip i's a-th) holds element a * n + i
+            lane = np.arange(batch, dtype=np.int32)
+            per = batch // devices
+            self._elem_of_lane = (lane % per) * devices + lane // per
         self.shard_idx = shard_idx
         self.shard_cnt = shard_cnt
         self.batch = batch
@@ -310,15 +352,18 @@ class VerifyStage(Stage):
         for name in _PHASE_COUNTERS + _CLOSE_COUNTERS:
             self.metrics.counters[name] = 0
         self.metrics.counters["batch_stalls"] = 0
+        self.metrics.counters["mesh_devices"] = self.mesh_devices
         # sweep-granularity parser (drain-table path), built on first use
         self._burst_parser = None
         # -- native sweep client (ISSUE 13) -----------------------------------
         # the whole intake sweep (drain -> parse -> guards -> batch
         # assembly) in ONE fdr_sweep crossing with zero Python per frag;
-        # armed only on the plain generic lane (no plane, no comb bank)
-        # over all-native rings whose out link carries the preassembled
-        # frame size.  native_client: None = auto-arm for exact
-        # VerifyStage instances, False = never, True = required.
+        # armed only on the generic lane (no plane, no comb bank; one
+        # device or a mesh of them: the slot is the whole fixed-shape
+        # batch either way) over all-native rings whose out link carries
+        # the preassembled frame size.  native_client: None = auto-arm
+        # for exact VerifyStage instances, False = never, True =
+        # required (raises, naming what blocked it).
         self._sweep_client = None
         # (slot, n_elems, n_txn, result, n_ok, life)
         self._nv_inflight: list = []
@@ -332,10 +377,9 @@ class VerifyStage(Stage):
             # structural preconditions, each named so native_client=True
             # (the "required" contract) can say exactly what blocked it
             blocker = None
-            if plane is not None:
-                blocker = "a serving plane routes generic batches"
-            elif comb_slots != 0:
-                blocker = "the comb bank needs Python signer tracking"
+            if plane is not None or comb_slots != 0:
+                blocker = ("a serving plane's step and the comb bank's"
+                           " signer tracking dispatch from the Python lane")
             elif not self.ins or not self.outs:
                 blocker = "stage has no rings"
             elif not all(type(c).__name__ == "NativeConsumer"
@@ -394,6 +438,10 @@ class VerifyStage(Stage):
                      "frags dropped after the native intake stash"
                      " overflowed (dead/wedged consumer)")
             .counter("retunes", "autotuner geometry changes applied")
+            .gauge("mesh_devices",
+                   "chips behind this stage: 1 = the default device, n > 1"
+                   " = a mesh whose chip i takes elements i, i + n, ... of"
+                   " every batch (shard_elems_s{i} counts them)")
             # the life of a batch, summed over batches as each phase
             # ends (ns; divide a window's delta by its delta of batches)
             .counter("batch_open_ns",
@@ -446,6 +494,27 @@ class VerifyStage(Stage):
                 "in-flight batches at submit (async window fill)",
             )
         )
+
+    @classmethod
+    def metrics_schema_n(cls, n_shards: int) -> fm.MetricsSchema:
+        """The class schema + per-shard element counters (the per-shard
+        metrics the scrape surface labels by shard): what a stage over
+        a mesh of `n_shards` devices publishes, and what a process
+        topology sizes its shm segment from."""
+        s = cls.metrics_schema()
+        for i in range(n_shards):
+            s.counter(f"shard_elems_s{i}",
+                      f"signature elements dispatched on shard {i}")
+        return s
+
+    def _use_shard_schema(self, n_shards: int) -> None:
+        """Swap the stage's metrics for ones over metrics_schema_n,
+        keeping what was counted so far; the shard counters start at 0."""
+        kept = self.metrics.counters
+        self.metrics = type(self.metrics)(self.metrics_schema_n(n_shards))
+        self.metrics.counters.update(kept)
+        for i in range(n_shards):
+            self.metrics.counters.setdefault(f"shard_elems_s{i}", 0)
 
     # -- mux callbacks ------------------------------------------------------
 
@@ -726,19 +795,16 @@ class VerifyStage(Stage):
         its own warmup())."""
         if self.precomputed_ok or self.plane is not None:
             return 0.0
-        import jax.numpy as jnp
-
         from firedancer_tpu.ops import sigverify as sv
 
         t0 = time.monotonic()
         b = self.batch
         mask, _ = sv.verify_dispatch(
             self.kernel,
-            jnp.asarray(np.zeros((self.max_msg_len, b), dtype=np.uint8)),
-            jnp.asarray(np.zeros((b,), dtype=np.int32)),
-            jnp.asarray(np.zeros((64, b), dtype=np.uint8)),
-            jnp.asarray(np.zeros((32, b), dtype=np.uint8)),
-            0,
+            *self._place(np.zeros((self.max_msg_len, b), dtype=np.uint8),
+                         np.zeros((b,), dtype=np.int32),
+                         np.zeros((64, b), dtype=np.uint8),
+                         np.zeros((32, b), dtype=np.uint8), 0),
             max_msg_len=self.max_msg_len,
         )
         mask.block_until_ready()
@@ -802,23 +868,81 @@ class VerifyStage(Stage):
         if life is not None:
             life.seq = self.metrics.get("batches") + 1
 
-    def _device_verify(self, life: _Life | None, msg, ln, sig, pk, n: int):
-        """The kernel-ladder dispatch of one batch's byte rows: the four
-        host->device copies (the end of the batch's h2d phase), then the
-        kernel call (fused by default: one compiled module per batch,
-        pad lanes masked + ok-count computed on device); the caller ends
-        the launch phase.  -> (mask future, ok-count future | None)."""
-        import jax.numpy as jnp
+    def _place(self, msg, ln, sig, pk, n: int) -> tuple:
+        """One batch's host arrays (element e in column e, `n` of them
+        real) onto the device(s) -> the program's five arguments.  One
+        device: four copies to the default device and `n` itself.  A
+        mesh of d devices: chip i is dealt columns i, i + d, ... — each
+        array goes straight onto its shards, one copy per array per
+        device, of a strided view of that device's columns only
+        (wrapping in jnp.asarray first would commit the whole batch to
+        device 0 and then reshard it) — and, the real lanes being no
+        prefix then, `n` goes as a lane vector placed with them: lane p
+        holds a value above p where its element is real (the program's
+        `lane < n_real`, elementwise).  uint8 byte rows: 4x less
+        host->device transfer; the kernel widens to int32 on-device."""
+        if self._lane_shardings is None:
+            import jax.numpy as jnp
 
+            return (jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
+                    jnp.asarray(pk), n)
+        import jax
+
+        d = self.mesh_devices
+        per = self.batch // d
+        rows, vec = self._lane_shardings
+
+        def dealt(host, sharding):
+            return jax.make_array_from_callback(
+                host.shape, sharding,
+                lambda idx: host[..., idx[-1].start // per::d])
+
+        real = np.where(self._elem_of_lane < n, np.int32(self.batch),
+                        np.int32(0))
+        return (dealt(msg, rows), dealt(ln, vec), dealt(sig, rows),
+                dealt(pk, rows), jax.device_put(real, vec))
+
+    def _mask_of(self, result) -> np.ndarray:
+        """A dispatched batch's mask on the host, element e at index e
+        (over a mesh the lanes come back chip by chip: dealt back; the
+        all-pass mask never left the host)."""
+        mask = np.asarray(result)
+        if self._lane_shardings is None or mask is result:
+            return mask
+        return mask.reshape(self.mesh_devices, -1).T.reshape(-1)
+
+    def _device_verify(self, life: _Life | None, msg, ln, sig, pk, n: int):
+        """The kernel-ladder dispatch of one batch's byte rows: the
+        host->device copies of its arrays (_place: to the default
+        device, or to each mesh device the columns it is dealt; the end
+        of the batch's h2d phase), then the kernel call (fused by
+        default: one compiled module per batch — over a mesh one module
+        a step, the same program partitioned by its arguments'
+        shardings — pad lanes masked + ok-count computed on device); the
+        caller ends the launch phase.  -> (mask future, ok-count future
+        | None); the mask is read through _mask_of."""
         from firedancer_tpu.ops import sigverify as sv
 
-        # uint8 byte rows: 4x less host->device transfer; the kernel
-        # widens to int32 on-device
-        dev = (jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
-               jnp.asarray(pk))
+        dev = self._place(msg, ln, sig, pk, n)
         self._phase_end(life, PH_H2D)
-        return sv.verify_dispatch(self.kernel, *dev, n,
+        return sv.verify_dispatch(self.kernel, *dev,
                                   max_msg_len=self.max_msg_len)
+
+    def _count_dispatch(self, n: int, close: int, occupancy: int) -> None:
+        """The books of one dispatched batch of `n` elements, on both
+        lanes.  Over a mesh of d devices, device i was dealt elements
+        i, i + d, ... below `n`: from the fill alone, no per-element
+        work."""
+        m = self.metrics
+        m.inc("batches", 1)
+        m.inc(_CLOSE_COUNTERS[close])
+        m.inc("batch_elems", n)
+        m.observe("batch_fill", n)
+        m.observe("inflight_occupancy", occupancy)
+        self.trace(fm.EV_BATCH_SUBMIT, n)
+        d = self.mesh_devices
+        for i in range(min(d, n) if d > 1 else 0):
+            m.inc(f"shard_elems_s{i}", (n - i + d - 1) // d)
 
     # -- native sweep-client plumbing ---------------------------------------
 
@@ -871,12 +995,7 @@ class VerifyStage(Stage):
                     n_elems)
         self._phase_end(life, PH_LAUNCH)
         self._nv_inflight.append((slot, n_elems, n_txn, result, n_ok, life))
-        self.metrics.inc("batches", 1)
-        self.metrics.inc(_CLOSE_COUNTERS[close])
-        self.metrics.inc("batch_elems", n_elems)
-        self.metrics.observe("batch_fill", n_elems)
-        self.metrics.observe("inflight_occupancy", len(self._nv_inflight))
-        self.trace(fm.EV_BATCH_SUBMIT, n_elems)
+        self._count_dispatch(n_elems, close, len(self._nv_inflight))
 
     def _nv_drain(self, block: bool) -> None:
         c = self._sweep_client
@@ -886,7 +1005,7 @@ class VerifyStage(Stage):
                 return
             self._phase_end(life, PH_INFLIGHT)
             with self._span("verify.reap", life):
-                mask = np.asarray(result)
+                mask = self._mask_of(result)
                 self._nv_inflight.pop(0)
                 self.trace(fm.EV_BATCH_COMPLETE, n_elems)
                 views = c.slots[slot]
@@ -1090,12 +1209,7 @@ class VerifyStage(Stage):
                 life=life,
             )
         )
-        self.metrics.inc("batches", 1)
-        self.metrics.inc(_CLOSE_COUNTERS[acc.close])
-        self.metrics.inc("batch_elems", n)
-        self.metrics.observe("batch_fill", n)
-        self.metrics.observe("inflight_occupancy", len(self._inflight))
-        self.trace(fm.EV_BATCH_SUBMIT, n)
+        self._count_dispatch(n, acc.close, len(self._inflight))
         if cached:
             self.metrics.inc("comb_elems", n)
 
@@ -1164,7 +1278,7 @@ class VerifyStage(Stage):
         return self._mask_ready(head.result)
 
     def _result_mask(self, head) -> np.ndarray:
-        return np.asarray(head.result)
+        return self._mask_of(head.result)
 
     def _drain(self, block: bool) -> None:
         while self._inflight:

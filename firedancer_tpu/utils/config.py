@@ -33,6 +33,9 @@ class VerifyConfig:
     batch_deadline_ms: float = 2.0
     max_inflight: int = 3
     receive_buffer_depth: int = 1024
+    # chips behind each verify stage: 1 = the default device, n > 1 = a
+    # mesh of the first n local devices (runtime/verify.VerifyStage)
+    devices: int = 1
 
 
 @dataclass
@@ -144,6 +147,8 @@ def _validate(cfg: Config) -> None:
         raise ConfigError("layout.bank_stage_count must be in [1, 62]")
     if cfg.verify.batch < 1 or cfg.verify.batch & (cfg.verify.batch - 1):
         raise ConfigError("verify.batch must be a power of 2")
+    if cfg.verify.devices < 1 or cfg.verify.batch % cfg.verify.devices:
+        raise ConfigError("verify.devices must be >= 1 and divide verify.batch")
     if cfg.poh.hashes_per_tick < 1 or cfg.poh.ticks_per_slot < 1:
         raise ConfigError("poh cadence must be positive")
     if cfg.shred.batch_target_sz < 1:

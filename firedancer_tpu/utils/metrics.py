@@ -499,6 +499,25 @@ def batch_close_row(regs: list) -> dict | None:
     return {k: sum(r.get(n) for r in regs) for k, n in names}
 
 
+def mesh_row(src) -> dict | None:
+    """{"devices": n, "shard_elems": [useful lanes dispatched to chip
+    i, ...]} of a verify stage over a mesh of n > 1 devices, from its
+    registry (the monitor) or a dict of its metrics (slotreport); None
+    where the stage has one device or is not a verify stage."""
+    if src is None:
+        return None
+    if isinstance(src, MetricsRegistry):
+        reg = src
+        src = {n: reg.get(n) for n in reg._off
+               if n == "mesh_devices" or n.startswith("shard_elems_s")}
+    n = int(src.get("mesh_devices") or 0)
+    if n <= 1:
+        return None
+    return {"devices": n,
+            "shard_elems": [int(src.get(f"shard_elems_s{i}") or 0)
+                            for i in range(n)]}
+
+
 def batch_stall_arg(phase: int, ns: int) -> int:
     """EV_BATCH_STALL's arg: phase id in the high half, whole ms below."""
     return (phase << 32) | min(ns // 1_000_000, 0xFFFFFFFF)

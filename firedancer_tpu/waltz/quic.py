@@ -636,6 +636,8 @@ def parse_frames(payload: bytes):
         elif ft == FT_NEW_CONNECTION_ID:
             _seq, off = varint_decode(payload, off)
             _retire, off = varint_decode(payload, off)
+            if off >= n:
+                raise QuicError("NEW_CONNECTION_ID frame truncated")
             cid_len = payload[off]
             off += 1 + cid_len + 16  # cid + stateless reset token
         elif ft == FT_HANDSHAKE_DONE:

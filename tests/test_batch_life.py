@@ -27,7 +27,11 @@ from firedancer_tpu.runtime.verify import VerifyStage
 from firedancer_tpu.tango import shm
 from firedancer_tpu.utils import metrics as fm
 
-LANES = ["native", "python"]
+# "mesh" is the native lane in front of four (virtual) devices
+# (ISSUE 26): VerifyStage(devices=...), 16 lanes as 4 x 4
+LANES = ["native", "python", "mesh"]
+NATIVE_LANES = ("native", "mesh")
+MESH_DEVICES = 4
 # the close rule (ISSUE 25) also runs in parallel/serve.ShardedVerifyStage,
 # which names its accumulators and inherits the rest; it stamps no lives
 # (PR 24 left it out), so only the close tests take it
@@ -44,10 +48,10 @@ def pool():
 def _tile(lane: str, **stage_kw):
     """One VerifyStage over real (native) rings -> (stage, producer into
     it, consumer behind it)."""
-    if lane == "native" and not vn.available():
+    if lane in NATIVE_LANES and not vn.available():
         pytest.skip("native verify client unavailable")
     prev = os.environ.get(vn.ENV_SWITCH)
-    os.environ[vn.ENV_SWITCH] = "1" if lane == "native" else "0"
+    os.environ[vn.ENV_SWITCH] = "1" if lane in NATIVE_LANES else "0"
     uid = shm.fresh_uid()
     lin = shm.ShmLink.create(f"tbl_i_{uid}", depth=256, mtu=1232, n_fseq=1)
     lout = shm.ShmLink.create(f"tbl_o_{uid}", depth=256, mtu=4096, n_fseq=1)
@@ -73,8 +77,11 @@ def _tile(lane: str, **stage_kw):
             cls = serve.ShardedVerifyStage
         else:
             cls = VerifyStage
+            if lane == "mesh":
+                kw["devices"] = MESH_DEVICES
         st = cls("v0", ins=ins, outs=[shm.make_producer(lout)], **kw)
-        assert (st._sweep_client is not None) == (lane == "native")
+        assert (st._sweep_client is not None) == (lane in NATIVE_LANES)
+        assert st.mesh_devices == (MESH_DEVICES if lane == "mesh" else 1)
         yield st, prod, shm.make_consumer(lout, lazy=4)
     finally:
         if prev is None:
@@ -243,10 +250,11 @@ def test_a_full_batch_closes_without_the_deadline(lane, pool):
 
 
 class _SlowResult:
-    """A device future's surface, for a stubbed dispatch."""
+    """A device future's surface, for a stubbed dispatch: the mask of
+    the whole fixed-shape batch."""
 
-    def __init__(self, n):
-        self.mask = np.ones((n,), dtype=bool)
+    def __init__(self, lanes):
+        self.mask = np.ones((lanes,), dtype=bool)
 
     def is_ready(self):
         return True
@@ -263,7 +271,7 @@ def test_a_slow_dispatch_is_one_stall_event(lane, pool):
         def slow(life, msg, ln, sig, pk, n):
             st._phase_end(life, rv.PH_H2D)
             time.sleep(0.12)
-            return _SlowResult(n), None
+            return _SlowResult(len(ln)), None
 
         st._device_verify = slow
         for i in range(5):
@@ -451,9 +459,9 @@ CLOSE_COUNTERS = list(rv._CLOSE_COUNTERS)
 class _Gated:
     """A device future that is ready when the test says."""
 
-    def __init__(self, n):
+    def __init__(self, n, lanes):
         self.n = n
-        self.mask = np.ones((n,), dtype=bool)
+        self.mask = np.ones((lanes,), dtype=bool)
         self.done = False
 
     def is_ready(self):
@@ -477,7 +485,7 @@ def _gated_tile(lane: str, **kw):
 
         def dispatch(life, msg, ln, sig, pk, n):
             st._phase_end(life, rv.PH_H2D)
-            sent.append(_Gated(n))
+            sent.append(_Gated(n, len(ln)))
             return sent[-1], None
 
         st._device_verify = dispatch
@@ -733,11 +741,28 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
         rendered = mon.MonitorSession.render(
             [{"stage": "v0", "signal": 1, "heartbeat_age_ms": 1.0, "in": 0,
               "out": 0, "overrun": 0, "backpressure": 0, "iters": 1,
-              "batch_closes": row}], None, 1.0)
+              "batch_closes": row,
+              "mesh": fm.mesh_row(reg)}], None, 1.0)
         assert f"v0: batches closed full={row['full']:,} " \
                f"deadline={row['deadline']:,} window={row['window']:,}" \
                f"  batch_stalls=0" in rendered
         dump = fm.flight_dump_obj("t", {"v0": (reg, st.recorder)})
         block = slot_report.build_report(dump)["stages"]["v0"]
         assert block["batch_closes"] == {c: row[c] for c in fm.BATCH_CLOSES}
+        # over a mesh: how many chips and the useful lanes of each, in
+        # the same three places; with one device, in none
+        if lane == "mesh":
+            shards = [st.metrics.get(f"shard_elems_s{i}")
+                      for i in range(MESH_DEVICES)]
+            assert sum(shards) == st.metrics.get("batch_elems") == 20
+            assert block["mesh"] == {"devices": MESH_DEVICES,
+                                     "shard_elems": shards}
+            assert f"v0: mesh of {MESH_DEVICES} chips, useful lanes " \
+                + " ".join(f"s{i}={v:,}" for i, v in enumerate(shards)) \
+                in rendered
+            assert 'mesh_devices{stage="v0"} 4' in text
+            assert f'shard_elems_s0{{stage="v0"}} {shards[0]}' in text
+        elif lane != "sharded":
+            assert "mesh" not in block and "mesh of" not in rendered
+            assert 'mesh_devices{stage="v0"} 1' in text
     assert fm.batch_close_row([Stage("s").metrics.registry]) is None

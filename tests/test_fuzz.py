@@ -35,7 +35,7 @@ pytest.importorskip(
            "see scripts/fuzz_deep.sh)",
 )
 
-from hypothesis import HealthCheck, given, settings, strategies as st
+from hypothesis import HealthCheck, example, given, settings, strategies as st
 
 MAX_EXAMPLES = int(os.environ.get("FDTPU_FUZZ_EXAMPLES", "250"))
 
@@ -220,6 +220,8 @@ def test_fuzz_http_response(data):
 
 @FUZZ
 @given(st.one_of(raw, mutated(bytes([0x06, 0x00, 0x04]) + b"\x01" * 4)))
+# a NEW_CONNECTION_ID frame cut off before its length byte
+@example(b"\x18\x00\xc0\x00\x00\x00\x00\x00\x00\x00")
 def test_fuzz_quic_frames(data):
     from firedancer_tpu.waltz import quic as Q
 
