@@ -682,8 +682,7 @@ class ShardedVerifyStage(VerifyStage):
         the step (a shard held past its deadline), so the fills are
         read after it."""
         accs = self._shards
-        if len(self._inflight) >= self.max_inflight \
-                and any(a.elems for a in accs):
+        if not self._window_has_room() and any(a.elems for a in accs):
             self._drain(block=True)
         n_elems = sum(len(a.elems) for a in accs)
         if n_elems == 0:

@@ -14,9 +14,18 @@ Pipeline position and semantics mirror the reference's verify tile
     reparses (the parsed-txn trailer convention, fd_verify.c:93-100).
 
 TPU-native twist (the wiredancer async-offload shape, SURVEY §7.1): txns
-accumulate into fixed-shape device batches; 2+ batches stay in flight so
-host streaming overlaps device compute.  Fixed shapes mean partial batches
-are padded and the pad lanes' results ignored.
+accumulate into fixed-shape device batches, and a window of them stays
+in flight so host streaming overlaps device compute.  Fixed shapes mean
+partial batches are padded and the pad lanes' results ignored.
+
+How deep the window is (one test, `_window_has_room`, on the native lane,
+the Python lane and the sharded stage alike): WINDOW_DEPTH, two — one
+batch running and one queued behind it, which keeps the device back to
+back as long as the thread replaces a reaped head within one program
+length.  A batch dispatched into a window of d waits behind d - 1
+others, so every one too many is a program length in every signature's
+path and buys nothing.  `max_inflight` can only narrow it (1: one batch
+at a time).
 
 When a batch closes (one rule, `_deadline_close` + `_window_open`, on the
 native and the Python lane alike):
@@ -145,10 +154,9 @@ VERIFY_KERNELS = ("fused", "baseline", "split")
 DEFAULT_KERNEL = os.environ.get("FDTPU_VERIFY_KERNEL", "fused")
 
 # the async in-flight window (wiredancer shape): how many device batches
-# may be outstanding before submit defers.  >= 8 keeps the accelerator
-# fed while the host streams the next batches; reaping is strictly in
-# submission order regardless of width.
-DEFAULT_MAX_INFLIGHT = int(os.environ.get("FDTPU_VERIFY_INFLIGHT", "8"))
+# a stage keeps outstanding — one running, one queued behind it (module
+# docstring).  Reaping is strictly in submission order at any depth.
+WINDOW_DEPTH = 2
 
 # native sweep-client frames are payload + packed descriptor + u16; the
 # out link must carry them (fd_verify.cpp FRAME_CAP)
@@ -305,8 +313,10 @@ class VerifyStage(Stage):
         self.batch = batch
         self.max_msg_len = max_msg_len
         self.batch_deadline_s = batch_deadline_s
-        self.max_inflight = (max_inflight if max_inflight is not None
-                             else DEFAULT_MAX_INFLIGHT)
+        # the most batches in flight: WINDOW_DEPTH, or the fewer a caller
+        # holds the stage to
+        self.max_inflight = (WINDOW_DEPTH if max_inflight is None
+                             else min(WINDOW_DEPTH, max_inflight))
         self.kernel = kernel if kernel is not None else DEFAULT_KERNEL
         if self.kernel not in VERIFY_KERNELS:
             raise ValueError(
@@ -697,17 +707,24 @@ class VerifyStage(Stage):
         serving stage keeps one per shard)."""
         return (self._gen, self._comb)
 
+    def _window_has_room(self) -> bool:
+        """Fewer batches are in flight than the window is held to.  The
+        ONE test of the depth: the close rule, both lanes' submit loops
+        and the sharded stage's step all ask here."""
+        flying = (self._nv_inflight if self._sweep_client is not None
+                  else self._inflight)
+        return len(flying) < self.max_inflight
+
     def _window_open(self) -> bool:
         """A batch sealed now would be dispatched now: the in-flight
         window has room and no sealed batch waits ahead of it.  The ONE
         predicate of the close rule; it reads nothing but the stage's
         own window."""
+        if not self._window_has_room():
+            return False
         c = self._sweep_client
-        if c is not None:
-            return (len(self._nv_inflight) < self.max_inflight
-                    and not c.sealed_waiting())
-        return (len(self._inflight) < self.max_inflight
-                and not self._submit_queue)
+        return not (c.sealed_waiting() if c is not None
+                    else self._submit_queue)
 
     def _deadline_close(self) -> None:
         """The deadline's half of the close rule (p99 latency at low
@@ -969,7 +986,7 @@ class VerifyStage(Stage):
         self._nv_drain(block=False)
         self._nv_publish()
         self._deadline_close()
-        while len(self._nv_inflight) < self.max_inflight:
+        while self._window_has_room():
             got = c.take_sealed()
             if got is None:
                 break
@@ -1183,7 +1200,7 @@ class VerifyStage(Stage):
         """Move sealed batches into the device window, in seal order,
         while the window has room."""
         q = self._submit_queue
-        while q and len(self._inflight) < self.max_inflight:
+        while q and self._window_has_room():
             acc, cached = q.pop(0)
             self._submit(acc, cached)
 

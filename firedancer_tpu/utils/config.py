@@ -31,7 +31,6 @@ class VerifyConfig:
     batch: int = 256
     max_msg_len: int = 1232
     batch_deadline_ms: float = 2.0
-    max_inflight: int = 3
     receive_buffer_depth: int = 1024
     # chips behind each verify stage: 1 = the default device, n > 1 = a
     # mesh of the first n local devices (runtime/verify.VerifyStage)

@@ -476,7 +476,6 @@ def _gated_tile(lane: str, **kw):
     """A tile whose dispatches hand back _Gated results, in `sent`."""
     import jax.profiler  # noqa: F401  (the span's import, off the clock)
 
-    kw.setdefault("max_inflight", 2)
     with _tile(lane, precomputed_ok=False, **kw) as (st, prod, cons):
         if lane == "sharded":      # its dispatch is the plane's step
             yield st, prod, cons, st.plane.sent
@@ -766,3 +765,123 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
             assert "mesh" not in block and "mesh of" not in rendered
             assert 'mesh_devices{stage="v0"} 1' in text
     assert fm.batch_close_row([Stage("s").metrics.registry]) is None
+
+
+# -- how deep the in-flight window is (ISSUE 27) ------------------------------------
+#
+# Two: one batch running, one queued behind it, whatever the caller asked
+# for above that.  One test of the depth (_window_has_room) on every lane,
+# and nothing in a lane rests on the two.
+
+
+def _deadline_batch(st, prod, cons, pool, got, lo: int, n: int = 2) -> int:
+    """n transactions, sealed at their deadline with room in the window."""
+    before = st.metrics.get("batches")
+    _feed(prod, pool, lo, lo + n)
+    _spin(st, cons, got)
+    _past_deadline(st, cons, got)
+    assert st.metrics.get("batches") == before + 1
+    return lo + n
+
+
+def _held_batch(st, prod, cons, pool, got, lo: int, n: int = 3) -> int:
+    """n transactions, held open past their deadline by the full window."""
+    before = st.metrics.get("batches")
+    _feed(prod, pool, lo, lo + n)
+    _spin(st, cons, got)
+    _past_deadline(st, cons, got)
+    assert st.metrics.get("batches") == before and _open_elems(st) == n
+    return lo + n
+
+
+def _deepest(st, lane: str) -> int:
+    """The most batches that were in flight at any dispatch."""
+    if lane == "sharded":      # which observes no occupancy
+        return st.max_inflight
+    h = st.metrics.hist("inflight_occupancy")
+    assert h["count"] == st.metrics.get("batches")
+    return max(int(edge) for edge, n in zip(h["buckets"], h["counts"]) if n)
+
+
+@pytest.mark.parametrize("asked", [None, 8])
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_the_window_is_two_deep_whatever_was_asked_above_that(
+        lane, asked, pool):
+    """A third batch waits for a slot however dry the device has run and
+    however often: a batch dispatched behind d - 1 others waits d - 1
+    program lengths, and two keep the device back to back."""
+    with _gated_tile(lane, max_inflight=asked) as (st, prod, cons, sent):
+        assert st.max_inflight == rv.WINDOW_DEPTH == 2
+        got: list = []
+        n = 0
+        for k in range(3):
+            for _ in range(2):          # the window fills ...
+                n = _deadline_batch(st, prod, cons, pool, got, n)
+            # ... a batch is held behind it ...
+            n = _held_batch(st, prod, cons, pool, got, n)
+            assert len(st._nv_inflight or st._inflight) == 2
+            # ... and the device runs dry: the held batch takes a freed
+            # slot, and the window is as deep as it was
+            for g in sent:
+                g.done = True
+            _spin(st, cons, got)
+            assert len(sent) == 3 * k + 3
+            assert len(st._nv_inflight or st._inflight) == 1
+            sent[-1].done = True
+            _spin(st, cons, got)
+            assert got == list(pool[:n])
+            assert _closes(st) == [0, 2 * k + 2, k + 1]
+        assert _deepest(st, lane) == 2
+        assert sum(_closes(st)) == st.metrics.get("batches") == 9
+        assert st.metrics.get("txn_verified") == n
+
+
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_a_window_of_one_still_runs(lane, pool):
+    with _gated_tile(lane, max_inflight=1) as (st, prod, cons, sent):
+        got: list = []
+        n = _deadline_batch(st, prod, cons, pool, got, 0, 3)
+        n = _held_batch(st, prod, cons, pool, got, n)
+        sent[0].done = True
+        st.after_credit()
+        assert [g.n for g in sent] == [3, 3] and _closes(st) == [0, 1, 1]
+        sent[1].done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n]) and _deepest(st, lane) == 1
+
+
+@pytest.mark.parametrize("depth", [3, 5])
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_a_deeper_window_reaps_and_publishes_in_dispatch_order(
+        lane, depth, pool, monkeypatch):
+    """Nothing in a lane rests on the depth being two: with `depth` real
+    batches in flight and the later ones finished first, nothing leaves
+    past the head, and what leaves is in dispatch order."""
+    monkeypatch.setattr(rv, "WINDOW_DEPTH", depth)
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        assert st.max_inflight == depth
+        got: list = []
+        n = 0
+        for _ in range(depth):
+            n = _deadline_batch(st, prod, cons, pool, got, n)
+        n = _held_batch(st, prod, cons, pool, got, n)
+        assert len(sent) == depth and _deepest(st, lane) == depth
+        # the later batches finish ahead of the head: nothing comes out,
+        # no slot is freed
+        for g in sent[1:]:
+            g.done = True
+        _spin(st, cons, got)
+        assert got == [] and len(sent) == depth
+        assert st.metrics.get("txn_verified") == 0
+        # the head finishes: the whole window leaves in dispatch order,
+        # and the held batch takes the first freed slot
+        sent[0].done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:2 * depth])
+        assert [g.n for g in sent] == [2] * depth + [3]
+        assert _closes(st) == [0, depth, 1]
+        sent[-1].done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+        assert sum(_closes(st)) == st.metrics.get("batches") == depth + 1
+        assert _deepest(st, lane) == depth

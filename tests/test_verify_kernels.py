@@ -1,8 +1,9 @@
 """Verify kernel ladder + async window + autotuner (ISSUE 13).
 
 Tier-1 here is structural and host-only: ladder introspection (dispatch
-counts), the >= 8 deep in-flight window's in-order/backpressure
-semantics driven with fake device futures (no XLA), and the autotuner's
+counts), the in-flight window's in-order/backpressure semantics at its
+depth of two (ISSUE 27) and, so that nothing rests on the two, at deeper
+ones, driven with fake device futures (no XLA), and the autotuner's
 determinism.  The compile-heavy differential lanes (fused vs split vs
 baseline masks on adversarial inputs, cached interleave) live behind
 the `slow` marker — a single sigverify-program compile costs ~3 min on
@@ -14,6 +15,7 @@ import hashlib
 import numpy as np
 import pytest
 
+from firedancer_tpu.runtime import verify as rv
 from firedancer_tpu.runtime import verify_tune as vt
 from firedancer_tpu.runtime.benchg import gen_transfer_pool
 from firedancer_tpu.runtime.verify import VerifyStage
@@ -38,10 +40,20 @@ def test_stage_rejects_unknown_kernel():
         VerifyStage("v", ins=[], outs=[], kernel="warp")
 
 
-def test_stage_kernel_and_window_defaults():
+def test_stage_kernel_and_window_defaults(monkeypatch):
+    # the depth is a constant: the environment switch is gone
+    monkeypatch.setenv("FDTPU_VERIFY_INFLIGHT", "5")
     st = VerifyStage("v", ins=[], outs=[], native_client=False)
     assert st.kernel == "fused"
-    assert st.max_inflight >= 8  # the wiredancer-grade window
+    assert st.max_inflight == rv.WINDOW_DEPTH == 2  # one running, one queued
+
+
+@pytest.mark.parametrize("asked, held", [(None, 2), (1, 1), (2, 2), (3, 2),
+                                         (8, 2)])
+def test_max_inflight_can_only_narrow_the_window(asked, held):
+    st = VerifyStage("v", ins=[], outs=[], native_client=False,
+                     max_inflight=asked)
+    assert st.max_inflight == held
 
 
 # -- the async in-flight window (fake futures, no XLA) ------------------------
@@ -94,55 +106,74 @@ def txn_pool():
     return gen_transfer_pool(48, n_payers=8, n_dests=64)
 
 
-def test_window_fills_to_max_inflight_and_defers(txn_pool):
+@pytest.fixture(params=[2, 3, 8])
+def depth(request, monkeypatch):
+    """The window's depth: the one the stage runs at, and deeper ones the
+    same lane has to hold in order."""
+    monkeypatch.setattr(rv, "WINDOW_DEPTH", request.param)
+    return request.param
+
+
+@pytest.mark.parametrize("max_inflight", [None, 1, 2, 8])
+def test_window_fills_to_its_depth_and_defers(txn_pool, depth, max_inflight):
     st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
-                      max_inflight=8)
-    _feed(st, txn_pool[:44])  # 11 batches of 4
-    # nothing reaped (no fake is ready): the window holds exactly 8 and
-    # the remaining sealed batches parked in the submit queue — submit
-    # never blocked on a device future
-    assert len(st._inflight) == 8
+                      max_inflight=max_inflight)
+    held = min(depth, max_inflight or depth)
+    assert st.max_inflight == held
+    n = 4 * (held + 3)
+    _feed(st, txn_pool[:n])  # the window + 3 batches of 4
+    # nothing reaped (no fake is ready): the window holds exactly its
+    # depth and the remaining sealed batches parked in the submit queue
+    # — submit never blocked on a device future
+    assert len(st._inflight) == held
     assert len(st._submit_queue) == 3
     assert st.metrics.get("submit_deferred") > 0
-    assert st.metrics.get("batches") == 8  # only submitted ones dispatched
+    assert st.metrics.get("batches") == held  # only submitted ones
     occ = st.metrics.hist("inflight_occupancy")
-    assert occ["count"] == 8 and occ["sum"] > 0
+    assert occ["count"] == held and occ["sum"] == held * (held + 1) / 2
+    # and it still runs to the end, in order
+    for f in st.fakes:
+        f.ready = True
+    st.flush()
+    assert [e[2] for e in st.emitted] == list(range(1000, 1000 + n))
 
 
-def test_window_reaps_in_order_under_out_of_order_completion(txn_pool):
-    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
-                      max_inflight=8)
-    _feed(st, txn_pool[:32])  # 8 batches
-    assert len(st.fakes) == 8
+def test_window_reaps_in_order_under_out_of_order_completion(txn_pool, depth):
+    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256)
+    _feed(st, txn_pool[:4 * depth + 8])  # the window + 2 parked
+    assert len(st.fakes) == depth
     # complete LATER batches first: nothing may emit past the head
     for f in st.fakes[1:]:
         f.ready = True
     st.after_credit()
     assert st.emitted == []
-    # head completes: everything reaps, in submission order
+    # head completes: everything in the window reaps, in submission
+    # order, and the parked batches take the freed slots
     st.fakes[0].ready = True
     st.after_credit()
-    assert len(st.emitted) == 32
+    assert len(st.emitted) == 4 * depth
+    assert len(st._inflight) == 2 and not st._submit_queue
+    st.flush()
     tsorigs = [e[2] for e in st.emitted]
-    assert tsorigs == sorted(tsorigs)  # global emit order = intake order
+    # global emit order = intake order
+    assert tsorigs == list(range(1000, 1000 + 4 * depth + 8))
+    assert st.metrics.get("batches") == depth + 2
 
 
-def test_window_freed_slots_pull_deferred_submits(txn_pool):
-    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
-                      max_inflight=3)
-    _feed(st, txn_pool[:24])  # 6 batches: 3 in flight + 3 parked
-    assert len(st._inflight) == 3 and len(st._submit_queue) == 3
+def test_window_freed_slots_pull_deferred_submits(txn_pool, depth):
+    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256)
+    _feed(st, txn_pool[:4 * depth + 12])  # the window + 3 parked
+    assert len(st._inflight) == depth and len(st._submit_queue) == 3
     st.fakes[0].ready = True
     st.after_credit()
     # one reap -> one parked batch submitted into the freed slot
-    assert len(st._inflight) == 3
+    assert len(st._inflight) == depth
     assert len(st._submit_queue) == 2
-    assert len(st.fakes) == 4
+    assert len(st.fakes) == depth + 1
 
 
 def test_flush_drains_window_and_queue(txn_pool):
-    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
-                      max_inflight=3)
+    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256)
     _feed(st, txn_pool[:30])  # 7 full batches + a partial
     for f in st.fakes:
         f.ready = True
@@ -155,8 +186,7 @@ def test_flush_drains_window_and_queue(txn_pool):
 
 
 def test_deep_submit_queue_falls_back_to_blocking_drain(txn_pool):
-    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
-                      max_inflight=2)
+    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256)
     st._submit_queue_max = 2
     _feed(st, txn_pool[:40])  # 10 batches >> window + queue bound
     # the memory bound engaged: the blocking drain consumed heads, so
