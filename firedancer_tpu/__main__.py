@@ -1,7 +1,8 @@
 """fdctl-style CLI: `python -m firedancer_tpu <action>`.
 
 Mirrors the reference's action table (/root/reference/src/app/fdctl/
-main1.c: run / monitor / keys / configure / version, and fddev's bench):
+main1.c: run / monitor / keys / configure / version).  Measuring is not an
+action here: the benchmark is `python3 benchmarks/run.py` (BENCHMARK.json).
 
     run        build the leader pipeline from a TOML config and drive it
                (--processes: one supervised OS process per stage;
@@ -18,11 +19,9 @@ main1.c: run / monitor / keys / configure / version, and fddev's bench):
                (`chaos list`; `chaos run <scenario> --seed S`)
     configure  host setup stages: check | init (shm, fds, cpus, THP...)
     keys       new <path> | pubkey <path> — identity keypair management
-    bench      quick pipeline throughput measurement (bench.py has the
-               full headline benchmark)
     warmup     AOT-compile the sharded serving step for a mesh shape
                through the persistent compile cache (leader boot-time
-               obligation; `bench.py --multichip-serve` is the ladder)
+               obligation)
     genesis    create | show a genesis blob (+ faucet key)
     snapshot   inspect a snapshot archive
     ledger     show | ingest | replay a stored ledger (bank-hash checks)
@@ -176,19 +175,6 @@ def cmd_keys(args) -> int:
         print("malformed key file", file=sys.stderr)
         return 1
     print(b58_encode(ref.public_key(secret)))
-    return 0
-
-
-def cmd_bench(args) -> int:
-    from firedancer_tpu.utils.platform import select_device
-
-    platform, kind, count = select_device(args.cpu)
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    import bench as bench_mod
-
-    out = bench_mod.run_pipeline_bench(platform)
-    out["device"] = {"platform": platform, "kind": kind, "count": count}
-    print(json.dumps(out))
     return 0
 
 
@@ -525,10 +511,6 @@ def main(argv=None) -> int:
     keysp.add_argument("action", choices=["new", "pubkey"])
     keysp.add_argument("path")
 
-    benchp = sub.add_parser("bench", help="pipeline throughput bench")
-    benchp.add_argument("--cpu", action="store_true",
-                        help="explicit CPU run (default: require the TPU)")
-
     wup = sub.add_parser(
         "warmup",
         help="AOT-compile the sharded serving step (persistent cache)",
@@ -675,8 +657,6 @@ def _dispatch(args) -> int:
         return cmd_run(args)
     if args.cmd == "keys":
         return cmd_keys(args)
-    if args.cmd == "bench":
-        return cmd_bench(args)
     if args.cmd == "warmup":
         return cmd_warmup(args)
     if args.cmd == "config":

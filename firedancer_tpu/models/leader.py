@@ -124,9 +124,9 @@ class LeaderPipeline:
         # lingering Fseq/mcache numpy view pins the mmap, close() then
         # fails with BufferError, and at interpreter exit every
         # SharedMemory.__del__ retries and spews 'cannot close exported
-        # pointers exist' into whatever artifact tail captured stderr
-        # (the BENCH_r03-05 pollution).  Ordering is the fix: views die,
-        # THEN the mappings close, THEN the names unlink.
+        # pointers exist' into whatever captured stderr.  Ordering is
+        # the fix: views die, THEN the mappings close, THEN the names
+        # unlink.
         if hasattr(self.benchg, "sock"):
             self.benchg.close()  # socket ingress: fd + native client
         for s in self.stages:

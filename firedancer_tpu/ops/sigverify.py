@@ -256,10 +256,10 @@ def ed25519_verify_batch_split(msg, msg_len, sig, pubkey, *, max_msg_len):
 # -- the kernel ladder --------------------------------------------------------
 #
 # One registry for the generic-lane kernel choice (the verify stage's
-# `kernel=` knob, bench.py --kernel-ladder, and the dispatch-count
-# assertions in tests).  Every lane returns the SAME mask on the same
-# inputs — they all trace _verify_ok — and differs only in how many
-# compiled modules a batch dispatch enters:
+# `kernel=` knob and the dispatch-count assertions in tests).  Every
+# lane returns the SAME mask on the same inputs — they all trace
+# _verify_ok — and differs only in how many compiled modules a batch
+# dispatch enters:
 #
 #   fused    1 module  (mask + pad-lane mask + ok-count, the default)
 #   baseline 1 module  (mask only; pad masking/count fall to the host)

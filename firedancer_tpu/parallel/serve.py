@@ -24,7 +24,7 @@ counts, and the frag->shard assignment is deterministic (the router's
 `seq % n_shards`, carried by which per-shard ring a frag arrived on).
 
 Cold-start is a production concern (a leader that compiles for 2 minutes
-misses its slot — MULTICHIP_r05's 2m15s jit_step): the plane supports
+misses its slot): the plane supports
 AOT warmup (`warmup()` lowers+compiles before traffic arrives) and the
 persistent compilation cache (utils/platform.enable_compile_cache) so a
 warmed host's next process boots the step from cache in seconds.

@@ -121,14 +121,7 @@ class BenchGStage(Stage):
         # native ring lane: the whole sweep's frames in ONE crossing
         # (tsorig stamped in C++ — this stage is the stream's origin)
         buf, tbl = self._native_pool()
-        if self.ring_clock:
-            import time as _time
-
-            t0 = _time.perf_counter()
-            done = pub_pool(buf, tbl, len(self.pool), self._i, n)
-            self.ring_publish_s += _time.perf_counter() - t0
-        else:
-            done = pub_pool(buf, tbl, len(self.pool), self._i, n)
+        done = pub_pool(buf, tbl, len(self.pool), self._i, n)
         self._i += done
         if done:
             self.metrics.inc("txn_gen", done)

@@ -34,7 +34,6 @@ from firedancer_tpu.tango.rings import CNC_SIG_HALT, CNC_SIG_RUN, Cnc, MCache
 from firedancer_tpu.utils import metrics as fm
 from .autotune import OCC_EDGES
 
-_pc = time.perf_counter
 
 # tango.native, resolved lazily: stages must boot (and the Python lane
 # must run) in toolchain-less environments where the import-time .so
@@ -227,11 +226,6 @@ class Stage:
         # — never mid-poll, so a SIGKILL can never mark a frag consumed
         # whose downstream effects were not yet published
         self.safe_progress = False
-        # ring-cost instrument (bench.py): when enabled, poll/drain and
-        # publish time accumulate separately from stage compute
-        self.ring_clock = False
-        self.ring_poll_s = 0.0
-        self.ring_publish_s = 0.0
         # crc32, not builtin hash(): str hashing is salted per process
         # (PYTHONHASHSEED), and spawned children must derive the SAME
         # housekeeping phase for a given (name, seed) as the parent and
@@ -524,12 +518,7 @@ class Stage:
                 idx = (self._in_rr + k) % n_in
                 cons = self.ins[idx]
                 seq = cons.seq
-                if self.ring_clock:
-                    _t = _pc()
-                    res = cons.poll()
-                    self.ring_poll_s += _pc() - _t
-                else:
-                    res = cons.poll()
+                res = cons.poll()
                 if res == shm.POLL_EMPTY:
                     continue
                 if res == shm.POLL_OVERRUN:
@@ -621,10 +610,6 @@ class Stage:
         observation off the returned meta table."""
         max_frags = self.burst if self.burst > 0 else 1
         m = self.metrics
-        # the crossing fuses drain + stage compute + publish: its time
-        # is stage compute, not ring machinery — even under ring_clock
-        # it is NOT clocked into ring_poll_s (the A/B ring split stays
-        # honest)
         n, self._in_rr, d_ovr = drainer.sweep(self._in_rr, max_frags)
         if d_ovr:
             m.inc("overrun", d_ovr)
@@ -675,12 +660,7 @@ class Stage:
         if max_frags <= 0:
             return False
         m = self.metrics
-        if self.ring_clock:
-            _t = _pc()
-            n, self._in_rr, d_ovr = drainer.drain(self._in_rr, max_frags)
-            self.ring_poll_s += _pc() - _t
-        else:
-            n, self._in_rr, d_ovr = drainer.drain(self._in_rr, max_frags)
+        n, self._in_rr, d_ovr = drainer.drain(self._in_rr, max_frags)
         if d_ovr:
             m.inc("overrun", d_ovr)
             tot = m.get("overrun")
@@ -765,12 +745,7 @@ class Stage:
         if self._resume_guards and self._guarded(out_idx, sig):
             return True  # replay duplicate: already on the wire pre-crash
         p = self.outs[out_idx]
-        if self.ring_clock:
-            _t = _pc()
-            ok = p.try_publish(payload, sig=sig, tsorig=tsorig)
-            self.ring_publish_s += _pc() - _t
-        else:
-            ok = p.try_publish(payload, sig=sig, tsorig=tsorig)
+        ok = p.try_publish(payload, sig=sig, tsorig=tsorig)
         if ok:
             self.metrics.inc("frags_out")
         else:
@@ -806,12 +781,7 @@ class Stage:
         # 20): the crossing's duration observes into the stage's
         # publish-phase histogram from INSIDE C
         plane = self._native_plane() if burst is not None else None
-        if self.ring_clock:
-            _t = _pc()
-            n = self._publish_items(p, burst, items, plane)
-            self.ring_publish_s += _pc() - _t
-        else:
-            n = self._publish_items(p, burst, items, plane)
+        n = self._publish_items(p, burst, items, plane)
         if n:
             self.metrics.inc("frags_out", n)
         if n < len(items):

@@ -1062,7 +1062,6 @@ class VerifyStage(Stage):
             return
         c = self._sweep_client
         p = self.outs[0]
-        pc = time.perf_counter
         # the reap publishes OUTSIDE the sweep crossing: route the burst
         # through the metrics plane so its duration still lands in the
         # stage's publish-phase histogram (ISSUE 20)
@@ -1072,14 +1071,8 @@ class VerifyStage(Stage):
             slot, tbl, pos, life = ent
             sub = tbl[pos:]
             with self._span("verify.publish", life):
-                if self.ring_clock:
-                    _t = pc()
-                    done = p.publish_burst_raw(c.slots[slot].arena_ptr,
-                                               sub, len(sub), plane)
-                    self.ring_publish_s += pc() - _t
-                else:
-                    done = p.publish_burst_raw(c.slots[slot].arena_ptr,
-                                               sub, len(sub), plane)
+                done = p.publish_burst_raw(c.slots[slot].arena_ptr,
+                                           sub, len(sub), plane)
             if done:
                 self.metrics.inc("frags_out", done)
             ent[2] = pos + done

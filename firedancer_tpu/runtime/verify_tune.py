@@ -15,8 +15,7 @@ from a scraped snapshot matches what the live stage would pick.
 
 The stage applies a recommendation only at a quiet point (no open
 accumulator, no in-flight batches) and only when the autotune knob is
-on; bench.py --kernel-ladder records the recommendation alongside every
-capture so a future real-chip run can boot pre-tuned.
+on.
 """
 
 from __future__ import annotations

@@ -2,7 +2,7 @@
 
 One rule for choosing the device: the chip is the default and is
 required; the CPU is what a caller asks for.  Entry points that serve or
-measure (`run`, `bench`, `warmup`, bench.py, chip_smoke.py) call
+measure (`run`, `warmup`, benchmarks/run.py, chip_smoke.py) call
 `require_chip()` and fail when JAX resolves to anything but a TPU;
 tests, the chaos/cluster harnesses, the multi-chip dryrun and every
 non-verify child of the process topology call `force_cpu_backend()`

@@ -276,8 +276,8 @@ def test_chaos_cli_list_and_unknown(capsys):
 def test_pipeline_close_drops_every_shm_view():
     """LeaderPipeline.close() must leave every link's SharedMemory fully
     closed (fd gone, buffer released): a pinned view here is exactly the
-    'BufferError: cannot close exported pointers exist' spray that
-    polluted the BENCH_r03-05 artifact tails at interpreter exit."""
+    'BufferError: cannot close exported pointers exist' spray at
+    interpreter exit."""
     from firedancer_tpu.models.leader import build_leader_pipeline
 
     pipe = build_leader_pipeline(n_verify=1, n_bank=1, pool_size=4,

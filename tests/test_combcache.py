@@ -115,8 +115,8 @@ def test_bank_reinstall_overwrites_slot():
 
 def test_verify_stage_comb_path_end_to_end():
     """Stage-level: repeated signers promote into the device comb bank and
-    the cached lane produces the same accept/reject decisions (the
-    integration bench.py exercises on TPU; here on the CPU mesh)."""
+    the cached lane produces the same accept/reject decisions (on the
+    CPU mesh)."""
     import os as _os
     import time as _time
 

@@ -94,7 +94,8 @@ def test_sharded_process_topology_never_races_for_the_chip():
 
 @pytest.mark.parametrize("cmd", [
     ["chip_smoke.py"],
-    ["bench.py"],
+    ["benchmarks/run.py", "--workload", "verify-spam-flood", "--seed", "1",
+     "--seconds", "1"],
     ["-m", "firedancer_tpu", "run", "--txns", "8"],
 ])
 def test_entry_points_refuse_to_run_without_a_chip(cmd):
@@ -138,3 +139,28 @@ def test_chip_smoke_phase_a_on_cpu_at_tiny_size(tmp_path, capsys):
     assert all(ln["ok"] and ln["platform"] == "cpu" for ln in lines)
     assert lines[1]["txn_exec"] == 64 and lines[1]["verify_fail"] == 0
     assert lines[2]["txn_exec"] == 61 and lines[2]["verify_fail"] == 3
+
+
+def test_every_environment_switch_read_is_documented():
+    """The FDTPU_* names the program, its native code and its scripts
+    read are the names docs/OPERATIONS.md documents: a switch nobody can
+    find is a debt, and a documented one that nothing reads is a lie."""
+    import re
+
+    name = re.compile(r"FDTPU_[A-Z0-9_]+")
+    read = set()
+    files = [os.path.join(REPO, "chip_smoke.py"),
+             os.path.join(REPO, "__graft_entry__.py")]
+    for root in ("firedancer_tpu", "native", "scripts"):
+        for d, _, fs in os.walk(os.path.join(REPO, root)):
+            files += [os.path.join(d, f) for f in fs
+                      if f.endswith((".py", ".cpp", ".h", ".sh"))]
+    for f in files:
+        with open(f, encoding="utf-8") as fh:
+            read |= set(name.findall(fh.read()))
+    with open(os.path.join(REPO, "docs", "OPERATIONS.md"),
+              encoding="utf-8") as fh:
+        documented = set(name.findall(fh.read()))
+    assert read == documented, (sorted(read - documented),
+                                sorted(documented - read))
+    assert len(read) == 21  # a new switch is a decision, not a drift

@@ -321,7 +321,13 @@ def test_crash_loop_degrades_to_fail_fast_with_dump():
 def test_restart_covers_stale_heartbeat_too():
     """A frozen (SIGSTOP) stage trips the heartbeat watchdog; with a
     policy armed the wedged process is reaped and respawned in place
-    instead of killing the topology."""
+    instead of killing the topology.
+
+    The heartbeat timeout is also a respawn's boot grace
+    (_respawn_stage stamps the cnc; the child heartbeats only once it
+    runs), so it has to outlast a spawned child's imports — about a
+    second on an idle box, more beside five other test workers — or
+    each respawn is judged stale in turn and the budget runs out."""
     N = 4000
     h = ft.launch(_restart_topology(N))
     obs = shm.Consumer(h.links["rs"], fseq_idx=1, lazy=4)
@@ -341,7 +347,7 @@ def test_restart_covers_stale_heartbeat_too():
     try:
         ok = h.supervise(
             until=lambda hh: len(got) >= N, timeout_s=90,
-            heartbeat_timeout_s=1.0, on_poll=on_poll,
+            heartbeat_timeout_s=4.0, on_poll=on_poll,
             restart=RestartPolicy(max_restarts=2, backoff_base_s=0.02,
                                   seed=5))
         deadline = time.monotonic() + 3
@@ -353,7 +359,7 @@ def test_restart_covers_stale_heartbeat_too():
                 time.sleep(0.005)
         assert ok, f"supervise failed (failed={h.failed!r})"
         assert froze[0]
-        assert h.restarts.get("relay", 0) >= 1
+        assert set(h.restarts) == {"relay"}  # the frozen stage, no other
         assert got == list(range(N))
         h.halt()
     finally:
