@@ -93,37 +93,51 @@ def ed25519_verify_batch(
     return _verify_ok(msg, msg_len, sig, pubkey, max_msg_len=max_msg_len)
 
 
+# -- the stage's program: packed rows in, the mask out -------------------------
+#
+# A batch crosses the host-device boundary once each way.  Going in it is
+# ONE (B, row_width) uint8 array, element e in row e:
+#
+#     msg[max_msg_len] (zero past msg_len) | sig[64] | pk[32] | msg_len u32 LE
+#
+# — the native intake fills slots of exactly these rows
+# (native/fd_verify.cpp; runtime/verify_native mirrors the offsets, fdlint
+# FD305) and the Python lane's _assemble builds the same — and coming back
+# it is the (B,) bool mask.  The offsets count from the end of the message.
+
+ROW_SIG_OFF = 0
+ROW_PK_OFF = 64
+ROW_LEN_OFF = 96
+ROW_TAIL = 100
+
+
+def unpack_rows(rows: jnp.ndarray, *, max_msg_len: int):
+    """(B, max_msg_len + ROW_TAIL) uint8 packed rows -> the byte-row
+    arrays _verify_ok takes: msg (max_msg_len, B), msg_len (B,) int32,
+    sig (64, B), pubkey (32, B).  Traced: one transpose and four slices
+    on the device."""
+    t = rows.T
+    tail = t[max_msg_len:]
+    ln = tail[ROW_LEN_OFF:ROW_LEN_OFF + 4].astype(jnp.int32)
+    msg_len = ln[0] | (ln[1] << 8) | (ln[2] << 16) | (ln[3] << 24)
+    return (t[:max_msg_len], msg_len, tail[ROW_SIG_OFF:ROW_SIG_OFF + 64],
+            tail[ROW_PK_OFF:ROW_PK_OFF + 32])
+
+
+_unpack_rows = jax.jit(unpack_rows, static_argnames=("max_msg_len",))
+
+
 @functools.partial(jax.jit, static_argnames=("max_msg_len",))
-def ed25519_verify_batch_fused(
-    msg: jnp.ndarray,
-    msg_len: jnp.ndarray,
-    sig: jnp.ndarray,
-    pubkey: jnp.ndarray,
-    n_real: jnp.ndarray,
-    *,
-    max_msg_len: int,
-):
-    """The generic-lane serving program (ISSUE 13): the WHOLE per-batch
-    device computation — validate + sha512 + double-scalar-mult +
-    compare, plus the pad-lane mask and the batch ok-count — in ONE
-    compiled module, one dispatch per batch.
-
-    Replaces the four-phase split chain (and the baseline kernel + host
-    mask arithmetic) as the verify stage's default path: the split
-    pipeline pays three inter-phase HBM round trips and four dispatch
-    latencies per batch; here XLA fuses everything and the stage's reap
-    point reads `n_ok == n_real` to take the common all-pass fast path
-    without scanning the mask.
-
-    n_real: scalar int32 — lanes >= n_real are padding and come back
-    False (or a (B,) int32 vector of such limits, one a lane: lane p is
-    real where n_real[p] > p).  Returns ((B,) bool mask, scalar int32
-    ok-count over the real lanes).
-    """
-    ok = _verify_ok(msg, msg_len, sig, pubkey, max_msg_len=max_msg_len)
-    lane = jnp.arange(ok.shape[0], dtype=jnp.int32)
-    ok = ok & (lane < n_real)
-    return ok, jnp.sum(ok.astype(jnp.int32))
+def ed25519_verify_batch_fused(rows: jnp.ndarray, *,
+                               max_msg_len: int) -> jnp.ndarray:
+    """The generic-lane serving program: the WHOLE per-batch device
+    computation — unpack the packed rows, validate + sha512 +
+    double-scalar-mult + compare — in ONE compiled module, one dispatch
+    per batch, one array in and one back: the (B,) bool mask.  Pad rows
+    come back as whatever _verify_ok says of them (zeros, or an earlier
+    batch's bytes); the stage's reap reads the real lanes only."""
+    return _verify_ok(*unpack_rows(rows, max_msg_len=max_msg_len),
+                      max_msg_len=max_msg_len)
 
 
 # -- repeated-signer fast path ------------------------------------------------
@@ -257,13 +271,14 @@ def ed25519_verify_batch_split(msg, msg_len, sig, pubkey, *, max_msg_len):
 #
 # One registry for the generic-lane kernel choice (the verify stage's
 # `kernel=` knob and the dispatch-count assertions in tests).  Every
-# lane returns the SAME mask on the same inputs — they all trace
-# _verify_ok — and differs only in how many compiled modules a batch
-# dispatch enters:
+# lane takes the SAME packed rows and returns the SAME mask — they all
+# trace _verify_ok — and differs only in how many compiled modules a
+# batch dispatch enters:
 #
-#   fused    1 module  (mask + pad-lane mask + ok-count, the default)
-#   baseline 1 module  (mask only; pad masking/count fall to the host)
-#   split    4 modules (A/B reference: the cost of the phase boundaries)
+#   fused    1 module  (the default: the stage's program, unpack included)
+#   baseline 2 modules (the on-device unpack, then the library's kernel)
+#   split    5 modules (the unpack, then the four phases: the cost of
+#                       the phase boundaries)
 
 KERNEL_LADDER = ("fused", "baseline", "split")
 
@@ -272,8 +287,9 @@ KERNEL_LADDER = ("fused", "baseline", "split")
 # summing _cache_size() over a row counts its live compiled entries
 _KERNEL_JITS = {
     "fused": (ed25519_verify_batch_fused,),
-    "baseline": (ed25519_verify_batch,),
-    "split": (_phase_validate, _phase_hash, _phase_dsm, _phase_compare),
+    "baseline": (_unpack_rows, ed25519_verify_batch),
+    "split": (_unpack_rows, _phase_validate, _phase_hash, _phase_dsm,
+              _phase_compare),
 }
 
 
@@ -297,38 +313,19 @@ def kernel_clear_caches(kernel: str) -> None:
         f.clear_cache()
 
 
-def verify_dispatch(kernel: str, msg, msg_len, sig, pubkey, n_real,
-                    *, max_msg_len: int):
-    """Dispatch one batch on the chosen ladder lane.
-
-    n_real: how many leading lanes are real (an int), or, where the real
-    lanes are no prefix (a mesh dealt round-robin), a placed (B,) int32
-    lane vector with a value above p in lane p where it is real.
-
-    Returns (mask future, ok-count future | None): only the fused lane
-    computes the count on device; callers fall back to host mask
-    arithmetic when it is None.  Pad-lane masking is on-device for the
-    fused lane and the caller's job otherwise (the stage ignores lanes
-    >= n_real when reaping, so the masks agree on every REAL lane)."""
+def verify_dispatch(kernel: str, rows, *, max_msg_len: int):
+    """Dispatch one batch of packed rows (on the device already) on the
+    chosen ladder lane -> the (B,) bool mask future.  The fused lane is
+    one module; the two A/B references unpack on the device first and
+    take the same four arrays they always did.  No lane masks pad
+    lanes: the stage ignores lanes past its fill when reaping, so the
+    masks agree on every REAL lane."""
     if kernel == "fused":
-        import jax.numpy as _jnp
-
-        if getattr(n_real, "ndim", 0) == 0:
-            n_real = _jnp.int32(n_real)
-        return ed25519_verify_batch_fused(
-            msg, msg_len, sig, pubkey, n_real, max_msg_len=max_msg_len,
-        )
-    if kernel == "baseline":
-        return (
-            ed25519_verify_batch(msg, msg_len, sig, pubkey,
-                                 max_msg_len=max_msg_len),
-            None,
-        )
-    if kernel == "split":
-        return (
-            ed25519_verify_batch_split(msg, msg_len, sig, pubkey,
-                                       max_msg_len=max_msg_len),
-            None,
-        )
-    raise ValueError(f"unknown verify kernel {kernel!r} "
-                     f"(ladder: {', '.join(KERNEL_LADDER)})")
+        return ed25519_verify_batch_fused(rows, max_msg_len=max_msg_len)
+    if kernel not in KERNEL_LADDER:
+        raise ValueError(f"unknown verify kernel {kernel!r} "
+                         f"(ladder: {', '.join(KERNEL_LADDER)})")
+    lane = (ed25519_verify_batch if kernel == "baseline"
+            else ed25519_verify_batch_split)
+    return lane(*_unpack_rows(rows, max_msg_len=max_msg_len),
+                max_msg_len=max_msg_len)

@@ -18,6 +18,14 @@ accumulate into fixed-shape device batches, and a window of them stays
 in flight so host streaming overlaps device compute.  Fixed shapes mean
 partial batches are padded and the pad lanes' results ignored.
 
+A batch crosses the host-device boundary once each way: going in it is
+ONE (batch, row_width) uint8 array of packed rows — element e's message,
+signature, signer and message length in row e (ops/sigverify.unpack_rows
+has the layout; the native intake fills slots of exactly these rows, the
+Python lane's _assemble builds the same), one `device_put` of the slot's
+own memory — and coming back it is the program's one output, the (batch,)
+bool mask, fetched once at the reap, which counts the passes itself.
+
 How deep the window is (one test, `_window_has_room`, on the native lane,
 the Python lane and the sharded stage alike): WINDOW_DEPTH, two — one
 batch running and one queued behind it, which keeps the device back to
@@ -48,9 +56,9 @@ one-axis mesh over the first n local devices, the native intake seals
 slots of the whole fixed shape (n x batch // n lanes), and the dispatch
 deals the slot's elements round-robin — element e of a step goes to
 chip e % n, as the reference's N verify tiles each take seq % N — by
-placing each of the slot's four arrays straight onto its shards, and
-runs the same program over the mesh: one module a step, the ok-count's
-sum its only collective.  Reap, publish, the close rule, the stamps and
+placing rows i, i + n, ... of the slot's packed array straight onto chip
+i, and runs the same program over the mesh: one module a step, no
+collective in it.  Reap, publish, the close rule, the stamps and
 the counters are the one-device lane's; `shard_elems_s{i}` counts the
 useful lanes chip i was given.
 
@@ -85,6 +93,7 @@ from firedancer_tpu.protocol import txn as ft
 from firedancer_tpu.tango.rings import MCache, TCache
 from firedancer_tpu.utils import metrics as fm
 from .stage import Stage
+from . import verify_native as vn
 from .verify_native import CLOSE_DEADLINE, CLOSE_FULL, CLOSE_WINDOW
 
 # the per-packet parse is this stage's host hot path: prefer the native
@@ -147,9 +156,9 @@ VERIFY_TCACHE_DEPTH = 16  # tiny by design (fd_verify.h:6-7)
 COMB_FILL_BATCH = 32  # pubkeys per comb_fill dispatch (fixed jit shape)
 
 # the generic-lane kernel ladder (ops/sigverify.KERNEL_LADDER): fused is
-# the default — ONE compiled module per batch (validate + sha512 + dsm +
-# compare + pad mask + ok-count); split and baseline are A/B references
-# only, nothing falls back to them
+# the default — ONE compiled module per batch (unpack + validate + sha512
+# + dsm + compare); split and baseline are A/B references only, nothing
+# falls back to them
 VERIFY_KERNELS = ("fused", "baseline", "split")
 DEFAULT_KERNEL = os.environ.get("FDTPU_VERIFY_KERNEL", "fused")
 
@@ -208,10 +217,6 @@ class _Pending:
     tsorigs: list[int]
     n_elems: int
     result: object  # jax array future
-    # fused-lane rider: the on-device ok-count over real lanes (None on
-    # the baseline/split/cached/plane lanes — the reap falls back to
-    # host mask arithmetic)
-    n_ok: object = None
     # the batch's stamps (None where a subclass builds its own pending)
     life: _Life | None = None
 
@@ -285,10 +290,10 @@ class VerifyStage(Stage):
         # seq % N), so the chips fill evenly at any fill; every generic
         # batch is placed straight onto its shards and verified by the
         # SAME program (ops/sigverify.verify_dispatch), one compiled
-        # module a step over the mesh.  The lane shardings are the
-        # serving plane's (parallel/mesh.batch_sharding): P(None, axis)
-        # byte rows, P(axis) lane vectors.
-        self._lane_shardings = None
+        # module a step over the mesh.  The packed rows are sharded by
+        # row, P(axis, None), over the serving plane's mesh axis
+        # (parallel/mesh.AXIS); the mask comes back P(axis).
+        self._row_sharding = None
         self.mesh_devices = 1
         if devices is not None and devices != 1:
             if plane is not None or comb_slots:
@@ -299,15 +304,14 @@ class VerifyStage(Stage):
                 raise ValueError(
                     f"verify batch {batch} does not divide over"
                     f" {devices} devices")
+            from jax.sharding import NamedSharding, PartitionSpec
+
             from firedancer_tpu.parallel import mesh as pm
 
             self.mesh_devices = devices
-            self._lane_shardings = pm.batch_sharding(pm.make_mesh(devices))
+            self._row_sharding = NamedSharding(
+                pm.make_mesh(devices), PartitionSpec(pm.AXIS, None))
             self._use_shard_schema(devices)
-            # lane p = i * per + a (chip i's a-th) holds element a * n + i
-            lane = np.arange(batch, dtype=np.int32)
-            per = batch // devices
-            self._elem_of_lane = (lane % per) * devices + lane // per
         self.shard_idx = shard_idx
         self.shard_cnt = shard_cnt
         self.batch = batch
@@ -375,7 +379,7 @@ class VerifyStage(Stage):
         # for exact VerifyStage instances, False = never, True =
         # required (raises, naming what blocked it).
         self._sweep_client = None
-        # (slot, n_elems, n_txn, result, n_ok, life)
+        # (slot, n_elems, n_txn, result, life)
         self._nv_inflight: list = []
         self._nv_emit: list = []  # [slot, frame table, published idx, life]
         # the open batch (named by its C-side open stamp) that was seen
@@ -401,8 +405,6 @@ class VerifyStage(Stage):
                 blocker = (f"out link mtu {self.outs[0].link.mtu} <"
                            f" {_NATIVE_FRAME_MTU} (frame headroom)")
             if blocker is None:
-                from . import verify_native as vn
-
                 try:
                     if not vn.available():
                         raise vn.NativeUnavailable(
@@ -460,8 +462,8 @@ class VerifyStage(Stage):
                      "sealed -> the dispatch call begins (waiting for a"
                      " window slot, or for the thread to come back)")
             .counter("batch_h2d_ns",
-                     "the host->device copies of the dispatch (on the"
-                     " Python lane the byte-row assembly before them too)")
+                     "the host->device copy of the dispatch (on the"
+                     " Python lane the packed-row assembly before it too)")
             .counter("batch_launch_ns",
                      "the kernel dispatch call, copies excluded")
             .counter("batch_inflight_ns",
@@ -469,7 +471,7 @@ class VerifyStage(Stage):
                      " ready (device queue + execution + how late the"
                      " thread looked)")
             .counter("batch_reap_ns",
-                     "mask and ok-count fetched, per-txn reduction")
+                     "mask fetched, per-txn reduction")
             .counter("batch_publish_ns",
                      "reaped -> the batch's last frame out and its slot"
                      " released (credit waits included)")
@@ -815,16 +817,10 @@ class VerifyStage(Stage):
         from firedancer_tpu.ops import sigverify as sv
 
         t0 = time.monotonic()
-        b = self.batch
-        mask, _ = sv.verify_dispatch(
-            self.kernel,
-            *self._place(np.zeros((self.max_msg_len, b), dtype=np.uint8),
-                         np.zeros((b,), dtype=np.int32),
-                         np.zeros((64, b), dtype=np.uint8),
-                         np.zeros((32, b), dtype=np.uint8), 0),
-            max_msg_len=self.max_msg_len,
-        )
-        mask.block_until_ready()
+        rows = np.zeros((self.batch, vn.row_width(self.max_msg_len)),
+                        dtype=np.uint8)
+        sv.verify_dispatch(self.kernel, self._place(rows),
+                           max_msg_len=self.max_msg_len).block_until_ready()
         return time.monotonic() - t0
 
     def _mask_ready(self, result) -> bool:
@@ -885,64 +881,51 @@ class VerifyStage(Stage):
         if life is not None:
             life.seq = self.metrics.get("batches") + 1
 
-    def _place(self, msg, ln, sig, pk, n: int) -> tuple:
-        """One batch's host arrays (element e in column e, `n` of them
-        real) onto the device(s) -> the program's five arguments.  One
-        device: four copies to the default device and `n` itself.  A
-        mesh of d devices: chip i is dealt columns i, i + d, ... — each
-        array goes straight onto its shards, one copy per array per
-        device, of a strided view of that device's columns only
-        (wrapping in jnp.asarray first would commit the whole batch to
-        device 0 and then reshard it) — and, the real lanes being no
-        prefix then, `n` goes as a lane vector placed with them: lane p
-        holds a value above p where its element is real (the program's
-        `lane < n_real`, elementwise).  uint8 byte rows: 4x less
-        host->device transfer; the kernel widens to int32 on-device."""
-        if self._lane_shardings is None:
-            import jax.numpy as jnp
-
-            return (jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
-                    jnp.asarray(pk), n)
+    def _place(self, rows: np.ndarray):
+        """One batch's packed rows (element e in row e) onto the
+        device(s) -> the program's one argument.  One device: one
+        `device_put` of the contiguous array as it lies (the native
+        lane's is the slot's own memory, not written again until the
+        reap releases the slot) — no transpose, no host copy of ours.
+        A mesh of d devices: chip i is dealt rows i, i + d, ... — one
+        row-strided view per chip straight onto its shard (wrapping in
+        jnp.asarray first would commit the whole batch to device 0 and
+        then reshard it).  uint8: 4x less transfer; the program widens
+        to int32 on the device."""
         import jax
 
+        if self._row_sharding is None:
+            return jax.device_put(rows)
         d = self.mesh_devices
         per = self.batch // d
-        rows, vec = self._lane_shardings
-
-        def dealt(host, sharding):
-            return jax.make_array_from_callback(
-                host.shape, sharding,
-                lambda idx: host[..., idx[-1].start // per::d])
-
-        real = np.where(self._elem_of_lane < n, np.int32(self.batch),
-                        np.int32(0))
-        return (dealt(msg, rows), dealt(ln, vec), dealt(sig, rows),
-                dealt(pk, rows), jax.device_put(real, vec))
+        return jax.make_array_from_callback(
+            rows.shape, self._row_sharding,
+            lambda idx: rows[idx[0].start // per::d])
 
     def _mask_of(self, result) -> np.ndarray:
         """A dispatched batch's mask on the host, element e at index e
         (over a mesh the lanes come back chip by chip: dealt back; the
         all-pass mask never left the host)."""
         mask = np.asarray(result)
-        if self._lane_shardings is None or mask is result:
+        if self._row_sharding is None or mask is result:
             return mask
         return mask.reshape(self.mesh_devices, -1).T.reshape(-1)
 
-    def _device_verify(self, life: _Life | None, msg, ln, sig, pk, n: int):
-        """The kernel-ladder dispatch of one batch's byte rows: the
-        host->device copies of its arrays (_place: to the default
-        device, or to each mesh device the columns it is dealt; the end
-        of the batch's h2d phase), then the kernel call (fused by
-        default: one compiled module per batch — over a mesh one module
-        a step, the same program partitioned by its arguments'
-        shardings — pad lanes masked + ok-count computed on device); the
-        caller ends the launch phase.  -> (mask future, ok-count future
-        | None); the mask is read through _mask_of."""
+    def _device_verify(self, life: _Life | None, rows: np.ndarray):
+        """The kernel-ladder dispatch of one batch's packed rows, the
+        ONE signature of both lanes: the host->device copy (_place: to
+        the default device, or to each mesh device the rows it is
+        dealt; the end of the batch's h2d phase), then the kernel call
+        (fused by default: one compiled module per batch — over a mesh
+        one module a step, the same program partitioned by its
+        argument's sharding, no collective in it); the caller ends the
+        launch phase.  -> the mask future, read through _mask_of; pad
+        lanes say nothing, the reap reads the real ones."""
         from firedancer_tpu.ops import sigverify as sv
 
-        dev = self._place(msg, ln, sig, pk, n)
+        dev = self._place(rows)
         self._phase_end(life, PH_H2D)
-        return sv.verify_dispatch(self.kernel, *dev,
+        return sv.verify_dispatch(self.kernel, dev,
                                   max_msg_len=self.max_msg_len)
 
     def _count_dispatch(self, n: int, close: int, occupancy: int) -> None:
@@ -1004,20 +987,18 @@ class VerifyStage(Stage):
         self._phase_end(life, PH_OPEN, sealed_ns)
         self._dispatch_begins(life)
         if self.precomputed_ok:
-            result, n_ok = np.ones((n_elems,), dtype=bool), None
+            result = np.ones((n_elems,), dtype=bool)
         else:
             with self._span("verify.dispatch", life):
-                result, n_ok = self._device_verify(
-                    life, views.msg.T, views.ln, views.sig.T, views.pk.T,
-                    n_elems)
+                result = self._device_verify(life, views.rows)
         self._phase_end(life, PH_LAUNCH)
-        self._nv_inflight.append((slot, n_elems, n_txn, result, n_ok, life))
+        self._nv_inflight.append((slot, n_elems, n_txn, result, life))
         self._count_dispatch(n_elems, close, len(self._nv_inflight))
 
     def _nv_drain(self, block: bool) -> None:
         c = self._sweep_client
         while self._nv_inflight:
-            slot, n_elems, n_txn, result, n_ok, life = self._nv_inflight[0]
+            slot, n_elems, n_txn, result, life = self._nv_inflight[0]
             if not block and not self._mask_ready(result):
                 return
             self._phase_end(life, PH_INFLIGHT)
@@ -1027,11 +1008,7 @@ class VerifyStage(Stage):
                 self.trace(fm.EV_BATCH_COMPLETE, n_elems)
                 views = c.slots[slot]
                 frames = views.frames[:n_txn]
-                if n_ok is not None:
-                    all_ok = int(n_ok) == n_elems
-                else:
-                    all_ok = bool(mask[:n_elems].all())
-                if all_ok:
+                if mask[:n_elems].all():
                     tbl = frames
                     kept = n_txn
                 else:
@@ -1202,10 +1179,10 @@ class VerifyStage(Stage):
         life = acc.life
         self._dispatch_begins(life)
         if self.precomputed_ok:
-            result, n_ok = np.ones((n,), dtype=bool), None
+            result = np.ones((n,), dtype=bool)
         else:
             with self._span("verify.dispatch", life):
-                result, n_ok = self._dispatch(acc, cached)
+                result = self._dispatch(acc, cached)
         self._phase_end(life, PH_LAUNCH)
         self._inflight.append(
             _Pending(
@@ -1215,7 +1192,6 @@ class VerifyStage(Stage):
                 tsorigs=acc.tsorigs,
                 n_elems=n,
                 result=result,
-                n_ok=n_ok,
                 life=life,
             )
         )
@@ -1223,61 +1199,55 @@ class VerifyStage(Stage):
         if cached:
             self.metrics.inc("comb_elems", n)
 
-    def _assemble(self, acc: _Acc):
-        """elems -> device-shaped uint8 byte-row arrays.
+    def _assemble(self, acc: _Acc) -> np.ndarray:
+        """elems -> the batch's packed rows, (batch, row_width) uint8,
+        byte for byte what the native intake writes into a slot
+        (verify_native has the layout); pad rows are zero.
 
         Batched assembly: one bytes-join + frombuffer + reshape per
-        field instead of 4 numpy calls per ELEMENT — the per-element
-        loop measured ~100K elems/s on one core (scripts/
-        perf_verify_host.py), an order of magnitude under the 2M/s
-        target; the joined form is C-speed throughout.
+        field instead of 4 numpy calls per ELEMENT — the joined form is
+        C-speed throughout.
         """
         n = len(acc.elems)
-        b = self.batch
         mm = self.max_msg_len
         msgs, sigs, pks = zip(*acc.elems)
-        ln = np.zeros((b,), dtype=np.int32)
-        ln[:n] = np.fromiter(map(len, msgs), dtype=np.int32, count=n)
-        msg = np.zeros((b, mm), dtype=np.uint8)
         joined = b"".join(m if len(m) == mm else m.ljust(mm, b"\x00")
                           for m in msgs)
-        msg[:n] = np.frombuffer(joined, dtype=np.uint8).reshape(n, mm)
-        sig = np.zeros((b, 64), dtype=np.uint8)
-        sig[:n] = np.frombuffer(b"".join(sigs), dtype=np.uint8
-                                ).reshape(n, 64)
-        pk = np.zeros((b, 32), dtype=np.uint8)
-        pk[:n] = np.frombuffer(b"".join(pks), dtype=np.uint8).reshape(n, 32)
-        # kernels take byte ROWS (len, batch): transpose the packed form
-        return msg.T, ln, sig.T, pk.T
+        return vn.pack_rows(
+            np.frombuffer(joined, dtype=np.uint8).reshape(n, mm),
+            np.fromiter(map(len, msgs), dtype=np.int32, count=n),
+            np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64),
+            np.frombuffer(b"".join(pks), dtype=np.uint8).reshape(n, 32),
+            self.batch)
 
     def _dispatch(self, acc: _Acc, cached: bool):
-        """-> (mask future, ok-count future | None).  Ends the batch's
-        h2d phase once its arrays are on the device; the byte-row
-        assembly counts into it on this lane (the native lane assembles
-        in C, at intake)."""
+        """-> the mask future.  Ends the batch's h2d phase once its
+        rows are on the device; the packed-row assembly counts into it
+        on this lane (the native lane assembles in C, at intake)."""
         n = len(acc.elems)
-        b = self.batch
         life = acc.life
-        msg, ln, sig, pk = self._assemble(acc)
+        rows = self._assemble(acc)
         if not cached and self.plane is None:
-            return self._device_verify(life, msg, ln, sig, pk, n)
+            return self._device_verify(life, rows)
+        # the serving plane's step and the comb lane's kernel take the
+        # four byte-row arrays (len, batch) and make their own copies
+        msg, ln, sig, pk = vn.byte_rows(rows, self.max_msg_len)
         if not cached:
             # mesh route: the sharded serving step (pad lanes beyond n
-            # are masked by the step itself via the per-shard fills),
-            # which makes its own copies
+            # are masked by the step itself via the per-shard fills)
             self._phase_end(life, PH_H2D)
-            return self.plane.verify_batch(msg, ln, sig, pk), None
+            return self.plane.verify_batch(msg, ln, sig, pk)
         import jax.numpy as jnp
 
         from firedancer_tpu.ops import sigverify as sv
 
-        slots = np.zeros((b,), dtype=np.int32)
+        slots = np.zeros((self.batch,), dtype=np.int32)
         slots[:n] = acc.slots
         dev = (jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
                jnp.asarray(pk), self._bank, jnp.asarray(slots))
         self._phase_end(life, PH_H2D)
         return sv.ed25519_verify_batch_cached(
-            *dev, max_msg_len=self.max_msg_len), None
+            *dev, max_msg_len=self.max_msg_len)
 
     # result-extraction hooks: the sharded serving stage (parallel/serve.
     # ShardedVerifyStage) reuses THIS drain loop — the txn-level
@@ -1317,14 +1287,10 @@ class VerifyStage(Stage):
         self._pump_submits()
         self.trace(fm.EV_BATCH_COMPLETE, head.n_elems)
         # honest traffic overwhelmingly passes whole batches: one
-        # all-reduce decides the common case instead of a numpy
-        # slice + reduction per txn (~1.5us/txn of the host path).
-        # The fused lane computed the count on device — the reap
-        # reads one scalar instead of scanning the mask.
-        if head.n_ok is not None:
-            all_ok = int(head.n_ok) == head.n_elems
-        else:
-            all_ok = bool(mask[: head.n_elems].all())
+        # reduction over the fetched mask's real lanes decides the
+        # common case instead of a numpy slice + reduction per txn
+        # (~1.5us/txn of the host path)
+        all_ok = bool(mask[: head.n_elems].all())
         emits = []
         for payload, desc, (a, b), tsorig in zip(
             head.payloads, head.descs, head.elem_ranges, head.tsorigs

@@ -6,9 +6,11 @@ frag callback — shard filter, fd_txn_parse (function pointer into
 fd_txn_parse.so, the fd_pack/fd_shred precedent), tcache dedup, the
 msg-length/fit guards, and fixed-shape batch assembly into a ring of
 reusable slot buffers — with zero Python per frag.  Python touches the
-pipeline at BATCH granularity only: dispatch a sealed slot's numpy
-views to the device kernel, and publish the reaped frames straight from
-the slot's preassembled frame arena (one fdr_publish_burst crossing).
+pipeline at BATCH granularity only: hand a sealed slot's packed rows —
+one contiguous (batch, row_width) uint8 view of the slot's own memory,
+the one array the device program takes — to the device, and publish the
+reaped frames straight from the slot's preassembled frame arena (one
+fdr_publish_burst crossing).
 
 `FDTPU_NATIVE_VERIFY=0` disables the lane; a missing toolchain (or a
 missing fd_txn_parse.so) degrades to the Python intake path via
@@ -67,9 +69,8 @@ def _load():
         for name in ("fdv_meta_ptr", "fdv_counters_ptr"):
             getattr(lib, name).argtypes = [vp]
             getattr(lib, name).restype = vp
-        for name in ("fdv_slot_msg", "fdv_slot_ln", "fdv_slot_sig",
-                     "fdv_slot_pk", "fdv_slot_frames", "fdv_slot_ranges",
-                     "fdv_slot_arena"):
+        for name in ("fdv_slot_rows", "fdv_slot_frames",
+                     "fdv_slot_ranges", "fdv_slot_arena"):
             getattr(lib, name).argtypes = [vp, u64]
             getattr(lib, name).restype = vp
         _lib = lib
@@ -117,25 +118,88 @@ _TAIL_COUNTERS = 3
 # (state, n_elems, n_txn, arena_off, opened_ns, sealed_ns, close) per slot
 _META_NCOL = 7
 
+# One element's packed row (fd_verify.cpp fills it at intake; the ONE
+# array a batch sends to the device):
+#   msg[max_msg_len] (zero past msg_len) | sig[64] | pk[32] | msg_len u32 LE
+# offsets from the end of the message, and what a row holds past it
+ROW_SIG_OFF = 0
+ROW_PK_OFF = 64
+ROW_LEN_OFF = 96
+ROW_TAIL = 100
+
+
+def row_width(max_msg_len: int) -> int:
+    """Bytes in one element's packed row."""
+    return max_msg_len + ROW_TAIL
+
+
+def row_lens(rows: np.ndarray, max_msg_len: int) -> np.ndarray:
+    """The msg_len column of packed rows: a strided '<i4' view, no copy."""
+    off = max_msg_len + ROW_LEN_OFF
+    return rows[:, off:off + 4].view("<i4")[:, 0]
+
+
+def byte_rows(rows: np.ndarray, max_msg_len: int):
+    """Packed rows -> (msg (max_msg_len, B), msg_len (B,), sig (64, B),
+    pk (32, B)): strided views, no copy — the four byte-row arrays of
+    the kernels that do not take packed rows (the comb lane, the
+    serving plane), which make their own copies."""
+    tail = rows[:, max_msg_len:].T
+    return (rows[:, :max_msg_len].T, row_lens(rows, max_msg_len),
+            tail[ROW_SIG_OFF:ROW_SIG_OFF + 64],
+            tail[ROW_PK_OFF:ROW_PK_OFF + 32])
+
+
+def pack_rows(msg: np.ndarray, ln, sig: np.ndarray, pk: np.ndarray,
+              batch: int | None = None) -> np.ndarray:
+    """n elements, one per ROW of each array (msg (n, max_msg_len) zero
+    past its length, ln (n,), sig (n, 64), pk (n, 32)) -> (batch or n,
+    row_width) packed rows, the rest zero: byte for byte what the
+    native intake writes for the same elements."""
+    n, mm = msg.shape
+    rows = np.zeros((batch or n, row_width(mm)), dtype=np.uint8)
+    rows[:n, :mm] = msg
+    tail = rows[:n, mm:]
+    tail[:, ROW_SIG_OFF:ROW_SIG_OFF + 64] = sig
+    tail[:, ROW_PK_OFF:ROW_PK_OFF + 32] = pk
+    row_lens(rows, mm)[:n] = ln
+    return rows
+
+
+class _Owner:
+    """The C stage and every buffer behind it, freed when the client
+    AND the last numpy view over them are gone: a slot's rows go to the
+    device as they lie (`jax.device_put` of the view, an asynchronous
+    copy that keeps the view alive, not the memory under it), so the
+    memory has to outlive a stage that is dropped with a copy in
+    flight."""
+
+    def __init__(self, lib, h):
+        self._lib, self.h = lib, h
+
+    def __del__(self):
+        self._lib.fdv_stage_delete(self.h)
+
 
 class _SlotViews:
-    """Zero-copy numpy views over one slot's C buffers, built once."""
+    """Zero-copy numpy views over one slot's C buffers, built once;
+    each keeps the stage's memory alive (_Owner)."""
 
-    def __init__(self, lib, h, i: int, batch: int, mml: int):
+    def __init__(self, lib, owner: _Owner, i: int, batch: int, mml: int):
+        h = owner.h
+
         def view(ptr, n, dt):
             ct = (ctypes.c_uint8 * n) if dt == np.uint8 else \
                  (ctypes.c_uint32 * n) if dt == np.uint32 else \
-                 (ctypes.c_int32 * n) if dt == np.int32 else \
                  (ctypes.c_uint64 * n)
-            return np.frombuffer(ct.from_address(ptr), dtype=dt)
+            buf = ct.from_address(ptr)
+            buf._owner = owner
+            return np.frombuffer(buf, dtype=dt)
 
-        self.msg = view(lib.fdv_slot_msg(h, i), batch * mml,
-                        np.uint8).reshape(batch, mml)
-        self.ln = view(lib.fdv_slot_ln(h, i), batch, np.int32)
-        self.sig = view(lib.fdv_slot_sig(h, i), batch * 64,
-                        np.uint8).reshape(batch, 64)
-        self.pk = view(lib.fdv_slot_pk(h, i), batch * 32,
-                       np.uint8).reshape(batch, 32)
+        w = row_width(mml)
+        self.rows = view(lib.fdv_slot_rows(h, i), batch * w,
+                         np.uint8).reshape(batch, w)
+        self.ln = row_lens(self.rows, mml)  # the msg_len observe
         self.frames = view(lib.fdv_slot_frames(h, i), batch * 4,
                            np.uint64).reshape(batch, 4)
         self.ranges = view(lib.fdv_slot_ranges(h, i), batch * 2,
@@ -162,6 +226,7 @@ class StageClient:
                                     max_msg_len, n_slots, _parse_fn())
         if not self._h:
             raise NativeUnavailable("fdv_stage_new failed")
+        owner = _Owner(lib, self._h)
         self.cb = ctypes.cast(lib.fdv_frag_cb, ctypes.c_void_p)
         self.cb_ctx = ctypes.c_void_p(self._h)
         self.meta = np.frombuffer(
@@ -175,7 +240,7 @@ class StageClient:
                 int(lib.fdv_counters_ptr(self._h))),
             dtype=np.uint64,
         )
-        self.slots = [_SlotViews(lib, self._h, i, batch, max_msg_len)
+        self.slots = [_SlotViews(lib, owner, i, batch, max_msg_len)
                       for i in range(n_slots)]
         self._next_dispatch = 0  # cyclic = the C acquire order
 
@@ -258,11 +323,11 @@ class StageClient:
         self._lib.fdv_slot_release(self._h, slot)
 
     def close(self) -> None:
-        if self._h:
-            self.meta = self._tail = None
-            self.slots = []
-            self._lib.fdv_stage_delete(self._h)
-            self._h = None
+        """Drop the client's views; the C stage is freed with the last
+        of them (_Owner), which a device copy in flight may still hold."""
+        self.meta = self._tail = None
+        self.slots = []
+        self._h = None
 
     def __del__(self):
         try:

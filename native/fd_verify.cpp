@@ -7,8 +7,9 @@
 // pointer into fd_txn_parse.so: one parser implementation), the tiny
 // per-stage tcache dedup guard, the msg-length / batch-fit guards, and
 // fixed-shape batch assembly into reusable slot buffers — runs inside
-// ONE FFI crossing.  Python's per-batch work shrinks to dispatching the
-// device kernel over a sealed slot's numpy views and publishing the
+// ONE FFI crossing.  Python's per-batch work shrinks to handing a sealed
+// slot's packed rows (one contiguous array, what the device program
+// takes) to the device and publishing the
 // reaped frames (fdr_publish_burst straight out of the slot's
 // preassembled frame arena: payload || packed-descriptor || u16 len,
 // the verified-frag wire framing, built HERE so the emit path never
@@ -46,6 +47,18 @@ constexpr uint64_t FRAME_CAP = TXN_MTU + DESC_CAP + 2;
 constexpr int TC_DEPTH = 16;  // runtime/verify.VERIFY_TCACHE_DEPTH
 constexpr int STASH_CAP = 8;
 
+// One element's row in a slot, everything the device program reads of
+// it, so a batch goes to the device as ONE contiguous array:
+//   msg[mml] (zero past msg_len) | sig[64] | pk[32] | msg_len (u32 LE)
+// The offsets count from the end of the message; ROW_TAIL is what a row
+// holds past it.  runtime/verify_native mirrors them (fdlint FD305), as
+// do ops/sigverify's on-device unpack and the Python lane's _assemble
+// (tests/test_verify_kernels.py holds the three to the same bytes).
+constexpr uint64_t ROW_SIG_OFF = 0;
+constexpr uint64_t ROW_PK_OFF = 64;
+constexpr uint64_t ROW_LEN_OFF = 96;
+constexpr uint64_t ROW_TAIL = 100;
+
 enum { SLOT_FREE = 0, SLOT_OPEN = 1, SLOT_SEALED = 2, SLOT_INFLIGHT = 3 };
 
 // why a batch was sealed (runtime/verify.py CLOSE_*, the ids of its
@@ -72,10 +85,7 @@ static_assert(sizeof(fdv_slot_meta) == META_NCOL * sizeof(uint64_t),
               "slot-meta row is META_NCOL u64s");
 
 struct fdv_slot {
-  uint8_t* msg;      // batch x mml, row-major (elem e at msg + e*mml)
-  int32_t* ln;       // batch
-  uint8_t* sig;      // batch x 64
-  uint8_t* pk;       // batch x 32
+  uint8_t* rows;     // batch x (mml + ROW_TAIL), elem e in row e
   uint64_t* frames;  // batch x 4: (arena off, sz, sig_tag, tsorig) —
                      // fdr_publish_burst's frame-table format verbatim
   uint32_t* ranges;  // batch x 2: element [start, end) per txn
@@ -212,12 +222,14 @@ int ingest(fdv_stage* s, const uint8_t* payload, uint64_t sz,
   }
   fdv_slot* sl = &s->slots[s->open];
   for (uint64_t i = 0; i < sig_cnt; i++) {
-    uint64_t row = m->n_elems + i;
-    std::memcpy(sl->msg + row * s->mml, payload + msg_off, msg_len);
-    std::memset(sl->msg + row * s->mml + msg_len, 0, s->mml - msg_len);
-    sl->ln[row] = (int32_t)msg_len;
-    std::memcpy(sl->sig + row * 64, payload + sig_off + 64 * i, 64);
-    std::memcpy(sl->pk + row * 32, payload + acct_off + 32 * i, 32);
+    uint8_t* row = sl->rows + (m->n_elems + i) * (s->mml + ROW_TAIL);
+    uint8_t* tail = row + s->mml;
+    std::memcpy(row, payload + msg_off, msg_len);
+    std::memset(row + msg_len, 0, s->mml - msg_len);
+    std::memcpy(tail + ROW_SIG_OFF, payload + sig_off + 64 * i, 64);
+    std::memcpy(tail + ROW_PK_OFF, payload + acct_off + 32 * i, 32);
+    for (int k = 0; k < 4; k++)
+      tail[ROW_LEN_OFF + k] = (uint8_t)(msg_len >> (8 * k));
   }
   sl->ranges[2 * m->n_txn] = (uint32_t)m->n_elems;
   sl->ranges[2 * m->n_txn + 1] = (uint32_t)(m->n_elems + sig_cnt);
@@ -308,15 +320,11 @@ void* fdv_stage_new(uint64_t shard_idx, uint64_t shard_cnt, uint64_t batch,
   if (!s->slots || !s->meta) return nullptr;
   for (uint64_t i = 0; i < n_slots; i++) {
     fdv_slot* sl = &s->slots[i];
-    sl->msg = (uint8_t*)std::calloc(batch, max_msg_len);
-    sl->ln = (int32_t*)std::calloc(batch, sizeof(int32_t));
-    sl->sig = (uint8_t*)std::calloc(batch, 64);
-    sl->pk = (uint8_t*)std::calloc(batch, 32);
+    sl->rows = (uint8_t*)std::calloc(batch, max_msg_len + ROW_TAIL);
     sl->frames = (uint64_t*)std::calloc(batch, 4 * sizeof(uint64_t));
     sl->ranges = (uint32_t*)std::calloc(batch, 2 * sizeof(uint32_t));
     sl->arena = (uint8_t*)std::malloc(batch * FRAME_CAP);
-    if (!sl->msg || !sl->ln || !sl->sig || !sl->pk || !sl->frames ||
-        !sl->ranges || !sl->arena)
+    if (!sl->rows || !sl->frames || !sl->ranges || !sl->arena)
       return nullptr;
   }
   set_flags(s);  // every slot is free: intake accepts from the start
@@ -327,10 +335,7 @@ void fdv_stage_delete(void* ctx) {
   fdv_stage* s = (fdv_stage*)ctx;
   if (!s) return;
   for (uint64_t i = 0; i < s->n_slots; i++) {
-    std::free(s->slots[i].msg);
-    std::free(s->slots[i].ln);
-    std::free(s->slots[i].sig);
-    std::free(s->slots[i].pk);
+    std::free(s->slots[i].rows);
     std::free(s->slots[i].frames);
     std::free(s->slots[i].ranges);
     std::free(s->slots[i].arena);
@@ -385,17 +390,8 @@ void fdv_slot_release(void* ctx, uint64_t idx) {
 // zero-FFI view pointers (called once at construction from Python)
 void* fdv_meta_ptr(void* ctx) { return ((fdv_stage*)ctx)->meta; }
 void* fdv_counters_ptr(void* ctx) { return &((fdv_stage*)ctx)->flags; }
-void* fdv_slot_msg(void* ctx, uint64_t i) {
-  return ((fdv_stage*)ctx)->slots[i].msg;
-}
-void* fdv_slot_ln(void* ctx, uint64_t i) {
-  return ((fdv_stage*)ctx)->slots[i].ln;
-}
-void* fdv_slot_sig(void* ctx, uint64_t i) {
-  return ((fdv_stage*)ctx)->slots[i].sig;
-}
-void* fdv_slot_pk(void* ctx, uint64_t i) {
-  return ((fdv_stage*)ctx)->slots[i].pk;
+void* fdv_slot_rows(void* ctx, uint64_t i) {
+  return ((fdv_stage*)ctx)->slots[i].rows;
 }
 void* fdv_slot_frames(void* ctx, uint64_t i) {
   return ((fdv_stage*)ctx)->slots[i].frames;

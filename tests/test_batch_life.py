@@ -268,10 +268,10 @@ def test_a_slow_dispatch_is_one_stall_event(lane, pool):
     import jax.profiler  # noqa: F401  (the span's import, off the clock)
 
     with _tile(lane, precomputed_ok=False) as (st, prod, cons):
-        def slow(life, msg, ln, sig, pk, n):
+        def slow(life, rows):
             st._phase_end(life, rv.PH_H2D)
             time.sleep(0.12)
-            return _SlowResult(len(ln)), None
+            return _SlowResult(len(rows))
 
         st._device_verify = slow
         for i in range(5):
@@ -302,6 +302,92 @@ def test_a_slow_dispatch_is_one_stall_event(lane, pool):
         assert len(hit) == 1 and hit[0]["args"] == got
         block = slot_report.build_report(dump)["stages"]["v0"]
         assert [s["phase"] for s in block["batch_stalls"]] == ["launch"]
+
+
+def _toy_ok(t: bytes, toy_lane_ok) -> bool:
+    """conftest's toy verdict on a transaction: every element's."""
+    d = ft.txn_parse(t)
+    msg = d.message(t)
+    return all(toy_lane_ok(len(msg), msg[0], sig[0], sig[63], pk[0], pk[31])
+               for sig, pk in zip(d.signatures(t), d.signers(t)))
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_a_batch_crosses_the_boundary_once_each_way(lane, pool, exchange,
+                                                    toy_verify_ok):
+    """One packed array in, one mask out (ISSUE 29), counted where the
+    crossings are made: per dispatched batch one host->device array —
+    one `device_put` on one device; over a mesh of d one array of d
+    callbacks — one program, and one device->host fetch, at the reap."""
+    import jax.profiler  # noqa: F401  (the span's import, off the clock)
+
+    with _tile(lane, precomputed_ok=False) as (st, prod, cons):
+        out, _samples = _trickle(st, prod, cons, pool)
+        n = st.metrics.get("batches")
+        assert n >= 6
+        want = [t for t in pool if _toy_ok(t, toy_verify_ok)]
+        assert 0 < len(want) < len(pool)
+        assert out == len(want) == st.metrics.get("txn_verified")
+        assert st.metrics.get("verify_fail") == len(pool) - len(want)
+        assert exchange.programs == n
+        assert len(exchange.h2d) == n and len(exchange.fetches) == n
+        w = vn.row_width(256)
+        assert {(a.shape, str(a.dtype)) for a in exchange.h2d} \
+            == {((16, w), "uint8")}
+        assert {f.shape for f in exchange.fetches} == {(16,)}
+        if lane == "mesh":
+            assert not exchange.puts
+            assert len(exchange.callbacks) == MESH_DEVICES * n
+            assert all(len(a.sharding.device_set) == MESH_DEVICES
+                       for a in exchange.made)
+        else:
+            assert not exchange.made and not exchange.callbacks
+            assert all(len(a.sharding.device_set) == 1
+                       for a in exchange.puts)
+        if lane == "native":
+            # the array is the slot's own memory as it lies: no copy of
+            # the stage's between the intake's fill and the device_put
+            c = st._sweep_client
+            assert all(v.rows.flags.c_contiguous for v in c.slots)
+
+
+def test_pad_rows_stale_from_an_earlier_batch_change_no_answer(
+        pool, toy_verify_ok):
+    """A reused slot's pad rows hold an earlier batch's elements, which
+    the program verifies like any row (no pad mask on the device): the
+    reap reads the real lanes only, so the same transactions leave the
+    stage whatever the pad rows say."""
+    import jax.profiler  # noqa: F401
+
+    passing = [t for t in pool if _toy_ok(t, toy_verify_ok)]
+    failing = [t for t in pool if not _toy_ok(t, toy_verify_ok)]
+    assert len(passing) >= 14 and len(failing) >= 14
+    with _tile("native", precomputed_ok=False, max_inflight=1,
+               batch_deadline_s=10.0) as (st, prod, cons):
+        c = st._sweep_client
+        n_slots = c.n_slots
+
+        def batch(txns) -> int:
+            for i, t in enumerate(txns):
+                assert prod.try_publish(t, sig=i, tsorig=0)
+            for _ in range(4):
+                st.run_once()
+            st.flush()
+            return _drain(cons)
+
+        # fill every slot of the ring with rows that pass, then with
+        # rows that fail, and send a short batch each time: its pad
+        # rows are the earlier batch's
+        for filler, rest in ((passing, failing), (failing, passing)):
+            for k in range(n_slots):
+                elems = st.metrics.get("batch_elems")
+                batch(filler[4 * k:4 * k + 4])
+                assert st.metrics.get("batch_elems") == elems + 4
+            before = st.metrics.get("batches")
+            assert batch(rest[-2:]) == (2 if rest is passing else 0)
+            assert st.metrics.get("batches") == before + 1
+        # the stale rows were there: some slot's pad rows are not zero
+        assert any(v.rows[2:4].any() for v in c.slots)
 
 
 def test_stall_event_wire_value_and_arg():
@@ -459,8 +545,8 @@ CLOSE_COUNTERS = list(rv._CLOSE_COUNTERS)
 class _Gated:
     """A device future that is ready when the test says."""
 
-    def __init__(self, n, lanes):
-        self.n = n
+    def __init__(self, lanes):
+        self.n = None      # the batch's fill, from the stage's own books
         self.mask = np.ones((lanes,), dtype=bool)
         self.done = False
 
@@ -482,12 +568,19 @@ def _gated_tile(lane: str, **kw):
             return
         sent: list[_Gated] = []
 
-        def dispatch(life, msg, ln, sig, pk, n):
+        def dispatch(life, rows):
             st._phase_end(life, rv.PH_H2D)
-            sent.append(_Gated(n, len(ln)))
-            return sent[-1], None
+            sent.append(_Gated(len(rows)))
+            return sent[-1]
+
+        books = st._count_dispatch
+
+        def count(n, close, occupancy):
+            sent[-1].n = n
+            books(n, close, occupancy)
 
         st._device_verify = dispatch
+        st._count_dispatch = count
         yield st, prod, cons, sent
 
 

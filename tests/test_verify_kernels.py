@@ -3,11 +3,14 @@
 Tier-1 here is structural and host-only: ladder introspection (dispatch
 counts), the in-flight window's in-order/backpressure semantics at its
 depth of two (ISSUE 27) and, so that nothing rests on the two, at deeper
-ones, driven with fake device futures (no XLA), and the autotuner's
-determinism.  The compile-heavy differential lanes (fused vs split vs
-baseline masks on adversarial inputs, cached interleave) live behind
-the `slow` marker — a single sigverify-program compile costs ~3 min on
-one core.
+ones, driven with fake device futures (no XLA), the autotuner's
+determinism, and the packed row a batch goes to the device as (ISSUE
+29): its layout held equal between native/fd_verify.cpp, the binding,
+the Python lane's _assemble and the program's on-device unpack, which
+compiles in no time.  The compile-heavy differential lanes (the packed
+program vs ops/ref vs split vs baseline masks on adversarial inputs,
+cached interleave) live behind the `slow` marker — a single
+sigverify-program compile costs ~3 min on one core.
 """
 
 import hashlib
@@ -28,9 +31,11 @@ def test_kernel_ladder_dispatch_counts():
     from firedancer_tpu.ops import sigverify as sv
 
     assert set(sv.KERNEL_LADDER) == {"fused", "baseline", "split"}
+    # the stage's program is one module, unpack included; the A/B
+    # references unpack the same packed rows in a module of their own
     assert sv.kernel_dispatch_count("fused") == 1
-    assert sv.kernel_dispatch_count("baseline") == 1
-    assert sv.kernel_dispatch_count("split") == 4
+    assert sv.kernel_dispatch_count("baseline") == 2
+    assert sv.kernel_dispatch_count("split") == 5
     with pytest.raises(KeyError):
         sv.kernel_dispatch_count("nope")
 
@@ -86,7 +91,7 @@ class _WindowStage(VerifyStage):
     def _dispatch(self, acc, cached):
         f = _FakeResult(np.ones((len(acc.elems),), dtype=bool))
         self.fakes.append(f)
-        return f, None
+        return f
 
     def _emit_burst(self, emits):
         self.emitted.extend(emits)
@@ -274,6 +279,186 @@ def test_stage_autotune_waits_for_quiet_point(txn_pool):
     assert st.batch == 4  # never retunes with work in flight
 
 
+# -- the packed row (ISSUE 29) --------------------------------------------------
+#
+# msg[max_msg_len] | sig[64] | pk[32] | msg_len u32 LE, one element a row:
+# written by native/fd_verify.cpp at intake, mirrored by the binding
+# (fdlint FD305), built by the Python lane's _assemble, read back by the
+# program's on-device unpack.
+
+ROW_NAMES = ("ROW_SIG_OFF", "ROW_PK_OFF", "ROW_LEN_OFF", "ROW_TAIL")
+
+
+def _repo(*parts):
+    import os
+
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), *parts)
+
+
+def test_row_layout_is_one_layout_in_c_the_binding_and_the_program():
+    from firedancer_tpu.analysis import abi_check
+    from firedancer_tpu.ops import sigverify as sv
+    from firedancer_tpu.runtime import verify_native as vn
+
+    c = abi_check.extract_c(_repo("native", "fd_verify.cpp")).consts
+    py = abi_check.extract_py(
+        _repo("firedancer_tpu", "runtime", "verify_native.py")).consts
+    for name in ROW_NAMES:
+        # both sides of FD305 see the constant, so the lint holds it
+        assert c[name] == py[name][0] == getattr(vn, name) \
+            == getattr(sv, name), name
+    assert (vn.ROW_SIG_OFF, vn.ROW_PK_OFF, vn.ROW_LEN_OFF, vn.ROW_TAIL) \
+        == (0, 64, 96, 100)
+    assert vn.row_width(256) == 356 and vn.row_width(1232) == 1332
+
+
+def test_fdlint_fd305_catches_a_drifted_row_offset(tmp_path):
+    from firedancer_tpu.analysis import abi_check
+
+    with open(_repo("firedancer_tpu", "runtime", "verify_native.py")) as f:
+        src = f.read()
+    assert "ROW_PK_OFF = 64\n" in src
+    drifted = tmp_path / "verify_native.py"
+    drifted.write_text(src.replace("ROW_PK_OFF = 64\n", "ROW_PK_OFF = 60\n"))
+    cpp = _repo("native", "fd_verify.cpp")
+    hits = [f for f in abi_check.check_pair(str(drifted), cpp)
+            if f.rule == "FD305"]
+    assert len(hits) == 1 and "ROW_PK_OFF" in hits[0].msg
+    clean = tmp_path / "clean" / "verify_native.py"
+    clean.parent.mkdir()
+    clean.write_text(src)
+    assert not [f for f in abi_check.check_pair(str(clean), cpp)
+                if f.rule == "FD305"]
+
+
+def _txn_with_msg_len(target: int, seed: int) -> bytes:
+    """A one-signature transaction whose message is `target` bytes."""
+    from firedancer_tpu.protocol import txn as ft
+
+    keys = [hashlib.sha256(b"row%d-%d" % (seed, j)).digest()
+            for j in range(2)]
+    for pad in range(target):
+        msg = ft.message_build(
+            version=ft.VLEGACY, signature_cnt=1, readonly_signed_cnt=0,
+            readonly_unsigned_cnt=1, acct_addrs=keys + [ft.SYSTEM_PROGRAM],
+            recent_blockhash=hashlib.sha256(b"bh%d" % seed).digest(),
+            instrs=[ft.InstrSpec(program_id=2, accounts=bytes([0, 1]),
+                                 data=bytes([seed & 0xFF]) * pad)])
+        if len(msg) == target:
+            sig = hashlib.sha512(b"sig%d" % seed).digest()
+            return ft.txn_assemble([sig], msg)
+    raise AssertionError(f"no message of {target} bytes")
+
+
+ROW_MML = 256
+
+
+def _row_txns():
+    """Messages short, across the 255/256 byte boundary (msg_len's byte
+    order) and at max_msg_len; a multi-signature one; the pool's."""
+    from tests.test_batch_life import _three_sig_txn
+
+    return ([_txn_with_msg_len(n, n) for n in (160, 255, ROW_MML)]
+            + [_three_sig_txn(1)]
+            + gen_transfer_pool(6, n_payers=3, n_dests=8))
+
+
+def test_native_slot_rows_equal_the_python_lanes_assemble():
+    """The same transactions through the C intake and through the Python
+    lane's intake + _assemble give the same packed rows, byte for byte;
+    the layout read off them is the documented one."""
+    from firedancer_tpu.protocol import txn as ft
+    from firedancer_tpu.runtime import verify_native as vn
+
+    if not vn.available():
+        pytest.skip("native verify client unavailable")
+    txns = _row_txns()
+    batch = 16
+    c = vn.StageClient(shard_idx=0, shard_cnt=1, batch=batch,
+                       max_msg_len=ROW_MML, n_slots=2)
+    st = VerifyStage("v", ins=[], outs=[], batch=batch, max_msg_len=ROW_MML,
+                     native_client=False)
+    try:
+        for i, t in enumerate(txns):
+            assert c.append(t, 1000 + i)
+            got = st._intake(t)
+            assert got is not None
+            st._accumulate(got, t, 1000 + i)
+        n = 3 + 3 + 6
+        assert c.open_elems() == n == len(st._gen.elems)
+        c.seal(vn.CLOSE_DEADLINE)
+        slot, n_elems, n_txn, *_ = c.take_sealed()
+        assert (n_elems, n_txn) == (n, len(txns))
+        native = c.slots[slot].rows
+        python = st._assemble(st._gen)
+        w = vn.row_width(ROW_MML)
+        assert native.shape == python.shape == (batch, w) == (16, 356)
+        assert native.dtype == python.dtype == np.uint8
+        assert native.flags.c_contiguous and python.flags.c_contiguous
+        assert native.tobytes() == python.tobytes()
+        assert not native[n:].any()          # a fresh slot's pad rows
+        # the layout, read off the bytes with no help from the code
+        # under test: element e's message, signature, signer, length
+        e = 0
+        for t in txns:
+            d = ft.txn_parse(t)
+            msg = d.message(t)
+            for sig, pk in zip(d.signatures(t), d.signers(t)):
+                row = native[e].tobytes()
+                assert row[:len(msg)] == msg
+                assert row[len(msg):ROW_MML] == bytes(ROW_MML - len(msg))
+                assert row[ROW_MML:ROW_MML + 64] == sig
+                assert row[ROW_MML + 64:ROW_MML + 96] == pk
+                assert row[ROW_MML + 96:w] == len(msg).to_bytes(4, "little")
+                e += 1
+        assert e == n
+        # 256 = 00 01 00 00: the order of msg_len's bytes is little-endian
+        assert native[2, ROW_MML + 96:].tolist() == [0, 1, 0, 0]
+        assert c.slots[slot].ln[:n].tolist() \
+            == vn.row_lens(python, ROW_MML)[:n].tolist() \
+            == [160, 255, 256] + [len(ft.txn_parse(t).message(t))
+                                  for t in txns[3:4] for _ in range(3)] \
+            + [len(ft.txn_parse(t).message(t)) for t in txns[4:]]
+        c.release(slot)
+    finally:
+        c.close()
+
+
+@pytest.mark.parametrize("mml", [256, 96, 33, 1232])
+def test_the_programs_unpack_inverts_pack_rows(mml):
+    """ops/sigverify.unpack_rows (traced into the program; here jitted
+    alone, which compiles in no time) hands _verify_ok exactly the four
+    arrays the rows were packed from: any max_msg_len (33: the length
+    column unaligned), msg_len 0, 255, 256 and max_msg_len."""
+    import jax
+
+    from firedancer_tpu.ops import sigverify as sv
+    from firedancer_tpu.runtime import verify_native as vn
+
+    rng = np.random.default_rng(mml)
+    b = 24
+    ln = rng.integers(0, mml + 1, (b,)).astype(np.int32)
+    ln[:4] = [0, min(255, mml), min(256, mml), mml]
+    msg = rng.integers(0, 256, (b, mml), dtype=np.uint8)
+    msg[np.arange(mml)[None, :] >= ln[:, None]] = 0
+    sig = rng.integers(0, 256, (b, 64), dtype=np.uint8)
+    pk = rng.integers(0, 256, (b, 32), dtype=np.uint8)
+    rows = vn.pack_rows(msg, ln, sig, pk, batch=b + 8)
+    assert rows.shape == (b + 8, mml + sv.ROW_TAIL)
+    assert vn.row_lens(rows, mml).tolist() == ln.tolist() + [0] * 8
+    got = sv._unpack_rows(jax.device_put(rows), max_msg_len=mml)
+    want = (msg.T, ln, sig.T, pk.T)
+    # the host's own unpack (strided views, for the comb lane and the
+    # serving plane) reads the same layout
+    for h, x in zip(vn.byte_rows(rows, mml), want):
+        assert np.shares_memory(h, rows) and (h[..., :b] == x).all()
+    for g, x, dt in zip(got, want, (np.uint8, np.int32, np.uint8, np.uint8)):
+        g = np.asarray(g)
+        assert g.dtype == dt and g.shape == (*x.shape[:-1], b + 8)
+        assert (g[..., :b] == x).all() and not g[..., b:].any()
+
+
 # -- differential lanes (compile-heavy: slow tier) ----------------------------
 
 
@@ -286,10 +471,10 @@ def _cases(rng):
 
     L = (1 << 252) + 27742317777372353535851937790883648493
     cases, expect = [], []
-    for i in range(4):  # honest, varied lengths incl. empty
+    for i in range(6):  # honest: seeded lengths, then empty and max_msg_len
         secret = hashlib.sha256(b"k%d" % i).digest()
         pub = ref.public_key(secret)
-        m = rng.bytes(int(rng.integers(0, MAX_MSG + 1)))
+        m = rng.bytes((int(rng.integers(0, MAX_MSG + 1)), 0, MAX_MSG)[i % 3])
         cases.append((m, ref.sign(secret, m), pub))
         expect.append(True)
     secret = hashlib.sha256(b"adv").digest()
@@ -317,6 +502,13 @@ def _cases(rng):
     flip[2] ^= 4
     cases.append((m, bytes(flip), pub))
     expect.append(False)
+    # corrupted signatures of the empty and the max_msg_len message
+    for msg_c, sig_c, pub_c in (cases[1], cases[2]):
+        flip = bytearray(sig_c)
+        flip[40] ^= 1
+        cases.append((msg_c, bytes(flip), pub_c))
+        expect.append(False)
+    assert [ref.verify(*c) for c in cases] == expect
     return cases, expect
 
 
@@ -334,33 +526,51 @@ def _arrays(cases):
     return msg, ln, sig, pk
 
 
+def _rows(cases, batch=None):
+    from firedancer_tpu.runtime import verify_native as vn
+
+    msg, ln, sig, pk = _arrays(cases)
+    return vn.pack_rows(msg.T, ln, sig.T, pk.T, batch)
+
+
 @pytest.mark.slow  # three sigverify-program compiles (~3 min each)
 def test_ladder_lanes_byte_identical_masks(rng):
-    import jax.numpy as jnp
+    """The packed program's mask equals ops/ref's verdicts and the
+    baseline / split rungs', on corrupted signatures, msg_len 0 and
+    max_msg_len; and at a partial fill the real lanes' verdicts do not
+    depend on the pad rows — zero, or stale from an earlier batch."""
+    import jax
 
     from firedancer_tpu.ops import sigverify as sv
 
     cases, expect = _cases(rng)
-    msg, ln, sig, pk = _arrays(cases)
-    args = (jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
-            jnp.asarray(pk))
     n = len(cases)
+    rows = jax.device_put(_rows(cases))
     masks = {}
     for kernel in sv.KERNEL_LADDER:
-        mask, n_ok = sv.verify_dispatch(kernel, *args, n,
-                                        max_msg_len=MAX_MSG)
-        masks[kernel] = np.asarray(mask)[:n]
-        if n_ok is not None:
-            assert int(np.asarray(n_ok)) == int(masks[kernel].sum())
+        mask = sv.verify_dispatch(kernel, rows, max_msg_len=MAX_MSG)
+        masks[kernel] = np.asarray(mask)
+        assert masks[kernel].dtype == np.bool_
+        assert masks[kernel].shape == (n,)
     assert masks["fused"].tolist() == expect
     assert masks["fused"].tolist() == masks["baseline"].tolist()
     assert masks["fused"].tolist() == masks["split"].tolist()
-    # the fused program masks pad lanes ON DEVICE
-    mask, n_ok = sv.verify_dispatch("fused", *args, n - 2,
-                                    max_msg_len=MAX_MSG)
-    got = np.asarray(mask)
-    assert not got[n - 2:].any()
-    assert int(np.asarray(n_ok)) == int(got[: n - 2].sum())
+    # a partial fill of the same fixed shape: the first k rows real, the
+    # pad rows zero, then stale (the rows of the batch above, as a reused
+    # slot holds them).  What the program says of pad lanes is nobody's
+    # answer; the real lanes' is the same either way.
+    k = n - 5
+    zero = _rows(cases[:k], batch=n)
+    stale = _rows(cases)
+    stale[:k] = zero[:k]
+    assert not zero[k:].any() and stale[k:].any()
+    got_zero = np.asarray(sv.verify_dispatch(
+        "fused", jax.device_put(zero), max_msg_len=MAX_MSG))
+    got_stale = np.asarray(sv.verify_dispatch(
+        "fused", jax.device_put(stale), max_msg_len=MAX_MSG))
+    assert got_zero[:k].tolist() == got_stale[:k].tolist() == expect[:k]
+    assert not got_zero[k:].any()            # an all-zero row never verifies
+    assert got_stale[k:].tolist() == expect[k:]
 
 
 @pytest.mark.slow  # fused + cached kernel compiles
@@ -384,9 +594,8 @@ def test_cached_lane_interleave_matches_generic(rng):
         cases.append((m, s, pub))
     msg, ln, sig, pk = _arrays(cases)
     n = len(cases)
-    gen_mask, _ = sv.verify_dispatch(
-        "fused", jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
-        jnp.asarray(pk), n, max_msg_len=MAX_MSG)
+    gen_mask = sv.verify_dispatch("fused", jnp.asarray(_rows(cases)),
+                                  max_msg_len=MAX_MSG)
     fill = np.zeros((32, len(pubs)), dtype=np.uint8)
     for i, p in enumerate(pubs):
         fill[:, i] = np.frombuffer(p, dtype=np.uint8)

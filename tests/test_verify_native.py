@@ -263,3 +263,40 @@ def test_shard_filter_in_sweep(pool):
     finally:
         lin.close()
         lout.close()
+
+
+def test_a_slots_rows_keep_the_c_stage_alive_past_its_client(monkeypatch):
+    """A sealed slot's rows go to the device as they lie, by an
+    asynchronous copy that holds the numpy view and not the memory
+    under it: the C stage is freed with the last view, not with the
+    client (ISSUE 29)."""
+    import gc
+
+    if not vn.available():
+        pytest.skip("native verify client unavailable")
+    freed = []
+    delete = vn._Owner.__del__
+
+    def counted(self):
+        freed.append(self.h)
+        delete(self)
+
+    monkeypatch.setattr(vn._Owner, "__del__", counted)
+    c = vn.StageClient(shard_idx=0, shard_cnt=1, batch=8, max_msg_len=64,
+                       n_slots=2)
+    h = c._h
+    rows, ln = c.slots[1].rows, c.slots[0].ln
+    assert rows.shape == (8, vn.row_width(64)) and not rows.any()
+    c.close()
+    del c
+    gc.collect()
+    assert freed == []
+    rows[:] = 7                  # still the slot's memory, still there
+    assert int(rows.sum()) == 7 * rows.size
+    del rows
+    gc.collect()
+    assert freed == []           # the other slot's length column holds it
+    assert ln.tolist() == [0] * 8
+    del ln
+    gc.collect()
+    assert freed == [h]
