@@ -21,7 +21,7 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
-def _load_module(path: str, name: str):
+def load_module(path: str, name: str):
     spec = importlib.util.spec_from_file_location(name, path)
     if spec is None:
         raise ManifestError(f"cannot load {path}")
@@ -55,8 +55,24 @@ class Manifest:
             self.bench_dir, "traffic", cell["traffic"] + ".json"))
 
     def topology(self, kind: str):
-        return _load_module(os.path.join(
+        return load_module(os.path.join(
             self.bench_dir, "topologies", kind + ".py"), f"topology_{kind}")
+
+    def shape_path(self, traffic: dict) -> str:
+        """The file of the traffic mix's shape: what a row is, which
+        rows are corrupted, in which order they are offered."""
+        return os.path.join(self.bench_dir, "shapes",
+                            traffic.get("shape", "transfer") + ".py")
+
+    def shape(self, traffic: dict):
+        path = self.shape_path(traffic)
+        return load_module(path, "shape_" + os.path.basename(path)[:-3])
+
+    def arrivals(self, traffic: dict):
+        """A paced mix's arrivals: `due_ns(traffic, n, seed)`."""
+        name = traffic.get("arrivals", "poisson")
+        return load_module(os.path.join(
+            self.bench_dir, "arrivals", name + ".py"), f"arrivals_{name}")
 
     def metrics(self, group: str, cell_name: str) -> list[dict]:
         """The manifest's `end_to_end` or `per_layer` entries that this
@@ -68,7 +84,7 @@ class Manifest:
         """The metric's reader: `read(run) -> number | None` in
         <group dir>/<name>.py."""
         d = {"end_to_end": "e2e_metrics", "per_layer": "layer_metrics"}[group]
-        return _load_module(os.path.join(self.bench_dir, d, name + ".py"),
+        return load_module(os.path.join(self.bench_dir, d, name + ".py"),
                             f"metric_{name.replace('.', '_')}").read
 
     def peaks(self, device_kind: str) -> dict:

@@ -12,7 +12,6 @@ from __future__ import annotations
 import json
 import os
 import shutil
-import sys
 import time
 
 import numpy as np
@@ -101,50 +100,62 @@ def run_cell(args, t_entry: float, dev: tuple) -> dict:
         for h in head:
             node = node[h]
         node[leaf] = json.loads(val)
-    topo = man.topology(config["topology"])
-    replay = topo.System.replay
-    rate = traffic.get("rate_per_s")
-    warm_s = traffic["warmup_s"]
     extra_s = (TRACE_SETTLE_S + TRACE_S + 1.0) if args.trace else 0.0
-    span_s = warm_s + args.seconds + extra_s + 2.0
+    span_s = traffic["warmup_s"] + args.seconds + extra_s + 2.0
+    system, compiles, prewarm_s = build_system(
+        man, config, traffic, args.seed, span_s, args.control)
+    try:
+        return _drive(args, man, cell, traffic, system, compiles, t_entry,
+                      prewarm_s, dev)
+    finally:
+        system.close()
 
-    # -- set-up: the pool (spawned signers) beside the device warm-up ------
-    if replay:
-        n_pool = traffic["pool_txns"]
-    else:
-        n_pool = int(np.ceil(traffic["pool_txn_per_s"] * span_s))
+
+def build_system(man, config: dict, traffic: dict, seed: int, span_s: float,
+                 control: str | None):
+    """A cell's set-up: the pool its shape makes (spawned signers) beside
+    the device warm-up, the corrupted rows, the order and the arrivals
+    for `span_s` of traffic, then the system under test.
+    -> (system, the compile counter, prewarm seconds); the pool and the
+    order are the system's generator's (`system.gen`)."""
+    topo = man.topology(config["topology"])
+    shape = man.shape(traffic)
+    # a fixed pool is replayed (its order wraps); one sized by a rate has
+    # to last the run, and running out of it makes the run incorrect
+    wrap = "pool_txns" in traffic
+    n_pool = traffic["pool_txns"] if wrap \
+        else int(np.ceil(traffic["pool_txn_per_s"] * span_s))
     acct = config["traffic_accounts"]
-    job = T.PoolJob(args.seed, n_pool, acct["n_payers"], acct["n_dests"])
+    job = T.PoolJob(man.shape_path(traffic), seed, n_pool, acct, traffic)
     try:
         from firedancer_tpu.utils import nativebuild
 
         nativebuild.build_all()         # stale or missing libraries only
         compiles = Compiles()
-        prewarm_s = topo.prewarm(config, args.control)
+        prewarm_s = topo.prewarm(config, control)
         pool = job.result()
     except BaseException:
         job.abort()
         raise
-    bad = T.corrupt(pool, n_pool, traffic["corrupt_one_in"], args.seed)
+    pool.bad = shape.corrupt(pool, traffic["corrupt_one_in"], seed)
+    order = shape.order(pool, seed, traffic)
     due = None
     if traffic["kind"] == "paced":
-        n_due = int(np.ceil(rate * span_s))
-        due = T.poisson_due_ns(rate, n_due, args.seed)
+        n_due = int(np.ceil(traffic["rate_per_s"] * span_s))
+        due = man.arrivals(traffic).due_ns(traffic, n_due, seed)
     elif traffic["kind"] != "flood":
         raise ValueError(f"traffic kind {traffic['kind']!r}")
-    system = topo.System(config, dict(pool=pool, n_pool=n_pool, due_ns=due,
-                                      replay=replay), args.control, args.seed)
-    try:
-        return _drive(args, man, cell, traffic, system, compiles, pool,
-                      n_pool, bad, t_entry, prewarm_s, dev)
-    finally:
-        system.close()
+    system = topo.System(
+        config, dict(pool=pool, order=order, due_ns=due, wrap=wrap),
+        control, shape.genesis(acct, seed))
+    return system, compiles, prewarm_s
 
 
-def _drive(args, man, cell, traffic, system, compiles, pool, n_pool, bad,
-           t_entry, prewarm_s, dev) -> dict:
+def _drive(args, man, cell, traffic, system, compiles, t_entry, prewarm_s,
+           dev) -> dict:
     stages = system.stages
     gen = system.gen
+    pool = gen.pool
     warm2_s = system.warmup()   # the stage's own call: a jit-cache hit
     armed = system.armed()
     n_compiles_setup = compiles.n
@@ -155,7 +166,7 @@ def _drive(args, man, cell, traffic, system, compiles, pool, n_pool, bad,
     gen.start(t_start)
     sweep_until(stages, t_start + int(traffic["warmup_s"] * 1e9))
     say(setup={"setup_s": setup_s, "prewarm_s": prewarm_s,
-               "stage_warmup_s": warm2_s, "pool_txns": n_pool,
+               "stage_warmup_s": warm2_s, "pool_txns": pool.n,
                "compiles_in_setup": n_compiles_setup, "armed": armed})
 
     # -- the measured window ------------------------------------------------
@@ -182,12 +193,14 @@ def _drive(args, man, cell, traffic, system, compiles, pool, n_pool, bad,
     # -- drain and check ----------------------------------------------------
     drained = system.drain(DRAIN_LIMIT_S)
     c_end = system.counters()
-    landed, unknown = system.landed(pool, n_pool)
+    landed, unknown = system.landed()
+    offered = check.offered_rows(gen.order, 0, gen.i)
     res = check.compare(
-        pool=pool, n_pool=n_pool, bad=bad, n_offered=gen.i, landed=landed,
-        unknown=unknown, verify_fail=c_end["verify0"].get("verify_fail", 0),
-        dropped=system.dropped(c_end), drained=drained, window=(i0, i1),
-        seed=args.seed)
+        pool=pool, offered=offered, due=system.due(offered, pool.valid),
+        landed=landed, unknown=unknown,
+        verify_fail=c_end["verify0"].get("verify_fail", 0),
+        dedup=system.dedup_counted(c_end), dropped=system.dropped(c_end),
+        drained=drained, window=(i0, i1), seed=args.seed)
     numbers = dict(res.pop("numbers"))
     numbers["compiles_in_window"] = (compiles_in_window, 0)
     numbers["native_lanes_not_armed"] = (
@@ -218,9 +231,9 @@ def _drive(args, man, cell, traffic, system, compiles, pool, n_pool, bad,
                 "generator_late": stats.tail_ms(late),
                 "longest_generator_pause": _pause(gen, i0, i1, t0),
                 "stage_s": run["timers_s"],
-                "verify": {k: run["counters"]["verify0"].get(k, 0) for k in (
-                    "batches", "batch_elems", "txn_verified", "verify_fail",
-                    "submit_deferred")}})
+                "verify": _verify_counts(run["counters"]["verify0"]),
+                "dedup_dup": {s: c["dedup_dup"] for s, c
+                              in run["counters"].items() if "dedup_dup" in c}})
     group = "per_layer" if args.trace else "end_to_end"
     metrics = {}
     for m in man.metrics(group, cell["name"]):
@@ -235,6 +248,18 @@ def _drive(args, man, cell, traffic, system, compiles, pool, n_pool, bad,
         out["_trace"] = {"busy_s": trace["busy_s"],
                          "window_s": trace["window_s"]}
     return out
+
+
+def _verify_counts(v: dict) -> dict:
+    """The verify stage's counters a builder reads beside the metrics
+    (printed, not metrics): work, why its batches closed, stalls, and
+    over a mesh what each chip was dealt."""
+    keys = ["batches", "batch_elems", "txn_verified", "verify_fail",
+            "submit_deferred", "batch_close_full", "batch_close_deadline",
+            "batch_close_window", "batch_stalls"]
+    keys += sorted((k for k in v if k.startswith("shard_elems_s")),
+                   key=lambda k: int(k[len("shard_elems_s"):]))
+    return {k: v.get(k, 0) for k in keys}
 
 
 def _pause(gen, i0: int, i1: int, t0: int) -> dict | None:

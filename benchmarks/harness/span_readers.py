@@ -14,7 +14,7 @@ VERIFY = "verify0"
 
 def _phase_ms_per_batch(phase: str):
     """Mean ms a batch of the window spent in `phase` of its life.  A
-    window's two edges cut at most ten batches (eight in flight, one
+    window's two edges cut at most four batches (two in flight, one
     sealed, one open) of ~2,500."""
     counter = f"batch_{phase}_ns"
 

@@ -4,8 +4,9 @@ behind the verify tile, and the tap on the banks' commit rings.
 The generator is a `Stage` (the program's loop drives it like any
 other), but what it offers and when is decided here, from the cell's
 traffic file: `flood` publishes whenever the ring has room, `paced`
-publishes transaction i once its due time has come and stamps `tsorig`
-with the due time, so latency downstream counts from when it was due.
+makes offer k once its due time has come and stamps `tsorig` with the
+due time, so latency downstream counts from when it was due.  Which row
+an offer carries is the shape's (`order`).
 """
 
 from __future__ import annotations
@@ -15,37 +16,38 @@ import numpy as np
 from firedancer_tpu.runtime.stage import Stage
 from firedancer_tpu.tango.shm import now_ns
 
-from . import traffic as T
-
 
 class TrafficGen(Stage):
-    """Offers the pool in order.  `replay` lets the index wrap (verify
-    tile cells); otherwise reaching the end sets `exhausted` and stops,
-    which makes the run incorrect: a pool never wraps silently."""
+    """Offers what the shape says: offer k is pool row
+    `order[k % len(order)]`, with that row's own offset and length.
+    `wrap` lets the order come round again (a fixed pool, replayed);
+    otherwise reaching its end sets `exhausted` and stops, which makes
+    the run incorrect: an order never wraps silently."""
 
-    def __init__(self, *args, pool: np.ndarray, n_pool: int,
-                 due_ns: np.ndarray | None, replay: bool,
+    def __init__(self, *args, pool, order: np.ndarray,
+                 due_ns: np.ndarray | None, wrap: bool,
                  max_burst: int, **kwargs):
         super().__init__(*args, **kwargs)
         self.pool = pool
-        self.n_pool = n_pool
-        self.replay = replay
+        self.order = order
         self.due_rel = due_ns            # None = flood
         self.due = None                  # absolute, set by start()
         self.limit = None                # LeaderPipeline.finish sets 0
         self.max_burst = max_burst
-        self.i = 0                       # next transaction to offer
-        # how many transactions this generator can ever offer
-        self.cap = 1 << 62 if replay else n_pool
+        self.i = 0                       # offers made: the next is offer i
+        # how many offers this generator can ever make
+        self.cap = 1 << 62 if wrap else len(order)
         if due_ns is not None:
             self.cap = min(self.cap, len(due_ns))
         self.exhausted = False
         self.late: list = []             # (first index, now - due) chunks
-        base = np.zeros((n_pool, 4), dtype=np.uint64)
-        base[:, 0] = np.arange(n_pool, dtype=np.uint64) * T.TXN_SZ
-        base[:, 1] = T.TXN_SZ
-        self._rows = base
-        self._pool_ptr = pool.ctypes.data
+        # one lap of offers as the ring's burst table: offset, size, and
+        # two columns the burst fills in (the offer's index, its tsorig)
+        lap = np.zeros((len(order), 4), dtype=np.uint64)
+        lap[:, 0] = pool.off[order]
+        lap[:, 1] = pool.len[order]
+        self._lap = lap
+        self._pool_ptr = pool.buf.ctypes.data
         # one ring crossing per burst, straight from the pool's memory;
         # the python ring lane has no such call and is not measured
         self._raw = getattr(self.outs[0], "publish_burst_raw", None)
@@ -81,7 +83,7 @@ class TrafficGen(Stage):
                 self.metrics.inc("backpressure")
                 return
         idx = np.arange(i, i + n, dtype=np.int64)
-        rows = self._rows[idx % self.n_pool]
+        rows = self._lap[idx % len(self._lap)]
         rows[:, 2] = idx.astype(np.uint64)
         if self.due is not None:
             due = self.due[i:i + n]

@@ -1,27 +1,28 @@
-"""Seeded traffic: signed 1-signature system transfers, the corrupted
-set, and the arrival schedule.  Pure functions of the seed.
+"""What no traffic shape owns: the seed derivations, the pool a shape's
+rows are joined into, the spawned workers that sign it, and the bit
+flip that corrupts a signature.  What a row is, which rows are
+corrupted and in which order they are offered is the shape's
+(benchmarks/shapes/<name>.py, named by the cell's traffic file).
 
 Imports neither JAX nor the program: the signing workers are spawned
-processes that load this module alone.  The transfer wire format is
-Solana's legacy transaction (benchmarks/tests hold it to the program's
-own `transfer_txn` byte for byte); signing is OpenSSL's Ed25519 through
-`cryptography`, which is deterministic (RFC 8032), so a pool is the same
-bytes wherever it is made.
+processes that load this module and the shape alone.  Signing is
+OpenSSL's Ed25519 through `cryptography`, which is deterministic
+(RFC 8032), so a pool is the same bytes wherever it is made.
 """
 
 from __future__ import annotations
 
 import hashlib
 import os
+from dataclasses import dataclass, field
 
 import numpy as np
 
-TXN_SZ = 215          # 1 + 64 signature + 150-byte message
-SIG_OFF = 1           # byte 0 is the compact-u16 signature count
-MSG_OFF = 65
-PAYER_OFF = MSG_OFF + 4
-SYSTEM_PROGRAM = bytes(32)
-CHUNK = 4096          # transactions per signing task
+from .manifest import load_module
+
+CHUNK = 4096          # rows per signing task
+TXN_MTU = 1232        # no row is longer
+MAX_SIGS = 8          # nor carries more signatures
 
 
 def genesis_seed(seed: int) -> bytes:
@@ -39,7 +40,8 @@ def blockhash(gseed: bytes) -> bytes:
     return hashlib.sha256(gseed + b"bh").digest()
 
 
-def _signers(gseed: bytes, n_payers: int):
+def signers(gseed: bytes, n_payers: int):
+    """-> [(OpenSSL private key, 32-byte public key)] of the payers."""
     from cryptography.hazmat.primitives.asymmetric.ed25519 import (
         Ed25519PrivateKey,
     )
@@ -55,50 +57,94 @@ def _signers(gseed: bytes, n_payers: int):
     return out
 
 
-def sign_range(args) -> bytes:
-    """Transfers [lo, hi) of the pool for `gseed`, joined.  Transfer i:
-    payer i mod n_payers (rotation keeps pack's one-per-payer-per-
-    microblock rule fed), destination and lamports by index, so every
-    transaction of a pool is distinct."""
-    gseed, n_payers, n_dests, lo, hi = args
-    signers = _signers(gseed, n_payers)
-    bh = blockhash(gseed)
-    dests = [hashlib.sha256(gseed + b"to%d" % k).digest()
-             for k in range(n_dests)]
-    out = bytearray()
-    for i in range(lo, hi):
-        key, pub = signers[i % n_payers]
-        msg = (b"\x01\x00\x01\x03" + pub + dests[i % n_dests]
-               + SYSTEM_PROGRAM + bh + b"\x01\x02\x02\x00\x01\x0c"
-               + (2).to_bytes(4, "little") + (1 + i).to_bytes(8, "little"))
-        out += b"\x01" + key.sign(msg) + msg
-    return bytes(out)
+@dataclass
+class Pool:
+    """A shape's rows, joined: row i is `buf[off[i]:off[i] + len[i]]`, a
+    whole transaction of `sigs[i]` signatures and class
+    `classes[cls[i]]`.  `bad` is the rows the shape's `corrupt` broke
+    (the runner keeps them here)."""
+
+    buf: np.ndarray               # uint8
+    off: np.ndarray               # int64[n]
+    len: np.ndarray               # int64[n]
+    sigs: np.ndarray              # int64[n], 1..MAX_SIGS
+    cls: np.ndarray               # uint8[n], index into `classes`
+    classes: tuple[str, ...]
+    bad: np.ndarray = field(
+        default_factory=lambda: np.zeros((0,), dtype=np.int64))
+
+    @property
+    def n(self) -> int:
+        return len(self.off)
+
+    @property
+    def valid(self) -> np.ndarray:
+        """bool[n]: every signature of the row verifies."""
+        ok = np.ones((self.n,), dtype=bool)
+        ok[self.bad] = False
+        return ok
+
+    def row(self, i: int) -> bytes:
+        o = int(self.off[i])
+        return self.buf[o:o + int(self.len[i])].tobytes()
+
+    def first_sig_tags(self) -> np.ndarray:
+        """-> uint64[n]: the low 8 bytes of each row's first signature
+        (byte 0 of a row is its compact signature count), 0 read as 1:
+        the tag the program's verify stage gives the frag."""
+        at = self.off[:, None] + np.arange(1, 9)
+        tag = np.ascontiguousarray(self.buf[at]).view("<u8").ravel()
+        return np.where(tag == 0, np.uint64(1), tag)
+
+
+def join(rows: list[bytes], sigs, cls, classes) -> Pool:
+    """Rows (whole transactions) -> a Pool."""
+    ln = np.fromiter((len(r) for r in rows), dtype=np.int64, count=len(rows))
+    off = np.cumsum(ln) - ln
+    return Pool(np.frombuffer(b"".join(rows), dtype=np.uint8).copy(), off, ln,
+                np.asarray(sigs, dtype=np.int64),
+                np.asarray(cls, dtype=np.uint8), tuple(classes))
+
+
+def concat(parts: list[Pool]) -> Pool:
+    base = np.cumsum([0] + [p.buf.size for p in parts[:-1]])
+    return Pool(np.concatenate([p.buf for p in parts]),
+                np.concatenate([p.off + b for p, b in zip(parts, base)]),
+                np.concatenate([p.len for p in parts]),
+                np.concatenate([p.sigs for p in parts]),
+                np.concatenate([p.cls for p in parts]), parts[0].classes)
+
+
+def _build_range(task) -> Pool:
+    """In a worker: the shape from its file, as the harness loads it."""
+    path, seed, n_rows, accounts, traffic, lo, hi = task
+    shape = load_module(path, "shape_worker")
+    return shape.build(seed, n_rows, accounts, traffic, lo, hi)
 
 
 class PoolJob:
-    """A pool being signed by spawned workers while the parent does
-    something else (the JAX warm-up).  `result()` joins them."""
+    """A shape's pool being built by spawned workers, a range of rows
+    each, while the parent does something else (the JAX warm-up).
+    `result()` joins them."""
 
-    def __init__(self, seed: int, n: int, n_payers: int, n_dests: int,
-                 workers: int | None = None):
+    def __init__(self, shape_path: str, seed: int, n_rows: int,
+                 accounts: dict, traffic: dict, workers: int | None = None):
         import multiprocessing as mp
 
-        self.n = n
-        gseed = genesis_seed(seed)
-        tasks = [(gseed, n_payers, n_dests, lo, min(lo + CHUNK, n))
-                 for lo in range(0, n, CHUNK)]
+        self.n = n_rows
+        tasks = [(shape_path, seed, n_rows, accounts, traffic, lo,
+                  min(lo + CHUNK, n_rows)) for lo in range(0, n_rows, CHUNK)]
         if workers is None:
             workers = max(1, (os.cpu_count() or 2) - 1)
         workers = min(workers, len(tasks))
         self._pool = None
         if workers <= 1:
-            self._parts = [sign_range(t) for t in tasks]
+            self._parts = [_build_range(t) for t in tasks]
         else:
             self._pool = mp.get_context("spawn").Pool(workers)
-            self._async = self._pool.map_async(sign_range, tasks)
+            self._async = self._pool.map_async(_build_range, tasks)
 
-    def result(self) -> np.ndarray:
-        """-> the pool as one (n * TXN_SZ,) uint8 array."""
+    def result(self) -> Pool:
         if self._pool is not None:
             try:
                 self._parts = self._async.get()
@@ -106,10 +152,13 @@ class PoolJob:
                 self._pool.close()
                 self._pool.join()
                 self._pool = None
-        buf = np.frombuffer(b"".join(self._parts), dtype=np.uint8).copy()
-        if buf.size != self.n * TXN_SZ:
-            raise RuntimeError("signing workers returned a short pool")
-        return buf
+        pool = concat(self._parts)
+        if pool.n != self.n or int(pool.len.max()) > TXN_MTU \
+                or not 1 <= int(pool.sigs.min()) <= int(pool.sigs.max()) \
+                <= MAX_SIGS:
+            raise RuntimeError("the shape's workers returned a pool that is "
+                               "short, or a row over the MTU or 8 signatures")
+        return pool
 
     def abort(self) -> None:
         if self._pool is not None:
@@ -118,28 +167,9 @@ class PoolJob:
             self._pool = None
 
 
-def corrupt(buf: np.ndarray, n: int, every: int, seed: int) -> np.ndarray:
-    """Flip one seeded bit in the signature of one seeded transaction in
-    each run of `every` (chip_smoke.corrupt_pool's method, spread evenly
-    so any window holds its share).  In place; -> sorted bad indices."""
-    if not every:
-        return np.zeros((0,), dtype=np.int64)
-    rng = np.random.default_rng([seed, 0xBAD])
-    starts = np.arange(0, n - every + 1, every, dtype=np.int64)
-    bad = starts + rng.integers(0, every, size=starts.size)
-    byte = rng.integers(0, 64, size=bad.size)
-    bit = rng.integers(0, 8, size=bad.size)
-    buf[bad * TXN_SZ + SIG_OFF + byte] ^= (1 << bit).astype(np.uint8)
-    return bad
-
-
-def poisson_due_ns(rate_per_s: float, n: int, seed: int) -> np.ndarray:
-    """Offsets in ns, from the start of traffic, at which transaction i
-    of an open loop is due: exponential gaps at `rate_per_s`."""
-    rng = np.random.default_rng([seed, 0xA881])
-    gaps = rng.exponential(1e9 / rate_per_s, size=n)
-    return np.cumsum(gaps).astype(np.int64)
-
-
-def txn_bytes(buf: np.ndarray, i: int) -> bytes:
-    return buf[i * TXN_SZ:(i + 1) * TXN_SZ].tobytes()
+def flip(pool: Pool, rows, sig, byte, bit) -> None:
+    """Flip bit `bit` of byte `byte` of signature `sig` of each of
+    `rows`, in place (arrays of one length; chip_smoke.corrupt_pool's
+    method)."""
+    at = pool.off[rows] + 1 + 64 * np.asarray(sig) + byte
+    pool.buf[at] ^= (1 << np.asarray(bit)).astype(np.uint8)

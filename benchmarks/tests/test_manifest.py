@@ -71,6 +71,12 @@ def test_every_cell_resolves_by_name(man):
         tr = man.traffic(w)
         assert tr["kind"] in ("flood", "paced")
         assert ("rate_per_s" in tr) == (tr["kind"] == "paced")
+        assert ("pool_txns" in tr) != ("pool_txn_per_s" in tr)
+        shape = man.shape(tr)
+        assert all(callable(getattr(shape, f))
+                   for f in ("build", "corrupt", "order", "genesis"))
+        if tr["kind"] == "paced":
+            assert callable(man.arrivals(tr).due_ns)
         assert os.path.exists(os.path.join(
             man.bench_dir, "topologies", cfg["topology"] + ".py"))
         e2e = man.metrics("end_to_end", w["name"])

@@ -5,6 +5,7 @@ import pytest
 
 from harness import check, stats
 from harness import traffic as T
+from harness.manifest import Manifest
 
 
 def test_quantile_is_nearest_rank():
@@ -22,19 +23,30 @@ def test_quantile_is_nearest_rank():
     assert stats.tail_ms([]) == {"n": 0}
 
 
-def test_offered_counts_wrap():
-    assert check.offered_counts(10, 4).tolist() == [3, 3, 2, 2]
-    assert check.offered_counts(3, 4).tolist() == [1, 1, 1, 0]
+def test_offers_follow_the_order_and_wrap_over_it():
+    order = np.array([2, 0, 3, 3])
+    rows = check.offered_rows(order, 0, 10)
+    assert rows.tolist() == [2, 0, 3, 3, 2, 0, 3, 3, 2, 0]
+    assert np.bincount(rows, minlength=4).tolist() == [3, 0, 3, 4]
+    assert check.offered_rows(order, 3, 6).tolist() == [3, 2, 0]
+    assert check.offered_rows(order, 0, 0).tolist() == []
 
 
-def _compare(landed_of, **kw):
+def _compare(landed_of, n_offered=256, **kw):
     n = 256
-    pool = T.PoolJob(9, n, 64, 1024, workers=1).result()
-    bad = T.corrupt(pool, n, 64, 9)
-    offered = check.offered_counts(kw.get("n_offered", n), n)
-    landed = landed_of(offered.copy(), bad)
-    args = dict(pool=pool, n_pool=n, bad=bad, n_offered=n, landed=landed,
-                unknown=0, verify_fail=int(offered[bad].sum()), dropped=0,
+    man = Manifest()
+    shape = man.shape({})
+    pool = T.PoolJob(man.shape_path({}), 9, n, {"n_payers": 64,
+                     "n_dests": 1024}, {}, workers=1).result()
+    pool.bad = bad = shape.corrupt(pool, 64, 9)
+    offered = check.offered_rows(shape.order(pool, 9, {}), 0, n_offered)
+    offers = np.bincount(offered, minlength=n)
+    # what a tile with nothing deduping behind it says is due
+    due = {"landings": np.where(pool.valid, offers, 0),
+           "verify_fail": int(offers[bad].sum()), "duplicates": 0}
+    args = dict(pool=pool, offered=offered, due=due,
+                landed=landed_of(offers.copy(), bad), unknown=0,
+                verify_fail=due["verify_fail"], dedup=0, dropped=0,
                 drained=True, window=(0, n), seed=9)
     args.update(kw)
     return check.compare(**args)
@@ -57,7 +69,8 @@ def test_sound_run_compares_clean():
 
 @pytest.mark.parametrize("case", ["corrupted_landed", "duplicate",
                                   "lost_uncounted", "lost_counted",
-                                  "verify_fail_off", "wrapping_pool"])
+                                  "verify_fail_off", "dedup_off",
+                                  "wrapping_pool"])
 def test_each_fault_is_caught(case):
     if case == "corrupted_landed":   # the all-pass mask
         res = _compare(lambda o, bad: o, verify_fail=0)
@@ -87,6 +100,9 @@ def test_each_fault_is_caught(case):
     elif case == "verify_fail_off":
         assert _misses(_compare(_sound, verify_fail=3)) == {
             "verify_fail_minus_corrupted_offered"}
+    elif case == "dedup_off":        # the program dropped one as a duplicate
+        assert _misses(_compare(_sound, dedup=1)) == {
+            "duplicates_offered_minus_dedup_counted"}
     else:
         res = _compare(_sound, n_offered=600)
         assert not _misses(res) and 8 <= res["corrupted_offered"] <= 12

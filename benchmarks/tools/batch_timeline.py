@@ -177,37 +177,16 @@ def main() -> int:
         print(f"batch_timeline: {e}", file=sys.stderr)
         return 3
     import jax
-    import numpy as np
 
-    from firedancer_tpu.utils import nativebuild
-    from harness import runner, traffic as T
+    from harness import runner
     from harness.manifest import Manifest
 
     man = Manifest()
     cell = man.cell(a.workload)
     config, traffic = man.config(cell), man.traffic(cell)
-    topo = man.topology(config["topology"])
-    span_s = traffic["warmup_s"] + a.trace_s + 4.0
-    if topo.System.replay:
-        n_pool = traffic["pool_txns"]
-    else:
-        n_pool = int(np.ceil(traffic["pool_txn_per_s"] * span_s))
-    acct = config["traffic_accounts"]
-    job = T.PoolJob(a.seed, n_pool, acct["n_payers"], acct["n_dests"])
-    try:
-        nativebuild.build_all()
-        topo.prewarm(config, None)
-        pool = job.result()
-    except BaseException:
-        job.abort()
-        raise
-    T.corrupt(pool, n_pool, traffic["corrupt_one_in"], a.seed)
-    due = None
-    if traffic["kind"] == "paced":
-        rate = traffic["rate_per_s"]
-        due = T.poisson_due_ns(rate, int(np.ceil(rate * span_s)), a.seed)
-    system = topo.System(config, dict(pool=pool, n_pool=n_pool, due_ns=due,
-                                      replay=topo.System.replay), None, a.seed)
+    system = runner.build_system(
+        man, config, traffic, a.seed,
+        traffic["warmup_s"] + a.trace_s + 4.0, None)[0]
     trace_dir = os.path.join(ROOT, ".bench_trace")
     try:
         system.warmup()

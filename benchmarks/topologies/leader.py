@@ -11,33 +11,33 @@ import numpy as np
 
 from firedancer_tpu.models.leader import build_leader_pipeline_from_config
 from firedancer_tpu.runtime.bank import default_bank_ctx
+from firedancer_tpu.runtime.dedup import DEDUP_TCACHE_DEPTH
 from firedancer_tpu.runtime.slot_clock import SlotClockCfg
+from firedancer_tpu.runtime.verify import VERIFY_TCACHE_DEPTH
 from firedancer_tpu.utils.config import load_config
 
-from harness import traffic as T
+from harness import check
+from harness.rowmap import RowMap
 from harness.stages import CommitTap, TrafficGen
 
-# protocol/shred.MAX_PER_SLOT: a block of more data shreds does not parse.
-# The shred stage stays in slot 1 under the slot clock (nothing advances
-# `pipe.shred.slot`), so a whole run is one stored block: ~135,000 of
-# these transfers (PERF.md, Open questions).
+# protocol/shred.MAX_PER_SLOT: a block of more data shreds does not parse,
+# and `landed` says so as a number of the check.  Under the slot clock
+# the store holds a block a 400 ms slot (a run stores ~56), each far
+# under the limit.
 MAX_DATA_SHREDS_PER_SLOT = 1 << 15
 
 
 class System:
-    replay = False  # dedup and the status cache drop a replayed transfer
-
     def __init__(self, config: dict, gen_kw: dict, control: str | None,
-                 seed: int):
+                 genesis: dict):
         cfg = load_config(None, overrides=config["program_config"])
         self.batch = cfg.verify.batch
-        n_payers = config["traffic_accounts"]["n_payers"]
+        n_payers = genesis["n_payers"]
         clk = config["slot_clock"]
         self.pipe = pipe = build_leader_pipeline_from_config(
             cfg, pool_size=n_payers, gen_limit=0,
             verify_precomputed=(control == "allpass"),
-            bank_ctx=default_bank_ctx(seed=T.genesis_seed(seed),
-                                      n_payers=n_payers),
+            bank_ctx=default_bank_ctx(**genesis),   # the shape's payers
             keep_sets=False, n_payers=n_payers,
             slot_clock=SlotClockCfg(slot_ms=clk["slot_ms"], n_slots=None),
         )
@@ -96,16 +96,32 @@ class System:
                 return True
         return False
 
-    def landed(self, pool: np.ndarray, n_pool: int):
+    def due(self, offered: np.ndarray, valid: np.ndarray) -> dict:
+        """What the guarantees say of the offered rows: of those that
+        pass the verify stage, pack's tag cache drops a row it has seen
+        within its own depth, and what passes both lands once (the
+        bank's status cache rejects a replay that outlived pack's
+        memory)."""
+        passed, fail, dups = check.through_verify(
+            offered, valid, VERIFY_TCACHE_DEPTH)
+        packed = passed[check.tcache_keeps(passed, DEDUP_TCACHE_DEPTH)]
+        return {"landings": np.minimum(
+                    np.bincount(packed, minlength=len(valid)), 1),
+                "verify_fail": fail,
+                "duplicates": dups + len(passed) - len(packed)}
+
+    def dedup_counted(self, c: dict) -> int:
+        return c["verify0"].get("dedup_dup", 0) + c["pack"].get("dedup_dup", 0)
+
+    def landed(self):
         """-> (times each pool row landed, landed transactions that match
         no offered one), read from the stored block: every slot the
         store holds, reassembled from its FEC sets.  Entry batch:
         (u32 len | entry)*; entry: u32 num_hashes | 32B hash | u16 cnt |
-        (u16 len | payload)*.  A transfer's lamports field is 1 + its
-        pool index; the bytes must then equal that row's exactly."""
+        (u16 len | payload)*.  A payload is the pool row whose first
+        signature it carries, and its bytes must equal that row's."""
         store = self.pipe.store
         found = []
-        unknown = 0
         self.shreds_over_limit = 0
         for slot in sorted(store.sets_by_slot):
             n_data = sum(len(st.data_shreds) for st in store.sets_by_slot[slot])
@@ -122,26 +138,14 @@ class System:
                 o += 42
                 for _ in range(cnt):
                     ln = int.from_bytes(batch[o:o + 2], "little")
-                    o += 2
-                    if ln == T.TXN_SZ:
-                        found.append(batch[o:o + ln])
-                    else:
-                        unknown += 1
-                    o += ln
+                    found.append(batch[o + 2:o + 2 + ln])
+                    o += 2 + ln
                 if o != end:
                     raise RuntimeError("stored entry batch does not parse")
-        count = np.zeros((n_pool,), dtype=np.int64)
-        if found:
-            got = np.frombuffer(b"".join(found), dtype=np.uint8
-                                ).reshape(len(found), T.TXN_SZ)
-            idx = np.ascontiguousarray(got[:, -8:]).view("<u8").ravel() \
-                .astype(np.int64) - 1
-            ok = (idx >= 0) & (idx < n_pool)
-            rows = pool.reshape(n_pool, T.TXN_SZ)
-            ok[ok] = (rows[idx[ok]] == got[ok]).all(axis=1)
-            unknown += int((~ok).sum())
-            count = np.bincount(idx[ok], minlength=n_pool)
-        return count, unknown
+        pool = self.gen.pool
+        rows = RowMap(pool).of_payloads(found)
+        return (np.bincount(rows[rows >= 0], minlength=pool.n),
+                int((rows < 0).sum()))
 
     def extra_checks(self) -> dict:
         """name -> (value, limit); a value over its limit is a miss."""

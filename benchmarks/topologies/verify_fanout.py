@@ -2,13 +2,14 @@
 VerifyStage(devices=4) -> shm ring -> the harness's sink.  BASELINE.json
 configs[4], cut to one four-chip host.
 
-The tile topology's with a mesh behind the stage: everything but the
-construction, one more check (every chip was dealt signatures) and two
-notes on the check line (per-chip useful lanes, the close counters) is
-verify_tile.py's `System`.  A program whose verify
-stage cannot take a mesh (a commit before VerifyStage read `devices=`)
-cannot run this configuration: loading this file refuses it by name,
-with exit code 2, before anything is built, compiled or signed.
+The tile topology's with a mesh behind the stage (verify_tile.py's
+`System` reads `mesh.devices` itself): what is added here is one more
+check (every chip was dealt signatures), two notes on the check line
+(per-chip useful lanes, the close counters) and the mesh's prewarm.
+A program whose verify stage cannot take a mesh (a commit before
+VerifyStage read `devices=`) cannot run this configuration: loading
+this file refuses it by name, with exit code 2, before anything is
+built, compiled or signed.
 """
 
 from __future__ import annotations
@@ -16,10 +17,8 @@ from __future__ import annotations
 import sys
 
 from firedancer_tpu.runtime.verify import VerifyStage
-from firedancer_tpu.tango import shm
 
 from harness.manifest import Manifest
-from harness.stages import Sink, TrafficGen
 
 _tile = Manifest().topology("verify_tile")
 
@@ -42,34 +41,6 @@ def _mesh(config: dict) -> int:
 
 
 class System(_tile.System):
-    def __init__(self, config: dict, gen_kw: dict, control: str | None,
-                 seed: int):
-        n_dev = _mesh(config)
-        v = config["verify"]
-        self.batch = v["batch"]     # all lanes: fill_pct is over the mesh
-        uid = shm.fresh_uid()
-        self.links = [
-            shm.ShmLink.create(f"fdtpu_bgv_{uid}",
-                               depth=v["receive_buffer_depth"], mtu=1232),
-            shm.ShmLink.create(f"fdtpu_bvo_{uid}",
-                               depth=v["out_depth"], mtu=v["out_mtu"]),
-        ]
-        gv, vo = self.links
-        self.gen = TrafficGen("gen", outs=[shm.make_producer(gv)],
-                              max_burst=v["receive_buffer_depth"], **gen_kw)
-        self.verify = VerifyStage(
-            "verify0", ins=[shm.make_consumer(gv, lazy=32)],
-            outs=[shm.make_producer(vo)], batch=v["batch"],
-            max_msg_len=v["max_msg_len"],
-            batch_deadline_s=v["batch_deadline_ms"] / 1e3,
-            max_inflight=v["max_inflight"], devices=n_dev,
-            precomputed_ok=(control == "allpass"),
-        )
-        self.sink = Sink("sink", ins=[shm.make_consumer(vo, lazy=64)],
-                         keep=_tile.KEEP_FRAMES)
-        self.stages = [self.gen, self.verify, self.sink]
-        self.host_stages: list[str] = []
-
     def _shard_elems(self) -> list[int]:
         c = self.verify.metrics.counters
         return [int(c.get(f"shard_elems_s{i}", 0))

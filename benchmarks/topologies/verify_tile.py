@@ -1,5 +1,6 @@
 """The verify tile alone: generator -> shm ring -> one VerifyStage ->
-shm ring -> the harness's sink.  BASELINE.json configs[1]."""
+shm ring -> the harness's sink.  BASELINE.json configs[1].  A
+configuration with a `mesh` puts that many chips behind the stage."""
 
 from __future__ import annotations
 
@@ -7,20 +8,19 @@ import time
 
 import numpy as np
 
-from firedancer_tpu.runtime.verify import VerifyStage
+from firedancer_tpu.runtime.verify import VERIFY_TCACHE_DEPTH, VerifyStage
 from firedancer_tpu.tango import shm
 
-from harness import traffic as T
+from harness import check
+from harness.rowmap import RowMap
 from harness.stages import Sink, TrafficGen
 
 KEEP_FRAMES = 4096  # whole frames kept for the byte-for-byte comparison
 
 
 class System:
-    replay = True   # nothing downstream dedups: the pool may wrap
-
     def __init__(self, config: dict, gen_kw: dict, control: str | None,
-                 seed: int):
+                 genesis: dict):
         v = config["verify"]
         self.batch = v["batch"]
         uid = shm.fresh_uid()
@@ -38,13 +38,14 @@ class System:
             outs=[shm.make_producer(vo)], batch=v["batch"],
             max_msg_len=v["max_msg_len"],
             batch_deadline_s=v["batch_deadline_ms"] / 1e3,
-            max_inflight=v["max_inflight"],
+            devices=config.get("mesh", {}).get("devices"),
             precomputed_ok=(control == "allpass"),
         )
         self.sink = Sink("sink", ins=[shm.make_consumer(vo, lazy=64)],
                          keep=KEEP_FRAMES)
         self.stages = [self.gen, self.verify, self.sink]
         self.host_stages: list[str] = []
+        self.rowmap = RowMap(self.gen.pool)
 
     def warmup(self) -> float:
         return self.verify.warmup()
@@ -60,9 +61,20 @@ class System:
         return {s.name: dict(s.metrics.counters) for s in self.stages}
 
     def served(self) -> int:
-        """Signatures in verified-or-rejected frags that left the stage:
-        a batch counts only once its mask was reaped."""
-        return self.sink.n + self.verify.metrics.get("verify_fail")
+        """Signatures in verified-or-rejected transactions that left the
+        stage: a batch counts only once its mask was reaped.  What
+        passed is the sink's frags, each the row its tag names; what
+        failed is `verify_fail` transactions, which the stage (in order)
+        rejects in the order the corrupted rows were offered."""
+        gen, fail = self.gen, self.verify.metrics.get("verify_fail")
+        sigs = gen.pool.sigs
+        if sigs.min() == sigs.max():
+            return int(sigs[0]) * (self.sink.n + fail)
+        rows = self.rowmap.of_tags(self.sink.arrays()[1])
+        bad = sigs[gen.order][~gen.pool.valid[gen.order]]  # a lap's, in order
+        laps, rest = divmod(fail, max(len(bad), 1))
+        return int(sigs[rows[rows >= 0]].sum()
+                   + laps * bad.sum() + bad[:rest].sum())
 
     def latencies_ns(self, t0: int, t1: int) -> np.ndarray:
         arr, _, ts = self.sink.arrays()
@@ -95,27 +107,27 @@ class System:
                 return True
         return False
 
-    def landed(self, pool: np.ndarray, n_pool: int):
+    def due(self, offered: np.ndarray, valid: np.ndarray) -> dict:
+        """What the guarantees say of the offered rows: every offer
+        that passes the verify stage leaves the tile (nothing
+        downstream dedups)."""
+        passed, fail, dups = check.through_verify(
+            offered, valid, VERIFY_TCACHE_DEPTH)
+        return {"landings": np.bincount(passed, minlength=len(valid)),
+                "verify_fail": fail, "duplicates": dups}
+
+    def dedup_counted(self, c: dict) -> int:
+        return c["verify0"].get("dedup_dup", 0)
+
+    def landed(self):
         """-> (times each pool row landed, landed things that match no
         offered transaction).  Every frag by its tag; the kept frames
         byte for byte (payload || descriptor || u16 payload size)."""
-        _, tag, _ = self.sink.arrays()
-        sigs = pool.reshape(n_pool, T.TXN_SZ)[:, T.SIG_OFF:T.SIG_OFF + 8]
-        row_tag = np.ascontiguousarray(sigs).view("<u8").ravel()
-        row_tag = np.where(row_tag == 0, 1, row_tag)
-        order = np.argsort(row_tag)
-        sorted_tag = row_tag[order]
-        pos = np.minimum(np.searchsorted(sorted_tag, tag), n_pool - 1)
-        hit = sorted_tag[pos] == tag
-        count = np.bincount(order[pos[hit]], minlength=n_pool)
-        unknown = int((~hit).sum())
-        rows = pool.reshape(n_pool, T.TXN_SZ)
-        for f in self.sink.kept:
-            psz = int.from_bytes(f[-2:], "little")
-            i = int.from_bytes(f[T.TXN_SZ - 8:T.TXN_SZ], "little") - 1
-            if psz != T.TXN_SZ or not 0 <= i < n_pool \
-                    or f[:psz] != rows[i].tobytes():
-                unknown += 1
+        rows = self.rowmap.of_tags(self.sink.arrays()[1])
+        count = np.bincount(rows[rows >= 0], minlength=self.gen.pool.n)
+        kept = [f[:int.from_bytes(f[-2:], "little")] for f in self.sink.kept]
+        unknown = int((rows < 0).sum()) \
+            + int((self.rowmap.of_payloads(kept) < 0).sum())
         return count, unknown
 
     def extra_checks(self) -> dict:
