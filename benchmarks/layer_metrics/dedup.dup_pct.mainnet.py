@@ -1,0 +1,1 @@
+from harness.mix_readers import dup_pct as read  # noqa: F401

@@ -12,7 +12,8 @@ Parity and fallback contract:
   - `eligible_packed` is the Executor's routing classifier: a txn whose
     every instruction is in the native subset (the full system surface
     including the durable-nonce family, stake ops, vote vote/
-    vote_state_update/tower_sync) routes native; CPI, BPF, lookup
+    vote_state_update/tower_sync, and the compute-budget instructions,
+    whose limit and priority fee the lane applies) routes native; CPI, BPF, lookup
     tables and unsupported variants go through the Python lane
     byte-for-byte.
   - the C++ side may still PUNT any txn it is not sure about (old vote
@@ -29,6 +30,7 @@ import ctypes
 import os
 import struct
 
+from firedancer_tpu.pack.cost import COMPUTE_BUDGET_PROGRAM
 from firedancer_tpu.utils.nativebuild import NativeUnavailable, build_so
 from firedancer_tpu.protocol.txn import (
     SYSTEM_PROGRAM,
@@ -148,10 +150,13 @@ def eligible_packed(payload: bytes, desc_bytes: bytes) -> bool:
             return False
         pa = acct_off + 32 * prog
         pk = payload[pa : pa + 32]
-        if pk == SYSTEM_PROGRAM or pk == _STAKE_PROGRAM:
+        if pk == SYSTEM_PROGRAM or pk == _STAKE_PROGRAM \
+                or pk == COMPUTE_BUDGET_PROGRAM:
             # the whole native surface, durable-nonce family included
             # (the session's in-line durable gate owns the stale-
-            # blockhash decision); stake tags 0..4 execute, others no-op
+            # blockhash decision); stake tags 0..4 execute, others
+            # no-op; a compute-budget instruction touches no account
+            # (a malformed one punts)
             pass
         elif pk == VOTE_PROGRAM:
             if dsz >= 4:

@@ -142,7 +142,7 @@ def system_program(executor, ctx, program_id, iaccts, data, *, pda_signers):
 
 
 # -- compute budget program ---------------------------------------------------
-# The limits themselves are applied at txn load (pack.cost.txn_budget ->
+# The limits themselves are applied at txn load (pack.cost.txn_budget_fee ->
 # TxnCtx.budget/heap_size); execution of the instruction only re-validates
 # the payload (fd_compute_budget_program.c's processor is the same no-op).
 

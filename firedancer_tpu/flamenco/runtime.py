@@ -169,9 +169,11 @@ def default_sysvars(slot: int) -> dict:
         "clock": T.CLOCK.encode(T.Clock(slot=slot, epoch=epoch)),
         "rent": T.RENT.encode(T.Rent()),
         "epoch_schedule": T.EPOCH_SCHEDULE.encode(sched),
-        # recent bank hashes the vote program validates against; the
-        # caller (replay/consensus) supplies real entries via
-        # execute_block(slot_hashes=...) — empty means votes reject
+        # recent bank hashes the vote program validates against: empty
+        # here (every vote rejects) until the caller supplies the real
+        # entries — replay/consensus via execute_block(slot_hashes=...),
+        # a leader's bank via BankCtx(slot_hashes=...) /
+        # runtime/bank.genesis_bank_ctx
         "slot_hashes": T.SLOT_HASHES.encode([]),
         # Fees { fee_calculator: { lamports_per_signature } }
         "fees": LAMPORTS_PER_SIGNATURE.to_bytes(8, "little"),
@@ -237,7 +239,17 @@ def _execute_txn(
         # independent copies — stale reads + lamport mint/burn at commit
         return TxnResult(TXN_ERR_ACCT, 0)
     payer = addrs[0]
-    fee = LAMPORTS_PER_SIGNATURE * desc.signature_cnt
+    # the txn's requested compute budget + heap (SetComputeUnitLimit /
+    # RequestHeapFrame) drive execution — pack only *costs* them; here
+    # they are ENFORCED (the r3 gap: VM budget was fixed at 200k) — and
+    # its priority fee (limit x SetComputeUnitPrice, the fee pack
+    # ordered it by) is charged with the signature fee, whatever the
+    # outcome (Agave's fee calculation)
+    from firedancer_tpu.pack.cost import txn_budget_fee
+
+    budget = txn_budget_fee(payload, desc)
+    fee = LAMPORTS_PER_SIGNATURE * desc.signature_cnt + (
+        budget[2] if budget is not None else 0)
     payer_val = funk.rec_query(xid, payer)
     if acct_lamports(payer_val) < fee:
         return TxnResult(TXN_ERR_FEE, 0)
@@ -261,17 +273,11 @@ def _execute_txn(
     signer = [i < desc.signature_cnt for i in range(len(addrs))]
     writable = [desc.is_writable(i) for i in range(len(addrs))]
     baseline = [a.to_value() for a in accounts]
-    # the txn's requested compute budget + heap (SetComputeUnitLimit /
-    # RequestHeapFrame) drive execution — pack only *costs* them; here
-    # they are ENFORCED (the r3 gap: VM budget was fixed at 200k)
-    from firedancer_tpu.pack.cost import txn_budget
-
-    budget = txn_budget(payload, desc)
     if budget is None:
         # malformed compute-budget instruction: typed failure, fee stays
         # charged (pack's cost model would have dropped it pre-block)
         return _fail(TXN_ERR_PROGRAM)
-    cu_limit, heap_size = budget
+    cu_limit, heap_size, _prio = budget
     # resolve upgradeable programs' programdata up front (the reference's
     # account loader does the same indirection, fd_executor.c load path);
     # a broken indirection surfaces as a typed failure at invoke time
@@ -776,7 +782,10 @@ class SlotExecution:
         never materialized.  With the native funk plane armed the record
         stream arrives stripped (the values already live in the shm map)
         and the only per-txn slices left are the bh/sig pair the status
-        cache keys on.  Returns (n_ok, n_fail, n_rej)."""
+        cache keys on.  Returns (n_ok, n_fail, n_rej, n_vote,
+        n_vote_fail): landed, landed with a failed program, no
+        footprint, and the simple votes (pack/cost.py is_simple_vote:
+        one instruction, the vote program's) among the first two."""
         before = self._before
         q = self.funk.rec_query
         recs_d = self.funk.txn_recs_for_write(self.xid)
@@ -799,12 +808,13 @@ class SlotExecution:
         # dataclass exists to carry the pair out of the slot)
         res_cache = self._txnres_cache
         track_before = not self._funk_diff
-        n_ok = n_fail = n_rej = 0
+        n_ok = n_fail = n_rej = n_vote = n_vote_fail = 0
         sig_cnt = 0
+        vote_program = ft.VOTE_PROGRAM
         for frag, (status, fee, writes) in zip(frags, recs):
             psz = frag[-2] | (frag[-1] << 8)
+            acct_off = frag[psz + 9] | (frag[psz + 10] << 8)
             if writes:
-                acct_off = frag[psz + 9] | (frag[psz + 10] << 8)
                 for idx, val in writes:
                     a = frag[acct_off + 32 * idx : acct_off + 32 * (idx + 1)]
                     if track_before and a not in before:
@@ -816,6 +826,14 @@ class SlotExecution:
                 n_ok += 1
                 if status != TXN_SUCCESS:
                     n_fail += 1
+                if frag[psz + 16] == 1:
+                    # one instruction: the vote program's?  (its key's
+                    # first byte is 7, the system program's 0)
+                    po = acct_off + 32 * frag[psz + 17]
+                    if frag[po] == 7 and frag[po : po + 32] == vote_program:
+                        n_vote += 1
+                        if status != TXN_SUCCESS:
+                            n_vote_fail += 1
                 sig_cnt += frag[psz + 1]
                 if staged_append is not None:
                     sig_off = frag[psz + 2] | (frag[psz + 3] << 8)
@@ -835,7 +853,7 @@ class SlotExecution:
             res_append(r)
         self.native_done_cnt += n_ok + n_rej
         self.signature_cnt += sig_cnt
-        return n_ok, n_fail, n_rej
+        return n_ok, n_fail, n_rej, n_vote, n_vote_fail
 
     @staticmethod
     def _unpack_trailer(payload: bytes, desc_bytes: bytes) -> ft.Txn:

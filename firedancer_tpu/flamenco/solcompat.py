@@ -308,6 +308,8 @@ def load_fixture(path: str) -> InstrFixture:
 
 # -- runner -------------------------------------------------------------------
 
+# the owner of every sysvar account
+SYSVAR_OWNER = b58_decode32("Sysvar1111111111111111111111111111111111111")
 # canonical sysvar account addresses -> the names flamenco's TxnCtx uses
 SYSVAR_NAMES = {
     b58_decode32("SysvarC1ock11111111111111111111111111111111"): "clock",

@@ -165,6 +165,18 @@ def build_leader_pipeline_from_config(cfg, **overrides) -> "LeaderPipeline":
         verify_devices=cfg.verify.devices,
     )
     kw.update(overrides)
+    g = cfg.genesis
+    if (g.n_voters or g.slot_hashes) and kw.get("bank_ctx") is None:
+        # the deployment's genesis: the validator set's vote accounts
+        # and SlotHashes, beside the generator's funded payers
+        from firedancer_tpu.runtime.bank import (
+            genesis_bank_ctx, seeded_validators,
+        )
+
+        kw["bank_ctx"] = genesis_bank_ctx(
+            n_payers=kw.get("n_payers", 8),
+            **seeded_validators(n_voters=g.n_voters,
+                                n_slot_hashes=g.slot_hashes))
     return build_leader_pipeline(**kw)
 
 

@@ -103,7 +103,7 @@ def _cbp_parse(data: bytes, st: _CbpState) -> bool:
         if st.heap_size % HEAP_FRAME_GRANULARITY:
             return False
         # range-checked HERE so pack and the runtime agree on validity
-        # (txn_budget rejects the same range; a pack-admitted txn must
+        # (txn_budget_fee rejects the same range; a pack-admitted txn must
         # never fail the runtime's budget resolution)
         if not DEFAULT_HEAP_SIZE <= st.heap_size <= MAX_HEAP_SIZE:
             return False
@@ -205,11 +205,13 @@ def compute_cost(payload: bytes, t: ft.Txn) -> TxnCost | None:
     )
 
 
-def txn_budget(payload: bytes, t: ft.Txn) -> tuple[int, int] | None:
-    """The txn-wide (cu_limit, heap_bytes) from its compute-budget
-    instructions — the execution-side resolution the runtime feeds into
-    TxnCtx/the VM (fd_compute_budget_program's rules; the reference
-    resolves this during txn load, fd_executor.c).  None = malformed."""
+def txn_budget_fee(payload: bytes, t: ft.Txn) -> tuple[int, int, int] | None:
+    """The txn-wide (cu_limit, heap_bytes, priority fee in lamports) from
+    its compute-budget instructions — the execution-side resolution the
+    runtime feeds into TxnCtx/the VM and charges the fee payer
+    (fd_compute_budget_program's rules; the reference resolves this
+    during txn load, fd_executor.c).  The fee is the one `compute_cost`
+    orders the pool by.  None = malformed."""
     addrs = t.acct_addrs(payload)
     cbp = _CbpState()
     for ins in t.instrs:
@@ -218,7 +220,7 @@ def txn_budget(payload: bytes, t: ft.Txn) -> tuple[int, int] | None:
             data = payload[ins.data_off : ins.data_off + ins.data_sz]
             if not _cbp_parse(data, cbp):
                 return None
-    _, cu_limit = _cbp_finalize(cbp, len(t.instrs))
+    fee, cu_limit = _cbp_finalize(cbp, len(t.instrs))
     # heap range was validated by _cbp_parse (pack and runtime agree)
     heap = cbp.heap_size if cbp.flags & _FLAG_SET_HEAP else DEFAULT_HEAP_SIZE
-    return cu_limit, heap
+    return cu_limit, heap, fee

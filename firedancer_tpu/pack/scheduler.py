@@ -5,7 +5,10 @@ Behavioral port of /root/reference/src/ballet/pack/fd_pack.c:
   - pending transactions ordered by reward/cost ratio, compared exactly as
     r1*c2 > r2*c1 (no floating point; fd_pack.c:41-47);
   - separate pending pool for simple votes (scheduled against the vote
-    cost limit);
+    cost limit); every microblock takes votes FIRST, up to VOTE_FRACTION
+    of its cost and of its transaction slots, then fills from the
+    regular pool (fd_pack_schedule_next_microblock's vote_fraction);
+    a full pool never evicts a vote for a non-vote;
   - an account in use by an in-flight microblock blocks conflicting txns:
     write-locks are exclusive, read-locks are shared (fd_pack_bitset.h's
     semantics via per-account reader/writer bank masks);
@@ -94,12 +97,32 @@ class _RatioKey:
         return self.r * other.c == other.r * self.c
 
 
+# fd_pack_schedule_next_microblock(pack, total_cus, vote_fraction, ...):
+# the pack tile's VOTE_FRACTION 0.75, kept exact as a ratio of integers
+# so that both lanes cut at the same transaction
+VOTE_FRACTION_NUM = 3
+VOTE_FRACTION_DEN = 4
+
+
 @dataclass
 class BlockLimits:
     max_cost_per_block: int = fc.MAX_COST_PER_BLOCK
     max_vote_cost_per_block: int = fc.MAX_VOTE_COST_PER_BLOCK
     max_write_cost_per_acct: int = fc.MAX_WRITE_COST_PER_ACCT
     max_data_bytes_per_block: int = fc.MAX_DATA_PER_BLOCK
+
+
+@dataclass
+class _Microblock:
+    """What one schedule call has chosen so far, over both pools."""
+
+    chosen: list = field(default_factory=list)
+    taken_w: set = field(default_factory=set)
+    taken_r: set = field(default_factory=set)
+    cost: int = 0
+    vote_cost: int = 0
+    data: int = 0
+    write_cost: dict = field(default_factory=dict)
 
 
 class Pack:
@@ -139,6 +162,13 @@ class Pack:
         self.vote_cost_used = 0
         self.data_bytes_used = 0
         self._write_cost: dict[bytes, int] = {}
+        # cumulative counts the pack stage copies into its metrics
+        # (the native lane reports the same five out of its crossings)
+        self.stat_evicted = 0          # pooled txns a better newcomer evicted
+        self.stat_dropped_votes = 0    # votes refused or evicted
+        self.stat_votes_dropped_regular_pending = 0  # ...with a non-vote pooled
+        self.stat_scheduled_votes = 0
+        self.stat_conflict_skips = 0   # scan steps over an account in use
 
     # -- intake --------------------------------------------------------------
 
@@ -153,24 +183,43 @@ class Pack:
         sig = t.signatures(payload)[0]
         if sig in self._sigs:
             return False
-        pool = self._pending_votes if c.is_simple_vote else self._pending
+        vote = c.is_simple_vote
+        pool = self._pending_votes if vote else self._pending
         ord_txn = OrdTxn(payload, t, c, c.rewards(t.signature_cnt))
         if len(self._pending) + len(self._pending_votes) >= self.depth:
-            # full: evict the GLOBALLY lowest-priority txn iff the
-            # newcomer beats it (both pools' tails considered — evicting
-            # only from the newcomer's own pool would let a low-value
-            # vote survive a high-value txn, fd_pack's delete-worst rule)
-            tails = [p[-1] for p in (self._pending, self._pending_votes) if p]
-            if not tails:  # depth <= 0: nothing to evict, refuse
-                return False
-            worst = max(tails, key=OrdTxn.sort_key)  # key orders best-first
-            if not (ord_txn.sort_key() < worst.sort_key()):
+            # full.  Votes are consensus traffic: while a non-vote is
+            # pooled a vote is never the one to go — an arriving vote
+            # takes the place of the lowest-priority non-vote whatever
+            # the ratios say (a vote's 5,000 lamports stand over the
+            # dearer cost, so by ratio it would always lose).  A non-vote
+            # evicts the worst non-vote iff it strictly beats it, and
+            # never a vote; among votes alone the ratio decides.
+            if self._pending:
+                worst = self._pending[-1]
+                if not vote and not (ord_txn.sort_key() < worst.sort_key()):
+                    return False
+            elif vote and self._pending_votes and (
+                    ord_txn.sort_key() < self._pending_votes[-1].sort_key()):
+                worst = self._pending_votes[-1]
+                self._count_vote_drop()
+            else:   # a pool of votes refuses a non-vote; depth <= 0
+                if vote:
+                    self._count_vote_drop()
                 return False
             self._remove(worst)
+            self.stat_evicted += 1
         bisect.insort(pool, ord_txn, key=OrdTxn.sort_key)
         self._sigs.add(sig)
         self._by_sig[sig] = ord_txn
         return True
+
+    def _count_vote_drop(self) -> None:
+        """A vote leaves (or is refused by) the pool unscheduled.  The
+        second count is the guarantee's: it stays 0 while the rule above
+        holds, and says so if a later rule breaks it."""
+        self.stat_dropped_votes += 1
+        if self._pending:
+            self.stat_votes_dropped_regular_pending += 1
 
     def _remove(self, o: OrdTxn) -> None:
         # bisect to the sort-key position, then identity-match within the
@@ -265,66 +314,27 @@ class Pack:
                 return False
         return True
 
-    def schedule_next_microblock(
-        self, bank: int, *, votes: bool = False
-    ) -> list[OrdTxn]:
+    def schedule_next_microblock(self, bank: int) -> list[OrdTxn]:
         """Select a conflict-free microblock for `bank` (fd_pack.c
-        fd_pack_schedule_next_microblock).  Chosen txns' accounts become
-        in-use by this bank until microblock_done(bank)."""
+        fd_pack_schedule_next_microblock): votes first, up to
+        VOTE_FRACTION of the cost the block has left and of the
+        microblock's transaction slots (at least one), then the regular
+        pool fills what remains — all under the block's limits and the
+        account locks.  With no vote pooled this is the regular scan
+        alone.  Chosen txns' accounts become in-use by this bank until
+        microblock_done(bank)."""
         if not 0 <= bank < self.bank_cnt:
             raise ValueError("bad bank index")
-        pool = self._pending_votes if votes else self._pending
-        chosen: list[OrdTxn] = []
-        taken_w: set[bytes] = set()
-        taken_r: set[bytes] = set()
-        mb_cost = 0
-        mb_vote_cost = 0
-        mb_data = 0
-        mb_write_cost: dict[bytes, int] = {}
-        # scan IN PLACE: skipped entries never move (so they keep their
-        # priority order for free), chosen indices are deleted after the
-        # scan — the pop(0)+re-insort shape was O(pool^2) whenever the
-        # pool ran deep with conflicting txns
-        chosen_idx: list[int] = []
-        i = 0
-        limit = min(len(pool), self.max_schedule_search)
-        while i < len(pool) and len(chosen) < self.max_txn_per_microblock:
-            if i >= limit and chosen:
-                # bounded lookahead only once something was chosen: an
-                # all-unschedulable WINDOW must not starve schedulable
-                # txns sitting past it (the empty case falls through to
-                # a full scan — the pre-bound behavior)
-                break
-            o = pool[i]
-            sw, lr, lw = o.acct_sets()
-            # conflicts within this microblock too: serial execution inside
-            # a microblock is NOT a thing — the bank executes it as one
-            # conflict-free parallel burst.
-            if (
-                self._conflicts(bank, lw, lr)
-                or (lw & (taken_w | taken_r))
-                or (lr & taken_w)
-                or not self._fits_block(
-                    o, votes, sw, mb_cost, mb_vote_cost, mb_data, mb_write_cost
-                )
-            ):
-                i += 1
-                continue
-            self._sigs.discard(o.first_sig())
-            self._by_sig.pop(o.first_sig(), None)
-            chosen.append(o)
-            chosen_idx.append(i)
-            i += 1
-            taken_w |= lw
-            taken_r |= lr
-            mb_cost += o.cost.total
-            if votes:
-                mb_vote_cost += o.cost.total
-            mb_data += len(o.payload)
-            for a in sw:
-                mb_write_cost[a] = mb_write_cost.get(a, 0) + o.cost.total
-        for j in reversed(chosen_idx):
-            pool.pop(j)
+        mb = _Microblock()
+        max_txn = self.max_txn_per_microblock
+        vote_txns = max(1, max_txn * VOTE_FRACTION_NUM // VOTE_FRACTION_DEN)
+        vote_cost = (max(self.limits.max_cost_per_block - self.cost_used, 0)
+                     * VOTE_FRACTION_NUM // VOTE_FRACTION_DEN)
+        self._scan(bank, self._pending_votes, True, mb,
+                   min(vote_txns, max_txn), vote_cost)
+        self.stat_scheduled_votes += len(mb.chosen)
+        self._scan(bank, self._pending, False, mb, max_txn, None)
+        chosen = mb.chosen
         if not chosen:
             return []
         # commit locks + block accounting
@@ -339,11 +349,66 @@ class Pack:
             for a in sw:
                 self._write_cost[a] = self._write_cost.get(a, 0) + o.cost.total
             self.cost_used += o.cost.total
-            if votes:
-                self.vote_cost_used += o.cost.total
             self.data_bytes_used += len(o.payload)
+        self.vote_cost_used += mb.vote_cost
         self.data_bytes_used += fc.MICROBLOCK_DATA_OVERHEAD
         return chosen
+
+    def _scan(self, bank: int, pool: list[OrdTxn], votes: bool,
+              mb: "_Microblock", max_txn: int, cost_cap: int | None) -> None:
+        """One pool's pass of a microblock: take in priority order what
+        neither conflicts nor breaks a limit, until the microblock holds
+        `max_txn` (or, for votes, `cost_cap` cost units)."""
+        chosen = mb.chosen
+        # scan IN PLACE: skipped entries never move (so they keep their
+        # priority order for free), chosen indices are deleted after the
+        # scan — the pop(0)+re-insort shape was O(pool^2) whenever the
+        # pool ran deep with conflicting txns
+        chosen_idx: list[int] = []
+        i = 0
+        limit = min(len(pool), self.max_schedule_search)
+        while i < len(pool) and len(chosen) < max_txn:
+            if i >= limit and chosen:
+                # bounded lookahead only once something was chosen: an
+                # all-unschedulable WINDOW must not starve schedulable
+                # txns sitting past it (the empty case falls through to
+                # a full scan — the pre-bound behavior)
+                break
+            o = pool[i]
+            sw, lr, lw = o.acct_sets()
+            # conflicts within this microblock too: serial execution inside
+            # a microblock is NOT a thing — the bank executes it as one
+            # conflict-free parallel burst.
+            if (
+                self._conflicts(bank, lw, lr)
+                or (lw & (mb.taken_w | mb.taken_r))
+                or (lr & mb.taken_w)
+            ):
+                self.stat_conflict_skips += 1
+                i += 1
+                continue
+            if (cost_cap is not None
+                    and mb.cost + o.cost.total > cost_cap) \
+                    or not self._fits_block(o, votes, sw, mb.cost,
+                                            mb.vote_cost, mb.data,
+                                            mb.write_cost):
+                i += 1
+                continue
+            self._sigs.discard(o.first_sig())
+            self._by_sig.pop(o.first_sig(), None)
+            chosen.append(o)
+            chosen_idx.append(i)
+            i += 1
+            mb.taken_w |= lw
+            mb.taken_r |= lr
+            mb.cost += o.cost.total
+            if votes:
+                mb.vote_cost += o.cost.total
+            mb.data += len(o.payload)
+            for a in sw:
+                mb.write_cost[a] = mb.write_cost.get(a, 0) + o.cost.total
+        for j in reversed(chosen_idx):
+            pool.pop(j)
 
     def microblock_done(self, bank: int) -> None:
         """Release `bank`'s account locks (execution finished)."""

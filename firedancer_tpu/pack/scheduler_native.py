@@ -11,8 +11,8 @@ NativeTCache` (the same fd_tcache.so structure the dedup stage uses)
 into the insert path, so duplicate txns are dropped inside the same
 crossing and never surface into Python at all.
 
-Parity contract: byte-identical microblock frames, identical evictions
-and end_block accounting vs `pack/scheduler.py` + identical drop sets
+Parity contract: byte-identical microblock frames, identical evictions,
+counts (`stat_*`) and end_block accounting vs `pack/scheduler.py` + identical drop sets
 vs the DedupStage->PackStage python lane (tests/test_pack_native.py).
 `FDTPU_NATIVE_PACK=0` disables the lane; a missing toolchain degrades
 to the Python lane via NativeUnavailable (skip, never fail).
@@ -65,7 +65,7 @@ def _load():
         lib.fd_pack_block_state.argtypes = [vp, ctypes.POINTER(u64)]
         lib.fd_pack_schedule.restype = i64
         lib.fd_pack_schedule.argtypes = [
-            vp, u64, ctypes.c_int, ctypes.c_uint32, ctypes.c_char_p, u64,
+            vp, u64, ctypes.c_uint32, ctypes.c_char_p, u64,
             ctypes.POINTER(u64),
         ]
         lib.fd_pack_microblock_done.argtypes = [vp, u64]
@@ -145,12 +145,18 @@ class NativePack:
         self.bank_cnt = bank_cnt
         self.depth = depth
         self._frame_buf = ctypes.create_string_buffer(self.FRAME_CAP)
-        self._meta = (ctypes.c_uint64 * 4)()
-        self._pending_out = (ctypes.c_uint64 * 1)()
+        self._meta = (ctypes.c_uint64 * 9)()
+        self._stats_out = (ctypes.c_uint64 * 6)()
         # pool size as of the last crossing: every insert_burst/schedule
-        # reports it, so the stage's scheduling policy never pays a
-        # dedicated fd_pack_pending_cnt crossing per loop iteration
+        # reports it (with Pack's five stat_* counts behind it), so the
+        # stage's scheduling policy never pays a dedicated
+        # fd_pack_pending_cnt crossing per loop iteration
         self.last_pending = 0
+        self.stat_evicted = 0
+        self.stat_dropped_votes = 0
+        self.stat_votes_dropped_regular_pending = 0
+        self.stat_scheduled_votes = 0
+        self.stat_conflict_skips = 0
         # keep the tcache object alive: the native side holds raw pointers
         self._tcache = None
 
@@ -164,6 +170,14 @@ class NativePack:
         self._lib.fd_pack_set_tcache(
             self._h, ctypes.c_void_p(tcache._h), insert_fn
         )
+
+    def _take_stats(self, out, at: int) -> None:
+        """fd_pack.cpp write_stats: [pending, evicted, dropped_votes,
+        votes_dropped_while_regular_pending, scheduled_votes,
+        conflict_skips] at out[at:]."""
+        (self.last_pending, self.stat_evicted, self.stat_dropped_votes,
+         self.stat_votes_dropped_regular_pending, self.stat_scheduled_votes,
+         self.stat_conflict_skips) = out[at:at + 6]
 
     def insert_burst(self, entries) -> bytes:
         """One crossing for a burst of verified frags.
@@ -182,29 +196,27 @@ class NativePack:
         buf = b"".join(parts)
         codes = ctypes.create_string_buffer(max(n, 1))
         rc = self._lib.fd_pack_insert_burst(self._h, buf, len(buf), n, codes,
-                                            self._pending_out)
+                                            self._stats_out)
         if rc != n:
             raise NativeUnavailable(f"fd_pack_insert_burst rc={rc}")
-        self.last_pending = int(self._pending_out[0])
+        self._take_stats(self._stats_out, 0)
         return codes.raw[:n]
 
-    def schedule(self, bank: int, *, votes: bool = False, mb_seq: int = 0,
-                 any_pool: bool = False):
+    def schedule(self, bank: int, *, mb_seq: int = 0):
         """-> (frame_bytes, txn_cnt, cu, tsorig) or None when nothing is
         schedulable.  The frame is publish-ready (u32 mb_seq | u16 cnt |
-        (u16 len || frag)*), byte-identical to the Python lane's _emit.
-        any_pool=True tries the regular pool then the vote pool in ONE
-        crossing (the pack stage's fallback order)."""
+        (u16 len || frag)*), byte-identical to the Python lane's _emit:
+        votes first up to their share, then the regular pool
+        (Pack.schedule_next_microblock), in ONE crossing."""
         rc = self._lib.fd_pack_schedule(
-            self._h, bank, 2 if any_pool else (1 if votes else 0),
-            mb_seq & 0xFFFFFFFF,
+            self._h, bank, mb_seq & 0xFFFFFFFF,
             self._frame_buf, self.FRAME_CAP, self._meta,
         )
-        self.last_pending = int(self._meta[3])
-        if rc == 0:
-            return None
         if rc < 0:
             raise NativeUnavailable(f"fd_pack_schedule rc={rc}")
+        self._take_stats(self._meta, 3)
+        if rc == 0:
+            return None
         return (
             self._frame_buf.raw[:rc],
             int(self._meta[0]),
@@ -222,8 +234,8 @@ class NativePack:
         """Pack.shed_lowest parity: drop up to n lowest-priority pending
         regular txns in ONE crossing (votes never shed); the post-op
         pool size piggybacks so the policy stays zero-FFI."""
-        shed = int(self._lib.fd_pack_shed(self._h, n, self._pending_out))
-        self.last_pending = int(self._pending_out[0])
+        shed = int(self._lib.fd_pack_shed(self._h, n, self._stats_out))
+        self.last_pending = int(self._stats_out[0])
         return shed
 
     def pending_cnt(self) -> int:

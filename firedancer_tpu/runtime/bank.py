@@ -60,6 +60,16 @@ _TXN_SUCCESS = None
 _now_ns = None
 
 
+def is_simple_vote(payload: bytes, desc_bytes: bytes) -> bool:
+    """pack/cost.py's is_simple_vote over the packed descriptor: one
+    instruction, and the vote program's."""
+    db = desc_bytes
+    if db[16] != 1:
+        return False
+    o = (db[9] | (db[10] << 8)) + 32 * db[17]
+    return payload[o : o + 32] == ft.VOTE_PROGRAM
+
+
 def parse_microblock(frame: bytes) -> tuple[int, list[bytes]]:
     """-> (mb_seq, [verified-frag bytes])."""
     mb_seq = int.from_bytes(frame[:4], "little")
@@ -88,6 +98,7 @@ class BankCtx:
         status_cache=None,
         blockhashes: tuple[bytes, ...] = (),
         executor=None,
+        slot_hashes: list[tuple[int, bytes]] | None = None,
     ):
         from firedancer_tpu.funk import make_funk
 
@@ -101,6 +112,9 @@ class BankCtx:
         self._parent_bank_hash = parent_bank_hash
         self._parent_xid = parent_xid
         self._executor = executor
+        # the SlotHashes sysvar the slot runs under, newest first (None:
+        # default_sysvars' empty one, under which every vote rejects)
+        self._slot_hashes = slot_hashes
         self._sx = None
         # force the native executor .so build/load NOW (one g++ shell-out
         # on cold hosts), not inside the first microblock's after_frag —
@@ -139,6 +153,7 @@ class BankCtx:
                 parent_xid=self._parent_xid,
                 executor=self._executor,
                 status_cache=self.status_cache,
+                slot_hashes=self._slot_hashes,
             )
         return self._sx
 
@@ -158,28 +173,117 @@ class BankCtx:
         self.sx.publish()
 
 
-def default_bank_ctx(
+# size_of::<VoteStateVersions>() and its rent-exempt minimum under the
+# default Rent ((3762 + 128) bytes x 3480 lamports a byte-year x 2 years)
+VOTE_ACCOUNT_LAMPORTS = 27_074_400
+
+
+def genesis_bank_ctx(
     *,
     slot: int = 1,
     seed: bytes = b"benchg",
     n_payers: int = 8,
     payer_lamports: int = 10**12,
     with_status_cache: bool = True,
+    payers=None,
+    voters=(),
+    slot_hashes=None,
+    preload=(),
 ) -> BankCtx:
-    """A ctx pre-funded for the synthetic benchg load: the generator's
-    payer accounts exist with lamports (fees + transfers clear) and the
-    pool's blockhash passes the status-cache currency gate."""
+    """The bank a leader enters its slot with, made from a seed.
+
+    Payers: the synthetic load's `n_payers` keypairs off `seed`
+    (runtime/benchg.pool_payers), or the explicit `payers` pubkeys in
+    their place, each funded; the pool's blockhash passes the
+    status-cache currency gate.
+
+    voters: (identity pubkey, vote account address) pairs.  Each
+    identity is funded as a fee payer; each vote account holds a
+    current-version VoteState (3,762 bytes, owner the vote program)
+    with the identity as node, authorized voter and withdrawer.
+
+    slot_hashes: the SlotHashes sysvar as (slot, hash) pairs, newest
+    first; `slot`, the bank's, lies after them.  With it the sysvar is
+    readable by the vote program and, as Clock, nameable as an account
+    (Agave's vote instruction names both): the two sysvar accounts are
+    created with the blobs the slot runs under.
+
+    preload: addresses pushed into the native session before the first
+    microblock (BankCtx.preload), so that no first touch punts."""
     from firedancer_tpu.flamenco.blockstore import StatusCache
+    from firedancer_tpu.flamenco.runtime import acct_build
     from .benchg import pool_blockhash, pool_payers
 
+    if slot_hashes is not None:
+        slot_hashes = list(slot_hashes)
     ctx = BankCtx(
         slot=slot,
         status_cache=StatusCache() if with_status_cache else None,
         blockhashes=(pool_blockhash(seed),),
+        slot_hashes=slot_hashes,
     )
-    for _, pub in pool_payers(seed, n_payers):
+    if payers is None:
+        payers = [pub for _, pub in pool_payers(seed, n_payers)]
+    for pub in payers:
         ctx.fund(pub, payer_lamports)
+    if voters:
+        from firedancer_tpu.flamenco import agave_state as ast
+        from firedancer_tpu.flamenco.vote_program import VOTE_STATE_SIZE
+        from firedancer_tpu.protocol.txn import VOTE_PROGRAM
+
+        for identity, vote_account in voters:
+            ctx.fund(identity, payer_lamports)
+            state = ast.vote_state_encode(ast.VoteState(
+                node_pubkey=identity, authorized_withdrawer=identity,
+                authorized_voters={0: identity}))
+            ctx.funk.rec_insert(None, vote_account, acct_build(
+                VOTE_ACCOUNT_LAMPORTS, state.ljust(VOTE_STATE_SIZE, b"\x00"),
+                owner=VOTE_PROGRAM))
+    if slot_hashes is not None:
+        from firedancer_tpu.flamenco import types as T
+        from firedancer_tpu.flamenco.runtime import default_sysvars
+        from firedancer_tpu.flamenco.solcompat import SYSVAR_NAMES, SYSVAR_OWNER
+
+        # the blobs SlotExecution will run the slot under (the fork is
+        # prepared at the first `ctx.sx`: genesis writes come before it)
+        blobs = {"clock": default_sysvars(slot)["clock"],
+                 "slot_hashes": T.SLOT_HASHES.encode(
+                     [T.SlotHash(s, h) for s, h in slot_hashes])}
+        for addr, name in SYSVAR_NAMES.items():
+            if name in blobs:
+                ctx.funk.rec_insert(None, addr, acct_build(
+                    1, blobs[name], owner=SYSVAR_OWNER))
+    if preload:
+        ctx.preload(preload)
     return ctx
+
+
+def seeded_validators(seed: bytes = b"benchg", *, n_voters: int,
+                      n_slot_hashes: int, first_slot: int = 1) -> dict:
+    """`genesis_bank_ctx`'s `voters`, `slot_hashes` and `slot` for a
+    validator set and a SlotHashes sysvar made from a seed: validator k
+    has the identity key of secret sha256(seed | "voter<k>") and the
+    vote account sha256(seed | "voteacct<k>"); slot s of the
+    `n_slot_hashes` from `first_slot` on has the hash
+    sha256(seed | "slothash<s>"); the bank's slot follows them."""
+    from firedancer_tpu.ops.ref import ed25519_ref as ref
+
+    voters = [(ref.public_key(hashlib.sha256(seed + b"voter%d" % k).digest()),
+               hashlib.sha256(seed + b"voteacct%d" % k).digest())
+              for k in range(n_voters)]
+    last = first_slot + n_slot_hashes - 1
+    return {"voters": voters,
+            "slot_hashes": [
+                (s, hashlib.sha256(seed + b"slothash%d" % s).digest())
+                for s in range(last, first_slot - 1, -1)],
+            "slot": last + 1}
+
+
+def default_bank_ctx(**kw) -> BankCtx:
+    """`genesis_bank_ctx`'s payer-only case: a ctx pre-funded for the
+    synthetic benchg load (slot, seed, n_payers, payer_lamports,
+    with_status_cache)."""
+    return genesis_bank_ctx(**kw)
 
 
 class BankStage(Stage):
@@ -189,6 +293,11 @@ class BankStage(Stage):
             fm.MetricsSchema()
             .counter("txn_exec", "txns landed (fee charged)")
             .counter("txn_exec_failed", "landed txns whose program failed")
+            .counter("txn_exec_votes", "simple votes among txn_exec")
+            .counter("txn_exec_failed_votes",
+                     "simple votes among txn_exec_failed (a vote that"
+                     " lands after a later one of its validator fails"
+                     " VoteTooOld and still pays its fee)")
             .counter("txn_rejected", "txns with no on-chain footprint")
             .counter("microblocks", "microblocks committed")
             .counter("native_exec",
@@ -374,12 +483,16 @@ class BankStage(Stage):
                     # rings: result accounting only, straight off the
                     # frag bytes — no payload/descriptor slices, no
                     # per-frag tuple list
-                    n_ok, n_fail, n_rej = sx.native_apply_group(
-                        frags, recs)
+                    (n_ok, n_fail, n_rej, n_vote,
+                     n_vote_fail) = sx.native_apply_group(frags, recs)
                     if n_ok:
                         self.metrics.inc("txn_exec", n_ok)
                     if n_fail:
                         self.metrics.inc("txn_exec_failed", n_fail)
+                    if n_vote:
+                        self.metrics.inc("txn_exec_votes", n_vote)
+                    if n_vote_fail:
+                        self.metrics.inc("txn_exec_failed_votes", n_vote_fail)
                     if n_rej:
                         self.metrics.inc("txn_rejected", n_rej)
                     self.metrics.inc("native_exec", n_done)
@@ -406,6 +519,7 @@ class BankStage(Stage):
                         n_ok += 1
                         if status != TXN_SUCCESS:
                             n_fail += 1
+                        self._count_vote(p, db, status != TXN_SUCCESS)
                     else:
                         n_rej += 1
                 if batch:
@@ -440,6 +554,7 @@ class BankStage(Stage):
                         self.metrics.inc("txn_exec")
                         if r.status != TXN_SUCCESS:
                             self.metrics.inc("txn_exec_failed")
+                        self._count_vote(p, db, r.status != TXN_SUCCESS)
                     else:
                         self.metrics.inc("txn_rejected")
                 self.metrics.inc("microblocks")
@@ -471,6 +586,16 @@ class BankStage(Stage):
                 self._armed_ctx = nat
             except bd.NativeUnavailable:
                 self._disarm_native()
+
+    def _count_vote(self, payload: bytes, desc_bytes: bytes,
+                    failed: bool) -> None:
+        """A landed txn on the Python-lane paths: count it if it is a
+        simple vote (the sweep drain counts its own in
+        SlotExecution.native_apply_group)."""
+        if is_simple_vote(payload, desc_bytes):
+            self.metrics.inc("txn_exec_votes")
+            if failed:
+                self.metrics.inc("txn_exec_failed_votes")
 
     def after_frag(self, in_idx: int, meta, payload: bytes) -> None:
         from firedancer_tpu.flamenco.runtime import TXN_SUCCESS
@@ -517,6 +642,7 @@ class BankStage(Stage):
                 self.metrics.inc("txn_exec")
                 if r.status != TXN_SUCCESS:
                     self.metrics.inc("txn_exec_failed")
+                self._count_vote(p, db, r.status != TXN_SUCCESS)
             else:
                 # no on-chain footprint: never recorded in an entry
                 self.metrics.inc("txn_rejected")

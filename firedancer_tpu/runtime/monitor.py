@@ -322,6 +322,7 @@ class MonitorSession:
             row["sweep_phases"] = fm.nsweep_phase_row([j.registry])
             row["batch_closes"] = fm.batch_close_row([j.registry])
             row["mesh"] = fm.mesh_row(j.registry)
+            row["votes"] = fm.vote_row(j.registry)
             out.append(row)
         for logical, js in groups.items():
             sigs = [j.cnc.signal for j in js]
@@ -424,6 +425,12 @@ class MonitorSession:
                     " useful lanes " + " ".join(
                         f"s{i}={v:,}"
                         for i, v in enumerate(mesh["shard_elems"])))
+            votes = r.get("votes")
+            if votes:
+                # pack: votes scheduled / dropped, scan steps over a
+                # locked account; a bank: votes landed / landed failed
+                lines.append(f"{r['stage']}: votes " + " ".join(
+                    f"{k}={v:,}" for k, v in votes.items()))
         return "\n".join(lines)
 
     def run(self, *, interval_s: float = 1.0, iterations: int | None = None,

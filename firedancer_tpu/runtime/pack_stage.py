@@ -28,6 +28,12 @@ Microblock frame: u32 bank_seq | u16 txn_cnt | (u16 len || verified-frag)*
 where each verified-frag is payload||packed-desc||u16 (runtime/verify.py) —
 banks never reparse.
 
+What a microblock holds (one rule, both lanes; pack/scheduler.py
+schedule_next_microblock): votes first, up to three quarters of the cost
+the block has left and of the microblock's transaction slots, then the
+regular pool fills the rest, all under the block's limits and the banks'
+account locks.  A full pool never gives up a vote for a non-vote.
+
 Batching policy (shared by both lanes): a microblock is scheduled for an
 idle bank when at least `min_pending` txns are waiting, the oldest has
 waited `mb_deadline_s`, or — the ADAPTIVE close — the txn inputs ran dry
@@ -54,7 +60,9 @@ class PackStage(Stage):
         return (
             fm.MetricsSchema()
             .counter("txn_in", "verified txns accepted into the pool")
-            .counter("txn_dropped", "txns the pool rejected (full/limits)")
+            .counter("txn_dropped",
+                     "txns the pool rejected (full/limits) or evicted for"
+                     " a better newcomer")
             .counter("bad_frag", "malformed verified-frags dropped")
             .counter("dedup_dup",
                      "duplicate txns dropped by the fused dedup probe"
@@ -63,6 +71,19 @@ class PackStage(Stage):
             .counter("microblocks", "microblocks scheduled to banks")
             .counter("microblock_done", "bank completion acks consumed")
             .counter("txn_scheduled", "txns scheduled into microblocks")
+            .counter("txn_scheduled_votes",
+                     "simple votes among txn_scheduled (each microblock"
+                     " takes votes first, up to 3/4 of its cost and slots)")
+            .counter("txn_dropped_votes",
+                     "simple votes among txn_dropped (refused by, or"
+                     " evicted from, a pool full of votes)")
+            .counter("votes_dropped_while_regular_pending",
+                     "votes dropped while a non-vote was pooled: the"
+                     " guarantee says none, so anything but 0 is a fault")
+            .counter("conflict_skips",
+                     "pending txns a schedule scan passed over because an"
+                     " account they touch is locked by this or another"
+                     " bank's microblock")
             .counter("cu_consumed",
                      "cost units of every txn scheduled (the block cost"
                      " model, pack/cost.py)")
@@ -114,6 +135,7 @@ class PackStage(Stage):
         self._bank_busy = [False] * bank_cnt
         self._mb_seq = 0
         self._first_pending_at: float | None = None
+        self._pack_stats = [0] * len(self._PACK_STATS)
         self._input_idle = False  # stamped in before_credit (has_pending)
         # first-sig -> tsorig for end-to-end latency attribution; bounded:
         # entries for txns evicted from the pool would otherwise leak
@@ -137,6 +159,23 @@ class PackStage(Stage):
 
     def _make_pack(self, **kw):
         return Pack(**kw)
+
+    # the pool's own cumulative counts (Pack.stat_*; the native lane's
+    # come back with every crossing) -> this stage's counters
+    _PACK_STATS = (("stat_evicted", "txn_dropped"),
+                   ("stat_dropped_votes", "txn_dropped_votes"),
+                   ("stat_votes_dropped_regular_pending",
+                    "votes_dropped_while_regular_pending"),
+                   ("stat_scheduled_votes", "txn_scheduled_votes"),
+                   ("stat_conflict_skips", "conflict_skips"))
+
+    def _sync_pack_stats(self) -> None:
+        pack, seen = self.pack, self._pack_stats
+        for k, (attr, counter) in enumerate(self._PACK_STATS):
+            v = getattr(pack, attr)
+            if v != seen[k]:
+                self.metrics.inc(counter, v - seen[k])
+                seen[k] = v
 
     # -- callbacks ----------------------------------------------------------
 
@@ -194,6 +233,9 @@ class PackStage(Stage):
                 break  # nothing schedulable right now (conflicts/empty)
         if self._pending_cnt() == 0:
             self._first_pending_at = None
+
+    def during_housekeeping(self) -> None:
+        self._sync_pack_stats()
 
     # -- internals ----------------------------------------------------------
 
@@ -259,8 +301,6 @@ class PackStage(Stage):
     def _try_emit(self, bank: int) -> bool:
         chosen = self.pack.schedule_next_microblock(bank)
         if not chosen:
-            chosen = self.pack.schedule_next_microblock(bank, votes=True)
-        if not chosen:
             return False
         self._emit(bank, chosen)
         return True
@@ -299,6 +339,7 @@ class PackStage(Stage):
         their done feedback for this to terminate."""
         self.force_flush = True
         self.after_credit()
+        self._sync_pack_stats()
 
 
 class NativePackStage(PackStage):
@@ -377,8 +418,7 @@ class NativePackStage(PackStage):
         return self.pack.last_pending + len(self._burst)
 
     def _try_emit(self, bank: int) -> bool:
-        # regular-then-votes fallback inside ONE crossing (votes=2)
-        res = self.pack.schedule(bank, mb_seq=self._mb_seq, any_pool=True)
+        res = self.pack.schedule(bank, mb_seq=self._mb_seq)
         if res is None:
             return False
         frame, txn_cnt, cu, tsorig = res

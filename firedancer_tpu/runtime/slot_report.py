@@ -138,6 +138,11 @@ def _stage_block(mets: dict, records: list) -> dict:
     mesh = fm.mesh_row(mets)
     if mesh:
         block["mesh"] = mesh
+    # pack and the banks: votes scheduled / dropped / landed / landed
+    # failed, and the scan steps pack made over a locked account
+    votes = fm.vote_row(mets)
+    if votes:
+        block["votes"] = votes
     return block
 
 

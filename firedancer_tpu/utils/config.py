@@ -74,6 +74,16 @@ class LedgerConfig:
 
 
 @dataclass
+class GenesisConfig:
+    # what the leader's bank holds when its slot opens, beside the funded
+    # payers (runtime/bank.genesis_bank_ctx): a validator set's vote
+    # accounts and the SlotHashes sysvar their votes are checked against
+    # (0 / 0: none, and every vote rejects)
+    n_voters: int = 0
+    slot_hashes: int = 0
+
+
+@dataclass
 class LogConfig:
     path: str = ""
     level_stderr: str = "NOTICE"
@@ -89,6 +99,7 @@ class Config:
     shred: ShredConfig = field(default_factory=ShredConfig)
     net: NetConfig = field(default_factory=NetConfig)
     ledger: LedgerConfig = field(default_factory=LedgerConfig)
+    genesis: GenesisConfig = field(default_factory=GenesisConfig)
     log: LogConfig = field(default_factory=LogConfig)
 
 
@@ -150,5 +161,8 @@ def _validate(cfg: Config) -> None:
         raise ConfigError("verify.devices must be >= 1 and divide verify.batch")
     if cfg.poh.hashes_per_tick < 1 or cfg.poh.ticks_per_slot < 1:
         raise ConfigError("poh cadence must be positive")
+    if cfg.genesis.n_voters < 0 or not 0 <= cfg.genesis.slot_hashes <= 512:
+        raise ConfigError("genesis.n_voters must be >= 0 and "
+                          "genesis.slot_hashes in [0, 512]")
     if cfg.shred.batch_target_sz < 1:
         raise ConfigError("shred.batch_target_sz must be positive")

@@ -518,6 +518,31 @@ def mesh_row(src) -> dict | None:
                             for i in range(n)]}
 
 
+# What the pack stage and the banks count of votes and of account
+# conflicts (counter -> the key the monitor and slotreport show it by)
+VOTE_COUNTERS = {
+    "txn_scheduled_votes": "scheduled",          # pack
+    "txn_dropped_votes": "dropped",
+    "votes_dropped_while_regular_pending": "dropped_while_regular_pending",
+    "conflict_skips": "conflict_skips",
+    "txn_exec_votes": "exec",                    # bank
+    "txn_exec_failed_votes": "exec_failed",
+}
+
+
+def vote_row(src) -> dict | None:
+    """{key: count} of VOTE_COUNTERS' counters that the stage has, from
+    its registry (the monitor) or a dict of its metrics (slotreport);
+    None where the stage is neither the pack stage nor a bank."""
+    if src is None:
+        return None
+    if isinstance(src, MetricsRegistry):
+        have = {n: src.get(n) for n in VOTE_COUNTERS if n in src._off}
+    else:
+        have = {n: src[n] for n in VOTE_COUNTERS if n in src}
+    return {VOTE_COUNTERS[n]: int(v or 0) for n, v in have.items()} or None
+
+
 def batch_stall_arg(phase: int, ns: int) -> int:
     """EV_BATCH_STALL's arg: phase id in the high half, whole ms below."""
     return (phase << 32) | min(ns // 1_000_000, 0xFFFFFFFF)

@@ -78,6 +78,20 @@ static const Key STAKE_KEY = {
     'S', 't', 'a', 'k', 'e', '1', '1', '1', '1', '1',
 };
 
+// "ComputeBudget111111111111111111111111111111" (pack/cost.py)
+static const Key CB_KEY = {
+    0x03, 0x06, 0x46, 0x6f, 0xe5, 0x21, 0x17, 0x32,
+    0xff, 0xec, 0xad, 0xba, 0x72, 0xc3, 0x9b, 0xe7,
+    0xbc, 0x8c, 0xe5, 0xbb, 0xc5, 0xf7, 0x12, 0x6b,
+    0x2c, 0x43, 0x9b, 0x3a, 0x40, 0x00, 0x00, 0x00,
+};
+// builtin costs charged against the txn's compute budget
+// (pack/cost.py BUILTIN_COST, executor.execute_instr's up-front charge;
+// this lane's stake key is flamenco/stake.py's, which the table lacks)
+constexpr u64 CU_SYSTEM = 150, CU_VOTE = 2100, CU_COMPUTE_BUDGET = 150;
+constexpr u64 DEFAULT_INSTR_CU_LIMIT = 200000, MAX_CU_LIMIT = 1400000;
+constexpr u64 MICRO_LAMPORTS_PER_LAMPORT = 1000000;
+
 // typed failures: InstrError family mapped to the runtime's txn status
 struct Err { i64 status; };
 // this lane is not sure -> the caller runs the txn through Python
@@ -1182,7 +1196,64 @@ static TxnResult execute_txn(const TxnIn& in, Overlay& ov, u64 lps,
       if (std::memcmp(T.addr(i), T.addr(j), 32) == 0)
         return TxnResult{ST_ACCT, 0, {}};
 
-  u64 fee = lps * d.sig_cnt;
+  // the txn's compute budget and priority fee (pack/cost.py
+  // txn_budget_fee: _cbp_parse + _cbp_finalize).  A malformed
+  // compute-budget instruction punts: the Python lane owns that
+  // failure (fee without the priority part, TXN_ERR_PROGRAM)
+  u64 cu_limit;
+  u64 prio_fee = 0;
+  {
+    bool set_cu = false, set_fee = false, set_heap = false, total = false;
+    u64 cb_cnt = 0, cb_cu = 0, cb_total_fee = 0, cb_price = 0;
+    for (u32 k = 0; k < d.instr_cnt; k++) {
+      const Instr& ins = d.instrs[k];
+      if (ins.prog >= d.acct_cnt ||
+          std::memcmp(T.addr(ins.prog), CB_KEY.data(), 32) != 0)
+        continue;
+      if ((u64)ins.data_off + ins.data_sz > in.payload_sz) throw Punt{};
+      const u8* cb = in.payload + ins.data_off;
+      u32 n = ins.data_sz;
+      if (n < 5) throw Punt{};
+      if (cb[0] == 0) {  // RequestUnitsDeprecated
+        if (n != 9 || set_cu || set_fee) throw Punt{};
+        cb_cu = rd32(cb + 1);
+        cb_total_fee = rd32(cb + 5);
+        if (cb_cu > MAX_CU_LIMIT) throw Punt{};
+        set_cu = set_fee = total = true;
+      } else if (cb[0] == 1) {  // RequestHeapFrame
+        u32 heap = n == 5 ? rd32(cb + 1) : 1;
+        if (n != 5 || set_heap || heap % 1024 || heap < 32 * 1024 ||
+            heap > 256 * 1024)
+          throw Punt{};
+        set_heap = true;
+      } else if (cb[0] == 2) {  // SetComputeUnitLimit
+        if (n != 5 || set_cu) throw Punt{};
+        cb_cu = rd32(cb + 1);
+        if (cb_cu > MAX_CU_LIMIT) throw Punt{};
+        set_cu = true;
+      } else if (cb[0] == 3) {  // SetComputeUnitPrice
+        if (n != 9 || set_fee) throw Punt{};
+        cb_price = rd64(cb + 1);
+        set_fee = true;
+      } else {
+        throw Punt{};
+      }
+      cb_cnt++;
+    }
+    cu_limit = set_cu ? cb_cu : (d.instr_cnt - cb_cnt) * DEFAULT_INSTR_CU_LIMIT;
+    if (cu_limit > MAX_CU_LIMIT) cu_limit = MAX_CU_LIMIT;
+    if (total) {
+      prio_fee = cb_total_fee;
+    } else {
+      // ceil(cu_limit * price / 1e6): cu <= 2^21, price < 2^64
+      unsigned __int128 f =
+          ((unsigned __int128)cu_limit * cb_price +
+           MICRO_LAMPORTS_PER_LAMPORT - 1) / MICRO_LAMPORTS_PER_LAMPORT;
+      if (f > (unsigned __int128)(U64_MAX - lps * d.sig_cnt)) throw Punt{};
+      prio_fee = (u64)f;
+    }
+  }
+  u64 fee = lps * d.sig_cnt + prio_fee;
   Key payer_key;
   std::memcpy(payer_key.data(), T.addr(0), 32);
   Acct payer;
@@ -1246,6 +1317,7 @@ static TxnResult execute_txn(const TxnIn& in, Overlay& ov, u64 lps,
     return r;
   };
 
+  u64 cu_used = 0;
   for (u32 k = 0; k < d.instr_cnt; k++) {
     const Instr& ins = d.instrs[k];
     if (ins.prog >= d.acct_cnt) return fail(ST_ACCT);
@@ -1263,12 +1335,24 @@ static TxnResult execute_txn(const TxnIn& in, Overlay& ov, u64 lps,
     const u8* data = in.payload + ins.data_off;
     const u8* progkey = T.addr(ins.prog);
     try {
+      // a builtin charges its fixed cost against the txn's budget
+      // before it runs (executor.execute_instr -> TxnCtx.charge)
+      auto charge = [&](u64 cu) {
+        cu_used += cu;
+        if (cu_used > cu_limit) throw Err{ST_PROG};
+      };
       if (std::memcmp(progkey, SYS_KEY.data(), 32) == 0) {
+        charge(CU_SYSTEM);
         system_instr(T, ia, data, ins.data_sz, env);
       } else if (std::memcmp(progkey, VOTE_KEY.data(), 32) == 0) {
+        charge(CU_VOTE);
         vote_instr(T, ia, data, ins.data_sz, env);
       } else if (std::memcmp(progkey, STAKE_KEY.data(), 32) == 0) {
         stake_instr(T, ia, data, ins.data_sz, env);
+      } else if (std::memcmp(progkey, CB_KEY.data(), 32) == 0) {
+        // parsed above; executing it touches no account
+        // (programs.compute_budget_program re-validates only)
+        charge(CU_COMPUTE_BUDGET);
       } else {
         throw Punt{};  // BPF / other builtins: Python lane
       }

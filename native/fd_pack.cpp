@@ -3,7 +3,9 @@
 // Counterpart of the reference's ballet/pack library (fd_pack.c): a
 // priority-ordered pending pool (treap role: ordered iteration +
 // O(log n) insert/delete) with EXACT reward/cost comparison
-// (r1*c2 > r2*c1, no floating point), a separate simple-vote pool,
+// (r1*c2 > r2*c1, no floating point), a separate simple-vote pool that
+// every microblock draws from first (fd_pack_schedule_next_microblock's
+// vote_fraction) and that a full pool never trims for a non-vote,
 // per-account reader/writer conflict masks over an interned account
 // table (fd_pack_bitset.h semantics), and the consensus-critical block
 // limits (total/vote/per-writer cost, data bytes incl. the 48-byte
@@ -63,6 +65,9 @@ constexpr u64 FEE_PER_SIGNATURE = 5000;
 constexpr u64 DEFAULT_HEAP_SIZE = 32 * 1024;
 constexpr u64 MAX_HEAP_SIZE = 256 * 1024;
 constexpr u64 MICROBLOCK_DATA_OVERHEAD = 48;
+// the pack tile's VOTE_FRACTION 0.75 (pack/scheduler.py VOTE_FRACTION_*)
+constexpr u64 VOTE_FRACTION_NUM = 3;
+constexpr u64 VOTE_FRACTION_DEN = 4;
 
 // insert result codes (pack/scheduler_native.py maps them to metrics)
 constexpr u8 INS_OK = 0;         // accepted into the pool
@@ -578,6 +583,13 @@ struct Pack {
   u64 cost_used = 0, vote_cost_used = 0, data_bytes_used = 0;
   u64 seq_next = 0;
   u64 mb_gen = 0;
+  // cumulative counts every crossing reports (write_stats): pooled txns
+  // a newcomer evicted; votes refused or evicted; those of them with a
+  // non-vote pooled (the guarantee: stays 0); votes scheduled; scan
+  // steps over a txn whose account is in use
+  u64 stat_evicted = 0, stat_dropped_votes = 0;
+  u64 stat_votes_dropped_regular_pending = 0;
+  u64 stat_scheduled_votes = 0, stat_conflict_skips = 0;
   // fused dedup: the facade wires the EXISTING fd_tcache.so table in
   void* tcache = nullptr;
   tcache_insert_fn tcache_insert = nullptr;
@@ -620,6 +632,25 @@ static void build_acct_refs(Pack& P, Node& n, const u8* payload,
   }
   for (u32 k = 0; k < d.lut_cnt; k++)
     add(payload + d.luts[k].addr_off, AF_LW);
+}
+
+// out6 = [pending, evicted, dropped_votes,
+//         votes_dropped_while_regular_pending, scheduled_votes,
+//         conflict_skips]: the pool size and the five counts, so the
+// facade never pays a crossing of its own for them
+static void write_stats(const Pack& P, u64* out6) {
+  out6[0] = P.pending.size + P.pending_votes.size;
+  out6[1] = P.stat_evicted;
+  out6[2] = P.stat_dropped_votes;
+  out6[3] = P.stat_votes_dropped_regular_pending;
+  out6[4] = P.stat_scheduled_votes;
+  out6[5] = P.stat_conflict_skips;
+}
+
+// Pack._count_vote_drop
+static void count_vote_drop(Pack& P) {
+  P.stat_dropped_votes++;
+  if (P.pending.size) P.stat_votes_dropped_regular_pending++;
 }
 
 static void pool_remove(Pack& P, int id) {
@@ -665,19 +696,25 @@ static u8 insert_one(Pack& P, const u8* frag, u32 frag_len, u64 tag,
   build_acct_refs(P, n, payload, d);
 
   if (P.pending.size + P.pending_votes.size >= P.depth) {
-    // full: evict the GLOBALLY lowest-priority txn iff the newcomer
-    // strictly beats it (both pools' tails; ratio-only compare, the
-    // pending pool's tail wins ties -- pack/scheduler.py insert)
-    int wp = P.pending.worst(P.nodes);
-    int wv = P.pending_votes.worst(P.nodes);
-    int worst = wp;
-    if (worst < 0) worst = wv;
-    else if (wv >= 0 && ratio_lt(P.nodes[wp], P.nodes[wv])) worst = wv;
-    if (worst < 0 || !ratio_lt(n, P.nodes[worst])) {
+    // full (pack/scheduler.py insert): while a non-vote is pooled a
+    // vote is never the one to go -- an arriving vote takes the place
+    // of the worst non-vote whatever the ratios say; a non-vote evicts
+    // the worst non-vote iff it strictly beats it (ratio-only compare)
+    // and never a vote; among votes alone the ratio decides
+    int worst = P.pending.worst(P.nodes);
+    if (worst >= 0) {
+      if (!n.is_vote && !ratio_lt(n, P.nodes[worst])) worst = -1;
+    } else {
+      int wv = P.pending_votes.worst(P.nodes);
+      if (n.is_vote && wv >= 0 && ratio_lt(n, P.nodes[wv])) worst = wv;
+      if (n.is_vote) count_vote_drop(P);  // the newcomer or the one it evicts
+    }
+    if (worst < 0) {
       P.free_ids.push_back(id);
       return INS_FULL;
     }
     pool_remove(P, worst);
+    P.stat_evicted++;
   }
   (n.is_vote ? P.pending_votes : P.pending).insert(P.nodes, id);
   P.sigs.put(n.sig, id);
@@ -725,11 +762,12 @@ void fd_pack_set_tcache(void* h, void* tcache, void* insert_fn) {
 // One crossing per burst: `buf` holds n entries of
 //   u16 frag_len | u64 tag | u64 tsorig | frag bytes
 // out_codes[i] gets the per-frag INS_* result.  Returns entries
-// consumed, or -1 on a malformed buffer.  out_pending (optional) gets
-// the post-burst pool size, so the facade never pays a separate
-// crossing just to know whether scheduling is worth attempting.
+// consumed, or -1 on a malformed buffer.  out_stats (optional, u64[6])
+// gets write_stats' post-burst pool size and counts, so the facade never
+// pays a separate crossing just to know whether scheduling is worth
+// attempting.
 i64 fd_pack_insert_burst(void* h, const u8* buf, u64 buf_sz, u64 n,
-                         u8* out_codes, u64* out_pending) {
+                         u8* out_codes, u64* out_stats) {
   Pack* P = static_cast<Pack*>(h);
   u64 o = 0;
   for (u64 i = 0; i < n; i++) {
@@ -742,7 +780,7 @@ i64 fd_pack_insert_burst(void* h, const u8* buf, u64 buf_sz, u64 n,
     out_codes[i] = insert_one(*P, buf + o, frag_len, tag, tsorig);
     o += frag_len;
   }
-  if (out_pending) *out_pending = P->pending.size + P->pending_votes.size;
+  if (out_stats) write_stats(*P, out_stats);
   return (i64)n;
 }
 
@@ -778,30 +816,28 @@ void fd_pack_block_state(void* h, u64* out3) {
   out3[2] = P->data_bytes_used;
 }
 
-static i64 schedule_impl(Pack* P, u64 bank, int votes, u32 mb_seq, u8* out,
-                         u64 out_cap, u64* meta3) {
-  if (bank >= P->bank_cnt) return -1;
-  Treap& pool = votes ? P->pending_votes : P->pending;
-  P->mb_gen++;
-  u64 gen = P->mb_gen;
-  u64 other = ~(1ull << bank);
-
+// One pool's pass of a microblock (pack/scheduler.py Pack._scan): take
+// in priority order what neither conflicts nor breaks a limit, until the
+// microblock holds max_txn (for votes also: cost_cap cost units).
+struct MbState {
   std::vector<int> chosen;
-  chosen.reserve(P->max_txn_per_mb < 256 ? P->max_txn_per_mb : 256);
-  u64 n_chosen = 0;
-  u64 mb_cost = 0, mb_vote_cost = 0, mb_data = 0;
+  u64 cost = 0, vote_cost = 0, data = 0;
+};
 
-  // in-order scan with bounded lookahead (pack/scheduler.py
-  // schedule_next_microblock): skipped entries keep their order for
-  // free; `limit` binds the scan only once something was chosen, so an
-  // all-unschedulable WINDOW cannot starve schedulable txns past it
+static void scan_pool(Pack* P, Treap& pool, bool votes, u64 bank, u64 gen,
+                      MbState& mb, u64 max_txn, bool capped, u64 cost_cap) {
+  u64 other = ~(1ull << bank);
+  // in-order scan with bounded lookahead: skipped entries keep their
+  // order for free; `limit` binds the scan only once something was
+  // chosen, so an all-unschedulable WINDOW cannot starve schedulable
+  // txns past it
   u64 limit = pool.size < P->max_search ? pool.size : P->max_search;
   std::vector<int> stack_v;
   stack_v.reserve(64);
   int sp = 0;
   int t = pool.root;
   u64 i = 0;
-  while ((t >= 0 || sp > 0) && n_chosen < P->max_txn_per_mb) {
+  while ((t >= 0 || sp > 0) && mb.chosen.size() < max_txn) {
     while (t >= 0) {
       if (sp == (int)stack_v.size()) stack_v.push_back(t);
       else stack_v[sp] = t;
@@ -810,7 +846,7 @@ static i64 schedule_impl(Pack* P, u64 bank, int votes, u32 mb_seq, u8* out,
     }
     int cur = stack_v[--sp];
     t = P->nodes[cur].r;
-    if (i >= limit && n_chosen) break;
+    if (i >= limit && !mb.chosen.empty()) break;
     i++;
     Node& n = P->nodes[cur];
     // conflicts with in-flight banks + within this microblock, then the
@@ -828,34 +864,33 @@ static i64 schedule_impl(Pack* P, u64 bank, int votes, u32 mb_seq, u8* out,
         if ((wm & other) || (taken & 1)) bad = true;
       }
     }
-    if (!bad) {
-      // _fits_block
-      if (P->cost_used + mb_cost + n.cost > P->lim_cost) bad = true;
-      if (!bad && votes &&
-          P->vote_cost_used + mb_vote_cost + n.cost > P->lim_vote_cost)
+    if (bad) {
+      P->stat_conflict_skips++;
+      continue;
+    }
+    if (capped && mb.cost + n.cost > cost_cap) continue;
+    // _fits_block
+    if (P->cost_used + mb.cost + n.cost > P->lim_cost) continue;
+    if (votes && P->vote_cost_used + mb.vote_cost + n.cost > P->lim_vote_cost)
+      continue;
+    if (P->data_bytes_used + mb.data + n.payload_sz +
+            MICROBLOCK_DATA_OVERHEAD > P->lim_data)
+      continue;
+    for (u32 a = 0; a < n.n_accts && !bad; a++) {
+      const ARef& r = n.accts[a];
+      if (!(r.flags & AF_SW)) continue;
+      u64 mbwc = P->accts.mb_cost_gen[r.id] == gen
+                     ? P->accts.mb_write_cost[r.id]
+                     : 0;
+      if (P->accts.write_cost[r.id] + mbwc + n.cost > P->lim_write_cost)
         bad = true;
-      if (!bad && P->data_bytes_used + mb_data + n.payload_sz +
-                      MICROBLOCK_DATA_OVERHEAD > P->lim_data)
-        bad = true;
-      if (!bad) {
-        for (u32 a = 0; a < n.n_accts && !bad; a++) {
-          const ARef& r = n.accts[a];
-          if (!(r.flags & AF_SW)) continue;
-          u64 mbwc = P->accts.mb_cost_gen[r.id] == gen
-                         ? P->accts.mb_write_cost[r.id]
-                         : 0;
-          if (P->accts.write_cost[r.id] + mbwc + n.cost > P->lim_write_cost)
-            bad = true;
-        }
-      }
     }
     if (bad) continue;
     // chosen: mark within-microblock taken/cost state
-    chosen.push_back(cur);
-    n_chosen++;
-    mb_cost += n.cost;
-    if (votes) mb_vote_cost += n.cost;
-    mb_data += n.payload_sz;
+    mb.chosen.push_back(cur);
+    mb.cost += n.cost;
+    if (votes) mb.vote_cost += n.cost;
+    mb.data += n.payload_sz;
     for (u32 a = 0; a < n.n_accts; a++) {
       const ARef& r = n.accts[a];
       u8 tf = P->accts.taken_gen[r.id] == gen ? P->accts.taken_flags[r.id] : 0;
@@ -871,15 +906,46 @@ static i64 schedule_impl(Pack* P, u64 bank, int votes, u32 mb_seq, u8* out,
       }
     }
   }
+}
+
+// Schedule one conflict-free microblock for `bank` and write the
+// complete microblock FRAME (u32 mb_seq | u16 cnt | (u16 len||frag)*)
+// into out: votes first, up to VOTE_FRACTION of the cost the block has
+// left and of the microblock's transaction slots (at least one), then
+// the regular pool fills what remains (pack/scheduler.py
+// schedule_next_microblock; with no vote pooled, the regular scan alone).
+// meta9 = [txn_cnt, cu_consumed, inherited tsorig, write_stats' six].
+// Returns frame length, 0 = nothing schedulable, -1 bad args, -2 cap.
+i64 fd_pack_schedule(void* h, u64 bank, u32 mb_seq, u8* out, u64 out_cap,
+                     u64* meta9) {
+  Pack* P = static_cast<Pack*>(h);
+  if (bank >= P->bank_cnt) return -1;
+  P->mb_gen++;
+  u64 gen = P->mb_gen;
+  MbState mb;
+  mb.chosen.reserve(P->max_txn_per_mb < 256 ? P->max_txn_per_mb : 256);
+  u64 vote_txns = P->max_txn_per_mb * VOTE_FRACTION_NUM / VOTE_FRACTION_DEN;
+  if (vote_txns < 1) vote_txns = 1;
+  if (vote_txns > P->max_txn_per_mb) vote_txns = P->max_txn_per_mb;
+  u64 left = P->lim_cost > P->cost_used ? P->lim_cost - P->cost_used : 0;
+  // u128: a test's limits may sit near 2^64
+  u64 vote_cost = (u64)((u128)left * VOTE_FRACTION_NUM / VOTE_FRACTION_DEN);
+  scan_pool(P, P->pending_votes, true, bank, gen, mb, vote_txns, true,
+            vote_cost);
+  u64 n_votes = mb.chosen.size();
+  scan_pool(P, P->pending, false, bank, gen, mb, P->max_txn_per_mb, false, 0);
+  u64 n_chosen = mb.chosen.size();
+  meta9[0] = meta9[1] = meta9[2] = 0;
   if (!n_chosen) {
-    meta3[0] = meta3[1] = meta3[2] = 0;
+    write_stats(*P, meta9 + 3);
     return 0;
   }
 
   // commit: remove from pool, take locks, update block accounting, and
   // write the frame (pack/scheduler.py commit + runtime/pack_stage._emit)
   u64 need = 6;
-  for (u64 k = 0; k < n_chosen; k++) need += 2 + P->nodes[chosen[k]].frag_len;
+  for (u64 k = 0; k < n_chosen; k++)
+    need += 2 + P->nodes[mb.chosen[k]].frag_len;
   if (need > out_cap) return -2;
   wr32(out, mb_seq);
   wr16(out + 4, (u32)n_chosen);
@@ -887,7 +953,7 @@ static i64 schedule_impl(Pack* P, u64 bank, int votes, u32 mb_seq, u8* out,
   u64 cu = 0;
   u64 tsorig = 0;
   for (u64 k = 0; k < n_chosen; k++) {
-    Node& n = P->nodes[chosen[k]];
+    Node& n = P->nodes[mb.chosen[k]];
     wr16(out + o, n.frag_len);
     o += 2;
     std::memcpy(out + o, n.frag, n.frag_len);
@@ -910,35 +976,17 @@ static i64 schedule_impl(Pack* P, u64 bank, int votes, u32 mb_seq, u8* out,
       if (r.flags & AF_SW) P->accts.write_cost[r.id] += n.cost;
     }
     P->cost_used += n.cost;
-    if (votes) P->vote_cost_used += n.cost;
     P->data_bytes_used += n.payload_sz;
-    pool_remove(*P, chosen[k]);
+    pool_remove(*P, mb.chosen[k]);
   }
+  P->vote_cost_used += mb.vote_cost;
   P->data_bytes_used += MICROBLOCK_DATA_OVERHEAD;
-  meta3[0] = n_chosen;
-  meta3[1] = cu;
-  meta3[2] = tsorig;
+  P->stat_scheduled_votes += n_votes;
+  meta9[0] = n_chosen;
+  meta9[1] = cu;
+  meta9[2] = tsorig;
+  write_stats(*P, meta9 + 3);
   return (i64)o;
-}
-
-// Schedule one conflict-free microblock for `bank` and write the
-// complete microblock FRAME (u32 mb_seq | u16 cnt | (u16 len||frag)*)
-// into out.  votes: 0 = regular pool, 1 = vote pool, 2 = regular THEN
-// votes in one crossing (the pack stage's fallback order).
-// meta4 = [txn_cnt, cu_consumed, inherited tsorig, pending after].
-// Returns frame length, 0 = nothing schedulable, -1 bad args, -2 cap.
-i64 fd_pack_schedule(void* h, u64 bank, int votes, u32 mb_seq, u8* out,
-                     u64 out_cap, u64* meta4) {
-  Pack* P = static_cast<Pack*>(h);
-  i64 rc;
-  if (votes == 2) {
-    rc = schedule_impl(P, bank, 0, mb_seq, out, out_cap, meta4);
-    if (rc == 0) rc = schedule_impl(P, bank, 1, mb_seq, out, out_cap, meta4);
-  } else {
-    rc = schedule_impl(P, bank, votes, mb_seq, out, out_cap, meta4);
-  }
-  meta4[3] = P->pending.size + P->pending_votes.size;
-  return rc;
 }
 
 void fd_pack_microblock_done(void* h, u64 bank) {

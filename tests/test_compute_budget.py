@@ -28,7 +28,7 @@ from firedancer_tpu.ops.ref import ed25519_ref as ref
 from firedancer_tpu.pack.cost import (
     COMPUTE_BUDGET_PROGRAM,
     DEFAULT_HEAP_SIZE,
-    txn_budget,
+    txn_budget_fee,
 )
 from firedancer_tpu.protocol import txn as ft
 from tests.test_sbpf import build_elf, ins
@@ -51,7 +51,7 @@ def _req_heap(size: int) -> bytes:
     return bytes([1]) + size.to_bytes(4, "little")
 
 
-def test_txn_budget_resolution():
+def test_txn_budget_fee_resolution():
     secret, payer = keypair(b"cb")
     prog_key = hashlib.sha256(b"cb-prog").digest()
 
@@ -71,16 +71,20 @@ def test_txn_budget_resolution():
 
     # explicit limit wins
     p, t = build([_set_cu_limit(77_000)])
-    assert txn_budget(p, t) == (77_000, DEFAULT_HEAP_SIZE)
+    assert txn_budget_fee(p, t) == (77_000, DEFAULT_HEAP_SIZE, 0)
     # default: 200k per instruction (including the CB instr itself, capped)
     p, t = build([], n_other=2)
-    assert txn_budget(p, t) == (400_000, DEFAULT_HEAP_SIZE)
+    assert txn_budget_fee(p, t) == (400_000, DEFAULT_HEAP_SIZE, 0)
     # heap frame
     p, t = build([_req_heap(64 * 1024)])
-    assert txn_budget(p, t) == (200_000, 64 * 1024)
+    assert txn_budget_fee(p, t) == (200_000, 64 * 1024, 0)
+    # the priority fee: ceil(limit x price / 10^6) lamports
+    p, t = build([_set_cu_limit(20_000),
+                  b"\x03" + (1_000_001).to_bytes(8, "little")])
+    assert txn_budget_fee(p, t) == (20_000, DEFAULT_HEAP_SIZE, 20_001)
     # duplicate SetComputeUnitLimit = malformed
     p, t = build([_set_cu_limit(1), _set_cu_limit(2)])
-    assert txn_budget(p, t) is None
+    assert txn_budget_fee(p, t) is None
 
 
 def _loop_elf(iters: int) -> bytes:

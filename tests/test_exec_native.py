@@ -7,7 +7,8 @@ through both lanes must produce identical per-txn status codes and fees,
 an identical bank hash, and byte-identical final account state.  Since
 ISSUE 16 the native surface also covers stake-program ops and the
 durable-nonce family (the session's in-line durable gate owns the
-stale-blockhash decision); CPI/BPF/compute-budget/lookup-table txns
+stale-blockhash decision), and since ISSUE 31 the compute-budget
+instructions with the priority fee they name; CPI/BPF/lookup-table txns
 still route to the Python lane (classifier test).
 
 The whole module SKIPS (never fails) when the native lane is unavailable
@@ -398,9 +399,10 @@ def test_vote_state_bytes_identical():
 
 
 def test_fallback_routing_classifier():
-    """CPI/BPF, compute-budget and lookup-table txns never route native;
-    system transfers, votes, stake ops and the nonce family do
-    (ISSUE 16 widened the surface to stake + durable nonce)."""
+    """CPI/BPF and lookup-table txns never route native; system
+    transfers, votes, stake ops, the nonce family and compute-budget
+    instructions do (ISSUE 16 widened the surface to stake + durable
+    nonce, ISSUE 31 to the compute budget)."""
     from firedancer_tpu.protocol.base58 import b58_decode32
 
     rng = random.Random(3)
@@ -433,7 +435,7 @@ def test_fallback_routing_classifier():
     cb = _txn(rng, [p], [_pk("d"), b58_decode32(CB_PROG_B58)],
               [ft.InstrSpec(2, bytes([0]), b"\x02\x40\x42\x0f\x00")],
               ro_unsigned=1)
-    assert not eligible(cb)
+    assert eligible(cb)  # the compute budget runs native now
     vote_auth = _txn(rng, [_pk("voterA")], [_pk("voteacct"), VOTE_PROGRAM],
                      [ft.InstrSpec(2, bytes([1, 0]),
                                    T.U32.encode(1) + _pk("x")
@@ -445,6 +447,99 @@ def test_fallback_routing_classifier():
                ro_unsigned=1, version=ft.V0,
                luts=[ft.LutSpec(_pk("table"), bytes([0]), b"")])
     assert not eligible(lut)
+
+
+def _cb_stream(rng):
+    """Transfers and votes under compute-budget instructions: prices
+    from 0 to u64 scale, limits under and over what the builtins cost,
+    the deprecated units+fee form, a heap frame, malformed ones (the
+    native lane punts, Python fails them typed), a payer that covers
+    the signature fee but not the priority fee."""
+    from firedancer_tpu.protocol.base58 import b58_decode32
+
+    cbp = b58_decode32(CB_PROG_B58)
+
+    def cu(n):
+        return b"\x02" + n.to_bytes(4, "little")
+
+    def price(n):
+        return b"\x03" + n.to_bytes(8, "little")
+
+    budgets = [
+        [cu(20_000), price(1)], [cu(20_000), price(1_000_000)],
+        [price(999_999)], [cu(1_400_000), price(2**40)],
+        [cu(449), price(5)],          # three builtins cost 450: exceeded
+        [cu(450), price(5)],          # ...and exactly enough
+        [cu(0), price(7)], [cu(300)],
+        [b"\x00" + (90_000).to_bytes(4, "little")
+         + (1234).to_bytes(4, "little")],          # units + total fee
+        [b"\x01" + (64 * 1024).to_bytes(4, "little"), price(3)],
+        [cu(5), cu(6)],               # duplicate: malformed
+        [b"\x02\x01"], [b"\x09" * 5],             # truncated, unknown tag
+        [cu(20_000), price(2**63)],   # a fee no payer holds
+        [cu(1_400_000), price(2**64 - 1)],
+    ]
+    txns = []
+    for i in range(120):
+        cb = rng.choice(budgets)
+        pre = [ft.InstrSpec(3, b"", x) for x in cb]
+        if i % 3 == 0:  # a vote, priced
+            slot = 1 + (i // 3) % 39
+            data = T.U32.encode(2) + vp.VOTE_IX.encode(
+                vp.VoteIx([slot], SH[slot], 1000 + slot))
+            txns.append(_txn(rng, [_pk("voterA")],
+                             [_pk("voteacct"), VOTE_PROGRAM, cbp],
+                             pre + [ft.InstrSpec(2, bytes([1, 0]), data)],
+                             ro_unsigned=2))
+        else:
+            p = _pk(rng.choice(["payerA", "payerB", "exact"]))
+            txns.append(_txn(rng, [p],
+                             [_pk("dst%d" % (i % 7)), SYSTEM_PROGRAM, cbp],
+                             pre + [ft.InstrSpec(2, bytes([0, 1]),
+                                                 _transfer_data(1 + i))],
+                             ro_unsigned=2))
+    return txns
+
+
+@pytest.mark.parametrize("seed", [31, 32, 33])
+def test_differential_compute_budget_stream(seed):
+    """ComputeBudget native/Python parity: the same statuses, the same
+    fees — signature fee plus ceil(limit x price / 10^6), charged
+    whatever the outcome — the same bank hash and account bytes."""
+    txns = _cb_stream(random.Random(seed))
+    py = _run(txns, native=False)
+    nat = _run(txns, native=True)
+    assert py[0] == nat[0], [
+        (i, a, b) for i, (a, b) in enumerate(zip(py[0], nat[0])) if a != b
+    ][:10]
+    assert py[1] == nat[1] and py[2] == nat[2] and py[4] == nat[4]
+    fees = {f for _s, f in py[0]}
+    assert 5000 + 20_000 in fees and 5000 + 1 in fees    # priced landings
+    assert any(s == -4 and f > 5000 for s, f in py[0])   # budget exceeded
+    assert nat[5][0] > 0                                 # and ran natively
+
+
+def test_priority_fee_is_charged_to_the_payer():
+    """limit 20,000 x 1,000,000 micro-lamports = 20,000 lamports over
+    the 5,000 of the signature, on both lanes, off the fee payer."""
+    from firedancer_tpu.protocol.base58 import b58_decode32
+
+    rng = random.Random(5)
+    p, d = _pk("payerA"), _pk("dstfee")
+    txn = _txn(rng, [p], [d, SYSTEM_PROGRAM, b58_decode32(CB_PROG_B58)],
+               [ft.InstrSpec(3, b"", b"\x02" + (20_000).to_bytes(4, "little")),
+                ft.InstrSpec(3, b"", b"\x03" + (10**6).to_bytes(8, "little")),
+                ft.InstrSpec(2, bytes([0, 1]), _transfer_data(77))],
+               ro_unsigned=2)
+    from firedancer_tpu.flamenco.runtime import acct_lamports
+
+    funk0, _sc = _world()
+    before = acct_lamports(funk0.rec_query(None, p))
+    for native in (False, True):
+        res = _run([txn], native=native)
+        assert res[0] == [(0, 25_000)]
+        assert acct_lamports(res[4][p]) == before - 25_000 - 77
+        assert acct_lamports(res[4][d]) == 77
 
 
 def test_env_switch_disables():

@@ -182,16 +182,25 @@ class _Lanes:
                       else "dup" if code == sn.INS_DUP else "drop")
         assert (py_ok, py_reason) == (nat_ok, nat_reason), (
             i, py_reason, code)
+        assert self.stats(self.py) == self.stats(self.nat), i
         if not py_ok:
             self.py_drops.append((i, py_reason))
             self.nat_drops.append((i, nat_reason))
 
-    def schedule(self, bank, votes=False):
-        chosen = self.py.schedule_next_microblock(bank, votes=votes)
-        res = self.nat.schedule(bank, votes=votes, mb_seq=self.mb_seq)
+    STATS = ("stat_evicted", "stat_dropped_votes",
+             "stat_votes_dropped_regular_pending", "stat_scheduled_votes",
+             "stat_conflict_skips")
+
+    def stats(self, lane):
+        return {k: getattr(lane, k) for k in self.STATS}
+
+    def schedule(self, bank):
+        chosen = self.py.schedule_next_microblock(bank)
+        res = self.nat.schedule(bank, mb_seq=self.mb_seq)
+        assert self.stats(self.py) == self.stats(self.nat)
         if not chosen:
             assert res is None, ("native scheduled, python did not",
-                                 bank, votes, res and res[1])
+                                 bank, res and res[1])
             return False
         frame = self.mb_seq.to_bytes(4, "little")
         frame += len(chosen).to_bytes(2, "little")
@@ -199,8 +208,8 @@ class _Lanes:
             f = encode_verified(o.payload, o.desc)
             frame += len(f).to_bytes(2, "little") + f
         assert res is not None, ("python scheduled, native did not",
-                                 bank, votes, len(chosen))
-        assert res[0] == frame, ("frame mismatch", bank, votes)
+                                 bank, len(chosen))
+        assert res[0] == frame, ("frame mismatch", bank)
         assert res[1] == len(chosen)
         assert res[2] == sum(o.cost.total for o in chosen)
         self.frames.append(frame)
@@ -236,8 +245,7 @@ def test_randomized_streams_identical(seed):
         lanes.insert(i, p)
         r = rng.random()
         if r < 0.35:
-            lanes.schedule(rng.randrange(lanes.bank_cnt),
-                           votes=rng.random() < 0.3)
+            lanes.schedule(rng.randrange(lanes.bank_cnt))
         if r < 0.25:
             lanes.done(rng.randrange(lanes.bank_cnt))
         if rng.random() < 0.03:
@@ -247,7 +255,6 @@ def test_randomized_streams_identical(seed):
         progressed = False
         for b in range(lanes.bank_cnt):
             progressed |= lanes.schedule(b)
-            progressed |= lanes.schedule(b, votes=True)
             lanes.done(b)
         if not progressed:
             break
@@ -273,7 +280,7 @@ def test_limit_boundary_costs():
     for i, p in enumerate(_workload(rng, 150)):
         lanes.insert(i, p)
         if rng.random() < 0.3:
-            lanes.schedule(rng.randrange(2), votes=rng.random() < 0.4)
+            lanes.schedule(rng.randrange(2))
         if rng.random() < 0.2:
             lanes.done(rng.randrange(2))
         if rng.random() < 0.1:
@@ -291,20 +298,164 @@ def test_eviction_parity_small_pool():
         lanes.insert(i, p)
     lanes.check_accounting()
     # what remains schedules identically
-    while lanes.schedule(0) or lanes.schedule(0, votes=True):
+    while lanes.schedule(0):
         lanes.done(0)
     lanes.check_accounting()
 
 
-def test_vote_flood_separate_pool():
-    """An all-vote flood lands in the vote pool and schedules only via
-    votes=True, identically in both lanes."""
-    lanes = _Lanes(bank_cnt=2, depth=32)
-    rng = random.Random(11)
+def _is_vote(o):
+    return o.cost.is_simple_vote
+
+
+def _distinct_vote(i):
+    """A vote of validator i: its own payer and vote account, so votes
+    never conflict with each other (one a validator a slot)."""
+    sec, _pub = _keypair(b"pnvv%d" % i)
+    return ft.vote_txn(sec, hashlib.sha256(b"pnva%d" % i).digest(),
+                       100 + i, BH, bank_hash=hashlib.sha256(b"vbh").digest())
+
+
+def _distinct_transfer(i, cb=()):
+    sec, pub = _keypair(b"pntp%d" % i)
+    accts = [pub, hashlib.sha256(b"pntd%d" % i).digest(), ft.SYSTEM_PROGRAM]
+    instrs = []
+    if cb:
+        accts.append(fc.COMPUTE_BUDGET_PROGRAM)
+        instrs += [ft.InstrSpec(program_id=3, accounts=b"", data=x)
+                   for x in cb]
+    instrs.append(ft.InstrSpec(program_id=2, accounts=bytes([0, 1]),
+                               data=(2).to_bytes(4, "little")
+                               + (1 + i).to_bytes(8, "little")))
+    msg = ft.message_build(
+        version=ft.VLEGACY, signature_cnt=1, readonly_signed_cnt=0,
+        readonly_unsigned_cnt=len(accts) - 2, acct_addrs=accts,
+        recent_blockhash=BH, instrs=instrs)
+    return _sign_txn(sec, msg)
+
+
+def test_vote_flood_schedules_with_no_regular_txn():
+    """An all-vote flood lands in the vote pool and schedules by the
+    one rule, up to the votes' share of a microblock, identically in
+    both lanes."""
+    lanes = _Lanes(bank_cnt=2, depth=64, max_txn_per_microblock=8)
     for i in range(40):
-        lanes.insert(i, _vote(rng, i))
-    assert not lanes.schedule(0)          # non-vote pool is empty
-    assert lanes.schedule(0, votes=True)  # the vote pool is not
+        lanes.insert(i, _distinct_vote(i))
+    assert lanes.schedule(0)
+    assert lanes.frames[-1][4:6] == (6).to_bytes(2, "little")  # 3/4 of 8
+    assert lanes.py.stat_scheduled_votes == 6
+    lanes.check_accounting()
+
+
+@pytest.mark.parametrize("max_txn,n_votes,n_regular,want", [
+    (8, 20, 20, (6, 2)),     # votes take 3/4 of the slots, regular the rest
+    (8, 3, 20, (3, 5)),      # fewer votes than their share: regular fills
+    (8, 20, 0, (6, 0)),      # no regular: the share still caps votes
+    (8, 0, 20, (0, 8)),      # no vote: the rule is silent
+    (1, 5, 5, (1, 0)),       # a one-slot microblock still takes a vote
+    (31, 40, 40, (23, 8)),   # the pipeline's default width
+])
+def test_reservation_votes_first_then_regular(max_txn, n_votes, n_regular,
+                                              want):
+    """Each microblock takes votes first, up to VOTE_FRACTION of its
+    slots, then fills from the regular pool — though every regular
+    txn here outranks every vote by fee/cost.  Both lanes, one frame."""
+    lanes = _Lanes(bank_cnt=1, depth=256, max_txn_per_microblock=max_txn)
+    price = [_cb_cu(20_000), _cb_price(1_000_000)]
+    k = 0
+    for i in range(max(n_votes, n_regular)):
+        if i < n_regular:
+            lanes.insert(k, _distinct_transfer(i, cb=price)); k += 1
+        if i < n_votes:
+            lanes.insert(k, _distinct_vote(i)); k += 1
+    chosen = lanes.py.schedule_next_microblock(0)
+    got = [_is_vote(o) for o in chosen]
+    assert (sum(got), len(got) - sum(got)) == want
+    assert got == sorted(got, reverse=True)           # votes lead the frame
+    res = lanes.nat.schedule(0, mb_seq=0)
+    assert res is not None and res[1] == len(chosen)
+    assert res[0][6:] == b"".join(
+        len(f).to_bytes(2, "little") + f for f in
+        (encode_verified(o.payload, o.desc) for o in chosen))
+    assert lanes.stats(lanes.py) == lanes.stats(lanes.nat)
+    assert lanes.py.stat_scheduled_votes == want[0]
+    lanes.check_accounting()
+
+
+def test_reservation_vote_cost_share_of_what_the_block_has_left():
+    """Votes stop at 3/4 of the cost the block has left, before their
+    slot share is used up; the regular pool takes the rest."""
+    vote_cost = fc.compute_cost(
+        _distinct_vote(0), ft.txn_parse(_distinct_vote(0))).total
+    limits = BlockLimits(max_cost_per_block=4 * vote_cost)
+    lanes = _Lanes(bank_cnt=1, depth=64, max_txn_per_microblock=31,
+                   limits=limits)
+    for i in range(6):
+        lanes.insert(2 * i, _distinct_vote(i))
+        lanes.insert(2 * i + 1, _distinct_transfer(i))
+    assert lanes.schedule(0)
+    n = int.from_bytes(lanes.frames[-1][4:6], "little")
+    assert lanes.py.stat_scheduled_votes == 3         # 3/4 of four votes' cost
+    assert n > 3                                      # transfers filled the rest
+    lanes.check_accounting()
+
+
+@pytest.mark.parametrize("seed", [21, 22, 23])
+def test_mixed_pool_differential_under_flood(seed):
+    """70 % votes over a hot account set into a small pool: both lanes
+    emit the same frames, evict the same txns and count the same
+    drops, and no vote is dropped while a non-vote is pooled."""
+    rng = random.Random(seed)
+    lanes = _Lanes(bank_cnt=2, depth=24, max_txn_per_microblock=8)
+    for i in range(400):
+        if rng.random() < 0.7:
+            p = _distinct_vote(rng.randrange(64) * 1000 + i)
+        else:
+            cb = ([_cb_cu(20_000), _cb_price(rng.choice([1, 10**3, 10**6]))]
+                  if rng.random() < 0.5 else ())
+            p = _transfer(rng, dest=0 if rng.random() < 0.5 else None, cb=cb)
+        lanes.insert(i, p)
+        if rng.random() < 0.08:
+            lanes.schedule(rng.randrange(2))
+        if rng.random() < 0.06:
+            lanes.done(rng.randrange(2))
+    for _ in range(100):
+        if not any([lanes.schedule(b) for b in range(2)]):
+            break
+        for b in range(2):
+            lanes.done(b)
+    lanes.check_accounting()
+    st = lanes.stats(lanes.py)
+    assert st["stat_evicted"] > 0 and st["stat_conflict_skips"] > 0
+    assert st["stat_scheduled_votes"] > 0
+    assert st["stat_votes_dropped_regular_pending"] == 0
+
+
+def test_full_pool_never_gives_up_a_vote_for_a_non_vote():
+    """A full pool: an arriving vote evicts the worst non-vote though
+    it loses by ratio; a non-vote never evicts a vote; among votes
+    alone the newcomer is refused and counted."""
+    lanes = _Lanes(bank_cnt=1, depth=8)
+    price = [_cb_cu(20_000), _cb_price(1_000_000)]
+    for i in range(4):
+        lanes.insert(i, _distinct_vote(i))
+    for i in range(4):
+        lanes.insert(4 + i, _distinct_transfer(i, cb=price))
+    assert lanes.py.pending_cnt() == 8
+    # four more votes push out the four (dearer) transfers
+    for i in range(4):
+        lanes.insert(8 + i, _distinct_vote(10 + i))
+    assert len(lanes.py._pending_votes) == 8 and not lanes.py._pending
+    assert lanes.py.stat_evicted == 4 and lanes.py.stat_dropped_votes == 0
+    # a pool of votes refuses the best-paying non-vote...
+    lanes.insert(12, _distinct_transfer(9, cb=price))
+    assert lanes.py_drops[-1] == (12, "drop")
+    assert len(lanes.py._pending_votes) == 8
+    # ...and a ninth vote (equal ratio: the incumbent stays), counted
+    lanes.insert(13, _distinct_vote(20))
+    assert lanes.py_drops[-1] == (13, "drop")
+    assert lanes.py.stat_dropped_votes == 1
+    assert lanes.py.stat_votes_dropped_regular_pending == 0
+    assert lanes.stats(lanes.py) == lanes.stats(lanes.nat)
     lanes.check_accounting()
 
 

@@ -418,7 +418,7 @@ def test_native_pack_shed_parity():
     assert nat.pending_cnt() == py.pending_cnt() == 11
     # the survivors schedule identically: shed trimmed the same tail
     mb_py = py.schedule_next_microblock(0)
-    res_nat = nat.schedule(0, mb_seq=0, any_pool=True)
+    res_nat = nat.schedule(0, mb_seq=0)
     assert (res_nat is None) == (not mb_py)
     if mb_py:
         assert res_nat[1] == len(mb_py)
