@@ -580,7 +580,6 @@ class ServePlane:
 
 
 from firedancer_tpu.runtime.verify import (  # noqa: E402
-    _CLOSE_COUNTERS,
     CLOSE_DEADLINE,
     CLOSE_FULL,
     MCACHE_COL_TSORIG,
@@ -602,10 +601,10 @@ class ShardedVerifyStage(VerifyStage):
     placement (ring i -> mesh device i) with no host-side reshuffle.
 
     The batch closes when any shard's lane range fills, or when the
-    deadline has passed and the in-flight window has room (the
-    VerifyStage close rule, `_deadline_close`: this stage only names its
-    accumulators); uneven fills pad and the step masks pad lanes on
-    device from the per-shard counts.
+    deadline has passed and the window is open to it (the VerifyStage
+    close rule, `_deadline_close`: this stage only names its accumulators);
+    uneven fills pad and the step masks pad lanes on device from the
+    per-shard counts.
     """
 
     def __init__(self, *args, plane: ServePlane, **kwargs):
@@ -677,10 +676,12 @@ class ShardedVerifyStage(VerifyStage):
 
     def _close_batch(self, acc=None, why: int = CLOSE_FULL) -> None:
         """Close the WHOLE step (every shard's partial fill) and dispatch
-        it.  A shard that filled closes it whatever the window holds
-        and waits for the head; the reap it waits for may itself close
-        the step (a shard held past its deadline), so the fills are
-        read after it."""
+        it.  A shard that filled closes it whatever is in flight — the
+        step takes the window's second place, or waits for the head
+        where that is taken; the reap it waits for may itself close the
+        step (a shard held past its deadline and the window open to it
+        after the reap), so the fills are read after it.  The deadline comes
+        through VerifyStage._deadline_close, which asks first."""
         accs = self._shards
         if not self._window_has_room() and any(a.elems for a in accs):
             self._drain(block=True)
@@ -727,11 +728,7 @@ class ShardedVerifyStage(VerifyStage):
                 result=result,
             )
         )
-        self.metrics.inc("batches", 1)
-        self.metrics.inc(_CLOSE_COUNTERS[why])
-        self.metrics.inc("batch_elems", n_elems)
-        self.metrics.observe("batch_fill", n_elems)
-        self.trace(fmet.EV_BATCH_SUBMIT, n_elems)
+        self._count_dispatch(n_elems, why, len(self._inflight))
 
     # the drain loop itself is VerifyStage._drain (ONE implementation of
     # the txn-level pass-iff-all-pass rule); these hooks adapt it to the

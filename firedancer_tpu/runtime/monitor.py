@@ -409,14 +409,16 @@ class MonitorSession:
                 f"{fm.format_latency_ms(r.get('lat_p99_ms')):>9}"
                 f"{fm.format_phase_cell(r.get('sweep_phases') or {}):>16}"
             )
-        # under the table: what closed each verify stage's batches, and
-        # its stalls (cumulative)
+        # under the table: what closed each verify stage's batches, how
+        # many were dispatched behind a running one, and its stalls
+        # (cumulative)
         for r in rows:
             bc = r.get("batch_closes")
             if bc:
                 lines.append(
                     f"{r['stage']}: batches closed "
                     + " ".join(f"{k}={bc[k]:,}" for k in fm.BATCH_CLOSES)
+                    + f"  queued_behind={bc['queued_behind']:,}"
                     + f"  batch_stalls={bc['stalls']:,}")
             mesh = r.get("mesh")
             if mesh:

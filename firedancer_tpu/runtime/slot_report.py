@@ -133,6 +133,9 @@ def _stage_block(mets: dict, records: list) -> dict:
         block["batch_closes"] = {
             c: int(mets.get(name, 0) or 0)
             for c, name in zip(fm.BATCH_CLOSES, fm.BATCH_CLOSE_COUNTERS)}
+        # and how many of them were dispatched behind a running one
+        block[fm.BATCH_QUEUED_BEHIND] = int(
+            mets.get(fm.BATCH_QUEUED_BEHIND, 0) or 0)
     # a verify stage over a mesh: how many chips, and the useful lanes
     # each was dealt
     mesh = fm.mesh_row(mets)

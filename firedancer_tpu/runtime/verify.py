@@ -28,28 +28,41 @@ bool mask, fetched once at the reap, which counts the passes itself.
 
 How deep the window is (one test, `_window_has_room`, on the native lane,
 the Python lane and the sharded stage alike): WINDOW_DEPTH, two — one
-batch running and one queued behind it, which keeps the device back to
-back as long as the thread replaces a reaped head within one program
-length.  A batch dispatched into a window of d waits behind d - 1
-others, so every one too many is a program length in every signature's
-path and buys nothing.  `max_inflight` can only narrow it (1: one batch
-at a time).
+batch running and one queued behind it.  The second place is for FULL
+batches, and for the batch right behind one: it exists to keep the
+device back to back, which matters only when the device is what limits,
+and then the batches fill.  A batch dispatched behind another waits a
+whole program length on the device's queue, so a batch that is not full
+is not queued behind one that was not full either (next paragraph): it
+would run no sooner than if it had stayed open, and what arrived
+meanwhile would wait for the batch after.  `max_inflight` can only
+narrow the window (1: one batch at a time).
 
 When a batch closes (one rule, `_deadline_close` + `_window_open`, on the
 native and the Python lane alike):
 
-  - when it is full (in C, inside the crossing, on the native lane);
-  - when its deadline (`batch_deadline_s`) has passed AND it could be
-    dispatched now: the in-flight window has room and no sealed batch
-    waits ahead of it.  While the window is full an open batch past its
-    deadline stays open and keeps taking frags — sealed, it would only
-    wait for a slot while the frags waited in the ring in front; the
-    pump that reaps the head seals and dispatches it in the same pass
-    (reap -> seal -> dispatch);
-  - on `flush()`, whatever the window holds.
+  - when it is full (in C, inside the crossing, on the native lane): it
+    is dispatched at once if the window has room, behind a running
+    batch if there is one, and is parked until a reap otherwise;
+  - when its deadline (`batch_deadline_s`) has passed, no sealed batch
+    waits ahead of it, AND either nothing is in flight (it would run
+    now) or the window has room behind a batch that closed full (the
+    stage is saturated: the queued program length is the slack that
+    rides out the thread's hiccups).  While a batch that was not full
+    is in flight an open batch past its deadline stays open and keeps
+    taking frags, until it fills (the line above) or the pump that
+    reaps the running batch seals and dispatches it in the same pass
+    (reap -> publish -> seal -> dispatch);
+  - on `flush()`, whatever is in flight.
 
+So a saturated stage keeps two in flight and a paced or thread-bound one
+keeps one, from what the stage itself observes — whether the batches
+fill and whether the device has work — with no setting.
 `batch_close_full + batch_close_deadline + batch_close_window == batches`
-says which of the three closed each dispatched batch.
+says which of the three closed each dispatched batch, and
+`batch_queued_behind` how many were dispatched while another was in
+flight (the second place used: full batches, the batch sealed behind a
+full one, and what flush() sends).
 
 More than one chip behind one intake (`devices=n`): the stage builds a
 one-axis mesh over the first n local devices, the native intake seals
@@ -163,8 +176,9 @@ VERIFY_KERNELS = ("fused", "baseline", "split")
 DEFAULT_KERNEL = os.environ.get("FDTPU_VERIFY_KERNEL", "fused")
 
 # the async in-flight window (wiredancer shape): how many device batches
-# a stage keeps outstanding — one running, one queued behind it (module
-# docstring).  Reaping is strictly in submission order at any depth.
+# a stage keeps outstanding — one running and, if it or the running one
+# is full, one queued behind it (module docstring).  Reaping is strictly
+# in submission order at any depth.
 WINDOW_DEPTH = 2
 
 # native sweep-client frames are payload + packed descriptor + u16; the
@@ -179,9 +193,9 @@ _PHASE_COUNTERS = tuple(f"batch_{p}_ns" for p in fm.BATCH_PHASES)
 
 # what closed a batch (the ids are the binding's, held to
 # native/fd_verify.cpp by fdlint FD305): it filled; its deadline passed
-# with room in the window; or it was held past its deadline by a full
-# window and sealed at a freed slot.  Counted at dispatch, so the three
-# add up to `batches`.
+# with the window open to it (_window_open); or it was held past its
+# deadline by the window and sealed at a reap.  Counted at dispatch, so
+# the three add up to `batches`.
 _CLOSE_COUNTERS = fm.BATCH_CLOSE_COUNTERS
 
 _now_ns = time.monotonic_ns
@@ -233,7 +247,7 @@ class _Acc:
     slots: list[int] = field(default_factory=list)  # cached path only
     opened_at: float = 0.0
     life: _Life | None = None  # stamped when the first element enters
-    held: bool = False  # seen past its deadline while the window was full
+    held: bool = False  # seen past its deadline while the window was shut
     close: int = CLOSE_FULL  # what sealed it (CLOSE_*)
 
     def clear(self) -> None:
@@ -363,7 +377,8 @@ class VerifyStage(Stage):
         # still queued] per reaped batch — a batch's publish phase ends
         # when its last frame has left the queue
         self._emit_marks: list = []
-        for name in _PHASE_COUNTERS + _CLOSE_COUNTERS:
+        for name in (_PHASE_COUNTERS + _CLOSE_COUNTERS
+                     + (fm.BATCH_QUEUED_BEHIND,)):
             self.metrics.counters[name] = 0
         self.metrics.counters["batch_stalls"] = 0
         self.metrics.counters["mesh_devices"] = self.mesh_devices
@@ -383,8 +398,10 @@ class VerifyStage(Stage):
         self._nv_inflight: list = []
         self._nv_emit: list = []  # [slot, frame table, published idx, life]
         # the open batch (named by its C-side open stamp) that was seen
-        # past its deadline while the window was full
+        # past its deadline while the window was shut to it
         self._nv_held_ns = 0
+        # the newest dispatched batch closed full (_window_open)
+        self._last_full = False
         want_native = (native_client if native_client is not None
                        else type(self) is VerifyStage)
         if want_native:
@@ -485,11 +502,17 @@ class VerifyStage(Stage):
                      "batches sealed because they filled (or the next"
                      " txn's signatures did not fit)")
             .counter("batch_close_deadline",
-                     "batches sealed at their deadline with room in the"
-                     " in-flight window (flush() counts here)")
+                     "batches sealed at their deadline with nothing in"
+                     " flight, or with room behind a batch that closed"
+                     " full (flush() counts here)")
             .counter("batch_close_window",
-                     "batches held open past their deadline by a full"
-                     " in-flight window, sealed when a reap freed a slot")
+                     "batches held open past their deadline by a batch in"
+                     " flight that was not full (or by a full window),"
+                     " sealed at a reap")
+            .counter(fm.BATCH_QUEUED_BEHIND,
+                     "batches dispatched while another was in flight (the"
+                     " window's second place: full batches, the batch"
+                     " sealed behind a full one, and flush())")
             .histogram(
                 "batch_fill",
                 fm.exp_buckets(1, 4096, 13),
@@ -709,33 +732,46 @@ class VerifyStage(Stage):
         serving stage keeps one per shard)."""
         return (self._gen, self._comb)
 
+    def _flying(self) -> list:
+        """The batches in flight, in dispatch order (the lane's own)."""
+        return (self._nv_inflight if self._sweep_client is not None
+                else self._inflight)
+
     def _window_has_room(self) -> bool:
         """Fewer batches are in flight than the window is held to.  The
-        ONE test of the depth: the close rule, both lanes' submit loops
-        and the sharded stage's step all ask here."""
-        flying = (self._nv_inflight if self._sweep_client is not None
-                  else self._inflight)
-        return len(flying) < self.max_inflight
+        ONE test of the depth, asked for batches that are sealed
+        already (full ones, and what flush() seals): both lanes' submit
+        loops and the sharded stage's step ask here."""
+        return len(self._flying()) < self.max_inflight
 
     def _window_open(self) -> bool:
-        """A batch sealed now would be dispatched now: the in-flight
-        window has room and no sealed batch waits ahead of it.  The ONE
-        predicate of the close rule; it reads nothing but the stage's
-        own window."""
-        if not self._window_has_room():
-            return False
+        """A batch that is not full may be sealed now: no sealed batch
+        waits ahead of it, and either nothing is in flight (it would
+        run now) or the window has room right behind a batch that
+        closed FULL.  The ONE predicate of the close rule; it reads
+        nothing but the stage's own window.  Behind a batch that was
+        not full the second place is not taken: queued there these
+        elements would start no sooner, and the batch would stop
+        taking what arrives meanwhile.  Behind a full one it is: a
+        full batch in flight is the stage's own evidence that the
+        device limits, and then a program length of work on the
+        device's queue is what keeps it back to back through the
+        thread's hiccups."""
         c = self._sweep_client
-        return not (c.sealed_waiting() if c is not None
-                    else self._submit_queue)
+        if c.sealed_waiting() if c is not None else self._submit_queue:
+            return False
+        return not self._flying() or (self._last_full
+                                      and self._window_has_room())
 
     def _deadline_close(self) -> None:
         """The deadline's half of the close rule (p99 latency at low
-        occupancy): an open batch past its deadline seals if it could
-        be dispatched now, and otherwise stays open, taking frags,
-        until the reap that frees a slot comes through here again.
-        Sealing it into a full window would only move its wait from
-        the batch (where lanes fill) to a parked slot (where the ring
-        in front backs up instead)."""
+        occupancy): an open batch past its deadline seals if the
+        window is open to it (_window_open), and otherwise stays open,
+        taking frags, until it fills or a reap comes through here
+        again.  Sealing it behind a running batch that was not full
+        would only move its wait from the batch (where lanes fill) to
+        the device's queue (where the frags behind it wait a program
+        length more)."""
         c = self._sweep_client
         if c is not None:
             t = c.open_since_ns()  # ONE u64 read; 0 = nothing open
@@ -939,6 +975,9 @@ class VerifyStage(Stage):
         m.inc("batch_elems", n)
         m.observe("batch_fill", n)
         m.observe("inflight_occupancy", occupancy)
+        if occupancy > 1:
+            m.inc(fm.BATCH_QUEUED_BEHIND)
+        self._last_full = close == CLOSE_FULL
         self.trace(fm.EV_BATCH_SUBMIT, n)
         d = self.mesh_devices
         for i in range(min(d, n) if d > 1 else 0):
@@ -958,13 +997,12 @@ class VerifyStage(Stage):
     def _nv_pump(self) -> None:
         """The native lane's batch-granular loop: reap completed heads
         (in order), publish reaped frames from the slot arenas, seal
-        the open batch if its deadline has passed and it can go now,
+        the open batch if its deadline has passed and it would run now,
         submit sealed slots into the in-flight window (in seal order).
-        Reap -> seal -> dispatch, so a freed window slot is used in the
-        pass that freed it; the publish goes before the dispatch
-        because it is a tenth of a ms and the dispatch nearly two, and
-        the window still holds the device's next batches while the
-        reaped transactions have nowhere else to wait."""
+        Reap -> seal -> dispatch, so the batch the window held open
+        goes in the pass that reaped its head; the publish goes before the
+        dispatch because it is a tenth of a ms and the dispatch nearly
+        two, and the reaped transactions have nowhere else to wait."""
         c = self._sweep_client
         self._nv_drain(block=False)
         self._nv_publish()
@@ -1143,8 +1181,9 @@ class VerifyStage(Stage):
         future just to close a batch) until reaping frees a slot.  Only
         a deep submit queue (the memory bound) falls back to the
         blocking drain.  `why` is what closed it (CLOSE_*): a batch that
-        filled, the default, seals whatever the window holds; the
-        deadline comes through _deadline_close, which asks first."""
+        filled, the default, seals whatever is in flight and may take
+        the window's second place; the deadline comes through
+        _deadline_close, which asks first."""
         if acc is None:  # legacy single-lane callers (tests)
             acc = self._gen
         if not acc.elems:
@@ -1279,10 +1318,10 @@ class VerifyStage(Stage):
         the frames of the transactions that passed."""
         mask = self._result_mask(head)
         self._inflight.pop(0)
-        # a window slot freed: seal the batch the full window held past
-        # its deadline, and submit it and any parked ones before walking
-        # the mask (keeps the device fed meanwhile); their dispatch
-        # falls inside this batch's reap phase
+        # a window slot freed: if the window is open to it now, seal
+        # the batch that was held past its deadline, and submit it or
+        # any parked ones before walking the mask (keeps the device fed
+        # meanwhile); their dispatch falls inside this batch's reap phase
         self._deadline_close()
         self._pump_submits()
         self.trace(fm.EV_BATCH_COMPLETE, head.n_elems)

@@ -478,24 +478,30 @@ BATCH_PHASES = ("open", "sealed_wait", "h2d", "launch", "inflight", "reap",
 BATCH_BLOCKING_PHASES = frozenset(
     BATCH_PHASES.index(p) for p in ("h2d", "launch", "reap", "publish"))
 BATCH_STALL_NS = 100_000_000
-# What closed a batch, by id: it filled; its deadline passed with room in
-# the in-flight window; a full window held it past its deadline and a
-# reap freed a slot.  The verify stage counts each dispatched batch in
+# What closed a batch, by id: it filled; its deadline passed with the
+# in-flight window open to it (nothing in flight, or room behind a full
+# batch); the window held it open past its deadline and a reap sealed
+# it.  The verify stage counts each dispatched batch in
 # `batch_close_<why>`, so the three add up to `batches`.
 BATCH_CLOSES = ("full", "deadline", "window")
 BATCH_CLOSE_COUNTERS = tuple(f"batch_close_{c}" for c in BATCH_CLOSES)
+# dispatches made while another batch was in flight: how often the
+# window's second place (full batches, the batch sealed behind a full
+# one, and flush()) is used
+BATCH_QUEUED_BEHIND = "batch_queued_behind"
 
 
 def batch_close_row(regs: list) -> dict | None:
-    """{why: batches closed that way, "stalls": batch_stalls} summed over
-    the shard registries of one logical stage, for the monitor and
-    slotreport; None where the stage is not a verify stage."""
+    """{why: batches closed that way, "queued_behind":
+    batch_queued_behind, "stalls": batch_stalls} summed over the shard
+    registries of one logical stage, for the monitor and slotreport;
+    None where the stage is not a verify stage."""
     regs = [r for r in regs
             if r is not None and BATCH_CLOSE_COUNTERS[0] in r._off]
     if not regs:
         return None
-    names = zip(BATCH_CLOSES + ("stalls",),
-                BATCH_CLOSE_COUNTERS + ("batch_stalls",))
+    names = zip(BATCH_CLOSES + ("queued_behind", "stalls"),
+                BATCH_CLOSE_COUNTERS + (BATCH_QUEUED_BEHIND, "batch_stalls"))
     return {k: sum(r.get(n) for r in regs) for k, n in names}
 
 

@@ -533,20 +533,24 @@ def test_a_txn_that_does_not_fit_opens_the_next_batch(lane):
         assert len(lives) == 2 and lives[0] is not lives[1]
 
 
-# -- when a batch closes (ISSUE 25) ------------------------------------------------
+# -- when a batch closes (ISSUE 25, ISSUE 32) ----------------------------------------
 #
-# Full, or past its deadline AND dispatchable now, or flush(): the same
-# rule on both lanes, driven with a result that is not ready until the
-# test says so.
+# Full (and then it may be dispatched behind a running batch), or past its
+# deadline AND the window open to it (nothing in flight, or room behind a
+# batch that closed full), or flush(): the same rule on every lane,
+# driven with a result that is not ready until the test says so.
 
 CLOSE_COUNTERS = list(rv._CLOSE_COUNTERS)
+QUEUED_BEHIND = fm.BATCH_QUEUED_BEHIND
 
 
 class _Gated:
     """A device future that is ready when the test says."""
 
     def __init__(self, lanes):
-        self.n = None      # the batch's fill, from the stage's own books
+        # from the stage's own books (_count_dispatch): the batch's
+        # fill, what closed it, how many were in flight ahead of it
+        self.n = self.close = self.behind = None
         self.mask = np.ones((lanes,), dtype=bool)
         self.done = False
 
@@ -563,10 +567,8 @@ def _gated_tile(lane: str, **kw):
     import jax.profiler  # noqa: F401  (the span's import, off the clock)
 
     with _tile(lane, precomputed_ok=False, **kw) as (st, prod, cons):
-        if lane == "sharded":      # its dispatch is the plane's step
-            yield st, prod, cons, st.plane.sent
-            return
-        sent: list[_Gated] = []
+        # the sharded stage's dispatch is the plane's step
+        sent: list = st.plane.sent if lane == "sharded" else []
 
         def dispatch(life, rows):
             st._phase_end(life, rv.PH_H2D)
@@ -576,10 +578,12 @@ def _gated_tile(lane: str, **kw):
         books = st._count_dispatch
 
         def count(n, close, occupancy):
-            sent[-1].n = n
+            sent[-1].n, sent[-1].close = n, close
+            sent[-1].behind = occupancy - 1
             books(n, close, occupancy)
 
-        st._device_verify = dispatch
+        if lane != "sharded":
+            st._device_verify = dispatch
         st._count_dispatch = count
         yield st, prod, cons, sent
 
@@ -596,21 +600,30 @@ def _sealed_waiting(st) -> bool:
     return bool(c.sealed_waiting() if c is not None else st._submit_queue)
 
 
+def _in_flight(st) -> int:
+    return len(st._flying())
+
+
 def _feed(prod, pool, lo: int, hi: int) -> None:
     for i in range(lo, hi):
         assert prod.try_publish(pool[i], sig=i, tsorig=0)
+
+
+def _collect(cons, got: list) -> None:
+    """The transaction bytes that came out."""
+    while True:
+        res = cons.poll()
+        if res in (shm.POLL_EMPTY, shm.POLL_OVERRUN):
+            return
+        payload = bytes(res[1])
+        got.append(payload[:int.from_bytes(payload[-2:], "little")])
 
 
 def _spin(st, cons, got: list, loops: int = 60, until=None) -> None:
     """Run the stage, collecting the transaction bytes that come out."""
     for _ in range(loops):
         st.run_once()
-        while True:
-            res = cons.poll()
-            if res in (shm.POLL_EMPTY, shm.POLL_OVERRUN):
-                break
-            payload = bytes(res[1])
-            got.append(payload[:int.from_bytes(payload[-2:], "little")])
+        _collect(cons, got)
         if until is not None and until():
             return
 
@@ -624,28 +637,72 @@ def _closes(st) -> list[int]:
     return [st.metrics.get(k) for k in CLOSE_COUNTERS]
 
 
+def _deadline_batch(st, prod, cons, pool, got, lo: int, n: int = 2) -> int:
+    """n transactions, sealed at their deadline with nothing in flight."""
+    before = st.metrics.get("batches")
+    assert _in_flight(st) == 0
+    _feed(prod, pool, lo, lo + n)
+    _spin(st, cons, got)
+    _past_deadline(st, cons, got)
+    assert st.metrics.get("batches") == before + 1
+    return lo + n
+
+
+def _full_batch(st, prod, cons, pool, got, lo: int) -> int:
+    """One batch's worth (16), sealed by filling and dispatched at once,
+    behind whatever is in flight: the window has room."""
+    before = st.metrics.get("batches"), st.metrics.get(QUEUED_BEHIND)
+    flying = _in_flight(st)
+    assert flying < st.max_inflight
+    _feed(prod, pool, lo, lo + 16)
+    _spin(st, cons, got)
+    assert st.metrics.get("batches") == before[0] + 1
+    assert st.metrics.get(QUEUED_BEHIND) == before[1] + (flying > 0)
+    assert _in_flight(st) == flying + 1 and _open_elems(st) == 0
+    return lo + 16
+
+
+def _held_batch(st, prod, cons, pool, got, lo: int, n: int = 3) -> int:
+    """n transactions, held open past their deadline by the window: a
+    batch that was not full is in flight, or the window is full."""
+    before = st.metrics.get("batches")
+    assert _in_flight(st) > 0
+    _feed(prod, pool, lo, lo + n)
+    _spin(st, cons, got)
+    _past_deadline(st, cons, got)
+    assert st.metrics.get("batches") == before and _open_elems(st) == n
+    assert not _sealed_waiting(st)
+    return lo + n
+
+
 def _fill_window(st, prod, cons, pool, got) -> int:
-    """Two small batches, each sealed on its deadline with room in the
-    window: the window (2) is then full.  -> transactions fed."""
-    for k in range(2):
-        _feed(prod, pool, 3 * k, 3 * k + 3)
-        _spin(st, cons, got)
-        _past_deadline(st, cons, got)
-        assert st.metrics.get("batches") == k + 1
-    assert _closes(st) == [0, 2, 0] and _open_elems(st) == 0
-    return 6
+    """A small batch sealed on its deadline with nothing in flight, and a
+    full one dispatched behind it: the window (2) is then full.
+    -> transactions fed."""
+    n = _deadline_batch(st, prod, cons, pool, got, 0, 3)
+    n = _full_batch(st, prod, cons, pool, got, n)
+    assert _closes(st) == [1, 1, 0] and _in_flight(st) == 2
+    return n
+
+
+def _deepest(st) -> int:
+    """The most batches that were in flight at any dispatch."""
+    h = st.metrics.hist("inflight_occupancy")
+    assert h["count"] == st.metrics.get("batches")
+    return max(int(edge) for edge, n in zip(h["buckets"], h["counts"]) if n)
 
 
 @pytest.mark.parametrize("lane", CLOSE_LANES)
 def test_close_counters_are_in_the_schema_and_start_at_zero(lane):
     with _tile(lane) as (st, _prod, _cons):
-        for k in CLOSE_COUNTERS:
+        for k in CLOSE_COUNTERS + [QUEUED_BEHIND]:
             assert st.metrics.counters[k] == 0
-        assert set(CLOSE_COUNTERS) <= st.metrics_schema().names()
+        assert set(CLOSE_COUNTERS + [QUEUED_BEHIND]) \
+            <= st.metrics_schema().names()
 
 
 @pytest.mark.parametrize("lane", CLOSE_LANES)
-def test_window_with_room_seals_at_the_deadline_as_before(lane, pool):
+def test_nothing_in_flight_seals_at_the_deadline_as_before(lane, pool):
     with _gated_tile(lane, batch_deadline_s=0.05) as (st, prod, cons, sent):
         got: list = []
         _feed(prod, pool, 0, 5)
@@ -657,50 +714,57 @@ def test_window_with_room_seals_at_the_deadline_as_before(lane, pool):
               until=lambda: st.metrics.get("batches") == 1)
         assert 0.05 <= time.monotonic() - t0 < 5
         assert _closes(st) == [0, 1, 0] and [g.n for g in sent] == [5]
+        assert st.metrics.get(QUEUED_BEHIND) == 0 and _deepest(st) == 1
         if lane != "sharded":      # which stamps no lives
             open_ms = st.metrics.get("batch_open_ns") / 1e6
             assert 50 <= open_ms < 1000
 
 
 @pytest.mark.parametrize("lane", CLOSE_LANES)
-def test_full_window_holds_the_batch_open_until_a_reap_frees_a_slot(
+def test_a_batch_in_flight_holds_the_open_batch_until_the_pump_that_reaps_it(
         lane, pool):
+    """A batch that is not full does not queue behind a running one
+    that was not full either (ISSUE 32): the window has room for it,
+    and it stays open."""
     with _gated_tile(lane) as (st, prod, cons, sent):
         got: list = []
-        n = _fill_window(st, prod, cons, pool, got)
-        # deadline passed, window full: the batch stays open ...
+        n = _deadline_batch(st, prod, cons, pool, got, 0, 3)
+        assert st._window_has_room() and not st._window_open()
+        # deadline passed, one batch in flight: the batch stays open ...
         _feed(prod, pool, n, n + 3)
         _spin(st, cons, got)
         _past_deadline(st, cons, got)
-        assert st.metrics.get("batches") == 2 and len(sent) == 2
+        assert st.metrics.get("batches") == 1 and len(sent) == 1
         assert _open_elems(st) == 3 and not _sealed_waiting(st)
         # ... and takes later frags
         _feed(prod, pool, n + 3, n + 5)
         _spin(st, cons, got)
         assert _open_elems(st) == 5 and not _sealed_waiting(st)
-        assert st.metrics.get("batches") == 2 and got == []
-        # the reap that frees a slot seals and dispatches it, in one pump
+        assert st.metrics.get("batches") == 1 and got == []
+        # the pump that reaps the running batch seals and dispatches it
         sent[0].done = True
         st.after_credit()
-        assert st.metrics.get("batches") == 3
-        assert [g.n for g in sent] == [3, 3, 5]
+        assert st.metrics.get("batches") == 2
+        assert [g.n for g in sent] == [3, 5]
         assert _open_elems(st) == 0 and not _sealed_waiting(st)
-        assert _closes(st) == [0, 2, 1]
+        assert _closes(st) == [0, 1, 1]
         # nothing lost, nothing reordered across the hold
-        for g in sent:
-            g.done = True
+        sent[1].done = True
         _spin(st, cons, got)
         assert got == list(pool[:n + 5])
-        assert sum(_closes(st)) == st.metrics.get("batches") == 3
+        assert sum(_closes(st)) == st.metrics.get("batches") == 2
         assert st.metrics.get("txn_verified") == n + 5
+        # and nothing was ever queued behind anything
+        assert st.metrics.get(QUEUED_BEHIND) == 0 and _deepest(st) == 1
 
 
 @pytest.mark.parametrize("lane", LANES)
-def test_a_full_batch_seals_whatever_the_window_holds(lane, pool):
+def test_a_full_batch_takes_the_second_place_and_never_a_third(lane, pool):
     with _gated_tile(lane) as (st, prod, cons, sent):
         got: list = []
-        n = _fill_window(st, prod, cons, pool, got)
-        _feed(prod, pool, n, n + 16)           # one batch's worth
+        n = _fill_window(st, prod, cons, pool, got)   # 3 running, 16 behind
+        assert st.metrics.get(QUEUED_BEHIND) == 1
+        _feed(prod, pool, n, n + 16)           # one more batch's worth
         _spin(st, cons, got)
         assert _sealed_waiting(st) and st.metrics.get("batches") == 2
         assert _open_elems(st) == 0
@@ -712,83 +776,99 @@ def test_a_full_batch_seals_whatever_the_window_holds(lane, pool):
         # one freed slot goes to the sealed batch; the open one stays
         sent[0].done = True
         st.after_credit()
-        assert [g.n for g in sent] == [3, 3, 16]
+        assert [g.n for g in sent] == [3, 16, 16]
         assert _open_elems(st) == 4 and not _sealed_waiting(st)
-        # the next freed slot is the open batch's
+        # the next reap leaves a FULL batch in flight and room behind
+        # it: the stage is saturated, and the open batch goes behind it
         sent[1].done = True
         st.after_credit()
-        assert [g.n for g in sent] == [3, 3, 16, 4]
-        assert _closes(st) == [1, 2, 1]
+        assert [g.n for g in sent] == [3, 16, 16, 4]
+        assert _in_flight(st) == 2 and _open_elems(st) == 0
+        assert _closes(st) == [2, 1, 1]
+        assert st.metrics.get(QUEUED_BEHIND) == 3
+        assert [g.behind for g in sent] == [0, 1, 1, 1]
         for g in sent:
             g.done = True
         _spin(st, cons, got)
         assert got == list(pool[:n + 20])
         assert sum(_closes(st)) == st.metrics.get("batches") == 4
+        assert _deepest(st) == 2
 
 
-def test_a_full_shard_closes_a_held_step_through_the_reap_it_waits_for(pool):
+@pytest.mark.parametrize("depth", [2, 1])
+def test_a_full_shard_closes_a_held_step_through_the_reap_it_waits_for(
+        depth, pool):
     """The sharded stage closes the WHOLE step when one shard fills, and
     with a full window it blocks on the head first.  With shard 0 held
     past its deadline that reap comes back through the close rule
-    (_close_batch -> _drain -> _reap -> _deadline_close -> _close_batch):
-    the step goes out once, at the freed slot, every shard's fill in it."""
-    with _gated_tile("sharded") as (st, prod, cons, sent):
+    (_close_batch -> _drain -> _reap -> _deadline_close -> _close_batch)
+    where it leaves the window open to the step — two deep, room behind
+    the full batch still in flight; one deep, nothing in flight: the
+    step goes out once, every shard's fill in it."""
+    with _gated_tile("sharded", max_inflight=depth) as (st, prod, cons,
+                                                       sent):
         got: list = []
-        n = _fill_window(st, prod, cons, pool, got)
-        _feed(prod, pool, n, n + 3)            # shard 0: held
-        _spin(st, cons, got)
-        _past_deadline(st, cons, got)
-        assert _open_elems(st) == 3 and st.metrics.get("batches") == 2
+        n = _deadline_batch(st, prod, cons, pool, got, 0, 3)
+        first = [3]
+        if depth == 2:
+            n = _full_batch(st, prod, cons, pool, got, n)
+            first.append(16)
+        n = _held_batch(st, prod, cons, pool, got, n)    # shard 0: held
         assert st._shards[0].held and got == []
-        _feed(prod.shard1, pool, n + 3, n + 19)   # shard 1 fills
+        _feed(prod.shard1, pool, n, n + 16)       # shard 1 fills
         _spin(st, cons, got, loops=4)
-        assert [g.n for g in sent] == [3, 3, 19]
-        assert st.metrics.get("batches") == 3 and _open_elems(st) == 0
-        assert _closes(st) == [0, 2, 1]
-        assert len(st._inflight) == 2 and got == list(pool[:3])
+        assert [g.n for g in sent] == first + [19]
+        assert st.metrics.get("batches") == depth + 1
+        assert _open_elems(st) == 0
+        assert _closes(st) == [depth - 1, 1, 1]
+        assert [g.behind for g in sent] == [0, 1, 1][:depth] + [depth - 1]
+        assert _in_flight(st) == depth and got == list(pool[:3])
         assert not any(a.held or a.opened_at for a in st._shards)
-        # the next step opens with its own deadline, and room closes it
+        # the next step opens with its own deadline, and a dry device
+        # closes it
         for g in sent:
             g.done = True
-        _feed(prod.shard1, pool, n + 19, n + 21)
+        _feed(prod.shard1, pool, n + 16, n + 18)
         _spin(st, cons, got)
         _past_deadline(st, cons, got)
-        assert [g.n for g in sent] == [3, 3, 19, 2]
-        assert _closes(st) == [0, 3, 1]
+        assert [g.n for g in sent] == first + [19, 2]
+        assert _closes(st) == [depth - 1, 2, 1]
         sent[-1].done = True
         _spin(st, cons, got)
-        assert got == list(pool[:n + 21])
-        assert sum(_closes(st)) == st.metrics.get("batches") == 4
-        assert st.metrics.get("shard_elems_s0") == 9
+        assert got == list(pool[:n + 18])
+        assert sum(_closes(st)) == st.metrics.get("batches") == depth + 2
+        assert st.metrics.get("shard_elems_s0") == n
         assert st.metrics.get("shard_elems_s1") == 18
 
 
 @pytest.mark.parametrize("held", [False, True])
 @pytest.mark.parametrize("lane", CLOSE_LANES)
-def test_flush_seals_whatever_the_window_holds(lane, held, pool):
+def test_flush_seals_whatever_is_in_flight(lane, held, pool):
     with _gated_tile(lane, batch_deadline_s=0.001 if held else 10.0) \
             as (st, prod, cons, sent):
         got: list = []
         if held:
             n = _fill_window(st, prod, cons, pool, got)
+            n = _held_batch(st, prod, cons, pool, got, n, 4)
         else:
-            n = 0
-        _feed(prod, pool, n, n + 4)
-        _spin(st, cons, got)
-        if held:
-            _past_deadline(st, cons, got)
+            n = 4
+            _feed(prod, pool, 0, n)
+            _spin(st, cons, got)
         assert _open_elems(st) == 4 and len(sent) == (2 if held else 0)
         st.flush()                  # blocks on the heads: no gate needed
         _spin(st, cons, got)
-        assert got == list(pool[:n + 4])
-        assert [g.n for g in sent] == ([3, 3, 4] if held else [4])
+        assert got == list(pool[:n])
+        assert [g.n for g in sent] == ([3, 16, 4] if held else [4])
+        # what flush() seals counts as a deadline close and may be
+        # dispatched behind whatever runs
         if held and lane == "sharded":
             # its flush closes by blocking on the head, and that reap
-            # seals the held step at the slot it frees
-            assert _closes(st) == [0, 2, 1]
+            # seals the held step behind the full batch still in flight
+            assert _closes(st) == [1, 1, 1]
         else:
-            assert _closes(st) == [0, 3 if held else 1, 0]
+            assert _closes(st) == ([1, 2, 0] if held else [0, 1, 0])
         assert sum(_closes(st)) == st.metrics.get("batches")
+        assert st.metrics.get(QUEUED_BEHIND) == (2 if held else 0)
 
 
 def test_the_native_seal_hands_its_reason_back(pool):
@@ -813,34 +893,43 @@ def test_the_native_seal_hands_its_reason_back(pool):
 
 @pytest.mark.parametrize("lane", CLOSE_LANES)
 def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
-    """Beside the stalls: the three close counters, through the registry
-    a scraper reads (schema -> Prometheus), the monitor's table and
-    slotreport's stage block."""
+    """Beside the stalls: the three close counters and
+    batch_queued_behind, through the registry a scraper reads (schema ->
+    Prometheus), the monitor's table and slotreport's stage block."""
     from firedancer_tpu.runtime import monitor as mon
 
-    with _tile(lane) as (st, prod, cons):
-        _trickle(st, prod, cons, pool[:20])
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        got: list = []
+        n = _fill_window(st, prod, cons, pool, got)   # one queued behind
+        for g in sent:
+            g.done = True
+        _spin(st, cons, got)
+        n = _deadline_batch(st, prod, cons, pool, got, n, 1)
+        sent[-1].done = True
+        st.flush()
         st.metrics.flush()
         reg = st.metrics.registry
         assert reg is not None
-        n = st.metrics.get("batches")
         row = fm.batch_close_row([reg])
-        assert row is not None and row["stalls"] == 0
-        assert sum(row[c] for c in fm.BATCH_CLOSES) == n > 0
+        assert row == {"full": 1, "deadline": 2, "window": 0,
+                       "queued_behind": 1, "stalls": 0}
+        assert sum(row[c] for c in fm.BATCH_CLOSES) \
+            == st.metrics.get("batches")
         text = fm.render_prometheus({"v0": reg})
         for k in CLOSE_COUNTERS:
             assert f"{k}{{" in text or f"{k} " in text
+        assert f'{QUEUED_BEHIND}{{stage="v0"}} 1' in text
         rendered = mon.MonitorSession.render(
             [{"stage": "v0", "signal": 1, "heartbeat_age_ms": 1.0, "in": 0,
               "out": 0, "overrun": 0, "backpressure": 0, "iters": 1,
               "batch_closes": row,
               "mesh": fm.mesh_row(reg)}], None, 1.0)
-        assert f"v0: batches closed full={row['full']:,} " \
-               f"deadline={row['deadline']:,} window={row['window']:,}" \
-               f"  batch_stalls=0" in rendered
+        assert "v0: batches closed full=1 deadline=2 window=0" \
+               "  queued_behind=1  batch_stalls=0" in rendered
         dump = fm.flight_dump_obj("t", {"v0": (reg, st.recorder)})
         block = slot_report.build_report(dump)["stages"]["v0"]
         assert block["batch_closes"] == {c: row[c] for c in fm.BATCH_CLOSES}
+        assert block[QUEUED_BEHIND] == 1
         # over a mesh: how many chips and the useful lanes of each, in
         # the same three places; with one device, in none
         if lane == "mesh":
@@ -858,73 +947,51 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
             assert "mesh" not in block and "mesh of" not in rendered
             assert 'mesh_devices{stage="v0"} 1' in text
     assert fm.batch_close_row([Stage("s").metrics.registry]) is None
+    assert QUEUED_BEHIND not in slot_report.build_report(
+        fm.flight_dump_obj("t", {"s": (Stage("s").metrics.registry, None)})
+    )["stages"].get("s", {})
 
 
-# -- how deep the in-flight window is (ISSUE 27) ------------------------------------
+# -- how deep the in-flight window is (ISSUE 27, ISSUE 32) ---------------------------
 #
-# Two: one batch running, one queued behind it, whatever the caller asked
-# for above that.  One test of the depth (_window_has_room) on every lane,
-# and nothing in a lane rests on the two.
-
-
-def _deadline_batch(st, prod, cons, pool, got, lo: int, n: int = 2) -> int:
-    """n transactions, sealed at their deadline with room in the window."""
-    before = st.metrics.get("batches")
-    _feed(prod, pool, lo, lo + n)
-    _spin(st, cons, got)
-    _past_deadline(st, cons, got)
-    assert st.metrics.get("batches") == before + 1
-    return lo + n
-
-
-def _held_batch(st, prod, cons, pool, got, lo: int, n: int = 3) -> int:
-    """n transactions, held open past their deadline by the full window."""
-    before = st.metrics.get("batches")
-    _feed(prod, pool, lo, lo + n)
-    _spin(st, cons, got)
-    _past_deadline(st, cons, got)
-    assert st.metrics.get("batches") == before and _open_elems(st) == n
-    return lo + n
-
-
-def _deepest(st, lane: str) -> int:
-    """The most batches that were in flight at any dispatch."""
-    if lane == "sharded":      # which observes no occupancy
-        return st.max_inflight
-    h = st.metrics.hist("inflight_occupancy")
-    assert h["count"] == st.metrics.get("batches")
-    return max(int(edge) for edge, n in zip(h["buckets"], h["counts"]) if n)
+# Two: one batch running and, if it is full, one queued behind it,
+# whatever the caller asked for above that.  One test of the depth
+# (_window_has_room) on every lane, and nothing in a lane rests on the two.
 
 
 @pytest.mark.parametrize("asked", [None, 8])
 @pytest.mark.parametrize("lane", CLOSE_LANES)
 def test_the_window_is_two_deep_whatever_was_asked_above_that(
         lane, asked, pool):
-    """A third batch waits for a slot however dry the device has run and
-    however often: a batch dispatched behind d - 1 others waits d - 1
-    program lengths, and two keep the device back to back."""
+    """A full batch is dispatched behind a running one, nothing behind
+    the two however dry the device has run and however often, and a
+    batch that is not full behind a full one, once there is room."""
     with _gated_tile(lane, max_inflight=asked) as (st, prod, cons, sent):
         assert st.max_inflight == rv.WINDOW_DEPTH == 2
         got: list = []
         n = 0
         for k in range(3):
-            for _ in range(2):          # the window fills ...
-                n = _deadline_batch(st, prod, cons, pool, got, n)
-            # ... a batch is held behind it ...
+            # the window fills: a batch at its deadline, a full one
+            # behind it ...
+            n = _deadline_batch(st, prod, cons, pool, got, n)
+            n = _full_batch(st, prod, cons, pool, got, n)
+            # ... and a batch is held behind them
             n = _held_batch(st, prod, cons, pool, got, n)
-            assert len(st._nv_inflight or st._inflight) == 2
-            # ... and the device runs dry: the held batch takes a freed
-            # slot, and the window is as deep as it was
+            assert _in_flight(st) == 2
+            # the head is reaped: the full batch runs, there is room
+            # behind it, and the held batch takes it; never a third
+            sent[-2].done = True
+            _spin(st, cons, got)
+            assert len(sent) == 3 * k + 3 and _in_flight(st) == 2
+            assert _open_elems(st) == 0
+            assert [g.behind for g in sent[-3:]] == [0, 1, 1]
             for g in sent:
                 g.done = True
             _spin(st, cons, got)
-            assert len(sent) == 3 * k + 3
-            assert len(st._nv_inflight or st._inflight) == 1
-            sent[-1].done = True
-            _spin(st, cons, got)
-            assert got == list(pool[:n])
-            assert _closes(st) == [0, 2 * k + 2, k + 1]
-        assert _deepest(st, lane) == 2
+            assert got == list(pool[:n]) and _in_flight(st) == 0
+            assert _closes(st) == [k + 1] * 3
+            assert st.metrics.get(QUEUED_BEHIND) == 2 * (k + 1)
+        assert _deepest(st) == 2
         assert sum(_closes(st)) == st.metrics.get("batches") == 9
         assert st.metrics.get("txn_verified") == n
 
@@ -940,7 +1007,8 @@ def test_a_window_of_one_still_runs(lane, pool):
         assert [g.n for g in sent] == [3, 3] and _closes(st) == [0, 1, 1]
         sent[1].done = True
         _spin(st, cons, got)
-        assert got == list(pool[:n]) and _deepest(st, lane) == 1
+        assert got == list(pool[:n]) and _deepest(st) == 1
+        assert st.metrics.get(QUEUED_BEHIND) == 0
 
 
 @pytest.mark.parametrize("depth", [3, 5])
@@ -948,17 +1016,19 @@ def test_a_window_of_one_still_runs(lane, pool):
 def test_a_deeper_window_reaps_and_publishes_in_dispatch_order(
         lane, depth, pool, monkeypatch):
     """Nothing in a lane rests on the depth being two: with `depth` real
-    batches in flight and the later ones finished first, nothing leaves
-    past the head, and what leaves is in dispatch order."""
+    batches in flight (full ones behind the first) and the later ones
+    finished first, nothing leaves past the head, and what leaves is in
+    dispatch order."""
     monkeypatch.setattr(rv, "WINDOW_DEPTH", depth)
     with _gated_tile(lane) as (st, prod, cons, sent):
         assert st.max_inflight == depth
         got: list = []
-        n = 0
-        for _ in range(depth):
-            n = _deadline_batch(st, prod, cons, pool, got, n)
+        n = _deadline_batch(st, prod, cons, pool, got, 0)
+        for _ in range(depth - 1):
+            n = _full_batch(st, prod, cons, pool, got, n)
+        flown = n
         n = _held_batch(st, prod, cons, pool, got, n)
-        assert len(sent) == depth and _deepest(st, lane) == depth
+        assert len(sent) == depth and _deepest(st) == depth
         # the later batches finish ahead of the head: nothing comes out,
         # no slot is freed
         for g in sent[1:]:
@@ -967,14 +1037,65 @@ def test_a_deeper_window_reaps_and_publishes_in_dispatch_order(
         assert got == [] and len(sent) == depth
         assert st.metrics.get("txn_verified") == 0
         # the head finishes: the whole window leaves in dispatch order,
-        # and the held batch takes the first freed slot
+        # and the held batch goes at a freed slot (behind the full
+        # batches still in flight, where the lane reaps them one by one)
         sent[0].done = True
         _spin(st, cons, got)
-        assert got == list(pool[:2 * depth])
-        assert [g.n for g in sent] == [2] * depth + [3]
-        assert _closes(st) == [0, depth, 1]
+        assert got == list(pool[:flown])
+        assert [g.n for g in sent] == [2] + [16] * (depth - 1) + [3]
+        assert _closes(st) == [depth - 1, 1, 1]
+        assert depth - 1 <= st.metrics.get(QUEUED_BEHIND) <= depth
         sent[-1].done = True
         _spin(st, cons, got)
         assert got == list(pool[:n])
         assert sum(_closes(st)) == st.metrics.get("batches") == depth + 1
-        assert _deepest(st, lane) == depth
+        assert _deepest(st) == depth
+
+
+# -- the books of the window under mixed traffic (ISSUE 32) --------------------------
+
+
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_the_windows_books_hold_under_bursts_of_every_size(lane, pool):
+    """Bursts from one transaction to more than a batch's worth, results
+    that come ready a round late: never more than two in flight, a
+    batch that is not full behind a full one only, the close counters
+    add up, and what leaves is what entered, in order."""
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        got: list = []
+        fed = 0
+        bursts = [1, 16, 3, 20, 2, 16, 16, 5, 17]
+        assert sum(bursts) == len(pool)
+        for burst in bursts:
+            earlier = len(sent)    # still in flight when the burst lands
+            _feed(prod, pool, fed, fed + burst)
+            fed += burst
+            for it in range(80):
+                if it == 20:       # the open batch's deadline passes
+                    time.sleep(st.batch_deadline_s * 3)
+                if it in (40, 60):  # an earlier round's head comes ready
+                    late = [g for g in sent[:earlier] if not g.done]
+                    if late:
+                        late[0].done = True
+                st.run_once()
+                _collect(cons, got)
+                assert _in_flight(st) <= 2
+        before_flush = len(sent)
+        for g in sent:
+            g.done = True
+        st.flush()
+        _spin(st, cons, got)
+        assert got == list(pool)
+        # behind another batch went only a full one, or the batch right
+        # behind a full one
+        for ahead, g in zip(sent, sent[1:before_flush]):
+            assert not g.behind or rv.CLOSE_FULL in (g.close, ahead.close)
+        assert all(g.behind in (0, 1) for g in sent)
+        c = st.metrics.get
+        assert c(QUEUED_BEHIND) == sum(g.behind for g in sent)
+        assert sum(_closes(st)) == c("batches") == len(sent)
+        assert sum(g.n for g in sent) == c("batch_elems") == len(pool)
+        assert _deepest(st) == 2
+        assert c("batch_close_full") >= 4 and c("batch_close_window") >= 2
+        # a batch held behind one that was not full (the 2 behind the 4)
+        assert any(g.close == rv.CLOSE_WINDOW and not g.behind for g in sent)

@@ -3,7 +3,8 @@
 Tier-1 here is structural and host-only: ladder introspection (dispatch
 counts), the in-flight window's in-order/backpressure semantics at its
 depth of two (ISSUE 27) and, so that nothing rests on the two, at deeper
-ones, driven with fake device futures (no XLA), the autotuner's
+ones — full batches behind the running one, a batch that is not full
+only behind a full one (ISSUE 32) — driven with fake device futures (no XLA), the autotuner's
 determinism, and the packed row a batch goes to the device as (ISSUE
 29): its layout held equal between native/fd_verify.cpp, the binding,
 the Python lane's _assemble and the program's on-device unpack, which
@@ -50,7 +51,8 @@ def test_stage_kernel_and_window_defaults(monkeypatch):
     monkeypatch.setenv("FDTPU_VERIFY_INFLIGHT", "5")
     st = VerifyStage("v", ins=[], outs=[], native_client=False)
     assert st.kernel == "fused"
-    assert st.max_inflight == rv.WINDOW_DEPTH == 2  # one running, one queued
+    # one running and, if it is full, one queued behind it
+    assert st.max_inflight == rv.WINDOW_DEPTH == 2
 
 
 @pytest.mark.parametrize("asked, held", [(None, 2), (1, 1), (2, 2), (3, 2),
@@ -120,20 +122,24 @@ def depth(request, monkeypatch):
 
 
 @pytest.mark.parametrize("max_inflight", [None, 1, 2, 8])
-def test_window_fills_to_its_depth_and_defers(txn_pool, depth, max_inflight):
+def test_window_fills_to_its_depth_with_full_batches_and_defers(
+        txn_pool, depth, max_inflight):
     st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
                       max_inflight=max_inflight)
     held = min(depth, max_inflight or depth)
     assert st.max_inflight == held
     n = 4 * (held + 3)
-    _feed(st, txn_pool[:n])  # the window + 3 batches of 4
+    _feed(st, txn_pool[:n])  # the window + 3 batches of 4, every one full
     # nothing reaped (no fake is ready): the window holds exactly its
-    # depth and the remaining sealed batches parked in the submit queue
-    # — submit never blocked on a device future
+    # depth — full batches go behind the running one — and the
+    # remaining sealed batches parked in the submit queue: submit never
+    # blocked on a device future
     assert len(st._inflight) == held
     assert len(st._submit_queue) == 3
     assert st.metrics.get("submit_deferred") > 0
     assert st.metrics.get("batches") == held  # only submitted ones
+    assert st.metrics.get("batch_close_full") == held
+    assert st.metrics.get("batch_queued_behind") == held - 1
     occ = st.metrics.hist("inflight_occupancy")
     assert occ["count"] == held and occ["sum"] == held * (held + 1) / 2
     # and it still runs to the end, in order
@@ -165,16 +171,86 @@ def test_window_reaps_in_order_under_out_of_order_completion(txn_pool, depth):
     assert st.metrics.get("batches") == depth + 2
 
 
-def test_window_freed_slots_pull_deferred_submits(txn_pool, depth):
-    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256)
-    _feed(st, txn_pool[:4 * depth + 12])  # the window + 3 parked
+def test_window_freed_slots_pull_deferred_full_batches(txn_pool, depth):
+    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
+                      batch_deadline_s=0.0)
+    # the window + 3 parked, and a batch that is not full, past its
+    # deadline from the start
+    _feed(st, txn_pool[:4 * depth + 12 + 2])
     assert len(st._inflight) == depth and len(st._submit_queue) == 3
+    st.before_credit()
     st.fakes[0].ready = True
     st.after_credit()
-    # one reap -> one parked batch submitted into the freed slot
+    # one reap -> one parked batch submitted into the freed slot, behind
+    # the ones in flight; the open batch stays open behind them all
     assert len(st._inflight) == depth
     assert len(st._submit_queue) == 2
     assert len(st.fakes) == depth + 1
+    assert len(st._gen.elems) == 2 and st._gen.held
+    # while a sealed batch is parked ahead of it, it stays open
+    for _ in range(2):
+        assert st.metrics.get("batch_close_window") == 0
+        assert len(st._gen.elems) == 2
+        next(f for f in st.fakes if not f.ready).ready = True
+        st.after_credit()
+    assert not st._submit_queue and len(st._gen.elems) == 2
+    # the next freed slot is behind full batches, and its own
+    next(f for f in st.fakes if not f.ready).ready = True
+    st.after_credit()
+    assert not st._gen.elems and len(st._inflight) == depth
+    assert st.metrics.get("batch_close_window") == 1
+    assert st.metrics.get("batch_close_full") == depth + 3
+    assert st.metrics.get("batch_queued_behind") == depth + 3
+    for f in st.fakes:
+        f.ready = True
+    st.flush()
+    assert [e[2] for e in st.emitted] \
+        == list(range(1000, 1000 + 4 * depth + 14))
+
+
+@pytest.mark.parametrize("max_inflight", [None, 1])
+def test_a_batch_that_is_not_full_is_not_queued_behind_another_such(
+        txn_pool, max_inflight):
+    """The second place in the window is for full batches and the batch
+    behind one (ISSUE 32): a batch past its deadline stays open while
+    one that was not full is in flight, takes what arrives, and goes in
+    the pump that reaps the running one; one that fills meanwhile goes
+    behind it at once."""
+    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
+                      batch_deadline_s=0.0, max_inflight=max_inflight)
+    _feed(st, txn_pool[:2])
+    st.before_credit()
+    st.after_credit()            # nothing in flight: goes at its deadline
+    assert len(st._inflight) == 1
+    assert st.metrics.get("batch_close_deadline") == 1
+    _feed(st, txn_pool[2:4], t0=1002)
+    for _ in range(3):           # one in flight: stays open, whatever room
+        st.before_credit()
+        st.after_credit()
+    assert st._window_has_room() == (max_inflight is None)
+    assert not st._window_open()
+    assert len(st._inflight) == 1 and len(st._gen.elems) == 2
+    assert st._gen.held and not st._submit_queue
+    _feed(st, txn_pool[4:5], t0=1004)      # and takes what arrives
+    assert len(st._gen.elems) == 3
+    if max_inflight is None:
+        # it fills: the second place is its
+        _feed(st, txn_pool[5:6], t0=1005)
+        assert len(st._inflight) == 2 and not st._gen.elems
+        assert st.metrics.get("batch_close_full") == 1
+        assert st.metrics.get("batch_queued_behind") == 1
+        st.fakes[0].ready = True
+    else:
+        # the reap of the running batch sends it, in the same pass
+        st.fakes[0].ready = True
+        st.after_credit()
+        assert len(st._inflight) == 1 and not st._gen.elems
+        assert st.metrics.get("batch_close_window") == 1
+        assert st.metrics.get("batch_queued_behind") == 0
+    st.fakes[1].ready = True
+    st.flush()
+    n = 6 if max_inflight is None else 5
+    assert [e[2] for e in st.emitted] == list(range(1000, 1000 + n))
 
 
 def test_flush_drains_window_and_queue(txn_pool):

@@ -333,6 +333,9 @@ def test_every_txn_leaves_a_native_armed_mesh_stage_exactly_once(
         assert shards[-1] >= len(pool) // N_DEV - c("batches")
         assert sum(c(k) for k in rv._CLOSE_COUNTERS) == c("batches") >= 9
         assert c("batch_close_full") >= 2 and c("batch_close_deadline") >= 2
+        # behind a running step goes a full one, the step sealed right
+        # behind a full one, and what flush() sends
+        assert c("batch_queued_behind") <= 2 * c("batch_close_full") + 1
         assert c("mesh_devices") == N_DEV
         phases = [c(f"batch_{p}_ns") for p in fm.BATCH_PHASES]
         if mask == "toy":
