@@ -1,0 +1,1 @@
+from harness.readers import idle_pct as read  # noqa: F401

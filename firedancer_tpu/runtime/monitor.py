@@ -323,6 +323,7 @@ class MonitorSession:
             row["batch_closes"] = fm.batch_close_row([j.registry])
             row["mesh"] = fm.mesh_row(j.registry)
             row["votes"] = fm.vote_row(j.registry)
+            row["dedup"] = fm.dedup_row(j.registry)
             out.append(row)
         for logical, js in groups.items():
             sigs = [j.cnc.signal for j in js]
@@ -410,8 +411,10 @@ class MonitorSession:
                 f"{fm.format_phase_cell(r.get('sweep_phases') or {}):>16}"
             )
         # under the table: what closed each verify stage's batches, how
-        # many were dispatched behind a running one, and its stalls
-        # (cumulative)
+        # many were dispatched behind a running one, its stalls, and
+        # the lanes nobody used: left empty because the next
+        # transaction did not fit, spent on transactions that failed
+        # whole (cumulative)
         for r in rows:
             bc = r.get("batch_closes")
             if bc:
@@ -419,7 +422,9 @@ class MonitorSession:
                     f"{r['stage']}: batches closed "
                     + " ".join(f"{k}={bc[k]:,}" for k in fm.BATCH_CLOSES)
                     + f"  queued_behind={bc['queued_behind']:,}"
-                    + f"  batch_stalls={bc['stalls']:,}")
+                    + f"  batch_stalls={bc['stalls']:,}"
+                    + f"  fit_pad_lanes={bc['fit_pad_lanes']:,}"
+                    + f"  verify_fail_elems={bc['fail_elems']:,}")
             mesh = r.get("mesh")
             if mesh:
                 lines.append(
@@ -433,6 +438,12 @@ class MonitorSession:
                 # locked account; a bank: votes landed / landed failed
                 lines.append(f"{r['stage']}: votes " + " ".join(
                     f"{k}={v:,}" for k, v in votes.items()))
+            dedup = r.get("dedup")
+            if dedup:
+                # the dedup stage: transactions its tag cache dropped,
+                # and the signatures verify had checked for them
+                lines.append(f"{r['stage']}: dropped " + " ".join(
+                    f"{k}={v:,}" for k, v in dedup.items()))
         return "\n".join(lines)
 
     def run(self, *, interval_s: float = 1.0, iterations: int | None = None,

@@ -133,9 +133,12 @@ def _stage_block(mets: dict, records: list) -> dict:
         block["batch_closes"] = {
             c: int(mets.get(name, 0) or 0)
             for c, name in zip(fm.BATCH_CLOSES, fm.BATCH_CLOSE_COUNTERS)}
-        # and how many of them were dispatched behind a running one
-        block[fm.BATCH_QUEUED_BEHIND] = int(
-            mets.get(fm.BATCH_QUEUED_BEHIND, 0) or 0)
+        # how many of them were dispatched behind a running one, the
+        # lanes they left empty because the next transaction did not
+        # fit, and the lanes of the transactions that failed whole
+        for name in (fm.BATCH_QUEUED_BEHIND, fm.BATCH_FIT_PAD_LANES,
+                     fm.VERIFY_FAIL_ELEMS):
+            block[name] = int(mets.get(name, 0) or 0)
     # a verify stage over a mesh: how many chips, and the useful lanes
     # each was dealt
     mesh = fm.mesh_row(mets)
@@ -146,6 +149,11 @@ def _stage_block(mets: dict, records: list) -> dict:
     votes = fm.vote_row(mets)
     if votes:
         block["votes"] = votes
+    # the dedup stage: transactions its tag cache dropped and the
+    # signatures they carried
+    dedup = fm.dedup_row(mets)
+    if dedup:
+        block["dedup"] = dedup
     return block
 
 

@@ -62,7 +62,11 @@ fill and whether the device has work — with no setting.
 says which of the three closed each dispatched batch, and
 `batch_queued_behind` how many were dispatched while another was in
 flight (the second place used: full batches, the batch sealed behind a
-full one, and what flush() sends).
+full one, and what flush() sends).  Two counters say which lanes carried
+no verdict anyone used: `batch_fit_pad_lanes`, the lanes left empty by
+batches sealed because the next transaction's signatures did not fit
+(such a batch closed full: it is as full as its transactions allow), and
+`verify_fail_elems`, the lanes of the transactions that failed whole.
 
 More than one chip behind one intake (`devices=n`): the stage builds a
 one-axis mesh over the first n local devices, the native intake seals
@@ -378,7 +382,8 @@ class VerifyStage(Stage):
         # when its last frame has left the queue
         self._emit_marks: list = []
         for name in (_PHASE_COUNTERS + _CLOSE_COUNTERS
-                     + (fm.BATCH_QUEUED_BEHIND,)):
+                     + (fm.BATCH_QUEUED_BEHIND, fm.BATCH_FIT_PAD_LANES,
+                        fm.VERIFY_FAIL_ELEMS)):
             self.metrics.counters[name] = 0
         self.metrics.counters["batch_stalls"] = 0
         self.metrics.counters["mesh_devices"] = self.mesh_devices
@@ -513,6 +518,17 @@ class VerifyStage(Stage):
                      "batches dispatched while another was in flight (the"
                      " window's second place: full batches, the batch"
                      " sealed behind a full one, and flush())")
+            # lanes that carried no verdict anyone used: those a batch
+            # sealed for want of room left empty, and those of the
+            # transactions that failed whole (one bad signature fails
+            # all of a transaction's lanes)
+            .counter(fm.BATCH_FIT_PAD_LANES,
+                     "lanes left empty by batches sealed because the next"
+                     " txn's signatures did not fit (a txn's elements land"
+                     " in one batch)")
+            .counter(fm.VERIFY_FAIL_ELEMS,
+                     "signature elements of the txns counted in"
+                     " verify_fail")
             .histogram(
                 "batch_fill",
                 fm.exp_buckets(1, 4096, 13),
@@ -594,6 +610,9 @@ class VerifyStage(Stage):
         slots = self._signer_slots(signers)
         acc = self._comb if slots is not None else self._gen
         if acc.elems and len(acc.elems) + len(sigs) > self.batch:
+            # sealed for want of room (fd_verify.cpp counts the same)
+            self.metrics.inc(fm.BATCH_FIT_PAD_LANES,
+                             self.batch - len(acc.elems))
             self._close_batch(acc)
             acc = self._comb if slots is not None else self._gen
         if not acc.elems:
@@ -1050,13 +1069,16 @@ class VerifyStage(Stage):
                     tbl = frames
                     kept = n_txn
                 else:
-                    starts = views.ranges[:n_txn, 0].astype(np.int64)
+                    ranges = views.ranges[:n_txn].astype(np.int64)
                     ok_txn = np.minimum.reduceat(
-                        mask[:n_elems].astype(np.uint8), starts
+                        mask[:n_elems].astype(np.uint8), ranges[:, 0]
                     ).astype(bool)
                     tbl = np.ascontiguousarray(frames[ok_txn])
                     kept = int(ok_txn.sum())
                     self.metrics.inc("verify_fail", n_txn - kept)
+                    lanes = ranges[~ok_txn]
+                    self.metrics.inc(fm.VERIFY_FAIL_ELEMS,
+                                     int((lanes[:, 1] - lanes[:, 0]).sum()))
             self._phase_end(life, PH_REAP)
             if kept:
                 self.metrics.inc("txn_verified", kept)
@@ -1338,6 +1360,7 @@ class VerifyStage(Stage):
                 emits.append(self._encode_emit(payload, desc, tsorig))
             else:
                 self.metrics.inc("verify_fail")
+                self.metrics.inc(fm.VERIFY_FAIL_ELEMS, b - a)
         return emits
 
     def _encode_emit(self, payload: bytes, desc_pair, tsorig: int):

@@ -109,7 +109,7 @@ def available() -> bool:
 # copies them verbatim
 _COUNTERS = ("filtered", "frags_in", "parse_fail", "dedup_dup",
              "msg_too_long", "too_many_sigs", "txn_in", "elems_in",
-             "intake_dropped", "sealed_batches")
+             "intake_dropped", "sealed_batches", "batch_fit_pad_lanes")
 _TAIL_FLAGS = 0
 _TAIL_OPEN_ELEMS = 1
 _TAIL_OPEN_NS = 2

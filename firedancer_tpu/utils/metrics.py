@@ -489,20 +489,50 @@ BATCH_CLOSE_COUNTERS = tuple(f"batch_close_{c}" for c in BATCH_CLOSES)
 # window's second place (full batches, the batch sealed behind a full
 # one, and flush()) is used
 BATCH_QUEUED_BEHIND = "batch_queued_behind"
+# lanes whose verdict nobody used: those a batch sealed for want of room
+# left empty (the next transaction's signatures did not fit, and a
+# transaction's elements land in one batch), and those of the
+# transactions that failed whole
+BATCH_FIT_PAD_LANES = "batch_fit_pad_lanes"
+VERIFY_FAIL_ELEMS = "verify_fail_elems"
 
 
 def batch_close_row(regs: list) -> dict | None:
     """{why: batches closed that way, "queued_behind":
-    batch_queued_behind, "stalls": batch_stalls} summed over the shard
-    registries of one logical stage, for the monitor and slotreport;
-    None where the stage is not a verify stage."""
+    batch_queued_behind, "fit_pad_lanes": batch_fit_pad_lanes,
+    "fail_elems": verify_fail_elems, "stalls": batch_stalls} summed
+    over the shard registries of one logical stage, for the monitor and
+    slotreport; None where the stage is not a verify stage."""
     regs = [r for r in regs
             if r is not None and BATCH_CLOSE_COUNTERS[0] in r._off]
     if not regs:
         return None
-    names = zip(BATCH_CLOSES + ("queued_behind", "stalls"),
-                BATCH_CLOSE_COUNTERS + (BATCH_QUEUED_BEHIND, "batch_stalls"))
+    names = zip(BATCH_CLOSES + ("queued_behind", "fit_pad_lanes",
+                                "fail_elems", "stalls"),
+                BATCH_CLOSE_COUNTERS + (BATCH_QUEUED_BEHIND,
+                                        BATCH_FIT_PAD_LANES,
+                                        VERIFY_FAIL_ELEMS, "batch_stalls"))
     return {k: sum(r.get(n) for r in regs) for k, n in names}
+
+
+# What a dedup stage's tag cache dropped (counter -> the key the monitor
+# and slotreport show it by): transactions, and the signatures they
+# carried, which the verify stage in front of it spent lanes on
+DEDUP_COUNTERS = {"dedup_dup": "dup", "dedup_dup_sigs": "dup_sigs"}
+
+
+def dedup_row(src) -> dict | None:
+    """{key: count} of DEDUP_COUNTERS, from a stage's registry (the
+    monitor) or a dict of its metrics (slotreport); None where the
+    stage is not a dedup stage (verify and pack count `dedup_dup` too,
+    and have no `dedup_dup_sigs`)."""
+    if src is None:
+        return None
+    if isinstance(src, MetricsRegistry):
+        src = {n: src.get(n) for n in DEDUP_COUNTERS if n in src._off}
+    if "dedup_dup_sigs" not in src:
+        return None
+    return {k: int(src.get(n) or 0) for n, k in DEDUP_COUNTERS.items()}
 
 
 def mesh_row(src) -> dict | None:

@@ -120,7 +120,10 @@ struct fdv_stage {
                         // ONE word per pump; the stamp names the batch)
   uint64_t c_filtered, c_frags_in, c_parse_fail, c_dedup_dup,
       c_msg_too_long, c_too_many_sigs, c_txn_in, c_elems_in,
-      c_intake_dropped, c_sealed_batches;
+      c_intake_dropped, c_sealed_batches,
+      // lanes batches sealed for want of room left empty: the next
+      // txn's signatures did not fit (a txn's elements land in ONE batch)
+      c_batch_fit_pad_lanes;
 };
 
 inline void set_flags(fdv_stage* s) {
@@ -216,6 +219,7 @@ int ingest(fdv_stage* s, const uint8_t* payload, uint64_t sz,
   if (s->open < 0) acquire_open(s);  // cannot fail: probed above
   fdv_slot_meta* m = &s->meta[s->open];
   if (m->n_elems + sig_cnt > s->batch) {
+    s->c_batch_fit_pad_lanes += s->batch - m->n_elems;
     seal_open(s, CLOSE_FULL);
     acquire_open(s);  // cannot fail: probed above
     m = &s->meta[s->open];

@@ -912,7 +912,8 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
         assert reg is not None
         row = fm.batch_close_row([reg])
         assert row == {"full": 1, "deadline": 2, "window": 0,
-                       "queued_behind": 1, "stalls": 0}
+                       "queued_behind": 1, "fit_pad_lanes": 0,
+                       "fail_elems": 0, "stalls": 0}
         assert sum(row[c] for c in fm.BATCH_CLOSES) \
             == st.metrics.get("batches")
         text = fm.render_prometheus({"v0": reg})
