@@ -411,7 +411,8 @@ class MonitorSession:
                 f"{fm.format_phase_cell(r.get('sweep_phases') or {}):>16}"
             )
         # under the table: what closed each verify stage's batches, how
-        # many were dispatched behind a running one, its stalls, and
+        # many were dispatched behind a running one, how many a backlog
+        # in front kept open past their deadline, its stalls, and
         # the lanes nobody used: left empty because the next
         # transaction did not fit, spent on transactions that failed
         # whole (cumulative)
@@ -422,6 +423,7 @@ class MonitorSession:
                     f"{r['stage']}: batches closed "
                     + " ".join(f"{k}={bc[k]:,}" for k in fm.BATCH_CLOSES)
                     + f"  queued_behind={bc['queued_behind']:,}"
+                    + f"  held_backlogged={bc['held_backlogged']:,}"
                     + f"  batch_stalls={bc['stalls']:,}"
                     + f"  fit_pad_lanes={bc['fit_pad_lanes']:,}"
                     + f"  verify_fail_elems={bc['fail_elems']:,}")

@@ -133,11 +133,12 @@ def _stage_block(mets: dict, records: list) -> dict:
         block["batch_closes"] = {
             c: int(mets.get(name, 0) or 0)
             for c, name in zip(fm.BATCH_CLOSES, fm.BATCH_CLOSE_COUNTERS)}
-        # how many of them were dispatched behind a running one, the
+        # how many of them were dispatched behind a running one, how
+        # many a backlog in front kept open past their deadline, the
         # lanes they left empty because the next transaction did not
         # fit, and the lanes of the transactions that failed whole
-        for name in (fm.BATCH_QUEUED_BEHIND, fm.BATCH_FIT_PAD_LANES,
-                     fm.VERIFY_FAIL_ELEMS):
+        for name in (fm.BATCH_QUEUED_BEHIND, fm.BATCH_HELD_BACKLOGGED,
+                     fm.BATCH_FIT_PAD_LANES, fm.VERIFY_FAIL_ELEMS):
             block[name] = int(mets.get(name, 0) or 0)
     # a verify stage over a mesh: how many chips, and the useful lanes
     # each was dealt

@@ -195,6 +195,10 @@ class Stage:
         self.require_credit = False
         # frags drained per run_once sweep (see run_once's burst loop)
         self.burst = 16
+        # the last intake sweep took its whole burst from the rings in
+        # front: more is waiting there (_note_sweep; the verify stage's
+        # close rule reads it)
+        self.backlogged = False
         # native ring plane: when every input is a NativeConsumer the
         # sweep drains through ONE fdr_drain FFI crossing (cached plan,
         # rebuilt when the input list changes — e.g. a chaos LossyConsumer
@@ -508,11 +512,14 @@ class Stage:
         # (credits, housekeeping checks, empty polls of sibling inputs)
         # per frag — the dominant host-path cost at profile; the
         # reference's stem loop amortizes the same way in C.
-        for _ in range(max(1, self.burst)):
+        asked = max(1, self.burst)
+        taken = 0
+        while taken < asked:
             if progressed and self.require_credit and any(
                 p.cr_avail <= 0 for p in self.outs
             ):
-                break  # mid-burst credit exhaustion: stop cleanly
+                asked = taken  # mid-burst credit exhaustion: stop cleanly
+                break
             got = False
             for k in range(n_in):
                 idx = (self._in_rr + k) % n_in
@@ -559,9 +566,23 @@ class Stage:
                 break
             if not got:
                 break
+            taken += 1
+        self._note_sweep(taken, asked)
         if progressed and self.safe_progress:
             self._commit_progress()
         return progressed
+
+    def _note_sweep(self, n: int, asked: int) -> None:
+        """One intake sweep took `n` frags where it was allowed `asked`
+        (at most `burst`).  The whole burst: more is waiting in the
+        rings in front, the stage is backlogged.  Fewer than it was
+        allowed: they ran dry.  A sweep that was skipped, or that
+        credits downstream held short of the burst, says nothing about
+        the rings in front and leaves the observation as it was."""
+        if n >= max(1, self.burst):
+            self.backlogged = True
+        elif n < asked:
+            self.backlogged = False
 
     # -- native ring burst path ---------------------------------------------
 
@@ -611,6 +632,7 @@ class Stage:
         max_frags = self.burst if self.burst > 0 else 1
         m = self.metrics
         n, self._in_rr, d_ovr = drainer.sweep(self._in_rr, max_frags)
+        self._note_sweep(n, max_frags)
         if d_ovr:
             m.inc("overrun", d_ovr)
             tot = m.get("overrun")
@@ -661,6 +683,7 @@ class Stage:
             return False
         m = self.metrics
         n, self._in_rr, d_ovr = drainer.drain(self._in_rr, max_frags)
+        self._note_sweep(n, max_frags)
         if d_ovr:
             m.inc("overrun", d_ovr)
             tot = m.get("overrun")

@@ -29,40 +29,67 @@ bool mask, fetched once at the reap, which counts the passes itself.
 How deep the window is (one test, `_window_has_room`, on the native lane,
 the Python lane and the sharded stage alike): WINDOW_DEPTH, two — one
 batch running and one queued behind it.  The second place is for FULL
-batches, and for the batch right behind one: it exists to keep the
-device back to back, which matters only when the device is what limits,
-and then the batches fill.  A batch dispatched behind another waits a
-whole program length on the device's queue, so a batch that is not full
-is not queued behind one that was not full either (next paragraph): it
-would run no sooner than if it had stayed open, and what arrived
-meanwhile would wait for the batch after.  `max_inflight` can only
-narrow the window (1: one batch at a time).
+batches, and for the batch right behind a full one that was itself
+queued: it exists to keep the device back to back, which matters only
+when the device is what limits, and then the batches fill faster than
+the device runs them.  A batch dispatched behind another waits a whole
+program length on the device's queue, so a batch that is not full is
+not queued behind any other batch (next paragraph): it would run no
+sooner than if it had stayed open, and what arrived meanwhile would
+wait for the batch after.  `max_inflight` can only narrow the window
+(1: one batch at a time).
 
-When a batch closes (one rule, `_deadline_close` + `_window_open`, on the
-native and the Python lane alike):
+When a batch closes (one rule, `_past_deadline` over `_window_open`,
+asked in `_deadline_close` on the native and the Python lane alike):
 
   - when it is full (in C, inside the crossing, on the native lane): it
     is dispatched at once if the window has room, behind a running
     batch if there is one, and is parked until a reap otherwise;
   - when its deadline (`batch_deadline_s`) has passed, no sealed batch
-    waits ahead of it, AND either nothing is in flight (it would run
-    now) or the window has room behind a batch that closed full (the
-    stage is saturated: the queued program length is the slack that
-    rides out the thread's hiccups).  While a batch that was not full
-    is in flight an open batch past its deadline stays open and keeps
-    taking frags, until it fills (the line above) or the pump that
-    reaps the running batch seals and dispatches it in the same pass
-    (reap -> publish -> seal -> dispatch);
+    waits ahead of it, AND either
+      (a) nothing is in flight (it would run now) and the intake is not
+          backlogged, or
+      (b) the window has room behind a batch that closed full behind a
+          running one (the stage is saturated: the queued program
+          length is the slack that rides out the thread's hiccups).
+    While any other batch is in flight an open batch past its deadline
+    stays open and keeps taking frags, until it fills (the line above)
+    or the pump that reaps the running batch comes through the rule
+    again in the same pass (reap -> publish -> seal -> dispatch);
   - on `flush()`, whatever is in flight.
 
-So a saturated stage keeps two in flight and a paced or thread-bound one
-keeps one, from what the stage itself observes — whether the batches
-fill and whether the device has work — with no setting.
+The one thing the rule reads that is not the window is the intake's
+`backlogged` (runtime/stage.py `_note_sweep`): the last intake sweep
+took its whole burst from the ring in front, so more is waiting there.
+While that holds, a batch that would run now but part empty stays open
+and fills — (a) waits — and goes the way full batches go; the first
+sweep that comes back short ends the backlog, and the batch goes at
+the next pass.  Why (a) and (b) differ: a dispatch costs the thread the
+same blocking calls (copy, launch, reap, publish) at any fill.  Where
+the thread limits — a full batch takes longer to gather than the
+program takes to run, so nothing is in flight when the deadline passes
+— a partial dispatch is a whole dispatch's cost for part of its lanes,
+and under a backlog the ring in front is where the latency is anyway.
+Where the device limits — the thread fills 1,024 lanes in under a
+program length, so full batches go out BEHIND running ones, which is
+the evidence (b) asks for — a queued partial batch costs nothing the
+device was not going to wait for, and is slack.  A full batch that
+went out alone is no such evidence: under a backlog every batch of a
+thread-bound stage closes full.
+
+So a saturated stage keeps two in flight, a paced one keeps one and
+seals at the reap, and a thread-bound one under a backlog dispatches
+once per full batch, from what the stage itself observes — whether the
+batches fill behind running ones, whether the device has work, whether
+the ring in front ran dry — with no setting.
 `batch_close_full + batch_close_deadline + batch_close_window == batches`
-says which of the three closed each dispatched batch, and
+says which of the three closed each dispatched batch,
 `batch_queued_behind` how many were dispatched while another was in
-flight (the second place used: full batches, the batch sealed behind a
-full one, and what flush() sends).  Two counters say which lanes carried
+flight (the second place used: full batches, the batch sealed under
+(b), and what flush() sends), and `batch_held_backlogged` how many
+were kept open past their deadline for the backlog alone (over
+`batches`: ~1 in a thread-bound flood, ~0 where the stage is paced or
+the device limits).  Two counters say which lanes carried
 no verdict anyone used: `batch_fit_pad_lanes`, the lanes left empty by
 batches sealed because the next transaction's signatures did not fit
 (such a batch closed full: it is as full as its transactions allow), and
@@ -197,10 +224,15 @@ _PHASE_COUNTERS = tuple(f"batch_{p}_ns" for p in fm.BATCH_PHASES)
 
 # what closed a batch (the ids are the binding's, held to
 # native/fd_verify.cpp by fdlint FD305): it filled; its deadline passed
-# with the window open to it (_window_open); or it was held past its
-# deadline by the window and sealed at a reap.  Counted at dispatch, so
+# with the window open to it (_window_open) and no backlog in front
+# (_past_deadline); or it was held past its deadline by the window and
+# sealed at a reap.  Counted at dispatch, so
 # the three add up to `batches`.
 _CLOSE_COUNTERS = fm.BATCH_CLOSE_COUNTERS
+
+# why an open batch was kept open past its deadline (_past_deadline): the
+# window was shut to it; or the window was open and the intake backlogged
+_HELD_WINDOW, _HELD_BACKLOGGED = 1, 2
 
 _now_ns = time.monotonic_ns
 
@@ -251,14 +283,14 @@ class _Acc:
     slots: list[int] = field(default_factory=list)  # cached path only
     opened_at: float = 0.0
     life: _Life | None = None  # stamped when the first element enters
-    held: bool = False  # seen past its deadline while the window was shut
+    held: int = 0  # _HELD_* marks: seen past its deadline and kept open
     close: int = CLOSE_FULL  # what sealed it (CLOSE_*)
 
     def clear(self) -> None:
         self.payloads, self.descs = [], []
         self.elems, self.ranges, self.tsorigs, self.slots = [], [], [], []
         self.opened_at = 0.0  # re-stamped by before_credit when reopened
-        self.held = False
+        self.held = 0
 
 
 class VerifyStage(Stage):
@@ -382,8 +414,8 @@ class VerifyStage(Stage):
         # when its last frame has left the queue
         self._emit_marks: list = []
         for name in (_PHASE_COUNTERS + _CLOSE_COUNTERS
-                     + (fm.BATCH_QUEUED_BEHIND, fm.BATCH_FIT_PAD_LANES,
-                        fm.VERIFY_FAIL_ELEMS)):
+                     + (fm.BATCH_QUEUED_BEHIND, fm.BATCH_HELD_BACKLOGGED,
+                        fm.BATCH_FIT_PAD_LANES, fm.VERIFY_FAIL_ELEMS)):
             self.metrics.counters[name] = 0
         self.metrics.counters["batch_stalls"] = 0
         self.metrics.counters["mesh_devices"] = self.mesh_devices
@@ -402,11 +434,12 @@ class VerifyStage(Stage):
         # (slot, n_elems, n_txn, result, life)
         self._nv_inflight: list = []
         self._nv_emit: list = []  # [slot, frame table, published idx, life]
-        # the open batch (named by its C-side open stamp) that was seen
-        # past its deadline while the window was shut to it
-        self._nv_held_ns = 0
-        # the newest dispatched batch closed full (_window_open)
-        self._last_full = False
+        # the open batch (named by its C-side open stamp) that was kept
+        # open past its deadline, and its _HELD_* marks
+        self._nv_held = (0, 0)
+        # the newest dispatched batch closed full behind a running one:
+        # the stage's evidence that the device limits (_window_open)
+        self._last_full_behind = False
         want_native = (native_client if native_client is not None
                        else type(self) is VerifyStage)
         if want_native:
@@ -507,17 +540,25 @@ class VerifyStage(Stage):
                      "batches sealed because they filled (or the next"
                      " txn's signatures did not fit)")
             .counter("batch_close_deadline",
-                     "batches sealed at their deadline with nothing in"
-                     " flight, or with room behind a batch that closed"
-                     " full (flush() counts here)")
+                     "batches sealed past their deadline with nothing in"
+                     " flight (and no backlog in front, or none any more),"
+                     " or with room behind a full batch that was itself"
+                     " queued behind one (flush() counts here)")
             .counter("batch_close_window",
                      "batches held open past their deadline by a batch in"
-                     " flight that was not full (or by a full window),"
-                     " sealed at a reap")
+                     " flight that was not full or went out alone (or by"
+                     " a full window), sealed at a reap")
             .counter(fm.BATCH_QUEUED_BEHIND,
                      "batches dispatched while another was in flight (the"
                      " window's second place: full batches, the batch"
-                     " sealed behind a full one, and flush())")
+                     " sealed behind a full one that was itself queued,"
+                     " and flush())")
+            .counter(fm.BATCH_HELD_BACKLOGGED,
+                     "batches kept open past their deadline with nothing"
+                     " in flight because the intake was backlogged (the"
+                     " last sweep took its whole burst), once a batch:"
+                     " over `batches`, ~1 in a thread-bound flood, ~0"
+                     " where the stage is paced or the device limits")
             # lanes that carried no verdict anyone used: those a batch
             # sealed for want of room left empty, and those of the
             # transactions that failed whole (one bad signature fails
@@ -764,54 +805,77 @@ class VerifyStage(Stage):
         return len(self._flying()) < self.max_inflight
 
     def _window_open(self) -> bool:
-        """A batch that is not full may be sealed now: no sealed batch
-        waits ahead of it, and either nothing is in flight (it would
-        run now) or the window has room right behind a batch that
-        closed FULL.  The ONE predicate of the close rule; it reads
-        nothing but the stage's own window.  Behind a batch that was
-        not full the second place is not taken: queued there these
-        elements would start no sooner, and the batch would stop
-        taking what arrives meanwhile.  Behind a full one it is: a
-        full batch in flight is the stage's own evidence that the
-        device limits, and then a program length of work on the
-        device's queue is what keeps it back to back through the
-        thread's hiccups."""
+        """The window's half of the close rule (module docstring): a
+        batch that is not full may take a place in it now.  No sealed
+        batch waits ahead of it, and either nothing is in flight (it
+        would run now) or the window has room right behind a batch
+        that closed FULL BEHIND A RUNNING ONE: the thread filled a
+        whole batch in under a program length, the stage's own
+        evidence that the device limits.  It reads nothing but the
+        stage's own window.  Behind any other batch the second place
+        is not taken: queued there these elements would start no
+        sooner, and the batch would stop taking what arrives
+        meanwhile.  After a hiccup that lets the device run dry one
+        full batch goes out alone, and the next one restores the
+        evidence."""
         c = self._sweep_client
         if c.sealed_waiting() if c is not None else self._submit_queue:
             return False
-        return not self._flying() or (self._last_full
+        return not self._flying() or (self._last_full_behind
                                       and self._window_has_room())
+
+    def _past_deadline(self, held: int) -> tuple[int | None, int]:
+        """The close rule for one open batch past its deadline, asked
+        here alone on every lane: -> (the CLOSE_* reason to seal it
+        with, or None to keep it open; its _HELD_* marks, `held` and
+        what this pass adds).  It seals if the window is open to it
+        (_window_open) — unless nothing is in flight and the intake is
+        backlogged (Stage.backlogged).  Then the batch would run now,
+        but part empty, at a whole dispatch's cost to a thread that
+        limits: it stays open until it fills, or until the first short
+        sweep ends the backlog and it goes at the next pass through
+        here.  The place behind a full batch that went out behind a
+        running one is taken backlogged or not: where the device
+        limits, a queued partial batch is slack (module docstring).
+        `batch_held_backlogged` counts a batch the first time it is
+        kept open for the backlog alone."""
+        if not self._window_open():
+            return None, held | _HELD_WINDOW
+        if self.backlogged and not self._flying():
+            if not held & _HELD_BACKLOGGED:
+                self.metrics.inc(fm.BATCH_HELD_BACKLOGGED)
+            return None, held | _HELD_BACKLOGGED
+        return (CLOSE_WINDOW if held & _HELD_WINDOW else CLOSE_DEADLINE), held
 
     def _deadline_close(self) -> None:
         """The deadline's half of the close rule (p99 latency at low
-        occupancy): an open batch past its deadline seals if the
-        window is open to it (_window_open), and otherwise stays open,
-        taking frags, until it fills or a reap comes through here
-        again.  Sealing it behind a running batch that was not full
-        would only move its wait from the batch (where lanes fill) to
-        the device's queue (where the frags behind it wait a program
-        length more)."""
+        occupancy): an open batch past its deadline seals if
+        _past_deadline says so, and otherwise stays open, taking
+        frags, until it fills or a pass comes through here again (every
+        pump, and the reap).  Sealing it behind a running batch that
+        was not full would only move its wait from the batch (where
+        lanes fill) to the device's queue (where the frags behind it
+        wait a program length more)."""
         c = self._sweep_client
         if c is not None:
             t = c.open_since_ns()  # ONE u64 read; 0 = nothing open
             if not t or _now_ns() - t < self.batch_deadline_s * 1e9:
                 return
-            if self._window_open():
-                c.seal(CLOSE_WINDOW if self._nv_held_ns == t
-                       else CLOSE_DEADLINE)
+            was, held = self._nv_held
+            why, held = self._past_deadline(held if was == t else 0)
+            if why is None:
+                self._nv_held = (t, held)
             else:
-                self._nv_held_ns = t
+                c.seal(why)
             return
         now = time.monotonic()
         for acc in self._open_accs():
             if not (acc.elems and acc.opened_at
                     and now - acc.opened_at >= self.batch_deadline_s):
                 continue
-            if self._window_open():
-                self._close_batch(
-                    acc, CLOSE_WINDOW if acc.held else CLOSE_DEADLINE)
-            else:
-                acc.held = True
+            why, acc.held = self._past_deadline(acc.held)
+            if why is not None:
+                self._close_batch(acc, why)
 
     def during_housekeeping(self) -> None:
         c = self._sweep_client
@@ -996,7 +1060,7 @@ class VerifyStage(Stage):
         m.observe("inflight_occupancy", occupancy)
         if occupancy > 1:
             m.inc(fm.BATCH_QUEUED_BEHIND)
-        self._last_full = close == CLOSE_FULL
+        self._last_full_behind = close == CLOSE_FULL and occupancy > 1
         self.trace(fm.EV_BATCH_SUBMIT, n)
         d = self.mesh_devices
         for i in range(min(d, n) if d > 1 else 0):
@@ -1011,12 +1075,19 @@ class VerifyStage(Stage):
             # publish first so the intake window reopens
             self._nv_pump()
             return False
-        return super()._native_sweep(drainer)
+        was = self.backlogged
+        progressed = super()._native_sweep(drainer)
+        if c is not None and c.stash_pending:
+            # cut short for want of a slot, not of frags: it says
+            # nothing about the ring in front (Stage._note_sweep)
+            self.backlogged = was
+        return progressed
 
     def _nv_pump(self) -> None:
         """The native lane's batch-granular loop: reap completed heads
         (in order), publish reaped frames from the slot arenas, seal
-        the open batch if its deadline has passed and it would run now,
+        the open batch if its deadline has passed and the close rule
+        lets it go (_deadline_close),
         submit sealed slots into the in-flight window (in seal order).
         Reap -> seal -> dispatch, so the batch the window held open
         goes in the pass that reaped its head; the publish goes before the

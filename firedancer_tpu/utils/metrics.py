@@ -479,16 +479,22 @@ BATCH_BLOCKING_PHASES = frozenset(
     BATCH_PHASES.index(p) for p in ("h2d", "launch", "reap", "publish"))
 BATCH_STALL_NS = 100_000_000
 # What closed a batch, by id: it filled; its deadline passed with the
-# in-flight window open to it (nothing in flight, or room behind a full
-# batch); the window held it open past its deadline and a reap sealed
-# it.  The verify stage counts each dispatched batch in
+# in-flight window open to it (nothing in flight and no backlog in
+# front, or room behind a full batch that was itself queued behind a
+# running one); the window held it open past its deadline and a reap
+# sealed it.  The verify stage counts each dispatched batch in
 # `batch_close_<why>`, so the three add up to `batches`.
 BATCH_CLOSES = ("full", "deadline", "window")
 BATCH_CLOSE_COUNTERS = tuple(f"batch_close_{c}" for c in BATCH_CLOSES)
 # dispatches made while another batch was in flight: how often the
 # window's second place (full batches, the batch sealed behind a full
-# one, and flush()) is used
+# one that was itself queued, and flush()) is used
 BATCH_QUEUED_BEHIND = "batch_queued_behind"
+# batches kept open past their deadline, with nothing in flight, because
+# the intake was backlogged (its last sweep took the whole burst): they
+# fill instead of going out part empty.  Once a batch, so over `batches`
+# it is how often that rule engages
+BATCH_HELD_BACKLOGGED = "batch_held_backlogged"
 # lanes whose verdict nobody used: those a batch sealed for want of room
 # left empty (the next transaction's signatures did not fit, and a
 # transaction's elements land in one batch), and those of the
@@ -499,7 +505,8 @@ VERIFY_FAIL_ELEMS = "verify_fail_elems"
 
 def batch_close_row(regs: list) -> dict | None:
     """{why: batches closed that way, "queued_behind":
-    batch_queued_behind, "fit_pad_lanes": batch_fit_pad_lanes,
+    batch_queued_behind, "held_backlogged": batch_held_backlogged,
+    "fit_pad_lanes": batch_fit_pad_lanes,
     "fail_elems": verify_fail_elems, "stalls": batch_stalls} summed
     over the shard registries of one logical stage, for the monitor and
     slotreport; None where the stage is not a verify stage."""
@@ -507,9 +514,10 @@ def batch_close_row(regs: list) -> dict | None:
             if r is not None and BATCH_CLOSE_COUNTERS[0] in r._off]
     if not regs:
         return None
-    names = zip(BATCH_CLOSES + ("queued_behind", "fit_pad_lanes",
-                                "fail_elems", "stalls"),
+    names = zip(BATCH_CLOSES + ("queued_behind", "held_backlogged",
+                                "fit_pad_lanes", "fail_elems", "stalls"),
                 BATCH_CLOSE_COUNTERS + (BATCH_QUEUED_BEHIND,
+                                        BATCH_HELD_BACKLOGGED,
                                         BATCH_FIT_PAD_LANES,
                                         VERIFY_FAIL_ELEMS, "batch_stalls"))
     return {k: sum(r.get(n) for r in regs) for k, n in names}

@@ -542,6 +542,7 @@ def test_a_txn_that_does_not_fit_opens_the_next_batch(lane):
 
 CLOSE_COUNTERS = list(rv._CLOSE_COUNTERS)
 QUEUED_BEHIND = fm.BATCH_QUEUED_BEHIND
+HELD_BACKLOGGED = fm.BATCH_HELD_BACKLOGGED
 
 
 class _Gated:
@@ -795,6 +796,267 @@ def test_a_full_batch_takes_the_second_place_and_never_a_third(lane, pool):
         assert _deepest(st) == 2
 
 
+# -- a backlogged intake fills its batch (ISSUE 36) ------------------------------
+#
+# The one thing the close rule reads that is not the window: whether the
+# last intake sweep took its whole burst (Stage.backlogged).  Driven with a
+# burst of 4 under a batch of 16, so that a sweep can be full and the batch
+# not; a pass through the rule without a sweep is after_credit().
+
+BURST = 4
+
+
+def _full_sweeps(st, prod, pool, lo: int, k: int) -> int:
+    """k sweeps that each take their whole burst: the ring in front never
+    runs dry.  -> transactions fed."""
+    assert st.burst == BURST
+    _feed(prod, pool, lo, lo + k * BURST)
+    for _ in range(k):
+        st.run_once()
+        assert st.backlogged
+    return lo + k * BURST
+
+
+def _short_sweep(st, prod, pool, lo: int, n: int = 0) -> int:
+    """One sweep that takes n < burst frags: the ring ran dry."""
+    assert n < st.burst
+    _feed(prod, pool, lo, lo + n)
+    st.run_once()
+    assert not st.backlogged
+    return lo + n
+
+
+def _overdue(st) -> None:
+    """Let the open batch's deadline pass, and go through the close rule
+    once, without a sweep."""
+    st.before_credit()         # the Python lane stamps the open batch here
+    time.sleep(st.batch_deadline_s * 3)
+    st.after_credit()
+
+
+def _held(st) -> int:
+    return st.metrics.get(HELD_BACKLOGGED)
+
+
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_a_backlogged_intake_keeps_the_open_batch_filling_past_the_reap(
+        lane, pool):
+    """With a batch that was not full in flight and every sweep full, the
+    pump that reaps it does not seal the open batch: it would run now,
+    part empty, at a whole dispatch's cost.  It goes when it fills, as a
+    full batch, and is counted once as held."""
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        st.burst = BURST
+        got: list = []
+        n = _deadline_batch(st, prod, cons, pool, got, 0, 3)
+        assert not st.backlogged and _held(st) == 0
+        n = _full_sweeps(st, prod, pool, n, 2)
+        _overdue(st)           # held by the window: 3 lanes are in flight
+        assert st.metrics.get("batches") == 1 and _open_elems(st) == 8
+        assert _held(st) == 0
+        sent[0].done = True
+        st.after_credit()      # the reap: nothing in flight, but backlogged
+        _collect(cons, got)
+        assert _in_flight(st) == 0 and got == list(pool[:3])
+        assert st.metrics.get("batches") == 1 and _open_elems(st) == 8
+        assert not _sealed_waiting(st) and _held(st) == 1
+        n = _full_sweeps(st, prod, pool, n, 2)      # it fills ...
+        st.after_credit()
+        assert [(g.n, g.close, g.behind) for g in sent] \
+            == [(3, rv.CLOSE_DEADLINE, 0), (16, rv.CLOSE_FULL, 0)]
+        assert _closes(st) == [1, 1, 0] and _open_elems(st) == 0
+        assert _held(st) == 1                   # ... counted once, not a pass
+        # a full batch that went out alone is no evidence that the
+        # device limits: the next one is held the same way
+        n = _full_sweeps(st, prod, pool, n, 1)
+        _overdue(st)
+        assert not st._last_full_behind and _open_elems(st) == 4
+        assert st.metrics.get("batches") == 2 and _held(st) == 1
+        sent[1].done = True
+        st.after_credit()
+        assert st.metrics.get("batches") == 2 and _held(st) == 2
+        for _ in range(3):
+            st.after_credit()
+        assert _held(st) == 2 and _open_elems(st) == 4
+        st.flush()
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+        assert sum(_closes(st)) == st.metrics.get("batches") == 3
+        assert st.metrics.get(QUEUED_BEHIND) == 0
+
+
+@pytest.mark.parametrize("flying", [True, False])
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_the_first_short_sweep_ends_the_backlog(lane, flying, pool):
+    """On/off feed: the batch a backlog held goes out at the first pass
+    through the rule after a sweep came back short, closed by the
+    deadline, or by the window if a batch in flight held it first."""
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        st.burst = BURST
+        got: list = []
+        n = 0
+        if flying:
+            n = _deadline_batch(st, prod, cons, pool, got, 0, 3)
+        n = _full_sweeps(st, prod, pool, n, 2)
+        _overdue(st)
+        if flying:
+            assert _held(st) == 0
+            sent[0].done = True
+            st.after_credit()
+        assert _held(st) == 1 and _in_flight(st) == 0
+        assert st.metrics.get("batches") == flying and _open_elems(st) == 8
+        st.run_once()          # the rule (still held), then a short sweep
+        assert not st.backlogged
+        assert st.metrics.get("batches") == flying and _open_elems(st) == 8
+        st.run_once()          # the next pass: the rule as it was
+        assert st.metrics.get("batches") == flying + 1
+        assert (sent[-1].n, sent[-1].behind) == (8, 0)
+        assert _closes(st) == ([0, 1, 1] if flying else [0, 1, 0])
+        assert _held(st) == 1 and _open_elems(st) == 0
+        # and a batch that opens with the ring dry is not held at all
+        sent[-1].done = True
+        n = _short_sweep(st, prod, pool, n, 2)
+        _overdue(st)
+        assert st.metrics.get("batches") == flying + 2 and _held(st) == 1
+        sent[-1].done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+
+
+@pytest.mark.parametrize("backlogged", [True, False])
+@pytest.mark.parametrize("behind", [True, False])
+@pytest.mark.parametrize("lane", LANES)
+def test_only_a_full_batch_queued_behind_a_running_one_is_evidence(
+        lane, behind, backlogged, pool):
+    """Clause (b) of the close rule (ISSUE 32) on the evidence of ISSUE
+    36.  Behind a full batch that was itself dispatched behind a running
+    one the deadline seal queues a batch that is not full, backlogged or
+    not: the device limits, and a queued partial batch is slack.  Behind
+    a full batch that went out alone it does not: under a backlog every
+    batch of a thread-bound stage closes full."""
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        st.burst = BURST
+        got: list = []
+        if behind:
+            n = _fill_window(st, prod, cons, pool, got)   # 3, 16 behind it
+            sent[0].done = True
+            st.after_credit()
+        else:
+            n = _full_batch(st, prod, cons, pool, got, 0)
+        first = len(sent)
+        assert _in_flight(st) == 1 and sent[-1].close == rv.CLOSE_FULL
+        assert st._last_full_behind == behind and st._window_has_room()
+        if backlogged:
+            n = _full_sweeps(st, prod, pool, n, 1)
+        else:
+            n = _short_sweep(st, prod, pool, n, 3)
+        k = _open_elems(st)
+        assert k == (4 if backlogged else 3)
+        _overdue(st)
+        if behind:
+            assert st._window_open() is False     # both places taken now
+            assert len(sent) == first + 1 and _open_elems(st) == 0
+            assert (sent[-1].n, sent[-1].close, sent[-1].behind) \
+                == (k, rv.CLOSE_DEADLINE, 1)
+            assert _held(st) == 0
+        else:
+            # held by the window, not by the backlog: not counted
+            assert len(sent) == first and _open_elems(st) == k
+            assert not _sealed_waiting(st) and _held(st) == 0
+            sent[0].done = True
+            st.after_credit()      # the reap: nothing in flight
+            if backlogged:
+                assert len(sent) == first and _held(st) == 1
+                n = _short_sweep(st, prod, pool, n)
+                st.after_credit()
+            assert len(sent) == first + 1 and _open_elems(st) == 0
+            assert (sent[-1].n, sent[-1].close, sent[-1].behind) \
+                == (k, rv.CLOSE_WINDOW, 0)
+        for g in sent:
+            g.done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+        assert sum(_closes(st)) == st.metrics.get("batches") == len(sent)
+
+
+@pytest.mark.parametrize("lane", CLOSE_LANES)
+def test_flush_sends_a_batch_the_backlog_held(lane, pool):
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        st.burst = BURST
+        got: list = []
+        n = _full_sweeps(st, prod, pool, 0, 3)
+        _overdue(st)
+        assert _held(st) == 1 and sent == [] and _open_elems(st) == 12
+        st.flush()             # blocks on the head: no gate needed
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+        assert [(g.n, g.behind) for g in sent] == [(12, 0)]
+        assert _closes(st) == [0, 1, 0] and _held(st) == 1
+
+
+# What the rule of PR 32 (the parent of ISSUE 36) dispatches under a paced
+# feed, every sweep short of its burst, as (lanes, close reason, batches
+# in flight ahead) per batch: recorded from the parent commit with the
+# scripts below, the same on every lane.  "feed" k offers and sweeps them
+# (k < 16 = the burst: a full batch is fed as 15 + 1); "late" lets the
+# deadline pass; "reap" lets the oldest batch in flight come back.
+_F, _D, _W = rv.CLOSE_FULL, rv.CLOSE_DEADLINE, rv.CLOSE_WINDOW
+PACED_CASES = {
+    "alone_at_the_deadline": (
+        [("feed", 5), ("late",), ("reap",)],
+        [(5, _D, 0)]),
+    "held_by_one_in_flight": (
+        [("feed", 3), ("late",), ("feed", 3), ("late",), ("feed", 2),
+         ("reap",)],
+        [(3, _D, 0), (5, _W, 0)]),
+    "one_after_another": (
+        [("feed", 2), ("late",), ("reap",), ("feed", 2), ("late",)],
+        [(2, _D, 0), (2, _D, 0)]),
+    "fills_behind_a_running_one": (
+        [("feed", 3), ("late",), ("feed", 15), ("feed", 1)],
+        [(3, _D, 0), (16, _F, 1)]),
+    "queued_behind_a_full_one_that_was_queued": (
+        [("feed", 3), ("late",), ("feed", 15), ("feed", 1), ("reap",),
+         ("feed", 3), ("late",)],
+        [(3, _D, 0), (16, _F, 1), (3, _D, 1)]),
+    "a_full_window_holds_the_sealed_and_the_open": (
+        [("feed", 3), ("late",), ("feed", 15), ("feed", 1), ("feed", 15),
+         ("feed", 1), ("feed", 4), ("late",), ("reap",), ("reap",)],
+        [(3, _D, 0), (16, _F, 1), (16, _F, 1), (4, _W, 1)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACED_CASES))
+@pytest.mark.parametrize("lane", LANES)
+def test_a_paced_feed_closes_its_batches_as_before(lane, case, pool):
+    """Short sweeps throughout: the backlog never holds, and the seals
+    and dispatches are the parent's, case by case."""
+    script, want = PACED_CASES[case]
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        assert st.burst == 16
+        got: list = []
+        n = reaped = 0
+        for op, *arg in script:
+            if op == "feed":
+                _feed(prod, pool, n, n + arg[0])
+                n += arg[0]
+                _spin(st, cons, got, loops=8)
+            elif op == "late":
+                _past_deadline(st, cons, got)
+            else:
+                sent[reaped].done = True
+                reaped += 1
+                st.after_credit()
+            assert not st.backlogged
+        assert [(g.n, g.close, g.behind) for g in sent] == want
+        assert _held(st) == 0
+        for g in sent:
+            g.done = True
+        st.flush()
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+
+
 @pytest.mark.parametrize("depth", [2, 1])
 def test_a_full_shard_closes_a_held_step_through_the_reap_it_waits_for(
         depth, pool):
@@ -815,7 +1077,11 @@ def test_a_full_shard_closes_a_held_step_through_the_reap_it_waits_for(
             first.append(16)
         n = _held_batch(st, prod, cons, pool, got, n)    # shard 0: held
         assert st._shards[0].held and got == []
-        _feed(prod.shard1, pool, n, n + 16)       # shard 1 fills
+        # shard 1 fills, from a ring that runs dry (a sweep short of the
+        # burst: no backlog keeps the held step open at the reap)
+        _feed(prod.shard1, pool, n, n + 15)
+        _spin(st, cons, got, loops=2)
+        _feed(prod.shard1, pool, n + 15, n + 16)
         _spin(st, cons, got, loops=4)
         assert [g.n for g in sent] == first + [19]
         assert st.metrics.get("batches") == depth + 1
@@ -912,8 +1178,8 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
         assert reg is not None
         row = fm.batch_close_row([reg])
         assert row == {"full": 1, "deadline": 2, "window": 0,
-                       "queued_behind": 1, "fit_pad_lanes": 0,
-                       "fail_elems": 0, "stalls": 0}
+                       "queued_behind": 1, "held_backlogged": 0,
+                       "fit_pad_lanes": 0, "fail_elems": 0, "stalls": 0}
         assert sum(row[c] for c in fm.BATCH_CLOSES) \
             == st.metrics.get("batches")
         text = fm.render_prometheus({"v0": reg})
@@ -926,11 +1192,12 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
               "batch_closes": row,
               "mesh": fm.mesh_row(reg)}], None, 1.0)
         assert "v0: batches closed full=1 deadline=2 window=0" \
-               "  queued_behind=1  batch_stalls=0" in rendered
+               "  queued_behind=1  held_backlogged=0  batch_stalls=0" \
+               in rendered
         dump = fm.flight_dump_obj("t", {"v0": (reg, st.recorder)})
         block = slot_report.build_report(dump)["stages"]["v0"]
         assert block["batch_closes"] == {c: row[c] for c in fm.BATCH_CLOSES}
-        assert block[QUEUED_BEHIND] == 1
+        assert block[QUEUED_BEHIND] == 1 and block[HELD_BACKLOGGED] == 0
         # over a mesh: how many chips and the useful lanes of each, in
         # the same three places; with one device, in none
         if lane == "mesh":

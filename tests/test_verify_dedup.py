@@ -248,45 +248,74 @@ def test_one_bad_signature_in_eight_fails_the_transaction_whole(
     assert want.out == [0, 2] and want.verify_fail_elems == 8
 
 
+@pytest.mark.parametrize("behind", [True, False])
 @pytest.mark.parametrize("lane", LANES)
 def test_a_batch_sealed_for_want_of_room_still_counts_as_full(
-        lane, stream):
+        lane, behind, stream):
     """Nine 7-signature transactions fill 63 of 64 lanes; the next does
     not fit, so the batch is sealed CLOSE_FULL one lane short, and the
-    clause of PR 32 that rests on "full" holds for it: the batch behind
-    it, past its deadline, is queued behind it while it runs."""
+    clause of PR 32 that rests on "full" holds for it on the evidence
+    of ISSUE 36: dispatched BEHIND a running batch it says that the
+    device limits, and the batch behind it, past its deadline, is queued
+    behind it while it runs; dispatched alone it says nothing, and the
+    batch behind it stays open until the reap."""
     pool, _order, _bad = stream
     seven = [i for i in np.flatnonzero(pool.sigs == 7) if pool.valid[i]][:9]
-    two = [i for i in np.flatnonzero(pool.sigs == 2) if pool.valid[i]][:1]
-    assert len(seven) == 9 and len(two) == 1
+    two = [i for i in np.flatnonzero(pool.sigs == 2) if pool.valid[i]][:2]
+    assert len(seven) == 9 and len(two) == 2
     with _pair(lane, deadline_s=0.001, precomputed_ok=True) \
             as (verify, dedup, prod, cons):
-        verify._mask_ready = lambda result: False     # the batch stays up
-        for fed, i in enumerate(list(seven) + two):
+        reaped: list = []          # the results the test lets come back
+        verify._mask_ready = lambda result: any(result is r for r in reaped)
+        m = verify.metrics
+        rows = list(seven) + two[:1]
+        if behind:
+            # a batch of two lanes goes out alone at its deadline first
+            assert prod.try_publish(pool.row(int(two[1])), sig=99, tsorig=1)
+            for _ in range(5):
+                verify.run_once()
+            time.sleep(0.005)
+            for _ in range(5):
+                verify.run_once()
+            assert m.get("batches") == 1 and len(verify._flying()) == 1
+            first = verify._flying()[0]
+        for fed, i in enumerate(rows):
             assert prod.try_publish(pool.row(int(i)), sig=fed, tsorig=1)
         for _ in range(20):
             verify.run_once()
-        m = verify.metrics
-        assert m.get("batches") >= 1 and m.get("batch_close_full") == 1
+        assert m.get("batches") == 1 + behind
+        assert m.get("batch_close_full") == 1
         assert m.get(fm.BATCH_FIT_PAD_LANES) in (0, 1)
         verify.during_housekeeping()
         assert m.get(fm.BATCH_FIT_PAD_LANES) == 1
-        assert verify._last_full
+        assert m.get(fm.BATCH_QUEUED_BEHIND) == behind
+        assert verify._last_full_behind == behind
         time.sleep(0.005)
         for _ in range(20):
             verify.run_once()
-        # the two-lane batch went behind the running one, at its deadline
-        assert len(verify._flying()) == 2
-        assert m.get("batch_close_deadline") == 1
-        assert m.get(fm.BATCH_QUEUED_BEHIND) == 1
-        assert m.get("batch_elems") == 65
+        # past its deadline the two-lane batch is held: by the full
+        # window, or by the full batch that went out alone
+        assert len(verify._flying()) == 1 + behind
+        assert m.get("batches") == 1 + behind and not verify._window_open()
+        if behind:
+            # the reap of the first leaves room behind the 63 lanes, and
+            # the two-lane batch goes behind them in the same pass
+            reaped.append(first.result if lane != "native" else first[3])
+            verify.run_once()
+            assert len(verify._flying()) == 2
+            assert m.get("batch_close_window") == 1
+            assert m.get(fm.BATCH_QUEUED_BEHIND) == 2
+        else:
+            assert m.get(fm.BATCH_QUEUED_BEHIND) == 0
+        assert m.get(fm.BATCH_HELD_BACKLOGGED) == 0
         del verify._mask_ready
         out: list = []
         verify.flush()
+        assert m.get("batch_elems") == 65 + 2 * behind
         for _ in range(20):
             dedup.run_once()
             _collect(cons, out)
-        assert len(out) == 10
+        assert len(out) == 10 + behind
 
 
 def _one_bad_in_each_position(shape, seed: int) -> list[bytes]:

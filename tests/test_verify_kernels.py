@@ -22,7 +22,9 @@ import pytest
 from firedancer_tpu.runtime import verify as rv
 from firedancer_tpu.runtime import verify_tune as vt
 from firedancer_tpu.runtime.benchg import gen_transfer_pool
+from firedancer_tpu.runtime.stage import Stage
 from firedancer_tpu.runtime.verify import VerifyStage
+from firedancer_tpu.tango import shm
 
 
 # -- ladder structure (no device) ---------------------------------------------
@@ -251,6 +253,159 @@ def test_a_batch_that_is_not_full_is_not_queued_behind_another_such(
     st.flush()
     n = 6 if max_inflight is None else 5
     assert [e[2] for e in st.emitted] == list(range(1000, 1000 + n))
+
+
+@pytest.mark.parametrize("max_inflight", [None, 1])
+def test_a_backlogged_intake_holds_the_batch_that_would_run_part_empty(
+        txn_pool, max_inflight):
+    """ISSUE 36, the Python lane with fake futures: while the intake's
+    last sweep took its whole burst (Stage.backlogged, set here by hand:
+    no ring feeds this stage) a batch past its deadline with nothing in
+    flight stays open, is counted once, and goes when it fills, or at
+    the first pass after the backlog ends."""
+    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
+                      batch_deadline_s=0.0, max_inflight=max_inflight)
+
+    def held():
+        return st.metrics.get("batch_held_backlogged")
+
+    st.backlogged = True
+    _feed(st, txn_pool[:2])
+    for _ in range(3):
+        st.before_credit()
+        st.after_credit()
+    assert not st._inflight and len(st._gen.elems) == 2
+    assert st._gen.held == rv._HELD_BACKLOGGED and held() == 1
+    _feed(st, txn_pool[2:4], t0=1002)       # it fills: out at once, alone
+    assert len(st._inflight) == 1 and not st._gen.elems
+    assert st.metrics.get("batch_close_full") == 1
+    assert not st._last_full_behind
+    # behind it the next one is held by the window, then by the backlog
+    _feed(st, txn_pool[4:6], t0=1004)
+    st.before_credit()
+    st.after_credit()
+    assert st._gen.held == rv._HELD_WINDOW and held() == 1
+    st.fakes[0].ready = True
+    st.after_credit()
+    assert not st._inflight and len(st._gen.elems) == 2
+    assert st._gen.held == rv._HELD_WINDOW | rv._HELD_BACKLOGGED
+    assert held() == 2
+    # the backlog ends: the rule as it was, at the next pass
+    st.backlogged = False
+    st.after_credit()
+    assert len(st._inflight) == 1 and not st._gen.elems
+    assert st.metrics.get("batch_close_window") == 1
+    assert st.metrics.get("batch_queued_behind") == 0
+    st.fakes[1].ready = True
+    st.flush()
+    assert [e[2] for e in st.emitted] == list(range(1000, 1006))
+    assert st.metrics.get("batches") == 2 and held() == 2
+
+
+@pytest.mark.parametrize("backlogged", [False, True])
+def test_the_place_behind_a_full_batch_needs_it_to_have_been_queued(
+        txn_pool, backlogged):
+    """Clause (b): a batch that is not full goes behind a full one only
+    if that one was itself dispatched behind a running batch, backlogged
+    or not."""
+    st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
+                      batch_deadline_s=0.0)
+    st.backlogged = backlogged
+    _feed(st, txn_pool[:4])                 # full, out alone
+    _feed(st, txn_pool[4:6], t0=1004)
+    st.before_credit()
+    st.after_credit()
+    assert len(st._inflight) == 1 and len(st._gen.elems) == 2
+    assert not st._window_open() and st._window_has_room()
+    _feed(st, txn_pool[6:8], t0=1006)       # full, behind the running one
+    assert len(st._inflight) == 2 and st._last_full_behind
+    _feed(st, txn_pool[8:10], t0=1008)
+    st.before_credit()
+    st.fakes[0].ready = True
+    st.after_credit()                       # room behind it: taken
+    assert len(st._inflight) == 2 and not st._gen.elems
+    assert st.metrics.get("batch_queued_behind") == 2
+    assert st.metrics.get("batch_held_backlogged") == 0
+    assert not st._last_full_behind         # that one was not full
+    for f in st.fakes:
+        f.ready = True
+    st.flush()
+    assert [e[2] for e in st.emitted] == list(range(1000, 1010))
+
+
+class _CountStage(Stage):
+    """Forwards what it takes: the out ring's credits bound its sweeps."""
+
+    def after_frag(self, in_idx, meta, payload):
+        self.publish(0, payload, sig=int(meta[1]))
+
+
+@pytest.mark.parametrize("rings", ["native", "python"])
+def test_the_intake_says_whether_the_ring_in_front_ran_dry(
+        rings, monkeypatch):
+    """Stage.backlogged on the poll loop and on the native drain: a sweep
+    that took its whole burst sets it, one that came back short clears
+    it, and one that credits downstream cut short, or that was skipped
+    for want of them, leaves it as it was."""
+    monkeypatch.setenv("FDTPU_NATIVE_RING", "1" if rings == "native" else "0")
+    uid = shm.fresh_uid()
+    lin = shm.ShmLink.create(f"tvk_i_{uid}", depth=64, mtu=64, n_fseq=1)
+    lout = shm.ShmLink.create(f"tvk_o_{uid}", depth=4, mtu=64, n_fseq=1)
+    st = None
+    try:
+        prod = shm.make_producer(lin)
+        cons = shm.make_consumer(lout, lazy=1)
+        st = _CountStage("s", ins=[shm.make_consumer(lin, lazy=1)],
+                         outs=[shm.make_producer(lout)])
+        assert (type(st.ins[0]).__name__ == "NativeConsumer") \
+            == (rings == "native")
+        st.burst = 4
+        st.require_credit = True
+        fed = iter(range(64))
+
+        def feed(n):
+            for _ in range(n):
+                assert prod.try_publish(b"x" * 8, sig=next(fed), tsorig=0)
+
+        def drain():
+            n = 0
+            while cons.poll() not in (shm.POLL_EMPTY, shm.POLL_OVERRUN):
+                n += 1
+            return n
+
+        assert st.backlogged is False
+        feed(5)
+        st.run_once()                       # 4 of 5: the whole burst
+        assert st.backlogged and st.metrics.get("frags_in") == 4
+        st.run_once()                       # no credits: skipped
+        assert st.backlogged and st.metrics.get("frags_in") == 4
+        assert drain() == 4
+        st.run_once()                       # 1 left: the ring ran dry
+        assert not st.backlogged and st.metrics.get("frags_in") == 5
+        assert drain() == 1
+        st.run_once()                       # nothing there
+        assert not st.backlogged
+        # fewer credits than the burst, for a ring that holds more: the
+        # sweep is cut short, and says nothing either way
+        out = st.outs[0]
+        feed(2)
+        st.run_once()
+        assert not st.backlogged and st.metrics.get("frags_in") == 7
+        out.refresh_credits()
+        left = out.cr_avail
+        assert 0 < left < st.burst
+        feed(12)
+        st.run_once()
+        assert st.metrics.get("frags_in") == 7 + left and not st.backlogged
+        drain()
+        st.run_once()                       # credits for the whole burst
+        assert st.metrics.get("frags_in") == 11 + left and st.backlogged
+    finally:
+        if st is not None:
+            st.ins, st.outs = [], []
+            st.drop_native_views()
+        lin.close()
+        lout.close()
 
 
 def test_flush_drains_window_and_queue(txn_pool):
