@@ -421,6 +421,7 @@ class BankStage(Stage):
         if last is not None:
             slot = min(slot, last + 1)  # window-bounded, like pack's
         if slot > self._clock_slot:
+            self._loop_worked = True    # a slot rolled
             self.metrics.inc("slot_boundaries", slot - self._clock_slot)
             self.trace(fm.EV_SLOT_ROLL, slot)
             self._clock_slot = slot
@@ -458,6 +459,7 @@ class BankStage(Stage):
         sx = self.ctx.sx
         log = c.take_log()
         if log:
+            self._loop_worked = True    # the last sweep's results land here
             groups = bd.parse_log(log)
             # All-or-nothing credit gate: the C lane stashed these
             # microblocks BECAUSE an out ring had no credit, and

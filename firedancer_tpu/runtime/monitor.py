@@ -320,7 +320,9 @@ class MonitorSession:
             }
             row.update(fm.latency_row(j.registry))
             row["sweep_phases"] = fm.nsweep_phase_row([j.registry])
+            row["loop"] = fm.loop_row([j.registry])
             row["batch_closes"] = fm.batch_close_row([j.registry])
+            row["chip_empty"] = fm.chip_empty_row(j.registry)
             row["mesh"] = fm.mesh_row(j.registry)
             row["votes"] = fm.vote_row(j.registry)
             row["dedup"] = fm.dedup_row(j.registry)
@@ -348,6 +350,7 @@ class MonitorSession:
             row.update(fm.latency_row_merged([j.registry for j in js]))
             row["sweep_phases"] = fm.nsweep_phase_row(
                 [j.registry for j in js])
+            row["loop"] = fm.loop_row([j.registry for j in js])
             row["batch_closes"] = fm.batch_close_row(
                 [j.registry for j in js])
             out.append(row)
@@ -394,9 +397,15 @@ class MonitorSession:
             if p and dt_s > 0:
                 in_rate = (r["in"] - p["in"]) / dt_s
                 out_rate = (r["out"] - p["out"]) / dt_s
-                diters = r["iters"] - p["iters"]
-                dwork = r["in"] - p["in"] + r["out"] - p["out"]
-                busy = 100.0 * dwork / diters if diters > 0 else 0.0
+                # busy% is time: the share of the stage's loop time,
+                # between the two samples, in calls that did work (the
+                # thread's ledger; "-" where the metrics plane is not
+                # joined)
+                lp, lp0 = r.get("loop"), p.get("loop")
+                if lp and lp0:
+                    pct = fm.loop_busy_pct(*(lp[k] - lp0[k] for k in
+                                             ("work_ns", "poll_ns", "hk_ns")))
+                    busy = 0.0 if pct is None else pct
             hb = (f"{r['heartbeat_age_ms']:.1f}"
                   if r["heartbeat_age_ms"] is not None else "-")
             fmt = lambda v: "-" if v != v else f"{v:,.0f}"  # noqa: E731
@@ -415,7 +424,10 @@ class MonitorSession:
         # in front kept open past their deadline, its stalls, and
         # the lanes nobody used: left empty because the next
         # transaction did not fit, spent on transactions that failed
-        # whole (cumulative)
+        # whole (cumulative); then, between the two samples, the share
+        # of the time the chip had nothing of the stage's to run, and
+        # of that the thread's time in other stages and in the stage's
+        # own blocking calls
         for r in rows:
             bc = r.get("batch_closes")
             if bc:
@@ -426,7 +438,11 @@ class MonitorSession:
                     + f"  held_backlogged={bc['held_backlogged']:,}"
                     + f"  batch_stalls={bc['stalls']:,}"
                     + f"  fit_pad_lanes={bc['fit_pad_lanes']:,}"
-                    + f"  verify_fail_elems={bc['fail_elems']:,}")
+                    + f"  verify_fail_elems={bc['fail_elems']:,}"
+                    + ("  " + fm.format_chip_empty(
+                        r["chip_empty"],
+                        (prev_by.get(r["stage"]) or {}).get("chip_empty"),
+                        dt_s) if r.get("chip_empty") else ""))
             mesh = r.get("mesh")
             if mesh:
                 lines.append(

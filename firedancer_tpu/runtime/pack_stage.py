@@ -256,6 +256,7 @@ class PackStage(Stage):
             # time while the topology drains)
             slot = min(slot, last + 1)
         if slot > self._clock_slot:
+            self._loop_worked = True    # a block closed
             self.pack.end_block()
             self.metrics.inc("blocks_closed", slot - self._clock_slot)
             self.trace(fm.EV_SLOT_ROLL, slot)
@@ -395,6 +396,7 @@ class NativePackStage(PackStage):
             return
         from firedancer_tpu.pack import scheduler_native as sn
 
+        self._loop_worked = True    # the last sweep's frags go in here
         codes = self.pack.insert_burst(self._burst)
         self._burst.clear()
         m = self.metrics

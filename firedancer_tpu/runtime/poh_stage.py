@@ -159,6 +159,7 @@ class PohStage(Stage):
         n = min(self.hashes_per_iter, room)
         if n <= 0:  # clock stopped (drain mode)
             return
+        self._loop_worked = True    # hashed: the call did work
         self.chain.append(n)
         self._hashes_since_entry += n
         if self.chain.hashcnt % self.hashes_per_tick == 0:
@@ -207,6 +208,7 @@ class PohStage(Stage):
             if need > 0:
                 cap = need if due else min(self.hashes_per_iter, need - 1)
                 if cap > 0:
+                    self._loop_worked = True    # hashed: the call did work
                     self.chain.append(cap)
                     self._hashes_since_entry += cap
             if not due or self._tick_progress() < self.hashes_per_tick:
@@ -251,6 +253,7 @@ class PohStage(Stage):
         self._advance_slot(self.slot + missed)
 
     def _advance_slot(self, slot: int) -> None:
+        self._loop_worked = True    # a slot sealed or missed
         self.slot = slot
         self._tick_cnt = 0
         self._slot_hash_base = self.chain.hashcnt

@@ -162,6 +162,7 @@ class ShredStage(Stage):
             # batch deferred for credits in C: retry with the flag the
             # deferred flush recorded (block_complete survives the wait)
             if c.pending_flush:
+                self._loop_worked = True    # publishes in C
                 c.retry_flush()
             return
         # batch closed for size but deferred for credits: retry here

@@ -140,6 +140,16 @@ def _stage_block(mets: dict, records: list) -> dict:
         for name in (fm.BATCH_QUEUED_BEHIND, fm.BATCH_HELD_BACKLOGGED,
                      fm.BATCH_FIT_PAD_LANES, fm.VERIFY_FAIL_ELEMS):
             block[name] = int(mets.get(name, 0) or 0)
+    # when the chip had nothing of the verify stage's to run (ns and
+    # intervals, cumulative), and of that the shares the thread spent
+    # in other stages and in the stage's own blocking calls
+    empty = fm.chip_empty_row(mets)
+    if empty:
+        ns = empty["ns"]
+        block["chip_empty"] = dict(
+            empty,
+            away_pct=100.0 * empty["away_ns"] / ns if ns else None,
+            call_pct=100.0 * empty["call_ns"] / ns if ns else None)
     # a verify stage over a mesh: how many chips, and the useful lanes
     # each was dealt
     mesh = fm.mesh_row(mets)

@@ -34,6 +34,8 @@ from firedancer_tpu.tango.rings import CNC_SIG_HALT, CNC_SIG_RUN, Cnc, MCache
 from firedancer_tpu.utils import metrics as fm
 from .autotune import OCC_EDGES
 
+_now_ns = time.monotonic_ns
+
 
 # tango.native, resolved lazily: stages must boot (and the Python lane
 # must run) in toolchain-less environments where the import-time .so
@@ -75,7 +77,11 @@ class Metrics:
 
     def __init__(self, schema: fm.MetricsSchema | None = None):
         self.schema = schema if schema is not None else fm.stage_schema()
-        self.counters: dict[str, int] = {}
+        # the loop's own counters start at 0: run_once adds to them
+        # every call and reads `frags_out` beside them, without asking
+        # whether they are there (a stage may swap in a wider Metrics)
+        self.counters: dict[str, int] = dict.fromkeys(
+            fm.LOOP_COUNTERS + ("frags_out",), 0)
         # histogram state: plain lists + float sums; bisect_left over a
         # tuple of precomputed edges is ~10x cheaper than np.searchsorted
         self._hedges: dict[str, tuple] = {}
@@ -243,6 +249,14 @@ class Stage:
         self._next_housekeeping = 0
         self._iter = 0
         self._in_rr = 0  # round-robin input cursor
+        # the thread's ledger (run_once): every call is charged to one
+        # regime.  A hook that worked without consuming or publishing a
+        # frag says so here; run_once reads and clears it
+        self._loop_worked = False
+        # the last call's entry and exit, on time.monotonic_ns(): what
+        # lies between an exit and the next entry is the other stages'
+        self._loop_entry_ns = 0
+        self._loop_exit_ns = 0
         self.cnc.signal = CNC_SIG_RUN
 
     # -- observability ------------------------------------------------------
@@ -464,12 +478,45 @@ class Stage:
         )
 
     def run_once(self) -> bool:
-        """One loop iteration; returns True if any frag was processed."""
+        """One loop iteration; returns True if any frag was processed.
+
+        The ONE place a call is stamped (the thread's ledger, upstream's
+        stem regimes): two clock reads a call, charged whole to one of
+        three regimes.  `loop_hk_ns`: the housekeeping pass, taken out
+        of the call it ran in.  `loop_work_ns` / `loop_work_n`: the
+        call did work — it consumed a frag (the three intake paths say
+        so), its own `frags_out` moved (a publish from any hook), or a
+        hook that works with neither said so through `_loop_worked`
+        (verify's pump dispatching or reaping, a tick's hashes, a slot
+        close, a flush that publishes in C).  `loop_poll_ns` /
+        `loop_poll_n`: it found nothing to do (a credit-gated return
+        too).  The exit stamp is kept: what lies between it and the
+        next entry is the other stages' time."""
+        c = self.metrics.counters
+        t0 = self._loop_entry_ns = _now_ns()
         self._iter += 1
+        halted = False
         if self._iter >= self._next_housekeeping:
             self._housekeeping()
-            if self.cnc.signal == CNC_SIG_HALT:
-                return False
+            t = _now_ns()
+            c["loop_hk_ns"] += t - t0
+            t0 = t
+            halted = self.cnc.signal == CNC_SIG_HALT
+        out0 = c["frags_out"]
+        progressed = False if halted else self._sweep()
+        t1 = self._loop_exit_ns = _now_ns()
+        if progressed or self._loop_worked or c["frags_out"] != out0:
+            self._loop_worked = False
+            c["loop_work_ns"] += t1 - t0
+            c["loop_work_n"] += 1
+        else:
+            c["loop_poll_ns"] += t1 - t0
+            c["loop_poll_n"] += 1
+        return progressed
+
+    def _sweep(self) -> bool:
+        """The body of one iteration, after housekeeping: credits, the
+        hooks, one intake sweep.  -> whether a frag was processed."""
         self.before_credit()
         backpressured = any(p.cr_avail <= 0 for p in self.outs)
         if backpressured:

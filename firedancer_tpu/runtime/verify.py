@@ -95,6 +95,17 @@ batches sealed because the next transaction's signatures did not fit
 (such a batch closed full: it is as full as its transactions allow), and
 `verify_fail_elems`, the lanes of the transactions that failed whole.
 
+When the chip had nothing of this stage's to run, and whose time that
+was, is stamped too (`_phase_end`, with the clock reads it makes
+anyway): `chip_empty_ns` / `chip_empty_n` run from the loop's first
+sight of a finished batch with no other in flight to the end of the
+next dispatch's launch; of that, `chip_empty_away_ns` is the time the
+thread spent outside this stage's run_once (the other stages on its
+thread) and `chip_empty_call_ns` the time inside this stage's own
+blocking phases; the rest is the stage in its own loop, filling the
+next batch.  What the stage cannot see is how late the ready flag
+turns: the device's own idle share is that much larger.
+
 More than one chip behind one intake (`devices=n`): the stage builds a
 one-axis mesh over the first n local devices, the native intake seals
 slots of the whole fixed shape (n x batch // n lanes), and the dispatch
@@ -221,6 +232,9 @@ _NATIVE_FRAME_MTU = 1232 + 2048 + 2
 (PH_OPEN, PH_SEALED_WAIT, PH_H2D, PH_LAUNCH, PH_INFLIGHT, PH_REAP,
  PH_PUBLISH) = range(len(fm.BATCH_PHASES))
 _PHASE_COUNTERS = tuple(f"batch_{p}_ns" for p in fm.BATCH_PHASES)
+# the phases the stage's thread spends getting the chip its next batch:
+# the blocking calls, and a sealed batch's wait for the thread
+_CHIP_CALL_PHASES = fm.BATCH_BLOCKING_PHASES | {PH_SEALED_WAIT}
 
 # what closed a batch (the ids are the binding's, held to
 # native/fd_verify.cpp by fdlint FD305): it filled; its deadline passed
@@ -419,6 +433,16 @@ class VerifyStage(Stage):
             self.metrics.counters[name] = 0
         self.metrics.counters["batch_stalls"] = 0
         self.metrics.counters["mesh_devices"] = self.mesh_devices
+        for name in fm.CHIP_EMPTY_COUNTERS:
+            self.metrics.counters[name] = 0
+        # the open interval in which the chip has nothing of this
+        # stage's to run (_phase_end): when the loop first saw the chip
+        # done (0 = the chip has work); up to when the interval's time
+        # is charged to a call of this stage's; and the two sums so far
+        self._chip_empty_since = 0
+        self._chip_mark = 0
+        self._chip_call_ns = 0
+        self._chip_away_ns = 0
         # sweep-granularity parser (drain-table path), built on first use
         self._burst_parser = None
         # -- native sweep client (ISSUE 13) -----------------------------------
@@ -570,6 +594,21 @@ class VerifyStage(Stage):
             .counter(fm.VERIFY_FAIL_ELEMS,
                      "signature elements of the txns counted in"
                      " verify_fail")
+            # when the chip had nothing of this stage's to run, and
+            # whose time that was (added as each interval ends)
+            .counter("chip_empty_ns",
+                     "the loop first saw a batch done with no other in"
+                     " flight -> the next dispatch's launch returned")
+            .counter("chip_empty_n", "such intervals ended")
+            .counter("chip_empty_call_ns",
+                     "of chip_empty_ns, inside this stage's blocking"
+                     " phases (reap and publish of the batch that left;"
+                     " sealed_wait, h2d and launch of the one that ends"
+                     " it), each charged its part in the call that ends"
+                     " it")
+            .counter("chip_empty_away_ns",
+                     "of chip_empty_ns, the thread was outside this"
+                     " stage's run_once: the other stages' time")
             .histogram(
                 "batch_fill",
                 fm.exp_buckets(1, 4096, 13),
@@ -764,6 +803,12 @@ class VerifyStage(Stage):
         # backpressure.  The clock is only read when a batch newly
         # opened — idle spins stay syscall-free.  (clear() resets
         # opened_at, so a stale stamp can never survive a close.)
+        since = self._chip_empty_since
+        if since and since < self._loop_entry_ns:
+            # this call began with the chip empty: the thread was in
+            # the other stages since the last one ended (run_once's two
+            # stamps; no clock read here)
+            self._chip_away_ns += self._loop_entry_ns - self._loop_exit_ns
         if self._sweep_client is not None:
             # native lane: the C side stamps a batch as it opens, in
             # the crossing (one clock read a batch; open_since_ns)
@@ -977,7 +1022,25 @@ class VerifyStage(Stage):
         The ONE place a batch is stamped — the native lane and the
         Python lane both come through here, so they cannot stamp
         differently.  A thread-blocking phase of BATCH_STALL_NS or more
-        is a flight event that names the phase."""
+        is a flight event that names the phase.
+
+        The chip's ledger is kept here too, with the same `now`: the
+        chip has nothing of this stage's to run from the PH_INFLIGHT
+        end of a batch with no other in flight (the loop first saw the
+        chip done) to the PH_LAUNCH end of the next dispatch.  When
+        that interval ends `chip_empty_ns` takes its length,
+        `chip_empty_away_ns` the part the thread spent outside this
+        stage's run_once (before_credit sums it from run_once's
+        stamps), and `chip_empty_call_ns` the part inside the phases of
+        _CHIP_CALL_PHASES that ended in it — each from where it began,
+        or where the last such phase ended, or where this call began,
+        whichever is latest, so that no instant is charged twice: a
+        phase that spans calls (a publish waiting on credits, a sealed
+        batch waiting for the thread) is charged its part in the call
+        that ends it.  The rest of the interval is the stage in its
+        own loop with no blocking call: intake, polls, the close rule.
+        Not under the all-pass mask, which has no chip (like _span)."""
+        self._loop_worked = True    # a batch moved: run_once's regime
         if life is None:  # a subclass's own pending (parallel/serve)
             return
         if now is None:
@@ -992,6 +1055,21 @@ class VerifyStage(Stage):
         if ns >= fm.BATCH_STALL_NS and phase in fm.BATCH_BLOCKING_PHASES:
             c["batch_stalls"] += 1
             self.trace(fm.EV_BATCH_STALL, fm.batch_stall_arg(phase, ns))
+        since = self._chip_empty_since
+        if phase == PH_INFLIGHT:
+            if len(self._flying()) == 1 and not self.precomputed_ok:
+                self._chip_empty_since = self._chip_mark = now
+        elif since and phase in _CHIP_CALL_PHASES:
+            self._chip_call_ns += now - max(t[phase], self._chip_mark,
+                                            self._loop_entry_ns)
+            self._chip_mark = now
+            if phase == PH_LAUNCH:
+                c["chip_empty_ns"] += now - since
+                c["chip_empty_n"] += 1
+                c["chip_empty_call_ns"] += self._chip_call_ns
+                c["chip_empty_away_ns"] += self._chip_away_ns
+                self._chip_empty_since = 0
+                self._chip_call_ns = self._chip_away_ns = 0
 
     def _dispatch_begins(self, life: _Life | None) -> None:
         """The sealed batch's wait is over: it takes its place in
@@ -1107,10 +1185,12 @@ class VerifyStage(Stage):
                      opened_ns: int, sealed_ns: int, close: int) -> None:
         c = self._sweep_client
         views = c.slots[slot]
-        # per-txn msg lengths for the autotuner: one vectorized observe
-        # off the ln column at the txns' first elements
-        starts = views.ranges[:n_txn, 0].astype(np.int64)
-        self.metrics.observe_batch("msg_len", views.ln[starts])
+        if self.autotune_after:
+            # per-txn msg lengths, the autotuner's evidence and nobody
+            # else's: one vectorized observe off the ln column at the
+            # txns' first elements, only where the tuner is armed
+            starts = views.ranges[:n_txn, 0].astype(np.int64)
+            self.metrics.observe_batch("msg_len", views.ln[starts])
         life = _Life(opened_ns)
         self._phase_end(life, PH_OPEN, sealed_ns)
         self._dispatch_begins(life)

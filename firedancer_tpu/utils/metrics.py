@@ -501,6 +501,68 @@ BATCH_HELD_BACKLOGGED = "batch_held_backlogged"
 # transactions that failed whole
 BATCH_FIT_PAD_LANES = "batch_fit_pad_lanes"
 VERIFY_FAIL_ELEMS = "verify_fail_elems"
+# The thread's ledger (runtime/stage.py run_once): every call of a
+# stage's loop is charged whole to one regime — it did work, it found
+# nothing to do, or (taken out of the call it ran in) housekeeping.
+# ns and, for the first two, calls
+LOOP_COUNTERS = ("loop_work_ns", "loop_work_n", "loop_poll_ns",
+                 "loop_poll_n", "loop_hk_ns")
+# When the chip had nothing of a verify stage's to run (runtime/verify.py
+# _phase_end): from the loop's first sight of a finished batch with no
+# other in flight to the end of the next dispatch's launch, and of that
+# the stage's own blocking calls and the time the thread was in other
+# stages.  Added when an interval ends; the rest of an interval is the
+# stage waiting inside its own loop (intake, polls, the close rule)
+CHIP_EMPTY_COUNTERS = ("chip_empty_ns", "chip_empty_n",
+                       "chip_empty_call_ns", "chip_empty_away_ns")
+
+
+def loop_busy_pct(work_ns: int, poll_ns: int, hk_ns: int) -> float | None:
+    """Share of a stage's loop time that did work, in %, from deltas of
+    its three regime counters; None where the ledger saw no time."""
+    total = work_ns + poll_ns + hk_ns
+    return 100.0 * work_ns / total if total > 0 else None
+
+
+def loop_row(regs: list) -> dict | None:
+    """{"work_ns", "poll_ns", "hk_ns"} summed over the shard registries
+    of one logical stage (the monitor's busy% is the share of the first
+    in a sample's delta of the three); None where no registry has the
+    ledger."""
+    regs = [r for r in regs if r is not None and "loop_work_ns" in r._off]
+    if not regs:
+        return None
+    return {k: sum(r.get(f"loop_{k}") for r in regs)
+            for k in ("work_ns", "poll_ns", "hk_ns")}
+
+
+def chip_empty_row(src) -> dict | None:
+    """{"ns", "n", "call_ns", "away_ns"} of a verify stage's
+    CHIP_EMPTY_COUNTERS, from its registry (the monitor) or a dict of
+    its metrics (slotreport); None where the stage has none."""
+    if src is None:
+        return None
+    if isinstance(src, MetricsRegistry):
+        src = {n: src.get(n) for n in CHIP_EMPTY_COUNTERS if n in src._off}
+    if CHIP_EMPTY_COUNTERS[0] not in src:
+        return None
+    return {n[len("chip_empty_"):]: int(src.get(n) or 0)
+            for n in CHIP_EMPTY_COUNTERS}
+
+
+def format_chip_empty(row: dict, prev: dict | None, dt_s: float) -> str:
+    """'chip_empty=71.2% (away=33% call=29%)': the share of the `dt_s`
+    between two samples' chip_empty_row in which the chip had nothing
+    of the stage's to run, and of that the shares the thread spent in
+    other stages and in the stage's own blocking calls; '-' where there
+    is no earlier sample, or no interval ended since."""
+    if not prev or dt_s <= 0:
+        return "chip_empty=- (away=- call=-)"
+    ns, away, call = (row[k] - prev[k] for k in ("ns", "away_ns", "call_ns"))
+    if ns <= 0:
+        return "chip_empty=0.0% (away=- call=-)"
+    return (f"chip_empty={100.0 * ns / (dt_s * 1e9):.1f}%"
+            f" (away={100.0 * away / ns:.0f}% call={100.0 * call / ns:.0f}%)")
 
 
 def batch_close_row(regs: list) -> dict | None:
@@ -829,6 +891,20 @@ def stage_schema() -> MetricsSchema:
                  "sum of tsorig->consume ns over the frags observed into"
                  " frag_latency_ns (the histogram's sum, as a counter)")
         .counter("frag_wait_n", "frags in frag_wait_ns")
+        # the thread's ledger: where this stage's share of its thread
+        # went (run_once stamps each call whole into one regime)
+        .counter("loop_work_ns",
+                 "ns inside run_once calls that did work: consumed or"
+                 " published a frag, or a hook dispatched, reaped or"
+                 " published a device batch, ticked, closed a slot")
+        .counter("loop_work_n", "run_once calls that did work")
+        .counter("loop_poll_ns",
+                 "ns inside run_once calls that found nothing to do (a"
+                 " credit-gated return counts here)")
+        .counter("loop_poll_n", "run_once calls that found nothing to do")
+        .counter("loop_hk_ns",
+                 "ns inside housekeeping passes, taken out of the call"
+                 " they ran in")
         .histogram(
             "frag_latency_ns",
             exp_buckets(1e3, 1e10, 24),
