@@ -7,13 +7,28 @@ schoolbook convolution of 13-bit limbs stays below 2^31 (20 * (2^13)^2 =
 reference's radix-2^43x6 AVX-512 IFMA representation plays on x86
 (/root/reference/src/ballet/ed25519/avx512/fd_r43x6.h) and its radix-2^25.5
 portable representation (/root/reference/src/ballet/ed25519/ref/) — but the
-*lane* dimension here is the batch: every op below is elementwise in a
-trailing batch axis, so one field op is a handful of (B,)-wide VPU
+*lane* dimension here is the batch: every op below is elementwise in the
+trailing batch axes, so one field op is a handful of batch-wide VPU
 instructions regardless of batch size.
 
-Layout: an fe is an int32 array of shape (20, ...batch) — limbs leading so
-that the batch occupies the TPU lane/sublane dimensions and limb indexing is
-cheap row slicing.
+Layout: an fe is an int32 array of shape (20, ...batch) — limbs leading,
+any number of batch axes behind (every pad, reshape and broadcast below is
+written for `x.ndim - 1` of them).  The TPU tiles an array's two minor
+dimensions (8 sublanes x 128 lanes a vreg), so what the layout costs is the
+caller's choice of batch shape:
+
+  - batch (R, 128), an fe (20, R, 128) — what the sigverify programs use
+    (ops/sigverify.fold_batch, whenever the batch is a multiple of 128):
+    the batch occupies BOTH tiled dimensions and the limb axis is untiled.
+    Limb indexing, the shifted accumulates of the convolution and the
+    carry shift are whole-register moves (at R = 8 a limb is exactly one
+    vreg, an fe 20, a convolution accumulator 41);
+  - batch (B,), an fe (20, B): the limb axis IS the sublane axis.  An fe
+    is 24 sublane rows for 20 limbs, limb i sits on sublane i % 8, and
+    every `a[i][None] * b`, every pad to row i of the accumulator and
+    every carry shift is a sublane shuffle.  Correct at any B (the small
+    batches of tests and tools, and the other curves' callers); ~2.5x the
+    vreg ops of the folded form by count.
 
 Invariants ("loose" form, maintained by every public op):
     limbs[1:] in [0, 2^13],  limbs[0] in [0, 2^14]
@@ -86,7 +101,10 @@ def _shift_rows(hi: jnp.ndarray, head: jnp.ndarray) -> jnp.ndarray:
     Written as a concatenate (pure data movement XLA folds into the
     surrounding elementwise DAG) rather than `.at[1:].add`: scatter-add
     lowers to a real scatter op on TPU and measured ~7x slower than an
-    entire fe_mul (scripts/perf_probe.py, round 4).
+    entire fe_mul (scripts/perf_probe.py, round 4).  Axis 0 is untiled
+    when the batch has two axes (the module docstring's folded layout):
+    the shift then renames registers; with a one-axis batch it is a
+    one-row sublane shift of every vreg of the operand.
     """
     return jnp.concatenate([head[None], hi[:-1]], axis=0)
 
@@ -119,7 +137,7 @@ def fe_neg(a: jnp.ndarray) -> jnp.ndarray:
 
 
 def _conv_fold(c: jnp.ndarray) -> jnp.ndarray:
-    """Reduce a (41, B) convolution accumulator to 20 loose limbs mod p.
+    """Reduce a (41, ...batch) convolution accumulator to 20 loose limbs mod p.
 
     Input terms are < 1.6e9 (see fe_mul bounds).  Three parallel carry passes
     bring every limb to ~2^13 (limb 40 only ever holds carry spill, < 2^5),
@@ -136,7 +154,8 @@ def _conv_fold(c: jnp.ndarray) -> jnp.ndarray:
 
 
 def _conv(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """(20,B) x (20,B) -> (41,B) schoolbook convolution via shifted adds."""
+    """(20, ...batch) x (20, ...batch) -> (41, ...batch) schoolbook
+    convolution via shifted adds."""
     pad = [(0, 0)] * (a.ndim - 1)
     acc = None
     for i in range(NLIMB):

@@ -36,6 +36,41 @@ from . import scalar as fs
 from . import sha512 as fsha
 
 
+# -- the layout of the batch inside a program ---------------------------------
+#
+# The TPU tiles an array's two minor dimensions (8 sublanes x 128 lanes a
+# vreg), and the library under this file is written for any batch rank
+# (ops/limbs.py's docstring has what each layout costs).  So a program
+# folds its batch onto BOTH tiled axes, (..., B) -> (..., B // 128, 128):
+# the limb, word, byte, bit and table-entry axes lead, untiled, and at the
+# tile batch of 1,024 a limb is exactly one (8, 128) vreg, a field element
+# 20 and a convolution accumulator 41.  One reshape at a program's entry,
+# one of the mask at its exit.  On a v5e the folded program takes 0.78x the
+# time of the one-axis one (/PERF.md section 6, PR 38).
+
+FOLD_LANES = 128
+
+
+def fold_lanes(batch: int) -> int:
+    """128 when a program over `batch` lanes folds them to
+    (batch // 128, 128), 0 when it runs them on their one trailing axis:
+    the rule reads the input's shape and nothing else (the verify
+    stage's gauge kernel_fold_lanes is this number)."""
+    return FOLD_LANES if batch > 0 and batch % FOLD_LANES == 0 else 0
+
+
+def fold_batch(*arrays: jnp.ndarray):
+    """Every (..., B) array -> (..., B // 128, 128) where
+    `fold_lanes(B)`, else as it is.  The ONE place a program decides its
+    batch layout: each entry below folds what it was given and reshapes
+    its mask back to (B,), so the lanes cannot diverge."""
+    if not fold_lanes(arrays[0].shape[-1]):
+        return arrays
+    return tuple(
+        x.reshape(x.shape[:-1] + (x.shape[-1] // FOLD_LANES, FOLD_LANES))
+        for x in arrays)
+
+
 def _verify_ok(
     msg: jnp.ndarray,
     msg_len: jnp.ndarray,
@@ -47,7 +82,8 @@ def _verify_ok(
     """The verify ladder core (traced, unjitted): validate + sha512 +
     double-scalar-mult + compare.  ONE implementation — every kernel in
     the ladder (baseline, fused, the serving-plane step) traces exactly
-    this, so their masks cannot diverge by construction."""
+    this, so their masks cannot diverge by construction.  Lane-wise over
+    whatever batch axes trail (msg_len's shape); -> bool of that shape."""
     msg = msg.astype(jnp.int32)
     sig = sig.astype(jnp.int32)
     pubkey = pubkey.astype(jnp.int32)
@@ -90,7 +126,14 @@ def ed25519_verify_batch(
     pubkey:  (32, B) byte rows
     Returns (B,) bool.
     """
-    return _verify_ok(msg, msg_len, sig, pubkey, max_msg_len=max_msg_len)
+    return _verify_lanes(msg, msg_len, sig, pubkey, max_msg_len=max_msg_len)
+
+
+def _verify_lanes(msg, msg_len, sig, pubkey, *, max_msg_len: int):
+    """_verify_ok over the (..., B) byte-row arrays on the folded batch
+    (fold_batch) -> the (B,) bool mask."""
+    return _verify_ok(*fold_batch(msg, msg_len, sig, pubkey),
+                      max_msg_len=max_msg_len).reshape(msg_len.shape)
 
 
 # -- the stage's program: packed rows in, the mask out -------------------------
@@ -136,8 +179,8 @@ def ed25519_verify_batch_fused(rows: jnp.ndarray, *,
     per batch, one array in and one back: the (B,) bool mask.  Pad rows
     come back as whatever _verify_ok says of them (zeros, or an earlier
     batch's bytes); the stage's reap reads the real lanes only."""
-    return _verify_ok(*unpack_rows(rows, max_msg_len=max_msg_len),
-                      max_msg_len=max_msg_len)
+    return _verify_lanes(*unpack_rows(rows, max_msg_len=max_msg_len),
+                         max_msg_len=max_msg_len)
 
 
 # -- repeated-signer fast path ------------------------------------------------
@@ -164,8 +207,13 @@ def ed25519_verify_batch_cached(
 
     The pubkey byte rows are still required (k = SHA512(R||A||msg)); A's
     point validity/small-order checks happened at bank-fill time
-    (comb_fill), so invalid pubkeys never enter the bank.
+    (comb_fill), so invalid pubkeys never enter the bank.  The batch is
+    folded like every other lane's (fold_batch): the bank gather takes
+    the two-axis `slots` as it is.
     """
+    batch = msg_len.shape
+    msg, msg_len, sig, pubkey, slots = fold_batch(
+        msg, msg_len, sig, pubkey, slots)
     msg = msg.astype(jnp.int32)
     sig = sig.astype(jnp.int32)
     pubkey = pubkey.astype(jnp.int32)
@@ -183,7 +231,7 @@ def ed25519_verify_batch_cached(
     k_bits = fs.sc_bits(k)
     s_bits = fs.sc_bits(fs.sc_frombytes(s_enc))
     r_cmp = fc.double_scalar_mul_comb(k_bits, s_bits, bank, slots)
-    return ok_s & ok_r & fc.point_eq_z1(r_cmp, r_pt)
+    return (ok_s & ok_r & fc.point_eq_z1(r_cmp, r_pt)).reshape(batch)
 
 
 @jax.jit
@@ -222,11 +270,15 @@ def bank_alloc(n_slots: int):
 # The same computation as four separately jitted programs: an A/B
 # reference that shows what XLA's fusion buys (each phase boundary is an
 # HBM round trip the fused program does not pay).  Same inputs, same
-# mask; nothing dispatches to it by default or as a fallback.
+# mask; nothing dispatches to it by default or as a fallback.  Each
+# phase folds the byte rows it is given (fold_batch); points, bits and
+# the running mask cross the phase boundaries folded, and the last
+# phase hands back the (B,) mask.
 
 
 @jax.jit
 def _phase_validate(sig, pubkey):
+    sig, pubkey = fold_batch(sig, pubkey)
     sig = sig.astype(jnp.int32)
     pubkey = pubkey.astype(jnp.int32)
     r_enc = sig[:32]
@@ -240,6 +292,7 @@ def _phase_validate(sig, pubkey):
 
 @functools.partial(jax.jit, static_argnames=("max_msg_len",))
 def _phase_hash(msg, msg_len, sig, pubkey, *, max_msg_len):
+    msg, msg_len, sig, pubkey = fold_batch(msg, msg_len, sig, pubkey)
     msg = msg.astype(jnp.int32)
     sig = sig.astype(jnp.int32)
     pubkey = pubkey.astype(jnp.int32)
@@ -250,13 +303,14 @@ def _phase_hash(msg, msg_len, sig, pubkey, *, max_msg_len):
 
 @jax.jit
 def _phase_dsm(k_bits, a_pt, sig):
+    (sig,) = fold_batch(sig)
     s_bits = fs.sc_bits(fs.sc_frombytes(sig[32:].astype(jnp.int32)))
     return fc.double_scalar_mul_base(k_bits, fc.point_neg(a_pt), s_bits)
 
 
 @jax.jit
 def _phase_compare(r_cmp, r_pt, ok):
-    return ok & fc.point_eq_z1(r_cmp, r_pt)
+    return (ok & fc.point_eq_z1(r_cmp, r_pt)).reshape(-1)
 
 
 def ed25519_verify_batch_split(msg, msg_len, sig, pubkey, *, max_msg_len):

@@ -622,6 +622,11 @@ class ShardedVerifyStage(VerifyStage):
         self._shards = [_Acc() for _ in range(self.n_shards)]
         self._use_shard_schema(self.n_shards)
         self.metrics.counters["mesh_devices"] = self.n_shards
+        # the plane's step sees all the shards' lanes as one batch
+        from firedancer_tpu.ops.sigverify import fold_lanes
+
+        self.metrics.counters[fmet.KERNEL_FOLD_LANES] = fold_lanes(
+            self.batch * self.n_shards)
 
     # -- observability ------------------------------------------------------
 

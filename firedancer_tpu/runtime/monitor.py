@@ -424,7 +424,8 @@ class MonitorSession:
         # in front kept open past their deadline, its stalls, and
         # the lanes nobody used: left empty because the next
         # transaction did not fit, spent on transactions that failed
-        # whole (cumulative); then, between the two samples, the share
+        # whole (cumulative), how the program lays its batch (128: on
+        # both tiled axes); then, between the two samples, the share
         # of the time the chip had nothing of the stage's to run, and
         # of that the thread's time in other stages and in the stage's
         # own blocking calls
@@ -439,6 +440,7 @@ class MonitorSession:
                     + f"  batch_stalls={bc['stalls']:,}"
                     + f"  fit_pad_lanes={bc['fit_pad_lanes']:,}"
                     + f"  verify_fail_elems={bc['fail_elems']:,}"
+                    + f"  kernel_fold_lanes={bc['fold_lanes']}"
                     + ("  " + fm.format_chip_empty(
                         r["chip_empty"],
                         (prev_by.get(r["stage"]) or {}).get("chip_empty"),

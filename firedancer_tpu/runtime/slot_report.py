@@ -136,9 +136,11 @@ def _stage_block(mets: dict, records: list) -> dict:
         # how many of them were dispatched behind a running one, how
         # many a backlog in front kept open past their deadline, the
         # lanes they left empty because the next transaction did not
-        # fit, and the lanes of the transactions that failed whole
+        # fit, the lanes of the transactions that failed whole, and
+        # how the program lays its batch (the gauge)
         for name in (fm.BATCH_QUEUED_BEHIND, fm.BATCH_HELD_BACKLOGGED,
-                     fm.BATCH_FIT_PAD_LANES, fm.VERIFY_FAIL_ELEMS):
+                     fm.BATCH_FIT_PAD_LANES, fm.VERIFY_FAIL_ELEMS,
+                     fm.KERNEL_FOLD_LANES):
             block[name] = int(mets.get(name, 0) or 0)
     # when the chip had nothing of the verify stage's to run (ns and
     # intervals, cumulative), and of that the shares the thread spent

@@ -357,6 +357,8 @@ class VerifyStage(Stage):
         # module a step over the mesh.  The packed rows are sharded by
         # row, P(axis, None), over the serving plane's mesh axis
         # (parallel/mesh.AXIS); the mask comes back P(axis).
+        from firedancer_tpu.ops.sigverify import FOLD_LANES, fold_lanes
+
         self._row_sharding = None
         self.mesh_devices = 1
         if devices is not None and devices != 1:
@@ -368,6 +370,13 @@ class VerifyStage(Stage):
                 raise ValueError(
                     f"verify batch {batch} does not divide over"
                     f" {devices} devices")
+            if fold_lanes(batch) and batch % (FOLD_LANES * devices):
+                # the program sees the global shape and folds it to
+                # (batch // 128, 128) rows: they have to divide too
+                raise ValueError(
+                    f"verify batch {batch} is folded by the program to"
+                    f" {batch // FOLD_LANES} rows of {FOLD_LANES} lanes,"
+                    f" which do not divide over {devices} devices")
             from jax.sharding import NamedSharding, PartitionSpec
 
             from firedancer_tpu.parallel import mesh as pm
@@ -433,6 +442,9 @@ class VerifyStage(Stage):
             self.metrics.counters[name] = 0
         self.metrics.counters["batch_stalls"] = 0
         self.metrics.counters["mesh_devices"] = self.mesh_devices
+        # the layout of the batch inside the program this stage
+        # dispatches, from the program's own predicate on the shape
+        self.metrics.counters[fm.KERNEL_FOLD_LANES] = fold_lanes(batch)
         for name in fm.CHIP_EMPTY_COUNTERS:
             self.metrics.counters[name] = 0
         # the open interval in which the chip has nothing of this
@@ -533,6 +545,11 @@ class VerifyStage(Stage):
                    "chips behind this stage: 1 = the default device, n > 1"
                    " = a mesh whose chip i takes elements i, i + n, ... of"
                    " every batch (shard_elems_s{i} counts them)")
+            .gauge(fm.KERNEL_FOLD_LANES,
+                   "128 when the program this stage dispatches folds its"
+                   " batch onto both tiled axes, (batch // 128, 128): a"
+                   " limb is whole vregs (ops/sigverify.fold_batch; the"
+                   " batch is a multiple of 128); 0 = one trailing axis")
             # the life of a batch, summed over batches as each phase
             # ends (ns; divide a window's delta by its delta of batches)
             .counter("batch_open_ns",

@@ -501,6 +501,9 @@ BATCH_HELD_BACKLOGGED = "batch_held_backlogged"
 # transactions that failed whole
 BATCH_FIT_PAD_LANES = "batch_fit_pad_lanes"
 VERIFY_FAIL_ELEMS = "verify_fail_elems"
+# gauge: 128 where the program the stage dispatches folds its batch to
+# (batch // 128, 128) (ops/sigverify.fold_batch), 0 where it does not
+KERNEL_FOLD_LANES = "kernel_fold_lanes"
 # The thread's ledger (runtime/stage.py run_once): every call of a
 # stage's loop is charged whole to one regime — it did work, it found
 # nothing to do, or (taken out of the call it ran in) housekeeping.
@@ -570,8 +573,10 @@ def batch_close_row(regs: list) -> dict | None:
     batch_queued_behind, "held_backlogged": batch_held_backlogged,
     "fit_pad_lanes": batch_fit_pad_lanes,
     "fail_elems": verify_fail_elems, "stalls": batch_stalls} summed
-    over the shard registries of one logical stage, for the monitor and
-    slotreport; None where the stage is not a verify stage."""
+    over the shard registries of one logical stage, and "fold_lanes":
+    the gauge kernel_fold_lanes (the same on every shard), for the
+    monitor and slotreport; None where the stage is not a verify
+    stage."""
     regs = [r for r in regs
             if r is not None and BATCH_CLOSE_COUNTERS[0] in r._off]
     if not regs:
@@ -582,7 +587,9 @@ def batch_close_row(regs: list) -> dict | None:
                                         BATCH_HELD_BACKLOGGED,
                                         BATCH_FIT_PAD_LANES,
                                         VERIFY_FAIL_ELEMS, "batch_stalls"))
-    return {k: sum(r.get(n) for r in regs) for k, n in names}
+    row = {k: sum(r.get(n) for r in regs) for k, n in names}
+    row["fold_lanes"] = max(r.get(KERNEL_FOLD_LANES) for r in regs)
+    return row
 
 
 # What a dedup stage's tag cache dropped (counter -> the key the monitor

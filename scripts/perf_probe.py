@@ -1,22 +1,27 @@
-"""Probe WHY composed point ops run ~5x slower than raw fe_mul chains.
+"""Probe what a field multiply and a point doubling cost on the chip, as
+chains in a fori_loop, on either batch layout.
 
-An earlier field-op microbenchmark measured (TPU v5e, batch 16384):
-    jnp13 (one fe_mul chained)   0.024 ms/iter
-    pdbl13 (point_dbl chained)   0.757 ms/iter  (~6.4 fe_mul-equiv of work)
-The gap means the kernel's cost is NOT the multiply count.  Decompose:
+Steps (one per iteration, the state fed back):
 
-  mulchain   — one fe_mul/iter (re-measure with wide k spread)
-  mul4       — 4 independent fe_mul per iter (state of 4 fe's: does a
-               bigger loop state alone cause the slowdown?)
-  sqr4       — 4 fe_sqr per iter
-  addchain   — one fe_add (carry2) per iter: carry-pass cost
+  mulchain   — one fe_mul
+  mul4       — 4 independent fe_mul (a state of 4 fe's)
+  sqr4       — 4 fe_sqr
+  addchain   — one fe_add (carry2): the carry-pass cost
   dblnoc     — point_dbl with NO carry passes on add/sub (raw +/-, bounds
                be damned — timing only)
   dblprod    — the 4 sqr + 4 mul of point_dbl with the adds replaced by
                constants (isolates the mul DAG shape)
   dbl        — production point_dbl
 
+--fold lays the chain state as (20, B // 128, 128) — the batch on both
+tiled axes, what the sigverify programs run (ops/sigverify.fold_batch) —
+instead of (20, B).  ms/iter is (t(k2) - t(k1)) / (k2 - k1) over the best of
+three calls each: at --batch 1024 an iteration is microseconds, so ask for
+--k1 512 --k2 4096 there.  /PERF.md section 6 (PR 38) has both layouts'
+columns at 1,024 and 16,384 as read on a v5e.
+
 Usage: python scripts/perf_probe.py [--batch 16384] [--k1 64] [--k2 256]
+                                    [--fold] [--only mulchain,dbl]
 """
 from __future__ import annotations
 
@@ -32,6 +37,7 @@ import numpy as np
 
 from firedancer_tpu.ops import limbs as fl
 from firedancer_tpu.ops import curve as fc
+from firedancer_tpu.ops import sigverify as sv
 
 
 def bench_step(name, step, state, k1, k2):
@@ -118,16 +124,22 @@ def main():
     ap.add_argument("--k1", type=int, default=64)
     ap.add_argument("--k2", type=int, default=256)
     ap.add_argument("--only", type=str, default="")
+    ap.add_argument("--fold", action="store_true",
+                    help="chain state (20, B // 128, 128): the batch on both"
+                         " tiled axes, as ops/sigverify.fold_batch lays it")
     args = ap.parse_args()
     B = args.batch
     only = set(args.only.split(",")) if args.only else None
-    print("backend:", jax.default_backend(), jax.devices(), "batch", B)
     rng = np.random.default_rng(11)
 
     def mk():
-        return jnp.asarray(rng.integers(0, 1 << 13, (fl.NLIMB, B)), jnp.int32)
+        x = jnp.asarray(rng.integers(0, 1 << 13, (fl.NLIMB, B)), jnp.int32)
+        return sv.fold_batch(x)[0] if args.fold else x
+
 
     x, y = mk(), mk()
+    print("backend:", jax.default_backend(), jax.devices(), "batch", B,
+          "state", x.shape)
     p4 = (mk(), mk(), mk(), mk())
 
     todo = [

@@ -1179,7 +1179,8 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
         row = fm.batch_close_row([reg])
         assert row == {"full": 1, "deadline": 2, "window": 0,
                        "queued_behind": 1, "held_backlogged": 0,
-                       "fit_pad_lanes": 0, "fail_elems": 0, "stalls": 0}
+                       "fit_pad_lanes": 0, "fail_elems": 0, "stalls": 0,
+                       "fold_lanes": 0}     # a batch of 16: one axis
         assert sum(row[c] for c in fm.BATCH_CLOSES) \
             == st.metrics.get("batches")
         text = fm.render_prometheus({"v0": reg})
@@ -1198,6 +1199,10 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
         block = slot_report.build_report(dump)["stages"]["v0"]
         assert block["batch_closes"] == {c: row[c] for c in fm.BATCH_CLOSES}
         assert block[QUEUED_BEHIND] == 1 and block[HELD_BACKLOGGED] == 0
+        # how the program lays its batch, in the same three places
+        assert f'{fm.KERNEL_FOLD_LANES}{{stage="v0"}} 0' in text
+        assert "  kernel_fold_lanes=0" in rendered
+        assert block[fm.KERNEL_FOLD_LANES] == 0
         # over a mesh: how many chips and the useful lanes of each, in
         # the same three places; with one device, in none
         if lane == "mesh":

@@ -11,6 +11,7 @@ from firedancer_tpu.ops import curve as fc
 from firedancer_tpu.ops import limbs as fl
 from firedancer_tpu.ops import scalar as fs
 from firedancer_tpu.ops.ref import ed25519_ref as ref
+from firedancer_tpu.ops.sigverify import fold_batch
 
 P = ref.P
 L = ref.L
@@ -217,3 +218,93 @@ def test_windowed_matches_ladder(rng):
     fast = points_from_jax(jax.jit(fc.double_scalar_mul_base)(kb, a, sb))
     slow = points_from_jax(jax.jit(fc.double_scalar_mul_base_ladder)(kb, a, sb))
     assert fast == slow  # affine (x, y) pairs
+
+
+# -- the folded batch (ISSUE 38) ----------------------------------------------
+#
+# ops/sigverify.fold_batch hands the ladder its batch as (..., B // 128,
+# 128).  The group and scalar ops it is made of, each on that layout
+# against itself on the one-axis batch, lane by lane.
+
+FOLD_B = 256
+
+
+def _fold(x):
+    return fold_batch(jnp.asarray(x))[0]
+
+
+def _loose_points(rng, k):
+    """k-tuples of (20, FOLD_B) loose limb arrays (limbs at and below
+    the invariant's maxima: what the ladder's state may hold), the
+    first and last lanes at the maxima."""
+    out = []
+    for _ in range(k):
+        x = rng.integers(0, (1 << fl.RADIX) + 1, (fl.NLIMB, FOLD_B))
+        x[0] = rng.integers(0, (1 << (fl.RADIX + 1)) + 1, FOLD_B)
+        x[1:, [0, -1]], x[0, [0, -1]] = 1 << fl.RADIX, 1 << (fl.RADIX + 1)
+        out.append(jnp.asarray(x.astype(np.int32)))
+    return tuple(out)
+
+
+def _table(rng):
+    return tuple(jnp.asarray(rng.integers(
+        0, 1 << fl.RADIX, (16, fl.NLIMB, FOLD_B)).astype(np.int32))
+        for _ in range(4))
+
+
+def _fold_case(name, rng):
+    """-> (jitted fn, flat args): the op under test and its inputs with
+    the batch as their last axis."""
+    if name == "point_dbl":
+        return j_dbl, (_loose_points(rng, 4),)
+    if name == "add_cached":
+        return jax.jit(fc.add_cached), (_loose_points(rng, 4),
+                                        _loose_points(rng, 4))
+    if name == "select16":
+        sel = np.arange(FOLD_B, dtype=np.int32) % 16
+        return jax.jit(fc._select16), (_table(rng), jnp.asarray(sel))
+    if name == "select16_comb_row":     # the base comb's constant rows
+        row = jnp.asarray(fc._comb_table()[3])          # (16, 4, NLIMB)
+        sel = jnp.asarray(rng.integers(0, 16, FOLD_B).astype(np.int32))
+
+        def comb(sel):
+            one = (1,) * (sel.ndim)
+            return fc._select16(tuple(
+                row[:, c, :].reshape((16, fl.NLIMB) + one)
+                for c in range(4)), sel)
+        return jax.jit(comb), (sel,)
+    if name == "windows":
+        bits = rng.integers(0, 2, (fc.NBITS, FOLD_B)).astype(np.int32)
+        bits[:, 0], bits[:, -1] = 1, 0
+        return jax.jit(fc._windows), (jnp.asarray(bits),)
+    if name == "sc_bits":
+        return jax.jit(fs.sc_bits), (_loose_points(rng, 1)[0] & fl.MASK,)
+    assert name == "sc_reduce512"
+    enc = rng.integers(0, 256, (64, FOLD_B)).astype(np.int32)
+    enc[:, 0], enc[:, -1] = 255, 0
+    return j_reduce, (jnp.asarray(enc),)
+
+
+@pytest.mark.parametrize("name", [
+    "point_dbl", "add_cached", "select16", "select16_comb_row", "windows",
+    "sc_bits", "sc_reduce512"])
+def test_folded_batch_equals_flat(name, rng):
+    fn, args = _fold_case(name, rng)
+    flat = jax.tree_util.tree_leaves(fn(*args))
+    fold = jax.tree_util.tree_leaves(
+        fn(*jax.tree_util.tree_map(_fold, args)))
+    assert len(flat) == len(fold) > 0
+    for a, b in zip(flat, fold):
+        a, b = np.asarray(a), np.asarray(b)
+        assert b.shape == a.shape[:-1] + (FOLD_B // 128, 128)
+        assert np.array_equal(b.reshape(a.shape), a)
+    if name == "select16":      # lane e picked entry e % 16
+        for got, t in zip(flat, args[0]):
+            t = np.asarray(t)
+            assert np.array_equal(
+                np.asarray(got), t[np.arange(FOLD_B) % 16, :,
+                                   np.arange(FOLD_B)].T)
+    if name == "windows":
+        w = np.asarray(flat[0])
+        assert w.shape == (fc.NWIN, FOLD_B) and w[:, 0].tolist() == \
+            [15] * 63 + [1] and not w[:, -1].any()

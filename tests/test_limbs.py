@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from firedancer_tpu.ops import limbs as fl
+from firedancer_tpu.ops.sigverify import fold_batch
 
 P = fl.P
 
@@ -123,3 +124,65 @@ def test_bytes_roundtrip(rng):
     # msb masking drops bit 255
     fe2 = j_frombytes(jnp.asarray(raw))
     assert from_fe(fe2) == [(v & ((1 << 255) - 1)) % P for v in vals]
+
+
+# -- the folded batch (ISSUE 38) ----------------------------------------------
+#
+# ops/sigverify.fold_batch lays a program's batch on both tiled axes,
+# (20, B // 128, 128): the field ops are written for any batch rank, and
+# here each is held, lane by lane, to itself on the one-axis batch.
+
+FOLD_B = 256
+
+
+def loose_extremes(rng, n=FOLD_B):
+    """(20, n) limb columns at the edges of the loose invariant
+    (limbs[1:] in [0, 2^13], limbs[0] in [0, 2^14]): every limb at its
+    maximum, zero, p's own limbs, 2p's reduced, alternating, the rest
+    random loose limbs."""
+    top = np.full(fl.NLIMB, 1 << fl.RADIX, np.int32)
+    top[0] = 1 << (fl.RADIX + 1)
+    alt = np.where(np.arange(fl.NLIMB) % 2, top, 0).astype(np.int32)
+    cols = [top, np.zeros(fl.NLIMB, np.int32), fl._P_LIMBS.astype(np.int32),
+            fl.int_to_limbs(2 * P - 1), alt, top - alt]
+    x = rng.integers(0, (1 << fl.RADIX) + 1, (fl.NLIMB, n)).astype(np.int32)
+    x[0] = rng.integers(0, (1 << (fl.RADIX + 1)) + 1, n)
+    for i, c in enumerate(cols):
+        x[:, i] = c
+        x[:, n - 1 - i] = c         # and in the last row of the fold
+    return x
+
+
+FOLD_OPS = {
+    "mul": (j_mul, 2), "sqr": (j_sqr, 1), "sub": (j_sub, 2),
+    "add": (j_add, 2), "neg": (j_neg, 1), "freeze": (j_freeze, 1),
+    "tobytes": (j_tobytes, 1), "parity": (j_parity, 1),
+}
+
+
+@pytest.mark.parametrize("op", sorted(FOLD_OPS))
+def test_folded_batch_equals_flat(op, rng):
+    fn, nargs = FOLD_OPS[op]
+    args = [loose_extremes(rng)]
+    if nargs == 2:      # the extremes against each other and themselves
+        args.append(np.roll(args[0], 3, axis=1))
+    flat = np.asarray(fn(*[jnp.asarray(a) for a in args]))
+    fold = np.asarray(fn(*[jnp.asarray(a) for a in fold_batch(*args)]))
+    assert fold.shape == flat.shape[:-1] + (FOLD_B // 128, 128)
+    assert np.array_equal(fold.reshape(flat.shape), flat)
+    if op in ("mul", "sqr", "sub", "add", "neg"):   # and to the integers
+        ints = [[fl.limbs_to_int(a[:, i]) for i in range(8)] for a in args]
+        want = {"mul": lambda a, b: a * b, "sqr": lambda a: a * a,
+                "sub": lambda a, b: a - b, "add": lambda a, b: a + b,
+                "neg": lambda a: -a}[op]
+        assert from_fe(flat[:, :8]) == [want(*v) % P for v in zip(*ints)]
+        assert flat.min() >= 0 and flat.max() <= 1 << (fl.RADIX + 1)
+
+
+def test_folded_frombytes_equals_flat(rng):
+    raw = rng.integers(0, 256, (32, FOLD_B)).astype(np.int32)
+    raw[:, 0], raw[:, 1], raw[31, 2] = 255, 0, 0x80
+    for fn in (j_frombytes, j_frombytes_raw):
+        flat = np.asarray(fn(jnp.asarray(raw)))
+        fold = np.asarray(fn(jnp.asarray(*fold_batch(raw))))
+        assert np.array_equal(fold.reshape(flat.shape), flat)

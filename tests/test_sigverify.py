@@ -17,9 +17,10 @@ pytestmark = pytest.mark.slow  # XLA-compile/socket-heavy tier (see conftest)
 MAX_MSG = 128
 
 
-def run_batch(cases):
-    """cases: list of (msg, sig, pubkey) byte strings -> np bool array."""
-    b = len(cases)
+def run_batch(cases, batch=None, program=sv.ed25519_verify_batch):
+    """cases: list of (msg, sig, pubkey) byte strings -> np bool array
+    (over `batch` lanes, the rows past the cases all zero)."""
+    b = batch or len(cases)
     msg = np.zeros((MAX_MSG, b), dtype=np.int32)
     ln = np.zeros(b, dtype=np.int32)
     sig = np.zeros((64, b), dtype=np.int32)
@@ -29,7 +30,7 @@ def run_batch(cases):
         ln[i] = len(m)
         sig[:, i] = np.frombuffer(s, dtype=np.uint8)
         pk[:, i] = np.frombuffer(p, dtype=np.uint8)
-    out = sv.ed25519_verify_batch(
+    out = program(
         jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig), jnp.asarray(pk),
         max_msg_len=MAX_MSG,
     )
@@ -146,3 +147,46 @@ def test_non_canonical_encodings_match_ref():
         rx, ry = rp[0], rp[1] % ref.P
         assert fl.limbs_to_int(xs[:, i]) % ref.P == rx
         assert fl.limbs_to_int(ys[:, i]) % ref.P == ry
+
+
+def test_folded_batch_equals_ref_and_the_flat_program(rng):
+    """At 128 lanes the program folds its batch to (1, 128)
+    (sv.fold_batch).  Lane by lane its mask is ops/ref's verdict and
+    the mask of the same ladder on the one-axis batch: honest
+    signatures of seeded lengths, a flipped bit in R, in S and in the
+    message, s >= L, a small-order A, a small-order R, a non-canonical
+    y as A and as R, and zero pad rows."""
+    cases = []
+    for i in range(6):
+        secret, pub = keypair(b"f%d" % i)
+        m = rng.bytes((int(rng.integers(1, MAX_MSG)), 0, MAX_MSG)[i % 3])
+        cases.append((m, ref.sign(secret, m), pub))
+    secret, pub = keypair(b"fold")
+    m = b"one vreg a limb"
+    s = ref.sign(secret, m)
+    for byte in (2, 40):                        # a bit of R, a bit of S
+        bad = bytearray(s)
+        bad[byte] ^= 0x10
+        cases.append((m, bytes(bad), pub))
+    cases.append((m[:-1] + b"B", s, pub))       # a bit of the message
+    high_s = int.from_bytes(s[32:], "little") + ref.L
+    cases.append((m, s[:32] + high_s.to_bytes(32, "little"), pub))
+    ident = int.to_bytes(1, 32, "little")
+    cases.append((m, s, ident))                 # small-order A
+    cases.append((m, ident + s[32:], pub))      # small-order R
+    noncanon = [int.to_bytes(y, 32, "little")
+                for y in range(ref.P, 1 << 255)
+                if ref.point_decompress(int.to_bytes(y, 32, "little"))]
+    assert noncanon
+    cases.append((m, s, noncanon[0]))           # y >= p as A
+    cases.append((m, noncanon[-1] + s[32:], pub))       # and as R
+    expect = [ref.verify(*c) for c in cases]
+    assert expect[:6] == [True] * 6 and not any(expect[6:])
+    assert sv.fold_lanes(128) and not sv.fold_lanes(len(cases))
+    got = run_batch(cases, batch=128)
+    assert got.shape == (128,) and got.dtype == np.bool_
+    assert got[:len(cases)].tolist() == expect
+    assert not got[len(cases):].any()           # a zero row never verifies
+    flat = run_batch(cases, batch=128, program=jax.jit(
+        sv._verify_ok, static_argnames=("max_msg_len",)))
+    assert (flat == got).all()

@@ -690,6 +690,75 @@ def test_the_programs_unpack_inverts_pack_rows(mml):
         assert (g[..., :b] == x).all() and not g[..., b:].any()
 
 
+# -- the layout of the batch inside the program (ISSUE 38) ---------------------
+
+
+@pytest.mark.parametrize("kernel", ["fused", "baseline"])
+@pytest.mark.parametrize("batch, lanes", [
+    (96, (96,)), (128, (1, 128)), (200, (200,)), (1024, (8, 128))])
+def test_the_program_folds_its_batch_by_the_shape_alone(
+        kernel, batch, lanes, toy_verify_ok, monkeypatch):
+    """A batch that is a multiple of 128 lanes is laid on both tiled
+    axes inside the program, (batch // 128, 128); any other keeps its
+    one axis.  The packed rows in and the (batch,) bool mask out are
+    the same either way, lane for lane, and the stage's gauge says
+    which program it dispatches."""
+    import jax
+
+    from firedancer_tpu.ops import sigverify as sv
+    from firedancer_tpu.runtime import verify_native as vn
+    from firedancer_tpu.utils import metrics as fm
+
+    mml = 64
+    seen = []
+    toy = sv._verify_ok
+
+    def spy(msg, msg_len, sig, pubkey, *, max_msg_len):
+        seen.append((msg.shape, msg_len.shape, sig.shape, pubkey.shape))
+        ok = toy(msg, msg_len, sig, pubkey, max_msg_len=max_msg_len)
+        assert ok.shape == msg_len.shape
+        return ok
+
+    monkeypatch.setattr(sv, "_verify_ok", spy)
+    folded = len(lanes) == 2
+    assert sv.fold_lanes(batch) == (128 if folded else 0) == \
+        (sv.FOLD_LANES if folded else 0)
+    rng = np.random.default_rng(batch)
+    rows = rng.integers(0, 256, (batch, vn.row_width(mml)), dtype=np.uint8)
+    ln = vn.row_lens(rows, mml)
+    ln[:] = rng.integers(0, mml + 1, (batch,))
+    tail = rows[:, mml:].astype(np.int64)
+    want = toy_verify_ok(ln, rows[:, 0], tail[:, 0], tail[:, 63],
+                         tail[:, 64], tail[:, 95])
+    dev = jax.device_put(rows)
+    for _ in range(2):          # the second call traces nothing
+        mask = np.asarray(sv.verify_dispatch(kernel, dev, max_msg_len=mml))
+        assert mask.dtype == np.bool_ and mask.shape == (batch,)
+        assert (mask == want).all() and want.any() and not want.all()
+    assert seen == [((mml,) + lanes, lanes, (64,) + lanes, (32,) + lanes)]
+    assert sv.kernel_compiled_entries(kernel) \
+        == sv.kernel_dispatch_count(kernel)
+    shape = jax.eval_shape(
+        lambda r: sv.ed25519_verify_batch_fused(r, max_msg_len=mml), dev)
+    assert (shape.shape, shape.dtype) == ((batch,), np.bool_)
+    st = VerifyStage("v", ins=[], outs=[], batch=batch, max_msg_len=mml,
+                     native_client=False)
+    assert st.metrics.get(fm.KERNEL_FOLD_LANES) == (128 if folded else 0)
+    assert fm.KERNEL_FOLD_LANES in VerifyStage.metrics_schema().names()
+
+
+def test_fold_batch_is_a_reshape_of_the_last_axis():
+    from firedancer_tpu.ops import sigverify as sv
+
+    x = np.arange(3 * 256).reshape(3, 256)
+    ln = np.arange(256)
+    fx, fl_ = sv.fold_batch(x, ln)
+    assert fx.shape == (3, 2, 128) and fl_.shape == (2, 128)
+    assert (fx.reshape(3, 256) == x).all() and fl_[1, 5] == 128 + 5
+    assert sv.fold_batch(x[:, :200], ln[:200])[0].shape == (3, 200)
+    assert [sv.fold_lanes(b) for b in (0, 64, 200, 4096)] == [0, 0, 0, 128]
+
+
 # -- differential lanes (compile-heavy: slow tier) ----------------------------
 
 
@@ -733,6 +802,18 @@ def _cases(rng):
     flip[2] ^= 4
     cases.append((m, bytes(flip), pub))
     expect.append(False)
+    # one flipped bit of the message
+    cases.append((m[:5] + bytes([m[5] ^ 0x20]) + m[6:], s, pub))
+    expect.append(False)
+    # a non-canonical y (>= p: accepted as an encoding, reduced mod p)
+    # in A's place and in R's
+    noncanon = next(e for e in (int.to_bytes(y, 32, "little")
+                                for y in range(ref.P, 1 << 255))
+                    if ref.point_decompress(e))
+    cases.append((m, s, noncanon))
+    expect.append(False)
+    cases.append((m, noncanon + s[32:], pub))
+    expect.append(False)
     # corrupted signatures of the empty and the max_msg_len message
     for msg_c, sig_c, pub_c in (cases[1], cases[2]):
         flip = bytearray(sig_c)
@@ -743,8 +824,8 @@ def _cases(rng):
     return cases, expect
 
 
-def _arrays(cases):
-    b = len(cases)
+def _arrays(cases, batch=None):
+    b = batch or len(cases)
     msg = np.zeros((MAX_MSG, b), dtype=np.uint8)
     ln = np.zeros(b, dtype=np.int32)
     sig = np.zeros((64, b), dtype=np.uint8)
@@ -765,18 +846,23 @@ def _rows(cases, batch=None):
 
 
 @pytest.mark.slow  # three sigverify-program compiles (~3 min each)
-def test_ladder_lanes_byte_identical_masks(rng):
+@pytest.mark.parametrize("batch", [None, 128])
+def test_ladder_lanes_byte_identical_masks(batch, rng):
     """The packed program's mask equals ops/ref's verdicts and the
     baseline / split rungs', on corrupted signatures, msg_len 0 and
     max_msg_len; and at a partial fill the real lanes' verdicts do not
-    depend on the pad rows — zero, or stale from an earlier batch."""
+    depend on the pad rows — zero, or stale from an earlier batch.  At
+    a batch of 128 every rung folds its lanes to (1, 128)
+    (sv.fold_batch): the masks are those of the one-axis ladder too."""
     import jax
 
     from firedancer_tpu.ops import sigverify as sv
 
     cases, expect = _cases(rng)
-    n = len(cases)
-    rows = jax.device_put(_rows(cases))
+    n = batch or len(cases)
+    assert bool(sv.fold_lanes(n)) == (batch is not None)
+    expect = expect + [False] * (n - len(cases))    # zero pad rows
+    rows = jax.device_put(_rows(cases, batch))
     masks = {}
     for kernel in sv.KERNEL_LADDER:
         mask = sv.verify_dispatch(kernel, rows, max_msg_len=MAX_MSG)
@@ -786,13 +872,17 @@ def test_ladder_lanes_byte_identical_masks(rng):
     assert masks["fused"].tolist() == expect
     assert masks["fused"].tolist() == masks["baseline"].tolist()
     assert masks["fused"].tolist() == masks["split"].tolist()
+    if batch:
+        flat = jax.jit(lambda r: sv._verify_ok(
+            *sv.unpack_rows(r, max_msg_len=MAX_MSG), max_msg_len=MAX_MSG))
+        assert np.asarray(flat(rows)).tolist() == expect
     # a partial fill of the same fixed shape: the first k rows real, the
     # pad rows zero, then stale (the rows of the batch above, as a reused
     # slot holds them).  What the program says of pad lanes is nobody's
     # answer; the real lanes' is the same either way.
-    k = n - 5
+    k = len(cases) - 5
     zero = _rows(cases[:k], batch=n)
-    stale = _rows(cases)
+    stale = _rows(cases, batch)
     stale[:k] = zero[:k]
     assert not zero[k:].any() and stale[k:].any()
     got_zero = np.asarray(sv.verify_dispatch(
@@ -805,9 +895,11 @@ def test_ladder_lanes_byte_identical_masks(rng):
 
 
 @pytest.mark.slow  # fused + cached kernel compiles
-def test_cached_lane_interleave_matches_generic(rng):
+@pytest.mark.parametrize("batch", [None, 128])
+def test_cached_lane_interleave_matches_generic(batch, rng):
     """Cached-signer (comb) verifies agree with the generic fused lane
-    on an interleaved honest/adversarial batch."""
+    on an interleaved honest/adversarial batch; at 128 lanes both fold
+    their batch and the bank gather takes the two-axis slots."""
     import jax.numpy as jnp
 
     from firedancer_tpu.ops import sigverify as sv
@@ -823,9 +915,9 @@ def test_cached_lane_interleave_matches_generic(rng):
         if i == 5:
             m = m[:-1] + b"\xff"  # one corrupted element mid-batch
         cases.append((m, s, pub))
-    msg, ln, sig, pk = _arrays(cases)
+    msg, ln, sig, pk = _arrays(cases, batch)
     n = len(cases)
-    gen_mask = sv.verify_dispatch("fused", jnp.asarray(_rows(cases)),
+    gen_mask = sv.verify_dispatch("fused", jnp.asarray(_rows(cases, batch)),
                                   max_msg_len=MAX_MSG)
     fill = np.zeros((32, len(pubs)), dtype=np.uint8)
     for i, p in enumerate(pubs):
@@ -836,9 +928,10 @@ def test_cached_lane_interleave_matches_generic(rng):
     bank = sv.bank_install(
         bank, tables, jnp.asarray(np.arange(len(pubs), dtype=np.int32)))
     slots = jnp.asarray(
-        np.asarray([i % 3 for i in range(n)], dtype=np.int32))
+        np.asarray([i % 3 for i in range(batch or n)], dtype=np.int32))
     cached = sv.ed25519_verify_batch_cached(
         jnp.asarray(msg), jnp.asarray(ln), jnp.asarray(sig),
         jnp.asarray(pk), bank, slots, max_msg_len=MAX_MSG)
+    assert np.asarray(cached).shape == (batch or n,)
     assert np.asarray(cached)[:n].tolist() == \
-        np.asarray(gen_mask)[:n].tolist()
+        np.asarray(gen_mask)[:n].tolist() == [i != 5 for i in range(n)]

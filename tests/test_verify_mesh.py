@@ -29,6 +29,7 @@ N_DEV = 4
 BATCH = 16          # 4 lanes a device
 MAX_MSG = 256
 PER = BATCH // N_DEV
+FOLD_BATCH = N_DEV * 128    # the program folds it: (1, 128) a chip
 
 
 def _devices():
@@ -37,8 +38,8 @@ def _devices():
     return jax.devices()[:N_DEV]
 
 
-def _ringless(devices):
-    return VerifyStage("m", batch=BATCH, max_msg_len=MAX_MSG,
+def _ringless(devices, batch=BATCH):
+    return VerifyStage("m", batch=batch, max_msg_len=MAX_MSG,
                        native_client=False, devices=devices)
 
 
@@ -77,6 +78,12 @@ def test_devices_must_divide_the_batch_and_own_the_dispatch():
     with pytest.raises(ValueError, match="comb bank"):
         VerifyStage("m", batch=BATCH, devices=N_DEV, comb_slots=4,
                     native_client=False)
+    # a batch the program folds to (batch // 128, 128): the rows have
+    # to divide over the devices too (3 rows over 2: no; 4 over 2: yes)
+    with pytest.raises(ValueError, match="3 rows of 128 lanes"):
+        _ringless(2, 3 * 128)
+    assert _ringless(2, 4 * 128).metrics.get(fm.KERNEL_FOLD_LANES) == 128
+    assert _ringless(2, 3 * 64).metrics.get(fm.KERNEL_FOLD_LANES) == 0
 
 
 def test_config_asks_for_the_mesh():
@@ -108,15 +115,15 @@ def test_the_pipeline_builder_passes_the_mesh_to_its_verify_stages():
 # -- placement and verdicts, the dispatch alone ------------------------------------
 
 
-def _toy_batch(n: int, seed: int, toy_lane_ok):
+def _toy_batch(n: int, seed: int, toy_lane_ok, batch: int = BATCH):
     """Random packed rows, `n` of them real and the pad rows random too
     (a reused slot's are an earlier batch's); -> (rows, the toy's
     verdicts on every row)."""
     rng = np.random.default_rng(seed)
-    rows = rng.integers(0, 256, (BATCH, vn.row_width(MAX_MSG)),
+    rows = rng.integers(0, 256, (batch, vn.row_width(MAX_MSG)),
                         dtype=np.uint8)
     ln = vn.row_lens(rows, MAX_MSG)
-    ln[:] = rng.integers(1, MAX_MSG, (BATCH,))
+    ln[:] = rng.integers(1, MAX_MSG, (batch,))
     tail = rows[:, MAX_MSG:].astype(np.int64)
     want = toy_lane_ok(ln, rows[:, 0], tail[:, 0], tail[:, 63],
                        tail[:, 64], tail[:, 95])
@@ -124,7 +131,7 @@ def _toy_batch(n: int, seed: int, toy_lane_ok):
     return rows, want
 
 
-def _signed_batch(n: int, seed: int):
+def _signed_batch(n: int, seed: int, batch: int = BATCH):
     """`n` rows of honestly signed messages (seeded keys, messages of
     seeded lengths), every third with one seeded corrupted signature
     bit, the pad rows zero; -> (rows, ops/ref's verdicts)."""
@@ -135,7 +142,7 @@ def _signed_batch(n: int, seed: int):
     ln = np.zeros((n,), dtype=np.int32)
     sig = np.zeros((n, 64), dtype=np.uint8)
     pk = np.zeros((n, 32), dtype=np.uint8)
-    want = np.zeros((BATCH,), dtype=bool)
+    want = np.zeros((batch,), dtype=bool)
     for i in range(n):
         secret = hashlib.sha256(b"mesh%d-%d" % (seed, i)).digest()
         pub = ref.public_key(secret)
@@ -150,7 +157,7 @@ def _signed_batch(n: int, seed: int):
         pk[i] = np.frombuffer(pub, dtype=np.uint8)
         want[i] = ref.verify(m, bytes(s), pub)
     assert want[:n].any() and not want[:n].all() or n < 2
-    return vn.pack_rows(msg, ln, sig, pk, batch=BATCH), want
+    return vn.pack_rows(msg, ln, sig, pk, batch=batch), want
 
 
 # fills (chip i is dealt elements i, i + 4, ...): full; three chips one
@@ -163,7 +170,7 @@ def _dispatch_both(fill: int, make, *args):
     rows, want = make(fill, 1000 + fill, *args)
     got = {}
     for name, devices in (("one", None), ("mesh", N_DEV)):
-        st = _ringless(devices)
+        st = _ringless(devices, len(rows))
         mask = st._device_verify(None, rows)
         got[name] = (mask, st._mask_of(mask))
     return got, want
@@ -174,7 +181,7 @@ def _check_verdicts(got, want, fill: int):
     pad lanes and all; on the real lanes both are the wanted verdicts."""
     for name in ("one", "mesh"):
         _fut, mask = got[name]
-        assert mask.dtype == np.bool_ and mask.shape == (BATCH,)
+        assert mask.dtype == np.bool_ and mask.shape == want.shape
         assert (mask[:fill] == want[:fill]).all(), name   # booleans: exact
     assert (got["one"][1] == got["mesh"][1]).all()
 
@@ -220,11 +227,18 @@ def _collectives(hlo_text: str) -> dict:
                        "collective-permute", "reduce-scatter")}
 
 
-def test_the_mesh_module_holds_no_collective(toy_verify_ok):
+@pytest.mark.parametrize("batch, lanes", [(BATCH, (BATCH,)),
+                                          (FOLD_BATCH, (N_DEV, 128))])
+def test_the_mesh_module_holds_no_collective(batch, lanes, toy_verify_ok,
+                                             monkeypatch):
     """The program as the mesh dispatch compiles it — the packed rows
     sharded by row, partitioned by that sharding alone — over four
     devices: the unpack's transpose moves the sharded axis and nothing
-    between chips; the mask stays on the lanes' shards."""
+    between chips; the mask stays on the lanes' shards.  At 4 x 128
+    lanes the program folds its batch (ops/sigverify.fold_batch): the
+    sharded axis becomes the rows of the fold, one (1, 128) a chip,
+    still with nothing between chips, and the mask comes back (B,)
+    for _mask_of to deal the lanes back in order."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -232,26 +246,49 @@ def test_the_mesh_module_holds_no_collective(toy_verify_ok):
     from firedancer_tpu.ops import sigverify as sv
     from firedancer_tpu.parallel.mesh import AXIS
 
-    st = _ringless(N_DEV)
+    seen = []
+    toy = sv._verify_ok
+
+    def spy(msg, msg_len, sig, pubkey, *, max_msg_len):
+        seen.append(msg_len.shape)
+        return toy(msg, msg_len, sig, pubkey, max_msg_len=max_msg_len)
+
+    monkeypatch.setattr(sv, "_verify_ok", spy)
+    st = _ringless(N_DEV, batch)
+    assert st.metrics.get(fm.KERNEL_FOLD_LANES) == (128 if len(lanes) == 2
+                                                    else 0)
     assert st._row_sharding.spec == P(AXIS, None)
-    rows = jax.ShapeDtypeStruct((BATCH, vn.row_width(MAX_MSG)), jnp.uint8,
+    rows = jax.ShapeDtypeStruct((batch, vn.row_width(MAX_MSG)), jnp.uint8,
                                 sharding=st._row_sharding)
     compiled = sv.ed25519_verify_batch_fused.lower(
         rows, max_msg_len=MAX_MSG).compile()
+    assert seen == [lanes]
     assert not any(_collectives(compiled.as_text()).values())
     assert compiled.output_shardings.spec == P(AXIS)
+    # dispatched: element e dealt to chip e % 4 and its verdict dealt
+    # back to index e, pad rows and all
+    real, want = _toy_batch(batch - 3, 7, toy_verify_ok, batch)
+    fut = st._device_verify(None, real)
+    assert {s.data.shape for s in fut.addressable_shards} \
+        == {(batch // N_DEV,)}
+    mask = st._mask_of(fut)
+    assert mask.shape == (batch,) and (mask == want).all()
     # the count of ops that would cross chips, on a program that has one
     assert _collectives("x = f32[] all-reduce(y)\n"
                         "z = all-gather-start(w)")["all-reduce"] == 1
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("fill", [BATCH - 3, N_DEV - 1])
-def test_mesh_lane_equals_the_reference_and_the_single_device_lane(fill):
+@pytest.mark.parametrize("batch, fill", [(BATCH, BATCH - 3),
+                                         (BATCH, N_DEV - 1),
+                                         (FOLD_BATCH, FOLD_BATCH - 131)])
+def test_mesh_lane_equals_the_reference_and_the_single_device_lane(
+        batch, fill):
     """The real program (ed25519_verify_batch_fused) over the mesh:
     the mask equals ops/ref's verdicts and the one-device lane's, with
-    corrupted signatures, the last shard partly and wholly empty."""
-    got, want = _dispatch_both(fill, _signed_batch)
+    corrupted signatures, the last shard partly and wholly empty; at
+    4 x 128 lanes the folded program, a chip's shard one (1, 128) row."""
+    got, want = _dispatch_both(fill, _signed_batch, batch)
     _check_verdicts(got, want, fill)
     assert not got["mesh"][1][fill:].any()    # a zero row never verifies
 
