@@ -116,7 +116,9 @@ def _run_processes(args) -> int:
     over shm links, optional per-stage jail, monitor table at exit.
     Done means what it means on the cooperative path: the bank executed
     every generated transaction."""
-    from firedancer_tpu.models.leader_topo import build_leader_topology
+    from firedancer_tpu.models.leader_topo import (
+        build_leader_topology_from_config,
+    )
     from firedancer_tpu.runtime import topo as ft
 
     cfg = _load_cfg(args)
@@ -125,10 +127,12 @@ def _run_processes(args) -> int:
         print(f"# the process topology runs 1 bank stage (config asks "
               f"{cfg.layout.bank_stage_count})", file=sys.stderr)
     sandbox = {"rlimits": {"nofile": 512}} if args.sandbox else None
-    topo = build_leader_topology(
-        n_txns=args.txns, pool_size=args.txns, batch=cfg.verify.batch,
-        max_msg_len=cfg.verify.max_msg_len, verify_cpu=args.cpu,
-        n_payers=RUN_PAYERS, sandbox=sandbox,
+    # the config's batch, widths, deadline, ring depths, pack rule and
+    # slot cadence; the generator's payers are the bank's genesis
+    topo = build_leader_topology_from_config(
+        cfg, n_bank=1, n_txns=args.txns, pool_size=args.txns,
+        verify_cpu=args.cpu, n_payers=RUN_PAYERS, sandbox=sandbox,
+        boot_grace_s=5.0 if cfg.poh.slot_ms > 0 else 0.0,
     )
     h = ft.launch(topo)
     try:

@@ -368,6 +368,7 @@ class SlotExecution:
         status_cache=None,
         ancestors: set[int] | None = None,
         slot_hashes: list[tuple[int, bytes]] | None = None,
+        xid: bytes | None = None,
     ):
         self.funk = funk
         self.slot = slot
@@ -382,7 +383,9 @@ class SlotExecution:
         # embedding the full parent xid grows the key by ~15 bytes per
         # unpublished ancestor, and a partitioned fork chain blows past
         # the native funk's FFK_XID_MAX (128) within a handful of slots.
-        self.xid = b"slot:%d:%d:%s" % (
+        # A caller that has to find the fork again from another process
+        # (a supervisor reading a bank tile's funk) names it: `xid`.
+        self.xid = xid if xid is not None else b"slot:%d:%d:%s" % (
             slot, next(_xid_seq),
             hashlib.sha256(parent_xid).hexdigest()[:24].encode()
             if parent_xid else b"root")

@@ -30,6 +30,22 @@ from firedancer_tpu.utils import log as fl
 _log = fl.get_logger("leader_topo")
 
 
+# ring depths, in frags, by link (the cooperative pipeline sizes every
+# ring but "ss" from verify.receive_buffer_depth)
+LINK_DEPTHS = {"gv": 1024, "vd": 1024, "pb": 256, "bp": 256, "bd": 256,
+               "ps": 1024, "ss": 4096}
+
+# what a `persist` topology's tiles make beside the links, named per
+# run (Topology.own): the bank tile's funk segment, the store's slots
+_FUNK_SHM = "fdtpu_funk_{uid}_bank0"
+
+
+def _store_dir_template() -> str:
+    from firedancer_tpu.runtime import monitor as mon
+
+    return mon.RUN_DIR + "/fdtpu_store_{uid}"
+
+
 def _cpu():
     from firedancer_tpu.utils.platform import enable_compile_cache, force_cpu_backend
 
@@ -59,7 +75,7 @@ def build_benchg(links, cnc, *, pool_size, n_txns, n_payers=8):
 
 
 def build_verify(links, cnc, *, batch, max_msg_len=256, precomputed=False,
-                 cpu=False):
+                 cpu=False, batch_deadline_s=0.002):
     from firedancer_tpu.utils.platform import select_device
 
     dev = None if precomputed else select_device(cpu)
@@ -72,7 +88,7 @@ def build_verify(links, cnc, *, batch, max_msg_len=256, precomputed=False,
         cnc=cnc,
         batch=batch,
         max_msg_len=max_msg_len,
-        batch_deadline_s=0.002,
+        batch_deadline_s=batch_deadline_s,
         precomputed_ok=precomputed,
     )
     if dev is not None:
@@ -158,7 +174,7 @@ def build_pack(links, cnc, *, n_bank, slot_clock=None, shed_keep=None):
 
 
 def build_pack_native(links, cnc, *, n_bank, txn_links, slot_clock=None,
-                      shed_keep=None):
+                      shed_keep=None, hold_when_full=False):
     """The fused native dedup+pack stage: consumes the verify output
     links directly (no dedup process) and runs native/fd_pack.cpp via
     one FFI crossing per burst.  The parent only wires this when
@@ -178,18 +194,30 @@ def build_pack_native(links, cnc, *, n_bank, txn_links, slot_clock=None,
         mb_deadline_s=0.0,
         clock=slot_clock,
         shed_keep=shed_keep,
+        hold_when_full=hold_when_full,
     )
 
 
-def build_bank(links, cnc, *, bank_idx, slot=1, slot_clock=None, n_payers=8):
+# the bank tile's funk fork, by a name its supervisor knows too (the
+# account store is read back through NativeFunk.attach_readonly)
+BANK_FORK_XID = b"leader_topo:bank"
+
+
+def build_bank(links, cnc, *, bank_idx, slot=1, slot_clock=None, n_payers=8,
+               genesis=None, funk_shm=None):
     # the bank process OWNS the live bank (its own funk + SlotExecution,
     # default_bank_ctx): the process topology therefore runs n_bank=1 —
     # multiple real-execution banks need the funk state shared, which the
     # cooperative pipeline gets in-process (models/leader.py) and a
     # multi-process topology would need a cross-process funk backend for
     # (the reference shares fd_funk in a wksp across tiles the same way)
+    # genesis: default_bank_ctx's arguments where the caller has them
+    # (a traffic shape's payers), else the generator's n_payers.
+    # funk_shm: the run's name for the account store's segment.
     from firedancer_tpu.runtime.bank import BankStage, default_bank_ctx
 
+    if genesis is None:
+        genesis = {"slot": slot, "n_payers": n_payers}
     stage = BankStage(
         f"bank{bank_idx}",
         ins=[shm.make_consumer(links[f"pb{bank_idx}"], lazy=8)],
@@ -199,7 +227,8 @@ def build_bank(links, cnc, *, bank_idx, slot=1, slot_clock=None, n_payers=8):
         ],
         cnc=cnc,
         bank_idx=bank_idx,
-        ctx=default_bank_ctx(slot=slot, n_payers=n_payers),
+        ctx=default_bank_ctx(**genesis, funk_shm=funk_shm,
+                             fork_xid=BANK_FORK_XID),
         clock=slot_clock,
     )
     stage.require_credit = True
@@ -220,7 +249,7 @@ def build_poh(links, cnc, *, n_bank, slot_clock=None):
     return stage
 
 
-def build_shred(links, cnc, *, secret, slot):
+def build_shred(links, cnc, *, secret, slot, batch_target_sz=4096):
     _cpu()  # reedsol can dispatch on device: the chip is the verify child's
     from firedancer_tpu.ops.ref import ed25519_ref as ref
     from firedancer_tpu.runtime.shred_stage import ShredStage
@@ -233,7 +262,7 @@ def build_shred(links, cnc, *, secret, slot):
         signer=lambda root: ref.sign(secret, root),
         secret=secret,  # arms the native shredder lane when available
         slot=slot,
-        batch_target_sz=4096,
+        batch_target_sz=batch_target_sz,
     )
 
 
@@ -264,16 +293,24 @@ def build_poh_shred_fused(links, cnc, *, n_bank, secret, slot,
     return stage
 
 
-def build_store(links, cnc, *, leader_pub):
+def build_store(links, cnc, *, leader_pub, persist_dir=None):
+    """persist_dir: the leader's own store tile as the cooperative
+    pipeline builds it (models/leader.py: it trusts its own signing
+    path), writing each stored slot where the supervisor reads the
+    block back (runtime/store.StoredSlots).  None: a store that
+    signature-checks every set and keeps them in its own memory."""
     _cpu()  # the resolver's RS recover can dispatch on device
     from firedancer_tpu.ops.ref import ed25519_ref as ref
     from firedancer_tpu.runtime.store import StoreStage
 
+    own = persist_dir is not None
     return StoreStage(
         "store",
         ins=[shm.make_consumer(links["ss"], lazy=64)],
         cnc=cnc,
-        verify_sig=lambda r, s: ref.verify(r, s, leader_pub),
+        verify_sig=None if own else lambda r, s: ref.verify(r, s, leader_pub),
+        trust_membership=own,
+        persist_dir=persist_dir,
     )
 
 
@@ -295,9 +332,29 @@ def build_leader_topology(
     max_msg_len: int = 256,
     verify_cpu: bool = False,
     n_payers: int = 8,
+    batch_deadline_s: float = 0.002,
+    depths: dict | None = None,
+    genesis: dict | None = None,
+    hold_when_full: bool = False,
+    persist: bool = False,
+    shred_batch_target_sz: int = 4096,
 ) -> ft.Topology:
     """n_payers: the generator's funded payer set, known to benchg and
     to the bank's genesis alike (models/leader.build_leader_pipeline).
+
+    depths: ring depths by link ("gv", "vd", "pb", "bp", "bd", "ps",
+    "ss": LINK_DEPTHS; a key left out keeps its depth there).
+    genesis: `default_bank_ctx`'s arguments for the bank tile (a
+    traffic shape's payers) in place of slot / n_payers.
+    hold_when_full: pack leaves its txn input unpolled while its pool
+    has no room for a burst (PackStage): backpressure through every
+    ring up to the source, no eviction.
+    persist: the tiles keep what a supervisor reads after the drain
+    where it can reach it — the store tile writes each stored slot
+    under the run's directory (`store_dir(handle)`,
+    runtime/store.StoredSlots), and the bank tile's funk segment has
+    the run's name (`bank_funk_shm(handle)`, NativeFunk
+    .attach_readonly, fork BANK_FORK_XID); close() removes both.
 
     verify_cpu: the verify child runs its kernel on the CPU backend
     instead of owning the chip (tests, chip-less boxes) — the CPU is what
@@ -338,6 +395,8 @@ def build_leader_topology(
     from firedancer_tpu.runtime.dedup import DedupStage
     from firedancer_tpu.runtime.pack_stage import PackStage
     from firedancer_tpu.runtime.poh_stage import PohStage
+    from firedancer_tpu.runtime.shred_stage import ShredStage
+    from firedancer_tpu.runtime.store import StoreStage
     from firedancer_tpu.runtime.verify import VerifyStage
 
     if slot_clock is not None:
@@ -354,18 +413,21 @@ def build_leader_topology(
         )
 
     use_native_pack = resolve_native_pack(native_pack)
+    d = dict(LINK_DEPTHS, **(depths or {}))
     topo = ft.Topology()
-    topo.link("gv", depth=1024, mtu=1232)
-    topo.link("vd", depth=1024, mtu=4096)
+    topo.link("gv", depth=d["gv"], mtu=1232)
+    topo.link("vd", depth=d["vd"], mtu=4096)
     if not use_native_pack:
-        topo.link("dp", depth=1024, mtu=4096)
+        topo.link("dp", depth=d["vd"], mtu=4096)
     for b in range(n_bank):
-        topo.link(f"pb{b}", depth=256, mtu=65536)
-        topo.link(f"bp{b}", depth=256, mtu=65536)
-        topo.link(f"bd{b}", depth=256, mtu=64)
+        topo.link(f"pb{b}", depth=d["pb"], mtu=65536)
+        topo.link(f"bp{b}", depth=d["bp"], mtu=65536)
+        topo.link(f"bd{b}", depth=d["bd"], mtu=64)
     if not fuse_poh_shred:
-        topo.link("ps", depth=1024, mtu=65536)
-    topo.link("ss", depth=4096, mtu=1232)
+        topo.link("ps", depth=d["ps"], mtu=65536)
+    topo.link("ss", depth=d["ss"], mtu=1232)
+    funk_shm = topo.own(_FUNK_SHM) if persist else None
+    persist_dir = topo.own(_store_dir_template()) if persist else None
 
     secret = hashlib.sha256(leader_seed).digest()
     leader_pub = ref.public_key(secret)
@@ -382,11 +444,16 @@ def build_leader_topology(
     topo.stage("verify0", build_verify, batch=batch,
                max_msg_len=max_msg_len, sandbox=sb,
                precomputed=verify_precomputed, cpu=verify_cpu,
+               batch_deadline_s=batch_deadline_s,
                ins=["gv"], outs=["vd"], schema=VerifyStage.metrics_schema())
+    if hold_when_full and not use_native_pack:
+        raise ValueError("hold_when_full is the native pack lane's here "
+                         "(native/fd_pack.so did not build or is off)")
     if use_native_pack:
         topo.stage("pack", build_pack_native, n_bank=n_bank,
                    txn_links=["vd"], sandbox=sb,
                    slot_clock=slot_clock, shed_keep=shed_keep,
+                   hold_when_full=hold_when_full,
                    ins=["vd"] + [f"bd{b}" for b in range(n_bank)],
                    outs=[f"pb{b}" for b in range(n_bank)],
                    schema=PackStage.metrics_schema())
@@ -401,6 +468,7 @@ def build_leader_topology(
     for b in range(n_bank):
         topo.stage(f"bank{b}", build_bank, bank_idx=b, slot=slot, sandbox=sb,
                    slot_clock=slot_clock, n_payers=n_payers,
+                   genesis=genesis, funk_shm=funk_shm,
                    ins=[f"pb{b}"], outs=[f"bp{b}", f"bd{b}"],
                    credit_gated=True, schema=BankStage.metrics_schema())
     if fuse_poh_shred:
@@ -418,10 +486,66 @@ def build_leader_topology(
                    ins=[f"bp{b}" for b in range(n_bank)], outs=["ps"],
                    credit_gated=True, schema=PohStage.metrics_schema())
         topo.stage("shred", build_shred, secret=secret, slot=slot,
-                   sandbox=sb, ins=["ps"], outs=["ss"])
+                   batch_target_sz=shred_batch_target_sz,
+                   sandbox=sb, ins=["ps"], outs=["ss"],
+                   schema=ShredStage.metrics_schema())
     topo.stage("store", build_store, leader_pub=leader_pub, sandbox=sb,
-               ins=["ss"])
+               persist_dir=persist_dir, ins=["ss"],
+               schema=StoreStage.metrics_schema())
     return topo
+
+
+def build_leader_topology_from_config(cfg, *, genesis: dict | None = None,
+                                      slot_clock=None,
+                                      **overrides) -> ft.Topology:
+    """The process topology derived from a typed Config
+    (utils/config.py), as models/leader
+    .build_leader_pipeline_from_config derives the cooperative one: one
+    configuration file describes both forms.  From the config: the
+    verify batch, message width and deadline, the ring depths
+    ([links]; the generator's ring is verify.receive_buffer_depth),
+    pack's full-pool rule, the shredder's batch target, and the slot
+    cadence (poh.slot_ms; `slot_clock`, a SlotClockCfg, overrides it).
+    `genesis`: the bank tile's (`default_bank_ctx`'s arguments).  The
+    tiles persist what they hold (`build_leader_topology`'s `persist`).
+
+    One bank tile: layout.bank_stage_count has to be 1 (funk has one
+    writer; `build_leader_topology` refuses another count).  A tile
+    that dies takes the topology down: restart under load is not part
+    of this deployment yet."""
+    if slot_clock is None and cfg.poh.slot_ms > 0:
+        from firedancer_tpu.runtime.slot_clock import SlotClockCfg
+
+        slot_clock = SlotClockCfg(slot_ms=cfg.poh.slot_ms,
+                                  ticks_per_slot=cfg.poh.ticks_per_slot)
+    ln = cfg.links
+    kw = dict(
+        n_bank=cfg.layout.bank_stage_count,
+        batch=cfg.verify.batch,
+        max_msg_len=cfg.verify.max_msg_len,
+        batch_deadline_s=cfg.verify.batch_deadline_ms / 1e3,
+        depths={"gv": cfg.verify.receive_buffer_depth,
+                "vd": ln.verify_pack, "pb": ln.pack_bank,
+                "bp": ln.bank_poh, "bd": ln.bank_done,
+                "ps": ln.poh_shred, "ss": ln.shred_store},
+        hold_when_full=cfg.pack.hold_when_full,
+        shred_batch_target_sz=cfg.shred.batch_target_sz,
+        genesis=genesis,
+        slot_clock=slot_clock,
+        persist=True,
+    )
+    kw.update(overrides)
+    return build_leader_topology(**kw)
+
+
+def store_dir(handle) -> str:
+    """Where a `persist` topology's store tile writes its slots."""
+    return _store_dir_template().format(uid=handle.uid)
+
+
+def bank_funk_shm(handle) -> str:
+    """The shm name of a `persist` topology's bank tile's funk."""
+    return _FUNK_SHM.format(uid=handle.uid)
 
 
 def build_leader_topology_fused(**kw) -> ft.Topology:

@@ -99,6 +99,7 @@ class BankCtx:
         blockhashes: tuple[bytes, ...] = (),
         executor=None,
         slot_hashes: list[tuple[int, bytes]] | None = None,
+        fork_xid: bytes | None = None,
     ):
         from firedancer_tpu.funk import make_funk
 
@@ -115,6 +116,9 @@ class BankCtx:
         # the SlotHashes sysvar the slot runs under, newest first (None:
         # default_sysvars' empty one, under which every vote rejects)
         self._slot_hashes = slot_hashes
+        # the slot's funk fork under a name the caller chose (None: the
+        # execution's own), for a reader in another process
+        self._fork_xid = fork_xid
         self._sx = None
         # force the native executor .so build/load NOW (one g++ shell-out
         # on cold hosts), not inside the first microblock's after_frag —
@@ -154,6 +158,7 @@ class BankCtx:
                 executor=self._executor,
                 status_cache=self.status_cache,
                 slot_hashes=self._slot_hashes,
+                xid=self._fork_xid,
             )
         return self._sx
 
@@ -189,8 +194,17 @@ def genesis_bank_ctx(
     voters=(),
     slot_hashes=None,
     preload=(),
+    funk_shm: str | None = None,
+    fork_xid: bytes | None = None,
 ) -> BankCtx:
     """The bank a leader enters its slot with, made from a seed.
+
+    funk_shm: the name of the account store's shm segment (the native
+    funk's; a bank tile in a process of its own is given its run's, so
+    that the supervisor can `NativeFunk.attach_readonly` it and can
+    unlink it if the tile dies).  None: a name of the funk's own.
+    fork_xid: the slot's funk fork under this name (BankCtx), which is
+    what such a reader asks the store for.
 
     Payers: the synthetic load's `n_payers` keypairs off `seed`
     (runtime/benchg.pool_payers), or the explicit `payers` pubkeys in
@@ -216,11 +230,19 @@ def genesis_bank_ctx(
 
     if slot_hashes is not None:
         slot_hashes = list(slot_hashes)
+    funk = None
+    if funk_shm is not None:
+        from firedancer_tpu.funk import funk_native
+
+        if funk_native.available():
+            funk = funk_native.NativeFunk(shm_name=funk_shm)
     ctx = BankCtx(
+        funk,
         slot=slot,
         status_cache=StatusCache() if with_status_cache else None,
         blockhashes=(pool_blockhash(seed),),
         slot_hashes=slot_hashes,
+        fork_xid=fork_xid,
     )
     if payers is None:
         payers = [pub for _, pub in pool_payers(seed, n_payers)]
@@ -367,6 +389,11 @@ class BankStage(Stage):
         # routes whole credit windows through fdb_frag_cb
         self._armed_ctx = None
         self._arm_native()
+
+    def native_lanes(self) -> dict[str, bool]:
+        return dict(super().native_lanes(),
+                    bank=self._sweep_client is not None,
+                    funk=hasattr(self.ctx.funk, "txn_diff"))
 
     def _arm_native(self) -> None:
         self._sweep_client = None

@@ -8,7 +8,7 @@ every tile heartbeats in the RUN state
 (/root/reference/src/app/fdctl/ready.c).
 
 A running topology advertises itself in a run descriptor
-(`/tmp/fdtpu_run_<uid>.json`, written by runtime/topo.launch): stage
+(`fdtpu_run_<uid>.json` under RUN_DIR, written by runtime/topo.launch): stage
 names + cnc shared-memory names.  `attach()` joins those cnc regions
 READ-ONLY from any process, so the monitor and `ready` work exactly
 like the reference's: against a live validator they did not start.
@@ -19,6 +19,7 @@ from __future__ import annotations
 import json
 import os
 import sys
+import tempfile
 import time
 from dataclasses import dataclass
 from multiprocessing import shared_memory
@@ -29,8 +30,8 @@ from firedancer_tpu.tango import rings
 from firedancer_tpu.tango.rings import CNC_SIG_FAIL, CNC_SIG_RUN, Cnc
 from firedancer_tpu.utils import metrics as fm
 
-RUN_DIR = os.environ.get("FDTPU_RUN_DIR", "/tmp")
-_SIG_NAMES = {0: "BOOT", 1: "RUN", 2: "HALT", 3: "FAIL"}
+RUN_DIR = os.environ.get("FDTPU_RUN_DIR") or tempfile.gettempdir()
+_SIG_NAMES = {0: "BOOT", 1: "RUN", 2: "HALT", 3: "FAIL", 4: "SYNC"}
 
 
 def _attach_shm(name: str) -> shared_memory.SharedMemory:
@@ -387,25 +388,27 @@ class MonitorSession:
     def render(rows: list[dict], prev: list[dict] | None,
                dt_s: float) -> str:
         hdr = (f"{'stage':<14}{'state':<6}{'hb_ms':>8}{'in/s':>11}"
-               f"{'out/s':>11}{'busy%':>7}{'ovrn':>7}{'bkp':>7}"
+               f"{'out/s':>11}{'busy%':>7}{'backp%':>7}{'ovrn':>7}{'bkp':>7}"
                f"{'p50 lat':>9}{'p99 lat':>9}{'sweep p50us':>16}")
         lines = [hdr, "-" * len(hdr)]
         prev_by = {r["stage"]: r for r in prev or []}
         for r in rows:
             p = prev_by.get(r["stage"])
-            in_rate = out_rate = busy = float("nan")
+            in_rate = out_rate = busy = backp = float("nan")
             if p and dt_s > 0:
                 in_rate = (r["in"] - p["in"]) / dt_s
                 out_rate = (r["out"] - p["out"]) / dt_s
-                # busy% is time: the share of the stage's loop time,
-                # between the two samples, in calls that did work (the
+                # busy% and backp% are time: the shares of the stage's
+                # loop time, between the two samples, in calls that did
+                # work and in calls that the tile behind it held up (the
                 # thread's ledger; "-" where the metrics plane is not
-                # joined)
+                # joined).  With a process a tile the busiest tile
+                # limits; the tiles in front of it show backp%
                 lp, lp0 = r.get("loop"), p.get("loop")
                 if lp and lp0:
-                    pct = fm.loop_busy_pct(*(lp[k] - lp0[k] for k in
-                                             ("work_ns", "poll_ns", "hk_ns")))
-                    busy = 0.0 if pct is None else pct
+                    sh = fm.loop_shares(lp, lp0)
+                    busy, backp = ((sh["busy_pct"], sh["backp_pct"])
+                                   if sh else (0.0, 0.0))
             hb = (f"{r['heartbeat_age_ms']:.1f}"
                   if r["heartbeat_age_ms"] is not None else "-")
             fmt = lambda v: "-" if v != v else f"{v:,.0f}"  # noqa: E731
@@ -414,7 +417,8 @@ class MonitorSession:
             lines.append(
                 f"{r['stage']:<14}{_SIG_NAMES.get(r['signal'], '?'):<6}"
                 f"{hb:>8}{fmt(in_rate):>11}{fmt(out_rate):>11}"
-                f"{fmt(busy):>7}{r['overrun']:>7}{r['backpressure']:>7}"
+                f"{fmt(busy):>7}{fmt(backp):>7}"
+                f"{r['overrun']:>7}{r['backpressure']:>7}"
                 f"{fm.format_latency_ms(r.get('lat_p50_ms')):>9}"
                 f"{fm.format_latency_ms(r.get('lat_p99_ms')):>9}"
                 f"{fm.format_phase_cell(r.get('sweep_phases') or {}):>16}"

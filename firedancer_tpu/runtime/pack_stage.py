@@ -47,6 +47,7 @@ from __future__ import annotations
 import time
 
 from firedancer_tpu.pack.scheduler import Pack
+from firedancer_tpu.tango import shm
 from firedancer_tpu.tango.rings import MCache
 from firedancer_tpu.utils import metrics as fm
 from .slot_clock import resolve_clock
@@ -114,9 +115,17 @@ class PackStage(Stage):
         clock=None,
         close_frac: float = 0.25,
         shed_keep: int | None = None,
+        hold_when_full: bool = False,
         **kwargs,
     ):
         super().__init__(*args, **kwargs)
+        # a pool without room for one more burst leaves the txn inputs
+        # unpolled (backpressure through the ring in front, up to the
+        # source) instead of evicting its cheapest transaction for the
+        # newcomer: for a deployment whose source can wait (a process a
+        # tile under a generator).  The banks' done frames keep coming
+        # in (`_drain_done`), or the pool would never drain
+        self.hold_when_full = hold_when_full
         if len(self.outs) != bank_cnt:
             raise ValueError("need one output link per bank")
         self.bank_cnt = bank_cnt
@@ -160,6 +169,9 @@ class PackStage(Stage):
     def _make_pack(self, **kw):
         return Pack(**kw)
 
+    def native_lanes(self) -> dict[str, bool]:
+        return dict(super().native_lanes(), pack=False)
+
     # the pool's own cumulative counts (Pack.stat_*; the native lane's
     # come back with every crossing) -> this stage's counters
     _PACK_STATS = (("stat_evicted", "txn_dropped"),
@@ -196,10 +208,33 @@ class PackStage(Stage):
             else:
                 self.metrics.inc("txn_dropped")
         else:
-            bank = in_idx - self.n_txn_ins
-            self.pack.microblock_done(bank)
-            self._bank_busy[bank] = False
-            self.metrics.inc("microblock_done")
+            self._bank_done(in_idx - self.n_txn_ins)
+
+    def _bank_done(self, bank: int) -> None:
+        self.pack.microblock_done(bank)
+        self._bank_busy[bank] = False
+        self.metrics.inc("microblock_done")
+
+    def _drain_done(self) -> None:
+        """The banks' done frames, polled past the held intake, with
+        the books the intake keeps (frags_in, the frag's wait)."""
+        m = self.metrics
+        now = 0
+        for bank in range(self.bank_cnt):
+            cons = self.ins[self.n_txn_ins + bank]
+            while (res := cons.poll()) != shm.POLL_EMPTY:
+                if res == shm.POLL_OVERRUN:
+                    m.inc("overrun")
+                    continue
+                self._bank_done(bank)
+                self._loop_worked = True
+                m.inc("frags_in")
+                ts = int(res[0][MCache.COL_TSORIG])
+                now = now or shm.now_ns()
+                if 0 < ts <= now:
+                    m.observe("frag_latency_ns", now - ts)
+                    m.inc("frag_wait_ns", now - ts)
+                    m.inc("frag_wait_n")
 
     def before_credit(self) -> None:
         # the mb_deadline_s clock starts here, not in after_frag (the
@@ -209,6 +244,11 @@ class PackStage(Stage):
         # unconditionally every iteration, so the stamp lags a txn's
         # arrival by at most one iteration even under backpressure
         self._flush_intake()
+        if self.hold_when_full:
+            full = self.pack.depth - self._pending_cnt() < self.burst
+            self.intake_room = 0 if full else None
+            if full:
+                self._drain_done()
         if self._clock is not None:
             self._clock_roll(self._clock.now())
         if self.adaptive:
@@ -367,6 +407,9 @@ class NativePackStage(PackStage):
         # and the per-burst FFI crossing amortize over 4x the frags
         self.burst = 64
 
+    def native_lanes(self) -> dict[str, bool]:
+        return dict(super().native_lanes(), pack=True)
+
     def _make_pack(self, **kw):
         from firedancer_tpu.pack import scheduler_native as sn
         from firedancer_tpu.tango.tcache_native import NativeTCache
@@ -386,10 +429,7 @@ class NativePackStage(PackStage):
                  int(meta[MCache.COL_TSORIG]))
             )
         else:
-            bank = in_idx - self.n_txn_ins
-            self.pack.microblock_done(bank)
-            self._bank_busy[bank] = False
-            self.metrics.inc("microblock_done")
+            self._bank_done(in_idx - self.n_txn_ins)
 
     def _flush_intake(self) -> None:
         if not self._burst:

@@ -45,12 +45,28 @@ Python shredder end to end.
 from __future__ import annotations
 
 from firedancer_tpu.tango.rings import MCache
+from firedancer_tpu.utils import metrics as fm
 from .poh_stage import PohStage, poh_sig_fields
 from .shredder import EntryBatchMeta, FecSet, Shredder
 from .stage import Stage
 
 
 class ShredStage(Stage):
+    @classmethod
+    def extra_schema(cls) -> fm.MetricsSchema:
+        # counted in C on the sweep lane (shred_native._COUNTERS, copied
+        # in housekeeping) and by the Python lane alike
+        return (
+            fm.MetricsSchema()
+            .counter("entries_in", "entries taken into a batch")
+            .counter("entry_batches", "entry batches closed and shredded")
+            .counter("fec_sets", "FEC sets made and published")
+            .counter("data_shreds_out", "data shreds published")
+            .counter("parity_shreds_out", "parity shreds published")
+            .counter("batches_dropped",
+                     "batches the C lane could not shred (never-path)")
+        )
+
     def __init__(
         self,
         *args,
@@ -117,6 +133,10 @@ class ShredStage(Stage):
         self._slot = v
         if self._sweep_client is not None:
             self._sweep_client.set_slot(v)
+
+    def native_lanes(self) -> dict[str, bool]:
+        return dict(super().native_lanes(),
+                    shred=self._sweep_client is not None)
 
     def after_frag(self, in_idx: int, meta, payload: bytes) -> None:
         c = self._sweep_client

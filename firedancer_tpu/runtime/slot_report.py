@@ -142,6 +142,13 @@ def _stage_block(mets: dict, records: list) -> dict:
                      fm.BATCH_FIT_PAD_LANES, fm.VERIFY_FAIL_ELEMS,
                      fm.KERNEL_FOLD_LANES):
             block[name] = int(mets.get(name, 0) or 0)
+    # the thread's ledger: what of the stage's loop time went to work,
+    # to backpressure (the tile behind it held it up) and to empty
+    # polls, since boot.  With a process a tile: the busiest limits
+    loop = fm.loop_row([mets])
+    shares = loop and fm.loop_shares(loop)
+    if shares:
+        block["loop"] = dict(loop, **shares)
     # when the chip had nothing of the verify stage's to run (ns and
     # intervals, cumulative), and of that the shares the thread spent
     # in other stages and in the stage's own blocking calls

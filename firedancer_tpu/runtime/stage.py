@@ -30,7 +30,9 @@ from bisect import bisect_left
 import numpy as np
 
 from firedancer_tpu.tango import shm
-from firedancer_tpu.tango.rings import CNC_SIG_HALT, CNC_SIG_RUN, Cnc, MCache
+from firedancer_tpu.tango.rings import (
+    CNC_SIG_HALT, CNC_SIG_RUN, CNC_SIG_SYNC, Cnc, MCache,
+)
 from firedancer_tpu.utils import metrics as fm
 from .autotune import OCC_EDGES
 
@@ -253,6 +255,16 @@ class Stage:
         # regime.  A hook that worked without consuming or publishing a
         # frag says so here; run_once reads and clears it
         self._loop_worked = False
+        # this call's sweep found a ring behind the stage without
+        # credits (or the stage without room: `intake_room`): a call
+        # that then did nothing while a ring in front held a frag is
+        # charged to backpressure, not to the polls
+        self._loop_blocked = False
+        # frags the stage has room to take in one sweep, where it has a
+        # bound of its own (pack's pool); None: the burst.  A hook sets
+        # it before the intake (before_credit); the Python and the
+        # native burst paths keep to it (a C sweep client gates itself)
+        self.intake_room: int | None = None
         # the last call's entry and exit, on time.monotonic_ns(): what
         # lies between an exit and the next entry is the other stages'
         self._loop_entry_ns = 0
@@ -335,6 +347,32 @@ class Stage:
             if set_metrics is not None:
                 set_metrics(None)  # C drops its raw pointer too
 
+    def native_lanes(self) -> dict[str, bool]:
+        """lane -> armed, of the native lanes this stage would run on:
+        here the rings (every consumer and producer the native ring
+        plane's); a stage kind with a C sweep client or a native store
+        adds its own.  A benchmark holds a run to all of them; in a
+        process topology each tile answers for its own process
+        (`sync_counters`: the gauges native_lanes / native_lanes_off)."""
+        fn = _native_ring()
+        ends = self.ins + self.outs
+        if not ends:
+            return {}
+        return {"rings": fn is not None and all(
+            type(e) in (fn.NativeConsumer, fn.NativeProducer) for e in ends)}
+
+    def sync_counters(self) -> None:
+        """Everything a reader in another process is about to read,
+        put out now: the C-side counters into the metrics, how many of
+        the stage's native lanes are armed and how many are not, and
+        the metrics into the registry."""
+        self.during_housekeeping()
+        lanes = self.native_lanes()
+        c = self.metrics.counters
+        c["native_lanes"] = sum(lanes.values())
+        c["native_lanes_off"] = len(lanes) - c["native_lanes"]
+        self.metrics.flush()
+
     def _copy_sweep_counters(self) -> None:
         """Time inside this stage's non-empty native crossings, for a
         reader of `metrics.counters`: the sums of the four nsweep_*_ns
@@ -346,13 +384,7 @@ class Stage:
         cached = self._nplane
         if cached is None or cached[1] is None:
             return
-        reg = cached[1].registry
-        if "nsweep_crossings" not in reg._off:
-            return  # a schema without the native-sweep block
-        c = self.metrics.counters
-        c["sweep_busy_ns"] = int(sum(reg.hist_sum(f"nsweep_{ph}_ns")
-                                     for ph in fm.NSWEEP_PHASES))
-        c["sweep_crossings"] = reg.get("nsweep_crossings")
+        self.metrics.counters.update(fm.sweep_counters(cached[1].registry))
 
     # -- in-place restart (supervisor respawn) -------------------------------
 
@@ -482,16 +514,21 @@ class Stage:
 
         The ONE place a call is stamped (the thread's ledger, upstream's
         stem regimes): two clock reads a call, charged whole to one of
-        three regimes.  `loop_hk_ns`: the housekeeping pass, taken out
+        four regimes.  `loop_hk_ns`: the housekeeping pass, taken out
         of the call it ran in.  `loop_work_ns` / `loop_work_n`: the
         call did work — it consumed a frag (the three intake paths say
         so), its own `frags_out` moved (a publish from any hook), or a
         hook that works with neither said so through `_loop_worked`
         (verify's pump dispatching or reaping, a tick's hashes, a slot
-        close, a flush that publishes in C).  `loop_poll_ns` /
-        `loop_poll_n`: it found nothing to do (a credit-gated return
-        too).  The exit stamp is kept: what lies between it and the
-        next entry is the other stages' time."""
+        close, a flush that publishes in C).  `loop_backp_ns` /
+        `loop_backp_n`: it did nothing because a ring behind it had no
+        credits, or the stage no room (`_sweep` says so through
+        `_loop_blocked`), while a ring in front held a frag: the tile
+        behind limits (fdctl monitor's % backp).  `loop_poll_ns` /
+        `loop_poll_n`: it found nothing to do.  With a process a tile
+        the two tell a starved tile from a blocked one.  The exit
+        stamp is kept: what lies between it and the next entry is the
+        other stages' time."""
         c = self.metrics.counters
         t0 = self._loop_entry_ns = _now_ns()
         self._iter += 1
@@ -509,6 +546,10 @@ class Stage:
             self._loop_worked = False
             c["loop_work_ns"] += t1 - t0
             c["loop_work_n"] += 1
+        elif self._loop_blocked and not halted and any(
+                cons.has_pending() for cons in self.ins):
+            c["loop_backp_ns"] += t1 - t0
+            c["loop_backp_n"] += 1
         else:
             c["loop_poll_ns"] += t1 - t0
             c["loop_poll_n"] += 1
@@ -532,6 +573,8 @@ class Stage:
         elif self._bp_since is not None:
             self.trace(fm.EV_BACKPRESSURE_OFF, self._iter - self._bp_since)
             self._bp_since = None
+        room = self.intake_room
+        self._loop_blocked = backpressured or room == 0
         if not backpressured:
             self.after_credit()
         if self.require_credit and any(p.cr_avail <= 0 for p in self.outs):
@@ -539,6 +582,7 @@ class Stage:
             # credit (e.g. a poh tick entry), and consuming an input frag
             # we can't forward would silently drop it.
             self.metrics.inc("backpressure_stall")
+            self._loop_blocked = True
             return False
         n_in = len(self.ins)
         if n_in:
@@ -560,6 +604,8 @@ class Stage:
         # per frag — the dominant host-path cost at profile; the
         # reference's stem loop amortizes the same way in C.
         asked = max(1, self.burst)
+        if room is not None and room < asked:
+            asked = max(room, 0)
         taken = 0
         while taken < asked:
             if progressed and self.require_credit and any(
@@ -726,6 +772,9 @@ class Stage:
             cap = min(p.cr_avail for p in self.outs)
             if cap < max_frags:
                 max_frags = cap
+        room = self.intake_room
+        if room is not None and room < max_frags:
+            max_frags = room
         if max_frags <= 0:
             return False
         m = self.metrics
@@ -791,7 +840,15 @@ class Stage:
         it = 0
         idle = 0
         self.trace(fm.EV_RUN)
-        while self.cnc.signal != CNC_SIG_HALT:
+        while True:
+            sig = self.cnc.signal
+            if sig != CNC_SIG_RUN:
+                if sig == CNC_SIG_HALT:
+                    break
+                if sig == CNC_SIG_SYNC:
+                    # the supervisor reads the counters next
+                    self.sync_counters()
+                    self.cnc.signal = CNC_SIG_RUN
             if self.run_once():
                 idle = 0
             else:

@@ -38,7 +38,7 @@ import multiprocessing as mp
 import os
 import signal as _signal
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from multiprocessing import shared_memory
 
 import numpy as np
@@ -122,6 +122,17 @@ class StageSpec:
 class Topology:
     links: list[LinkSpec] = field(default_factory=list)
     stages: list[StageSpec] = field(default_factory=list)
+    # what the run's stages make beside the links, named per run: a
+    # template with "{uid}" in it, a /dev/shm segment name or (with a
+    # "/" in it) a directory.  launch() puts the run's uid into these
+    # and into every string among the stages' kwargs, makes the
+    # directories, and close() takes all of it away again, whether or
+    # not the stage that made it lived to do so (`own`)
+    owned: list[str] = field(default_factory=list)
+
+    def own(self, template: str) -> str:
+        self.owned.append(template)
+        return template
 
     def link(self, name: str, **kw) -> "LinkSpec":
         spec = LinkSpec(name, **kw)
@@ -194,8 +205,22 @@ def _quiet_shm_close(s: shared_memory.SharedMemory) -> None:
             pass
 
 
+def _die_with_parent(parent_pid: int) -> None:
+    """A tile does not outlive its supervisor: SIGKILL when the parent
+    goes, however it goes (prctl PR_SET_PDEATHSIG; Linux).  A parent
+    that was gone before the call is caught by the pid check."""
+    import ctypes
+
+    try:
+        ctypes.CDLL(None, use_errno=True).prctl(1, int(_signal.SIGKILL))
+    except (OSError, AttributeError):
+        return
+    if os.getppid() != parent_pid:
+        os._exit(1)
+
+
 def _stage_main(spec: StageSpec, link_names: dict, uid: str,
-                resume: bool = False) -> None:
+                resume: bool = False, parent_pid: int | None = None) -> None:
     """Child entry: join links + cnc + metrics segment, build the stage,
     run until HALT.  On any raise the flight ring gets an EV_FAIL record
     BEFORE the cnc flips to FAIL — the ring lives in shm, so the record
@@ -206,6 +231,8 @@ def _stage_main(spec: StageSpec, link_names: dict, uid: str,
     at their published fseqs, producers at their recovered mcache
     frontiers with the replay-dedup guard armed — and its counters
     continue from the registry's last flushed values instead of zero."""
+    if parent_pid is not None:
+        _die_with_parent(parent_pid)
     cnc_shm = shared_memory.SharedMemory(name=_cnc_shm_name(uid, spec.name))
     cnc = Cnc(np.frombuffer(cnc_shm.buf, dtype=rings.U64, count=2 + Cnc.NDIAG))
     met_shm = shared_memory.SharedMemory(name=_met_shm_name(uid, spec.name))
@@ -286,13 +313,24 @@ def _stage_main(spec: StageSpec, link_names: dict, uid: str,
 
 class TopologyHandle:
     def __init__(self, topo, uid, links, cncs, cnc_shms, procs,
-                 met_shms=None, met_views=None, link_names=None):
+                 met_shms=None, met_views=None, link_names=None,
+                 owned=()):
         self.topo = topo
         self.uid = uid
         self.links = links  # name -> ShmLink (parent-side joins)
         self.cncs = cncs  # stage name -> Cnc
         self._cnc_shms = cnc_shms
-        self.procs = procs  # stage name -> mp.Process
+        self.procs = procs  # stage name -> mp.Process (spawned stages)
+        # stages the caller runs in its own process (launch(held=...)):
+        # name -> the Stage once the caller has built it (`hold`)
+        self.held: dict[str, object] = {}
+        # Topology.owned with the uid in: segments and directories of
+        # the run that close() takes away
+        self.owned = list(owned)
+        # stage name -> its StageSpec with the run's uid in its kwargs:
+        # what a child is built from, and a held stage by its caller
+        self.run_specs: dict[str, StageSpec] = {}
+        self._closed = False
         self._met_shms = met_shms or {}
         # stage name -> (MetricsRegistry, FlightRecorder), parent views
         self.met_views = met_views or {}
@@ -435,18 +473,106 @@ class TopologyHandle:
         EXISTING segments (same uid, same rings, same cnc + metrics shm):
         _stage_main(resume=True) makes the stage reattach its cursors
         instead of starting at seq 0."""
-        spec = next(s for s in self.topo.stages if s.name == name)
+        spec = self.run_specs[name]
         # the respawned child gets a fresh boot-grace heartbeat window
         self.cncs[name].heartbeat(time.monotonic_ns())
         ctx = mp.get_context("spawn")
         p = ctx.Process(
             target=_stage_main, args=(spec, self._link_names, self.uid),
-            kwargs={"resume": True}, name=spec.name,
+            kwargs={"resume": True, "parent_pid": os.getpid()},
+            name=spec.name,
         )
         p.daemon = True
         p.start()
         self.procs[name] = p
         _log.notice(f"respawned stage '{name}' in place, pid={p.pid}")
+
+    # -- stages held in the caller's process --------------------------------
+
+    def build_held(self, name: str, **override):
+        """Build held stage `name` in this process with the builder and
+        kwargs a child would have been given, and hold it."""
+        spec = self.run_specs[name]
+        stage = spec.builder(self.links, self.cncs[name],
+                             **{**spec.kwargs, **override})
+        self.hold(stage)
+        return stage
+
+    def hold(self, stage) -> None:
+        """The caller built held stage `stage.name` over `links` and
+        `cncs[name]`: bind it to its metrics segment and flight ring
+        like a child binds its own, so that monitor, scrape and
+        `counters` see every stage alike."""
+        if stage.name in self.procs or stage.name not in self.met_views:
+            raise ValueError(f"stage '{stage.name}' is not held by this "
+                             f"launch (held=...)")
+        stage.attach_observability(*self.met_views[stage.name])
+        self.held[stage.name] = stage
+
+    def dead(self) -> list[str]:
+        """Spawned stages whose process is gone or that signalled FAIL."""
+        return [n for n, p in self.procs.items()
+                if not p.is_alive() or self.cncs[n].signal == CNC_SIG_FAIL]
+
+    def wait_running(self, timeout_s: float = 120.0,
+                     poll_s: float = 0.01) -> None:
+        """Until every spawned stage is in its run loop (its builder
+        returned: RUN and a heartbeat); a stage that dies booting, or
+        the timeout, raises with the stage's name."""
+        deadline = time.monotonic() + timeout_s
+        waiting = list(self.procs)
+        while waiting:
+            dead = self.dead()
+            if dead:
+                self.failed = dead[0]
+                self.dump_flight(f"stage '{dead[0]}' died booting")
+                raise RuntimeError(f"stage '{dead[0]}' died booting")
+            waiting = [n for n in waiting
+                       if self.cncs[n].signal != CNC_SIG_RUN
+                       or not self.cncs[n].last_heartbeat]
+            if time.monotonic() > deadline:
+                raise RuntimeError(f"stages {waiting} not running after "
+                                   f"{timeout_s:.0f}s")
+            if waiting:
+                time.sleep(poll_s)
+
+    def counters(self, timeout_s: float = 2.0) -> dict[str, dict]:
+        """stage -> counter -> value for every stage, held or spawned.
+        A spawned stage is asked to put its counters out first (its
+        C-side ones into its metrics, those into shm: CNC_SIG_SYNC,
+        answered from its run loop) and is read from its shm segment;
+        a held one is asked in place.  A stage that does not answer in
+        `timeout_s` (dead, wedged, still booting) is read as it last
+        flushed.  Each tile answers at its own instant (they are apart
+        by what the tiles' current calls take): counters of two tiles
+        agree only once the work between them has settled."""
+        asked = []
+        for name, p in self.procs.items():
+            cnc = self.cncs[name]
+            if p.is_alive() and cnc.signal == CNC_SIG_RUN:
+                cnc.signal = rings.CNC_SIG_SYNC
+                asked.append(name)
+        for st in self.held.values():
+            st.sync_counters()
+        deadline = time.monotonic() + timeout_s
+        while asked and time.monotonic() < deadline:
+            asked = [n for n in asked
+                     if self.cncs[n].signal == rings.CNC_SIG_SYNC
+                     and self.procs[n].is_alive()]
+        for name in asked:     # unanswered: do not leave the request up
+            if self.cncs[name].signal == rings.CNC_SIG_SYNC:
+                self.cncs[name].signal = CNC_SIG_RUN
+        out = {}
+        for spec in self.topo.stages:
+            reg = self.met_views.get(spec.name, (None, None))[0]
+            if reg is None:
+                continue
+            c = fm.registry_counters(reg)
+            st = self.held.get(spec.name)
+            if st is not None:      # and what the schema does not name
+                c.update(st.metrics.counters)
+            out[spec.name] = c
+        return out
 
     def halt(self, timeout_s: float = 10.0) -> None:
         """Clean shutdown: HALT every cnc, join, terminate stragglers."""
@@ -459,6 +585,9 @@ class TopologyHandle:
         self.kill()
 
     def kill(self) -> None:
+        """No spawned stage outlives this call: SIGTERM, and SIGKILL
+        for what has not gone 5 s later (a child stuck in a C call, or
+        one that blocks the signal)."""
         for p in self.procs.values():
             if p.is_alive():
                 # a SIGSTOPped child ignores SIGTERM until continued —
@@ -468,8 +597,13 @@ class TopologyHandle:
                 except (OSError, TypeError):
                     pass
                 p.terminate()
+        deadline = time.monotonic() + 5
         for p in self.procs.values():
-            p.join(timeout=5)
+            p.join(timeout=max(deadline - time.monotonic(), 0.05))
+        for p in self.procs.values():
+            if p.is_alive():
+                p.kill()
+                p.join(timeout=5)
 
     # -- fault injection (the chaos harness's supervisor surface) ------------
 
@@ -491,13 +625,25 @@ class TopologyHandle:
 
     def shm_names(self) -> list[str]:
         """Every shared-memory segment name this topology owns (links +
-        cnc + metrics) — the chaos leak check scans /dev/shm for them
-        after close()."""
+        cnc + metrics + what its stages make: Topology.owned) — the
+        chaos leak check scans /dev/shm for them after close()."""
         out = [f"fdtpu_{spec.name}_{self.uid}" for spec in self.topo.links]
         for spec in self.topo.stages:
             out.append(_cnc_shm_name(self.uid, spec.name))
             out.append(_met_shm_name(self.uid, spec.name))
-        return out
+        return out + [o for o in self.owned if "/" not in o]
+
+    def left_behind(self) -> list[str]:
+        """After close(): spawned stages still alive and segments or
+        directories of the run still there, by name.  Empty is the
+        contract."""
+        left = [f"process:{n}" for n, p in self.procs.items()
+                if p.is_alive()]
+        left += [f"shm:{n}" for n in self.shm_names()
+                 if os.path.exists(os.path.join("/dev/shm", n))]
+        left += [f"dir:{o}" for o in self.owned
+                 if "/" in o and os.path.exists(o)]
+        return left
 
     def dump_flight(self, reason: str = "") -> str | None:
         """Write the crash dump — every stage's flight ring + a final
@@ -524,16 +670,38 @@ class TopologyHandle:
             return None
 
     def close(self) -> None:
+        """Everything of the run goes: the children, the descriptor,
+        every segment and directory — also after a child died, and
+        twice is once.  Held stages are the caller's: they must have
+        dropped their link views (Stage.ins / outs, drop_native_views)
+        before this."""
+        import shutil
+
         from firedancer_tpu.runtime import monitor as mon
 
+        if self._closed:
+            return
+        self._closed = True
+        self.held = {}
         mon.remove_descriptor(self.uid)
         self.kill()
         for link in self.links.values():
-            link.close()
+            try:
+                link.close()
+            except BufferError:
+                pass    # a view still pins the mapping: unlink all the same
             try:
                 link.unlink()
             except FileNotFoundError:
                 pass
+        for o in self.owned:
+            if "/" in o:
+                shutil.rmtree(o, ignore_errors=True)
+            else:   # made by a stage that may have died before its unlink
+                try:
+                    os.unlink(os.path.join("/dev/shm", o))
+                except FileNotFoundError:
+                    pass
         # numpy views into the metric and cnc segments must drop before
         # close — a pinned view turns close() into a BufferError and the
         # interpreter-exit SharedMemory.__del__ into stderr noise
@@ -564,12 +732,14 @@ class TopologyHandle:
 
         now = time.monotonic_ns()
         out = []
-        for name, p in self.procs.items():
+        for spec in self.topo.stages:
+            name = spec.name
+            p = self.procs.get(name)    # None: held in this process
             cnc = self.cncs[name]
             hb = cnc.last_heartbeat
             row = {
                 "stage": name,
-                "alive": p.is_alive(),
+                "alive": p.is_alive() if p is not None else True,
                 "signal": cnc.signal,
                 "heartbeat_age_ms": (now - hb) / 1e6 if hb else None,
                 "frags_in": cnc.diag(Stage.DIAG_FRAGS_IN),
@@ -580,21 +750,31 @@ class TopologyHandle:
             }
             reg = self.met_views.get(name, (None, None))[0]
             row.update(fm.latency_row(reg))
+            row["loop"] = fm.loop_row([reg])
             out.append(row)
         return out
 
     def format_monitor(self) -> str:
+        """The table, with what of its loop time since boot each tile
+        worked (busy%) and was held by the tile behind it (backp%),
+        from the shm segments.  The limiting tile is the busiest one;
+        the tiles in front of it show backp%, those behind it poll."""
         rows = self.snapshot()
         hdr = (
             f"{'stage':<12}{'alive':<7}{'hb_ms':>8}{'in':>10}{'out':>10}"
+            f"{'busy%':>7}{'backp%':>7}"
             f"{'ovrn':>7}{'bkp':>7}{'p50 lat':>10}{'p99 lat':>10}"
         )
         lines = [hdr]
         for r in rows:
             hb = f"{r['heartbeat_age_ms']:.1f}" if r["heartbeat_age_ms"] else "-"
+            sh = r["loop"] and fm.loop_shares(r["loop"])
+            busy, backp = ((f"{sh['busy_pct']:.0f}", f"{sh['backp_pct']:.0f}")
+                           if sh else ("-", "-"))
             lines.append(
                 f"{r['stage']:<12}{str(r['alive']):<7}{hb:>8}"
                 f"{r['frags_in']:>10}{r['frags_out']:>10}"
+                f"{busy:>7}{backp:>7}"
                 f"{r['overrun']:>7}{r['backpressure']:>7}"
                 f"{fm.format_latency_ms(r.get('lat_p50_ms')):>10}"
                 f"{fm.format_latency_ms(r.get('lat_p99_ms')):>10}"
@@ -602,15 +782,32 @@ class TopologyHandle:
         return "\n".join(lines)
 
 
-def launch(topo: Topology, *, namespace: str | None = None) -> TopologyHandle:
+def _with_uid(v, uid: str):
+    return v.format(uid=uid) if isinstance(v, str) and "{uid}" in v else v
+
+
+def launch(topo: Topology, *, namespace: str | None = None,
+           held: tuple[str, ...] = ()) -> TopologyHandle:
     """`namespace` prefixes every segment name this topology creates
     (links, cnc, metrics): N simultaneous topologies in one box — e.g.
     one per validator of a cluster — stay disjoint in /dev/shm, and a
-    supervisor FAIL/close reclaims only its own validator's segments."""
+    supervisor FAIL/close reclaims only its own validator's segments.
+
+    `held`: stages that are NOT spawned.  The caller runs them in its
+    own process — the process that holds the chip and traces it, or
+    the one whose generator a check counts — over the same shm links:
+    it builds each with the builder a child would use, from
+    `handle.links` and `handle.cncs[name]`, and hands it to
+    `handle.hold`.  Their segments exist like any stage's, so monitor,
+    scrape and `counters()` see one topology."""
     # fail fast IN THE PARENT: a mis-wired graph raises a readable
     # TopologyError here, before any shm segment or child process exists
     # (the fd_topob contract — validation precedes boot)
     topo.validate()
+    unknown = set(held) - {s.name for s in topo.stages}
+    if unknown:
+        raise ValueError(f"held stages {sorted(unknown)} are not in the "
+                         f"topology")
     ctx = mp.get_context("spawn")  # fresh interpreters: see module docstring
     uid = shm.fresh_uid(namespace)
     links: dict[str, shm.ShmLink] = {}
@@ -644,15 +841,29 @@ def launch(topo: Topology, *, namespace: str | None = None) -> TopologyHandle:
         )
         met_shms[spec.name] = ms
         met_views[spec.name] = fm.metrics_segment_init(ms.buf, schema)
-    procs: dict[str, mp.Process] = {}
-    for spec in topo.stages:
-        p = ctx.Process(
-            target=_stage_main, args=(spec, link_names, uid), name=spec.name
-        )
-        p.daemon = True
-        p.start()
-        procs[spec.name] = p
-        _log.info(f"spawned stage '{spec.name}' pid={p.pid}")
+    owned = [_with_uid(o, uid) for o in topo.owned]
+    handle = TopologyHandle(topo, uid, links, cncs, cnc_shms, {},
+                            met_shms, met_views, link_names, owned)
+    try:
+        for o in owned:
+            if "/" in o:
+                os.makedirs(o, exist_ok=True)
+        for spec in topo.stages:
+            run_spec = handle.run_specs[spec.name] = replace(spec, kwargs={
+                k: _with_uid(v, uid) for k, v in spec.kwargs.items()})
+            if spec.name in held:
+                continue
+            p = ctx.Process(
+                target=_stage_main, args=(run_spec, link_names, uid),
+                kwargs={"parent_pid": os.getpid()}, name=spec.name,
+            )
+            p.daemon = True
+            p.start()
+            handle.procs[spec.name] = p
+            _log.info(f"spawned stage '{spec.name}' pid={p.pid}")
+    except BaseException:
+        handle.close()      # no half-launched run is left behind
+        raise
     # advertise the run so `fdtpu monitor` / `fdtpu ready` / `fdtpu
     # metrics` can attach from another process (runtime/monitor.py);
     # the metrics entries carry the schema so an uninvolved scraper can
@@ -677,5 +888,4 @@ def launch(topo: Topology, *, namespace: str | None = None) -> TopologyHandle:
             if s.shard is not None
         },
     )
-    return TopologyHandle(topo, uid, links, cncs, cnc_shms, procs,
-                          met_shms, met_views, link_names)
+    return handle

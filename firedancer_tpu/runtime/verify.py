@@ -810,6 +810,10 @@ class VerifyStage(Stage):
             accumulate((sigs, msg, signers, None, packed), payload, row[5])
         return n_done, ts_done
 
+    def native_lanes(self) -> dict[str, bool]:
+        return dict(super().native_lanes(),
+                    verify=self._sweep_client is not None)
+
     def before_credit(self) -> None:
         # The batch-deadline clock is stamped HERE, not in after_frag
         # (the per-frag path must stay free of wall-clock syscalls,

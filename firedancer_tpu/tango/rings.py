@@ -289,6 +289,9 @@ CNC_SIG_BOOT = 0
 CNC_SIG_RUN = 1
 CNC_SIG_HALT = 2
 CNC_SIG_FAIL = 3
+# the supervisor asks a running stage to put its counters out to shm
+# now (Stage.run does, and answers RUN): TopologyHandle.counters
+CNC_SIG_SYNC = 4
 
 
 class Cnc:

@@ -43,6 +43,25 @@ class PackConfig:
     max_txn_per_microblock: int = 31
     min_pending: int = 8
     microblock_deadline_ms: float = 2.0
+    # a pool without room for one more burst leaves its txn input
+    # unpolled (backpressure up to the source) instead of evicting its
+    # cheapest transaction for the newcomer (runtime/pack_stage.py; the
+    # process topology reads it)
+    hold_when_full: bool = False
+
+
+@dataclass
+class LinksConfig:
+    # ring depths of the process topology's links, in frags, powers of
+    # two (models/leader_topo.py; the ring in front of verify is
+    # verify.receive_buffer_depth, and the cooperative pipeline sizes
+    # every ring but shred_store from that)
+    verify_pack: int = 1024
+    pack_bank: int = 256
+    bank_poh: int = 256
+    bank_done: int = 256
+    poh_shred: int = 1024
+    shred_store: int = 4096
 
 
 @dataclass
@@ -50,6 +69,9 @@ class PohConfig:
     hashes_per_tick: int = 64
     ticks_per_slot: int = 8
     hashes_per_iter: int = 16
+    # the wall-clock slot cadence the process topology runs under
+    # (runtime/slot_clock.py); 0: free-running, slots seal on drain
+    slot_ms: float = 0.0
 
 
 @dataclass
@@ -95,6 +117,7 @@ class Config:
     layout: LayoutConfig = field(default_factory=LayoutConfig)
     verify: VerifyConfig = field(default_factory=VerifyConfig)
     pack: PackConfig = field(default_factory=PackConfig)
+    links: LinksConfig = field(default_factory=LinksConfig)
     poh: PohConfig = field(default_factory=PohConfig)
     shred: ShredConfig = field(default_factory=ShredConfig)
     net: NetConfig = field(default_factory=NetConfig)
@@ -159,8 +182,13 @@ def _validate(cfg: Config) -> None:
         raise ConfigError("verify.batch must be a power of 2")
     if cfg.verify.devices < 1 or cfg.verify.batch % cfg.verify.devices:
         raise ConfigError("verify.devices must be >= 1 and divide verify.batch")
-    if cfg.poh.hashes_per_tick < 1 or cfg.poh.ticks_per_slot < 1:
+    if cfg.poh.hashes_per_tick < 1 or cfg.poh.ticks_per_slot < 1 \
+            or cfg.poh.slot_ms < 0:
         raise ConfigError("poh cadence must be positive")
+    for f in dataclasses.fields(cfg.links):
+        d = getattr(cfg.links, f.name)
+        if d < 1 or d & (d - 1):
+            raise ConfigError(f"links.{f.name} must be a power of 2")
     if cfg.genesis.n_voters < 0 or not 0 <= cfg.genesis.slot_hashes <= 512:
         raise ConfigError("genesis.n_voters must be >= 0 and "
                           "genesis.slot_hashes in [0, 512]")

@@ -506,10 +506,13 @@ VERIFY_FAIL_ELEMS = "verify_fail_elems"
 KERNEL_FOLD_LANES = "kernel_fold_lanes"
 # The thread's ledger (runtime/stage.py run_once): every call of a
 # stage's loop is charged whole to one regime — it did work, it found
-# nothing to do, or (taken out of the call it ran in) housekeeping.
-# ns and, for the first two, calls
+# nothing to do, it could do nothing because a ring behind it was full
+# while a ring in front held a frag (backpressure), or (taken out of the
+# call it ran in) housekeeping.  ns and, for the first three, calls
 LOOP_COUNTERS = ("loop_work_ns", "loop_work_n", "loop_poll_ns",
-                 "loop_poll_n", "loop_hk_ns")
+                 "loop_poll_n", "loop_backp_ns", "loop_backp_n",
+                 "loop_hk_ns")
+LOOP_REGIMES = ("work_ns", "poll_ns", "backp_ns", "hk_ns")
 # When the chip had nothing of a verify stage's to run (runtime/verify.py
 # _phase_end): from the loop's first sight of a finished batch with no
 # other in flight to the end of the next dispatch's launch, and of that
@@ -520,23 +523,36 @@ CHIP_EMPTY_COUNTERS = ("chip_empty_ns", "chip_empty_n",
                        "chip_empty_call_ns", "chip_empty_away_ns")
 
 
-def loop_busy_pct(work_ns: int, poll_ns: int, hk_ns: int) -> float | None:
-    """Share of a stage's loop time that did work, in %, from deltas of
-    its three regime counters; None where the ledger saw no time."""
-    total = work_ns + poll_ns + hk_ns
-    return 100.0 * work_ns / total if total > 0 else None
-
-
-def loop_row(regs: list) -> dict | None:
-    """{"work_ns", "poll_ns", "hk_ns"} summed over the shard registries
-    of one logical stage (the monitor's busy% is the share of the first
-    in a sample's delta of the three); None where no registry has the
-    ledger."""
-    regs = [r for r in regs if r is not None and "loop_work_ns" in r._off]
-    if not regs:
+def loop_shares(loop: dict, since: dict | None = None) -> dict | None:
+    """{"busy_pct", "backp_pct", "poll_pct"}: what of a stage's loop
+    time went to calls that did work, to calls held by backpressure and
+    to empty polls, from a `loop_row` (less an earlier one: the
+    monitor's two samples); None where the ledger saw no time."""
+    d = {k: loop[k] - (since[k] if since else 0) for k in LOOP_REGIMES}
+    total = sum(d.values())
+    if total <= 0:
         return None
-    return {k: sum(r.get(f"loop_{k}") for r in regs)
-            for k in ("work_ns", "poll_ns", "hk_ns")}
+    return {"busy_pct": 100.0 * d["work_ns"] / total,
+            "backp_pct": 100.0 * d["backp_ns"] / total,
+            "poll_pct": 100.0 * d["poll_ns"] / total}
+
+
+def loop_row(srcs: list) -> dict | None:
+    """LOOP_REGIMES summed over the shards of one logical stage, each
+    its registry (the monitor) or a dict of its metrics (slotreport):
+    the monitor's busy% and backp% are shares of a sample's delta of
+    the four.  None where no source has the ledger; a source from
+    before the backpressure regime reads 0 there."""
+    rows = []
+    for src in srcs:
+        if isinstance(src, MetricsRegistry):
+            src = {n: src.get(n) for n in LOOP_COUNTERS if n in src._off}
+        if src and "loop_work_ns" in src:
+            rows.append(src)
+    if not rows:
+        return None
+    return {k: sum(int(r.get(f"loop_{k}") or 0) for r in rows)
+            for k in LOOP_REGIMES}
 
 
 def chip_empty_row(src) -> dict | None:
@@ -906,12 +922,23 @@ def stage_schema() -> MetricsSchema:
                  " published a device batch, ticked, closed a slot")
         .counter("loop_work_n", "run_once calls that did work")
         .counter("loop_poll_ns",
-                 "ns inside run_once calls that found nothing to do (a"
-                 " credit-gated return counts here)")
+                 "ns inside run_once calls that found nothing to do")
         .counter("loop_poll_n", "run_once calls that found nothing to do")
+        .counter("loop_backp_ns",
+                 "ns inside run_once calls that did nothing because a"
+                 " ring behind the stage had no credits (or the stage no"
+                 " room) while a ring in front held a frag")
+        .counter("loop_backp_n", "run_once calls held by backpressure")
         .counter("loop_hk_ns",
                  "ns inside housekeeping passes, taken out of the call"
                  " they ran in")
+        .gauge("native_lanes",
+               "native lanes of this stage that are armed (rings, a C"
+               " sweep client, the native pack / funk), as of the last"
+               " time a supervisor asked (Stage.sync_counters)")
+        .gauge("native_lanes_off",
+               "native lanes of this stage that are NOT armed: it runs"
+               " them in Python")
         .histogram(
             "frag_latency_ns",
             exp_buckets(1e3, 1e10, 24),
@@ -935,6 +962,29 @@ NSWEEP_PHASE_BUCKETS = exp_buckets(1e2, 1e9, 22)
 # The sweep-phase histogram per phase, in crossing order.  The names
 # double as the slotreport "sweep_phases" keys.
 NSWEEP_PHASES = ("drain", "callback", "apply", "publish")
+
+
+def sweep_counters(reg: "MetricsRegistry") -> dict:
+    """Time inside a stage's non-empty native crossings, from the words
+    C writes into its registry: {"sweep_busy_ns": the sums of the four
+    nsweep_*_ns phase histograms, "sweep_crossings": nsweep_crossings};
+    {} where the schema lacks the native-sweep block."""
+    if "nsweep_crossings" not in reg._off:
+        return {}
+    return {"sweep_busy_ns": int(sum(reg.hist_sum(f"nsweep_{ph}_ns")
+                                     for ph in NSWEEP_PHASES)),
+            "sweep_crossings": reg.get("nsweep_crossings")}
+
+
+def registry_counters(reg: "MetricsRegistry") -> dict:
+    """A stage's counters and gauges as its registry holds them — what
+    `Metrics.counters` is to a reader in the stage's own process, for
+    one in another (the registry lives in shm) — with `sweep_counters`
+    beside them."""
+    out = {d.name: reg.get(d.name) for d in reg.schema.defs
+           if d.kind != HISTOGRAM}
+    out.update(sweep_counters(reg))
+    return out
 
 
 def add_native_sweep_schema(s: MetricsSchema) -> MetricsSchema:

@@ -31,7 +31,7 @@ from firedancer_tpu.tango import shm
 from firedancer_tpu.utils import metrics as fm
 
 STEP = 1_000_000        # one clock read: 1 ms of scripted time
-REGIMES = ("loop_work_ns", "loop_poll_ns", "loop_hk_ns")
+REGIMES = ("loop_work_ns", "loop_poll_ns", "loop_backp_ns", "loop_hk_ns")
 CHIP = list(fm.CHIP_EMPTY_COUNTERS)
 # the verify lanes: "mesh" is the native lane in front of two (virtual)
 # devices
@@ -179,10 +179,13 @@ def test_a_call_that_consumes_is_work_and_an_empty_one_a_poll(intake, clock):
 
 
 @pytest.mark.parametrize("intake", INTAKES[:2])
-def test_a_credit_gated_return_is_a_poll(intake, clock):
+def test_a_credit_gated_return_with_a_frag_in_front_is_backpressure(
+        intake, clock):
     """A stage that may not consume what it cannot forward, with no
-    credit downstream: the frags stay in the ring, the call is a poll,
-    `backpressure_stall` counts it."""
+    credit downstream: the frags stay in the ring in front, and the
+    call is charged to backpressure, not to the polls —
+    `backpressure_stall` counts it — on the two clock reads a call
+    always makes."""
     with _staged(intake, out_depth=4) as (st, prod, cons, frames):
         _no_housekeeping(st)
         st.require_credit = True
@@ -193,16 +196,96 @@ def test_a_credit_gated_return_is_a_poll(intake, clock):
             _call(st, trail)
         assert st.metrics.get("frags_in") == 4      # the out ring is full
         stalls = st.metrics.get("backpressure_stall")
+        reads = clock.reads
         d = _call(st, trail)
+        assert clock.reads - reads == 2             # no clock read added
         assert st.metrics.get("frags_in") == 4
-        assert (d["loop_poll_n"], d["loop_work_n"]) == (1, 0)
-        assert d["loop_poll_ns"] == trail[-1][1] - trail[-1][0]
+        assert (d["loop_backp_n"], d["loop_poll_n"], d["loop_work_n"]) \
+            == (1, 0, 0)
+        assert d["loop_backp_ns"] == trail[-1][1] - trail[-1][0] > 0
+        assert d["loop_poll_ns"] == d["loop_work_ns"] == 0
         if intake == "python_burst":    # the native path is cut to 0 frags
             assert st.metrics.get("backpressure_stall") == stalls + 1
         _drained(cons)                                  # credits again
         cons.publish_progress()
         d = _call(st, trail)
-        assert st.metrics.get("frags_in") > 4 and d["loop_work_n"] == 1
+        assert st.metrics.get("frags_in") > 4
+        assert (d["loop_work_n"], d["loop_backp_n"]) == (1, 0)
+
+
+@pytest.mark.parametrize("intake", INTAKES[:2])
+def test_a_blocked_stage_with_nothing_in_front_polls(intake, clock):
+    """The same stage, out ring full, once the ring in front has run
+    dry: it is starved as much as blocked, and that is a poll."""
+    with _staged(intake, out_depth=4) as (st, prod, cons, frames):
+        _no_housekeeping(st)
+        st.require_credit = True
+        trail: list = []
+        for i, f in enumerate(frames[:4]):
+            assert prod.try_publish(f, sig=i, tsorig=0)
+        for _ in range(4):
+            _call(st, trail)
+        assert st.metrics.get("frags_in") == 4      # out full, in empty
+        d = _call(st, trail)
+        assert (d["loop_poll_n"], d["loop_backp_n"], d["loop_work_n"]) \
+            == (1, 0, 0)
+        assert d["loop_poll_ns"] == trail[-1][1] - trail[-1][0] > 0
+        # an idle stage with credits: a poll too
+        _drained(cons)
+        cons.publish_progress()
+        d = _call(st, trail)
+        assert (d["loop_poll_n"], d["loop_backp_n"]) == (1, 0)
+
+
+@pytest.mark.parametrize("intake", INTAKES[:2])
+def test_a_stage_without_room_is_held_like_one_without_credits(intake,
+                                                               clock):
+    """`intake_room`, a stage's own bound (pack's pool): 0 leaves the
+    ring in front unpolled and charges backpressure; a number under
+    the burst caps the sweep; None is the burst."""
+    with _staged(intake) as (st, prod, cons, frames):
+        _no_housekeeping(st)
+        trail: list = []
+        for i, f in enumerate(frames):
+            assert prod.try_publish(f, sig=i, tsorig=0)
+        st.intake_room = 0
+        d = _call(st, trail)
+        assert st.metrics.get("frags_in") == 0
+        assert (d["loop_backp_n"], d["loop_poll_n"]) == (1, 0)
+        st.intake_room = 3
+        d = _call(st, trail)
+        assert st.metrics.get("frags_in") == 3 and d["loop_work_n"] == 1
+        st.intake_room = None
+        _call(st, trail)
+        assert st.metrics.get("frags_in") == len(frames)
+        assert _drained(cons) == len(frames)
+
+
+@pytest.mark.parametrize("intake", INTAKES[:2])
+def test_the_four_regimes_add_up_to_the_call_times(intake, clock):
+    """Work, backpressure, polls and housekeeping: every call whole in
+    one of them (housekeeping taken out of the call it ran in), so the
+    four sums are the calls' entry-to-exit times."""
+    with _staged(intake, out_depth=4) as (st, prod, cons, frames):
+        st.require_credit = True
+        st._next_housekeeping = 3       # a pass falls among the calls
+        trail: list = []
+        total = {k: 0 for k in fm.LOOP_COUNTERS}
+        for step in range(12):
+            if step == 1:
+                for i, f in enumerate(frames):
+                    assert prod.try_publish(f, sig=i, tsorig=0)
+            if step == 8:
+                _drained(cons)
+                cons.publish_progress()
+            for k, v in _call(st, trail, clock, away_ns=7 * STEP).items():
+                total[k] += v
+        assert all(total[k] > 0 for k in (
+            "loop_work_ns", "loop_backp_ns", "loop_poll_ns", "loop_hk_ns"))
+        assert sum(total[k] for k in total if k.endswith("_ns")) \
+            == sum(b - a for a, b in trail)
+        assert total["loop_work_n"] + total["loop_backp_n"] \
+            + total["loop_poll_n"] == len(trail)
 
 
 @pytest.mark.parametrize("intake", INTAKES)
@@ -586,14 +669,18 @@ def test_the_pump_that_dispatches_or_reaps_is_a_working_call(lane, pool,
 def test_the_monitors_busy_is_time_and_its_chip_line(clock):
     from firedancer_tpu.runtime import monitor as mon
 
-    assert fm.loop_busy_pct(30, 60, 10) == pytest.approx(30.0)
-    assert fm.loop_busy_pct(0, 0, 0) is None
+    ns = dict(work_ns=30, poll_ns=40, backp_ns=20, hk_ns=10)
+    assert fm.loop_shares(ns) == {"busy_pct": pytest.approx(30.0),
+                                  "backp_pct": pytest.approx(20.0),
+                                  "poll_pct": pytest.approx(40.0)}
+    assert fm.loop_shares(ns, ns) is None
     st = VerifyStage("v0", batch=16, max_msg_len=256, native_client=False)
     st.metrics.attach(fm.MetricsRegistry(st.metrics.schema))
 
-    def row(work, poll, hk, empty, call, away):
+    def row(work, poll, hk, empty, call, away, backp=0):
         c = st.metrics.counters
         c.update(loop_work_ns=work, loop_poll_ns=poll, loop_hk_ns=hk,
+                 loop_backp_ns=backp,
                  chip_empty_ns=empty, chip_empty_n=empty // 10**6,
                  chip_empty_call_ns=call, chip_empty_away_ns=away)
         st.metrics.flush()
@@ -605,14 +692,16 @@ def test_the_monitors_busy_is_time_and_its_chip_line(clock):
                 "chip_empty": fm.chip_empty_row(reg)}
 
     a = row(10**8, 10**8, 0, 10**8, 10**7, 10**7)
-    b = row(4 * 10**8, 5 * 10**8, 2 * 10**8, 8 * 10**8, 8 * 10**7,
-            36 * 10**7)
-    assert b["loop"] == {"work_ns": 4 * 10**8, "poll_ns": 5 * 10**8,
-                         "hk_ns": 2 * 10**8}
+    b = row(4 * 10**8, 2 * 10**8, 2 * 10**8, 8 * 10**8, 8 * 10**7,
+            36 * 10**7, backp=3 * 10**8)
+    assert b["loop"] == {"work_ns": 4 * 10**8, "poll_ns": 2 * 10**8,
+                         "backp_ns": 3 * 10**8, "hk_ns": 2 * 10**8}
     text = mon.MonitorSession.render([b], [a], 1.0)
+    assert text.splitlines()[0].split()[5:7] == ["busy%", "backp%"]
     line = text.splitlines()[2]
-    # work 3e8 of (3 + 4 + 2)e8 between the samples: 33 %, not frags a pass
-    assert line.split()[5] == "33"
+    # work 3e8 and backpressure 3e8 of (3 + 1 + 3 + 2)e8 between the
+    # samples: 33 % each, time and not frags a pass
+    assert line.split()[5:7] == ["33", "33"]
     assert "  chip_empty=70.0% (away=50% call=10%)" in text
     first = mon.MonitorSession.render([a], None, 1.0)
     assert "chip_empty=- (away=- call=-)" in first
@@ -629,6 +718,11 @@ def test_the_monitors_busy_is_time_and_its_chip_line(clock):
         "ns": 8 * 10**8, "n": 800, "call_ns": 8 * 10**7,
         "away_ns": 36 * 10**7, "away_pct": pytest.approx(45.0),
         "call_pct": pytest.approx(10.0)}
+    # and the ledger's shares since boot, backpressure among them
+    assert block["loop"]["backp_ns"] == 3 * 10**8
+    assert block["loop"]["busy_pct"] == pytest.approx(400 / 11)
+    assert block["loop"]["backp_pct"] == pytest.approx(300 / 11)
+    assert block["loop"]["poll_pct"] == pytest.approx(200 / 11)
     plain = fm.flight_dump_obj("t", {"s": (Stage("s").metrics.registry,
                                            None)})
     assert "chip_empty" not in slot_report.build_report(plain)["stages"] \
