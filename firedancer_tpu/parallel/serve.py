@@ -689,6 +689,7 @@ class ShardedVerifyStage(VerifyStage):
         through VerifyStage._deadline_close, which asks first."""
         accs = self._shards
         if not self._window_has_room() and any(a.elems for a in accs):
+            self._waits_for_place(why)
             self._drain(block=True)
         n_elems = sum(len(a.elems) for a in accs)
         if n_elems == 0:

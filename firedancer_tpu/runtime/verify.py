@@ -29,15 +29,15 @@ bool mask, fetched once at the reap, which counts the passes itself.
 How deep the window is (one test, `_window_has_room`, on the native lane,
 the Python lane and the sharded stage alike): WINDOW_DEPTH, two — one
 batch running and one queued behind it.  The second place is for FULL
-batches, and for the batch right behind a full one that was itself
-queued: it exists to keep the device back to back, which matters only
+batches, and for a batch that is not full only while the thread LEADS
+the chip: it exists to keep the device back to back, which matters only
 when the device is what limits, and then the batches fill faster than
 the device runs them.  A batch dispatched behind another waits a whole
 program length on the device's queue, so a batch that is not full is
-not queued behind any other batch (next paragraph): it would run no
-sooner than if it had stayed open, and what arrived meanwhile would
-wait for the batch after.  `max_inflight` can only narrow the window
-(1: one batch at a time).
+not queued behind any other batch unless the stage has seen that the
+device limits (next paragraph): it would run no sooner than if it had
+stayed open, and what arrived meanwhile would wait for the batch after.
+`max_inflight` can only narrow the window (1: one batch at a time).
 
 When a batch closes (one rule, `_past_deadline` over `_window_open`,
 asked in `_deadline_close` on the native and the Python lane alike):
@@ -49,9 +49,11 @@ asked in `_deadline_close` on the native and the Python lane alike):
     waits ahead of it, AND either
       (a) nothing is in flight (it would run now) and the intake is not
           backlogged, or
-      (b) the window has room behind a batch that closed full behind a
-          running one (the stage is saturated: the queued program
-          length is the slack that rides out the thread's hiccups).
+      (b) the window has room and, since the chip last ran dry, a full
+          batch had to wait for its place in it (the thread leads the
+          chip: the queued program length is the slack that rides out
+          the thread's hiccups), and no batch that was not full has
+          taken that slack since.
     While any other batch is in flight an open batch past its deadline
     stays open and keeps taking frags, until it fills (the line above)
     or the pump that reaps the running batch comes through the rule
@@ -71,25 +73,44 @@ program takes to run, so nothing is in flight when the deadline passes
 — a partial dispatch is a whole dispatch's cost for part of its lanes,
 and under a backlog the ring in front is where the latency is anyway.
 Where the device limits — the thread fills 1,024 lanes in under a
-program length, so full batches go out BEHIND running ones, which is
-the evidence (b) asks for — a queued partial batch costs nothing the
-device was not going to wait for, and is slack.  A full batch that
-went out alone is no such evidence: under a backlog every batch of a
-thread-bound stage closes full.
+program length, so a batch fills while two are still in flight, finds no
+place and waits sealed for the next reap, which is the evidence (b)
+asks for (`_waits_for_place`; `batch_sealed_wait_ns` is the same fact
+on the clock) — a queued partial batch costs nothing the device was not
+going to wait for, and is slack.  A full batch that went out at once
+behind a running one is no such evidence: a thread that TRAILS the chip
+does that too, whenever a part-empty batch is still running, and a rule
+that took it for evidence would make that part-empty batch itself, every
+other dispatch, for ever.  Nor is a full batch that went out alone:
+under a backlog every batch of a thread-bound stage closes full.  The
+evidence lasts until the chip runs dry (a reap leaves nothing in
+flight, where `chip_empty_ns` starts: after a hiccup one full batch
+goes out alone, and the next that has to wait restores it) or the slack
+is spent (a batch that is not full is dispatched): the stage keeps at
+most one part-empty batch queued for each full batch that waited, so a
+thread that falls behind the chip finds its way back to one dispatch
+per full batch.
 
-So a saturated stage keeps two in flight, a paced one keeps one and
-seals at the reap, and a thread-bound one under a backlog dispatches
-once per full batch, from what the stage itself observes — whether the
-batches fill behind running ones, whether the device has work, whether
-the ring in front ran dry — with no setting.
+So a stage whose thread leads the chip keeps two in flight, a paced one
+keeps one and seals at the reap, and one whose thread trails under a
+backlog dispatches once per full batch — with the chip running or not
+when the batch fills — from what the stage itself observes: whether a
+full batch had to wait for a place, whether the device has work,
+whether the ring in front ran dry.  No setting, no clock, no estimate
+of the program's length.
 `batch_close_full + batch_close_deadline + batch_close_window == batches`
 says which of the three closed each dispatched batch,
 `batch_queued_behind` how many were dispatched while another was in
 flight (the second place used: full batches, the batch sealed under
-(b), and what flush() sends), and `batch_held_backlogged` how many
+(b), and what flush() sends; over `batches` ~1 where the thread leads
+the chip, between 0 and ~1 where it trails — a full batch goes out
+behind the running one or after it by how the fill time compares with
+the program's — and 0 where the stage is paced or shares its thread
+with slower stages), and `batch_held_backlogged` how many
 were kept open past their deadline for the backlog alone (over
-`batches`: ~1 in a thread-bound flood, ~0 where the stage is paced or
-the device limits).  Two counters say which lanes carried
+`batches`: ~1 in a thread-bound flood, the chip's own speed
+notwithstanding; ~0 where the stage is paced or the thread leads).
+Two counters say which lanes carried
 no verdict anyone used: `batch_fit_pad_lanes`, the lanes left empty by
 batches sealed because the next transaction's signatures did not fit
 (such a batch closed full: it is as full as its transactions allow), and
@@ -473,9 +494,12 @@ class VerifyStage(Stage):
         # the open batch (named by its C-side open stamp) that was kept
         # open past its deadline, and its _HELD_* marks
         self._nv_held = (0, 0)
-        # the newest dispatched batch closed full behind a running one:
-        # the stage's evidence that the device limits (_window_open)
-        self._last_full_behind = False
+        # since the chip last ran dry (a reap left nothing in flight) a
+        # full batch had to wait for its place in the window, and no
+        # batch that was not full has been dispatched since: the stage's
+        # evidence that its thread leads the chip (_waits_for_place sets
+        # it, the reap and _count_dispatch end it; _window_open reads it)
+        self._full_waited = False
         want_native = (native_client if native_client is not None
                        else type(self) is VerifyStage)
         if want_native:
@@ -583,23 +607,26 @@ class VerifyStage(Stage):
             .counter("batch_close_deadline",
                      "batches sealed past their deadline with nothing in"
                      " flight (and no backlog in front, or none any more),"
-                     " or with room behind a full batch that was itself"
-                     " queued behind one (flush() counts here)")
+                     " or with room in the window after a full batch had"
+                     " to wait for its place (flush() counts here)")
             .counter("batch_close_window",
                      "batches held open past their deadline by a batch in"
-                     " flight that was not full or went out alone (or by"
-                     " a full window), sealed at a reap")
+                     " flight while no full batch had had to wait for a"
+                     " place (or by a full window), sealed at a reap")
             .counter(fm.BATCH_QUEUED_BEHIND,
                      "batches dispatched while another was in flight (the"
                      " window's second place: full batches, the batch"
-                     " sealed behind a full one that was itself queued,"
-                     " and flush())")
+                     " sealed past its deadline after a full one had to"
+                     " wait for its place, and flush()): over `batches`,"
+                     " ~1 where the thread leads the chip, 0 where the"
+                     " stage is paced")
             .counter(fm.BATCH_HELD_BACKLOGGED,
                      "batches kept open past their deadline with nothing"
                      " in flight because the intake was backlogged (the"
                      " last sweep took its whole burst), once a batch:"
-                     " over `batches`, ~1 in a thread-bound flood, ~0"
-                     " where the stage is paced or the device limits")
+                     " over `batches`, ~1 in a thread-bound flood (with"
+                     " the chip faster than the thread or not), ~0 where"
+                     " the stage is paced or the thread leads the chip")
             # lanes that carried no verdict anyone used: those a batch
             # sealed for want of room left empty, and those of the
             # transactions that failed whole (one bad signature fails
@@ -870,24 +897,37 @@ class VerifyStage(Stage):
         loops and the sharded stage's step ask here."""
         return len(self._flying()) < self.max_inflight
 
+    def _waits_for_place(self, close: int) -> None:
+        """A lane's submit loop left a sealed batch behind for want of
+        room (the native pump and the Python lane's), or the sharded
+        stage is about to block on the head for it.  A FULL one is the
+        evidence clause (b) reads (module docstring); what flush()
+        seals and parks says nothing."""
+        if close == CLOSE_FULL:
+            self._full_waited = True
+
+    def _window_freed(self) -> None:
+        """A reap took its batch out of the window (both lanes' reaps
+        call here): one that leaves nothing in flight is the chip
+        running dry, which ends the evidence — on the window's own
+        state, so under the all-pass mask too, which stamps no
+        chip_empty."""
+        if not self._flying():
+            self._full_waited = False
+
     def _window_open(self) -> bool:
         """The window's half of the close rule (module docstring): a
         batch that is not full may take a place in it now.  No sealed
         batch waits ahead of it, and either nothing is in flight (it
-        would run now) or the window has room right behind a batch
-        that closed FULL BEHIND A RUNNING ONE: the thread filled a
-        whole batch in under a program length, the stage's own
-        evidence that the device limits.  It reads nothing but the
-        stage's own window.  Behind any other batch the second place
-        is not taken: queued there these elements would start no
-        sooner, and the batch would stop taking what arrives
-        meanwhile.  After a hiccup that lets the device run dry one
-        full batch goes out alone, and the next one restores the
-        evidence."""
+        would run now) or the window has room and the evidence of
+        clause (b) stands: _waits_for_place set it, and neither
+        _window_freed nor a dispatch that was not full
+        (_count_dispatch) has ended it since.  It reads nothing but
+        the stage's own window."""
         c = self._sweep_client
         if c.sealed_waiting() if c is not None else self._submit_queue:
             return False
-        return not self._flying() or (self._last_full_behind
+        return not self._flying() or (self._full_waited
                                       and self._window_has_room())
 
     def _past_deadline(self, held: int) -> tuple[int | None, int]:
@@ -900,9 +940,9 @@ class VerifyStage(Stage):
         but part empty, at a whole dispatch's cost to a thread that
         limits: it stays open until it fills, or until the first short
         sweep ends the backlog and it goes at the next pass through
-        here.  The place behind a full batch that went out behind a
-        running one is taken backlogged or not: where the device
-        limits, a queued partial batch is slack (module docstring).
+        here.  With a batch in flight, the place behind it is taken
+        backlogged or not, on the evidence _window_open asks for and on
+        nothing else (module docstring, "Why (a) and (b) differ").
         `batch_held_backlogged` counts a batch the first time it is
         kept open for the backlog alone."""
         if not self._window_open():
@@ -1147,10 +1187,14 @@ class VerifyStage(Stage):
                                   max_msg_len=self.max_msg_len)
 
     def _count_dispatch(self, n: int, close: int, occupancy: int) -> None:
-        """The books of one dispatched batch of `n` elements, on both
-        lanes.  Over a mesh of d devices, device i was dealt elements
-        i, i + d, ... below `n`: from the fill alone, no per-element
-        work."""
+        """The books of one dispatched batch of `n` elements, on every
+        lane (the sharded stage's step too).  Over a mesh of d devices,
+        device i was dealt elements i, i + d, ... below `n`: from the
+        fill alone, no per-element work.
+
+        A batch that was not full spends the evidence of clause (b):
+        it is the slack the evidence stood for, and only a full batch
+        that has to wait again (_waits_for_place) buys the next one."""
         m = self.metrics
         m.inc("batches", 1)
         m.inc(_CLOSE_COUNTERS[close])
@@ -1159,7 +1203,8 @@ class VerifyStage(Stage):
         m.observe("inflight_occupancy", occupancy)
         if occupancy > 1:
             m.inc(fm.BATCH_QUEUED_BEHIND)
-        self._last_full_behind = close == CLOSE_FULL and occupancy > 1
+        if close != CLOSE_FULL:
+            self._full_waited = False
         self.trace(fm.EV_BATCH_SUBMIT, n)
         d = self.mesh_devices
         for i in range(min(d, n) if d > 1 else 0):
@@ -1187,7 +1232,9 @@ class VerifyStage(Stage):
         (in order), publish reaped frames from the slot arenas, seal
         the open batch if its deadline has passed and the close rule
         lets it go (_deadline_close),
-        submit sealed slots into the in-flight window (in seal order).
+        submit sealed slots into the in-flight window (in seal order);
+        a sealed slot left behind for want of room is what the close
+        rule's clause (b) reads (_waits_for_place).
         Reap -> seal -> dispatch, so the batch the window held open
         goes in the pass that reaped its head; the publish goes before the
         dispatch because it is a tenth of a ms and the dispatch nearly
@@ -1199,8 +1246,10 @@ class VerifyStage(Stage):
         while self._window_has_room():
             got = c.take_sealed()
             if got is None:
-                break
+                return
             self._nv_dispatch(*got)
+        if c.sealed_waiting():      # and no room for it
+            self._waits_for_place(c.sealed_close())
 
     def _nv_dispatch(self, slot: int, n_elems: int, n_txn: int,
                      opened_ns: int, sealed_ns: int, close: int) -> None:
@@ -1234,6 +1283,7 @@ class VerifyStage(Stage):
             with self._span("verify.reap", life):
                 mask = self._mask_of(result)
                 self._nv_inflight.pop(0)
+                self._window_freed()
                 self.trace(fm.EV_BATCH_COMPLETE, n_elems)
                 views = c.slots[slot]
                 frames = views.frames[:n_txn]
@@ -1401,11 +1451,14 @@ class VerifyStage(Stage):
 
     def _pump_submits(self) -> None:
         """Move sealed batches into the device window, in seal order,
-        while the window has room."""
+        while the window has room; one left behind for want of room is
+        what the close rule's clause (b) reads (_waits_for_place)."""
         q = self._submit_queue
         while q and self._window_has_room():
             acc, cached = q.pop(0)
             self._submit(acc, cached)
+        if q:
+            self._waits_for_place(q[0][0].close)
 
     def _submit(self, acc: _Acc, cached: bool) -> None:
         n = len(acc.elems)
@@ -1512,6 +1565,7 @@ class VerifyStage(Stage):
         the frames of the transactions that passed."""
         mask = self._result_mask(head)
         self._inflight.pop(0)
+        self._window_freed()
         # a window slot freed: if the window is open to it now, seal
         # the batch that was held past its deadline, and submit it or
         # any parked ones before walking the mask (keeps the device fed

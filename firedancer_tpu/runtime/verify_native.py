@@ -301,6 +301,11 @@ class StageClient:
         order, so the oldest).  ONE u64 read."""
         return bool(self.meta[self._next_dispatch, 0] == SLOT_SEALED)
 
+    def sealed_close(self) -> int:
+        """What sealed the slot that is waiting (CLOSE_*; the reason
+        take_sealed will hand back).  ONE u64 read."""
+        return int(self.meta[self._next_dispatch, 6])
+
     def pump(self) -> None:
         self._lib.fdv_pump(self._h)
 

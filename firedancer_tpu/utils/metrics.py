@@ -480,15 +480,16 @@ BATCH_BLOCKING_PHASES = frozenset(
 BATCH_STALL_NS = 100_000_000
 # What closed a batch, by id: it filled; its deadline passed with the
 # in-flight window open to it (nothing in flight and no backlog in
-# front, or room behind a full batch that was itself queued behind a
-# running one); the window held it open past its deadline and a reap
-# sealed it.  The verify stage counts each dispatched batch in
+# front, or room in the window after a full batch had to wait for its
+# place: the thread leads the chip); the window held it open past its
+# deadline and a reap sealed it.  The verify stage counts each dispatched batch in
 # `batch_close_<why>`, so the three add up to `batches`.
 BATCH_CLOSES = ("full", "deadline", "window")
 BATCH_CLOSE_COUNTERS = tuple(f"batch_close_{c}" for c in BATCH_CLOSES)
 # dispatches made while another batch was in flight: how often the
-# window's second place (full batches, the batch sealed behind a full
-# one that was itself queued, and flush()) is used
+# window's second place (full batches, the batch sealed past its
+# deadline after a full one had to wait for its place, and flush()) is
+# used
 BATCH_QUEUED_BEHIND = "batch_queued_behind"
 # batches kept open past their deadline, with nothing in flight, because
 # the intake was backlogged (its last sweep took the whole burst): they

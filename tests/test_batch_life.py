@@ -533,12 +533,13 @@ def test_a_txn_that_does_not_fit_opens_the_next_batch(lane):
         assert len(lives) == 2 and lives[0] is not lives[1]
 
 
-# -- when a batch closes (ISSUE 25, ISSUE 32) ----------------------------------------
+# -- when a batch closes (ISSUE 25, ISSUE 32, ISSUE 40) ------------------------------
 #
 # Full (and then it may be dispatched behind a running batch), or past its
-# deadline AND the window open to it (nothing in flight, or room behind a
-# batch that closed full), or flush(): the same rule on every lane,
-# driven with a result that is not ready until the test says so.
+# deadline AND the window open to it (nothing in flight, or room in it
+# after a full batch had to wait for its place), or flush(): the same rule
+# on every lane, driven with a result that is not ready until the test
+# says so.
 
 CLOSE_COUNTERS = list(rv._CLOSE_COUNTERS)
 QUEUED_BEHIND = fm.BATCH_QUEUED_BEHIND
@@ -663,17 +664,32 @@ def _full_batch(st, prod, cons, pool, got, lo: int) -> int:
     return lo + 16
 
 
-def _held_batch(st, prod, cons, pool, got, lo: int, n: int = 3) -> int:
+def _held_batch(st, prod, cons, pool, got, lo: int, n: int = 3,
+                parked: bool = False) -> int:
     """n transactions, held open past their deadline by the window: a
-    batch that was not full is in flight, or the window is full."""
+    batch is in flight and no full one has had to wait for a place, or
+    the window is full (with a sealed batch `parked` behind it or not)."""
     before = st.metrics.get("batches")
     assert _in_flight(st) > 0
     _feed(prod, pool, lo, lo + n)
     _spin(st, cons, got)
     _past_deadline(st, cons, got)
     assert st.metrics.get("batches") == before and _open_elems(st) == n
-    assert not _sealed_waiting(st)
+    assert _sealed_waiting(st) == parked
     return lo + n
+
+
+def _parked_batch(st, prod, cons, pool, got, lo: int) -> int:
+    """One batch's worth (16) that fills while the window is full: it
+    waits sealed for its place, which is the evidence that the thread
+    leads the chip (ISSUE 40)."""
+    before = st.metrics.get("batches")
+    assert not st._window_has_room() and not _sealed_waiting(st)
+    _feed(prod, pool, lo, lo + 16)
+    _spin(st, cons, got)
+    assert st.metrics.get("batches") == before and _open_elems(st) == 0
+    assert _sealed_waiting(st) and st._full_waited
+    return lo + 16
 
 
 def _fill_window(st, prod, cons, pool, got) -> int:
@@ -779,8 +795,9 @@ def test_a_full_batch_takes_the_second_place_and_never_a_third(lane, pool):
         st.after_credit()
         assert [g.n for g in sent] == [3, 16, 16]
         assert _open_elems(st) == 4 and not _sealed_waiting(st)
-        # the next reap leaves a FULL batch in flight and room behind
-        # it: the stage is saturated, and the open batch goes behind it
+        # the next reap leaves room behind a batch that had to wait for
+        # its place: the thread leads the chip, and the open batch goes
+        # behind it
         sent[1].done = True
         st.after_credit()
         assert [g.n for g in sent] == [3, 16, 16, 4]
@@ -870,7 +887,7 @@ def test_a_backlogged_intake_keeps_the_open_batch_filling_past_the_reap(
         # device limits: the next one is held the same way
         n = _full_sweeps(st, prod, pool, n, 1)
         _overdue(st)
-        assert not st._last_full_behind and _open_elems(st) == 4
+        assert not st._full_waited and _open_elems(st) == 4
         assert st.metrics.get("batches") == 2 and _held(st) == 1
         sent[1].done = True
         st.after_credit()
@@ -924,28 +941,34 @@ def test_the_first_short_sweep_ends_the_backlog(lane, flying, pool):
 
 
 @pytest.mark.parametrize("backlogged", [True, False])
-@pytest.mark.parametrize("behind", [True, False])
+@pytest.mark.parametrize("waited", [True, False])
 @pytest.mark.parametrize("lane", LANES)
-def test_only_a_full_batch_queued_behind_a_running_one_is_evidence(
-        lane, behind, backlogged, pool):
+def test_only_a_full_batch_that_waited_for_its_place_is_evidence(
+        lane, waited, backlogged, pool):
     """Clause (b) of the close rule (ISSUE 32) on the evidence of ISSUE
-    36.  Behind a full batch that was itself dispatched behind a running
-    one the deadline seal queues a batch that is not full, backlogged or
-    not: the device limits, and a queued partial batch is slack.  Behind
-    a full batch that went out alone it does not: under a backlog every
-    batch of a thread-bound stage closes full."""
+    40.  After a full batch had to wait sealed for a place in the
+    window the deadline seal queues a batch that is not full behind the
+    running one, backlogged or not: the thread leads the chip, and a
+    queued partial batch is slack.  Behind a full batch that found room
+    at once behind a running one it does not — that is all the evidence
+    ISSUE 36 asked for, and a thread that trails the chip makes it too:
+    the batch stays open, and at the reap that empties the window the
+    backlog holds it."""
     with _gated_tile(lane) as (st, prod, cons, sent):
         st.burst = BURST
         got: list = []
-        if behind:
-            n = _fill_window(st, prod, cons, pool, got)   # 3, 16 behind it
+        n = _fill_window(st, prod, cons, pool, got)   # 3, 16 behind it
+        assert sent[-1].behind == 1 and not st._full_waited
+        if waited:
+            n = _parked_batch(st, prod, cons, pool, got, n)
             sent[0].done = True
-            st.after_credit()
-        else:
-            n = _full_batch(st, prod, cons, pool, got, 0)
+            st.after_credit()   # the one that waited takes the place
+            assert [g.n for g in sent] == [3, 16, 16]
+        sent[len(sent) - 2].done = True
+        st.after_credit()
         first = len(sent)
         assert _in_flight(st) == 1 and sent[-1].close == rv.CLOSE_FULL
-        assert st._last_full_behind == behind and st._window_has_room()
+        assert st._full_waited == waited and st._window_has_room()
         if backlogged:
             n = _full_sweeps(st, prod, pool, n, 1)
         else:
@@ -953,17 +976,18 @@ def test_only_a_full_batch_queued_behind_a_running_one_is_evidence(
         k = _open_elems(st)
         assert k == (4 if backlogged else 3)
         _overdue(st)
-        if behind:
+        if waited:
             assert st._window_open() is False     # both places taken now
             assert len(sent) == first + 1 and _open_elems(st) == 0
             assert (sent[-1].n, sent[-1].close, sent[-1].behind) \
                 == (k, rv.CLOSE_DEADLINE, 1)
             assert _held(st) == 0
+            assert not st._full_waited            # and the slack with them
         else:
             # held by the window, not by the backlog: not counted
             assert len(sent) == first and _open_elems(st) == k
             assert not _sealed_waiting(st) and _held(st) == 0
-            sent[0].done = True
+            sent[first - 1].done = True
             st.after_credit()      # the reap: nothing in flight
             if backlogged:
                 assert len(sent) == first and _held(st) == 1
@@ -977,6 +1001,201 @@ def test_only_a_full_batch_queued_behind_a_running_one_is_evidence(
         _spin(st, cons, got)
         assert got == list(pool[:n])
         assert sum(_closes(st)) == st.metrics.get("batches") == len(sent)
+
+
+# -- the second place is for a thread that leads the chip (ISSUE 40) -----------
+#
+# What the stage reads as "the device limits": a full batch found the
+# window full and waited sealed for its place.  A full batch that went out
+# at once behind a running one says nothing: a thread that trails the chip
+# sends those whenever a part-empty batch is still running, and under the
+# rule of ISSUE 36 each of them made the next part-empty batch.  Driven
+# with a batch of 8 under a burst of 4 where many batches have to fill.
+
+
+def _trailing_thread(st, prod, cons, pool, got, sent) -> int:
+    """A part-empty batch (3) running at its deadline and a full one
+    dispatched at once behind it: where a stage whose thread trails the
+    chip stands after any partial dispatch.  -> transactions fed."""
+    st.burst = BURST
+    n = _deadline_batch(st, prod, cons, pool, got, 0, 3)
+    n = _full_sweeps(st, prod, pool, n, st.batch // BURST)
+    st.after_credit()
+    assert [(g.n, g.close, g.behind) for g in sent] \
+        == [(3, rv.CLOSE_DEADLINE, 0), (st.batch, rv.CLOSE_FULL, 1)]
+    assert not st._full_waited
+    return n
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_a_thread_that_trails_the_chip_sends_full_batches_only(lane, pool):
+    """(i) A full batch dispatched at once behind a running partial one
+    is no evidence.  The next batch, past its deadline, stays open
+    behind whatever runs, is counted as held for the backlog once the
+    window empties, and goes full; and so for every batch after it,
+    whether the one before is still running when it fills or not: the
+    alternation of full and part-empty batches does not arise."""
+    with _gated_tile(lane, batch=8) as (st, prod, cons, sent):
+        got: list = []
+        n = _trailing_thread(st, prod, cons, pool, got, sent)
+        for k in range(9):
+            n = _full_sweeps(st, prod, pool, n, 1)      # half a batch
+            _overdue(st)                # held by the window, whatever flies
+            assert len(sent) == k + 2 and _open_elems(st) == 4
+            assert not st._full_waited and _held(st) == (k + 2) // 3
+            if k % 3 == 0:
+                # two in flight, and the chip outruns the thread: both
+                # come back before the batch has filled, and the backlog
+                # holds it from there
+                assert _in_flight(st) == 2
+                for g in sent:
+                    g.done = True
+                st.after_credit()
+                assert _in_flight(st) == 0 and len(sent) == k + 2
+                assert _held(st) == k // 3 + 1 and _open_elems(st) == 4
+            elif k % 3 == 2:
+                # two in flight, the head comes back: room behind a full
+                # batch that went out behind a running one, where ISSUE
+                # 36's rule queued this one part empty.  No full batch
+                # waited: it stays open
+                assert _in_flight(st) == 2
+                sent[-2].done = True
+                st.after_credit()
+                assert _in_flight(st) == 1 and st._window_has_room()
+                assert len(sent) == k + 2 and _open_elems(st) == 4
+            behind = _in_flight(st)
+            assert behind == (0, 1, 1)[k % 3]
+            n = _full_sweeps(st, prod, pool, n, 1)      # it fills
+            st.after_credit()
+            assert (sent[-1].n, sent[-1].close, sent[-1].behind) \
+                == (8, rv.CLOSE_FULL, behind)
+        assert _closes(st) == [10, 1, 0] and _held(st) == 3
+        assert not st._full_waited and _deepest(st) == 2
+        for g in sent:
+            g.done = True
+        st.flush()
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+        assert st.metrics.get("batch_elems") == n == 83
+
+
+@pytest.mark.parametrize("backlogged", [True, False])
+@pytest.mark.parametrize("lane", LANES)
+def test_a_thread_that_leads_the_chip_keeps_one_partial_batch_of_slack(
+        lane, backlogged, pool):
+    """(ii) A full batch that waited sealed with two in flight is the
+    evidence: the batch behind it is queued at the reap that leaves
+    room, backlogged or not, as under ISSUE 32.  That spends the slack:
+    the batch after it stays open until a full batch waits again."""
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        st.burst = BURST
+        got: list = []
+        n = _fill_window(st, prod, cons, pool, got)      # 3, 16 behind it
+        n = _parked_batch(st, prod, cons, pool, got, n)
+        if backlogged:
+            n = _full_sweeps(st, prod, pool, n, 1)
+        else:
+            n = _short_sweep(st, prod, pool, n, 3)
+        k = _open_elems(st)
+        _overdue(st)            # a sealed batch waits ahead of it: held
+        assert len(sent) == 2 and _open_elems(st) == k
+        sent[0].done = True
+        st.after_credit()       # its place goes to the batch that waited
+        assert [g.n for g in sent] == [3, 16, 16] and _open_elems(st) == k
+        sent[1].done = True
+        st.after_credit()       # room behind it: the open batch is queued
+        assert [(g.n, g.close, g.behind) for g in sent[3:]] \
+            == [(k, rv.CLOSE_WINDOW, 1)]
+        assert _held(st) == 0 and not st._full_waited
+        # the next one is held behind the same running batch ...
+        n = _short_sweep(st, prod, pool, n, 2)
+        _overdue(st)
+        assert len(sent) == 4 and _open_elems(st) == 2
+        sent[2].done = True
+        st.after_credit()
+        assert len(sent) == 4 and _in_flight(st) == 1
+        # ... until the thread is a whole batch ahead again
+        _feed(prod, pool, n, n + 14)        # it fills: behind at once
+        _spin(st, cons, got)
+        n = _parked_batch(st, prod, cons, pool, got, n + 14)
+        assert [(g.n, g.behind) for g in sent[4:]] == [(16, 1)]
+        for g in sent:
+            g.done = True
+        st.flush()
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+        assert sum(_closes(st)) == st.metrics.get("batches") == len(sent)
+@pytest.mark.parametrize("lane", LANES)
+def test_a_hiccup_clears_the_evidence_and_the_next_wait_restores_it(
+        lane, pool):
+    """(iii) The chip runs dry (a reap leaves nothing in flight): the
+    evidence is gone, one full batch goes out alone and a batch past its
+    deadline stays open behind it; the next full batch that has to wait
+    for a place restores the evidence."""
+    with _gated_tile(lane) as (st, prod, cons, sent):
+        got: list = []
+        n = _fill_window(st, prod, cons, pool, got)      # 3, 16 behind it
+        n = _parked_batch(st, prod, cons, pool, got, n)
+        sent[0].done = True
+        st.after_credit()
+        assert _in_flight(st) == 2 and st._full_waited
+        for g in sent:          # the hiccup: the thread looks late
+            g.done = True
+        _spin(st, cons, got)
+        assert _in_flight(st) == 0 and not st._full_waited
+        n = _full_batch(st, prod, cons, pool, got, n)    # alone
+        n = _held_batch(st, prod, cons, pool, got, n)    # room, no evidence
+        assert st._window_has_room() and len(sent) == 4
+        _feed(prod, pool, n, n + 13)        # it fills: behind at once
+        _spin(st, cons, got)
+        assert [g.behind for g in sent[3:]] == [0, 1]
+        assert not st._full_waited
+        n = _parked_batch(st, prod, cons, pool, got, n + 13)
+        sent[3].done = True
+        st.after_credit()       # the one that waited takes the place
+        n = _held_batch(st, prod, cons, pool, got, n)    # a full window
+        sent[4].done = True
+        st.after_credit()       # room, on the evidence: taken
+        assert [(g.n, g.close, g.behind) for g in sent[5:]] \
+            == [(16, rv.CLOSE_FULL, 1), (3, rv.CLOSE_WINDOW, 1)]
+        for g in sent:
+            g.done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+        assert sum(_closes(st)) == st.metrics.get("batches") == 7
+
+
+@pytest.mark.parametrize("how", ["flush", "short_sweep"])
+@pytest.mark.parametrize("lane", LANES)
+def test_what_a_trailing_thread_holds_is_released_as_before(
+        lane, how, pool):
+    """(iv) The batch that stays open behind a running full one for
+    want of evidence is still sent by flush(), behind whatever runs, and
+    by the first short sweep once the window has emptied."""
+    with _gated_tile(lane, batch=8) as (st, prod, cons, sent):
+        got: list = []
+        n = _trailing_thread(st, prod, cons, pool, got, sent)
+        n = _full_sweeps(st, prod, pool, n, 1)
+        _overdue(st)
+        sent[0].done = True
+        st.after_credit()       # room behind the full batch: not taken
+        assert len(sent) == 2 and _open_elems(st) == 4 and _held(st) == 0
+        if how == "flush":
+            st.flush()          # blocks on the heads: no gate needed
+            want = (4, rv.CLOSE_DEADLINE, 1)
+        else:
+            sent[1].done = True
+            st.after_credit()   # the window empties: the backlog holds it
+            assert len(sent) == 2 and _held(st) == 1
+            n = _short_sweep(st, prod, pool, n)
+            st.after_credit()
+            want = (4, rv.CLOSE_WINDOW, 0)
+        assert [(g.n, g.close, g.behind) for g in sent[2:]] == [want]
+        for g in sent:
+            g.done = True
+        _spin(st, cons, got)
+        assert got == list(pool[:n])
+        assert sum(_closes(st)) == st.metrics.get("batches") == 3
 
 
 @pytest.mark.parametrize("lane", CLOSE_LANES)
@@ -997,7 +1216,10 @@ def test_flush_sends_a_batch_the_backlog_held(lane, pool):
 # What the rule of PR 32 (the parent of ISSUE 36) dispatches under a paced
 # feed, every sweep short of its burst, as (lanes, close reason, batches
 # in flight ahead) per batch: recorded from the parent commit with the
-# scripts below, the same on every lane.  "feed" k offers and sweeps them
+# scripts below, the same on every lane — but for the two cases that
+# ISSUE 40 re-aimed: behind a full batch that found room at once the
+# batch past its deadline now stays open until the reap; behind one that
+# had to wait for its place it is queued as it was.  "feed" k offers and sweeps them
 # (k < 16 = the burst: a full batch is fed as 15 + 1); "late" lets the
 # deadline pass; "reap" lets the oldest batch in flight come back.
 _F, _D, _W = rv.CLOSE_FULL, rv.CLOSE_DEADLINE, rv.CLOSE_WINDOW
@@ -1015,10 +1237,14 @@ PACED_CASES = {
     "fills_behind_a_running_one": (
         [("feed", 3), ("late",), ("feed", 15), ("feed", 1)],
         [(3, _D, 0), (16, _F, 1)]),
-    "queued_behind_a_full_one_that_was_queued": (
+    "held_behind_a_full_one_that_found_room_at_once": (
         [("feed", 3), ("late",), ("feed", 15), ("feed", 1), ("reap",),
-         ("feed", 3), ("late",)],
-        [(3, _D, 0), (16, _F, 1), (3, _D, 1)]),
+         ("feed", 3), ("late",), ("reap",)],
+        [(3, _D, 0), (16, _F, 1), (3, _W, 0)]),
+    "queued_behind_a_full_one_that_waited_for_its_place": (
+        [("feed", 3), ("late",), ("feed", 15), ("feed", 1), ("feed", 15),
+         ("feed", 1), ("reap",), ("reap",), ("feed", 3), ("late",)],
+        [(3, _D, 0), (16, _F, 1), (16, _F, 1), (3, _D, 1)]),
     "a_full_window_holds_the_sealed_and_the_open": (
         [("feed", 3), ("late",), ("feed", 15), ("feed", 1), ("feed", 15),
          ("feed", 1), ("feed", 4), ("late",), ("reap",), ("reap",)],
@@ -1127,12 +1353,7 @@ def test_flush_seals_whatever_is_in_flight(lane, held, pool):
         assert [g.n for g in sent] == ([3, 16, 4] if held else [4])
         # what flush() seals counts as a deadline close and may be
         # dispatched behind whatever runs
-        if held and lane == "sharded":
-            # its flush closes by blocking on the head, and that reap
-            # seals the held step behind the full batch still in flight
-            assert _closes(st) == [1, 1, 1]
-        else:
-            assert _closes(st) == ([1, 2, 0] if held else [0, 1, 0])
+        assert _closes(st) == ([1, 2, 0] if held else [0, 1, 0])
         assert sum(_closes(st)) == st.metrics.get("batches")
         assert st.metrics.get(QUEUED_BEHIND) == (2 if held else 0)
 
@@ -1238,34 +1459,48 @@ def test_the_window_is_two_deep_whatever_was_asked_above_that(
         lane, asked, pool):
     """A full batch is dispatched behind a running one, nothing behind
     the two however dry the device has run and however often, and a
-    batch that is not full behind a full one, once there is room."""
+    batch that is not full behind a full one that had to wait, once
+    there is room."""
     with _gated_tile(lane, max_inflight=asked) as (st, prod, cons, sent):
         assert st.max_inflight == rv.WINDOW_DEPTH == 2
         got: list = []
         n = 0
-        for k in range(3):
+        for k in range(2):
             # the window fills: a batch at its deadline, a full one
             # behind it ...
             n = _deadline_batch(st, prod, cons, pool, got, n)
             n = _full_batch(st, prod, cons, pool, got, n)
-            # ... and a batch is held behind them
-            n = _held_batch(st, prod, cons, pool, got, n)
-            assert _in_flight(st) == 2
-            # the head is reaped: the full batch runs, there is room
-            # behind it, and the held batch takes it; never a third
+            # ... a second full one waits for its place, and a batch is
+            # held open behind them; never a third in flight
+            if lane == "sharded":
+                # which parks nothing: its full step blocks on the head
+                _feed(prod, pool, n, n + 16)
+                _spin(st, cons, got)
+                n = _held_batch(st, prod, cons, pool, got, n + 16)
+            else:
+                n = _parked_batch(st, prod, cons, pool, got, n)
+                n = _held_batch(st, prod, cons, pool, got, n, parked=True)
+                assert _in_flight(st) == 2 and len(sent) == 4 * k + 2
+                # the head is reaped: the sealed batch takes its place
+                sent[-2].done = True
+                _spin(st, cons, got)
+            assert len(sent) == 4 * k + 3 and _in_flight(st) == 2
+            assert _open_elems(st) == 3 and st._full_waited
+            # the next is reaped: the full batch that waited runs, there
+            # is room behind it, and the held batch takes it
             sent[-2].done = True
             _spin(st, cons, got)
-            assert len(sent) == 3 * k + 3 and _in_flight(st) == 2
+            assert len(sent) == 4 * k + 4 and _in_flight(st) == 2
             assert _open_elems(st) == 0
-            assert [g.behind for g in sent[-3:]] == [0, 1, 1]
+            assert [g.behind for g in sent[-4:]] == [0, 1, 1, 1]
             for g in sent:
                 g.done = True
             _spin(st, cons, got)
             assert got == list(pool[:n]) and _in_flight(st) == 0
-            assert _closes(st) == [k + 1] * 3
-            assert st.metrics.get(QUEUED_BEHIND) == 2 * (k + 1)
+            assert _closes(st) == [2 * (k + 1), k + 1, k + 1]
+            assert st.metrics.get(QUEUED_BEHIND) == 3 * (k + 1)
         assert _deepest(st) == 2
-        assert sum(_closes(st)) == st.metrics.get("batches") == 9
+        assert sum(_closes(st)) == st.metrics.get("batches") == 8
         assert st.metrics.get("txn_verified") == n
 
 

@@ -248,74 +248,86 @@ def test_one_bad_signature_in_eight_fails_the_transaction_whole(
     assert want.out == [0, 2] and want.verify_fail_elems == 8
 
 
-@pytest.mark.parametrize("behind", [True, False])
+@pytest.mark.parametrize("waited", [True, False])
 @pytest.mark.parametrize("lane", LANES)
 def test_a_batch_sealed_for_want_of_room_still_counts_as_full(
-        lane, behind, stream):
+        lane, waited, stream):
     """Nine 7-signature transactions fill 63 of 64 lanes; the next does
     not fit, so the batch is sealed CLOSE_FULL one lane short, and the
-    clause of PR 32 that rests on "full" holds for it on the evidence
-    of ISSUE 36: dispatched BEHIND a running batch it says that the
-    device limits, and the batch behind it, past its deadline, is queued
-    behind it while it runs; dispatched alone it says nothing, and the
-    batch behind it stays open until the reap."""
+    clause of the close rule that rests on "full" holds for it on the
+    evidence of ISSUE 40: when it had to WAIT for its place in the
+    window (two were in flight) it says that the thread leads the chip,
+    and the batch behind it, past its deadline, is queued behind it
+    once there is room; dispatched at once behind a running batch it
+    says nothing, and the batch behind it stays open."""
     pool, _order, _bad = stream
-    seven = [i for i in np.flatnonzero(pool.sigs == 7) if pool.valid[i]][:9]
+    n7 = 18 if waited else 9
+    seven = [i for i in np.flatnonzero(pool.sigs == 7)
+             if pool.valid[i]][:n7]
     two = [i for i in np.flatnonzero(pool.sigs == 2) if pool.valid[i]][:2]
-    assert len(seven) == 9 and len(two) == 2
+    assert len(seven) == n7 and len(two) == 2
     with _pair(lane, deadline_s=0.001, precomputed_ok=True) \
             as (verify, dedup, prod, cons):
         reaped: list = []          # the results the test lets come back
         verify._mask_ready = lambda result: any(result is r for r in reaped)
         m = verify.metrics
-        rows = list(seven) + two[:1]
-        if behind:
-            # a batch of two lanes goes out alone at its deadline first
-            assert prod.try_publish(pool.row(int(two[1])), sig=99, tsorig=1)
-            for _ in range(5):
-                verify.run_once()
-            time.sleep(0.005)
-            for _ in range(5):
-                verify.run_once()
-            assert m.get("batches") == 1 and len(verify._flying()) == 1
-            first = verify._flying()[0]
-        for fed, i in enumerate(rows):
-            assert prod.try_publish(pool.row(int(i)), sig=fed, tsorig=1)
-        for _ in range(20):
+
+        def reap_head():
+            head = verify._flying()[0]
+            reaped.append(head.result if lane != "native" else head[3])
             verify.run_once()
-        assert m.get("batches") == 1 + behind
+
+        # a batch of two lanes goes out alone at its deadline first
+        assert prod.try_publish(pool.row(int(two[1])), sig=99, tsorig=1)
+        for _ in range(5):
+            verify.run_once()
+        time.sleep(0.005)
+        for _ in range(5):
+            verify.run_once()
+        assert m.get("batches") == 1 and len(verify._flying()) == 1
+        # 63 lanes sealed for want of room go out behind it at once;
+        # with `waited`, 63 more find the window full and wait sealed
+        for fed, i in enumerate(list(seven) + two[:1]):
+            assert prod.try_publish(pool.row(int(i)), sig=fed, tsorig=1)
+        for _ in range(40):
+            verify.run_once()
+        assert m.get("batches") == 2 and len(verify._flying()) == 2
         assert m.get("batch_close_full") == 1
-        assert m.get(fm.BATCH_FIT_PAD_LANES) in (0, 1)
         verify.during_housekeeping()
-        assert m.get(fm.BATCH_FIT_PAD_LANES) == 1
-        assert m.get(fm.BATCH_QUEUED_BEHIND) == behind
-        assert verify._last_full_behind == behind
+        assert m.get(fm.BATCH_FIT_PAD_LANES) == 1 + waited
+        assert m.get(fm.BATCH_QUEUED_BEHIND) == 1
+        assert verify._full_waited == waited
         time.sleep(0.005)
         for _ in range(20):
             verify.run_once()
-        # past its deadline the two-lane batch is held: by the full
-        # window, or by the full batch that went out alone
-        assert len(verify._flying()) == 1 + behind
-        assert m.get("batches") == 1 + behind and not verify._window_open()
-        if behind:
-            # the reap of the first leaves room behind the 63 lanes, and
-            # the two-lane batch goes behind them in the same pass
-            reaped.append(first.result if lane != "native" else first[3])
-            verify.run_once()
+        # past its deadline the two-lane batch is held by the full window
+        assert m.get("batches") == 2 and not verify._window_open()
+        reap_head()
+        if waited:
+            # the sealed 63 lanes take the freed place, and at the next
+            # reap the two-lane batch goes behind them in the same pass
+            assert m.get("batches") == 3 and len(verify._flying()) == 2
+            assert m.get("batch_close_full") == 2
+            reap_head()
             assert len(verify._flying()) == 2
             assert m.get("batch_close_window") == 1
-            assert m.get(fm.BATCH_QUEUED_BEHIND) == 2
+            assert m.get(fm.BATCH_QUEUED_BEHIND) == 3
+            assert not verify._full_waited      # the slack is taken
         else:
-            assert m.get(fm.BATCH_QUEUED_BEHIND) == 0
+            # room behind the 63 lanes, which found their place at once:
+            # a thread that trails the chip sends such batches too
+            assert len(verify._flying()) == 1 and verify._window_has_room()
+            assert m.get("batches") == 2 and not verify._window_open()
+            assert m.get(fm.BATCH_QUEUED_BEHIND) == 1
         assert m.get(fm.BATCH_HELD_BACKLOGGED) == 0
         del verify._mask_ready
         out: list = []
         verify.flush()
-        assert m.get("batch_elems") == 65 + 2 * behind
+        assert m.get("batch_elems") == 2 + 63 * (1 + waited) + 2
         for _ in range(20):
             dedup.run_once()
             _collect(cons, out)
-        assert len(out) == 10 + behind
+        assert len(out) == 1 + n7 + 1
 
 
 def _one_bad_in_each_position(shape, seed: int) -> list[bytes]:

@@ -279,7 +279,7 @@ def test_a_backlogged_intake_holds_the_batch_that_would_run_part_empty(
     _feed(st, txn_pool[2:4], t0=1002)       # it fills: out at once, alone
     assert len(st._inflight) == 1 and not st._gen.elems
     assert st.metrics.get("batch_close_full") == 1
-    assert not st._last_full_behind
+    assert not st._full_waited
     # behind it the next one is held by the window, then by the backlog
     _feed(st, txn_pool[4:6], t0=1004)
     st.before_credit()
@@ -303,11 +303,13 @@ def test_a_backlogged_intake_holds_the_batch_that_would_run_part_empty(
 
 
 @pytest.mark.parametrize("backlogged", [False, True])
-def test_the_place_behind_a_full_batch_needs_it_to_have_been_queued(
+def test_the_place_behind_a_full_batch_needs_one_to_have_waited_for_it(
         txn_pool, backlogged):
-    """Clause (b): a batch that is not full goes behind a full one only
-    if that one was itself dispatched behind a running batch, backlogged
-    or not."""
+    """Clause (b), on the evidence of ISSUE 40: a batch that is not full
+    goes behind a running one only after a full batch had to wait for
+    its place in the window (the thread leads the chip), backlogged or
+    not; a full batch that found room behind a running one at once says
+    nothing, since a thread that trails the chip sends those too."""
     st = _WindowStage("v", ins=[], outs=[], batch=4, max_msg_len=256,
                       batch_deadline_s=0.0)
     st.backlogged = backlogged
@@ -318,19 +320,33 @@ def test_the_place_behind_a_full_batch_needs_it_to_have_been_queued(
     assert len(st._inflight) == 1 and len(st._gen.elems) == 2
     assert not st._window_open() and st._window_has_room()
     _feed(st, txn_pool[6:8], t0=1006)       # full, behind the running one
-    assert len(st._inflight) == 2 and st._last_full_behind
+    assert len(st._inflight) == 2 and not st._full_waited
     _feed(st, txn_pool[8:10], t0=1008)
     st.before_credit()
     st.fakes[0].ready = True
-    st.after_credit()                       # room behind it: taken
+    st.after_credit()                       # room behind it: not taken
+    assert len(st._inflight) == 1 and len(st._gen.elems) == 2
+    assert st._window_has_room() and not st._window_open()
+    _feed(st, txn_pool[10:12], t0=1010)     # it fills: behind at once
+    _feed(st, txn_pool[12:16], t0=1012)     # the next finds no place
+    assert len(st._inflight) == 2 and len(st._submit_queue) == 1
+    assert st._full_waited
+    _feed(st, txn_pool[16:18], t0=1016)
+    st.before_credit()
+    st.fakes[1].ready = True
+    st.after_credit()           # the one that waited takes the place
+    assert len(st._inflight) == 2 and len(st._gen.elems) == 2
+    st.fakes[2].ready = True
+    st.after_credit()           # room behind it, on its evidence: taken
     assert len(st._inflight) == 2 and not st._gen.elems
-    assert st.metrics.get("batch_queued_behind") == 2
+    assert st.metrics.get("batch_queued_behind") == 4
+    assert st.metrics.get("batch_close_window") == 1
     assert st.metrics.get("batch_held_backlogged") == 0
-    assert not st._last_full_behind         # that one was not full
+    assert not st._full_waited              # the slack is spent
     for f in st.fakes:
         f.ready = True
     st.flush()
-    assert [e[2] for e in st.emitted] == list(range(1000, 1010))
+    assert [e[2] for e in st.emitted] == list(range(1000, 1018))
 
 
 class _CountStage(Stage):
