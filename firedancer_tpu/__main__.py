@@ -122,6 +122,8 @@ def _run_processes(args) -> int:
     from firedancer_tpu.runtime import topo as ft
 
     cfg = _load_cfg(args)
+    if cfg.layout.benchs_stage_count > 0:
+        return _run_front(args, cfg)
     if cfg.layout.bank_stage_count != 1:
         # each bank process owns its funk (leader_topo.build_bank)
         print(f"# the process topology runs 1 bank stage (config asks "
@@ -158,6 +160,43 @@ def _run_processes(args) -> int:
               f"compile included)")
         h.halt()
         return 0 if ok and n_exec == args.txns else 1
+    finally:
+        h.close()
+
+
+def _run_front(args, cfg) -> int:
+    """The front door (layout.benchs_stage_count >= 1): benchg ->
+    benchs x S -> loopback UDP/QUIC -> quic -> verify -> out, a process
+    a tile.  Done: every generated transaction left the verify tile."""
+    from firedancer_tpu.models.leader_topo import (
+        build_quic_topology_from_config,
+    )
+    from firedancer_tpu.runtime import topo as ft
+
+    sandbox = {"rlimits": {"nofile": 512}} if args.sandbox else None
+    topo = build_quic_topology_from_config(
+        cfg, n_txns=args.txns, pool_size=args.txns, n_payers=RUN_PAYERS,
+        verify_cpu=args.cpu, sandbox=sandbox)
+    h = ft.launch(topo)
+    try:
+        print(f"# {len(h.procs)} stage processes; descriptor "
+              f"fdtpu_run_{h.uid}.json"
+              + (" (sandboxed)" if sandbox else ""), file=sys.stderr)
+
+        def out() -> int:
+            return (h.met_views["out"][0].get("frags_in")
+                    + h.met_views["verify0"][0].get("verify_fail"))
+
+        t0 = time.time()
+        ok = h.supervise(until=lambda h: out() >= args.txns,
+                         timeout_s=1200, heartbeat_timeout_s=300)
+        dt = time.time() - t0
+        print(h.format_monitor())
+        n_out = out()
+        print(f"# {n_out} txns through quic and verify in {dt:.2f}s (boot "
+              f"and compile included)")
+        h.halt()
+        return 0 if ok and n_out == args.txns else 1
     finally:
         h.close()
 

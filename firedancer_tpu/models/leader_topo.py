@@ -62,13 +62,13 @@ def _warm_verify(stage, dev) -> None:
                 f"count={dev[2]} warmup_s={warm_s:.2f}")
 
 
-def build_benchg(links, cnc, *, pool_size, n_txns, n_payers=8):
+def build_benchg(links, cnc, *, pool_size, n_txns, n_payers=8, out="gv"):
     from firedancer_tpu.runtime.benchg import BenchGStage, gen_transfer_pool
 
     return BenchGStage(
         gen_transfer_pool(pool_size, n_payers=n_payers),
         "benchg",
-        outs=[shm.make_producer(links["gv"])],
+        outs=[shm.make_producer(links[out])],
         cnc=cnc,
         limit=n_txns,
     )
@@ -94,6 +94,54 @@ def build_verify(links, cnc, *, batch, max_msg_len=256, precomputed=False,
     if dev is not None:
         _warm_verify(stage, dev)
     return stage
+
+
+def build_benchs(links, cnc, *, idx, n, run_dir, peer_pub, max_datagram,
+                 capture=False):
+    """Sender tile `idx` of `n` on the generator's ring: waits for the
+    quic tile's address in the run's directory, then handshakes — both
+    before its first heartbeat, so set-up covers them."""
+    from firedancer_tpu.runtime.benchs import BenchSStage, wait_addr
+
+    addr = wait_addr(quic_addr_file(run_dir), 120.0)
+    return BenchSStage(
+        f"benchs{idx}",
+        ins=[shm.make_consumer(links["gb"], fseq_idx=idx, lazy=16)],
+        cnc=cnc,
+        addr=addr,
+        expected_peer=peer_pub,
+        max_datagram=max_datagram,
+        shard_idx=idx,
+        shard_cnt=n,
+        capture=f"{run_dir}/benchs{idx}" if capture else None,
+    )
+
+
+def build_quic(links, cnc, *, secret, run_dir, host, port, rx_burst,
+               reasm_depth, max_conns, retry, stream_window):
+    from firedancer_tpu.runtime.net import QuicIngressStage
+
+    return QuicIngressStage(
+        "quic",
+        outs=[shm.make_producer(links["gv"])],
+        cnc=cnc,
+        host=host,
+        port=port,
+        rx_burst=rx_burst,
+        identity_secret=secret,
+        reasm_depth=reasm_depth,
+        max_conns=max_conns,
+        retry=retry,
+        stream_window=stream_window,
+        addr_file=quic_addr_file(run_dir),
+    )
+
+
+def build_out(links, cnc):
+    from firedancer_tpu.runtime.benchs import OutStage
+
+    return OutStage("out", ins=[shm.make_consumer(links["vd"], lazy=64)],
+                    cnc=cnc)
 
 
 def build_router(links, cnc, *, n_shards):
@@ -536,6 +584,102 @@ def build_leader_topology_from_config(cfg, *, genesis: dict | None = None,
     )
     kw.update(overrides)
     return build_leader_topology(**kw)
+
+
+def _quic_dir_template() -> str:
+    from firedancer_tpu.runtime import monitor as mon
+
+    return mon.RUN_DIR + "/fdtpu_quic_{uid}"
+
+
+def quic_addr_file(run_dir: str) -> str:
+    return run_dir + "/quic.addr"
+
+
+def quic_dir(handle) -> str:
+    """The front-door topology's directory of the run: the quic tile's
+    address, and each sender tile's key log and captured datagrams
+    (`benchs<i>.keys`, `benchs<i>.dgrams`: runtime/benchs.QuicSender)."""
+    return _quic_dir_template().format(uid=handle.uid)
+
+
+def build_quic_topology_from_config(
+    cfg, *, n_txns: int = 64, pool_size: int = 64, n_payers: int = 8,
+    leader_seed: bytes = b"leader", sandbox: dict | None = None,
+    verify_precomputed: bool = False, verify_cpu: bool = False,
+    capture: bool = False,
+) -> ft.Topology:
+    """The front door as a process topology, from the typed Config:
+
+        benchg -> gb -> benchs x S -> (loopback UDP, QUIC) -> quic
+               -> gv -> verify0 -> vd -> out
+
+    S = layout.benchs_stage_count sender tiles share the generator's
+    ring (sender k takes seq % S == k), each with one QUIC connection
+    pinned to the quic tile's identity; the quic tile listens where
+    [net] says and writes the address it got into the run's directory
+    (`quic_dir(handle)`), where `capture` also puts each sender's key
+    log and first datagrams; verify is the tile the config's [verify]
+    describes, at ITS row bound (max_msg_len: 1,232 unless the file
+    narrows it).  The ring in front of verify is
+    verify.receive_buffer_depth deep, the generator's too; verify's
+    output ring is links.verify_pack.
+
+    What it cannot build it refuses by name: no sender tile
+    (benchs_stage_count 0 is the topology without a front); more than
+    one verify tile (N verify tiles each taking seq % N of the quic
+    tile's ring exist only cooperatively, and a chip belongs to one
+    process)."""
+    from firedancer_tpu.ops.ref import ed25519_ref as ref
+    from firedancer_tpu.runtime.benchs import BenchSStage, OutStage
+    from firedancer_tpu.runtime.net import QuicIngressStage
+    from firedancer_tpu.runtime.verify import VerifyStage
+
+    n_benchs = cfg.layout.benchs_stage_count
+    if n_benchs < 1:
+        raise ValueError(
+            "the front-door topology needs layout.benchs_stage_count >= 1 "
+            "(0 is the topology without a quic tile: "
+            "build_leader_topology_from_config)")
+    if cfg.layout.verify_stage_count != 1:
+        raise ValueError(
+            f"quic with layout.verify_stage_count = "
+            f"{cfg.layout.verify_stage_count}: the process topology has one "
+            f"verify tile behind the quic tile until N verify tiles each "
+            f"take seq % N of its ring in processes of their own")
+    if cfg.verify.devices != 1:
+        raise ValueError("quic with verify.devices > 1: the front-door "
+                         "topology runs its verify tile on one chip")
+    topo = ft.Topology()
+    depth = cfg.verify.receive_buffer_depth
+    topo.link("gb", depth=depth, mtu=1232, n_consumers=n_benchs)
+    topo.link("gv", depth=depth, mtu=1232)
+    topo.link("vd", depth=cfg.links.verify_pack, mtu=4096)
+    run_dir = topo.own(_quic_dir_template())
+    secret = hashlib.sha256(leader_seed).digest()
+    sb = sandbox
+    topo.stage("benchg", build_benchg, pool_size=pool_size, n_txns=n_txns,
+               n_payers=n_payers, out="gb", sandbox=sb, outs=["gb"])
+    for i in range(n_benchs):
+        topo.stage(f"benchs{i}", build_benchs, idx=i, n=n_benchs,
+                   run_dir=run_dir, peer_pub=ref.public_key(secret),
+                   max_datagram=cfg.quic.max_datagram, capture=capture,
+                   sandbox=sb, ins=["gb"],
+                   schema=BenchSStage.metrics_schema())
+    topo.stage("quic", build_quic, secret=secret, run_dir=run_dir,
+               host=cfg.net.listen_host, port=cfg.net.listen_port,
+               rx_burst=cfg.net.rx_burst, reasm_depth=cfg.quic.reasm_depth,
+               max_conns=cfg.quic.max_conns, retry=cfg.quic.retry,
+               stream_window=cfg.quic.stream_window, sandbox=sb,
+               outs=["gv"], schema=QuicIngressStage.metrics_schema())
+    topo.stage("verify0", build_verify, batch=cfg.verify.batch,
+               max_msg_len=cfg.verify.max_msg_len, sandbox=sb,
+               precomputed=verify_precomputed, cpu=verify_cpu,
+               batch_deadline_s=cfg.verify.batch_deadline_ms / 1e3,
+               ins=["gv"], outs=["vd"], schema=VerifyStage.metrics_schema())
+    topo.stage("out", build_out, sandbox=sb, ins=["vd"],
+               schema=OutStage.metrics_schema())
+    return topo
 
 
 def store_dir(handle) -> str:

@@ -327,6 +327,7 @@ class MonitorSession:
             row["mesh"] = fm.mesh_row(j.registry)
             row["votes"] = fm.vote_row(j.registry)
             row["dedup"] = fm.dedup_row(j.registry)
+            row["front"] = fm.front_row(j.registry)
             out.append(row)
         for logical, js in groups.items():
             sigs = [j.cnc.signal for j in js]
@@ -468,6 +469,15 @@ class MonitorSession:
                 # and the signatures verify had checked for them
                 lines.append(f"{r['stage']}: dropped " + " ".join(
                     f"{k}={v:,}" for k, v in dedup.items()))
+            front = r.get("front")
+            if front:
+                # the quic tile: datagrams, punts to the Python lane,
+                # what the reassembler made of the streams, whole
+                # transactions that waited for verify's ring; a sender
+                # tile: what it sent, sent again, and how often the
+                # peer's credit held it (cumulative)
+                lines.append(f"{r['stage']}: front " + " ".join(
+                    f"{k}={v:,}" for k, v in front.items()))
         return "\n".join(lines)
 
     def run(self, *, interval_s: float = 1.0, iterations: int | None = None,

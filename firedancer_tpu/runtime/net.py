@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import errno
 import os
+import select
 import socket
 
 from firedancer_tpu.protocol.txn import TXN_MTU
@@ -90,6 +91,7 @@ class UdpIngressStage(Stage):
                 if e.errno in (errno.EAGAIN, errno.EWOULDBLOCK):
                     return
                 raise
+            self._loop_worked = True    # the thread's ledger: a receive
             if not self._on_datagram(data, src):
                 return  # backpressured: stop draining the socket
 
@@ -258,17 +260,89 @@ class QuicIngressStage(UdpIngressStage):
 
     _NATIVE_UDP = False  # the native seam is the QUIC datagram path
 
+    # kernel receive buffer asked for (the effective size is the gauge
+    # rcvbuf_bytes): every sender's stream window has to fit in it, or
+    # the kernel drops what the tile has not read yet
+    RCVBUF_BYTES = 8 << 20
+
+    @classmethod
+    def extra_schema(cls):
+        from firedancer_tpu.utils import metrics as fm
+
+        return (
+            fm.MetricsSchema()
+            .counter("dgram_rx", "datagrams taken off the socket")
+            .counter("dgram_rx_bytes", "their UDP payload bytes")
+            .counter("net_punts", "datagrams the C lane handed to Python")
+            .counter("pkt_rx", "datagrams whose packets were accepted")
+            .counter("bad_packet", "datagrams dropped: auth, flow, frames")
+            .counter("handshakes_done", "connections established")
+            .gauge("conn_active", "connections held")
+            .counter("conn_drop", "Initials refused: the table is full")
+            .counter("conn_evict", "connections evicted for a newcomer")
+            .counter("txn_rx", "transactions published to the ring behind")
+            .counter("reasm_published", "transactions reassembled whole")
+            .counter("reasm_multi_chunk",
+                     "of them, joined from more than one STREAM chunk")
+            .counter("reasm_evicted",
+                     "streams dropped: their reassembly slot was stolen")
+            .counter("reasm_oversz", "streams dropped: over the MTU")
+            .counter("reasm_cancelled", "streams reset by the transport")
+            .counter("reasm_dup_stream",
+                     "STREAM chunks of streams already over (late copies)")
+            .counter("txn_held_for_credit",
+                     "whole transactions that waited for the ring behind")
+            .gauge("txn_held", "whole transactions waiting now")
+            .counter("streams_granted", "stream credit returned to senders")
+            .gauge("rcvbuf_bytes", "the socket's receive buffer")
+        )
+
     def __init__(self, *args, identity_secret: bytes, reasm_depth: int = 64,
                  max_conns: int = 64, tx_filter=None, retry: bool = False,
+                 stream_window: int = 64, addr_file: str | None = None,
                  **kwargs):
         super().__init__(*args, **kwargs)
         import hashlib
+        from collections import deque
 
         from firedancer_tpu.waltz import quic
         from .tpu_reasm import TpuReasm
 
         self.identity_secret = identity_secret
         self.max_conns = max_conns
+        # stream credit (RFC 9000 §4.6): a connection may have
+        # `stream_window` unidirectional streams that this tile has
+        # not yet handed to the ring behind it; credit goes back as
+        # transactions are PUBLISHED (or dropped under a named
+        # counter), so a full ring holds the senders, not the kernel's
+        # buffer.  The senders learn the window from the handshake
+        # (initial_max_streams_uni)
+        self.stream_window = stream_window
+        # credit goes back a quarter window at a time: a MAX_STREAMS
+        # frame is ack-eliciting, so one a sweep would cost the sender
+        # an ACK datagram, and this tile its crossing, almost every
+        # transaction; a sender is never left with less than three
+        # quarters of its window
+        self._grant_quantum = max(1, stream_window // 4)
+        self._tp = quic.encode_transport_params(
+            {quic.TP_INITIAL_MAX_STREAMS_UNI: stream_window})
+        # whole transactions the ring behind had no credit for, in
+        # order: (payload, connection, stream id).  Bounded by the
+        # stream credit outstanding (connections x stream_window)
+        self._held: deque = deque()
+        self._held_counted = 0      # native out rows already counted held
+        self._py_dup_stream = 0     # the Python lane's share of two counters
+        self._py_multi_chunk = 0
+        self._grant: dict = {}      # cid -> (connection, credit to return)
+        if isinstance(self.sock, socket.socket):
+            for opt in (socket.SO_RCVBUF, 33):   # 33: SO_RCVBUFFORCE
+                try:
+                    self.sock.setsockopt(socket.SOL_SOCKET, opt,
+                                         self.RCVBUF_BYTES)
+                except OSError:
+                    pass
+            self.metrics.counters["rcvbuf_bytes"] = self.sock.getsockopt(
+                socket.SOL_SOCKET, socket.SO_RCVBUF)
         self.conns: dict = {}
         self._addr_by_cid: dict = {}   # server CID -> current peer addr
         self._migrations: dict = {}    # CID -> (candidate addr, token)
@@ -303,6 +377,53 @@ class QuicIngressStage(UdpIngressStage):
                     max_conns=max_conns, reasm_depth=reasm_depth)
             except NativeUnavailable:
                 self._net_client = None
+        if addr_file:
+            # a tile in a process of its own: where its senders find it
+            import json
+
+            host, port = self.addr
+            tmp = addr_file + ".tmp"
+            with open(tmp, "w") as f:
+                json.dump({"host": host, "port": port}, f)
+            os.replace(tmp, addr_file)
+
+    def native_lanes(self) -> dict[str, bool]:
+        lanes = super().native_lanes()
+        lanes["net"] = self._net_client is not None
+        return lanes
+
+    def run_once(self) -> bool:
+        """A source stage: what `run` naps on is whether the call
+        worked (a receive, a publish), since no frag is consumed."""
+        c = self.metrics.counters
+        w0 = c["loop_work_n"]
+        super().run_once()
+        return c["loop_work_n"] != w0
+
+    def _input_pending(self) -> bool:
+        if self._held or (self._net_client is not None
+                          and self._net_client.out_count()):
+            return True
+        try:
+            return bool(select.select([self.sock], [], [], 0)[0])
+        except (OSError, ValueError, TypeError):
+            return False
+
+    def during_housekeeping(self) -> None:
+        c = self.metrics.counters
+        r = self.reasm.metrics
+        nc = self._net_client
+        n = nc.counters() if nc is not None else {}
+        c["reasm_published"] = r["published"] + n.get("txn", 0)
+        c["reasm_multi_chunk"] = (self._py_multi_chunk
+                                  + n.get("multi_chunk", 0))
+        c["reasm_evicted"] = r["evicted"] + n.get("evicted", 0)
+        c["reasm_oversz"] = r["oversz"] + n.get("oversz", 0)
+        c["reasm_cancelled"] = r["cancelled"]
+        c["reasm_dup_stream"] = n.get("dup_stream", 0) + self._py_dup_stream
+        c["conn_active"] = len(self.conns)
+        c["txn_held"] = len(self._held) + (nc.out_count() if nc else 0)
+        self._copy_sweep_counters()
 
     def _send(self, dg: bytes, dst) -> None:
         if self.tx_filter is not None and not self.tx_filter(dg):
@@ -320,13 +441,24 @@ class QuicIngressStage(UdpIngressStage):
             budget[1] += len(dg)
         self.sock.sendto(dg, dst)
 
+    def before_credit(self) -> None:
+        # credit for what the last sweep published goes back even when
+        # the ring behind has just filled (after_credit is then skipped)
+        if self._grant:
+            self._return_credit()
+
     def after_credit(self) -> None:
+        # retry the credit-gated tail before taking more off the socket
+        # (queued, never dropped: a drain point that does not depend
+        # on further ingress); while any of it waits, nothing more is
+        # read, so the senders run out of stream credit and stop
+        drained = self._flush_held()
         if self._net_client is not None:
-            # retry the credit-gated native txn tail before taking more
-            # off the socket — queued-never-dropped needs a drain point
-            # that does not depend on further ingress
-            self._flush_native_txns()
-        super().after_credit()
+            drained = self._flush_native_txns() and drained
+        if drained:
+            super().after_credit()
+        if self._grant:
+            self._return_credit()
         # loss-recovery housekeeping: fire PTO retransmissions even when
         # the socket is quiet (a lost server flight must not deadlock the
         # handshake — fd_quic's service loop runs its timers the same way)
@@ -341,6 +473,9 @@ class QuicIngressStage(UdpIngressStage):
         drops it (auth/flow/frame violations — byte-for-byte the Python
         lane's verdict), or PUNTs it to the Python lane below in arrival
         order."""
+        c = self.metrics.counters
+        c["dgram_rx"] = c.get("dgram_rx", 0) + 1
+        c["dgram_rx_bytes"] = c.get("dgram_rx_bytes", 0) + len(data)
         nc = self._net_client
         if nc is None:
             return self._py_datagram(data, src)
@@ -372,6 +507,7 @@ class QuicIngressStage(UdpIngressStage):
         advanced push back down so the C table never goes stale."""
         from firedancer_tpu.waltz import quic
 
+        self.metrics.inc("net_punts")
         conn = self.conns.get(src)
         prev = None
         if conn is not None:
@@ -415,6 +551,8 @@ class QuicIngressStage(UdpIngressStage):
             self._native_idx[cid] = idx
             self._by_idx[idx] = conn
             self._native_src[idx] = src
+            nc.conn_streams(idx, conn.rx_max_streams_uni or 0,
+                            conn.rx_fin_floor)
             self.metrics.inc("net_conn_exported")
 
     def _sync_after_punt(self, conn, idx: int, old_ranges, src) -> None:
@@ -440,6 +578,8 @@ class QuicIngressStage(UdpIngressStage):
             for pn in range(cur, hi + 1):
                 nc.conn_pn_add(idx, pn)
         nc.conn_window(idx, conn.rx_max_data, conn.rx_data_total)
+        nc.conn_streams(idx, conn.rx_max_streams_uni or 0,
+                        conn.rx_fin_floor)
         if self.conns.get(src) is conn and self._native_src.get(idx) != src:
             nc.conn_set_addr(idx, self._intern_addr(src))  # migrated
             self._native_src[idx] = src
@@ -486,7 +626,7 @@ class QuicIngressStage(UdpIngressStage):
             elif typ == net_native.EV_WIN:
                 conn.rx_consumed += a
                 conn.rx_data_total += b
-                if conn.rx_consumed * 2 > conn.rx_max_data:
+                if conn.rx_window_low():
                     # _rx_window_updates' MAX_DATA advertisement, pushed
                     # back down so the native flow check tracks it
                     conn.rx_max_data = (conn.rx_consumed
@@ -497,6 +637,12 @@ class QuicIngressStage(UdpIngressStage):
                     nc.conn_window(idx, conn.rx_max_data,
                                    conn.rx_data_total)
                 touched.add(idx)
+            elif typ == net_native.EV_RETIRE:
+                # the stream ended without a transaction (counted:
+                # reasm_oversz / reasm_evicted): over here too, and
+                # its credit goes back
+                conn.stream_finish(a)
+                self._retire(conn)
         if nev:
             nc.events_clear()
         ok = self._flush_native_txns()
@@ -514,16 +660,71 @@ class QuicIngressStage(UdpIngressStage):
         n = nc.out_count()
         if not n:
             return True
+        if self._held:
+            return False    # the Python lane's tail is older: it goes first
         base = self.metrics.get("txn_rx")
         items = [(nc.out_txn(i), base + 1 + i, 0) for i in range(n)]
         done = self.publish_burst_out(0, items)
+        for i in range(done):
+            ci, sid = nc.out_owner(i)
+            conn = self._by_idx.get(ci)
+            if conn is not None:
+                conn.stream_finish(sid)
+                self._retire(conn)
         nc.out_pop(done)
         if done:
             self.metrics.inc("txn_rx", done)
-        if done < n:
-            self.metrics.inc("txn_drop_backpressure", n - done)
-            return False
+        # each waiting transaction is counted held once, however often
+        # its publish is tried again
+        left = n - done
+        fresh = left - max(self._held_counted - done, 0)
+        self._held_counted = left
+        if fresh > 0:
+            self.metrics.inc("txn_held_for_credit", fresh)
+            # (the counter's older name: it never counted a drop here)
+            self.metrics.inc("txn_drop_backpressure", fresh)
+        return left == 0
+
+    def _flush_held(self) -> bool:
+        """Publish the Python lane's waiting transactions, in order,
+        as far as the ring behind has credit.  -> none is left."""
+        held = self._held
+        while held:
+            txn, conn, sid = held[0]
+            if not self.publish(0, txn, sig=self.metrics.get("txn_rx") + 1):
+                return False
+            held.popleft()
+            self.metrics.inc("txn_rx")
+            self._retire(conn)
         return True
+
+    def _retire(self, conn, n: int = 1) -> None:
+        """`n` of the connection's streams left this tile (published,
+        or dropped under a named counter): their credit goes back with
+        the sweep's last flush (`_return_credit`)."""
+        cid = bytes(conn.local_cid)
+        had = self._grant.get(cid)
+        self._grant[cid] = (conn, n + (had[1] if had else 0))
+
+    def _return_credit(self) -> None:
+        nc = self._net_client
+        quantum = self._grant_quantum
+        for cid, (conn, n) in list(self._grant.items()):
+            home = self._addr_by_cid.get(cid)
+            if home is None or conn.closed:
+                del self._grant[cid]
+                continue
+            if n < quantum:
+                continue
+            del self._grant[cid]
+            conn.grant_streams_uni(n)
+            self.metrics.inc("streams_granted", n)
+            if nc is not None:
+                idx = self._native_idx.get(cid)
+                if idx is not None:
+                    nc.conn_streams(idx, conn.rx_max_streams_uni)
+            for dg in conn.flush():
+                self._send(dg, home)
 
     def net_counters(self) -> dict:
         """The native lane's counter block ({} on the Python lane) —
@@ -629,7 +830,11 @@ class QuicIngressStage(UdpIngressStage):
                     self.metrics.inc("addr_budget_full_drop")
                     return True
                 self._addr_budget[src] = [0, 0, now]
-            conn = quic.Connection.server_new(self.identity_secret)
+            conn = quic.Connection.server_new(
+                self.identity_secret, transport_params=self._tp)
+            conn.rx_max_streams_uni = self.stream_window
+        was_established = conn.established
+        dup0, multi0 = conn.rx_dup_stream, conn.rx_multi_chunk
         if src in self._addr_budget:
             self._addr_budget[src][0] += len(data)
             if conn is not None and conn.established:
@@ -653,6 +858,13 @@ class QuicIngressStage(UdpIngressStage):
         if fresh:
             self.conns[src] = conn
             self._addr_by_cid[bytes(conn.local_cid)] = src
+        if conn.established and not was_established:
+            self.metrics.inc("handshakes_done")
+            # the handshake validated the address (§8.1): the 3x cap
+            # ends HERE, not at the next datagram this lane sees — on
+            # the native lane none comes, and a capped tile stops
+            # answering after ~7 KB of ACKs
+            self._addr_budget.pop(src, None)
         self.metrics.inc("pkt_rx")
         home = (self._addr_by_cid.get(migrating_cid, src)
                 if migrating_cid else src)
@@ -682,18 +894,38 @@ class QuicIngressStage(UdpIngressStage):
         for dg in conn.flush():
             self._send(dg, home)
         ok = True
-        for sid, chunk, fin in conn.receive_stream_events(events):
+        held = self._held
+        chunks = conn.receive_stream_events(events)
+        self._py_dup_stream += conn.rx_dup_stream - dup0
+        self._py_multi_chunk += conn.rx_multi_chunk - multi0
+        for sid, chunk, fin in chunks:
             # every chunk feeds reassembly even under backpressure — the
             # datagram is already ACKed, so a skipped chunk would be a
-            # permanent hole in its stream; only completed txns can drop
+            # permanent hole in its stream; a completed txn the ring
+            # behind has no credit for WAITS (as the native lane's
+            # does): its stream was acknowledged, so nobody sends it
+            # again
             txn = self.reasm.append((src, sid), chunk, fin=fin)
             if txn is None:
                 continue
-            if not self.publish(0, txn, sig=self.metrics.get("txn_rx") + 1):
-                self.metrics.inc("txn_drop_backpressure")
+            if held or (self._net_client is not None
+                        and self._net_client.out_count()) \
+                    or not self.publish(
+                        0, txn, sig=self.metrics.get("txn_rx") + 1):
+                held.append((txn, conn, sid))
+                self.metrics.inc("txn_held_for_credit")
                 ok = False
                 continue
             self.metrics.inc("txn_rx")
+            self._retire(conn)
+        for (ksrc, ksid), _why in self.reasm.take_ended():
+            # ended without a transaction (reasm_evicted /
+            # reasm_oversz): later chunks are dropped, not joined into
+            # a short transaction, and the credit goes back
+            kconn = self.conns.get(ksrc)
+            if kconn is not None:
+                kconn.stream_finish(ksid)
+                self._retire(kconn)
         return ok
 
     def _evict(self) -> bool:
@@ -709,79 +941,53 @@ class QuicIngressStage(UdpIngressStage):
         return False
 
 
-class QuicTxnClient:
-    """Handshakes to a QuicIngressStage and ships txns, one
-    client-initiated unidirectional stream (ids 2, 6, 10, ...) per txn —
-    the benchs-tile sender position (src/app/fddev/tiles/fd_benchs.c)."""
+from .benchs import QuicSender  # noqa: E402  (benchs imports no net)
+
+
+class QuicTxnClient(QuicSender):
+    """The tests' blocking client over the sender tile's send path
+    (runtime/benchs.py `QuicSender`): handshakes to a QuicIngressStage
+    in its constructor and ships txns, one client-initiated
+    unidirectional stream (ids 2, 6, 10, ...) per txn.  `send_txn`
+    waits (pumping) where the tile would leave the transaction on its
+    ring: for the peer's stream or data credit."""
 
     def __init__(self, addr, *, expected_peer: bytes | None = None,
                  timeout_s: float = 10.0, tx_filter=None):
         from firedancer_tpu.waltz import quic
 
-        self.addr = addr
-        self.sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
-        self.sock.settimeout(0.05)
-        self.conn = quic.Connection.client_new(expected_peer=expected_peer)
-        self._next_stream = 2
-        self.tx_filter = tx_filter
-        import time as _time
-
-        deadline = _time.monotonic() + timeout_s
-        self._flush_out()
-        while not self.conn.established:
-            try:
-                data, _ = self.sock.recvfrom(2048)
-                self.conn.receive(data)
-            except socket.timeout:
-                pass
-            # PTO keeps a lossy handshake moving (lost Initial/Handshake
-            # flights retransmit; without this a single drop deadlocks)
-            self.conn.poll_timers()
-            self._flush_out()
-            if _time.monotonic() > deadline:
-                raise TimeoutError("QUIC handshake timed out")
-
-    def _flush_out(self) -> None:
-        for dg in self.conn.flush():
-            if self.tx_filter is not None and not self.tx_filter(dg):
-                continue
-            self.sock.sendto(dg, self.addr)
+        super().__init__(addr, expected_peer=expected_peer,
+                         max_datagram=quic.MAX_DATAGRAM,
+                         tx_filter=tx_filter)
+        self.timeout_s = timeout_s
+        self.handshake(timeout_s)
 
     def _drain_rx(self) -> None:
-        """Nonblocking drain of inbound datagrams (acks, MAX_DATA window
-        updates) — restores the socket's handshake timeout after."""
+        """Nonblocking drain of inbound datagrams (acks, credit); a
+        test may have swapped the socket for a blocking one."""
         self.sock.setblocking(False)
-        try:
-            while True:
-                try:
-                    data, _ = self.sock.recvfrom(2048)
-                except (BlockingIOError, InterruptedError, socket.timeout):
-                    break
-                self.conn.receive(data)
-        finally:
-            self.sock.settimeout(0.05)
+        self._recv()
+
+    def _flush_out(self) -> None:
+        self._flush()
 
     def send_txn(self, txn: bytes) -> None:
-        # learn window updates BEFORE queueing: past ~1 MiB cumulative
-        # the peer's MAX_DATA must be seen or writes park in blocked_out
+        import time as _time
+
+        # learn window updates BEFORE sending: past the peer's windows
+        # its MAX_DATA / MAX_STREAMS must be seen first
         self._drain_rx()
-        sid = self._next_stream
-        self._next_stream += 4
-        self.conn.send_stream(sid, txn, fin=True)
-        self._flush_out()
+        deadline = _time.monotonic() + self.timeout_s
+        while not QuicSender.send_txn(self, txn):
+            if _time.monotonic() > deadline:
+                raise TimeoutError("no stream or data credit from the peer")
+            _time.sleep(0.001)
+            self.pump()
 
     def pump(self) -> None:
         """Process inbound datagrams (acks, window updates) and fire any
         due retransmissions.  Call while waiting for delivery on lossy
-        links or during long send loops (flow-control windows only move
-        when inbound MAX_DATA frames are read)."""
+        links or during long send loops."""
         self._drain_rx()
         self.conn.poll_timers()
-        self._flush_out()
-
-    def unacked(self) -> bool:
-        """True while sent stream data is not yet fully acknowledged."""
-        return self.conn.has_unacked()
-
-    def close(self) -> None:
-        self.sock.close()
+        self._flush()

@@ -50,6 +50,7 @@ RC_DROP = 2
 EV_PKT = 1   # a = pn, b = flag (0 ack-eliciting, 1 dup, 2 bad-frame, 3 pure-ack)
 EV_ACK = 2   # a = largest, b = first_range_len
 EV_WIN = 3   # a = rx_consumed delta, b = rx_data_total delta
+EV_RETIRE = 4  # a = stream id, b = 1 oversize / 2 slot stolen: no txn came
 
 _EV_CAP = 4096
 _OUT_CAP = 1024
@@ -79,6 +80,7 @@ def _load():
         lib.fdn_conn_set_addr.argtypes = [vp, i32, u32]
         lib.fdn_conn_window.argtypes = [vp, i32, u64, u64]
         lib.fdn_conn_pn_add.argtypes = [vp, i32, i64]
+        lib.fdn_conn_streams.argtypes = [vp, i32, u64, u64]
         lib.fdn_datagram.argtypes = [vp, cp, i32, u32]
         lib.fdn_datagram.restype = i32
         lib.fdn_udp_sweep.argtypes = [vp, i32, i32]
@@ -128,7 +130,8 @@ def available() -> bool:
 # counter tail, in fd_net.cpp declaration order
 _COUNTERS = ("rx_dgram", "consumed", "punt", "dup", "bad_packet", "txn",
              "oversz", "evicted", "flow_violation", "auth_fail",
-             "udp_pkts", "aesni", "pclmul", "tail_retained")
+             "udp_pkts", "aesni", "pclmul", "tail_retained", "dup_stream",
+             "multi_chunk", "defer")
 COUNTER_IDX = {name: i for i, name in enumerate(_COUNTERS)}
 
 
@@ -188,6 +191,13 @@ class NetClient:
     def conn_pn_add(self, idx: int, pn: int) -> None:
         self._lib.fdn_conn_pn_add(self._h, idx, pn)
 
+    def conn_streams(self, idx: int, rx_max_streams: int,
+                     fin_floor: int = 0) -> None:
+        """The peer's stream limit (0: none; Connection
+        .rx_max_streams_uni) and, at export, the floor of the streams
+        the Python lane already saw whole."""
+        self._lib.fdn_conn_streams(self._h, idx, rx_max_streams, fin_floor)
+
     # -- the hot path --------------------------------------------------------
 
     def datagram(self, data: bytes, addr_id: int) -> int:
@@ -234,6 +244,11 @@ class NetClient:
         off = int(self.out_tbl[row, 0])
         sz = int(self.out_tbl[row, 1])
         return bytes(self.arena[off : off + sz])
+
+    def out_owner(self, row: int) -> tuple[int, int]:
+        """(connection idx, stream id) of out row `row`: whose stream
+        credit its publish returns."""
+        return int(self.out_tbl[row, 2]), int(self.out_tbl[row, 3])
 
     def counters(self) -> dict[str, int]:
         return {name: int(self.counters_view[i])

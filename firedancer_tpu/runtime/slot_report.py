@@ -174,6 +174,12 @@ def _stage_block(mets: dict, records: list) -> dict:
     dedup = fm.dedup_row(mets)
     if dedup:
         block["dedup"] = dedup
+    # the front door: a quic tile's datagrams, punts, reassembly
+    # outcomes and held transactions; a sender tile's sends, resends
+    # and calls held by the peer's credit
+    front = fm.front_row(mets)
+    if front:
+        block["front"] = front
     return block
 
 

@@ -546,14 +546,19 @@ class Stage:
             self._loop_worked = False
             c["loop_work_ns"] += t1 - t0
             c["loop_work_n"] += 1
-        elif self._loop_blocked and not halted and any(
-                cons.has_pending() for cons in self.ins):
+        elif self._loop_blocked and not halted and self._input_pending():
             c["loop_backp_ns"] += t1 - t0
             c["loop_backp_n"] += 1
         else:
             c["loop_poll_ns"] += t1 - t0
             c["loop_poll_n"] += 1
         return progressed
+
+    def _input_pending(self) -> bool:
+        """Something waits in front of the stage (asked only on a call
+        that was blocked and did nothing): a ring in front holds a
+        frag; a stage fed by a socket answers for its socket."""
+        return any(cons.has_pending() for cons in self.ins)
 
     def _sweep(self) -> bool:
         """The body of one iteration, after housekeeping: credits, the

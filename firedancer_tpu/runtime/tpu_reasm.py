@@ -14,7 +14,7 @@ The transport (QUIC when it lands; any stream framing today) calls:
 
 from __future__ import annotations
 
-from collections import OrderedDict
+from collections import OrderedDict, deque
 
 from firedancer_tpu.protocol.txn import TXN_MTU
 
@@ -34,6 +34,12 @@ class TpuReasm:
             "evicted": 0,
             "cancelled": 0,
         }
+        # keys of streams that ended without a transaction since the
+        # owner last took them (`take_ended`): (key, "evicted" |
+        # "oversz").  Their packets were acknowledged, so the transport
+        # returns their stream credit and drops their later chunks (an
+        # owner that never asks keeps only the newest)
+        self._ended: deque = deque(maxlen=4096)
 
     def append(self, key, data: bytes, fin: bool = False) -> bytes | None:
         """Accumulate stream bytes; returns the whole txn at FIN."""
@@ -46,12 +52,15 @@ class TpuReasm:
                 # streams; the tombstone clears at FIN or reset
                 if fin:
                     del self._slots[key]
+                    self._ended.append((key, "oversz"))
                 return None
         else:
             if len(self._slots) >= self.depth:
                 # steal the least-recently-active slot (its stream stalls
-                # out and will be dropped; QUIC-level retransmit recovers)
-                self._slots.popitem(last=False)
+                # out and is dropped: its chunks were acknowledged, so no
+                # retransmission brings them again)
+                old, _ = self._slots.popitem(last=False)
+                self._ended.append((old, "evicted"))
                 self.metrics["evicted"] += 1
             slot = bytearray()
             self._slots[key] = slot
@@ -60,6 +69,7 @@ class TpuReasm:
             self.metrics["oversz"] += 1
             if fin:  # stream ended at the crossing: nothing to poison
                 del self._slots[key]
+                self._ended.append((key, "oversz"))
             else:  # poison the KEY so continuation frames can't churn
                 # fresh slots and evict honest streams
                 self._slots[key] = self._DEAD
@@ -77,6 +87,13 @@ class TpuReasm:
             self.metrics["cancelled"] += 1
             return True
         return False
+
+    def take_ended(self) -> list:
+        """[(key, why)] of the streams that ended without a
+        transaction since the last call."""
+        ended = list(self._ended)
+        self._ended.clear()
+        return ended
 
     def active(self) -> int:
         return len(self._slots)

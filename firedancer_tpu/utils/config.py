@@ -24,6 +24,11 @@ from dataclasses import dataclass, field
 class LayoutConfig:
     verify_stage_count: int = 1
     bank_stage_count: int = 2
+    # sender tiles in front of a quic tile (runtime/benchs.py): 0 = no
+    # front, the generator publishes into verify's ring; n >= 1 = the
+    # front-door topology benchs x n -> UDP/QUIC -> quic -> verify
+    # (models/leader_topo.build_quic_topology_from_config)
+    benchs_stage_count: int = 0
 
 
 @dataclass
@@ -88,6 +93,23 @@ class NetConfig:
 
 
 @dataclass
+class QuicConfig:
+    # the quic tile (runtime/net.QuicIngressStage); it listens where
+    # [net] says (listen_port 0: a free port, written to the run's
+    # directory for the senders) and drains net.rx_burst datagrams a sweep
+    reasm_depth: int = 64        # reassembly slots (fd_tpu_reasm)
+    max_conns: int = 64
+    retry: bool = False          # stateless Retry before any handshake
+    # unidirectional streams a connection may have open that the tile
+    # has not handed to verify's ring yet (initial_max_streams_uni;
+    # credit returns as transactions are published)
+    stream_window: int = 64
+    # the senders' largest UDP payload (1,200: RFC 9000's floor, a
+    # client's packet size before path-MTU discovery)
+    max_datagram: int = 1200
+
+
+@dataclass
 class LedgerConfig:
     # empty = in-memory funk; a directory enables the write-ahead
     # journal + snapshot persistence (funk/persist.py)
@@ -121,6 +143,7 @@ class Config:
     poh: PohConfig = field(default_factory=PohConfig)
     shred: ShredConfig = field(default_factory=ShredConfig)
     net: NetConfig = field(default_factory=NetConfig)
+    quic: QuicConfig = field(default_factory=QuicConfig)
     ledger: LedgerConfig = field(default_factory=LedgerConfig)
     genesis: GenesisConfig = field(default_factory=GenesisConfig)
     log: LogConfig = field(default_factory=LogConfig)
@@ -178,6 +201,17 @@ def _validate(cfg: Config) -> None:
         raise ConfigError("layout.verify_stage_count must be >= 1")
     if not 1 <= cfg.layout.bank_stage_count <= 62:  # fd_pack.h MAX_BANK_TILES
         raise ConfigError("layout.bank_stage_count must be in [1, 62]")
+    if not 0 <= cfg.layout.benchs_stage_count <= 64:
+        raise ConfigError("layout.benchs_stage_count must be in [0, 64]")
+    q = cfg.quic
+    if q.reasm_depth < 1 or q.max_conns < 1 or q.stream_window < 1:
+        raise ConfigError("quic.reasm_depth, quic.max_conns and "
+                          "quic.stream_window must be >= 1")
+    if q.max_conns < cfg.layout.benchs_stage_count:
+        raise ConfigError("quic.max_conns must hold every sender tile "
+                          "(layout.benchs_stage_count)")
+    if not 256 <= q.max_datagram <= 1452:
+        raise ConfigError("quic.max_datagram must be in [256, 1452]")
     if cfg.verify.batch < 1 or cfg.verify.batch & (cfg.verify.batch - 1):
         raise ConfigError("verify.batch must be a power of 2")
     if cfg.verify.devices < 1 or cfg.verify.batch % cfg.verify.devices:

@@ -629,6 +629,40 @@ def dedup_row(src) -> dict | None:
     return {k: int(src.get(n) or 0) for n, k in DEDUP_COUNTERS.items()}
 
 
+# What the front door counts (the monitor and slotreport show them by
+# these names): the quic tile's datagrams, punts from the C
+# lane to Python, connections, the reassembler's outcomes, whole
+# transactions that waited for verify's ring and the stream credit
+# returned; a sender tile's transactions, datagrams, chunks sent again,
+# streams acknowledged and calls held by the peer's credit
+FRONT_COUNTERS = (
+    # the quic tile
+    "dgram_rx", "dgram_rx_bytes", "net_punts", "handshakes_done",
+    "conn_active", "reasm_published", "reasm_multi_chunk", "reasm_evicted",
+    "reasm_oversz", "reasm_cancelled", "reasm_dup_stream",
+    "txn_held_for_credit", "streams_granted",
+    # a sender tile (dgram_rx is both's)
+    "txn_tx", "dgram_tx", "dgram_rtx", "streams_acked",
+    "send_blocked_credit",
+)
+
+
+def front_row(src) -> dict | None:
+    """{name: count} of FRONT_COUNTERS' counters that the stage has,
+    from its registry (the monitor) or a dict of its metrics
+    (slotreport); None where the stage is neither a quic tile nor a
+    sender tile."""
+    if src is None:
+        return None
+    if isinstance(src, MetricsRegistry):
+        have = {n: src.get(n) for n in FRONT_COUNTERS if n in src._off}
+    else:
+        have = {n: src[n] for n in FRONT_COUNTERS if n in src}
+    if "reasm_published" not in have and "txn_tx" not in have:
+        return None
+    return {n: int(v or 0) for n, v in have.items()}
+
+
 def mesh_row(src) -> dict | None:
     """{"devices": n, "shard_elems": [useful lanes dispatched to chip
     i, ...]} of a verify stage over a mesh of n > 1 devices, from its

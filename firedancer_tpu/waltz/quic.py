@@ -99,6 +99,8 @@ TIME_THRESHOLD = 9 / 8
 # flow control windows (our receive side / assumed peer until updated)
 DEFAULT_MAX_DATA = 1 << 20
 DEFAULT_MAX_STREAM_DATA = 1 << 18
+# transport parameter ids (RFC 9000 §18.2) this endpoint reads and writes
+TP_INITIAL_MAX_STREAMS_UNI = 0x09
 
 
 class QuicError(RuntimeError):
@@ -129,6 +131,37 @@ def varint_decode(buf: bytes, off: int) -> tuple[int, int]:
         raise QuicError("truncated varint body")
     v = int.from_bytes(buf[off : off + ln], "big") & ((1 << (8 * ln - 2)) - 1)
     return v, off + ln
+
+
+def encode_transport_params(params: dict[int, int]) -> bytes:
+    """{id: integer value} -> the extension's body (§18: id, length,
+    value, each a varint)."""
+    out = bytearray()
+    for k, v in params.items():
+        val = varint_encode(v)
+        out += varint_encode(k) + varint_encode(len(val)) + val
+    return bytes(out)
+
+
+def decode_transport_params(buf: bytes | None) -> dict[int, int]:
+    """The integer-valued parameters of a peer's extension body; a
+    parameter whose value is not one varint is skipped, a truncated
+    body ends the walk (untrusted input: never raises)."""
+    out: dict[int, int] = {}
+    off = 0
+    buf = buf or b""
+    try:
+        while off < len(buf):
+            k, off = varint_decode(buf, off)
+            ln, off = varint_decode(buf, off)
+            if off + ln > len(buf):
+                break
+            if ln in (1, 2, 4, 8) and 1 << (buf[off] >> 6) == ln:
+                out[k] = varint_decode(buf, off)[0]
+            off += ln
+    except (QuicError, IndexError):
+        pass
+    return out
 
 
 # -- per-level packet protection keys -----------------------------------------
@@ -167,21 +200,33 @@ def _hp_mask(hp: Aes, sample: bytes) -> bytes:
     return hp.encrypt_block(sample)
 
 
-def export_rx_app_keys(conn: "Connection") -> tuple[bytes, bytes, bytes] | None:
-    """Raw (key, iv, hp) bytes of the connection's APPLICATION-level rx
-    side, re-derived from the TLS secret (Keys keeps only the schedule
-    objects, never the raw bytes).  The native net lane installs these
-    into its interned connection table; None until the handshake has
-    produced the application secrets."""
+def _export_app_keys(conn: "Connection", tx: bool):
+    """Raw (key, iv, hp) bytes of one side of the connection's
+    APPLICATION level, re-derived from the TLS secret (Keys keeps only
+    the schedule objects, never the raw bytes); None until the
+    handshake has produced the application secrets."""
     sec = conn.tls.secrets.get(APPLICATION)
     if sec is None:
         return None
-    s = sec[1] if conn.is_client else sec[0]
+    s = sec[0] if conn.is_client == tx else sec[1]
     return (
         hkdf_expand_label(s, "quic key", b"", 16),
         hkdf_expand_label(s, "quic iv", b"", 12),
         hkdf_expand_label(s, "quic hp", b"", 16),
     )
+
+
+def export_rx_app_keys(conn: "Connection") -> tuple[bytes, bytes, bytes] | None:
+    """The rx side: what the native net lane installs into its
+    interned connection table."""
+    return _export_app_keys(conn, tx=False)
+
+
+def export_tx_app_keys(conn: "Connection") -> tuple[bytes, bytes, bytes] | None:
+    """The tx side: what a sender's key log holds, so that a reader of
+    its captured datagrams (ops/ref/quic_plain.py) can open them on its
+    own."""
+    return _export_app_keys(conn, tx=True)
 
 
 # -- packet sealing / opening -------------------------------------------------
@@ -549,7 +594,8 @@ def peek_dcid(datagram: bytes, *, short_dcid_len: int) -> bytes | None:
 def parse_frames(payload: bytes):
     """Yield ('crypto', off, data) | ('stream', StreamEvent) |
     ('ack', ranges) | ('max_data', n) | ('max_stream_data', sid, n) |
-    ('handshake_done',) | ('close', code) events."""
+    ('max_streams_uni', n) | ('handshake_done',) | ('close', code)
+    events."""
     off = 0
     n = len(payload)
     while off < n:
@@ -621,7 +667,10 @@ def parse_frames(payload: bytes):
             sid, off = varint_decode(payload, off)
             v, off = varint_decode(payload, off)
             yield ("max_stream_data", sid, v)
-        elif ft in (FT_MAX_STREAMS_BIDI, FT_MAX_STREAMS_UNI,
+        elif ft == FT_MAX_STREAMS_UNI:
+            v, off = varint_decode(payload, off)
+            yield ("max_streams_uni", v)
+        elif ft in (FT_MAX_STREAMS_BIDI,
                     FT_DATA_BLOCKED, FT_STREAMS_BLOCKED_BIDI,
                     FT_STREAMS_BLOCKED_UNI, FT_RETIRE_CONNECTION_ID):
             _v, off = varint_decode(payload, off)
@@ -661,6 +710,7 @@ class _OrderedStream:
         self.delivered = 0
         self.segments: dict[int, bytes] = {}
         self.fin_size: int | None = None
+        self.offs: set[int] = set()  # offsets at which a chunk came
 
     def insert(self, off: int, data: bytes) -> bytes:
         if data and off + len(data) > self.delivered:
@@ -835,6 +885,28 @@ class Connection:
         self.tx_max_data = DEFAULT_MAX_DATA
         self.tx_data_total = 0
         self.tx_stream_limit: dict[int, int] = {}
+        # stream credit (§4.6), client-initiated unidirectional streams:
+        # how many the peer lets us open (its initial_max_streams_uni,
+        # raised by MAX_STREAMS; None: it named no limit), and how many
+        # we let the peer open (None: no limit is enforced; the owner
+        # of a server connection sets it and raises it with
+        # `grant_streams_uni`)
+        self.tx_max_streams_uni: int | None = None
+        self.rx_max_streams_uni: int | None = None
+        # peer-opened unidirectional streams delivered whole or given
+        # up: every index below the floor, and those in the set.  A
+        # frame of one of them (a spurious retransmission) is dropped
+        self.rx_fin_floor = 0
+        self.rx_fin_set: set[int] = set()
+        self.rx_dup_stream = 0
+        # of the streams delivered whole, those joined from chunks at
+        # more than one offset
+        self.rx_multi_chunk = 0
+        # our streams whose last chunk's packet the peer acknowledged
+        self.streams_fin_acked = 0
+        # per-packet payload budget of flush(): an owner that must keep
+        # its datagrams under a size lowers it
+        self.max_payload = MAX_FRAMES_PAYLOAD
         self.blocked_out: list[tuple[int, bytes, bool]] = []
         # stream ids with a parked write — O(1) ordering check in
         # _send_stream_inner (a linear scan there is O(n^2) under
@@ -863,6 +935,10 @@ class Connection:
             else:
                 self.keys_tx[lvl] = Keys.from_secret(ssec)
                 self.keys_rx[lvl] = Keys.from_secret(csec)
+            if lvl == APPLICATION and self.tx_max_streams_uni is None:
+                self.tx_max_streams_uni = decode_transport_params(
+                    self.tls.peer_transport_params
+                ).get(TP_INITIAL_MAX_STREAMS_UNI)
 
     # -- inbound --
 
@@ -945,6 +1021,10 @@ class Connection:
                 elif ev[0] == "max_data":
                     self.tx_max_data = max(self.tx_max_data, ev[1])
                     self._drain_blocked()
+                elif ev[0] == "max_streams_uni":
+                    if self.tx_max_streams_uni is not None:
+                        self.tx_max_streams_uni = max(
+                            self.tx_max_streams_uni, ev[1])
                 elif ev[0] == "max_stream_data":
                     _, sid, v = ev
                     cur = self.tx_stream_limit.get(sid, DEFAULT_MAX_STREAM_DATA)
@@ -964,6 +1044,16 @@ class Connection:
     def _rx_flow_check(self, ev: StreamEvent) -> None:
         """Enforce our advertised windows on inbound stream data."""
         end = ev.offset + len(ev.data)
+        if ev.stream_id & 3 == (3 if self.is_client else 2):
+            if self.stream_finished(ev.stream_id):
+                return  # a late copy: no window is charged twice
+            if self.rx_max_streams_uni is not None and (
+                ev.stream_id >> 2 >= self.rx_max_streams_uni
+            ):
+                raise QuicError(
+                    f"stream {ev.stream_id} over the stream limit "
+                    f"({self.rx_max_streams_uni})"
+                )
         limit = self.rx_stream_limit.get(ev.stream_id, DEFAULT_MAX_STREAM_DATA)
         if end > limit:
             raise QuicError(
@@ -1034,7 +1124,9 @@ class Connection:
             if sample >= 0:
                 self._rtt_update(sample)
         for pn in newly:
-            del sent[pn]
+            for fr in sent.pop(pn).frames:
+                if fr[0] == "stream" and fr[4]:
+                    self.streams_fin_acked += 1
         if newly:
             self.pto_count = 0
         # packet-threshold loss: anything ACK_REORDER_THRESH below the
@@ -1143,12 +1235,20 @@ class Connection:
         for sid, data, fin in pending:
             self._send_stream_inner(sid, data, fin)
 
+    def rx_window_low(self) -> bool:
+        """Under half a window is left of what we advertised: time for
+        a MAX_DATA frame.  Measured against what is LEFT, not against
+        the total consumed (which passes any multiple of a window once
+        and for all: every packet would then bring a MAX_DATA frame,
+        and the peer owe an ACK for each)."""
+        return self.rx_max_data - self.rx_consumed < DEFAULT_MAX_DATA // 2
+
     def _rx_window_updates(self, dirty: set[int]) -> None:
         """Advertise bigger windows once half the current one is used.
         Only `dirty` streams (delivered-count changed this batch) are
         examined — the TPU client opens a stream per txn, so scanning
         every stream ever seen would be O(N^2) over a batch."""
-        if self.rx_consumed * 2 > self.rx_max_data:
+        if self.rx_window_low():
             self.rx_max_data = self.rx_consumed + DEFAULT_MAX_DATA
             self.ctrl_out.append(
                 bytes([FT_MAX_DATA]) + varint_encode(self.rx_max_data)
@@ -1216,7 +1316,7 @@ class Connection:
                     pending.append((stream_frame(sid, soff, data, fin),
                                     ("stream", sid, soff, data, fin)))
                 self.app_out.clear()
-            # pack frames greedily into <= MAX_FRAMES_PAYLOAD packets (a
+            # pack frames greedily into <= max_payload packets (a
             # single frame larger than the budget still goes out alone —
             # CRYPTO flights exceed it and the link MTU tolerates them)
             while pending:
@@ -1224,7 +1324,7 @@ class Connection:
                 record: list = []
                 while pending and (
                     not frames
-                    or len(frames) + len(pending[0][0]) <= MAX_FRAMES_PAYLOAD
+                    or len(frames) + len(pending[0][0]) <= self.max_payload
                 ):
                     wire, rec = pending.pop(0)
                     frames.extend(wire)
@@ -1252,6 +1352,52 @@ class Connection:
                     self.last_ae_time[lvl] = now  # re-arm the PTO timer
         return out
 
+    def send_stream_packet(self, stream_id: int, offset: int, data: bytes,
+                           fin: bool, now: float | None = None) -> bytes:
+        """One STREAM chunk in a packet of its own, sealed now: the
+        sender tile's path (a datagram a chunk, no queue between the
+        write and the wire).  The chunk is recorded for loss recovery
+        like one `flush` packed; connection flow control is the
+        caller's to ask first (`tx_data_total` against `tx_max_data`)."""
+        now = _time.monotonic() if now is None else now
+        pn = self.pn_next[APPLICATION]
+        self.pn_next[APPLICATION] = pn + 1
+        self.tx_data_total += len(data)
+        self.sent[APPLICATION][pn] = SentPacket(
+            pn, now, [("stream", stream_id, offset, data, fin)])
+        self.last_ae_time[APPLICATION] = now
+        return seal_packet(
+            self.keys_tx[APPLICATION], level=APPLICATION,
+            dcid=self.remote_cid, scid=self.local_cid, pn=pn,
+            payload=stream_frame(stream_id, offset, data, fin),
+        )
+
+    def grant_streams_uni(self, n: int) -> None:
+        """Let the peer open `n` more unidirectional streams (a
+        MAX_STREAMS frame on the next flush; lost ones are resent)."""
+        self.rx_max_streams_uni = (self.rx_max_streams_uni or 0) + n
+        self.ctrl_out.append(bytes([FT_MAX_STREAMS_UNI])
+                             + varint_encode(self.rx_max_streams_uni))
+
+    def stream_finished(self, stream_id: int) -> bool:
+        idx = stream_id >> 2
+        return idx < self.rx_fin_floor or idx in self.rx_fin_set
+
+    def stream_finish(self, stream_id: int) -> None:
+        """A peer-opened stream is over for good (delivered whole, or
+        given up by the owner: oversize, its reassembly slot stolen):
+        its state goes, and a later frame of it is dropped."""
+        self.stream_rx.pop(stream_id, None)
+        self.rx_stream_high.pop(stream_id, None)
+        self.rx_stream_limit.pop(stream_id, None)
+        done = self.rx_fin_set
+        done.add(stream_id >> 2)
+        floor = self.rx_fin_floor
+        while floor in done:
+            done.discard(floor)
+            floor += 1
+        self.rx_fin_floor = floor
+
     def probe_datagram(self, frames: bytes) -> bytes | None:
         """Seal ONE application packet carrying `frames` for an
         off-path probe (PATH_CHALLENGE to a migrating peer's new
@@ -1278,15 +1424,25 @@ class Connection:
         arriving ahead of a gap must not finalize a short stream."""
         out = []
         dirty: set[int] = set()
+        peer_uni = 3 if self.is_client else 2
         for ev in events:
+            tracked = ev.stream_id & 3 == peer_uni
+            if tracked and self.stream_finished(ev.stream_id):
+                self.rx_dup_stream += 1
+                continue
             st = self.stream_rx.setdefault(ev.stream_id, _OrderedStream())
             if ev.fin:
                 st.fin_size = ev.offset + len(ev.data)
+            if ev.data:
+                st.offs.add(ev.offset)
             ready = st.insert(ev.offset, ev.data)
             if ready:
                 self.rx_consumed += len(ready)
                 dirty.add(ev.stream_id)
             if ready or st.finished:
                 out.append((ev.stream_id, ready, st.finished))
+            if tracked and st.finished:
+                self.rx_multi_chunk += len(st.offs) > 1
+                self.stream_finish(ev.stream_id)
         self._rx_window_updates(dirty)
         return out

@@ -402,6 +402,7 @@ static i64 decode_pn(u64 truncated, int pn_nbits, i64 largest) {
 #define NET_MAX_RANGES 32
 #define NET_STREAM_LIMIT ((u64)1 << 18)   // quic.DEFAULT_MAX_STREAM_DATA
 #define NET_TXN_MTU 1232
+#define NET_FIN_WINDOW 4096
 
 struct PnWindow {
   i64 rng[NET_MAX_RANGES][2];  // ascending disjoint [lo, hi]
@@ -469,7 +470,38 @@ struct NetConn {
   PnWindow win;
   u64 rx_max_data;    // synced down from the Python Connection
   u64 rx_data_total;  // mirrored flow accounting (sum of stream highs)
+  // client-opened unidirectional streams (sid & 3 == 2), by index
+  // sid >> 2: how many the peer may open (0: no limit; synced down,
+  // quic.Connection.rx_max_streams_uni), and which are over for good
+  // (delivered whole, oversize, slot stolen): every index below the
+  // floor and the set bits of a ring over the NET_FIN_WINDOW above it.
+  // A frame of a finished stream is a late copy and is swallowed
+  // (Connection.stream_finished)
+  u64 rx_max_streams;
+  u64 fin_floor;
+  u64 fin_bits[NET_FIN_WINDOW / 64];
 };
+
+static inline int fin_test(const NetConn *n, u64 idx) {
+  if (idx < n->fin_floor) return 1;
+  u64 b = idx % NET_FIN_WINDOW;
+  return (int)((n->fin_bits[b >> 6] >> (b & 63)) & 1);
+}
+
+// idx in [fin_floor, fin_floor + NET_FIN_WINDOW): classify_frames
+// defers the packet otherwise
+static void fin_mark(NetConn *n, u64 idx) {
+  if (idx < n->fin_floor) return;
+  u64 b = idx % NET_FIN_WINDOW;
+  n->fin_bits[b >> 6] |= (u64)1 << (b & 63);
+  for (;;) {
+    b = n->fin_floor % NET_FIN_WINDOW;
+    u64 m = (u64)1 << (b & 63);
+    if (!(n->fin_bits[b >> 6] & m)) break;
+    n->fin_bits[b >> 6] &= ~m;
+    n->fin_floor++;
+  }
+}
 
 // =============================================================================
 // reassembly slots (tpu_reasm.py port + out-of-order ranges)
@@ -487,6 +519,7 @@ struct Slot {
   u64 high;        // max(offset+len) seen (flow accounting)
   u64 lru;
   i32 nrg;
+  u32 nfr;         // STREAM chunks that brought bytes (multi_chunk)
   u64 rg[SLOT_MAX_RANGES][2];  // received [off, end) ranges, ascending
   u8 buf[NET_TXN_MTU];
 };
@@ -495,7 +528,9 @@ struct Slot {
 // context
 // =============================================================================
 
-enum { EV_PKT = 1, EV_ACK = 2, EV_WIN = 3 };
+// EV_RETIRE: a stream ended without a transaction (a = sid, b = 1
+// oversize, 2 its slot was stolen): the owner returns its stream credit
+enum { EV_PKT = 1, EV_ACK = 2, EV_WIN = 3, EV_RETIRE = 4 };
 
 #define EV_CAP 4096
 #define OUT_CAP 1024
@@ -504,7 +539,8 @@ enum { EV_PKT = 1, EV_ACK = 2, EV_WIN = 3 };
 enum {
   C_RX_DGRAM = 0, C_CONSUMED, C_PUNT, C_DUP, C_BAD_PACKET, C_TXN,
   C_OVERSZ, C_EVICTED, C_FLOW_VIOLATION, C_AUTH_FAIL, C_UDP_PKTS,
-  C_AESNI, C_PCLMUL, C_TAIL_RETAINED, C_COUNT,
+  C_AESNI, C_PCLMUL, C_TAIL_RETAINED, C_DUP_STREAM, C_MULTI_CHUNK,
+  C_DEFER, C_COUNT,
 };
 
 struct NetCtx {
@@ -516,7 +552,7 @@ struct NetCtx {
   u64 lru_tick;
   u64 ev[EV_CAP][4];
   i32 ev_n;
-  u64 out_tbl[OUT_CAP][4];  // off, sz, sig, tsorig
+  u64 out_tbl[OUT_CAP][4];  // off, sz, conn idx, stream id
   i32 out_n;
   u64 arena_used;
   u8 *arena;
@@ -621,6 +657,9 @@ i32 fdn_conn_add(void *ctx, const u8 *dcid, u32 addr_id, const u8 *key,
   n->addr_id = addr_id;
   n->rx_max_data = rx_max_data;
   n->rx_data_total = rx_data_total;
+  n->rx_max_streams = 0;     // fdn_conn_streams sets both
+  n->fin_floor = 0;
+  memset(n->fin_bits, 0, sizeof(n->fin_bits));
   memcpy(n->iv, iv, 12);
   if (gcm_init(key, 16, &n->pp) != 0) { n->state = 2; return -1; }
   if (aes_expand(hp, 16, &n->hp) != 0) { n->state = 2; return -1; }
@@ -662,6 +701,20 @@ void fdn_conn_window(void *ctx, i32 idx, u64 rx_max_data,
 
 // Reverse pn sync: the Python lane consumed an APPLICATION packet for a
 // native-owned conn (a punted frame mix) — keep the dedup window honest.
+// The peer's stream limit (0: none) and, at export, the floor of the
+// streams the Python lane already saw whole.
+void fdn_conn_streams(void *ctx, i32 idx, u64 rx_max_streams,
+                      u64 fin_floor) {
+  NetCtx *c = (NetCtx *)ctx;
+  if (idx < 0 || idx >= c->cap || c->conns[idx].state != 1) return;
+  NetConn *n = &c->conns[idx];
+  n->rx_max_streams = rx_max_streams;
+  if (fin_floor > n->fin_floor) {
+    memset(n->fin_bits, 0, sizeof(n->fin_bits));
+    n->fin_floor = fin_floor;
+  }
+}
+
 void fdn_conn_pn_add(void *ctx, i32 idx, i64 pn) {
   NetCtx *c = (NetCtx *)ctx;
   if (idx < 0 || idx >= c->cap || c->conns[idx].state != 1) return;
@@ -710,6 +763,18 @@ static inline void ev_push(NetCtx *c, u64 type, u64 a, u64 b, u64 d) {
   row[0] = type; row[1] = a; row[2] = b; row[3] = d;
 }
 
+// A client-opened unidirectional stream ended without a transaction
+// (why: 1 oversize, 2 its slot was stolen).  Its packets were
+// acknowledged, so it can never complete: it is over (later chunks
+// are swallowed, not joined into a short transaction) and its owner
+// gets the stream credit back.
+static inline void stream_retire(NetCtx *c, NetConn *conn, i32 ci, u64 sid,
+                                 u64 why) {
+  if ((sid & 3) != 2) return;
+  fin_mark(conn, sid >> 2);
+  ev_push(c, EV_RETIRE, (u64)ci, sid, why);
+}
+
 static Slot *slot_find(NetCtx *c, i32 conn_idx, u8 gen, u64 sid) {
   for (i32 i = 0; i < c->depth; i++) {
     Slot *s = &c->slots[i];
@@ -728,6 +793,11 @@ static Slot *slot_new(NetCtx *c, i32 conn_idx, u8 gen, u64 sid) {
     if (!victim || s->lru < victim->lru) victim = s;
   }
   c->counters[C_EVICTED]++;  // steal the least-recently-active slot
+  if (victim->conn_idx >= 0 && victim->conn_idx < c->cap) {
+    NetConn *vc = &c->conns[victim->conn_idx];
+    if (vc->state == 1 && vc->gen == victim->conn_gen)
+      stream_retire(c, vc, victim->conn_idx, victim->sid, 2);
+  }
 init:
   memset(victim, 0, offsetof(Slot, buf));
   victim->used = 1;
@@ -770,7 +840,11 @@ enum { RC_CONSUMED = 0, RC_PUNT = 1, RC_DROP = 2 };
 
 // Frame classification for the PUNT contract.  CONSUME must be exactly
 // the set waltz/quic.py handles-or-skips without control-plane effects.
-enum { FR_CONSUME = 0, FR_PUNT = 1, FR_BAD = 2 };
+// FR_DEFER: a stream so far above the lowest unfinished one that the
+// finished-stream ring cannot hold it: the packet is dropped UNSEEN (no
+// pn recorded, so never acknowledged) and the sender's loss recovery
+// brings it again once the floor has moved.
+enum { FR_CONSUME = 0, FR_PUNT = 1, FR_BAD = 2, FR_DEFER = 3 };
 
 struct FrameScan {
   // one ACK frame (range_cnt==0, no ECN) may be consumed natively
@@ -778,7 +852,8 @@ struct FrameScan {
   u64 ack_largest, ack_first_len;
 };
 
-static int classify_frames(const u8 *p, size_t n, FrameScan *fs) {
+static int classify_frames(const NetConn *conn, const u8 *p, size_t n,
+                           FrameScan *fs) {
   size_t off = 0;
   u64 v, sid, slen;
   fs->have_ack = 0;
@@ -815,6 +890,9 @@ static int classify_frames(const u8 *p, size_t n, FrameScan *fs) {
       case 0x08: case 0x09: case 0x0A: case 0x0B:
       case 0x0C: case 0x0D: case 0x0E: case 0x0F:   // STREAM
         if (vdec(p, n, &off, &sid)) return FR_BAD;
+        if ((sid & 3) == 2 &&
+            (sid >> 2) >= conn->fin_floor + NET_FIN_WINDOW)
+          return FR_DEFER;
         if (ft & 0x04) { if (vdec(p, n, &off, &v)) return FR_BAD; }
         if (ft & 0x02) {
           if (vdec(p, n, &off, &slen) || off + slen > n) return FR_BAD;
@@ -863,9 +941,11 @@ static int apply_frames(NetCtx *c, i32 ci, const u8 *p, size_t n,
   while (off < n) {
     u8 ft = p[off++];
     // ack_pending parity: Python adds it only for frames parse_frames
-    // YIELDS (ping/stream/max_data/max_stream_data here — the silently
+    // YIELDS (ping/stream/max_data/max_stream_data/max_streams_uni
+    // here — the silently
     // skipped frame kinds and pure padding/ACK never trigger an ack)
-    if (ft == 0x01 || (ft >= 0x08 && ft <= 0x11)) *ack_elicit = 1;
+    if (ft == 0x01 || (ft >= 0x08 && ft <= 0x11) || ft == 0x13)
+      *ack_elicit = 1;
     if (ft == 0x00 || ft == 0x01) continue;
     if (ft == 0x02) {  // single-range ACK (classified consumable)
       u64 largest = 0, delay = 0, range_cnt = 0, first = 0;
@@ -884,6 +964,19 @@ static int apply_frames(NetCtx *c, i32 ci, const u8 *p, size_t n,
       off += slen;
       int fin = ft & 0x01;
       u64 end = soff + slen;
+      int uni = (sid & 3) == 2;
+      if (uni) {
+        // a late copy of a stream that is over: swallowed, no window
+        // charged twice (Connection._rx_flow_check's first test)
+        if (fin_test(conn, sid >> 2)) {
+          c->counters[C_DUP_STREAM]++;
+          continue;
+        }
+        if (conn->rx_max_streams && (sid >> 2) >= conn->rx_max_streams) {
+          c->counters[C_FLOW_VIOLATION]++;
+          return RC_DROP;
+        }
+      }
       // flow control (quic.Connection._rx_flow_check)
       if (end > NET_STREAM_LIMIT) {
         c->counters[C_FLOW_VIOLATION]++;
@@ -903,20 +996,26 @@ static int apply_frames(NetCtx *c, i32 ci, const u8 *p, size_t n,
       s->lru = ++c->lru_tick;
       if (end > high) s->high = end;
       if (s->dead) {   // poisoned oversize stream: swallow until FIN
-        if (fin) s->used = 0;
+        if (fin) {
+          s->used = 0;
+          stream_retire(c, conn, ci, sid, 1);
+        }
         continue;
       }
       if (fin) { s->fin = 1; s->fin_size = end; }
       if (end > NET_TXN_MTU) {  // oversize: tombstone (tpu_reasm rule)
         c->counters[C_OVERSZ]++;
-        if (fin) s->used = 0;
-        else s->dead = 1;
+        if (fin) {
+          s->used = 0;
+          stream_retire(c, conn, ci, sid, 1);
+        } else s->dead = 1;
         continue;
       }
       if (slen) {
         memcpy(s->buf + soff, data, slen);
         u64 before = s->delivered;
         s->delivered = slot_insert_range(s, soff, end);
+        s->nfr++;
         if (s->delivered > before) *consumed_delta += s->delivered - before;
       } else if (fin && !s->nrg) {
         // zero-length FIN-only stream: delivers an empty txn
@@ -929,12 +1028,14 @@ static int apply_frames(NetCtx *c, i32 ci, const u8 *p, size_t n,
           u64 *row = c->out_tbl[c->out_n++];
           row[0] = c->arena_used;
           row[1] = s->fin_size;
-          row[2] = 0;  // sig: stamped by the stage at publish
-          row[3] = 0;  // tsorig: stamped by the stage at publish
+          row[2] = (u64)ci;  // whose stream credit its publish returns
+          row[3] = sid;      // (sig and tsorig are the stage's, at publish)
           memcpy(c->arena + c->arena_used, s->buf, s->fin_size);
           c->arena_used += s->fin_size;
           c->counters[C_TXN]++;
+          if (s->nfr > 1) c->counters[C_MULTI_CHUNK]++;
         }
+        if (uni) fin_mark(conn, sid >> 2);
         s->used = 0;
       }
       continue;
@@ -1037,8 +1138,9 @@ static i32 fdn_datagram_inner(NetCtx *c, const u8 *data, i32 sz,
     return RC_CONSUMED;
   }
   FrameScan fs;
-  int cls = classify_frames(pt, ct_len, &fs);
+  int cls = classify_frames(conn, pt, ct_len, &fs);
   if (cls == FR_PUNT) { c->counters[C_PUNT]++; return RC_PUNT; }
+  if (cls == FR_DEFER) { c->counters[C_DEFER]++; return RC_DROP; }
   if (cls == FR_BAD) {
     // Python: tracker.add already ran when parse_frames raises
     pn_add(&conn->win, pn);
