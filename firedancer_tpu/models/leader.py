@@ -35,6 +35,7 @@ from firedancer_tpu.runtime.dedup import DedupStage
 from firedancer_tpu.runtime.pack_stage import NativePackStage, PackStage
 from firedancer_tpu.runtime.poh_stage import PohStage
 from firedancer_tpu.runtime.shred_stage import ShredStage
+from firedancer_tpu.runtime.stage import DEFAULT_BURST
 from firedancer_tpu.runtime.store import StoreStage
 from firedancer_tpu.runtime.verify import VerifyStage
 from firedancer_tpu.tango import shm
@@ -150,6 +151,21 @@ class LeaderPipeline:
 
     def report(self) -> dict:
         return {s.name: dict(s.metrics.counters) for s in self.stages}
+
+
+def _take_turns(verifies: list) -> None:
+    """The one-thread form's flow control.  Its stages take turns, and
+    its pack sheds what its pool cannot hold — it never leaves its ring
+    unpolled (build_leader_pipeline's docstring; `hold_when_full` is the
+    process form's) — so the one thing that keeps a closed-loop flood
+    under what pack and the banks land in a turn (about 30 transfers)
+    is how much the stage in front of pack takes in a turn.  Verify's
+    own sweep, a quarter of the ring in front (256), is for a stage
+    whose consumer pushes back or whose thread is its own; at 64 a turn
+    this pipeline's pack drops half of what verify verified.  Here
+    verify keeps Stage's sweep."""
+    for v in verifies:
+        v.burst = DEFAULT_BURST
 
 
 def build_leader_pipeline_from_config(cfg, **overrides) -> "LeaderPipeline":
@@ -292,6 +308,7 @@ def build_leader_pipeline(
         )
         for i in range(n_verify)
     ]
+    _take_turns(verifies)
     if use_native_pack:
         dedup = None
         pack = NativePackStage(
@@ -502,6 +519,7 @@ def build_sharded_leader_pipeline(
         batch_deadline_s=batch_deadline_s,
         precomputed_ok=verify_precomputed,
     )
+    _take_turns([verify])
     if use_native_pack:
         dedup = None
         pack = NativePackStage(

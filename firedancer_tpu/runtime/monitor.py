@@ -324,6 +324,7 @@ class MonitorSession:
             row["loop"] = fm.loop_row([j.registry])
             row["batch_closes"] = fm.batch_close_row([j.registry])
             row["chip_empty"] = fm.chip_empty_row(j.registry)
+            row["intake"] = fm.intake_row(j.registry)
             row["mesh"] = fm.mesh_row(j.registry)
             row["votes"] = fm.vote_row(j.registry)
             row["dedup"] = fm.dedup_row(j.registry)
@@ -433,10 +434,12 @@ class MonitorSession:
         # both tiled axes); then, between the two samples, the share
         # of the time the chip had nothing of the stage's to run, and
         # of that the thread's time in other stages and in the stage's
-        # own blocking calls
+        # own blocking calls; and how many frags one intake crossing
+        # took (the stage's whole burst under a backlog)
         for r in rows:
             bc = r.get("batch_closes")
             if bc:
+                before = prev_by.get(r["stage"]) or {}
                 lines.append(
                     f"{r['stage']}: batches closed "
                     + " ".join(f"{k}={bc[k]:,}" for k in fm.BATCH_CLOSES)
@@ -446,10 +449,12 @@ class MonitorSession:
                     + f"  fit_pad_lanes={bc['fit_pad_lanes']:,}"
                     + f"  verify_fail_elems={bc['fail_elems']:,}"
                     + f"  kernel_fold_lanes={bc['fold_lanes']}"
+                    + ("  " + fm.format_frags_per_crossing(
+                        r["intake"], before.get("intake"))
+                       if r.get("intake") else "")
                     + ("  " + fm.format_chip_empty(
-                        r["chip_empty"],
-                        (prev_by.get(r["stage"]) or {}).get("chip_empty"),
-                        dt_s) if r.get("chip_empty") else ""))
+                        r["chip_empty"], before.get("chip_empty"), dt_s)
+                       if r.get("chip_empty") else ""))
             mesh = r.get("mesh")
             if mesh:
                 lines.append(

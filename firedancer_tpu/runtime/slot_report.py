@@ -142,6 +142,11 @@ def _stage_block(mets: dict, records: list) -> dict:
                      fm.BATCH_FIT_PAD_LANES, fm.VERIFY_FAIL_ELEMS,
                      fm.KERNEL_FOLD_LANES):
             block[name] = int(mets.get(name, 0) or 0)
+        # and how many frags one intake crossing took, since boot
+        intake = fm.intake_row(mets)
+        if intake and intake["crossings"]:
+            block["frags_per_crossing"] = (intake["frags"]
+                                           / intake["crossings"])
     # the thread's ledger: what of the stage's loop time went to work,
     # to backpressure (the tile behind it held it up) and to empty
     # polls, since boot.  With a process a tile: the busiest limits

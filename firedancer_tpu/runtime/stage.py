@@ -38,6 +38,10 @@ from .autotune import OCC_EDGES
 
 _now_ns = time.monotonic_ns
 
+# what one sweep takes from the rings in front unless the stage says
+# otherwise (Stage.burst)
+DEFAULT_BURST = 16
+
 
 # tango.native, resolved lazily: stages must boot (and the Python lane
 # must run) in toolchain-less environments where the import-time .so
@@ -201,8 +205,13 @@ class Stage:
         # message would wedge upstream; the reference makes such links
         # reliable via credit flow, fd_topo.h:99-101).
         self.require_credit = False
-        # frags drained per run_once sweep (see run_once's burst loop)
-        self.burst = 16
+        # the most frags one run_once sweep takes from the rings in
+        # front, on all three intake paths.  A stage whose sweep costs
+        # much more than its frags sets its own (pack, dedup; verify: a
+        # quarter of the ring in front).  Where `backlogged` is read it
+        # has to stay under that ring's depth: a sweep that can empty
+        # the ring says nothing about what waits behind it (_note_sweep)
+        self.burst = DEFAULT_BURST
         # the last intake sweep took its whole burst from the rings in
         # front: more is waiting there (_note_sweep; the verify stage's
         # close rule reads it)

@@ -63,6 +63,21 @@ asked in `_deadline_close` on the native and the Python lane alike):
 The one thing the rule reads that is not the window is the intake's
 `backlogged` (runtime/stage.py `_note_sweep`): the last intake sweep
 took its whole burst from the ring in front, so more is waiting there.
+A burst, for this stage, is a quarter of the shallowest ring in front
+(at most a batch, at least Stage's 16; the constructor works it out
+from `ins` and `batch`): everything around the C crossing — the loop's
+stamps, the credit checks, the pump's poll of the device, the wait
+books — is paid once a sweep whatever the sweep takes, and so is a call
+of the stage in front, which offers what the ring has credits for, so
+a 1,024-lane batch is gathered in 4 sweeps where a burst of 16 made it
+64.  It stays under the ring's depth because the evidence needs it to:
+a sweep that may take all the ring can hold empties it every time and
+says nothing about what is waiting behind it; a quarter leaves a
+standing backlog three quarters of a ring to show in.  (The one-thread
+leader pipeline, whose pack sheds what its pool cannot hold instead of
+pushing back, sets its verify stages back to Stage's 16: what the
+stage in front of such a pack takes in a turn is that pipeline's only
+flow control — models/leader._take_turns.)
 While that holds, a batch that would run now but part empty stays open
 and fills — (a) waits — and goes the way full batches go; the first
 sweep that comes back short ends the backlog, and the batch goes at
@@ -409,6 +424,13 @@ class VerifyStage(Stage):
         self.shard_idx = shard_idx
         self.shard_cnt = shard_cnt
         self.batch = batch
+        # what one intake sweep may take, from the stage's own
+        # geometry: a quarter of the shallowest ring in front, at most
+        # a batch, at least Stage's default (module docstring: a
+        # sweep's cost is paid once a sweep, and the backlog's evidence
+        # needs a whole burst to be less than the ring can hold)
+        depth = min((c.link.depth for c in self.ins), default=0)
+        self.burst = max(self.burst, min(batch, depth // 4))
         self.max_msg_len = max_msg_len
         self.batch_deadline_s = batch_deadline_s
         # the most batches in flight: WINDOW_DEPTH, or the fewer a caller

@@ -609,6 +609,35 @@ def batch_close_row(regs: list) -> dict | None:
     return row
 
 
+def intake_row(src) -> dict | None:
+    """{"frags": frags_in, "crossings": nsweep_crossings} of a stage
+    whose intake is a native sweep, from its registry (the monitor) or
+    a dict of its metrics (slotreport); None where it has no such
+    sweep.  Over two samples, frags a crossing: how much one sweep
+    takes (a verify stage's whole burst under a backlog, 0-2 where it
+    is paced)."""
+    if src is None:
+        return None
+    if isinstance(src, MetricsRegistry):
+        src = {n: src.get(n) for n in ("frags_in", "nsweep_crossings")
+               if n in src._off}
+    if "nsweep_crossings" not in src:
+        return None
+    return {"frags": int(src.get("frags_in") or 0),
+            "crossings": int(src.get("nsweep_crossings") or 0)}
+
+
+def format_frags_per_crossing(row: dict, prev: dict | None) -> str:
+    """'frags/crossing=255.8' between two samples' intake_row (since
+    boot where there is no earlier one); '-' where no crossing took a
+    frag in between."""
+    prev = prev or {"frags": 0, "crossings": 0}
+    n = row["crossings"] - prev["crossings"]
+    if n <= 0:
+        return "frags/crossing=-"
+    return f"frags/crossing={(row['frags'] - prev['frags']) / n:.1f}"
+
+
 # What a dedup stage's tag cache dropped (counter -> the key the monitor
 # and slotreport show it by): transactions, and the signatures they
 # carried, which the verify stage in front of it spent lanes on
