@@ -12,7 +12,7 @@ trailing batch axes, so one field op is a handful of batch-wide VPU
 instructions regardless of batch size.
 
 Layout: an fe is an int32 array of shape (20, ...batch) — limbs leading,
-any number of batch axes behind (every pad, reshape and broadcast below is
+any number of batch axes behind (every pad, slice and broadcast below is
 written for `x.ndim - 1` of them).  The TPU tiles an array's two minor
 dimensions (8 sublanes x 128 lanes a vreg), so what the layout costs is the
 caller's choice of batch shape:
@@ -22,17 +22,28 @@ caller's choice of batch shape:
     the batch occupies BOTH tiled dimensions and the limb axis is untiled.
     Limb indexing, the shifted accumulates of the convolution and the
     carry shift are whole-register moves (at R = 8 a limb is exactly one
-    vreg, an fe 20, a convolution accumulator 41);
+    vreg, an fe 20, the rows of a product 41);
   - batch (B,), an fe (20, B): the limb axis IS the sublane axis.  An fe
     is 24 sublane rows for 20 limbs, limb i sits on sublane i % 8, and
-    every `a[i][None] * b`, every pad to row i of the accumulator and
-    every carry shift is a sublane shuffle.  Correct at any B (the small
+    every `a[i] * b`, every pad to row i of the accumulator and every
+    carry shift is a sublane shuffle.  Correct at any B (the small
     batches of tests and tools, and the other curves' callers); ~2.5x the
     vreg ops of the folded form by count.
 
-Invariants ("loose" form, maintained by every public op):
-    limbs[1:] in [0, 2^13],  limbs[0] in [0, 2^14]
-which keeps schoolbook products safely inside int32 (see _mul bounds note).
+Invariant ("loose" form; LOOSE_MIN / LOOSE_MAX below, limb by limb), kept
+by every public op and closed under any chain of them:
+    limbs[0]  in [-608, 9407]      (8191 + 2 * 608: an add's top carry)
+    limbs[1]  in [-1,   8238]      (8191 + 47: a product's limb-0 carry)
+    limbs[2:] in [-1,   8194]
+A limb can be NEGATIVE: the ops carry with arithmetic shifts, a limb keeps
+its low 13 bits (>= 0) and hands a signed high part up, so limb 0 goes
+below zero when the top limb's high part is -1 (a subtraction whose
+limb 19 borrowed: -608) and a limb above it reads -1 when the limb below
+was negative going into a product's last pass.  The value mod p is what
+the limbs sum to with their signs.  What keeps schoolbook products inside
+int32 is the magnitude (fe_mul's note); the invariant is the least one
+that the ops close (tests/test_limbs.py reaches it from freshly unpacked
+limbs and walks every op from its worst case in Python integers).
 Values are only canonically reduced by fe_freeze/fe_tobytes.
 """
 
@@ -86,6 +97,11 @@ def fe_const(x: int, batch_shape=(1,)) -> jnp.ndarray:
 _P_LIMBS = _to_limbs_raw(P)
 _2P_LIMBS = (2 * _P_LIMBS).astype(np.int32)
 
+# The loose invariant, limb by limb (the module docstring).
+LOOSE_MIN = np.array([-FOLD] + [-1] * (NLIMB - 1), dtype=np.int32)
+LOOSE_MAX = np.array([MASK + 2 * FOLD, MASK + 47] + [MASK + 3] * (NLIMB - 2),
+                     dtype=np.int32)
+
 
 def fe_zero(batch_shape) -> jnp.ndarray:
     return jnp.zeros((NLIMB,) + tuple(batch_shape), dtype=jnp.int32)
@@ -95,8 +111,16 @@ def fe_one(batch_shape) -> jnp.ndarray:
     return fe_zero(batch_shape).at[0].set(1)
 
 
-def _shift_rows(hi: jnp.ndarray, head: jnp.ndarray) -> jnp.ndarray:
-    """[head, hi[0], .., hi[-2]] along axis 0 — the carry-propagation shift.
+def _rows(x: jnp.ndarray, lo: int, hi: int) -> jnp.ndarray:
+    """x[lo:hi] along the limb axis (a static slice: no index arithmetic
+    is traced, and with the batch on both tiled axes it names registers)."""
+    return jax.lax.slice_in_dim(x, lo, hi, axis=0)
+
+
+def _carry(x: jnp.ndarray) -> jnp.ndarray:
+    """One parallel carry pass over 20 limbs: every limb keeps its low 13
+    bits and takes the (signed) high part of the limb below; the top
+    limb's goes to limb 0 times 608 (2^260 == 608 mod p).
 
     Written as a concatenate (pure data movement XLA folds into the
     surrounding elementwise DAG) rather than `.at[1:].add`: scatter-add
@@ -106,88 +130,105 @@ def _shift_rows(hi: jnp.ndarray, head: jnp.ndarray) -> jnp.ndarray:
     the shift then renames registers; with a one-axis batch it is a
     one-row sublane shift of every vreg of the operand.
     """
-    return jnp.concatenate([head[None], hi[:-1]], axis=0)
+    hi = x >> RADIX
+    head = FOLD * _rows(hi, NLIMB - 1, NLIMB)
+    return (x & MASK) + jnp.concatenate([head, _rows(hi, 0, NLIMB - 1)], axis=0)
 
 
-def _carry2(x: jnp.ndarray) -> jnp.ndarray:
-    """Two parallel carry passes restoring the loose invariant.
+def _two_p(ndim: int) -> jnp.ndarray:
+    return jnp.asarray(_2P_LIMBS).reshape((NLIMB,) + (1,) * (ndim - 1))
 
-    Input limbs must be < 2^27 or so (so `hi` stays small); output satisfies
-    limbs[1:] <= 2^13, limbs[0] <= 2^14.
-    """
-    for _ in range(2):
-        hi = x >> RADIX
-        x = (x & MASK) + _shift_rows(hi, FOLD * hi[-1])
-    return x
 
+# The add-like ops carry ONCE.  The sum of two loose elements is under
+# 2^15 in every limb, so every high part is in [-1, 2] and one pass leaves
+# limbs[1:] <= 8191 + 2 and limb 0 <= 8191 + 2 * 608 = 9407.  2p - b can be
+# negative in limb 19 alone (2p's limb 19 is 510, its others >= 16346):
+# its high part is then -1 and limb 0 takes -608.
 
 def fe_add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    return _carry2(a + b)
+    return _carry(a + b)
 
 
 def fe_sub(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    # a + 2p - b keeps every limb non-negative for loose inputs.
-    tp = jnp.asarray(_2P_LIMBS).reshape((NLIMB,) + (1,) * (a.ndim - 1))
-    return _carry2(a + tp - b)
+    return _carry(a + _two_p(a.ndim) - b)
 
 
 def fe_neg(a: jnp.ndarray) -> jnp.ndarray:
-    tp = jnp.asarray(_2P_LIMBS).reshape((NLIMB,) + (1,) * (a.ndim - 1))
-    return _carry2(tp - a)
+    return _carry(_two_p(a.ndim) - a)
 
 
 def _conv_fold(c: jnp.ndarray) -> jnp.ndarray:
-    """Reduce a (41, ...batch) convolution accumulator to 20 loose limbs mod p.
+    """Reduce the rows of a product to 20 loose limbs mod p.  `c` is
+    `_conv`'s: 41 rows, row k + 1 the product's row of weight 2^(13k)
+    (k = 0..38), rows 0 and 40 zero.
 
-    Input terms are < 1.6e9 (see fe_mul bounds).  Three parallel carry passes
-    bring every limb to ~2^13 (limb 40 only ever holds carry spill, < 2^5),
-    then a single fold maps weights 2^(13k), k >= 20, back into 0..19:
-        2^(13k) == 608 * 2^(13(k-20))  for 20 <= k <= 39   (2^260 == 19*32)
-        2^520   == 2^10 * 19^2 == 369664
+    Rows are within +-1.38e9 (fe_mul's note).  ONE carry pass over the
+    rows brings each under 8191 + 1.38e9 / 8192 < 176,300 in magnitude and
+    makes row 39 out of row 38's high part; the fold
+        2^(13(k+20)) == 608 * 2^(13k)   (2^260 == 19 * 32 mod p)
+    then leaves 20 limbs under 609 * 176,300 < 2^27, where two passes of
+    `_carry` restore the loose invariant (the first leaves limb 0 under
+    4e5 and the others under 21,300; the second's high parts are then at
+    most 47 into limb 1 and 2 elsewhere).  tests/test_limbs.py walks these
+    bounds limb by limb in Python integers.
+
+    The pass and the fold read four plain slices of `c` (a row, the row
+    below it, and the same twenty rows up): the zero rows at both ends
+    are what lets row 0 have a row below it and row 39 a low part, so
+    nothing is concatenated or padded here and the stage is one fusion.
     """
-    for _ in range(3):
-        hi = c >> RADIX
-        c = (c & MASK) + _shift_rows(hi, jnp.zeros_like(hi[-1]))
-    r = c[:NLIMB] + FOLD * c[NLIMB : 2 * NLIMB]
-    r = jnp.concatenate([(r[0] + 369664 * c[2 * NLIMB])[None], r[1:]], axis=0)
-    return _carry2(r)
+    low = (_rows(c, 1, NLIMB + 1) & MASK) + (_rows(c, 0, NLIMB) >> RADIX)
+    high = (_rows(c, NLIMB + 1, 2 * NLIMB + 1) & MASK) \
+        + (_rows(c, NLIMB, 2 * NLIMB) >> RADIX)
+    return _carry(_carry(low + FOLD * high))
 
 
 def _conv(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """(20, ...batch) x (20, ...batch) -> (41, ...batch) schoolbook
-    convolution via shifted adds."""
+    """(20, ...batch) x (20, ...batch) -> (41, ...batch): the 39 rows of
+    the schoolbook product between two rows of zeros (_conv_fold reads
+    them), by shifted adds of the twenty products a[i] * b.
+
+    The pads are not what a product pays for (PERF.md section 6, PR 43):
+    the twenty products are one fusion of 400 multiplies, the padded sum
+    one more whose 800 adds cost a third of what the multiplies do, and a
+    sum by output rows — no zeros added — is 39 or more fusions that each
+    cost a launch."""
     pad = [(0, 0)] * (a.ndim - 1)
     acc = None
     for i in range(NLIMB):
-        t = jnp.pad(a[i][None] * b, [(i, NLIMB + 1 - i)] + pad)
+        t = jnp.pad(_rows(a, i, i + 1) * b, [(i + 1, NLIMB - i)] + pad)
         acc = t if acc is None else acc + t
     return acc
 
 
+# fe_mul is jitted on its own: a program that traces hundreds of products
+# (the verify program: ~300) binds one cached call for each instead of
+# tracing and lowering its ~150 primitives again, and XLA inlines the
+# calls before it optimises, so the compiled program is the same.
+
+@jax.jit
 def fe_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Schoolbook 20x20 limb convolution, then fold mod p.
 
-    Max conv term: two a0-class products (2^14 * 2^13) plus 18 full products
-    (2^13.01 * 2^13.01 each) + one 2^14 * 2^14 < 1.6e9 < 2^31: safe int32.
+    Largest row (k = 19, twenty terms) for loose inputs: two products
+    with a limb 0 (9407 * 8194 each), two with a limb 1 (8238 * 8194) and
+    16 of 8194^2: under 1.38e9 < 2^31, safe int32, negative limbs
+    included (a row's negative terms are at most 2 * 608 * 8238 +
+    18 * 8238).
     """
     return _conv_fold(_conv(a, b))
 
 
-_SQR_DOUBLE = np.ones(NLIMB, dtype=np.int32) * 2
-_SQR_DOUBLE[0] = 1
-
-
 def fe_sqr(a: jnp.ndarray) -> jnp.ndarray:
-    """Squaring with shared cross terms (~half the multiplies of fe_mul)."""
-    pad = [(0, 0)] * (a.ndim - 1)
-    dbl = jnp.asarray(_SQR_DOUBLE).reshape((NLIMB,) + (1,) * (a.ndim - 1))
-    acc = None
-    for i in range(NLIMB):
-        # row i against rows i.. ; off-diagonal terms count twice
-        t = a[i][None] * (a[i:] * dbl[: NLIMB - i])
-        t = jnp.pad(t, [(2 * i, NLIMB + 1 - i)] + pad)  # total rows: 2N+1
-        acc = t if acc is None else acc + t
-    return _conv_fold(acc)
+    """a * a, as a product like any other.  A squaring that shares its
+    cross terms needs 210 multiplies for 400, but its rows are ragged
+    (a[m] against m + 1 fewer limbs each time): the compiler gives every
+    one its own fusion, and it keeps four squarings side by side from
+    being run as one, which it does for products of one shape
+    (point_dbl's four squarings and four products: 88 fusions this way,
+    180 before).  On the chip the shared form is the slower (PERF.md
+    section 6, PR 43)."""
+    return fe_mul(a, a)
 
 
 def fe_sqr_n(a: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -235,12 +276,25 @@ def fe_invert(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def fe_freeze(x: jnp.ndarray) -> jnp.ndarray:
-    """Full canonical reduction: output is the unique rep in [0, p)."""
-    x = _carry2(x)
-    # Two rounds of top-bit split (limb 19 holds bits 247..259; bits >= 255
-    # fold back as *19) with sequential carries brings the value below 2^255.
+    """Full canonical reduction: output is the unique rep in [0, p).
+
+    From any loose input, negative limbs included.  Two parallel passes
+    leave limbs 1.. in [-1, 8192] and limb 0 in [-608, 8799].  A round
+    takes limb 19's bits from 8 up (signed: -1 >> 8 is -1) off the top and
+    adds 19 times them to limb 0 — the value minus that many p — then
+    carries limb by limb with arithmetic shifts, which leaves limbs 0..18
+    in [0, 2^13) whatever their signs were.  Round one leaves limb 19's
+    low 8 bits over 19 limbs that were within (-2^236, 2^247 + 2^236), so
+    the value V is in (-2^236, 2^255 + 2^237) and limb 19 reads -1, 0..255
+    or 256.  Round two adds p to the first (V + p is in [0, p)), takes p
+    off the last (what is left is under 2^237) and changes nothing in
+    between: 0 <= V < 2^255 with every limb canonical, and one conditional
+    subtract of p ends it.
+    fe_eq, fe_is_zero, fe_parity and fe_tobytes read nothing but this.
+    """
+    x = _carry(_carry(x))
     # Row-list form, not `.at[k].set/add` — scatters lower poorly on TPU
-    # (see _shift_rows).
+    # (see _carry).
     rows = [x[k] for k in range(NLIMB)]
     for _ in range(2):
         hi = rows[NLIMB - 1] >> 8

@@ -13,6 +13,8 @@ from firedancer_tpu.ops import scalar as fs
 from firedancer_tpu.ops.ref import ed25519_ref as ref
 from firedancer_tpu.ops.sigverify import fold_batch
 
+import test_limbs as tl     # its loose extremes and jitted field ops
+
 P = ref.P
 L = ref.L
 
@@ -60,6 +62,8 @@ j_dbl = jax.jit(fc.point_dbl)
 j_add = jax.jit(fc.point_add)
 j_compress = jax.jit(fc.point_compress)
 j_small = jax.jit(lambda b: fc.is_small_order(fc.point_decompress(b)[0]))
+j_add_cached = jax.jit(fc.add_cached)
+j_small_pt = jax.jit(fc.is_small_order)
 j_validate = jax.jit(fs.sc_validate)
 j_reduce = jax.jit(fs.sc_reduce512)
 
@@ -234,14 +238,14 @@ def _fold(x):
 
 
 def _loose_points(rng, k):
-    """k-tuples of (20, FOLD_B) loose limb arrays (limbs at and below
-    the invariant's maxima: what the ladder's state may hold), the
-    first and last lanes at the maxima."""
+    """k-tuples of (20, FOLD_B) loose limb arrays (limbs inside the
+    invariant, fl.LOOSE_MIN .. fl.LOOSE_MAX: what the ladder's state may
+    hold), the first lane at the maxima and the last at the minima."""
     out = []
     for _ in range(k):
-        x = rng.integers(0, (1 << fl.RADIX) + 1, (fl.NLIMB, FOLD_B))
-        x[0] = rng.integers(0, (1 << (fl.RADIX + 1)) + 1, FOLD_B)
-        x[1:, [0, -1]], x[0, [0, -1]] = 1 << fl.RADIX, 1 << (fl.RADIX + 1)
+        x = rng.integers(fl.LOOSE_MIN[:, None], fl.LOOSE_MAX[:, None] + 1,
+                         (fl.NLIMB, FOLD_B))
+        x[:, 0], x[:, -1] = fl.LOOSE_MAX, fl.LOOSE_MIN
         out.append(jnp.asarray(x.astype(np.int32)))
     return tuple(out)
 
@@ -258,8 +262,7 @@ def _fold_case(name, rng):
     if name == "point_dbl":
         return j_dbl, (_loose_points(rng, 4),)
     if name == "add_cached":
-        return jax.jit(fc.add_cached), (_loose_points(rng, 4),
-                                        _loose_points(rng, 4))
+        return j_add_cached, (_loose_points(rng, 4), _loose_points(rng, 4))
     if name == "select16":
         sel = np.arange(FOLD_B, dtype=np.int32) % 16
         return jax.jit(fc._select16), (_table(rng), jnp.asarray(sel))
@@ -308,3 +311,137 @@ def test_folded_batch_equals_flat(name, rng):
         w = np.asarray(flat[0])
         assert w.shape == (fc.NWIN, FOLD_B) and w[:, 0].tolist() == \
             [15] * 63 + [1] and not w[:, -1].any()
+
+
+# -- the group ops over the field's lazier carries (ISSUE 43) -----------------
+#
+# Each against ops/ref on the CPU, fed what the ops before it left behind
+# (limbs anywhere inside the loose invariant, limb 0 below zero among
+# them), on the (20, FOLD_B) batch the fold cases above compile.
+
+
+def _limb_cols(cols):
+    """n rows of four integers -> a point of four (20, n) limb arrays."""
+    return tuple(jnp.asarray(np.stack(
+        [fl.int_to_limbs(c[k]) for c in cols], axis=-1)) for k in range(4))
+
+
+def _ext_limbs(pts, rng):
+    """ref points, each scaled by a random Z, as a point of (20, n) limbs."""
+    zs = [int.from_bytes(rng.bytes(32), "little") % P or 1 for _ in pts]
+    return _limb_cols([[x * z % P, y * z % P, z, x * y % P * z % P]
+                       for (x, y), z in zip((affine(p) for p in pts), zs)])
+
+
+def _in_invariant(p):
+    return all(tl.in_invariant(c) for c in p)
+
+
+@pytest.mark.parametrize("op", ["point_dbl", "add_cached"])
+def test_group_op_chain_vs_ref(op, rng):
+    pts = rand_points(rng, 14) + [ref.IDENT, (0, P - 1, 1, 0)]
+    jp = _ext_limbs([pts[i % 16] for i in range(FOLD_B)], rng)
+    look = list(range(16)) + list(range(FOLD_B - 16, FOLD_B))
+    if op == "point_dbl":
+        want = pts
+        for _ in range(3):      # each doubling eats the last one's limbs
+            jp = j_dbl(jp)
+            want = [ref.point_double(p) for p in want]
+            assert _in_invariant(jp)
+    else:
+        qs = rand_points(rng, 15) + [ref.IDENT]
+        jq = _limb_cols([
+            [(y + x) % P, (y - x) % P, 1, 2 * ref.D * x % P * y % P]
+            for x, y in (affine(qs[i % 16]) for i in range(FOLD_B))])
+        jp = j_dbl(j_add_cached(j_add_cached(jp, jq), jq))
+        want = [ref.point_double(ref.point_add(ref.point_add(p, q), q))
+                for p, q in zip(pts, qs)]
+        assert _in_invariant(jp)
+    got = points_from_jax(tuple(np.asarray(c)[:, look] for c in jp))
+    assert got == [affine(want[i % 16]) for i in look]
+    # T = XY / Z too: the next addition reads it
+    x, y, z, t = (fe_ints(np.asarray(c)[:, look]) for c in jp)
+    assert all((a * b - c * d) % P == 0 for a, b, c, d in zip(x, y, z, t))
+
+
+def torsion_encodings() -> list[bytes]:
+    """The eight points of order dividing 8, one encoding each."""
+    out = []
+    for enc in small_order_encodings():
+        out.append(enc)
+        if affine(ref.point_decompress(enc))[0]:    # the same y, the other root
+            out.append(enc[:31] + bytes([enc[31] | 0x80]))
+    return out
+
+
+def test_is_small_order_on_the_eight_torsion_points(rng):
+    enc = torsion_encodings()
+    pts = [ref.point_decompress(e) for e in enc]
+    assert len({affine(p) for p in pts}) == 8
+    assert all(ref.is_small_order(p) for p in pts)
+    enc += [ref.point_compress(p) for p in rand_points(rng, 4)]
+    flags = []
+    for half in (enc[:6], enc[6:]):     # the six-lane decompress program
+        jp, ok = j_decompress(bytes_cols(half))
+        assert np.asarray(ok).all()
+        flags += np.asarray(j_small_pt(jp)).tolist()
+    assert flags == [True] * 8 + [False] * 4
+
+
+def test_decompress_edge_cases_vs_ref(rng):
+    noncanon = [int.to_bytes(y, 32, "little") for y in range(P, 1 << 255)
+                if ref.point_decompress(int.to_bytes(y, 32, "little"))]
+    honest = ref.point_compress(rand_points(rng, 1)[0])
+    nonsquare = next(
+        e for e in (int.to_bytes(v, 32, "little") for v in range(2, 99))
+        if ref.point_decompress(e) is None)
+    one = int.to_bytes(1, 32, "little")
+    enc = [
+        noncanon[0], noncanon[-1],                  # y >= p, both ends
+        one[:31] + b"\x80",                         # x = 0, the sign bit set
+        nonsquare,
+        honest,
+        honest[:31] + bytes([honest[31] ^ 0x80]),   # the other root
+    ]
+    jp, ok = j_decompress(bytes_cols(enc))
+    want = [ref.point_decompress(e) for e in enc]
+    assert np.asarray(ok).tolist() == [w is not None for w in want]
+    got = points_from_jax(jp)
+    for g, w, e in zip(got, want, enc):
+        if w is not None:
+            assert g == affine(w), e.hex()
+    assert got[2] == (0, 1)     # (0, y) whatever the sign bit says (dalek)
+
+
+@pytest.mark.parametrize("what", ["freeze", "parity", "eq", "tobytes"])
+def test_canonical_forms_from_the_loosest_limbs(what, rng):
+    x = tl.loose_extremes(rng)
+    vals = [fl.limbs_to_int(x[:, i]) for i in range(x.shape[1])]
+    if what == "freeze":
+        got = np.asarray(tl.j_freeze(jnp.asarray(x)))
+        assert [got[:, i].tolist() for i in range(len(vals))] == [
+            fl.int_to_limbs(v).tolist() for v in vals]
+    elif what == "parity":
+        assert np.asarray(tl.j_parity(jnp.asarray(x))).tolist() == [
+            v & 1 for v in vals]
+    elif what == "tobytes":
+        got = np.asarray(tl.j_tobytes(jnp.asarray(x))).astype(np.uint8)
+        assert [got[:, i].tobytes() for i in range(len(vals))] == [
+            v.to_bytes(32, "little") for v in vals]
+    else:
+        # the same value in its canonical limbs, and one off it
+        same = np.stack([fl.int_to_limbs(v) for v in vals], axis=-1)
+        off = np.stack([fl.int_to_limbs(v + 1) for v in vals], axis=-1)
+        assert np.asarray(tl.j_eq(jnp.asarray(x), jnp.asarray(same))).all()
+        assert not np.asarray(tl.j_eq(jnp.asarray(x), jnp.asarray(off))).any()
+
+
+@pytest.mark.parametrize("chain", ["pow2523", "invert"])
+def test_power_chain_vs_pow(chain, rng):
+    x = tl.loose_extremes(rng, 16)
+    vals = [fl.limbs_to_int(x[:, i]) for i in range(16)]
+    fn, e = {"pow2523": (tl.j_pow2523, (P - 5) // 8),
+             "invert": (tl.j_invert, P - 2)}[chain]
+    out = fn(jnp.asarray(x))
+    assert tl.in_invariant(out)
+    assert tl.from_fe(out) == [pow(v, e, P) for v in vals]

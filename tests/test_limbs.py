@@ -77,8 +77,7 @@ def test_mul_stays_loose_after_chains(rng):
         fa = _chain_step(fa)
         ref = [(2 * r * r - 1) % P for r in ref]
     assert from_fe(fa) == ref
-    arr = np.asarray(fa)
-    assert arr.min() >= 0 and arr.max() < 1 << 15
+    assert in_invariant(fa)
 
 
 @pytest.mark.slow  # ~16 s compile; invert/pow2523 are exercised inside
@@ -126,6 +125,180 @@ def test_bytes_roundtrip(rng):
     assert from_fe(fe2) == [(v & ((1 << 255) - 1)) % P for v in vals]
 
 
+# -- the bound proof (ISSUE 43) -------------------------------------------------
+#
+# The schedule of ops/limbs.py once more, over any number type: on Python
+# integers it is held, limb for limb, to what the module computes
+# (test_folded_batch_equals_flat), and on intervals it is the proof that
+# no intermediate leaves int32 and that the loose invariant is closed.
+
+INT32 = 1 << 31
+
+
+class Iv:
+    """A closed interval of integers; every one ever made fits int32."""
+
+    def __init__(self, lo, hi=None):
+        self.lo, self.hi = lo, lo if hi is None else hi
+        assert -INT32 < self.lo <= self.hi < INT32, (self.lo, self.hi)
+
+    @staticmethod
+    def of(x):
+        return x if isinstance(x, Iv) else Iv(x)
+
+    def __add__(self, o):
+        o = Iv.of(o)
+        return Iv(self.lo + o.lo, self.hi + o.hi)
+
+    __radd__ = __add__
+
+    def __sub__(self, o):
+        o = Iv.of(o)
+        return Iv(self.lo - o.hi, self.hi - o.lo)
+
+    def __rsub__(self, o):
+        return Iv.of(o) - self
+
+    def __mul__(self, o):
+        o = Iv.of(o)
+        c = [self.lo * o.lo, self.lo * o.hi, self.hi * o.lo, self.hi * o.hi]
+        return Iv(min(c), max(c))
+
+    __rmul__ = __mul__
+
+    def __rshift__(self, n):
+        return Iv(self.lo >> n, self.hi >> n)
+
+    def __and__(self, m):
+        assert m == fl.MASK
+        if self.lo >> fl.RADIX == self.hi >> fl.RADIX:
+            return Iv(self.lo & m, self.hi & m)
+        return Iv(0, m)
+
+    def mag(self):
+        return max(abs(self.lo), abs(self.hi))
+
+    def within(self, lo, hi):
+        return lo <= self.lo and self.hi <= hi
+
+
+def sh_sum(terms):
+    """A row's terms added up; in whatever order the compiler adds them,
+    no partial sum passes the sum of their magnitudes."""
+    if isinstance(terms[0], Iv):
+        assert sum(t.mag() for t in terms) < INT32
+    acc = terms[0]
+    for t in terms[1:]:
+        acc = acc + t
+    return acc
+
+
+def sh_carry(x):
+    hi = [v >> fl.RADIX for v in x]
+    lo = [v & fl.MASK for v in x]
+    return [lo[0] + fl.FOLD * hi[-1]] + [
+        lo[k] + hi[k - 1] for k in range(1, fl.NLIMB)]
+
+
+TWO_P = [2 * int(v) for v in fl._P_LIMBS]
+
+
+def sh_add(a, b):
+    return sh_carry([x + y for x, y in zip(a, b)])
+
+
+def sh_sub(a, b):
+    return sh_carry([x + t - y for x, t, y in zip(a, TWO_P, b)])
+
+
+def sh_neg(a):
+    return sh_carry([t - x for t, x in zip(TWO_P, a)])
+
+
+def sh_conv(a, b):
+    n = fl.NLIMB
+    return [sh_sum([a[i] * b[k - i]
+                    for i in range(max(0, k - n + 1), min(k, n - 1) + 1)])
+            for k in range(2 * n - 1)]
+
+
+def sh_fold_stages(c):
+    """-> (rows after the one pass, the folded limbs, after one carry,
+    after two): _conv_fold's four stages."""
+    n = fl.NLIMB
+    hi = [v >> fl.RADIX for v in c]
+    lo = [v & fl.MASK for v in c]
+    one = [lo[0]] + [lo[k] + hi[k - 1] for k in range(1, 2 * n - 1)] \
+        + [hi[2 * n - 2]]
+    r = [one[k] + fl.FOLD * one[k + n] for k in range(n)]
+    c1 = sh_carry(r)
+    return one, r, c1, sh_carry(c1)
+
+
+def sh_mul(a, b):
+    return sh_fold_stages(sh_conv(a, b))[3]
+
+
+def sh_sqr(a):
+    return sh_mul(a, a)
+
+
+SHADOW = {"add": sh_add, "sub": sh_sub, "neg": sh_neg, "mul": sh_mul,
+          "sqr": sh_sqr}
+
+
+def loosest():
+    return [Iv(int(lo), int(hi)) for lo, hi in zip(fl.LOOSE_MIN, fl.LOOSE_MAX)]
+
+
+@pytest.mark.parametrize("op", sorted(SHADOW))
+def test_schedule_stays_in_int32_and_closes_the_invariant(op):
+    # from the invariant's worst case in every limb at once (Iv refuses
+    # an intermediate outside int32), back inside the invariant: closed
+    # under any chain of ops
+    fn = SHADOW[op]
+    out = fn(*[loosest()] * (2 if op in ("add", "sub", "mul") else 1))
+    for v, lo, hi in zip(out, fl.LOOSE_MIN, fl.LOOSE_MAX):
+        assert v.within(lo, hi), (op, v.lo, v.hi, lo, hi)
+
+
+def test_conv_fold_bounds():
+    # the numbers _conv_fold's and fe_mul's notes give, stage by stage
+    c = sh_conv(loosest(), loosest())
+    assert max(v.mag() for v in c) < 1.38e9
+    one, r, c1, c2 = sh_fold_stages(c)
+    assert max(v.mag() for v in one) < 176_300
+    assert max(v.mag() for v in r) < 1 << 27
+    assert c1[0].mag() < 400_000 and max(v.mag() for v in c1[1:]) < 21_300
+    assert all(v.within(lo, hi)
+               for v, lo, hi in zip(c2, fl.LOOSE_MIN, fl.LOOSE_MAX))
+
+
+def test_conv_rows_between_two_rows_of_zeros(rng):
+    # what _conv_fold's four slices rest on: 41 rows, the product's 39
+    # in the middle, limb for limb the integers'
+    a, b = loose_extremes(rng, 16), np.roll(loose_extremes(rng, 16), 5, axis=1)
+    c = np.asarray(jax.jit(fl._conv)(jnp.asarray(a), jnp.asarray(b)))
+    assert c.shape == (2 * fl.NLIMB + 1, 16)
+    assert not c[0].any() and not c[-1].any()
+    for i in range(16):
+        assert c[1:-1, i].tolist() == sh_conv(
+            [int(v) for v in a[:, i]], [int(v) for v in b[:, i]])
+
+
+def test_the_invariant_is_the_least_closed_one():
+    # what the ops reach from freshly unpacked limbs ([0, 2^13)) is the
+    # stated invariant, limb for limb: nothing in it is slack
+    inv = [Iv(0, fl.MASK)] * fl.NLIMB
+    for _ in range(8):
+        outs = [sh_add(inv, inv), sh_sub(inv, inv), sh_neg(inv),
+                sh_mul(inv, inv), sh_sqr(inv)]
+        inv = [Iv(min(v.lo for v in vs), max(v.hi for v in vs))
+               for vs in zip(inv, *outs)]
+    assert [v.lo for v in inv] == fl.LOOSE_MIN.tolist()
+    assert [v.hi for v in inv] == fl.LOOSE_MAX.tolist()
+
+
 # -- the folded batch (ISSUE 38) ----------------------------------------------
 #
 # ops/sigverify.fold_batch lays a program's batch on both tiled axes,
@@ -137,20 +310,26 @@ FOLD_B = 256
 
 def loose_extremes(rng, n=FOLD_B):
     """(20, n) limb columns at the edges of the loose invariant
-    (limbs[1:] in [0, 2^13], limbs[0] in [0, 2^14]): every limb at its
-    maximum, zero, p's own limbs, 2p's reduced, alternating, the rest
-    random loose limbs."""
-    top = np.full(fl.NLIMB, 1 << fl.RADIX, np.int32)
-    top[0] = 1 << (fl.RADIX + 1)
-    alt = np.where(np.arange(fl.NLIMB) % 2, top, 0).astype(np.int32)
-    cols = [top, np.zeros(fl.NLIMB, np.int32), fl._P_LIMBS.astype(np.int32),
-            fl.int_to_limbs(2 * P - 1), alt, top - alt]
-    x = rng.integers(0, (1 << fl.RADIX) + 1, (fl.NLIMB, n)).astype(np.int32)
-    x[0] = rng.integers(0, (1 << (fl.RADIX + 1)) + 1, n)
+    (fl.LOOSE_MIN <= limbs <= fl.LOOSE_MAX, negative limbs included):
+    every limb at its maximum, every limb at its minimum, zero, p's own
+    limbs, 2p - 1 reduced, maxima and minima alternating both ways, the
+    rest random loose limbs."""
+    lo, hi = fl.LOOSE_MIN, fl.LOOSE_MAX
+    odd = np.arange(fl.NLIMB) % 2 == 1
+    cols = [hi, lo, np.zeros(fl.NLIMB, np.int32), fl._P_LIMBS.astype(np.int32),
+            fl.int_to_limbs(2 * P - 1), np.where(odd, hi, lo),
+            np.where(odd, lo, hi)]
+    x = rng.integers(lo[:, None], hi[:, None] + 1, (fl.NLIMB, n)).astype(np.int32)
     for i, c in enumerate(cols):
         x[:, i] = c
         x[:, n - 1 - i] = c         # and in the last row of the fold
     return x
+
+
+def in_invariant(x):
+    x = np.asarray(x).reshape(fl.NLIMB, -1)
+    return bool((x >= fl.LOOSE_MIN[:, None]).all()
+                and (x <= fl.LOOSE_MAX[:, None]).all())
 
 
 FOLD_OPS = {
@@ -170,13 +349,17 @@ def test_folded_batch_equals_flat(op, rng):
     fold = np.asarray(fn(*[jnp.asarray(a) for a in fold_batch(*args)]))
     assert fold.shape == flat.shape[:-1] + (FOLD_B // 128, 128)
     assert np.array_equal(fold.reshape(flat.shape), flat)
-    if op in ("mul", "sqr", "sub", "add", "neg"):   # and to the integers
-        ints = [[fl.limbs_to_int(a[:, i]) for i in range(8)] for a in args]
+    if op in SHADOW:    # to the integers, and limb for limb to the shadow
+        cols = list(range(8)) + list(range(FOLD_B - 8, FOLD_B))
+        ints = [[fl.limbs_to_int(a[:, i]) for i in cols] for a in args]
         want = {"mul": lambda a, b: a * b, "sqr": lambda a: a * a,
                 "sub": lambda a, b: a - b, "add": lambda a, b: a + b,
                 "neg": lambda a: -a}[op]
-        assert from_fe(flat[:, :8]) == [want(*v) % P for v in zip(*ints)]
-        assert flat.min() >= 0 and flat.max() <= 1 << (fl.RADIX + 1)
+        assert from_fe(flat[:, cols]) == [want(*v) % P for v in zip(*ints)]
+        assert in_invariant(flat)
+        for i in cols:
+            assert SHADOW[op](*[[int(v) for v in a[:, i]] for a in args]) \
+                == flat[:, i].tolist()
 
 
 def test_folded_frombytes_equals_flat(rng):
