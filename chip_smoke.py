@@ -19,13 +19,12 @@ nothing compiles, nothing is printed on stdout, and the exit is non-zero.
          are set-up), 30,720 seeded transfers end to end, then 4,096
          with 37 corrupted signatures that must be exactly the ones
          missing from the stored block.
-  plane  a second process boots the one-device ServePlane step from the
-         blob phase A's warmup() wrote.
   B      the process topology (`run --processes`): the verify child owns
          the chip, every other child is pinned to the CPU or never
          imports JAX, and the cache written by A is hit, not extended.
-  C      only with >= 4 devices: ServePlane over four chips and the
-         sharded pipeline.
+  C      only with >= 4 devices: the same pipeline with `[verify]
+         devices = 4`, one program over a mesh of four chips (the path
+         the cell verify-fanout-4chip runs).
 
 Times and rates printed here are observations for whoever reads the
 log, not metrics.
@@ -54,6 +53,7 @@ N_BAD_STREAM = 4_096
 N_BAD = 37
 N_SAMPLE = 256
 N_TOPO = 8_192
+MESH_DEVICES = 4  # phase C's `[verify] devices`
 N_PAYERS = 64  # pack admits one txn per payer per microblock: over the
 #                generator's default 8 it sheds a stream this long
 COMB_SLOTS = 1_024
@@ -216,31 +216,6 @@ def compile_programs(batch: int, max_msg_len: int, shapes, comb_slots: int,
     return out
 
 
-def warm_plane(n_devices: int, batch_per_shard: int, max_msg_len: int,
-               seed: int):
-    """ServePlane.warmup() + one step held to the reference.
-    -> (plane, {"warmup_s", "loaded_blob"})."""
-    import numpy as np
-
-    from firedancer_tpu.parallel.serve import ServeConfig, ServePlane
-
-    plane = ServePlane(ServeConfig(
-        n_devices=n_devices, batch_per_shard=batch_per_shard,
-        max_msg_len=max_msg_len))
-    warm_s = plane.warmup()
-    msg, ln, sig, pk, expect = signed_lanes(
-        plane.cfg.batch, max_msg_len, 5, seed + 2)
-    full = np.full((n_devices,), batch_per_shard, dtype=np.int32)
-    placed = plane.place_verify(msg, ln, sig, pk)
-    on = {s.device for s in placed[0].addressable_shards}
-    pend = plane.submit(msg, ln, sig, pk, full)
-    if not (np.asarray(pend.ok) == expect).all():
-        raise AssertionError("serving step disagrees with ed25519_ref")
-    return plane, {"warmup_s": round(warm_s, 2),
-                   "loaded_blob": plane.loaded_blob,
-                   "input_devices": len(on)}
-
-
 def armed_lanes(pipe) -> dict:
     """Which native sweep clients this pipeline armed."""
     return {
@@ -342,7 +317,6 @@ def phase_a(dev, *, config: str = CONFIG, n_stream: int = N_STREAM,
     b, mm = cfg.verify.batch, cfg.verify.max_msg_len
     t0 = time.monotonic()
     progs = compile_programs(b, mm, ((b, mm), *extra_shapes), comb_slots, seed)
-    _, progs["serve_plane_1dev"] = warm_plane(1, b, mm, seed)
     emit(dev, phase="A", step="compile", ok=True,
          setup_s=round(time.monotonic() - t0, 1), first_call_s=progs)
 
@@ -366,53 +340,53 @@ def phase_a(dev, *, config: str = CONFIG, n_stream: int = N_STREAM,
 # -- the other phases ---------------------------------------------------------
 
 
-def phase_plane(dev) -> None:
-    """A fresh process boots the one-device serving step from the
-    serialized executable phase A's warmup() left in the cache."""
-    from firedancer_tpu.utils.config import load_config
+def phase_c(dev, *, config: str = CONFIG, n_topo: int = N_TOPO) -> None:
+    """Four chips, one process: the cooperative pipeline with `[verify]
+    devices = 4` at the config's widths — one native intake, one program
+    a step over a mesh of the first four devices — over `n_topo`
+    transactions.  Skips below four devices."""
+    import numpy as np
 
-    cfg = load_config(CONFIG)
-    _, obs = warm_plane(1, cfg.verify.batch, cfg.verify.max_msg_len, SEED)
-    if not obs["loaded_blob"]:
-        raise AssertionError(f"warm boot recompiled instead of loading: {obs}")
-    emit(dev, phase="plane", ok=True, **obs)
-
-
-def phase_c(dev) -> None:
-    """Four chips, one process: ServePlane(n_devices=4) and the sharded
-    cooperative pipeline over N_TOPO transactions."""
-    from firedancer_tpu.models.leader import build_sharded_leader_pipeline
+    from firedancer_tpu.models.leader import build_leader_pipeline_from_config
+    from firedancer_tpu.runtime import verify as fv
     from firedancer_tpu.runtime.bank import default_bank_ctx
     from firedancer_tpu.runtime.benchg import gen_transfer_pool
+    from firedancer_tpu.runtime.verify_native import row_width
     from firedancer_tpu.utils.config import load_config
 
-    if dev[2] < 4:
+    if dev[2] < MESH_DEVICES:
         emit(dev, phase="C", ok=True, skipped=f"{dev[2]} device")
         return
-    cfg = load_config(CONFIG)
-    per = cfg.verify.batch // 4
-    plane, obs = warm_plane(4, per, cfg.verify.max_msg_len, SEED)
-    pipe = build_sharded_leader_pipeline(
-        plane=plane, n_shards=4, batch_per_shard=per,
-        max_msg_len=cfg.verify.max_msg_len, pool_size=N_PAYERS,
-        gen_limit=N_TOPO, n_bank=cfg.layout.bank_stage_count,
-        bank_ctx=default_bank_ctx(n_payers=N_PAYERS),
+    cfg = load_config(config, overrides={"verify": {"devices": MESH_DEVICES}})
+    pipe = build_leader_pipeline_from_config(
+        cfg, pool_size=N_PAYERS, gen_limit=n_topo, verify_precomputed=False,
+        bank_ctx=default_bank_ctx(n_payers=N_PAYERS), keep_sets=False,
     )
     try:
-        pipe.benchg.pool = gen_transfer_pool(N_TOPO, n_payers=N_PAYERS,
+        pipe.benchg.pool = gen_transfer_pool(n_topo, n_payers=N_PAYERS,
                                              n_dests=1024)
+        v = pipe.verifies[0]
+        placed = fv.place_rows(
+            np.zeros((cfg.verify.batch, row_width(cfg.verify.max_msg_len)),
+                     dtype=np.uint8), v._row_sharding)
+        obs = {
+            "warmup_s": round(sum(s.warmup() for s in pipe.verifies), 2),
+            "input_devices": len({s.device
+                                  for s in placed.addressable_shards}),
+        }
         t0 = time.monotonic()
-        pipe.run(until_txns=N_TOPO, max_iters=300_000)
+        pipe.run(until_txns=n_topo, max_iters=2_000_000)
         obs["run_s"] = round(time.monotonic() - t0, 2)
         obs["txn_exec"] = sum(b.metrics.get("txn_exec") for b in pipe.banks)
         obs["pack_dropped"] = pipe.pack.metrics.get("txn_dropped")
-        vm = pipe.verifies[0].metrics
-        obs["shard_elems"] = [vm.get(f"shard_elems_s{i}") for i in range(4)]
-        obs["verify_fail"] = vm.get("verify_fail")
+        obs["shard_elems"] = [v.metrics.get(f"shard_elems_s{i}")
+                              for i in range(MESH_DEVICES)]
+        obs["verify_fail"] = v.metrics.get("verify_fail")
     finally:
         pipe.close()
-    ok = (obs["txn_exec"] == N_TOPO and all(obs["shard_elems"])
-          and obs["input_devices"] == 4 and not obs["verify_fail"])
+    ok = (obs["txn_exec"] == n_topo and all(obs["shard_elems"])
+          and obs["input_devices"] == MESH_DEVICES
+          and not obs["verify_fail"])
     emit(dev, phase="C", ok=ok, **obs)
     if not ok:
         raise AssertionError(f"phase C failed: {obs}")
@@ -435,7 +409,7 @@ def child_main(phase: str) -> int:
         built = nativebuild.build_all(force=True)
         emit(dev, phase="A", step="native_build", ok=True,
              built=[os.path.basename(p) for p in built])
-    {"A": phase_a, "plane": phase_plane, "C": phase_c}[phase](dev)
+    {"A": phase_a, "C": phase_c}[phase](dev)
     return 0
 
 
@@ -527,7 +501,7 @@ def main() -> int:
     t_end = time.monotonic() + BUDGET_S
     dev = None
     cold_fused_s = None
-    for phase in ("A", "plane", "B", "C"):
+    for phase in ("A", "B", "C"):
         left = t_end - time.monotonic()
         if phase == "B":
             if not phase_b(dev, cold_fused_s, left):

@@ -19,9 +19,9 @@ action here: the benchmark is `python3 benchmarks/run.py` (BENCHMARK.json).
                (`chaos list`; `chaos run <scenario> --seed S`)
     configure  host setup stages: check | init (shm, fds, cpus, THP...)
     keys       new <path> | pubkey <path> — identity keypair management
-    warmup     AOT-compile the sharded serving step for a mesh shape
-               through the persistent compile cache (leader boot-time
-               obligation)
+    warmup     compile the program a verify stage of this geometry
+               dispatches (--batch, --max-msg-len, --devices) through
+               the persistent compile cache, before a leader slot
     genesis    create | show a genesis blob (+ faucet key)
     snapshot   inspect a snapshot archive
     ledger     show | ingest | replay a stored ledger (bank-hash checks)
@@ -42,7 +42,7 @@ import os
 import sys
 import time
 
-__version__ = "0.7.0"  # round 7: sharded serving plane
+__version__ = "0.7.0"
 
 # `run` streams --txns transfers over this many funded payers: pack puts at
 # most one transaction per payer into a microblock, and at the configured
@@ -222,30 +222,35 @@ def cmd_keys(args) -> int:
 
 
 def cmd_warmup(args) -> int:
-    """AOT-compile the sharded serving step for a mesh shape, through the
-    persistent compile cache (utils/platform.enable_compile_cache): the
-    leader's boot-time obligation, run BEFORE a slot, so traffic never
-    waits on XLA.  Second runs load from cache in seconds — pass
-    --assert-warm S to fail (exit 2) when the compile/load took longer,
-    which is how CI proves the cache-hit path works."""
+    """Compile what a deployment dispatches — the verify stage's one
+    program at (--batch, --max-msg-len), placed over --devices chips as
+    `[verify] devices` places it — through the persistent compile cache
+    (utils/platform.enable_compile_cache): the leader's boot-time
+    obligation, run BEFORE a slot, so traffic never waits on XLA.  It
+    builds no stage and no rings: it calls what VerifyStage.warmup()
+    calls (runtime/verify.warm_program).  Second runs load from the
+    cache in seconds — pass --assert-warm S to fail (exit 2) when the
+    compile/load took longer, which is how CI proves the cache-hit path
+    works."""
     from firedancer_tpu.utils import platform as fp
 
-    fp.select_device(args.cpu, device_count=max(args.devices, 8))
-    from firedancer_tpu.parallel.serve import ServeConfig, ServePlane
+    platform, _, _ = fp.select_device(args.cpu,
+                                      device_count=max(args.devices, 8))
+    from firedancer_tpu.ops import sigverify as sv
+    from firedancer_tpu.runtime import verify as fv
 
-    cfg = ServeConfig(
-        n_devices=args.devices,
-        batch_per_shard=args.batch_per_shard,
-        max_msg_len=args.max_msg_len,
-        poh_iters=args.poh_iters,
-    )
-    plane = ServePlane(cfg)
-    compile_s = plane.warmup()
+    try:
+        sharding = fv.mesh_row_sharding(args.batch, args.devices)
+    except ValueError as e:
+        print(f"warmup: {e}", file=sys.stderr)
+        return 1
+    compile_s = fv.warm_program(args.batch, args.max_msg_len, sharding)
     print(json.dumps({
-        "serve_step": cfg.cache_key(),
+        "program": sv.ed25519_verify_batch_fused.__name__,
         "devices": args.devices,
-        "platform": plane._mesh_platform(),
-        "batch": cfg.batch,
+        "platform": platform,
+        "batch": args.batch,
+        "max_msg_len": args.max_msg_len,
         "compile_s": round(compile_s, 2),
         "cache_dir": fp.compile_cache_dir(),
     }))
@@ -556,15 +561,15 @@ def main(argv=None) -> int:
 
     wup = sub.add_parser(
         "warmup",
-        help="AOT-compile the sharded serving step (persistent cache)",
+        help="compile the verify stage's program (persistent cache)",
     )
-    wup.add_argument("--devices", type=int, default=8,
-                     help="mesh size (devices) to compile for")
-    wup.add_argument("--batch-per-shard", type=int, default=32)
+    wup.add_argument("--devices", type=int, default=1,
+                     help="chips behind the stage ([verify] devices)")
+    wup.add_argument("--batch", type=int, default=256,
+                     help="lanes a batch ([verify] batch)")
     wup.add_argument("--max-msg-len", type=int, default=256)
-    wup.add_argument("--poh-iters", type=int, default=64)
     wup.add_argument("--cpu", action="store_true",
-                     help="compile for a virtual CPU mesh (default: "
+                     help="compile for (virtual) CPU devices (default: "
                           "require the TPU)")
     wup.add_argument("--assert-warm", type=float, default=None, metavar="S",
                      help="exit 2 unless compile/load finished within S "

@@ -113,12 +113,12 @@ _BANK_PATH_FILES = frozenset({"bank.py", "bank_native.py"})
 # classes in the verify-path modules; the reap-point methods are the
 # allowlist.  Frag callbacks are excluded here — FD201 already owns
 # them.
-_FD214_FILES = frozenset({"verify.py", "serve.py", "verify_native.py"})
-# warmup() syncs by design, at boot, before any batch is in flight;
-# _mask_of is the fetch both lanes' reap (_nv_drain, _result_mask) share
+_FD214_FILES = frozenset({"verify.py", "verify_native.py"})
+# _mask_of is the fetch both lanes' reaps (_nv_drain, _drain's _reap)
+# share; the boot-time warm-up syncs outside the class
+# (runtime/verify.warm_program), before any batch is in flight
 _FD214_REAP_METHODS = frozenset({
-    "_drain", "_nv_drain", "_result_mask", "_result_ready", "_mask_of",
-    "flush", "warmup",
+    "_drain", "_nv_drain", "_mask_of", "flush",
 })
 _FD214_SYNC_CALLS = frozenset({
     ("np", "asarray"), ("np", "array"), ("jax", "device_get"),
@@ -365,9 +365,8 @@ class _Linter(ast.NodeVisitor):
         # FD209 scope: files under a chaos/ package directory
         parts = re.split(r"[/\\]", path)
         self._chaos = "chaos" in parts
-        # FD210 scope: the packages whose frag callbacks feed (or are) the
-        # sharded serving plane
-        self._serve_scope = "runtime" in parts or "parallel" in parts
+        # FD210 scope: the packages whose frag callbacks feed the device
+        self._transfer_scope = "runtime" in parts or "parallel" in parts
         # FD211 scope: pack modules (the pack package + the runtime pack
         # stage) — their frag callbacks are the pool intake hot path.
         # Exact matches only: a future packet.py/unpack_utils.py must
@@ -481,8 +480,8 @@ class _Linter(ast.NodeVisitor):
 
     def visit_ClassDef(self, node: ast.ClassDef) -> None:
         self._class_depth += 1
-        # FD214: a verify-stage class by name or by base (subclasses like
-        # ShardedVerifyStage inherit the async-window discipline)
+        # FD214: a verify-stage class by name or by base (a subclass
+        # inherits the async-window discipline)
         def _base_name(b: ast.AST) -> str:
             d = _dotted(b)
             return d[-1] if d else ""
@@ -666,7 +665,7 @@ class _Linter(ast.NodeVisitor):
             self.hit("FD214", node,
                      f"device sync {what} in verify-stage method "
                      f"'{method}' outside the designated reap point"
-                     " (_drain/_result_mask/flush): syncing mid-stream"
+                     " (_drain/_mask_of/flush): syncing mid-stream"
                      " serializes the async in-flight window")
 
     def _check_chaos_entropy(self, node: ast.Call) -> None:
@@ -723,8 +722,8 @@ class _Linter(ast.NodeVisitor):
         # The device->host direction (np.asarray, device_get, .item,
         # block_until_ready) is FD201 above; this closes the other half:
         # a device_put per frag re-commits (and on a mesh re-shards) one
-        # element at a time, serializing the plane behind the host.
-        if self._serve_scope:
+        # element at a time, serializing the chips behind the host.
+        if self._transfer_scope:
             if (mf == ("jax", "device_put")) or (
                 isinstance(node.func, ast.Attribute)
                 and node.func.attr == "copy_to_host_async"
@@ -735,8 +734,8 @@ class _Linter(ast.NodeVisitor):
                 )
                 self.hit("FD210", node,
                          f"{what} in a frag callback: commit device arrays"
-                         " at batch-close granularity (the serving plane's"
-                         " place_verify path), never per frag")
+                         " at batch-close granularity (runtime/verify"
+                         " place_rows), never per frag")
         if mf and mf[0] == "time" and mf[1] in _CLOCK_CALLS:
             self.hit("FD202", node,
                      f"time.{mf[1]}() in a frag callback; stamp deadlines"

@@ -169,10 +169,10 @@ FD209 = _rule(
 FD210 = _rule(
     "FD210", "transfer-in-frag", SEV_ERROR,
     "host<->device transfer (jax.device_put / .copy_to_host_async) inside a"
-    " frag callback in runtime/ or parallel/: on a sharded serving plane a"
-    " per-frag transfer serializes the mesh behind the host — commit arrays"
-    " at batch-close granularity (serve.ServePlane.place_verify), never per"
-    " frag (device->host syncs are FD201's half of the same rule)",
+    " frag callback in runtime/ or parallel/: a per-frag transfer"
+    " serializes the chips behind the host — commit arrays at batch-close"
+    " granularity (runtime/verify.place_rows), never per frag"
+    " (device->host syncs are FD201's half of the same rule)",
 )
 FD211 = _rule(
     "FD211", "alloc-sort-in-pack-frag", SEV_ERROR,
@@ -197,8 +197,8 @@ FD214 = _rule(
     "FD214", "sync-outside-reap-point", SEV_ERROR,
     "device->host sync (np.asarray/np.array on device values, .item(),"
     " .block_until_ready(), jax.device_get) inside a verify-stage method"
-    " that is NOT the designated reap point (_drain/_nv_drain, the"
-    " _result_mask/_result_ready hooks, flush): the verify stage keeps a"
+    " that is NOT the designated reap point (_drain/_nv_drain, the fetch"
+    " they share _mask_of, flush): the verify stage keeps a"
     " >= 8 deep async in-flight window and exactly one place may block on"
     " device results — a sync anywhere else (intake, batching, submit,"
     " housekeeping) quietly serializes the window back to depth 1",

@@ -66,8 +66,6 @@ class LeaderPipeline:
     store: StoreStage
     leader_pub: bytes
     bank_ctx: BankCtx = None
-    router: object = None  # ShardRouterStage in the sharded-serving form
-    plane: object = None  # parallel/serve.ServePlane when mesh-sharded
 
     def run(self, *, max_iters: int = 200_000, until_txns: int | None = None,
             finish: bool = True):
@@ -413,187 +411,4 @@ def build_leader_pipeline(
         store=store,
         leader_pub=leader_pub,
         bank_ctx=bank_ctx,
-    )
-
-
-def build_sharded_leader_pipeline(
-    *,
-    plane=None,
-    n_shards: int = 4,
-    batch_per_shard: int = 64,
-    pool_size: int = 512,
-    gen_limit: int | None = None,
-    max_msg_len: int = 256,
-    depth: int = 1024,
-    shard_depth: int = 512,
-    batch_deadline_s: float = 0.002,
-    slot: int = 1,
-    leader_seed: bytes = b"leader",
-    n_bank: int = 2,
-    bank_ctx: BankCtx | None = None,
-    verify_precomputed: bool = False,
-    hashes_per_tick: int = 64,
-    native_pack: bool | None = None,
-) -> LeaderPipeline:
-    """The SHARDED serving pipeline (cooperative form): real leader
-    traffic through the device mesh.
-
-        benchg -> router -> sv{i} (per-shard rings, seq%N deterministic)
-               -> sharded-verify (ONE stage, ONE pjit step over the mesh)
-               -> dedup -> pack -> bank xB -> poh -> shred -> store
-
-    The sharded-verify stage consumes all N per-shard rings and runs the
-    plane's single compiled leader step (verify + reedsol + PoH lanes,
-    partition specs matched across hops — parallel/serve.py); the shred
-    stage's normal-shape FEC parity and the poh stage's tick-span
-    self-audit ride the SAME plane.  Downstream of verify the host lane
-    (dedup -> pack -> bank -> poh -> shred -> store) is byte-identical
-    to the unsharded pipeline.
-
-    plane: a prebuilt (ideally warmed) ServePlane; None builds one for
-    `n_shards` devices.  hashes_per_tick doubles as the plane's PoH span
-    length so tick spans match the compiled shape.
-    """
-    from firedancer_tpu.parallel.router import ShardRouterStage
-    from firedancer_tpu.parallel.serve import (
-        ServeConfig,
-        ServePlane,
-        ShardedVerifyStage,
-    )
-
-    if plane is None:
-        plane = ServePlane(ServeConfig(
-            n_devices=n_shards,
-            batch_per_shard=batch_per_shard,
-            max_msg_len=max_msg_len,
-            poh_iters=hashes_per_tick,
-        ))
-    cfg = plane.cfg
-    if cfg.n_devices != n_shards:
-        raise ValueError(
-            f"plane has {cfg.n_devices} shards, pipeline asked for {n_shards}"
-        )
-
-    uid = shm.fresh_uid()
-    links = []
-
-    def mklink(name, mtu, n_consumers=1, d=None):
-        link = shm.ShmLink.create(
-            f"fdtpu_{name}_{uid}", depth=d or depth, mtu=mtu, n_fseq=n_consumers
-        )
-        links.append(link)
-        return link
-
-    use_native_pack = resolve_native_pack(native_pack)
-    gen_router = mklink("gv", mtu=1232)
-    shard_rings = [
-        mklink(f"sv{i}", mtu=1232, d=shard_depth) for i in range(n_shards)
-    ]
-    verify_dedup = mklink("vd", mtu=4096)
-    dedup_pack = None if use_native_pack else mklink("dp", mtu=4096)
-    pack_bank = [mklink(f"pb{b}", mtu=65536) for b in range(n_bank)]
-    bank_poh = [mklink(f"bp{b}", mtu=65536) for b in range(n_bank)]
-    bank_done = [mklink(f"bd{b}", mtu=64) for b in range(n_bank)]
-    poh_shred = mklink("ps", mtu=65536)
-    shred_store = mklink("ss", mtu=1232, d=4096)
-
-    secret = hashlib.sha256(leader_seed).digest()
-    leader_pub = ref.public_key(secret)
-
-    pool = gen_transfer_pool(pool_size)
-    benchg = BenchGStage(
-        pool, "benchg", outs=[shm.make_producer(gen_router)], limit=gen_limit
-    )
-    router = ShardRouterStage(
-        "router",
-        ins=[shm.make_consumer(gen_router, lazy=32)],
-        outs=[shm.make_producer(l) for l in shard_rings],
-        n_shards=n_shards,
-    )
-    verify = ShardedVerifyStage(
-        "verify",
-        ins=[shm.make_consumer(l, lazy=32) for l in shard_rings],
-        outs=[shm.make_producer(verify_dedup)],
-        plane=plane,
-        batch=cfg.batch_per_shard,
-        batch_deadline_s=batch_deadline_s,
-        precomputed_ok=verify_precomputed,
-    )
-    _take_turns([verify])
-    if use_native_pack:
-        dedup = None
-        pack = NativePackStage(
-            "pack",
-            ins=[shm.make_consumer(verify_dedup, lazy=32)]
-            + [shm.make_consumer(l, lazy=8) for l in bank_done],
-            outs=[shm.make_producer(l) for l in pack_bank],
-            bank_cnt=n_bank,
-        )
-    else:
-        dedup = DedupStage(
-            "dedup",
-            ins=[shm.make_consumer(verify_dedup, lazy=32)],
-            outs=[shm.make_producer(dedup_pack)],
-        )
-        pack = PackStage(
-            "pack",
-            ins=[shm.make_consumer(dedup_pack, lazy=32)]
-            + [shm.make_consumer(l, lazy=8) for l in bank_done],
-            outs=[shm.make_producer(l) for l in pack_bank],
-            bank_cnt=n_bank,
-        )
-    if bank_ctx is None:
-        bank_ctx = default_bank_ctx(slot=slot)
-    banks = [
-        BankStage(
-            f"bank{b}",
-            ins=[shm.make_consumer(pack_bank[b], lazy=8)],
-            outs=[shm.make_producer(bank_poh[b]), shm.make_producer(bank_done[b])],
-            bank_idx=b,
-            ctx=bank_ctx,
-        )
-        for b in range(n_bank)
-    ]
-    for bstage in banks:
-        bstage.require_credit = True
-    poh = PohStage(
-        "poh",
-        ins=[shm.make_consumer(l, lazy=8) for l in bank_poh],
-        outs=[shm.make_producer(poh_shred)],
-        hashes_per_tick=hashes_per_tick,
-        plane=plane,
-    )
-    poh.require_credit = True
-    shred = ShredStage(
-        "shred",
-        ins=[shm.make_consumer(poh_shred, lazy=8)],
-        outs=[shm.make_producer(shred_store)],
-        signer=lambda root: ref.sign(secret, root),
-        slot=slot,
-        keep_sets=True,
-        plane=plane,
-    )
-    store = StoreStage(
-        "store",
-        ins=[shm.make_consumer(shred_store, lazy=64)],
-        verify_sig=None,
-        trust_membership=True,
-    )
-    stages = [benchg, router, verify] + ([dedup] if dedup else []) \
-        + [pack, *banks, poh, shred, store]
-    return LeaderPipeline(
-        stages=stages,
-        links=links,
-        benchg=benchg,
-        verifies=[verify],
-        dedup=dedup,
-        pack=pack,
-        banks=banks,
-        poh=poh,
-        shred=shred,
-        store=store,
-        leader_pub=leader_pub,
-        bank_ctx=bank_ctx,
-        router=router,
-        plane=plane,
     )

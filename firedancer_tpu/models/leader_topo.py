@@ -144,59 +144,12 @@ def build_out(links, cnc):
                     cnc=cnc)
 
 
-def build_router(links, cnc, *, n_shards):
-    from firedancer_tpu.parallel.router import ShardRouterStage
-
-    return ShardRouterStage(
-        "router",
-        ins=[shm.make_consumer(links["gv"], lazy=32)],
-        outs=[shm.make_producer(links[f"sv{i}"]) for i in range(n_shards)],
-        cnc=cnc,
-        n_shards=n_shards,
-    )
-
-
-def build_verify_shard(links, cnc, *, shard_idx, batch, precomputed):
-    # N shard processes cannot share a chip, so a shard child never takes
-    # it: build_sharded_leader_topology only wires this builder
-    # precomputed or on the CPU
-    from firedancer_tpu.utils.platform import select_device
-
-    dev = None if precomputed else select_device(cpu=True)
-    from firedancer_tpu.runtime.verify import VerifyStage
-
-    stage = VerifyStage(
-        f"verify_s{shard_idx}",
-        ins=[shm.make_consumer(links[f"sv{shard_idx}"], lazy=32)],
-        outs=[shm.make_producer(links[f"vd{shard_idx}"])],
-        cnc=cnc,
-        batch=batch,
-        max_msg_len=256,
-        batch_deadline_s=0.002,
-        precomputed_ok=precomputed,
-    )
-    if dev is not None:
-        _warm_verify(stage, dev)
-    return stage
-
-
 def build_dedup(links, cnc):
     from firedancer_tpu.runtime.dedup import DedupStage
 
     return DedupStage(
         "dedup",
         ins=[shm.make_consumer(links["vd"], lazy=32)],
-        outs=[shm.make_producer(links["dp"])],
-        cnc=cnc,
-    )
-
-
-def build_dedup_sharded(links, cnc, *, n_shards):
-    from firedancer_tpu.runtime.dedup import DedupStage
-
-    return DedupStage(
-        "dedup",
-        ins=[shm.make_consumer(links[f"vd{i}"], lazy=32) for i in range(n_shards)],
         outs=[shm.make_producer(links["dp"])],
         cnc=cnc,
     )
@@ -718,114 +671,3 @@ def leader_window_done(n_slots: int, stage: str = "poh"):
                 >= n_slots)
 
     return _done
-
-
-def build_sharded_leader_topology(
-    *,
-    n_shards: int = 4,
-    n_txns: int = 64,
-    pool_size: int = 64,
-    batch: int = 32,
-    leader_seed: bytes = b"leader",
-    slot: int = 1,
-    sandbox: dict | None = None,
-    verify_precomputed: bool = False,
-    verify_cpu: bool = False,
-    shard_depth: int = 512,
-    native_pack: bool | None = None,
-) -> ft.Topology:
-    """The SHARDED serving topology (process form): ingress round-robins
-    through an explicit shard router into per-shard rings, and one verify
-    process per shard carries shard labels the whole observability plane
-    understands (run descriptor -> scrape {stage="verify",shard=i} ->
-    monitor aggregation).
-
-        benchg -> gv -> router -> sv{i} -> verify_s{i} -> vd{i} -> dedup
-               -> pack -> bank -> poh -> shred -> store
-
-    N shard processes cannot share a chip: on the device the sharded
-    deployment is ONE process driving every chip through ServePlane —
-    the COOPERATIVE form, models/leader.build_sharded_leader_pipeline.
-    This topology is its process-isolation counterpart where each shard
-    is a crash domain, and its shard children never take the chip: they
-    run verify_precomputed (the host-machinery instrument, no device
-    dispatch) or, with verify_cpu, the kernel on the CPU backend.
-    Asking for neither is an error here rather than N children racing
-    for one device.
-    """
-    if not (verify_precomputed or verify_cpu):
-        raise ValueError(
-            "the sharded PROCESS topology starts one verify process per "
-            "shard and a chip belongs to one process: drive the chips "
-            "from one process (models/leader.build_sharded_leader_pipeline"
-            " over a ServePlane), or pass verify_precomputed=True / "
-            "verify_cpu=True")
-    from firedancer_tpu.models.leader import resolve_native_pack
-    from firedancer_tpu.ops.ref import ed25519_ref as ref
-    from firedancer_tpu.parallel.router import ShardRouterStage
-    from firedancer_tpu.runtime.bank import BankStage
-    from firedancer_tpu.runtime.dedup import DedupStage
-    from firedancer_tpu.runtime.pack_stage import PackStage
-    from firedancer_tpu.runtime.poh_stage import PohStage
-    from firedancer_tpu.runtime.verify import VerifyStage
-
-    use_native_pack = resolve_native_pack(native_pack)
-    n_bank = 1  # see build_leader_topology: one bank until funk is shared
-    topo = ft.Topology()
-    topo.link("gv", depth=1024, mtu=1232)
-    for i in range(n_shards):
-        topo.link(f"sv{i}", depth=shard_depth, mtu=1232)  # pow2 (FD104)
-        topo.link(f"vd{i}", depth=shard_depth, mtu=4096)
-    if not use_native_pack:
-        topo.link("dp", depth=1024, mtu=4096)
-    for b in range(n_bank):
-        topo.link(f"pb{b}", depth=256, mtu=65536)
-        topo.link(f"bp{b}", depth=256, mtu=65536)
-        topo.link(f"bd{b}", depth=256, mtu=64)
-    topo.link("ps", depth=1024, mtu=65536)
-    topo.link("ss", depth=4096, mtu=1232)
-
-    secret = hashlib.sha256(leader_seed).digest()
-    leader_pub = ref.public_key(secret)
-
-    sb = sandbox
-    topo.stage("benchg", build_benchg, pool_size=pool_size, n_txns=n_txns,
-               sandbox=sb, outs=["gv"])
-    topo.stage("router", build_router, n_shards=n_shards, sandbox=sb,
-               ins=["gv"], outs=[f"sv{i}" for i in range(n_shards)],
-               credit_gated=True,
-               schema=ShardRouterStage.metrics_schema_n(n_shards))
-    for i in range(n_shards):
-        topo.stage(f"verify_s{i}", build_verify_shard,
-                   shard=i, logical="verify", shard_idx=i,
-                   batch=batch, precomputed=verify_precomputed, sandbox=sb,
-                   ins=[f"sv{i}"], outs=[f"vd{i}"],
-                   schema=VerifyStage.metrics_schema())
-    if use_native_pack:
-        vd_links = [f"vd{i}" for i in range(n_shards)]
-        topo.stage("pack", build_pack_native, n_bank=n_bank,
-                   txn_links=vd_links, sandbox=sb,
-                   ins=vd_links + [f"bd{b}" for b in range(n_bank)],
-                   outs=[f"pb{b}" for b in range(n_bank)],
-                   schema=PackStage.metrics_schema())
-    else:
-        topo.stage("dedup", build_dedup_sharded, n_shards=n_shards,
-                   sandbox=sb,
-                   ins=[f"vd{i}" for i in range(n_shards)], outs=["dp"],
-                   schema=DedupStage.metrics_schema())
-        topo.stage("pack", build_pack, n_bank=n_bank, sandbox=sb,
-                   ins=["dp"] + [f"bd{b}" for b in range(n_bank)],
-                   outs=[f"pb{b}" for b in range(n_bank)],
-                   schema=PackStage.metrics_schema())
-    for b in range(n_bank):
-        topo.stage(f"bank{b}", build_bank, bank_idx=b, slot=slot, sandbox=sb,
-                   ins=[f"pb{b}"], outs=[f"bp{b}", f"bd{b}"],
-                   credit_gated=True, schema=BankStage.metrics_schema())
-    topo.stage("poh", build_poh, n_bank=n_bank, sandbox=sb,
-               ins=[f"bp{b}" for b in range(n_bank)], outs=["ps"],
-               credit_gated=True, schema=PohStage.metrics_schema())
-    topo.stage("shred", build_shred, secret=secret, slot=slot, sandbox=sb,
-               ins=["ps"], outs=["ss"])
-    topo.stage("store", build_store, leader_pub=leader_pub, sandbox=sb,
-               ins=["ss"])
-    return topo

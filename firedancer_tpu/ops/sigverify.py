@@ -79,11 +79,12 @@ def _verify_ok(
     *,
     max_msg_len: int,
 ) -> jnp.ndarray:
-    """The verify ladder core (traced, unjitted): validate + sha512 +
-    double-scalar-mult + compare.  ONE implementation — every kernel in
-    the ladder (baseline, fused, the serving-plane step) traces exactly
-    this, so their masks cannot diverge by construction.  Lane-wise over
-    whatever batch axes trail (msg_len's shape); -> bool of that shape."""
+    """The verify core (traced, unjitted): validate + sha512 +
+    double-scalar-mult + compare.  ONE implementation — the stage's
+    program (ed25519_verify_batch_fused) and the four-array entry
+    (ed25519_verify_batch) trace exactly this, so their masks cannot
+    diverge by construction.  Lane-wise over whatever batch axes trail
+    (msg_len's shape); -> bool of that shape."""
     msg = msg.astype(jnp.int32)
     sig = sig.astype(jnp.int32)
     pubkey = pubkey.astype(jnp.int32)
@@ -167,9 +168,6 @@ def unpack_rows(rows: jnp.ndarray, *, max_msg_len: int):
             tail[ROW_PK_OFF:ROW_PK_OFF + 32])
 
 
-_unpack_rows = jax.jit(unpack_rows, static_argnames=("max_msg_len",))
-
-
 @functools.partial(jax.jit, static_argnames=("max_msg_len",))
 def ed25519_verify_batch_fused(rows: jnp.ndarray, *,
                                max_msg_len: int) -> jnp.ndarray:
@@ -181,6 +179,15 @@ def ed25519_verify_batch_fused(rows: jnp.ndarray, *,
     batch's bytes); the stage's reap reads the real lanes only."""
     return _verify_lanes(*unpack_rows(rows, max_msg_len=max_msg_len),
                          max_msg_len=max_msg_len)
+
+
+def verify_dispatch(rows, *, max_msg_len: int):
+    """Dispatch one batch of packed rows (on the device already) -> the
+    (B,) bool mask future: the ONE call the verify stage makes, one
+    compiled module a batch (the seam a test wraps to count or stub
+    dispatches).  Pad lanes are not masked: the stage ignores lanes
+    past its fill when reaping."""
+    return ed25519_verify_batch_fused(rows, max_msg_len=max_msg_len)
 
 
 # -- repeated-signer fast path ------------------------------------------------
@@ -263,123 +270,3 @@ def bank_alloc(n_slots: int):
     return jnp.zeros(
         (fc.NWIN, 16, 4, fl.NLIMB, n_slots), dtype=jnp.int16
     )
-
-
-# -- split-phase variant ------------------------------------------------------
-#
-# The same computation as four separately jitted programs: an A/B
-# reference that shows what XLA's fusion buys (each phase boundary is an
-# HBM round trip the fused program does not pay).  Same inputs, same
-# mask; nothing dispatches to it by default or as a fallback.  Each
-# phase folds the byte rows it is given (fold_batch); points, bits and
-# the running mask cross the phase boundaries folded, and the last
-# phase hands back the (B,) mask.
-
-
-@jax.jit
-def _phase_validate(sig, pubkey):
-    sig, pubkey = fold_batch(sig, pubkey)
-    sig = sig.astype(jnp.int32)
-    pubkey = pubkey.astype(jnp.int32)
-    r_enc = sig[:32]
-    ok_s = fs.sc_validate(sig[32:])
-    a_pt, ok_a = fc.point_decompress(pubkey)
-    r_pt, ok_r = fc.point_decompress(r_enc)
-    ok = ok_s & ok_a & ~fc.is_small_order(a_pt)
-    ok = ok & ok_r & ~fc.is_small_order(r_pt)
-    return a_pt, r_pt, ok
-
-
-@functools.partial(jax.jit, static_argnames=("max_msg_len",))
-def _phase_hash(msg, msg_len, sig, pubkey, *, max_msg_len):
-    msg, msg_len, sig, pubkey = fold_batch(msg, msg_len, sig, pubkey)
-    msg = msg.astype(jnp.int32)
-    sig = sig.astype(jnp.int32)
-    pubkey = pubkey.astype(jnp.int32)
-    hmsg = jnp.concatenate([sig[:32], pubkey, msg], axis=0)
-    digest = fsha.sha512_msg(hmsg, msg_len + 64, max_msg_len + 64)
-    return fs.sc_bits(fs.sc_reduce512(digest))
-
-
-@jax.jit
-def _phase_dsm(k_bits, a_pt, sig):
-    (sig,) = fold_batch(sig)
-    s_bits = fs.sc_bits(fs.sc_frombytes(sig[32:].astype(jnp.int32)))
-    return fc.double_scalar_mul_base(k_bits, fc.point_neg(a_pt), s_bits)
-
-
-@jax.jit
-def _phase_compare(r_cmp, r_pt, ok):
-    return (ok & fc.point_eq_z1(r_cmp, r_pt)).reshape(-1)
-
-
-def ed25519_verify_batch_split(msg, msg_len, sig, pubkey, *, max_msg_len):
-    """Drop-in for ed25519_verify_batch using the four-phase pipeline."""
-    a_pt, r_pt, ok = _phase_validate(sig, pubkey)
-    k_bits = _phase_hash(msg, msg_len, sig, pubkey, max_msg_len=max_msg_len)
-    r_cmp = _phase_dsm(k_bits, a_pt, sig)
-    return _phase_compare(r_cmp, r_pt, ok)
-
-
-# -- the kernel ladder --------------------------------------------------------
-#
-# One registry for the generic-lane kernel choice (the verify stage's
-# `kernel=` knob and the dispatch-count assertions in tests).  Every
-# lane takes the SAME packed rows and returns the SAME mask — they all
-# trace _verify_ok — and differs only in how many compiled modules a
-# batch dispatch enters:
-#
-#   fused    1 module  (the default: the stage's program, unpack included)
-#   baseline 2 modules (the on-device unpack, then the library's kernel)
-#   split    5 modules (the unpack, then the four phases: the cost of
-#                       the phase boundaries)
-
-KERNEL_LADDER = ("fused", "baseline", "split")
-
-# the jitted callables each lane enters per batch dispatch, in call
-# order — len() of a row IS that lane's dispatches-per-batch, and
-# summing _cache_size() over a row counts its live compiled entries
-_KERNEL_JITS = {
-    "fused": (ed25519_verify_batch_fused,),
-    "baseline": (_unpack_rows, ed25519_verify_batch),
-    "split": (_unpack_rows, _phase_validate, _phase_hash, _phase_dsm,
-              _phase_compare),
-}
-
-
-def kernel_dispatch_count(kernel: str) -> int:
-    """Compiled modules entered per batch dispatch on this lane."""
-    return len(_KERNEL_JITS[kernel])
-
-
-def kernel_compiled_entries(kernel: str) -> int:
-    """Live compiled-executable entries across the lane's jit caches —
-    after exactly one batch shape has run, this equals
-    kernel_dispatch_count (the acceptance assertion for 'the fused
-    program dispatches ONE compiled module per batch')."""
-    return sum(int(f._cache_size()) for f in _KERNEL_JITS[kernel])
-
-
-def kernel_clear_caches(kernel: str) -> None:
-    """Drop the lane's compiled entries (test isolation for the
-    entry-count assertions)."""
-    for f in _KERNEL_JITS[kernel]:
-        f.clear_cache()
-
-
-def verify_dispatch(kernel: str, rows, *, max_msg_len: int):
-    """Dispatch one batch of packed rows (on the device already) on the
-    chosen ladder lane -> the (B,) bool mask future.  The fused lane is
-    one module; the two A/B references unpack on the device first and
-    take the same four arrays they always did.  No lane masks pad
-    lanes: the stage ignores lanes past its fill when reaping, so the
-    masks agree on every REAL lane."""
-    if kernel == "fused":
-        return ed25519_verify_batch_fused(rows, max_msg_len=max_msg_len)
-    if kernel not in KERNEL_LADDER:
-        raise ValueError(f"unknown verify kernel {kernel!r} "
-                         f"(ladder: {', '.join(KERNEL_LADDER)})")
-    lane = (ed25519_verify_batch if kernel == "baseline"
-            else ed25519_verify_batch_split)
-    return lane(*_unpack_rows(rows, max_msg_len=max_msg_len),
-                max_msg_len=max_msg_len)

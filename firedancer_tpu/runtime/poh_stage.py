@@ -83,8 +83,6 @@ class PohStage(Stage):
             fm.MetricsSchema()
             .counter("ticks", "tick entries emitted")
             .counter("mixins", "microblock mixin entries emitted")
-            .counter("poh_spans_queued", "full-tick spans parked for the"
-                     " serving plane's on-mesh self-audit")
             .counter("slots_sealed",
                      "slots whose final tick landed at the deadline"
                      " (slot-clock mode)")
@@ -108,7 +106,6 @@ class PohStage(Stage):
         hashes_per_tick: int = 64,
         ticks_per_slot: int = 8,
         hashes_per_iter: int = 16,
-        plane=None,
         clock=None,
         **kwargs,
     ):
@@ -124,14 +121,6 @@ class PohStage(Stage):
         # entries is an optional in-memory record for replay tests
         self.last_entry_hash = seed
         self.entries: list[tuple[int, bytes, list[bytes]]] | None = None
-        # serving plane (parallel/serve.ServePlane): full-tick pure-append
-        # spans are parked on the plane and re-verified ON the mesh by the
-        # next serving step — the leader auditing its own clock with the
-        # same device program replay uses, at zero extra dispatches.  Spans
-        # only match the compiled shape when a whole tick passed without a
-        # mixin (poh_iters == hashes_per_tick); others are skipped.
-        self.plane = plane
-        self._span_start = seed
         # slot-clock mode (runtime/slot_clock): ticks PACED to the wall-
         # clock deadline, the slot sealed at its boundary regardless of
         # pending load, and a boundary that passes unsealable (frozen
@@ -282,7 +271,6 @@ class PohStage(Stage):
         self.chain.mixin(mixin)
         num_hashes = self._hashes_since_entry + 1  # mixin counts as one
         self._hashes_since_entry = 0
-        self._span_start = self.chain.hash  # mixin breaks the append span
         self.metrics.inc("mixins")
         self.entries_out += 1
         self.last_entry_hash = self.chain.hash
@@ -307,13 +295,6 @@ class PohStage(Stage):
         self._tick_cnt += 1
         num_hashes = self._hashes_since_entry
         self._hashes_since_entry = 0
-        if (
-            self.plane is not None
-            and num_hashes == self.plane.cfg.poh_iters
-            and self.plane.queue_poh_span(self._span_start, self.chain.hash)
-        ):
-            self.metrics.inc("poh_spans_queued")
-        self._span_start = self.chain.hash
         self.metrics.inc("ticks")
         self.entries_out += 1
         self.last_entry_hash = self.chain.hash

@@ -27,14 +27,14 @@ slot and the stage stays where it was put.
 Native lanes (ISSUE 11), chosen at construction when `secret` is given:
 
   - sweep mode: with the native shredder built, a native out producer,
-    and no keep_sets/plane requirement, the stage registers a
+    and no keep_sets requirement, the stage registers a
     shred_native.StageClient as its sweep-harness client — the ENTIRE
     run_once sweep (drain entries -> accumulate -> batch close -> shred
     -> publish) is one fdr_sweep crossing with zero Python per frag,
     the reference's mux-run-loop shape.  The Python callbacks below
     remain the fallback surface (mixed-lane/lossy splices) and forward
     into the SAME C-side batch buffer, so the lanes cannot diverge.
-  - batch mode: keep_sets/plane-less topologies that stay on the Python
+  - batch mode: keep_sets topologies, and those that stay on the Python
     frag path still shred through NativeShredder — one FFI crossing per
     entry batch, byte-identical sets.
 
@@ -76,7 +76,6 @@ class ShredStage(Stage):
         shred_version: int = 1,
         batch_target_sz: int = 16384,
         keep_sets: bool = False,
-        plane=None,
         **kwargs,
     ):
         super().__init__(*args, **kwargs)
@@ -91,12 +90,11 @@ class ShredStage(Stage):
         self._parent_off = 1
         self._pending_bc = False
         # -- lane selection ---------------------------------------------------
-        # the mesh-sharded parity path (plane) is the Python shredder's;
         # keep_sets needs materialized FecSets, so sweep mode is out
         self.shredder = None
         self._sweep_client = None
         self.native_shred = False
-        if secret is not None and plane is None:
+        if secret is not None:
             from . import shred_native as sd
 
             if sd.available():
@@ -117,7 +115,7 @@ class ShredStage(Stage):
                     self.native_shred = False
         if self.shredder is None:
             self.shredder = Shredder(signer=signer,
-                                     shred_version=shred_version, plane=plane)
+                                     shred_version=shred_version)
 
     # slot is a property so the sweep client's C-side state (and its
     # slot-scoped shred index reset) tracks reassignment exactly like
@@ -271,13 +269,13 @@ class FusedPohShredStage(PohStage):
     def __init__(self, *args, signer, secret: bytes | None = None,
                  shred_slot: int = 1, shred_version: int = 1,
                  batch_target_sz: int = 16384, keep_sets: bool = False,
-                 shred_plane=None, **kwargs):
+                 **kwargs):
         super().__init__(*args, **kwargs)
         self.shred_half = ShredStage(
             f"{self.name}/shred", ins=[], outs=list(self.outs),
             signer=signer, secret=secret, slot=shred_slot,
             shred_version=shred_version, batch_target_sz=batch_target_sz,
-            keep_sets=keep_sets, plane=shred_plane,
+            keep_sets=keep_sets,
         )
 
     def publish(self, out_idx: int, payload: bytes, sig: int = 0,

@@ -102,20 +102,13 @@ class FecSet:
 
 @dataclass
 class Shredder:
-    """Stateful across a slot: shred indices continue between batches.
-
-    plane: a parallel/serve.ServePlane — when configured, normal-shape
-    FEC groups (d=32) compute parity through the mesh-sharded RS program
-    (sets sharded over the mesh, sz zero-padded to the compiled width);
-    odd-shape tails keep the host lane, byte-identically.
-    """
+    """Stateful across a slot: shred indices continue between batches."""
 
     signer: object  # callable(merkle_root: bytes) -> 64-byte signature
     shred_version: int = 0
     slot: int = -1
     data_idx_offset: int = 0
     parity_idx_offset: int = 0
-    plane: object = None
 
     def __post_init__(self):
         # build/load the native RS encoder now, not when the first FEC
@@ -216,13 +209,8 @@ class Shredder:
                         dtype=np.uint8,
                     )
             # host lane: one-to-few sets per batch is dispatch-bound on
-            # the device path (native/fd_reedsol.cpp; parity-identical).
-            # With a serving plane configured, normal-shape groups ride
-            # the mesh-sharded RS program instead.
-            if self.plane is not None:
-                par = self.plane.encode_parity(stack, p)  # (nsets, p, elt_sz)
-            else:
-                par = reedsol.encode_host(stack, p)
+            # the device path (native/fd_reedsol.cpp; parity-identical)
+            par = reedsol.encode_host(stack, p)  # (nsets, p, elt_sz)
             for k, set_i in enumerate(idxs):
                 parity_by_set[set_i] = par[k]
 

@@ -26,8 +26,8 @@ Python lane's _assemble builds the same), one `device_put` of the slot's
 own memory — and coming back it is the program's one output, the (batch,)
 bool mask, fetched once at the reap, which counts the passes itself.
 
-How deep the window is (one test, `_window_has_room`, on the native lane,
-the Python lane and the sharded stage alike): WINDOW_DEPTH, two — one
+How deep the window is (one test, `_window_has_room`, on the native and
+the Python lane alike): WINDOW_DEPTH, two — one
 batch running and one queued behind it.  The second place is for FULL
 batches, and for a batch that is not full only while the thread LEADS
 the chip: it exists to keep the device back to back, which matters only
@@ -174,7 +174,6 @@ without the retry).
 from __future__ import annotations
 
 import contextlib
-import os
 import time
 from dataclasses import dataclass, field
 
@@ -246,13 +245,6 @@ VERIFY_TCACHE_DEPTH = 16  # tiny by design (fd_verify.h:6-7)
 
 COMB_FILL_BATCH = 32  # pubkeys per comb_fill dispatch (fixed jit shape)
 
-# the generic-lane kernel ladder (ops/sigverify.KERNEL_LADDER): fused is
-# the default — ONE compiled module per batch (unpack + validate + sha512
-# + dsm + compare); split and baseline are A/B references only, nothing
-# falls back to them
-VERIFY_KERNELS = ("fused", "baseline", "split")
-DEFAULT_KERNEL = os.environ.get("FDTPU_VERIFY_KERNEL", "fused")
-
 # the async in-flight window (wiredancer shape): how many device batches
 # a stage keeps outstanding — one running and, if it or the running one
 # is full, one queued behind it (module docstring).  Reaping is strictly
@@ -307,6 +299,78 @@ def sig_tag(sig: bytes) -> int:
     return int.from_bytes(sig[:8], "little") or 1
 
 
+# -- the program's one argument: where a batch's rows go ----------------------
+#
+# What a stage dispatches is fixed by three numbers — batch, max_msg_len,
+# devices — so the stage and `python -m firedancer_tpu warmup`, which
+# builds no stage, share these three functions and compile one program.
+
+
+def mesh_row_sharding(batch: int, devices: int | None):
+    """How a (batch, row_width) array of packed rows lies over `devices`
+    chips: None for the default device (devices None or 1), else rows
+    sharded P(axis, None) over a one-axis mesh of the first `devices`
+    local devices (parallel/mesh.AXIS; the mask comes back P(axis)).
+    Raises ValueError where the batch, or the (batch // 128, 128) rows
+    the program folds it to, do not divide over the chips."""
+    if devices is None or devices == 1:
+        return None
+    from firedancer_tpu.ops.sigverify import FOLD_LANES, fold_lanes
+
+    if batch % devices:
+        raise ValueError(
+            f"verify batch {batch} does not divide over"
+            f" {devices} devices")
+    if fold_lanes(batch) and batch % (FOLD_LANES * devices):
+        # the program sees the global shape and folds it to
+        # (batch // 128, 128) rows: they have to divide too
+        raise ValueError(
+            f"verify batch {batch} is folded by the program to"
+            f" {batch // FOLD_LANES} rows of {FOLD_LANES} lanes,"
+            f" which do not divide over {devices} devices")
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from firedancer_tpu.parallel import mesh as pm
+
+    return NamedSharding(pm.make_mesh(devices),
+                         PartitionSpec(pm.AXIS, None))
+
+
+def place_rows(rows: np.ndarray, sharding):
+    """One batch's packed rows (element e in row e) onto the device(s)
+    -> the program's one argument.  One device (`sharding` None): one
+    `device_put` of the contiguous array as it lies (the native lane's
+    is the slot's own memory, not written again until the reap releases
+    the slot) — no transpose, no host copy of ours.  A mesh of d
+    devices: chip i is dealt rows i, i + d, ... — one row-strided view
+    per chip straight onto its shard (wrapping in jnp.asarray first
+    would commit the whole batch to device 0 and then reshard it).
+    uint8: 4x less transfer; the program widens to int32 on the
+    device."""
+    import jax
+
+    if sharding is None:
+        return jax.device_put(rows)
+    d = sharding.mesh.size
+    per = rows.shape[0] // d
+    return jax.make_array_from_callback(
+        rows.shape, sharding, lambda idx: rows[idx[0].start // per::d])
+
+
+def warm_program(batch: int, max_msg_len: int, sharding) -> float:
+    """Compile (or load from the persistent cache) the program a stage
+    of this geometry dispatches, at its exact shape, dtype and
+    placement: one all-pad batch through the call the dispatch makes.
+    -> seconds."""
+    from firedancer_tpu.ops import sigverify as sv
+
+    t0 = time.monotonic()
+    rows = np.zeros((batch, vn.row_width(max_msg_len)), dtype=np.uint8)
+    sv.verify_dispatch(place_rows(rows, sharding),
+                       max_msg_len=max_msg_len).block_until_ready()
+    return time.monotonic() - t0
+
+
 @dataclass
 class _Pending:
     """A device batch in flight: txns + their element ranges + the future."""
@@ -317,8 +381,7 @@ class _Pending:
     tsorigs: list[int]
     n_elems: int
     result: object  # jax array future
-    # the batch's stamps (None where a subclass builds its own pending)
-    life: _Life | None = None
+    life: _Life  # the batch's stamps
 
 
 @dataclass
@@ -353,30 +416,15 @@ class VerifyStage(Stage):
         max_msg_len: int = 1232,
         batch_deadline_s: float = 0.002,
         max_inflight: int | None = None,
-        kernel: str | None = None,
         autotune_after: int = 0,
         native_client: bool | None = None,
         devices: int | None = None,
         precomputed_ok: bool = False,
         comb_slots: int = 0,
         promote_threshold: int = 2,
-        plane=None,
         **kwargs,
     ):
         super().__init__(*args, **kwargs)
-        # plane: a parallel/serve.ServePlane — when configured, generic
-        # batches dispatch through the mesh-sharded serving step instead
-        # of the single-device kernel (the stage's batch geometry must
-        # match the plane's compiled shape; checked here, not mid-stream)
-        self.plane = plane
-        if plane is not None:
-            if batch != plane.cfg.batch or max_msg_len != plane.cfg.max_msg_len:
-                raise ValueError(
-                    f"verify stage (batch={batch}, max_msg_len={max_msg_len})"
-                    f" does not match the serving plane's compiled shape"
-                    f" (batch={plane.cfg.batch},"
-                    f" max_msg_len={plane.cfg.max_msg_len})"
-                )
         # precomputed_ok: bench instrument — skip the device dispatch and
         # mark every element valid, so the HOST pipeline machinery (rings,
         # parse, dedup, pack, bank, poh, shred) is measured net of
@@ -390,36 +438,17 @@ class VerifyStage(Stage):
         # seq % N), so the chips fill evenly at any fill; every generic
         # batch is placed straight onto its shards and verified by the
         # SAME program (ops/sigverify.verify_dispatch), one compiled
-        # module a step over the mesh.  The packed rows are sharded by
-        # row, P(axis, None), over the serving plane's mesh axis
-        # (parallel/mesh.AXIS); the mask comes back P(axis).
-        from firedancer_tpu.ops.sigverify import FOLD_LANES, fold_lanes
+        # module a step over the mesh (mesh_row_sharding, place_rows).
+        from firedancer_tpu.ops.sigverify import fold_lanes
 
-        self._row_sharding = None
+        self._row_sharding = mesh_row_sharding(batch, devices)
         self.mesh_devices = 1
-        if devices is not None and devices != 1:
-            if plane is not None or comb_slots:
+        if self._row_sharding is not None:
+            if comb_slots:
                 raise ValueError(
-                    "devices= places generic batches itself: a serving"
-                    " plane or a comb bank dispatches elsewhere")
-            if batch % devices:
-                raise ValueError(
-                    f"verify batch {batch} does not divide over"
-                    f" {devices} devices")
-            if fold_lanes(batch) and batch % (FOLD_LANES * devices):
-                # the program sees the global shape and folds it to
-                # (batch // 128, 128) rows: they have to divide too
-                raise ValueError(
-                    f"verify batch {batch} is folded by the program to"
-                    f" {batch // FOLD_LANES} rows of {FOLD_LANES} lanes,"
-                    f" which do not divide over {devices} devices")
-            from jax.sharding import NamedSharding, PartitionSpec
-
-            from firedancer_tpu.parallel import mesh as pm
-
+                    "devices= places generic batches itself: a comb bank"
+                    " dispatches elsewhere")
             self.mesh_devices = devices
-            self._row_sharding = NamedSharding(
-                pm.make_mesh(devices), PartitionSpec(pm.AXIS, None))
             self._use_shard_schema(devices)
         self.shard_idx = shard_idx
         self.shard_cnt = shard_cnt
@@ -437,12 +466,6 @@ class VerifyStage(Stage):
         # holds the stage to
         self.max_inflight = (WINDOW_DEPTH if max_inflight is None
                              else min(WINDOW_DEPTH, max_inflight))
-        self.kernel = kernel if kernel is not None else DEFAULT_KERNEL
-        if self.kernel not in VERIFY_KERNELS:
-            raise ValueError(
-                f"unknown verify kernel {self.kernel!r} "
-                f"(ladder: {', '.join(VERIFY_KERNELS)})"
-            )
         # autotune_after: re-derive (batch, max_msg_len, comb split) from
         # this stage's own batch-fill/msg-len histograms every N closed
         # batches (runtime/verify_tune.py); 0 = off (retuning recompiles)
@@ -503,9 +526,9 @@ class VerifyStage(Stage):
         # -- native sweep client (ISSUE 13) -----------------------------------
         # the whole intake sweep (drain -> parse -> guards -> batch
         # assembly) in ONE fdr_sweep crossing with zero Python per frag;
-        # armed only on the generic lane (no plane, no comb bank; one
-        # device or a mesh of them: the slot is the whole fixed-shape
-        # batch either way) over all-native rings whose out link carries
+        # armed only on the generic lane (no comb bank; one device or a
+        # mesh of them: the slot is the whole fixed-shape batch either
+        # way) over all-native rings whose out link carries
         # the preassembled frame size.  native_client: None = auto-arm
         # for exact VerifyStage instances, False = never, True =
         # required (raises, naming what blocked it).
@@ -528,9 +551,9 @@ class VerifyStage(Stage):
             # structural preconditions, each named so native_client=True
             # (the "required" contract) can say exactly what blocked it
             blocker = None
-            if plane is not None or comb_slots != 0:
-                blocker = ("a serving plane's step and the comb bank's"
-                           " signer tracking dispatch from the Python lane")
+            if comb_slots != 0:
+                blocker = ("the comb bank's signer tracking dispatches"
+                           " from the Python lane")
             elif not self.ins or not self.outs:
                 blocker = "stage has no rings"
             elif not all(type(c).__name__ == "NativeConsumer"
@@ -720,10 +743,9 @@ class VerifyStage(Stage):
 
     def _intake(self, payload: bytes):
         """Parse + guard one ingress frag; (sigs, msg, signers, t,
-        packed) or None after counting the drop.  The ONE implementation
-        of the frag-intake rules — the sharded serving stage
-        (parallel/serve.ShardedVerifyStage) reuses it verbatim, so the
-        two verify lanes can never silently diverge on a guard."""
+        packed) or None after counting the drop.  The Python lane's
+        frag-intake rules (after_frag and the python-parser sweep; the
+        native intake holds the same guards in native/fd_verify.cpp)."""
         t, packed = _parse_pair(payload)
         if packed is not None:
             sigs, msg, signers = _packed_fields(payload, packed)
@@ -883,7 +905,7 @@ class VerifyStage(Stage):
             # native lane: the C side stamps a batch as it opens, in
             # the crossing (one clock read a batch; open_since_ns)
             return
-        for acc in self._open_accs():
+        for acc in (self._gen, self._comb):
             if acc.elems and acc.opened_at == 0.0:
                 acc.opened_at = time.monotonic()
 
@@ -902,11 +924,6 @@ class VerifyStage(Stage):
 
     # -- when a batch closes -------------------------------------------------
 
-    def _open_accs(self):
-        """The accumulating batches a deadline runs for (the sharded
-        serving stage keeps one per shard)."""
-        return (self._gen, self._comb)
-
     def _flying(self) -> list:
         """The batches in flight, in dispatch order (the lane's own)."""
         return (self._nv_inflight if self._sweep_client is not None
@@ -916,14 +933,13 @@ class VerifyStage(Stage):
         """Fewer batches are in flight than the window is held to.  The
         ONE test of the depth, asked for batches that are sealed
         already (full ones, and what flush() seals): both lanes' submit
-        loops and the sharded stage's step ask here."""
+        loops ask here."""
         return len(self._flying()) < self.max_inflight
 
     def _waits_for_place(self, close: int) -> None:
         """A lane's submit loop left a sealed batch behind for want of
-        room (the native pump and the Python lane's), or the sharded
-        stage is about to block on the head for it.  A FULL one is the
-        evidence clause (b) reads (module docstring); what flush()
+        room (the native pump and the Python lane's).  A FULL one is
+        the evidence clause (b) reads (module docstring); what flush()
         seals and parks says nothing."""
         if close == CLOSE_FULL:
             self._full_waited = True
@@ -997,7 +1013,7 @@ class VerifyStage(Stage):
                 c.seal(why)
             return
         now = time.monotonic()
-        for acc in self._open_accs():
+        for acc in (self._gen, self._comb):
             if not (acc.elems and acc.opened_at
                     and now - acc.opened_at >= self.batch_deadline_s):
                 continue
@@ -1055,20 +1071,12 @@ class VerifyStage(Stage):
     def warmup(self) -> float:
         """Compile (or load from the persistent cache) the generic-lane
         program at this stage's exact dispatch shape and dtypes, before
-        traffic: one all-pad batch through the same call the dispatch
-        paths make.  Returns seconds; 0.0 when the stage dispatches
-        nothing itself (precomputed mask, or a serving plane that has
-        its own warmup())."""
-        if self.precomputed_ok or self.plane is not None:
+        traffic (warm_program).  Returns seconds; 0.0 when the stage
+        dispatches nothing (the precomputed mask)."""
+        if self.precomputed_ok:
             return 0.0
-        from firedancer_tpu.ops import sigverify as sv
-
-        t0 = time.monotonic()
-        rows = np.zeros((self.batch, vn.row_width(self.max_msg_len)),
-                        dtype=np.uint8)
-        sv.verify_dispatch(self.kernel, self._place(rows),
-                           max_msg_len=self.max_msg_len).block_until_ready()
-        return time.monotonic() - t0
+        return warm_program(self.batch, self.max_msg_len,
+                            self._row_sharding)
 
     def _mask_ready(self, result) -> bool:
         """Whether a dispatched batch's mask can be fetched without
@@ -1098,7 +1106,7 @@ class VerifyStage(Stage):
             _trace_annotation = TraceAnnotation
         return _trace_annotation(name, batch=life.seq if life else 0)
 
-    def _phase_end(self, life: _Life | None, phase: int,
+    def _phase_end(self, life: _Life, phase: int,
                    now: int | None = None) -> None:
         """End `phase` of one batch's life (it began where the phase
         before it ended): add its nanoseconds to the phase's counter.
@@ -1124,8 +1132,6 @@ class VerifyStage(Stage):
         own loop with no blocking call: intake, polls, the close rule.
         Not under the all-pass mask, which has no chip (like _span)."""
         self._loop_worked = True    # a batch moved: run_once's regime
-        if life is None:  # a subclass's own pending (parallel/serve)
-            return
         if now is None:
             now = _now_ns()
         t = life.t
@@ -1154,33 +1160,11 @@ class VerifyStage(Stage):
                 self._chip_empty_since = 0
                 self._chip_call_ns = self._chip_away_ns = 0
 
-    def _dispatch_begins(self, life: _Life | None) -> None:
+    def _dispatch_begins(self, life: _Life) -> None:
         """The sealed batch's wait is over: it takes its place in
         dispatch order (the annotations' `batch`)."""
         self._phase_end(life, PH_SEALED_WAIT)
-        if life is not None:
-            life.seq = self.metrics.get("batches") + 1
-
-    def _place(self, rows: np.ndarray):
-        """One batch's packed rows (element e in row e) onto the
-        device(s) -> the program's one argument.  One device: one
-        `device_put` of the contiguous array as it lies (the native
-        lane's is the slot's own memory, not written again until the
-        reap releases the slot) — no transpose, no host copy of ours.
-        A mesh of d devices: chip i is dealt rows i, i + d, ... — one
-        row-strided view per chip straight onto its shard (wrapping in
-        jnp.asarray first would commit the whole batch to device 0 and
-        then reshard it).  uint8: 4x less transfer; the program widens
-        to int32 on the device."""
-        import jax
-
-        if self._row_sharding is None:
-            return jax.device_put(rows)
-        d = self.mesh_devices
-        per = self.batch // d
-        return jax.make_array_from_callback(
-            rows.shape, self._row_sharding,
-            lambda idx: rows[idx[0].start // per::d])
+        life.seq = self.metrics.get("batches") + 1
 
     def _mask_of(self, result) -> np.ndarray:
         """A dispatched batch's mask on the host, element e at index e
@@ -1191,26 +1175,25 @@ class VerifyStage(Stage):
             return mask
         return mask.reshape(self.mesh_devices, -1).T.reshape(-1)
 
-    def _device_verify(self, life: _Life | None, rows: np.ndarray):
-        """The kernel-ladder dispatch of one batch's packed rows, the
-        ONE signature of both lanes: the host->device copy (_place: to
-        the default device, or to each mesh device the rows it is
-        dealt; the end of the batch's h2d phase), then the kernel call
-        (fused by default: one compiled module per batch — over a mesh
-        one module a step, the same program partitioned by its
-        argument's sharding, no collective in it); the caller ends the
-        launch phase.  -> the mask future, read through _mask_of; pad
-        lanes say nothing, the reap reads the real ones."""
+    def _device_verify(self, life: _Life, rows: np.ndarray):
+        """The dispatch of one batch's packed rows, the ONE signature
+        of both lanes: the host->device copy (place_rows: to the default
+        device, or to each mesh device the rows it is dealt; the end of
+        the batch's h2d phase), then the program's call
+        (ops/sigverify.verify_dispatch: one compiled module per batch —
+        over a mesh one module a step, the same program partitioned by
+        its argument's sharding, no collective in it); the caller ends
+        the launch phase.  -> the mask future, read through _mask_of;
+        pad lanes say nothing, the reap reads the real ones."""
         from firedancer_tpu.ops import sigverify as sv
 
-        dev = self._place(rows)
+        dev = place_rows(rows, self._row_sharding)
         self._phase_end(life, PH_H2D)
-        return sv.verify_dispatch(self.kernel, dev,
-                                  max_msg_len=self.max_msg_len)
+        return sv.verify_dispatch(dev, max_msg_len=self.max_msg_len)
 
     def _count_dispatch(self, n: int, close: int, occupancy: int) -> None:
-        """The books of one dispatched batch of `n` elements, on every
-        lane (the sharded stage's step too).  Over a mesh of d devices,
+        """The books of one dispatched batch of `n` elements, on both
+        lanes.  Over a mesh of d devices,
         device i was dealt elements i, i + d, ... below `n`: from the
         fill alone, no per-element work.
 
@@ -1439,8 +1422,7 @@ class VerifyStage(Stage):
 
     # -- device batching ----------------------------------------------------
 
-    def _close_batch(self, acc: _Acc | None = None,
-                     why: int = CLOSE_FULL) -> None:
+    def _close_batch(self, acc: _Acc, why: int = CLOSE_FULL) -> None:
         """Seal the accumulating batch and submit it if the in-flight
         window has room; a full window PARKS the sealed batch (submit is
         backpressure-aware — the loop never blocks on the oldest device
@@ -1450,8 +1432,6 @@ class VerifyStage(Stage):
         filled, the default, seals whatever is in flight and may take
         the window's second place; the deadline comes through
         _deadline_close, which asks first."""
-        if acc is None:  # legacy single-lane callers (tests)
-            acc = self._gen
         if not acc.elems:
             return
         acc.close = why
@@ -1535,16 +1515,11 @@ class VerifyStage(Stage):
         n = len(acc.elems)
         life = acc.life
         rows = self._assemble(acc)
-        if not cached and self.plane is None:
-            return self._device_verify(life, rows)
-        # the serving plane's step and the comb lane's kernel take the
-        # four byte-row arrays (len, batch) and make their own copies
-        msg, ln, sig, pk = vn.byte_rows(rows, self.max_msg_len)
         if not cached:
-            # mesh route: the sharded serving step (pad lanes beyond n
-            # are masked by the step itself via the per-shard fills)
-            self._phase_end(life, PH_H2D)
-            return self.plane.verify_batch(msg, ln, sig, pk)
+            return self._device_verify(life, rows)
+        # the comb lane's kernel takes the four byte-row arrays (len,
+        # batch) and makes its own copies
+        msg, ln, sig, pk = vn.byte_rows(rows, self.max_msg_len)
         import jax.numpy as jnp
 
         from firedancer_tpu.ops import sigverify as sv
@@ -1557,21 +1532,10 @@ class VerifyStage(Stage):
         return sv.ed25519_verify_batch_cached(
             *dev, max_msg_len=self.max_msg_len)
 
-    # result-extraction hooks: the sharded serving stage (parallel/serve.
-    # ShardedVerifyStage) reuses THIS drain loop — the txn-level
-    # pass-iff-all-pass rule must have exactly one implementation — and
-    # only overrides how a pending entry exposes readiness and its mask.
-
-    def _result_ready(self, head) -> bool:
-        return self._mask_ready(head.result)
-
-    def _result_mask(self, head) -> np.ndarray:
-        return self._mask_of(head.result)
-
     def _drain(self, block: bool) -> None:
         while self._inflight:
             head = self._inflight[0]
-            if not block and not self._result_ready(head):
+            if not block and not self._mask_ready(head.result):
                 return
             life = head.life
             self._phase_end(life, PH_INFLIGHT)
@@ -1585,7 +1549,7 @@ class VerifyStage(Stage):
     def _reap(self, head) -> list:
         """Fetch the head batch's mask, free its window slot, and encode
         the frames of the transactions that passed."""
-        mask = self._result_mask(head)
+        mask = self._mask_of(head.result)
         self._inflight.pop(0)
         self._window_freed()
         # a window slot freed: if the window is open to it now, seal
@@ -1649,7 +1613,7 @@ class VerifyStage(Stage):
         batch's publish phase ends when its last frame has left the
         queue, published or dropped."""
         marks = self._emit_marks
-        if emits or life is not None:
+        if life is not None:
             marks.append([life, len(emits)])
         with self._span("verify.publish", marks[0][0] if marks else None):
             self._emit_burst(emits)
@@ -1660,10 +1624,6 @@ class VerifyStage(Stage):
             self._phase_end(marks.pop(0)[0], PH_PUBLISH)
         if marks:
             marks[0][1] -= gone
-
-    def _emit(self, payload: bytes, desc_pair, tsorig: int = 0) -> None:
-        """Single-frag emit (compat surface for tests/subclasses)."""
-        self._emit_reaped([self._encode_emit(payload, desc_pair, tsorig)])
 
     def flush(self) -> None:
         """Close and drain everything (test/shutdown path)."""

@@ -117,8 +117,7 @@ def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory
     (compile_cache_dir).  The directory can be placed from outside: when
     JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and this
-    function sets nothing.  `ServePlane.warmup` keeps its warm-boot blobs
-    in the same directory.
+    function sets nothing.
 
     The CPU backend shares the cache.  The older jaxlib's XLA:CPU AOT
     (de)serialisation segfaults, which the CPU no-op and the

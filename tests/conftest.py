@@ -64,32 +64,33 @@ def toy_lane_ok(msg_len, msg0, sig0, sig63, pk0, pk31):
              + pk0 + pk31) & 1) == 0
 
 
+def toy_verify_core(msg, msg_len, sig, pubkey, *, max_msg_len):
+    """toy_lane_ok as the program traces it: what stands in for
+    ops/sigverify._verify_ok (the fixture below; a child process of a
+    test patches it in by hand)."""
+    import jax.numpy as jnp
+
+    assert msg.shape[0] == max_msg_len
+    assert sig.shape[0] == 64 and pubkey.shape[0] == 32
+    i32 = jnp.int32
+    total = (msg_len + msg[0].astype(i32) + sig[0].astype(i32)
+             + sig[63].astype(i32) + pubkey[0].astype(i32)
+             + pubkey[31].astype(i32))
+    return (total & 1) == 0
+
+
 @pytest.fixture
 def toy_verify_ok(monkeypatch):
     """ops/sigverify._verify_ok — the program's arithmetic, minutes of
     compile on a CPU — replaced by a lane-wise toy that compiles in no
     time.  Everything around it is the real thing: the packed rows, the
-    on-device unpack, the jitted program under its own name, the ladder
+    on-device unpack, the jitted program under its own name, its
     dispatch.  -> toy_lane_ok, the toy's verdicts on the host."""
-    import jax.numpy as jnp
-
     from firedancer_tpu.ops import sigverify as sv
 
-    def toy(msg, msg_len, sig, pubkey, *, max_msg_len):
-        assert msg.shape[0] == max_msg_len
-        assert sig.shape[0] == 64 and pubkey.shape[0] == 32
-        i32 = jnp.int32
-        total = (msg_len + msg[0].astype(i32) + sig[0].astype(i32)
-                 + sig[63].astype(i32) + pubkey[0].astype(i32)
-                 + pubkey[31].astype(i32))
-        return (total & 1) == 0
-
-    def clear():
-        for kernel in sv.KERNEL_LADDER:
-            sv.kernel_clear_caches(kernel)
-
+    clear = sv.ed25519_verify_batch_fused.clear_cache
     clear()   # nothing traced before may answer for the toy, nor after
-    monkeypatch.setattr(sv, "_verify_ok", toy)
+    monkeypatch.setattr(sv, "_verify_ok", toy_verify_core)
     yield toy_lane_ok
     clear()
 
@@ -129,8 +130,9 @@ class Exchange:
             return out
 
         class Output:
-            """The mask future: ready when the device says, fetched
-            through __array__ and by no other way."""
+            """The mask future: ready when the device says (or waited
+            for, unfetched), fetched through __array__ and by no other
+            way."""
 
             def __init__(self, fut):
                 self.fut = fut
@@ -138,13 +140,17 @@ class Exchange:
             def is_ready(self):
                 return self.fut.is_ready()
 
+            def block_until_ready(self):      # the warm-up's wait
+                self.fut.block_until_ready()
+                return self
+
             def __array__(self, dtype=None, copy=None):
                 ex.fetches.append(self.fut)
                 return np.asarray(self.fut)
 
-        def verify_dispatch(kernel, rows, *, max_msg_len):
+        def verify_dispatch(rows, *, max_msg_len):
             ex.programs += 1
-            return Output(dispatch(kernel, rows, max_msg_len=max_msg_len))
+            return Output(dispatch(rows, max_msg_len=max_msg_len))
 
         monkeypatch.setattr(jax, "device_put", device_put)
         monkeypatch.setattr(jax, "make_array_from_callback",

@@ -27,15 +27,14 @@ from firedancer_tpu.runtime.verify import VerifyStage
 from firedancer_tpu.tango import shm
 from firedancer_tpu.utils import metrics as fm
 
-# "mesh" is the native lane in front of four (virtual) devices
-# (ISSUE 26): VerifyStage(devices=...), 16 lanes as 4 x 4
-LANES = ["native", "python", "mesh"]
+# two intakes x {1, n} devices: "mesh" is the native intake in front of
+# four (virtual) devices (ISSUE 26): VerifyStage(devices=...), 16 lanes as
+# 4 x 4; "python-mesh" the Python intake in front of the same four (what
+# a four-chip host without a toolchain runs)
+LANES = ["native", "python", "mesh", "python-mesh"]
 NATIVE_LANES = ("native", "mesh")
+MESH_LANES = ("mesh", "python-mesh")
 MESH_DEVICES = 4
-# the close rule (ISSUE 25) also runs in parallel/serve.ShardedVerifyStage,
-# which names its accumulators and inherits the rest; it stamps no lives
-# (PR 24 left it out), so only the close tests take it
-CLOSE_LANES = LANES + ["sharded"]
 PHASE_COUNTERS = [f"batch_{p}_ns" for p in fm.BATCH_PHASES]
 
 
@@ -55,34 +54,18 @@ def _tile(lane: str, **stage_kw):
     uid = shm.fresh_uid()
     lin = shm.ShmLink.create(f"tbl_i_{uid}", depth=256, mtu=1232, n_fseq=1)
     lout = shm.ShmLink.create(f"tbl_o_{uid}", depth=256, mtu=4096, n_fseq=1)
-    lin1 = st = None
+    st = None
     try:
         kw = dict(batch=16, max_msg_len=256, batch_deadline_s=0.001,
                   precomputed_ok=True)
         kw.update(stage_kw)
-        ins = [shm.make_consumer(lin, lazy=8)]
-        prod = shm.make_producer(lin)
-        if lane == "sharded":
-            # two shards of 16 lanes, a ring each; `prod` feeds shard 0
-            # and carries shard 1's producer as `prod.shard1`
-            from firedancer_tpu.parallel import serve
-
-            lin1 = shm.ShmLink.create(f"tbl_j_{uid}", depth=256, mtu=1232,
-                                      n_fseq=1)
-            ins.append(shm.make_consumer(lin1, lazy=8))
-            prod = _Shard0(prod, shm.make_producer(lin1))
-            kw["plane"] = _Plane(serve.ServeConfig(
-                n_devices=2, batch_per_shard=kw["batch"],
-                max_msg_len=kw.pop("max_msg_len")))
-            cls = serve.ShardedVerifyStage
-        else:
-            cls = VerifyStage
-            if lane == "mesh":
-                kw["devices"] = MESH_DEVICES
-        st = cls("v0", ins=ins, outs=[shm.make_producer(lout)], **kw)
+        if lane in MESH_LANES:
+            kw["devices"] = MESH_DEVICES
+        st = VerifyStage("v0", ins=[shm.make_consumer(lin, lazy=8)],
+                         outs=[shm.make_producer(lout)], **kw)
         assert (st._sweep_client is not None) == (lane in NATIVE_LANES)
-        assert st.mesh_devices == (MESH_DEVICES if lane == "mesh" else 1)
-        yield st, prod, shm.make_consumer(lout, lazy=4)
+        assert st.mesh_devices == (MESH_DEVICES if lane in MESH_LANES else 1)
+        yield st, shm.make_producer(lin), shm.make_consumer(lout, lazy=4)
     finally:
         if prev is None:
             os.environ.pop(vn.ENV_SWITCH, None)
@@ -93,38 +76,6 @@ def _tile(lane: str, **stage_kw):
             st.drop_native_views()
         lin.close()
         lout.close()
-        if lin1 is not None:
-            lin1.close()
-
-
-class _Shard0:
-    """The sharded tile's feeder: shard 0's ring, with shard 1's beside."""
-
-    def __init__(self, prod0, prod1):
-        self.try_publish = prod0.try_publish
-        self.shard1 = prod1
-
-
-class _Plane:
-    """What ShardedVerifyStage asks of a ServePlane: its geometry, and a
-    step that hands back a pending the test makes ready (`sent`)."""
-
-    def __init__(self, cfg):
-        self.cfg = cfg
-        self.sent: list = []
-
-    def submit(self, msg, ln, sig, pk, n_real):
-        from firedancer_tpu.parallel.serve import _PrecomputedPending
-
-        class _GatedPending(_PrecomputedPending):
-            n = int(n_real.sum())
-            done = False
-
-            def ready(self) -> bool:
-                return self.done
-
-        self.sent.append(_GatedPending(self.cfg.batch))
-        return self.sent[-1]
 
 
 def _drain(cons) -> int:
@@ -141,7 +92,7 @@ def _record_lives(st) -> list:
 
     def record(life, phase, now=None):
         inner(life, phase, now)
-        if phase == rv.PH_PUBLISH and life is not None:
+        if phase == rv.PH_PUBLISH:
             lives.append(life)
 
     st._phase_end = record
@@ -335,7 +286,7 @@ def test_a_batch_crosses_the_boundary_once_each_way(lane, pool, exchange,
         assert {(a.shape, str(a.dtype)) for a in exchange.h2d} \
             == {((16, w), "uint8")}
         assert {f.shape for f in exchange.fetches} == {(16,)}
-        if lane == "mesh":
+        if lane in MESH_LANES:
             assert not exchange.puts
             assert len(exchange.callbacks) == MESH_DEVICES * n
             assert all(len(a.sharding.device_set) == MESH_DEVICES
@@ -569,8 +520,7 @@ def _gated_tile(lane: str, **kw):
     import jax.profiler  # noqa: F401  (the span's import, off the clock)
 
     with _tile(lane, precomputed_ok=False, **kw) as (st, prod, cons):
-        # the sharded stage's dispatch is the plane's step
-        sent: list = st.plane.sent if lane == "sharded" else []
+        sent: list = []
 
         def dispatch(life, rows):
             st._phase_end(life, rv.PH_H2D)
@@ -584,8 +534,7 @@ def _gated_tile(lane: str, **kw):
             sent[-1].behind = occupancy - 1
             books(n, close, occupancy)
 
-        if lane != "sharded":
-            st._device_verify = dispatch
+        st._device_verify = dispatch
         st._count_dispatch = count
         yield st, prod, cons, sent
 
@@ -594,7 +543,7 @@ def _open_elems(st) -> int:
     c = st._sweep_client
     if c is not None:
         return c.open_elems()
-    return sum(len(a.elems) for a in st._open_accs())
+    return len(st._gen.elems) + len(st._comb.elems)
 
 
 def _sealed_waiting(st) -> bool:
@@ -709,7 +658,7 @@ def _deepest(st) -> int:
     return max(int(edge) for edge, n in zip(h["buckets"], h["counts"]) if n)
 
 
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_close_counters_are_in_the_schema_and_start_at_zero(lane):
     with _tile(lane) as (st, _prod, _cons):
         for k in CLOSE_COUNTERS + [QUEUED_BEHIND]:
@@ -718,7 +667,7 @@ def test_close_counters_are_in_the_schema_and_start_at_zero(lane):
             <= st.metrics_schema().names()
 
 
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_nothing_in_flight_seals_at_the_deadline_as_before(lane, pool):
     with _gated_tile(lane, batch_deadline_s=0.05) as (st, prod, cons, sent):
         got: list = []
@@ -732,12 +681,11 @@ def test_nothing_in_flight_seals_at_the_deadline_as_before(lane, pool):
         assert 0.05 <= time.monotonic() - t0 < 5
         assert _closes(st) == [0, 1, 0] and [g.n for g in sent] == [5]
         assert st.metrics.get(QUEUED_BEHIND) == 0 and _deepest(st) == 1
-        if lane != "sharded":      # which stamps no lives
-            open_ms = st.metrics.get("batch_open_ns") / 1e6
-            assert 50 <= open_ms < 1000
+        open_ms = st.metrics.get("batch_open_ns") / 1e6
+        assert 50 <= open_ms < 1000
 
 
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_a_batch_in_flight_holds_the_open_batch_until_the_pump_that_reaps_it(
         lane, pool):
     """A batch that is not full does not queue behind a running one
@@ -855,7 +803,7 @@ def _held(st) -> int:
     return st.metrics.get(HELD_BACKLOGGED)
 
 
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_a_backlogged_intake_keeps_the_open_batch_filling_past_the_reap(
         lane, pool):
     """With a batch that was not full in flight and every sweep full, the
@@ -903,7 +851,7 @@ def test_a_backlogged_intake_keeps_the_open_batch_filling_past_the_reap(
 
 
 @pytest.mark.parametrize("flying", [True, False])
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_the_first_short_sweep_ends_the_backlog(lane, flying, pool):
     """On/off feed: the batch a backlog held goes out at the first pass
     through the rule after a sweep came back short, closed by the
@@ -1198,7 +1146,7 @@ def test_what_a_trailing_thread_holds_is_released_as_before(
         assert sum(_closes(st)) == st.metrics.get("batches") == 3
 
 
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_flush_sends_a_batch_the_backlog_held(lane, pool):
     with _gated_tile(lane) as (st, prod, cons, sent):
         st.burst = BURST
@@ -1283,58 +1231,8 @@ def test_a_paced_feed_closes_its_batches_as_before(lane, case, pool):
         assert got == list(pool[:n])
 
 
-@pytest.mark.parametrize("depth", [2, 1])
-def test_a_full_shard_closes_a_held_step_through_the_reap_it_waits_for(
-        depth, pool):
-    """The sharded stage closes the WHOLE step when one shard fills, and
-    with a full window it blocks on the head first.  With shard 0 held
-    past its deadline that reap comes back through the close rule
-    (_close_batch -> _drain -> _reap -> _deadline_close -> _close_batch)
-    where it leaves the window open to the step — two deep, room behind
-    the full batch still in flight; one deep, nothing in flight: the
-    step goes out once, every shard's fill in it."""
-    with _gated_tile("sharded", max_inflight=depth) as (st, prod, cons,
-                                                       sent):
-        got: list = []
-        n = _deadline_batch(st, prod, cons, pool, got, 0, 3)
-        first = [3]
-        if depth == 2:
-            n = _full_batch(st, prod, cons, pool, got, n)
-            first.append(16)
-        n = _held_batch(st, prod, cons, pool, got, n)    # shard 0: held
-        assert st._shards[0].held and got == []
-        # shard 1 fills, from a ring that runs dry (a sweep short of the
-        # burst: no backlog keeps the held step open at the reap)
-        _feed(prod.shard1, pool, n, n + 15)
-        _spin(st, cons, got, loops=2)
-        _feed(prod.shard1, pool, n + 15, n + 16)
-        _spin(st, cons, got, loops=4)
-        assert [g.n for g in sent] == first + [19]
-        assert st.metrics.get("batches") == depth + 1
-        assert _open_elems(st) == 0
-        assert _closes(st) == [depth - 1, 1, 1]
-        assert [g.behind for g in sent] == [0, 1, 1][:depth] + [depth - 1]
-        assert _in_flight(st) == depth and got == list(pool[:3])
-        assert not any(a.held or a.opened_at for a in st._shards)
-        # the next step opens with its own deadline, and a dry device
-        # closes it
-        for g in sent:
-            g.done = True
-        _feed(prod.shard1, pool, n + 16, n + 18)
-        _spin(st, cons, got)
-        _past_deadline(st, cons, got)
-        assert [g.n for g in sent] == first + [19, 2]
-        assert _closes(st) == [depth - 1, 2, 1]
-        sent[-1].done = True
-        _spin(st, cons, got)
-        assert got == list(pool[:n + 18])
-        assert sum(_closes(st)) == st.metrics.get("batches") == depth + 2
-        assert st.metrics.get("shard_elems_s0") == n
-        assert st.metrics.get("shard_elems_s1") == 18
-
-
 @pytest.mark.parametrize("held", [False, True])
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_flush_seals_whatever_is_in_flight(lane, held, pool):
     with _gated_tile(lane, batch_deadline_s=0.001 if held else 10.0) \
             as (st, prod, cons, sent):
@@ -1378,7 +1276,7 @@ def test_the_native_seal_hands_its_reason_back(pool):
         c.release(second[0])
 
 
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
     """Beside the stalls: the three close counters and
     batch_queued_behind, through the registry a scraper reads (schema ->
@@ -1426,7 +1324,7 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
         assert block[fm.KERNEL_FOLD_LANES] == 0
         # over a mesh: how many chips and the useful lanes of each, in
         # the same three places; with one device, in none
-        if lane == "mesh":
+        if lane in MESH_LANES:
             shards = [st.metrics.get(f"shard_elems_s{i}")
                       for i in range(MESH_DEVICES)]
             assert sum(shards) == st.metrics.get("batch_elems") == 20
@@ -1437,7 +1335,7 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
                 in rendered
             assert 'mesh_devices{stage="v0"} 4' in text
             assert f'shard_elems_s0{{stage="v0"}} {shards[0]}' in text
-        elif lane != "sharded":
+        else:
             assert "mesh" not in block and "mesh of" not in rendered
             assert 'mesh_devices{stage="v0"} 1' in text
     assert fm.batch_close_row([Stage("s").metrics.registry]) is None
@@ -1454,7 +1352,7 @@ def test_slotreport_and_monitor_show_the_close_counters(lane, pool):
 
 
 @pytest.mark.parametrize("asked", [None, 8])
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_the_window_is_two_deep_whatever_was_asked_above_that(
         lane, asked, pool):
     """A full batch is dispatched behind a running one, nothing behind
@@ -1472,18 +1370,12 @@ def test_the_window_is_two_deep_whatever_was_asked_above_that(
             n = _full_batch(st, prod, cons, pool, got, n)
             # ... a second full one waits for its place, and a batch is
             # held open behind them; never a third in flight
-            if lane == "sharded":
-                # which parks nothing: its full step blocks on the head
-                _feed(prod, pool, n, n + 16)
-                _spin(st, cons, got)
-                n = _held_batch(st, prod, cons, pool, got, n + 16)
-            else:
-                n = _parked_batch(st, prod, cons, pool, got, n)
-                n = _held_batch(st, prod, cons, pool, got, n, parked=True)
-                assert _in_flight(st) == 2 and len(sent) == 4 * k + 2
-                # the head is reaped: the sealed batch takes its place
-                sent[-2].done = True
-                _spin(st, cons, got)
+            n = _parked_batch(st, prod, cons, pool, got, n)
+            n = _held_batch(st, prod, cons, pool, got, n, parked=True)
+            assert _in_flight(st) == 2 and len(sent) == 4 * k + 2
+            # the head is reaped: the sealed batch takes its place
+            sent[-2].done = True
+            _spin(st, cons, got)
             assert len(sent) == 4 * k + 3 and _in_flight(st) == 2
             assert _open_elems(st) == 3 and st._full_waited
             # the next is reaped: the full batch that waited runs, there
@@ -1504,7 +1396,7 @@ def test_the_window_is_two_deep_whatever_was_asked_above_that(
         assert st.metrics.get("txn_verified") == n
 
 
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_a_window_of_one_still_runs(lane, pool):
     with _gated_tile(lane, max_inflight=1) as (st, prod, cons, sent):
         got: list = []
@@ -1520,7 +1412,7 @@ def test_a_window_of_one_still_runs(lane, pool):
 
 
 @pytest.mark.parametrize("depth", [3, 5])
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_a_deeper_window_reaps_and_publishes_in_dispatch_order(
         lane, depth, pool, monkeypatch):
     """Nothing in a lane rests on the depth being two: with `depth` real
@@ -1563,7 +1455,7 @@ def test_a_deeper_window_reaps_and_publishes_in_dispatch_order(
 # -- the books of the window under mixed traffic (ISSUE 32) --------------------------
 
 
-@pytest.mark.parametrize("lane", CLOSE_LANES)
+@pytest.mark.parametrize("lane", LANES)
 def test_the_windows_books_hold_under_bursts_of_every_size(lane, pool):
     """Bursts from one transaction to more than a batch's worth, results
     that come ready a round late: never more than two in flight, a

@@ -714,7 +714,7 @@ def test_fd210_scoped_to_runtime_and_parallel():
     findings = ast_rules.lint_source(_TRANSFER_SRC, "firedancer_tpu/waltz/x.py")
     assert [f for f in findings if f.rule == "FD210"] == []
     findings = ast_rules.lint_source(
-        _TRANSFER_SRC, "firedancer_tpu/parallel/serve.py")
+        _TRANSFER_SRC, "firedancer_tpu/parallel/mesh.py")
     assert len([f for f in findings if f.rule == "FD210"]) == 3
 
 
@@ -965,8 +965,8 @@ class VerifyStage:
         mask = np.asarray(self._inflight[0].result)   # ok: THE reap point
         return mask
 
-    def _result_mask(self, head):
-        return np.asarray(head.result)            # ok: reap hook
+    def _mask_of(self, result):
+        return np.asarray(result)                 # ok: the reaps' fetch
 
     def flush(self):
         return np.asarray(self._tail)             # ok: shutdown drain
@@ -976,8 +976,8 @@ class VerifyStage:
         return x
 
 
-class ShardedVerifyStage(VerifyStage):
-    def _close_batch(self, acc=None):
+class MeshVerifyStage(VerifyStage):
+    def _close_batch(self, acc):
         n_ok = int(np.asarray(self._pend.n_ok))   # FD214: subclass inherits
         return n_ok
 
@@ -1016,7 +1016,6 @@ def test_fd214_registered_and_baselined_on_repo():
     # _fill_bank hits (deliberate comb-install sync, documented in
     # baseline.toml) and nothing else
     for rel, allowed in (("runtime/verify.py", 2),
-                         ("parallel/serve.py", 0),
                          ("runtime/verify_native.py", 0)):
         root = os.path.join(os.path.dirname(__file__), "..",
                             "firedancer_tpu", rel)

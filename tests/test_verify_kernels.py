@@ -1,7 +1,7 @@
-"""Verify kernel ladder + async window + autotuner (ISSUE 13).
+"""The verify program's one dispatch + async window + autotuner (ISSUE 13).
 
-Tier-1 here is structural and host-only: ladder introspection (dispatch
-counts), the in-flight window's in-order/backpressure semantics at its
+Tier-1 here is structural and host-only: one compiled module a
+dispatch, the in-flight window's in-order/backpressure semantics at its
 depth of two (ISSUE 27) and, so that nothing rests on the two, at deeper
 ones — full batches behind the running one, a batch that is not full
 only behind a full one (ISSUE 32) — driven with fake device futures (no XLA), the autotuner's
@@ -9,7 +9,7 @@ determinism, and the packed row a batch goes to the device as (ISSUE
 29): its layout held equal between native/fd_verify.cpp, the binding,
 the Python lane's _assemble and the program's on-device unpack, which
 compiles in no time.  The compile-heavy differential lanes (the packed
-program vs ops/ref vs split vs baseline masks on adversarial inputs,
+program vs ops/ref vs the four-array entry's masks on adversarial inputs,
 cached interleave) live behind the `slow` marker — a single
 sigverify-program compile costs ~3 min on one core.
 """
@@ -27,32 +27,32 @@ from firedancer_tpu.runtime.verify import VerifyStage
 from firedancer_tpu.tango import shm
 
 
-# -- ladder structure (no device) ---------------------------------------------
+# -- one module a dispatch -----------------------------------------------------
 
 
-def test_kernel_ladder_dispatch_counts():
+def test_a_dispatch_enters_one_compiled_module(toy_verify_ok):
+    """What is left of the ladder's books: after one batch shape has
+    run, a dispatch enters ONE compiled module, unpack included — and
+    the same one however often it is called."""
+    import jax
+
     from firedancer_tpu.ops import sigverify as sv
+    from firedancer_tpu.runtime import verify_native as vn
 
-    assert set(sv.KERNEL_LADDER) == {"fused", "baseline", "split"}
-    # the stage's program is one module, unpack included; the A/B
-    # references unpack the same packed rows in a module of their own
-    assert sv.kernel_dispatch_count("fused") == 1
-    assert sv.kernel_dispatch_count("baseline") == 2
-    assert sv.kernel_dispatch_count("split") == 5
-    with pytest.raises(KeyError):
-        sv.kernel_dispatch_count("nope")
-
-
-def test_stage_rejects_unknown_kernel():
-    with pytest.raises(ValueError, match="unknown verify kernel"):
-        VerifyStage("v", ins=[], outs=[], kernel="warp")
+    prog = sv.ed25519_verify_batch_fused
+    assert prog._cache_size() == 0
+    dev = jax.device_put(np.zeros((16, vn.row_width(64)), dtype=np.uint8))
+    for _ in range(3):
+        mask = np.asarray(sv.verify_dispatch(dev, max_msg_len=64))
+        assert mask.shape == (16,) and mask.all()   # the toy passes zeros
+        assert prog._cache_size() == 1
 
 
-def test_stage_kernel_and_window_defaults(monkeypatch):
+def test_stage_window_defaults(monkeypatch):
     # the depth is a constant: the environment switch is gone
     monkeypatch.setenv("FDTPU_VERIFY_INFLIGHT", "5")
     st = VerifyStage("v", ins=[], outs=[], native_client=False)
-    assert st.kernel == "fused"
+    assert not hasattr(st, "kernel")    # and so is the ladder's
     # one running and, if it is full, one queued behind it
     assert st.max_inflight == rv.WINDOW_DEPTH == 2
 
@@ -694,10 +694,10 @@ def test_the_programs_unpack_inverts_pack_rows(mml):
     rows = vn.pack_rows(msg, ln, sig, pk, batch=b + 8)
     assert rows.shape == (b + 8, mml + sv.ROW_TAIL)
     assert vn.row_lens(rows, mml).tolist() == ln.tolist() + [0] * 8
-    got = sv._unpack_rows(jax.device_put(rows), max_msg_len=mml)
+    got = sv.unpack_rows(jax.device_put(rows), max_msg_len=mml)
     want = (msg.T, ln, sig.T, pk.T)
-    # the host's own unpack (strided views, for the comb lane and the
-    # serving plane) reads the same layout
+    # the host's own unpack (strided views, for the comb lane) reads
+    # the same layout
     for h, x in zip(vn.byte_rows(rows, mml), want):
         assert np.shares_memory(h, rows) and (h[..., :b] == x).all()
     for g, x, dt in zip(got, want, (np.uint8, np.int32, np.uint8, np.uint8)):
@@ -709,16 +709,18 @@ def test_the_programs_unpack_inverts_pack_rows(mml):
 # -- the layout of the batch inside the program (ISSUE 38) ---------------------
 
 
-@pytest.mark.parametrize("kernel", ["fused", "baseline"])
+@pytest.mark.parametrize("entry", ["fused", "four_arrays"])
 @pytest.mark.parametrize("batch, lanes", [
     (96, (96,)), (128, (1, 128)), (200, (200,)), (1024, (8, 128))])
 def test_the_program_folds_its_batch_by_the_shape_alone(
-        kernel, batch, lanes, toy_verify_ok, monkeypatch):
+        entry, batch, lanes, toy_verify_ok, monkeypatch):
     """A batch that is a multiple of 128 lanes is laid on both tiled
     axes inside the program, (batch // 128, 128); any other keeps its
     one axis.  The packed rows in and the (batch,) bool mask out are
     the same either way, lane for lane, and the stage's gauge says
-    which program it dispatches."""
+    which program it dispatches.  The four-array entry (the tests'
+    reference, ed25519_verify_batch over the same rows unpacked) folds
+    by the same rule."""
     import jax
 
     from firedancer_tpu.ops import sigverify as sv
@@ -747,13 +749,27 @@ def test_the_program_folds_its_batch_by_the_shape_alone(
     want = toy_verify_ok(ln, rows[:, 0], tail[:, 0], tail[:, 63],
                          tail[:, 64], tail[:, 95])
     dev = jax.device_put(rows)
-    for _ in range(2):          # the second call traces nothing
-        mask = np.asarray(sv.verify_dispatch(kernel, dev, max_msg_len=mml))
-        assert mask.dtype == np.bool_ and mask.shape == (batch,)
-        assert (mask == want).all() and want.any() and not want.all()
-    assert seen == [((mml,) + lanes, lanes, (64,) + lanes, (32,) + lanes)]
-    assert sv.kernel_compiled_entries(kernel) \
-        == sv.kernel_dispatch_count(kernel)
+    if entry == "fused":
+        prog = sv.ed25519_verify_batch_fused
+
+        def call():
+            return sv.verify_dispatch(dev, max_msg_len=mml)
+    else:
+        prog = sv.ed25519_verify_batch
+        prog.clear_cache()      # the fixture clears the stage's program
+
+        def call():
+            return prog(*sv.unpack_rows(dev, max_msg_len=mml),
+                        max_msg_len=mml)
+    try:
+        for _ in range(2):          # the second call traces nothing
+            mask = np.asarray(call())
+            assert mask.dtype == np.bool_ and mask.shape == (batch,)
+            assert (mask == want).all() and want.any() and not want.all()
+        assert seen == [((mml,) + lanes, lanes, (64,) + lanes, (32,) + lanes)]
+        assert prog._cache_size() == 1
+    finally:
+        prog.clear_cache()      # nothing traced on the toy answers later
     shape = jax.eval_shape(
         lambda r: sv.ed25519_verify_batch_fused(r, max_msg_len=mml), dev)
     assert (shape.shape, shape.dtype) == ((batch,), np.bool_)
@@ -861,15 +877,16 @@ def _rows(cases, batch=None):
     return vn.pack_rows(msg.T, ln, sig.T, pk.T, batch)
 
 
-@pytest.mark.slow  # three sigverify-program compiles (~3 min each)
+@pytest.mark.slow  # two sigverify-program compiles (~3 min each)
 @pytest.mark.parametrize("batch", [None, 128])
-def test_ladder_lanes_byte_identical_masks(batch, rng):
+def test_program_masks_byte_identical_to_the_reference(batch, rng):
     """The packed program's mask equals ops/ref's verdicts and the
-    baseline / split rungs', on corrupted signatures, msg_len 0 and
-    max_msg_len; and at a partial fill the real lanes' verdicts do not
-    depend on the pad rows — zero, or stale from an earlier batch.  At
-    a batch of 128 every rung folds its lanes to (1, 128)
-    (sv.fold_batch): the masks are those of the one-axis ladder too."""
+    four-array entry's (ed25519_verify_batch over the same rows
+    unpacked), on corrupted signatures, msg_len 0 and max_msg_len; and
+    at a partial fill the real lanes' verdicts do not depend on the pad
+    rows — zero, or stale from an earlier batch.  At a batch of 128
+    both entries fold their lanes to (1, 128) (sv.fold_batch): the
+    masks are those of the one-axis program too."""
     import jax
 
     from firedancer_tpu.ops import sigverify as sv
@@ -879,15 +896,17 @@ def test_ladder_lanes_byte_identical_masks(batch, rng):
     assert bool(sv.fold_lanes(n)) == (batch is not None)
     expect = expect + [False] * (n - len(cases))    # zero pad rows
     rows = jax.device_put(_rows(cases, batch))
-    masks = {}
-    for kernel in sv.KERNEL_LADDER:
-        mask = sv.verify_dispatch(kernel, rows, max_msg_len=MAX_MSG)
-        masks[kernel] = np.asarray(mask)
-        assert masks[kernel].dtype == np.bool_
-        assert masks[kernel].shape == (n,)
+    masks = {
+        "fused": sv.verify_dispatch(rows, max_msg_len=MAX_MSG),
+        "four_arrays": sv.ed25519_verify_batch(
+            *sv.unpack_rows(rows, max_msg_len=MAX_MSG),
+            max_msg_len=MAX_MSG),
+    }
+    for entry, mask in masks.items():
+        masks[entry] = mask = np.asarray(mask)
+        assert mask.dtype == np.bool_ and mask.shape == (n,)
     assert masks["fused"].tolist() == expect
-    assert masks["fused"].tolist() == masks["baseline"].tolist()
-    assert masks["fused"].tolist() == masks["split"].tolist()
+    assert masks["fused"].tolist() == masks["four_arrays"].tolist()
     if batch:
         flat = jax.jit(lambda r: sv._verify_ok(
             *sv.unpack_rows(r, max_msg_len=MAX_MSG), max_msg_len=MAX_MSG))
@@ -902,9 +921,9 @@ def test_ladder_lanes_byte_identical_masks(batch, rng):
     stale[:k] = zero[:k]
     assert not zero[k:].any() and stale[k:].any()
     got_zero = np.asarray(sv.verify_dispatch(
-        "fused", jax.device_put(zero), max_msg_len=MAX_MSG))
+        jax.device_put(zero), max_msg_len=MAX_MSG))
     got_stale = np.asarray(sv.verify_dispatch(
-        "fused", jax.device_put(stale), max_msg_len=MAX_MSG))
+        jax.device_put(stale), max_msg_len=MAX_MSG))
     assert got_zero[:k].tolist() == got_stale[:k].tolist() == expect[:k]
     assert not got_zero[k:].any()            # an all-zero row never verifies
     assert got_stale[k:].tolist() == expect[k:]
@@ -933,7 +952,7 @@ def test_cached_lane_interleave_matches_generic(batch, rng):
         cases.append((m, s, pub))
     msg, ln, sig, pk = _arrays(cases, batch)
     n = len(cases)
-    gen_mask = sv.verify_dispatch("fused", jnp.asarray(_rows(cases, batch)),
+    gen_mask = sv.verify_dispatch(jnp.asarray(_rows(cases, batch)),
                                   max_msg_len=MAX_MSG)
     fill = np.zeros((32, len(pubs)), dtype=np.uint8)
     for i, p in enumerate(pubs):

@@ -112,6 +112,54 @@ def test_the_pipeline_builder_passes_the_mesh_to_its_verify_stages():
         pipe.close()
 
 
+@pytest.mark.parametrize("intake", ["native", "python"])
+def test_the_pipeline_over_a_mesh_stores_exactly_the_txns_that_verify(
+        intake, toy_verify_ok, monkeypatch):
+    """build_leader_pipeline(verify_devices=4) end to end, on both
+    intakes, with the program (toy arithmetic) in the path and not the
+    all-pass mask: of 64 transfers 7 have a signature bit flipped, and
+    exactly those are missing from the stored block; every chip was
+    dealt lanes."""
+    from firedancer_tpu.models.leader import build_leader_pipeline
+    from firedancer_tpu.runtime.poh_stage import parse_entry
+    from firedancer_tpu.runtime.shred_stage import deshred_entry_batch
+
+    if intake == "native" and not vn.available():
+        pytest.skip("native verify client unavailable")
+    monkeypatch.setenv(vn.ENV_SWITCH, "1" if intake == "native" else "0")
+    n, bad = 64, (3, 11, 12, 30, 41, 55, 63)
+    good = [t for t in gen_transfer_pool(192, n_payers=8, n_dests=64)
+            if _toy_txn_ok(t, toy_verify_ok)][:n]
+    assert len(good) == n
+    txns = list(good)
+    for i in bad:           # byte 0 is the signature count, 1.. the sig
+        txns[i] = bytes([txns[i][0], txns[i][1] ^ 1]) + txns[i][2:]
+        assert not _toy_txn_ok(txns[i], toy_verify_ok)
+    pipe = build_leader_pipeline(
+        verify_devices=N_DEV, batch=BATCH, max_msg_len=MAX_MSG,
+        pool_size=8, gen_limit=n, n_payers=8)
+    try:
+        pipe.benchg.pool = txns
+        v = pipe.verifies[0]
+        assert (v._sweep_client is not None) == (intake == "native")
+        assert v.mesh_devices == N_DEV and not v.precomputed_ok
+        pipe.run(until_txns=n - len(bad), max_iters=200_000)
+        v.during_housekeeping()
+        c = v.metrics.get
+        assert c("verify_fail") == len(bad)
+        assert c("txn_verified") == n - len(bad)
+        assert sum(b.metrics.get("txn_exec") for b in pipe.banks) \
+            == n - len(bad)
+        shards = [c(f"shard_elems_s{i}") for i in range(N_DEV)]
+        assert all(shards) and sum(shards) == c("batch_elems") == n
+        stored = {p for e in deshred_entry_batch(
+                      pipe.store.entry_batch_bytes(1))
+                  for p in parse_entry(e)[2]}
+        assert stored == set(good) - {good[i] for i in bad}
+    finally:
+        pipe.close()
+
+
 # -- placement and verdicts, the dispatch alone ------------------------------------
 
 
@@ -171,7 +219,7 @@ def _dispatch_both(fill: int, make, *args):
     got = {}
     for name, devices in (("one", None), ("mesh", N_DEV)):
         st = _ringless(devices, len(rows))
-        mask = st._device_verify(None, rows)
+        mask = st._device_verify(rv._Life(rv._now_ns()), rows)
         got[name] = (mask, st._mask_of(mask))
     return got, want
 
@@ -268,7 +316,7 @@ def test_the_mesh_module_holds_no_collective(batch, lanes, toy_verify_ok,
     # dispatched: element e dealt to chip e % 4 and its verdict dealt
     # back to index e, pad rows and all
     real, want = _toy_batch(batch - 3, 7, toy_verify_ok, batch)
-    fut = st._device_verify(None, real)
+    fut = st._device_verify(rv._Life(rv._now_ns()), real)
     assert {s.data.shape for s in fut.addressable_shards} \
         == {(batch // N_DEV,)}
     mask = st._mask_of(fut)
