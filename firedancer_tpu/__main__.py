@@ -124,15 +124,19 @@ def _run_processes(args) -> int:
     cfg = _load_cfg(args)
     if cfg.layout.benchs_stage_count > 0:
         return _run_front(args, cfg)
-    if cfg.layout.bank_stage_count != 1:
-        # each bank process owns its funk (leader_topo.build_bank)
+    n_bank = cfg.layout.bank_stage_count
+    if n_bank > 1 and not cfg.development.bench.disable_status_cache:
+        # a status cache a bank process would let a repeat land once a
+        # tile (leader_topo.build_leader_topology refuses it)
         print(f"# the process topology runs 1 bank stage (config asks "
-              f"{cfg.layout.bank_stage_count})", file=sys.stderr)
+              f"{n_bank}): more take [development.bench] "
+              f"disable_status_cache", file=sys.stderr)
+        n_bank = 1
     sandbox = {"rlimits": {"nofile": 512}} if args.sandbox else None
     # the config's batch, widths, deadline, ring depths, pack rule and
     # slot cadence; the generator's payers are the bank's genesis
     topo = build_leader_topology_from_config(
-        cfg, n_bank=1, n_txns=args.txns, pool_size=args.txns,
+        cfg, n_bank=n_bank, n_txns=args.txns, pool_size=args.txns,
         verify_cpu=args.cpu, n_payers=RUN_PAYERS, sandbox=sandbox,
         boot_grace_s=5.0 if cfg.poh.slot_ms > 0 else 0.0,
     )
@@ -142,7 +146,9 @@ def _run_processes(args) -> int:
               f"fdtpu_run_{h.uid}.json"
               + (" (sandboxed)" if sandbox else ""), file=sys.stderr)
         def executed() -> int:
-            return h.met_views["bank0"][0].get("txn_exec")
+            # the bank tiles commit into one account store
+            return sum(h.met_views[f"bank{b}"][0].get("txn_exec")
+                       for b in range(n_bank))
 
         t0 = time.time()
         ok = h.supervise(

@@ -369,6 +369,7 @@ class SlotExecution:
         ancestors: set[int] | None = None,
         slot_hashes: list[tuple[int, bytes]] | None = None,
         xid: bytes | None = None,
+        join: bool = False,
     ):
         self.funk = funk
         self.slot = slot
@@ -385,11 +386,18 @@ class SlotExecution:
         # the native funk's FFK_XID_MAX (128) within a handful of slots.
         # A caller that has to find the fork again from another process
         # (a supervisor reading a bank tile's funk) names it: `xid`.
+        # `join`: the fork `xid` is there already, prepared by the
+        # process that made the store — this execution is one more bank
+        # tile committing into it (NativeFunk.attach), and leaves the
+        # seal and the publish to the tile that prepared it.
         self.xid = xid if xid is not None else b"slot:%d:%d:%s" % (
             slot, next(_xid_seq),
             hashlib.sha256(parent_xid).hexdigest()[:24].encode()
             if parent_xid else b"root")
-        funk.txn_prepare(parent_xid, self.xid)
+        if not join:
+            funk.txn_prepare(parent_xid, self.xid)
+        elif funk.txn_is_frozen(self.xid):     # raises: no such fork
+            raise ValueError("the fork to join has children")
         self.sysvars = default_sysvars(slot)
         # durable nonces advance against the PARENT's bank hash: fresh,
         # deterministic, and fixed before any txn in this block runs
@@ -431,6 +439,9 @@ class SlotExecution:
         self._gate_shipped_version = None  # StatusCache.version last sent
         self._native_known: set[bytes] = set()  # addrs the session holds
         self._native_dirty: set[bytes] = set()  # py-written since sync
+        # values this lane shipped the session from a store that other
+        # processes write too (execute_batch `shared`)
+        self.session_refreshed = 0
         self._table_cache: dict = {}  # ALT decode, once per block
         self._before: dict[bytes, bytes | None] = {}  # start-of-slot view
         # native shm funk: seal() reads before/after pairs from the fork
@@ -888,6 +899,12 @@ class SlotExecution:
         # account-value overlay, so the per-txn python gate checks and
         # the per-call funk value marshalling disappear (ISSUE 9)
         session = self._native_session if nat is not None else None
+        # a store other processes write too (NativeFunk.attach): what the
+        # session holds of an account may be another bank tile's past,
+        # so every value ships from the store (the sweep lane's
+        # read-through, native/fd_bank.cpp, on this lane) and is counted
+        # as there (`session_refreshed`)
+        shared = self._funk_diff and self.funk.writers() > 1
         pend: list[list] = []   # [payload, desc_bytes, addrs, vals, bh, sig, sig_cnt]
         pend_keys: set = set()
 
@@ -964,12 +981,13 @@ class SlotExecution:
                     addrs.append(a)
                     if a not in before:
                         before[a] = q(self.parent_xid, a)
-                    if a in known and a not in dirty:
+                    if a in known and a not in dirty and not shared:
                         vals.append(None)  # the session holds it current
                     else:
                         vals.append(q(self.xid, a) or b"")
                         known.add(a)
                         dirty.discard(a)
+                        self.session_refreshed += shared
             else:
                 for i in range(acct_cnt):
                     a = payload[acct_off + 32 * i : acct_off + 32 * (i + 1)]

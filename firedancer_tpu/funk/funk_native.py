@@ -5,8 +5,9 @@ record map (ISSUE 19): the exact `funk/funk.py` API — fork-tree
 prepare/publish/cancel with frozen/ancestry semantics, overlay queries,
 tombstones, FunkError codes -1/-2/-3 — but every record lives inside
 ONE shm segment that `native/fd_bank.cpp` writes into directly from its
-sweep crossing.  Reads come back through a zero-copy memoryview over
-the mapping; Python-lane batch writes cross the FFI once per batch
+sweep crossing.  Reads are copied out of the mapping inside the
+crossing (under the segment's lock, or its seqlock for a read-only
+handle); Python-lane batch writes cross the FFI once per batch
 (`rec_insert_batch` / `_root_merge`), and the seal path's whole
 before/after read-out is one `txn_diff` crossing.
 
@@ -23,7 +24,12 @@ funk.py is the contract (tests/test_funk_native.py).
 Because the map lives in shm under a public name (`shm_name`), an
 uninvolved process can `attach_readonly()` the same store and observe a
 seqlock-consistent view — the seed of the read-replica plane
-(docs/OPERATIONS.md "Native funk plane").
+(docs/OPERATIONS.md "Native funk plane") — and further processes can
+`attach()` it to WRITE: the bank tiles of a process topology share one
+store this way (models/leader_topo.build_bank), as upstream's share
+fd_funk in a workspace.  Writers serialise on a lock inside the segment
+(fd_funk.cpp "Concurrency"); a holder that died inside it fails every
+other writer's next call with `FunkLockError`, which names it.
 """
 
 from __future__ import annotations
@@ -50,6 +56,7 @@ _ERR_FULL = -4
 _ERR_OOM = -5
 _ERR_RDONLY = -6
 _ERR_RANGE = -7
+_ERR_LOCK = -8
 
 _XID_MAX = 128  # FFK_XID_MAX
 
@@ -72,12 +79,27 @@ def _load():
         i64p = ctypes.POINTER(ctypes.c_int64)
         lib.ffk_create.argtypes = [cp, u64, i32]
         lib.ffk_create.restype = vp
-        lib.ffk_attach.argtypes = [cp]
-        lib.ffk_attach.restype = vp
+        for name in ("ffk_attach", "ffk_attach_rw"):
+            getattr(lib, name).argtypes = [cp]
+            getattr(lib, name).restype = vp
+        lib.ffk_set_ready.argtypes = [vp]
+        for name in ("ffk_writers", "ffk_writer_id"):
+            getattr(lib, name).argtypes = [vp]
+            getattr(lib, name).restype = ctypes.c_uint32
+        lib.ffk_lock.argtypes = [vp]
+        lib.ffk_lock.restype = i32
+        lib.ffk_unlock.argtypes = [vp]
+        lib.ffk_lock_stats.argtypes = [vp, u64p]
+        lib.ffk_lock_failed_holder.argtypes = [vp]
+        lib.ffk_lock_failed_holder.restype = u64
+        lib.ffk_rec_read.argtypes = [vp, cp, i32, cp, i32, vp, i64, i64p]
+        lib.ffk_rec_read.restype = i32
+        lib.ffk_rec_read_slot.argtypes = [vp, i32, cp, i32, vp, i64, i64p]
+        lib.ffk_rec_read_slot.restype = i32
         lib.ffk_close.argtypes = [vp, i32]
         lib.ffk_shm_name.argtypes = [vp]
         lib.ffk_shm_name.restype = cp
-        for name in ("ffk_base", "ffk_map_sz", "ffk_seq", "ffk_arena_used"):
+        for name in ("ffk_seq", "ffk_arena_used"):
             getattr(lib, name).argtypes = [vp]
             getattr(lib, name).restype = u64
         lib.ffk_txn_prepare.argtypes = [vp, cp, i32, cp, i32]
@@ -98,8 +120,6 @@ def _load():
         lib.ffk_rec_insert_slot.restype = i32
         lib.ffk_rec_remove.argtypes = [vp, cp, i32, cp, i32]
         lib.ffk_rec_remove.restype = i32
-        lib.ffk_rec_query.argtypes = [vp, cp, i32, cp, i32, u64p, i64p]
-        lib.ffk_rec_query.restype = i32
         lib.ffk_rec_cnt_root.argtypes = [vp]
         lib.ffk_rec_cnt_root.restype = i64
         lib.ffk_root_keys.argtypes = [vp, cp, i64]
@@ -131,6 +151,19 @@ def available() -> bool:
         return False
 
 
+class FunkLockError(RuntimeError):
+    """The segment's lock will not come free: its holder died inside
+    it (or, for a read-only handle, a mutation has not ended within the
+    limit).  `writer` / `pid` name whoever took the lock last; the
+    store may be torn, and no handle writes it again."""
+
+    def __init__(self, what: str, holder: int):
+        self.writer, self.pid = holder & 0xFFFFFFFF, holder >> 32
+        super().__init__(
+            f"native funk {what}: the lock's holder, writer {self.writer} "
+            f"(pid {self.pid}), died inside it")
+
+
 def _raise(rc: int, what: str) -> None:
     if rc == ERR_TXN:
         raise FunkError(ERR_TXN, f"{what}: unknown/duplicate txn")
@@ -160,7 +193,7 @@ class _RecsProxy:
             self._f._h, self._slot, bytes(key), len(key), bytes(val),
             len(val))
         if rc != 0:
-            _raise(rc, "rec_insert")
+            self._f._fail(rc, "rec_insert")
 
     def update(self, items) -> None:
         for k, v in (items.items() if hasattr(items, "items") else items):
@@ -170,7 +203,7 @@ class _RecsProxy:
 class NativeFunk:
     """The funk API over the native shm record map.  One authoritative
     store for both lanes: the bank sweep writes records in C inside its
-    crossing; this class is the Python lane's batched-write + zero-copy
+    crossing; this class is the Python lane's batched-write + copied-
     read surface over the same segment."""
 
     def __init__(self, *, shm_name: str | None = None,
@@ -183,36 +216,92 @@ class NativeFunk:
         if not self._h:
             raise NativeUnavailable("ffk_create failed")
         self._owns = True
-        self._init_views()
-        # cached out-cells for rec_query (no per-call ctypes churn)
-        self._voff = ctypes.c_uint64(0)
-        self._vlen = ctypes.c_int64(0)
-        self._voff_ref = ctypes.byref(self._voff)
-        self._vlen_ref = ctypes.byref(self._vlen)
+        self._init_cells()
 
-    def _init_views(self) -> None:
-        base = int(self._lib.ffk_base(self._h))
-        sz = int(self._lib.ffk_map_sz(self._h))
-        self._map = memoryview(
-            (ctypes.c_uint8 * sz).from_address(base)).cast("B")
+    def _init_cells(self) -> None:
+        # rec_query's out-cell and copy (no per-call ctypes churn)
+        self._vlen = ctypes.c_int64(0)
+        self._vlen_ref = ctypes.byref(self._vlen)
+        self._val = ctypes.create_string_buffer(4096)
+
+    @classmethod
+    def _attached(cls, h, what: str) -> "NativeFunk":
+        self = cls.__new__(cls)
+        self._lib = _load()
+        self._h = h
+        if not h:
+            raise NativeUnavailable(f"{what} failed")
+        self._owns = False
+        self._init_cells()
+        return self
 
     @classmethod
     def attach_readonly(cls, shm_name: str) -> "NativeFunk":
         """Read-only attach from an uninvolved process (the metrics /
-        read-replica shape).  Mutating calls raise RuntimeError."""
+        read-replica shape).  Mutating calls raise RuntimeError;
+        `rec_query` reads under the seqlock, so it is whole also while
+        the store's writers are at work."""
+        return cls._attached(_load().ffk_attach(shm_name.encode()),
+                             f"ffk_attach({shm_name!r})")
+
+    @classmethod
+    def attach(cls, shm_name: str, timeout_s: float = 120.0) -> "NativeFunk":
+        """One more WRITER of the store another process created under
+        `shm_name`, once that process has said the store is whole
+        (`set_ready`): asks until then, `timeout_s` at most.  The fork
+        tree and the records are the creator's; this handle's writes
+        take the segment's lock like the creator's."""
+        import time
+
         lib = _load()
-        self = cls.__new__(cls)
-        self._lib = lib
-        self._h = lib.ffk_attach(shm_name.encode())
-        if not self._h:
-            raise NativeUnavailable(f"ffk_attach({shm_name!r}) failed")
-        self._owns = False
-        self._init_views()
-        self._voff = ctypes.c_uint64(0)
-        self._vlen = ctypes.c_int64(0)
-        self._voff_ref = ctypes.byref(self._voff)
-        self._vlen_ref = ctypes.byref(self._vlen)
-        return self
+        t_end = time.monotonic() + timeout_s
+        while not (h := lib.ffk_attach_rw(shm_name.encode())):
+            if time.monotonic() > t_end:
+                raise NativeUnavailable(
+                    f"ffk_attach_rw({shm_name!r}): no ready store in "
+                    f"{timeout_s} s")
+            time.sleep(0.01)
+        return cls._attached(h, f"ffk_attach_rw({shm_name!r})")
+
+    def set_ready(self) -> None:
+        """The creator's word that what joining writers expect (the
+        genesis, the fork) is in the store: `attach` waits for it."""
+        self._lib.ffk_set_ready(self._h)
+
+    def writers(self) -> int:
+        """Handles that may write the segment, the creator's included."""
+        return int(self._lib.ffk_writers(self._h))
+
+    @property
+    def writer_id(self) -> int:
+        """1 the creator, 2.. in order of `attach`, 0 read-only."""
+        return int(self._lib.ffk_writer_id(self._h))
+
+    def lock(self) -> None:
+        """The writers' lock around a group of calls (they nest)."""
+        rc = self._lib.ffk_lock(self._h)
+        if rc != 0:
+            self._fail(rc, "lock")
+
+    def unlock(self) -> None:
+        self._lib.ffk_unlock(self._h)
+
+    def lock_stats(self) -> dict[str, int]:
+        """This handle's use of the lock: acquisitions, those that found
+        it held, ns waited, and of the waits over 100 us their number
+        and the last one's ns and holder (writer id)."""
+        out = (ctypes.c_uint64 * 6)()
+        self._lib.ffk_lock_stats(self._h, out)
+        return {"acquires": int(out[0]), "contended": int(out[1]),
+                "wait_ns": int(out[2]), "long_waits": int(out[3]),
+                "long_ns": int(out[4]),
+                "long_holder": int(out[5]) & 0xFFFFFFFF}
+
+    def _fail(self, rc: int, what: str) -> None:
+        if rc == _ERR_LOCK:
+            raise FunkLockError(
+                what, int(self._lib.ffk_lock_failed_holder(self._h)))
+        _raise(rc, what)
 
     # -- identity / shm surface ----------------------------------------------
 
@@ -241,13 +330,13 @@ class NativeFunk:
             rc = self._lib.ffk_txn_prepare(self._h, bytes(parent),
                                            len(parent), bytes(xid), len(xid))
         if rc != 0:
-            _raise(rc, "txn_prepare")
+            self._fail(rc, "txn_prepare")
         return xid
 
     def txn_is_frozen(self, xid: bytes) -> bool:
         rc = self._lib.ffk_txn_is_frozen(self._h, bytes(xid), len(xid))
         if rc < 0:
-            _raise(rc, "txn_is_frozen")
+            self._fail(rc, "txn_is_frozen")
         return bool(rc)
 
     def txn_cnt(self) -> int:
@@ -258,12 +347,12 @@ class NativeFunk:
         need = int(lib.ffk_txn_ancestry(self._h, bytes(xid), len(xid),
                                         None, 0))
         if need < 0:
-            _raise(need, "txn_ancestry")
+            self._fail(need, "txn_ancestry")
         buf = ctypes.create_string_buffer(need or 1)
         n = int(lib.ffk_txn_ancestry(self._h, bytes(xid), len(xid), buf,
                                      need))
         if n < 0:
-            _raise(n, "txn_ancestry")
+            self._fail(n, "txn_ancestry")
         out, p = [], 0
         raw = buf.raw[:n]
         while p < n:
@@ -275,13 +364,13 @@ class NativeFunk:
     def txn_cancel(self, xid: bytes) -> int:
         rc = self._lib.ffk_txn_cancel(self._h, bytes(xid), len(xid))
         if rc < 0:
-            _raise(rc, "txn_cancel")
+            self._fail(rc, "txn_cancel")
         return int(rc)
 
     def txn_publish(self, xid: bytes) -> int:
         rc = self._lib.ffk_txn_publish(self._h, bytes(xid), len(xid))
         if rc < 0:
-            _raise(rc, "txn_publish")
+            self._fail(rc, "txn_publish")
         return int(rc)
 
     @property
@@ -303,12 +392,12 @@ class NativeFunk:
                                           bytes(key), len(key), bytes(val),
                                           len(val))
         if rc != 0:
-            _raise(rc, "rec_insert")
+            self._fail(rc, "rec_insert")
 
     def txn_recs_for_write(self, xid: bytes) -> _RecsProxy:
         slot = int(self._lib.ffk_txn_slot(self._h, bytes(xid), len(xid)))
         if slot < 0:
-            _raise(slot, "txn_recs_for_write")
+            self._fail(slot, "txn_recs_for_write")
         return _RecsProxy(self, slot)
 
     def rec_insert_batch(self, xid: bytes | None, items) -> None:
@@ -336,7 +425,7 @@ class NativeFunk:
             rc = self._lib.ffk_batch_apply(self._h, bytes(xid), len(xid),
                                            blob, len(blob), n)
         if rc != 0:
-            _raise(rc, "batch_apply")
+            self._fail(rc, "batch_apply")
 
     def rec_remove(self, xid: bytes | None, key: bytes) -> None:
         if xid is None:
@@ -346,40 +435,24 @@ class NativeFunk:
             rc = self._lib.ffk_rec_remove(self._h, bytes(xid), len(xid),
                                           bytes(key), len(key))
         if rc != 0:
-            _raise(rc, "rec_remove")
+            self._fail(rc, "rec_remove")
 
     def rec_query(self, xid: bytes | None, key: bytes) -> bytes | None:
-        rc = self._query(xid, key)
-        if rc == 0:
-            return None
-        off = self._voff.value
-        ln = self._vlen.value
-        return bytes(self._map[off: off + ln]) if ln > 0 else b""
-
-    def rec_query_view(self, xid: bytes | None,
-                       key: bytes) -> memoryview | None:
-        """Zero-copy read: a memoryview into the shm mapping.  Valid
-        until the record is overwritten/published — consume before the
-        next store mutation."""
-        rc = self._query(xid, key)
-        if rc == 0:
-            return None
-        off = self._voff.value
-        ln = self._vlen.value
-        return self._map[off: off + ln]
-
-    def _query(self, xid: bytes | None, key: bytes) -> int:
-        if xid is None:
-            rc = self._lib.ffk_rec_query(self._h, None, -1, bytes(key),
-                                         len(key), self._voff_ref,
-                                         self._vlen_ref)
-        else:
-            rc = self._lib.ffk_rec_query(self._h, bytes(xid), len(xid),
-                                         bytes(key), len(key),
-                                         self._voff_ref, self._vlen_ref)
+        """The value, copied out in the crossing: under the lock for a
+        handle that may write, under the seqlock for a read-only one —
+        whole also while other processes write the store."""
+        lib, h, vlen = self._lib, self._h, self._vlen
+        bx, xl = (None, -1) if xid is None else (bytes(xid), len(xid))
+        bk = bytes(key)
+        while True:
+            rc = lib.ffk_rec_read(h, bx, xl, bk, len(bk), self._val,
+                                  len(self._val), self._vlen_ref)
+            if rc != _ERR_RANGE or vlen.value <= len(self._val):
+                break
+            self._val = ctypes.create_string_buffer(int(vlen.value))
         if rc < 0:
-            _raise(rc, "rec_query")
-        return rc
+            self._fail(rc, "rec_query")
+        return self._val[:vlen.value] if rc else None
 
     def rec_cnt_root(self) -> int:
         return int(self._lib.ffk_rec_cnt_root(self._h))
@@ -404,11 +477,11 @@ class NativeFunk:
         bx = bytes(xid)
         need = int(lib.ffk_txn_diff(self._h, bx, len(bx), None, 0))
         if need < 0:
-            _raise(need, "txn_diff")
+            self._fail(need, "txn_diff")
         buf = ctypes.create_string_buffer(need or 1)
         n = int(lib.ffk_txn_diff(self._h, bx, len(bx), buf, need))
         if n < 0:
-            _raise(n, "txn_diff")
+            self._fail(n, "txn_diff")
         raw = buf.raw[:n]
         out = []
         p = 0
@@ -434,11 +507,11 @@ class NativeFunk:
         lib = self._lib
         need = int(lib.ffk_root_keys(self._h, None, 0))
         if need < 0:
-            _raise(need, "root_keys")
+            self._fail(need, "root_keys")
         buf = ctypes.create_string_buffer(need or 1)
         n = int(lib.ffk_root_keys(self._h, buf, need))
         if n < 0:
-            _raise(n, "root_keys")
+            self._fail(n, "root_keys")
         raw = buf.raw[:n]
         out, p = [], 0
         while p < n:
@@ -452,11 +525,11 @@ class NativeFunk:
         bx = bytes(xid)
         need = int(lib.ffk_txn_keys(self._h, bx, len(bx), None, 0))
         if need < 0:
-            _raise(need, "txn_keys")
+            self._fail(need, "txn_keys")
         buf = ctypes.create_string_buffer(need or 1)
         n = int(lib.ffk_txn_keys(self._h, bx, len(bx), buf, need))
         if n < 0:
-            _raise(n, "txn_keys")
+            self._fail(n, "txn_keys")
         raw = buf.raw[:n]
         out, p = [], 0
         while p < n:
@@ -481,7 +554,6 @@ class NativeFunk:
 
     def close(self) -> None:
         if getattr(self, "_h", None):
-            self._map = None
             self._lib.ffk_close(self._h, 1 if self._owns else 0)
             self._h = None
 

@@ -166,10 +166,25 @@ def _take_turns(verifies: list) -> None:
         v.burst = DEFAULT_BURST
 
 
+def block_limits_of(cfg):
+    """pack's block limits as the config states them: the stock ones
+    (None: pack/scheduler.BlockLimits' defaults), or with
+    [development.bench] larger_max_cost_per_block the bench profile's
+    block cost."""
+    if not cfg.development.bench.larger_max_cost_per_block:
+        return None
+    from firedancer_tpu.pack import cost
+    from firedancer_tpu.pack.scheduler import BlockLimits
+
+    return BlockLimits(max_cost_per_block=cost.LARGER_MAX_COST_PER_BLOCK)
+
+
 def build_leader_pipeline_from_config(cfg, **overrides) -> "LeaderPipeline":
     """Topology derived from a typed Config (utils/config.py) — the
     config_parse -> topos/fd_frankendancer.c split."""
     kw = dict(
+        block_limits=block_limits_of(cfg),
+        status_cache=not cfg.development.bench.disable_status_cache,
         n_verify=cfg.layout.verify_stage_count,
         n_bank=cfg.layout.bank_stage_count,
         batch=cfg.verify.batch,
@@ -189,6 +204,7 @@ def build_leader_pipeline_from_config(cfg, **overrides) -> "LeaderPipeline":
 
         kw["bank_ctx"] = genesis_bank_ctx(
             n_payers=kw.get("n_payers", 8),
+            with_status_cache=kw["status_cache"],
             **seeded_validators(n_voters=g.n_voters,
                                 n_slot_hashes=g.slot_hashes))
     return build_leader_pipeline(**kw)
@@ -218,8 +234,14 @@ def build_leader_pipeline(
     fuse_poh_shred: bool = False,
     udp_ingress: bool = False,
     n_payers: int = 8,
+    block_limits=None,
+    status_cache: bool = True,
 ) -> LeaderPipeline:
-    """verify_devices: chips behind each verify stage (1 = the default
+    """block_limits: pack's (pack/scheduler.BlockLimits; None: stock).
+    status_cache: whether the default bank ctx keeps one (a `bank_ctx`
+    the caller brings is the caller's).
+
+    verify_devices: chips behind each verify stage (1 = the default
     device; n > 1 = a mesh of the first n local devices, `batch` lanes
     over all of them).
 
@@ -318,6 +340,7 @@ def build_leader_pipeline(
             n_txn_ins=n_verify,
             clock=slot_clock,
             shed_keep=shed_keep,
+            limits=block_limits,
         )
     else:
         dedup = DedupStage(
@@ -333,11 +356,13 @@ def build_leader_pipeline(
             bank_cnt=n_bank,
             clock=slot_clock,
             shed_keep=shed_keep,
+            limits=block_limits,
         )
     # ONE live bank shared by every bank stage (the Frankendancer shape:
     # all bank tiles commit into the same Agave bank over the FFI)
     if bank_ctx is None:
-        bank_ctx = default_bank_ctx(slot=slot, n_payers=n_payers)
+        bank_ctx = default_bank_ctx(slot=slot, n_payers=n_payers,
+                                    with_status_cache=status_cache)
     banks = [
         BankStage(
             f"bank{b}",

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import hashlib
 
+from firedancer_tpu.pack.cost import MAX_BANK_TILES
 from firedancer_tpu.runtime import topo as ft
 from firedancer_tpu.tango import shm
 from firedancer_tpu.utils import log as fl
@@ -35,9 +36,11 @@ _log = fl.get_logger("leader_topo")
 LINK_DEPTHS = {"gv": 1024, "vd": 1024, "pb": 256, "bp": 256, "bd": 256,
                "ps": 1024, "ss": 4096}
 
-# what a `persist` topology's tiles make beside the links, named per
-# run (Topology.own): the bank tile's funk segment, the store's slots
-_FUNK_SHM = "fdtpu_funk_{uid}_bank0"
+# what a topology's tiles make beside the links, named per run
+# (Topology.own: the supervisor's to take away, whichever tile made it
+# and whether or not that tile lived): the bank tiles' one funk segment,
+# the store's slots
+_FUNK_SHM = "fdtpu_funk_{uid}"
 
 
 def _store_dir_template() -> str:
@@ -155,7 +158,8 @@ def build_dedup(links, cnc):
     )
 
 
-def build_pack(links, cnc, *, n_bank, slot_clock=None, shed_keep=None):
+def build_pack(links, cnc, *, n_bank, slot_clock=None, shed_keep=None,
+               limits=None):
     from firedancer_tpu.runtime.pack_stage import PackStage
 
     return PackStage(
@@ -171,11 +175,12 @@ def build_pack(links, cnc, *, n_bank, slot_clock=None, shed_keep=None):
         mb_deadline_s=0.0,
         clock=slot_clock,
         shed_keep=shed_keep,
+        limits=limits,
     )
 
 
 def build_pack_native(links, cnc, *, n_bank, txn_links, slot_clock=None,
-                      shed_keep=None, hold_when_full=False):
+                      shed_keep=None, hold_when_full=False, limits=None):
     """The fused native dedup+pack stage: consumes the verify output
     links directly (no dedup process) and runs native/fd_pack.cpp via
     one FFI crossing per burst.  The parent only wires this when
@@ -196,29 +201,36 @@ def build_pack_native(links, cnc, *, n_bank, txn_links, slot_clock=None,
         clock=slot_clock,
         shed_keep=shed_keep,
         hold_when_full=hold_when_full,
+        limits=limits,
     )
 
 
-# the bank tile's funk fork, by a name its supervisor knows too (the
+# the bank tiles' funk fork, by a name its supervisor knows too (the
 # account store is read back through NativeFunk.attach_readonly)
 BANK_FORK_XID = b"leader_topo:bank"
 
 
 def build_bank(links, cnc, *, bank_idx, slot=1, slot_clock=None, n_payers=8,
-               genesis=None, funk_shm=None):
-    # the bank process OWNS the live bank (its own funk + SlotExecution,
-    # default_bank_ctx): the process topology therefore runs n_bank=1 —
-    # multiple real-execution banks need the funk state shared, which the
-    # cooperative pipeline gets in-process (models/leader.py) and a
-    # multi-process topology would need a cross-process funk backend for
-    # (the reference shares fd_funk in a wksp across tiles the same way)
+               genesis=None, funk_shm=None, status_cache=True):
+    # every bank process has its own BankCtx (SlotExecution, native
+    # session) over ONE account store: with `funk_shm` (the run's name
+    # for the native funk's segment) bank tile 0 makes the store, funds
+    # the genesis and prepares the fork BANK_FORK_XID, and every other
+    # bank tile attaches to both as one more writer
+    # (genesis_bank_ctx(funk_attach=): it waits until tile 0 says the
+    # store is whole) — upstream shares fd_funk in a wksp across its
+    # bank tiles the same way.  Pack's account locks order two tiles
+    # that touch one account; the sweep lane reads through the segment
+    # what the other left (native/fd_bank.cpp).
     # genesis: default_bank_ctx's arguments where the caller has them
     # (a traffic shape's payers), else the generator's n_payers.
-    # funk_shm: the run's name for the account store's segment.
+    # status_cache: False = [development.bench] disable_status_cache.
     from firedancer_tpu.runtime.bank import BankStage, default_bank_ctx
 
     if genesis is None:
         genesis = {"slot": slot, "n_payers": n_payers}
+    if not status_cache:
+        genesis = dict(genesis, with_status_cache=False)
     stage = BankStage(
         f"bank{bank_idx}",
         ins=[shm.make_consumer(links[f"pb{bank_idx}"], lazy=8)],
@@ -229,7 +241,8 @@ def build_bank(links, cnc, *, bank_idx, slot=1, slot_clock=None, n_payers=8,
         cnc=cnc,
         bank_idx=bank_idx,
         ctx=default_bank_ctx(**genesis, funk_shm=funk_shm,
-                             fork_xid=BANK_FORK_XID),
+                             fork_xid=BANK_FORK_XID,
+                             funk_attach=bank_idx > 0),
         clock=slot_clock,
     )
     stage.require_credit = True
@@ -339,6 +352,8 @@ def build_leader_topology(
     hold_when_full: bool = False,
     persist: bool = False,
     shred_batch_target_sz: int = 4096,
+    block_limits=None,
+    status_cache: bool = True,
 ) -> ft.Topology:
     """n_payers: the generator's funded payer set, known to benchg and
     to the bank's genesis alike (models/leader.build_leader_pipeline).
@@ -353,9 +368,19 @@ def build_leader_topology(
     persist: the tiles keep what a supervisor reads after the drain
     where it can reach it — the store tile writes each stored slot
     under the run's directory (`store_dir(handle)`,
-    runtime/store.StoredSlots), and the bank tile's funk segment has
+    runtime/store.StoredSlots), and the bank tiles' funk segment has
     the run's name (`bank_funk_shm(handle)`, NativeFunk
     .attach_readonly, fork BANK_FORK_XID); close() removes both.
+    n_bank > 1: B bank processes over that ONE segment (`build_bank`),
+    which then has the run's name whether or not `persist` — so it
+    takes the native funk, and `status_cache=False`: a status cache a
+    process would let a repeat that outlives pack's tags land once a
+    tile, and none lives in the segment yet.
+    block_limits: pack's (pack/scheduler.BlockLimits; None: stock), in
+    whichever pack lane runs.
+    status_cache: False = the bank tiles keep none
+    ([development.bench] disable_status_cache): exactly-once rests on
+    the verify tile's, dedup's and pack's signature tags.
 
     verify_cpu: the verify child runs its kernel on the CPU backend
     instead of owning the chip (tests, chip-less boxes) — the CPU is what
@@ -403,15 +428,24 @@ def build_leader_topology(
     if slot_clock is not None:
         slot_clock = slot_clock.anchored(boot_grace_s)
 
-    if n_bank != 1:
-        # each bank process owns its own funk: two real-execution banks
-        # in separate processes would commit into divergent state
-        # machines (see build_bank) — refuse rather than diverge
-        raise ValueError(
-            "process topology supports exactly one bank stage until funk "
-            "has a cross-process backend; the cooperative pipeline "
-            "(models/leader.py) runs any bank count over the shared ctx"
-        )
+    if not 1 <= n_bank <= MAX_BANK_TILES:
+        raise ValueError(f"bank_stage_count {n_bank}: pack schedules to 1.."
+                         f"{MAX_BANK_TILES} banks (pack/cost.MAX_BANK_TILES)")
+    if n_bank > 1:
+        from firedancer_tpu.funk import funk_native
+
+        if not funk_native.available():
+            raise ValueError(
+                f"bank_stage_count {n_bank} in processes needs the native "
+                f"funk (one shm segment every bank tile writes): "
+                f"native/fd_funk.so did not build or FDTPU_NATIVE_FUNK=0, "
+                f"and the Python funk lives in one process")
+        if status_cache:
+            raise ValueError(
+                f"bank_stage_count {n_bank} in processes needs "
+                f"[development.bench] disable_status_cache: a status cache "
+                f"a bank process would let a repeat that outlives pack's "
+                f"tags land once a tile")
 
     use_native_pack = resolve_native_pack(native_pack)
     d = dict(LINK_DEPTHS, **(depths or {}))
@@ -427,7 +461,7 @@ def build_leader_topology(
     if not fuse_poh_shred:
         topo.link("ps", depth=d["ps"], mtu=65536)
     topo.link("ss", depth=d["ss"], mtu=1232)
-    funk_shm = topo.own(_FUNK_SHM) if persist else None
+    funk_shm = topo.own(_FUNK_SHM) if persist or n_bank > 1 else None
     persist_dir = topo.own(_store_dir_template()) if persist else None
 
     secret = hashlib.sha256(leader_seed).digest()
@@ -454,22 +488,24 @@ def build_leader_topology(
         topo.stage("pack", build_pack_native, n_bank=n_bank,
                    txn_links=["vd"], sandbox=sb,
                    slot_clock=slot_clock, shed_keep=shed_keep,
-                   hold_when_full=hold_when_full,
+                   hold_when_full=hold_when_full, limits=block_limits,
                    ins=["vd"] + [f"bd{b}" for b in range(n_bank)],
                    outs=[f"pb{b}" for b in range(n_bank)],
-                   schema=PackStage.metrics_schema())
+                   schema=PackStage.metrics_schema_n(n_bank))
     else:
         topo.stage("dedup", build_dedup, sandbox=sb, ins=["vd"], outs=["dp"],
                    schema=DedupStage.metrics_schema())
         topo.stage("pack", build_pack, n_bank=n_bank, sandbox=sb,
                    slot_clock=slot_clock, shed_keep=shed_keep,
+                   limits=block_limits,
                    ins=["dp"] + [f"bd{b}" for b in range(n_bank)],
                    outs=[f"pb{b}" for b in range(n_bank)],
-                   schema=PackStage.metrics_schema())
+                   schema=PackStage.metrics_schema_n(n_bank))
     for b in range(n_bank):
         topo.stage(f"bank{b}", build_bank, bank_idx=b, slot=slot, sandbox=sb,
                    slot_clock=slot_clock, n_payers=n_payers,
                    genesis=genesis, funk_shm=funk_shm,
+                   status_cache=status_cache,
                    ins=[f"pb{b}"], outs=[f"bp{b}", f"bd{b}"],
                    credit_gated=True, schema=BankStage.metrics_schema())
     if fuse_poh_shred:
@@ -507,13 +543,19 @@ def build_leader_topology_from_config(cfg, *, genesis: dict | None = None,
     ([links]; the generator's ring is verify.receive_buffer_depth),
     pack's full-pool rule, the shredder's batch target, and the slot
     cadence (poh.slot_ms; `slot_clock`, a SlotClockCfg, overrides it).
-    `genesis`: the bank tile's (`default_bank_ctx`'s arguments).  The
+    `genesis`: the bank tiles' (`default_bank_ctx`'s arguments).  The
     tiles persist what they hold (`build_leader_topology`'s `persist`).
+    [development.bench]: larger_max_cost_per_block is pack's block cost
+    limit (models/leader.block_limits_of), disable_status_cache the
+    bank tiles' status cache.
 
-    One bank tile: layout.bank_stage_count has to be 1 (funk has one
-    writer; `build_leader_topology` refuses another count).  A tile
+    layout.bank_stage_count = B bank tiles, each a process, over one
+    funk segment (`build_bank`); B > 1 takes disable_status_cache and
+    the native funk (`build_leader_topology` refuses by name).  A tile
     that dies takes the topology down: restart under load is not part
     of this deployment yet."""
+    from firedancer_tpu.models.leader import block_limits_of
+
     if slot_clock is None and cfg.poh.slot_ms > 0:
         from firedancer_tpu.runtime.slot_clock import SlotClockCfg
 
@@ -534,6 +576,8 @@ def build_leader_topology_from_config(cfg, *, genesis: dict | None = None,
         genesis=genesis,
         slot_clock=slot_clock,
         persist=True,
+        block_limits=block_limits_of(cfg),
+        status_cache=not cfg.development.bench.disable_status_cache,
     )
     kw.update(overrides)
     return build_leader_topology(**kw)
@@ -641,7 +685,8 @@ def store_dir(handle) -> str:
 
 
 def bank_funk_shm(handle) -> str:
-    """The shm name of a `persist` topology's bank tile's funk."""
+    """The shm name of the bank tiles' funk (a `persist` topology's, or
+    one with more than one bank tile)."""
     return _FUNK_SHM.format(uid=handle.uid)
 
 

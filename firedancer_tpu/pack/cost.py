@@ -37,6 +37,9 @@ MICRO_LAMPORTS_PER_LAMPORT = 1_000_000
 FEE_PER_SIGNATURE = 5000  # lamports (FD_PACK_FEE_PER_SIGNATURE)
 
 MAX_COST_PER_BLOCK = 48_000_000
+# [development.bench] larger_max_cost_per_block (the pack tile's
+# LARGER_MAX_COST_PER_BLOCK, as recalled: /root/reference is not mounted)
+LARGER_MAX_COST_PER_BLOCK = 18 * MAX_COST_PER_BLOCK
 MAX_VOTE_COST_PER_BLOCK = 36_000_000
 MAX_WRITE_COST_PER_ACCT = 12_000_000
 MAX_DATA_PER_BLOCK = ((32 * 1024 - 17) // 31) * 25871 + 48

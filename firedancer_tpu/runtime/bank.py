@@ -22,9 +22,14 @@ exactly the txns with an on-chain footprint, so a replayer
 entries alone.  Executed-but-failed txns landed (fee charged) and stay.
 
 Process-runner note: the topo runner spawns each stage in its own
-interpreter, so there the bank count must be 1 (one process owns the
-bank) until funk grows a cross-process shm backend; the cooperative
-scheduler runs any bank count against the shared ctx.
+interpreter, so there the shared bank is the native funk's shm segment:
+bank tile 0 makes the store and its fork, every other bank tile
+attaches to both as one more writer (`genesis_bank_ctx(funk_attach=)`,
+NativeFunk.attach), and each tile's native session takes every account
+a microblock names from the segment, where another tile may have
+written it since (native/fd_bank.cpp's read-through;
+`session_refreshed`).  The
+cooperative scheduler runs any bank count against the shared ctx.
 
 Inputs:  ins[0] = pack->bank microblocks.
 Outputs: outs[0] = bank->poh executed microblocks; outs[1] = done->pack.
@@ -100,6 +105,7 @@ class BankCtx:
         executor=None,
         slot_hashes: list[tuple[int, bytes]] | None = None,
         fork_xid: bytes | None = None,
+        join_fork: bool = False,
     ):
         from firedancer_tpu.funk import make_funk
 
@@ -117,8 +123,10 @@ class BankCtx:
         # default_sysvars' empty one, under which every vote rejects)
         self._slot_hashes = slot_hashes
         # the slot's funk fork under a name the caller chose (None: the
-        # execution's own), for a reader in another process
+        # execution's own), for a reader in another process; join_fork:
+        # the fork is there, another process's (SlotExecution `join`)
         self._fork_xid = fork_xid
+        self._join_fork = join_fork
         self._sx = None
         # force the native executor .so build/load NOW (one g++ shell-out
         # on cold hosts), not inside the first microblock's after_frag —
@@ -159,6 +167,7 @@ class BankCtx:
                 status_cache=self.status_cache,
                 slot_hashes=self._slot_hashes,
                 xid=self._fork_xid,
+                join=self._join_fork,
             )
         return self._sx
 
@@ -196,6 +205,7 @@ def genesis_bank_ctx(
     preload=(),
     funk_shm: str | None = None,
     fork_xid: bytes | None = None,
+    funk_attach: bool = False,
 ) -> BankCtx:
     """The bank a leader enters its slot with, made from a seed.
 
@@ -205,6 +215,13 @@ def genesis_bank_ctx(
     unlink it if the tile dies).  None: a name of the funk's own.
     fork_xid: the slot's funk fork under this name (BankCtx), which is
     what such a reader asks the store for.
+    funk_attach: the store `funk_shm` and its fork `fork_xid` are
+    another bank tile's, which made them from the same arguments: this
+    ctx attaches to both as one more writer and funds nothing.  The
+    tile that makes the store says it is whole (`set_ready`) once the
+    genesis is in and the fork prepared; the attach waits for that.
+    Without a status cache only: one a process would let a repeat land
+    once a tile.
 
     Payers: the synthetic load's `n_payers` keypairs off `seed`
     (runtime/benchg.pool_payers), or the explicit `payers` pubkeys in
@@ -231,6 +248,18 @@ def genesis_bank_ctx(
     if slot_hashes is not None:
         slot_hashes = list(slot_hashes)
     funk = None
+    if funk_attach:
+        from firedancer_tpu.funk.funk_native import NativeFunk
+
+        if with_status_cache:
+            raise ValueError("a bank that attaches to another's store has "
+                             "no status cache (with_status_cache=False)")
+        ctx = BankCtx(NativeFunk.attach(funk_shm), slot=slot,
+                      slot_hashes=slot_hashes, fork_xid=fork_xid,
+                      join_fork=True)
+        if preload:
+            ctx.preload(preload)
+        return ctx
     if funk_shm is not None:
         from firedancer_tpu.funk import funk_native
 
@@ -277,6 +306,11 @@ def genesis_bank_ctx(
                     1, blobs[name], owner=SYSVAR_OWNER))
     if preload:
         ctx.preload(preload)
+    if funk is not None:
+        # a store under a run's name: another bank tile may be waiting
+        # to attach (funk_attach), and finds the genesis and the fork
+        ctx.sx
+        funk.set_ready()
     return ctx
 
 
@@ -350,6 +384,19 @@ class BankStage(Stage):
                      " map in-crossing")
             .counter("bank_funk_falls",
                      "groups that fell back to full-value logging")
+            .counter("session_refreshed",
+                     "account values this tile's session took from the"
+                     " store's segment: every account a microblock names"
+                     " where other bank tiles write the store too (0 where"
+                     " this tile is its one writer)")
+            # this tile's use of the account store's lock (fd_funk.cpp;
+            # NativeFunk.lock_stats), copied in during_housekeeping
+            .counter("funk_lock_acquires",
+                     "holds of the store's lock (the sweep: two a"
+                     " microblock, its reads and its writes)")
+            .counter("funk_lock_contended",
+                     "of those, the ones that found it held")
+            .counter("funk_lock_wait_ns", "ns spent waiting for it")
             # native-owned (ISSUE 20): fdb_frag_cb observes each
             # committed txn's commit latency in-crossing — the Python
             # facade never touches this histogram
@@ -388,6 +435,7 @@ class BankStage(Stage):
         # out producers are native — the sweep harness (stage.py) then
         # routes whole credit windows through fdb_frag_cb
         self._armed_ctx = None
+        self._lock_long_seen = 0
         self._arm_native()
 
     def native_lanes(self) -> dict[str, bool]:
@@ -455,9 +503,28 @@ class BankStage(Stage):
 
     def during_housekeeping(self) -> None:
         c = self._sweep_client
+        counters = c.counters() if c is not None else {}
+        sx = self.ctx._sx
+        if sx is not None and sx.session_refreshed:
+            # the Python lane's share of the read-through (a microblock
+            # the sweep punted), beside the sweep's own
+            counters["session_refreshed"] = sx.session_refreshed \
+                + counters.get("session_refreshed", 0)
+        self.metrics.counters.update(counters)
         if c is not None:
-            self.metrics.counters.update(c.counters())
             self._copy_sweep_counters()
+        fk = self.ctx.funk
+        if hasattr(fk, "lock_stats"):
+            st = fk.lock_stats()
+            self.metrics.counters.update(
+                funk_lock_acquires=st["acquires"],
+                funk_lock_contended=st["contended"],
+                funk_lock_wait_ns=st["wait_ns"])
+            if st["long_waits"] != self._lock_long_seen:
+                # the last wait over 100 us since the last look
+                self._lock_long_seen = st["long_waits"]
+                self.trace(fm.EV_FUNK_LOCK_WAIT, fm.funk_lock_wait_arg(
+                    st["long_holder"], st["long_ns"]))
 
     def flush(self) -> None:
         """Settle any pending stash (end-of-run: the harness stops
@@ -646,7 +713,8 @@ class BankStage(Stage):
             items.append((frag[:psz], None, frag[psz:-2]))
         # native-lane attribution: bracket the batch with the shared
         # SlotExecution's counters (safe: bank stages sharing a ctx run
-        # cooperatively in one thread; the process topology runs one bank)
+        # cooperatively in one thread; a process topology's bank tiles
+        # have a ctx each)
         sx = self.ctx.sx
         nd0, np0 = sx.native_done_cnt, sx.native_punt_cnt
         results = self.ctx.execute_batch(items)

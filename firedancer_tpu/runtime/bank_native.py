@@ -60,6 +60,7 @@ def _load():
         lib.fdb_stage_set_hdr.restype = ctypes.c_int
         lib.fdb_stage_set_funk.argtypes = [vp, vp, vp, vp, cp, u64]
         lib.fdb_stage_set_funk.restype = ctypes.c_int
+        lib.fdb_stage_set_funk_shared.argtypes = [vp, vp, vp, vp, vp]
         lib.fdb_stage_set_metrics.argtypes = [vp, vp]
         lib.fdb_log_ptr.argtypes = [vp]
         lib.fdb_log_ptr.restype = vp
@@ -103,7 +104,7 @@ def make_hdr(batch_ctx, *, gated: bool) -> bytes:
 # view can never drift from the struct layout
 _COUNTERS = ("bank_mb_seen", "bank_mb_native", "bank_mb_stashed",
              "bank_txn_native", "bank_credit_waits", "bank_mb_dropped",
-             "bank_funk_writes", "bank_funk_falls")
+             "bank_funk_writes", "bank_funk_falls", "session_refreshed")
 
 _GROUP_HEAD = struct.Struct("<QQQIBI")
 _REC_HEAD = struct.Struct("<bQB")  # status | fee | n_writes
@@ -211,10 +212,15 @@ class StageClient:
         """Arm (or disarm: funk/xid None) the native funk plane: the C
         side writes committed records slot-direct into `funk`'s shm map
         and strips write payloads from the result log.  Called alongside
-        set_hdr at every slot roll — the xid is the slot's funk fork."""
+        set_hdr at every slot roll — the xid is the slot's funk fork.
+        With it go the segment's lock (one hold a microblock's records)
+        and, for a store that other processes write too, the
+        read-through of every account a microblock names (fd_bank.cpp)."""
         if funk is None or xid is None:
             rc = self._lib.fdb_stage_set_funk(self._h, None, None, None,
                                               None, 0)
+            self._lib.fdb_stage_set_funk_shared(self._h, None, None, None,
+                                                None)
         else:
             from firedancer_tpu.funk import funk_native as fk
 
@@ -225,6 +231,10 @@ class StageClient:
                 ctypes.cast(flib.ffk_rec_insert_slot, ctypes.c_void_p),
                 xid, len(xid),
             )
+            self._lib.fdb_stage_set_funk_shared(
+                self._h, *(ctypes.cast(getattr(flib, name), ctypes.c_void_p)
+                           for name in ("ffk_lock", "ffk_unlock",
+                                        "ffk_writers", "ffk_rec_read_slot")))
         if rc == 0:
             raise NativeUnavailable("fdb_stage_set_funk failed")
 

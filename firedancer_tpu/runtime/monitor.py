@@ -327,6 +327,7 @@ class MonitorSession:
             row["intake"] = fm.intake_row(j.registry)
             row["mesh"] = fm.mesh_row(j.registry)
             row["votes"] = fm.vote_row(j.registry)
+            row["funk"] = fm.funk_row(j.registry)
             row["dedup"] = fm.dedup_row(j.registry)
             row["front"] = fm.front_row(j.registry)
             out.append(row)
@@ -483,6 +484,12 @@ class MonitorSession:
                 # peer's credit held it (cumulative)
                 lines.append(f"{r['stage']}: front " + " ".join(
                     f"{k}={v:,}" for k, v in front.items()))
+        # the bank tiles' one account store: the lock they meet at,
+        # what each took from the segment after another tile's write,
+        # and what pack gave each (cumulative)
+        funk = fm.format_funk({r["stage"]: r.get("funk") for r in rows})
+        if funk:
+            lines.append(funk)
         return "\n".join(lines)
 
     def run(self, *, interval_s: float = 1.0, iterations: int | None = None,

@@ -100,7 +100,25 @@ class PackStage(Stage):
             .counter("txn_shed",
                      "pending txns shed by the deadline load-shedding"
                      " degraded mode (lowest-priority first, never votes)")
+            .counter("bank_idle_polls",
+                     "times a scheduling pass left a bank without a"
+                     " microblock though it was idle and its ring had"
+                     " room: nothing pending, or every pending txn"
+                     " touches an account another bank's microblock"
+                     " holds")
         )
+
+    @classmethod
+    def metrics_schema_n(cls, bank_cnt: int) -> fm.MetricsSchema:
+        """The class schema + a counter a bank of the microblocks
+        scheduled to it (`mb_scheduled_b{i}`): what a pack stage in
+        front of `bank_cnt` banks publishes, and what a process
+        topology sizes its shm segment from."""
+        s = cls.metrics_schema()
+        for b in range(bank_cnt):
+            s.counter(f"mb_scheduled_b{b}",
+                      f"microblocks scheduled to bank {b}")
+        return s
 
     def __init__(
         self,
@@ -116,9 +134,12 @@ class PackStage(Stage):
         close_frac: float = 0.25,
         shed_keep: int | None = None,
         hold_when_full: bool = False,
+        limits=None,
         **kwargs,
     ):
         super().__init__(*args, **kwargs)
+        self._use_schema(self.metrics_schema_n(bank_cnt))
+        self._mb_counter = [f"mb_scheduled_b{b}" for b in range(bank_cnt)]
         # a pool without room for one more burst leaves the txn inputs
         # unpolled (backpressure through the ring in front, up to the
         # source) instead of evicting its cheapest transaction for the
@@ -134,6 +155,9 @@ class PackStage(Stage):
             bank_cnt=bank_cnt,
             depth=depth,
             max_txn_per_microblock=max_txn_per_microblock,
+            # the block's limits (pack/scheduler.BlockLimits; None: the
+            # stock ones), the same in both lanes
+            limits=limits,
         )
         self.min_pending = min_pending
         self.mb_deadline_s = mb_deadline_s
@@ -162,6 +186,7 @@ class PackStage(Stage):
         self._close_ns = 0
         self._shed_keep = shed_keep
         self._deadline_near = False
+        self._block_closing = False     # a boundary passed, banks busy
         if self._clock is not None:
             self._clock_slot = self._clock.cfg.slot0
             self._close_ns = int(self._clock.slot_ns * close_frac)
@@ -270,7 +295,12 @@ class PackStage(Stage):
             if self.outs[bank].cr_avail <= 0:
                 continue
             if not self._try_emit(bank):
-                break  # nothing schedulable right now (conflicts/empty)
+                # nothing schedulable right now (conflicts/empty): this
+                # bank and every idle one behind it go without
+                self.metrics.inc("bank_idle_polls", sum(
+                    not self._bank_busy[b] and self.outs[b].cr_avail > 0
+                    for b in range(bank, self.bank_cnt)))
+                break
         if self._pending_cnt() == 0:
             self._first_pending_at = None
 
@@ -284,7 +314,16 @@ class PackStage(Stage):
         close the block at each slot boundary — in-flight microblocks
         finish via the normal done-feedback, the unscheduled tail stays
         pooled for the next slot — and arm the deadline-close /
-        load-shed posture for the slot's final stretch."""
+        load-shed posture for the slot's final stretch.
+
+        The pool's `end_block` gives every bank's account locks back,
+        so it waits for the microblocks in flight (`_block_closing`:
+        nothing is scheduled meanwhile, one microblock's time at most):
+        a lock given back under a microblock a bank is still executing
+        would let pack hand the same account to another bank, and bank
+        tiles in processes of their own would then both start from its
+        old value.  (Upstream's pack tile drains its banks before
+        fd_pack_end_block the same way.)"""
         clock = self._clock
         slot = clock.slot_at(now)
         last = clock.last_slot()
@@ -297,10 +336,13 @@ class PackStage(Stage):
             slot = min(slot, last + 1)
         if slot > self._clock_slot:
             self._loop_worked = True    # a block closed
-            self.pack.end_block()
+            self._block_closing = True
             self.metrics.inc("blocks_closed", slot - self._clock_slot)
             self.trace(fm.EV_SLOT_ROLL, slot)
             self._clock_slot = slot
+        if self._block_closing and not any(self._bank_busy):
+            self.pack.end_block()
+            self._block_closing = False
         self._deadline_near = clock.remaining_ns(slot, now) <= self._close_ns
         if self._deadline_near and self._shed_keep is not None:
             excess = self._pending_cnt() - self._shed_keep
@@ -322,7 +364,7 @@ class PackStage(Stage):
 
     def _ready_to_schedule(self) -> bool:
         n = self._pending_cnt()
-        if n == 0:
+        if n == 0 or self._block_closing:
             return False
         if self.force_flush or n >= self.min_pending:
             return True
@@ -370,6 +412,7 @@ class PackStage(Stage):
         self.publish(bank, frame, sig=self._mb_seq, tsorig=tsorig)
         self._bank_busy[bank] = True
         self.metrics.inc("microblocks")
+        self.metrics.inc(self._mb_counter[bank])
         self.metrics.inc("txn_scheduled", txn_cnt)
         self.metrics.inc("cu_consumed", cu)
         self.metrics.observe("mb_fill", txn_cnt)

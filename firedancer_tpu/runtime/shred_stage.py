@@ -12,7 +12,11 @@ Outputs: outs[0] = wire shreds (mtu >= 1228).
 
 Entry batches close when the accumulated serialized entries reach
 `batch_target_sz` (the reference bounds batches by pending shred budget)
-or on flush at slot end.
+or on flush at slot end.  A close that finds the out ring short of
+credits waits (`pending_flush`), and so does the tail of a burst the
+ring could not take whole; the stage takes no further entry until both
+are out (`before_credit`), so a slow consumer backpressures the stage
+and everything in front of it, and no shred is dropped.
 
 Under the slot clock the stage follows poh's slot (poh_stage.poh_sig on
 every entry frag): the slot's last tick finishes the block (flush with
@@ -174,11 +178,25 @@ class ShredStage(Stage):
                 and self._room():
             self._shred_batch(block_complete=self._pending_bc)
 
+    def before_credit(self) -> None:
+        # a closed batch, or a burst's tail, that waits for the out
+        # ring's credits holds the intake: the ring in front fills and
+        # poh, the banks and pack wait with it (a store tile that cannot
+        # keep up slows the leader down; it does not lose shreds)
+        c = self._sweep_client
+        if c is not None:
+            waiting = c.pending_flush
+        else:
+            waiting = (len(self._buf) >= self.batch_target_sz
+                       or self._pending_bc) and not self._room()
+        self.intake_room = 0 if waiting else None
+
     def after_credit(self) -> None:
         c = self._sweep_client
         if c is not None:
-            # batch deferred for credits in C: retry with the flag the
-            # deferred flush recorded (block_complete survives the wait)
+            # batch deferred for credits in C, or a burst's tail the
+            # ring had no room for: retry with the flag the deferred
+            # flush recorded (block_complete survives the wait)
             if c.pending_flush:
                 self._loop_worked = True    # publishes in C
                 c.retry_flush()

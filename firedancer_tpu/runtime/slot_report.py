@@ -174,6 +174,15 @@ def _stage_block(mets: dict, records: list) -> dict:
     votes = fm.vote_row(mets)
     if votes:
         block["votes"] = votes
+    # a bank tile's use of the account store it shares with the others
+    # (its lock, what its session took from the segment after another
+    # tile's write, and each wait over 100 us with the holder's writer
+    # id: bank tile i is writer i + 1); pack: microblocks a bank
+    funk = fm.funk_row(mets)
+    if funk:
+        waits = [{"ts": ts, **fm.funk_lock_wait_fields(arg)}
+                 for ts, ev, arg in records if ev == fm.EV_FUNK_LOCK_WAIT]
+        block["funk"] = dict(funk, long_waits=waits) if waits else funk
     # the dedup stage: transactions its tag cache dropped and the
     # signatures they carried
     dedup = fm.dedup_row(mets)
@@ -191,17 +200,24 @@ def _stage_block(mets: dict, records: list) -> dict:
 def _funk_pseudo_stage(dump_stages: dict) -> dict | None:
     """Derive the `funk` stage block: funk apply runs inside the bank
     crossing (native shm storage plane), so its profile is the bank
-    shards' merged apply-phase histogram + funk counters."""
+    shards' merged apply-phase histogram + funk counters — with more
+    than one bank tile over the one store, every tile's: the lock they
+    meet at and what each took from the segment after another's write
+    (fm.FUNK_COUNTERS, summed; `bank_tiles` says over how many)."""
     apply_h = None
-    writes = falls = 0
+    writes = falls = n_banks = 0
+    shared = dict.fromkeys(fm.FUNK_COUNTERS, 0)
     found = False
     for name, st in dump_stages.items():
         mets = st.get("metrics") or {}
         if "bank_funk_writes" not in mets:
             continue
         found = True
+        n_banks += 1
         writes += int(mets.get("bank_funk_writes", 0) or 0)
         falls += int(mets.get("bank_funk_falls", 0) or 0)
+        for k in shared:
+            shared[k] += int(mets.get(k, 0) or 0)
         h = mets.get("nsweep_apply_ns")
         if _is_hist(h):
             apply_h = _hmerge(apply_h, h)
@@ -219,7 +235,8 @@ def _funk_pseudo_stage(dump_stages: dict) -> dict | None:
         "e2e": dict(empty),
         "nsweep_lat": dict(empty),
         "native": {"frags": 0, "crossings": 0},
-        "counters": {"bank_funk_writes": writes, "bank_funk_falls": falls},
+        "counters": {"bank_funk_writes": writes, "bank_funk_falls": falls,
+                     "bank_tiles": n_banks, **shared},
         "flight": {"nsweep_drain": 0, "nsweep_publish": 0,
                    "last_drain_ts": None, "last_publish_ts": None},
     }

@@ -356,6 +356,14 @@ class Stage:
             if set_metrics is not None:
                 set_metrics(None)  # C drops its raw pointer too
 
+    def _use_schema(self, schema: fm.MetricsSchema) -> None:
+        """Swap the stage's metrics for ones over `schema` (the class
+        schema plus counters whose number an instance decides: a chip,
+        a bank), keeping what was counted so far."""
+        kept = self.metrics.counters
+        self.metrics = type(self.metrics)(schema)
+        self.metrics.counters.update(kept)
+
     def native_lanes(self) -> dict[str, bool]:
         """lane -> armed, of the native lanes this stage would run on:
         here the rings (every consumer and producer the native ring
@@ -737,6 +745,11 @@ class Stage:
         bookkeeping only: frags_in and the batched frag_latency_ns
         observation off the returned meta table."""
         max_frags = self.burst if self.burst > 0 else 1
+        room = self.intake_room
+        if room is not None and room < max_frags:
+            max_frags = room
+        if max_frags <= 0:
+            return False
         m = self.metrics
         n, self._in_rr, d_ovr = drainer.sweep(self._in_rr, max_frags)
         self._note_sweep(n, max_frags)

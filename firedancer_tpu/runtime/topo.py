@@ -751,6 +751,7 @@ class TopologyHandle:
             reg = self.met_views.get(name, (None, None))[0]
             row.update(fm.latency_row(reg))
             row["loop"] = fm.loop_row([reg])
+            row["funk"] = fm.funk_row(reg)
             out.append(row)
         return out
 
@@ -779,6 +780,9 @@ class TopologyHandle:
                 f"{fm.format_latency_ms(r.get('lat_p50_ms')):>10}"
                 f"{fm.format_latency_ms(r.get('lat_p99_ms')):>10}"
             )
+        funk = fm.format_funk({r["stage"]: r["funk"] for r in rows})
+        if funk:    # the bank tiles' one account store
+            lines.append(funk)
         return "\n".join(lines)
 
 

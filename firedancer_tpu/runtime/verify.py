@@ -730,9 +730,7 @@ class VerifyStage(Stage):
     def _use_shard_schema(self, n_shards: int) -> None:
         """Swap the stage's metrics for ones over metrics_schema_n,
         keeping what was counted so far; the shard counters start at 0."""
-        kept = self.metrics.counters
-        self.metrics = type(self.metrics)(self.metrics_schema_n(n_shards))
-        self.metrics.counters.update(kept)
+        self._use_schema(self.metrics_schema_n(n_shards))
         for i in range(n_shards):
             self.metrics.counters.setdefault(f"shard_elems_s{i}", 0)
 

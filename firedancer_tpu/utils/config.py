@@ -128,6 +128,28 @@ class GenesisConfig:
 
 
 @dataclass
+class BenchConfig:
+    # upstream's [development.bench] keys of its committed bench profile
+    # (src/app/fdctl/config/bench-zen3-32core.toml), under its names
+    # and off by default.  larger_max_cost_per_block: pack closes a
+    # block at 18 x the stock 48M cost units (pack/cost.py
+    # LARGER_MAX_COST_PER_BLOCK) — blocks no other validator would
+    # accept, for measuring the tiles rather than the limit.
+    # disable_status_cache: the bank keeps no status cache, so
+    # exactly-once rests on dedup's and pack's signature tags (65,536
+    # deep each) and a repeat that outlives them lands again; bank
+    # tiles in processes of their own need it, since a cache a process
+    # would let such a repeat land once a tile (models/leader_topo.py).
+    larger_max_cost_per_block: bool = False
+    disable_status_cache: bool = False
+
+
+@dataclass
+class DevelopmentConfig:
+    bench: BenchConfig = field(default_factory=BenchConfig)
+
+
+@dataclass
 class LogConfig:
     path: str = ""
     level_stderr: str = "NOTICE"
@@ -146,6 +168,7 @@ class Config:
     quic: QuicConfig = field(default_factory=QuicConfig)
     ledger: LedgerConfig = field(default_factory=LedgerConfig)
     genesis: GenesisConfig = field(default_factory=GenesisConfig)
+    development: DevelopmentConfig = field(default_factory=DevelopmentConfig)
     log: LogConfig = field(default_factory=LogConfig)
 
 

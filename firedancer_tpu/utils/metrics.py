@@ -445,6 +445,9 @@ EV_NSWEEP_DRAIN = 18   # native sweep crossing drained (arg = frags; C-side,
 EV_NSWEEP_PUBLISH = 19  # native sweep crossing published (arg = frags; C-side)
 EV_BATCH_STALL = 20    # one thread-blocking phase of one device batch took
                        # BATCH_STALL_NS or more (arg = batch_stall_arg)
+EV_FUNK_LOCK_WAIT = 21  # a bank tile waited over 100 us for the account
+                        # store's lock (arg = funk_lock_wait_arg: the
+                        # holder's writer id and the microseconds)
 
 EVENT_NAMES = {
     EV_BOOT: "boot",
@@ -467,7 +470,19 @@ EVENT_NAMES = {
     EV_NSWEEP_DRAIN: "nsweep_drain",
     EV_NSWEEP_PUBLISH: "nsweep_publish",
     EV_BATCH_STALL: "batch_stall",
+    EV_FUNK_LOCK_WAIT: "funk_lock_wait",
 }
+
+
+def funk_lock_wait_arg(holder: int, wait_ns: int) -> int:
+    """EV_FUNK_LOCK_WAIT's arg: the holder's writer id (a bank tile's
+    index + 1 in a process topology) in the top 16 bits of 48, the wait
+    in microseconds under it."""
+    return ((holder & 0xFFFF) << 32) | min(wait_ns // 1000, 0xFFFFFFFF)
+
+
+def funk_lock_wait_fields(arg: int) -> dict:
+    return {"holder": arg >> 32, "us": arg & 0xFFFFFFFF}
 
 # The life of a device batch, in order.  Phase k ends where phase k+1
 # begins; the verify stage adds each phase's nanoseconds to the counter
@@ -736,6 +751,64 @@ def vote_row(src) -> dict | None:
     return {VOTE_COUNTERS[n]: int(v or 0) for n, v in have.items()} or None
 
 
+# A bank tile's use of the account store it shares with the other bank
+# tiles (native/fd_funk.cpp's lock, native/fd_bank.cpp's read-through),
+# and what pack gave each bank (counter -> the key shown)
+FUNK_COUNTERS = {
+    "funk_lock_acquires": "lock_holds",
+    "funk_lock_contended": "contended",
+    "funk_lock_wait_ns": "wait_ns",
+    "session_refreshed": "session_refreshed",
+}
+
+
+def funk_row(src) -> dict | None:
+    """{key: count} of FUNK_COUNTERS for a bank stage, and for the pack
+    stage its microblocks a bank (`mb_b<i>`) and `bank_idle_polls`, from
+    the stage's registry (the monitor) or a dict of its metrics
+    (slotreport); None for any other stage."""
+    if src is None:
+        return None
+    names = src._off if isinstance(src, MetricsRegistry) else src
+    get = src.get
+    out = {key: int(get(n) or 0) for n, key in FUNK_COUNTERS.items()
+           if n in names}
+    banks = sorted((n for n in names if n.startswith("mb_scheduled_b")),
+                   key=lambda n: int(n[len("mb_scheduled_b"):]))
+    for n in banks:
+        out["mb_" + n[len("mb_scheduled_"):]] = int(get(n) or 0)
+    if banks:
+        out["bank_idle_polls"] = int(get("bank_idle_polls") or 0)
+    return out or None
+
+
+def format_funk(rows: dict[str, dict]) -> str | None:
+    """The monitor's `funk` line: the bank tiles' one account store.
+    `rows`: stage -> its funk_row.  A contended share that is not
+    small says the tiles wait for each other at the store's lock, and
+    `session_refreshed` how many account values a tile's session took
+    from the segment (every account a microblock names, where other
+    tiles write the store too)."""
+    banks = {n: r for n, r in rows.items() if r and "lock_holds" in r}
+    if not banks:
+        return None
+    holds = sum(r["lock_holds"] for r in banks.values())
+    cont = sum(r["contended"] for r in banks.values())
+    line = (f"funk: {len(banks)} bank tile(s) over one store  lock "
+            f"holds={holds:,} contended={cont:,} "
+            f"({100.0 * cont / holds if holds else 0.0:.2f}%) wait_ms="
+            f"{sum(r['wait_ns'] for r in banks.values()) / 1e6:,.1f}  "
+            "session_refreshed " + " ".join(
+                f"{n}={r['session_refreshed']:,}" for n, r in banks.items()))
+    for r in rows.values():
+        if r and "bank_idle_polls" in r:
+            line += "  pack: microblocks " + " ".join(
+                f"{k[3:]}={v:,}" for k, v in r.items()
+                if k.startswith("mb_")) \
+                + f" bank_idle_polls={r['bank_idle_polls']:,}"
+    return line
+
+
 def batch_stall_arg(phase: int, ns: int) -> int:
     """EV_BATCH_STALL's arg: phase id in the high half, whole ms below."""
     return (phase << 32) | min(ns // 1_000_000, 0xFFFFFFFF)
@@ -940,7 +1013,8 @@ def flight_to_chrome_trace(dump: dict) -> dict:
                                "args": {"elems": arg}})
             else:
                 args = batch_stall_fields(arg) if ev == EV_BATCH_STALL \
-                    else {"arg": arg}
+                    else funk_lock_wait_fields(arg) \
+                    if ev == EV_FUNK_LOCK_WAIT else {"arg": arg}
                 events.append({"name": ev_name, "ph": "i", "pid": 1,
                                "tid": tid, "ts": us, "s": "t",
                                "args": args})

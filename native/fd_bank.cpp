@@ -27,7 +27,19 @@
 // valid set): the session's overlay is the ONLY account source, and an
 // overlay miss is a Punt by construction (ov_only).  Cold accounts
 // therefore punt exactly once — the Python resume ships their values —
-// and the steady state is all-native.  Fully-native results still reach
+// and the steady state is all-native.
+//
+// A store with MORE THAN ONE WRITER (fd_funk.cpp: the bank tiles of a
+// process topology over one segment) makes the session's copy of an
+// account stale whenever another tile wrote it since.  Pack's account
+// locks order the two tiles (A commits and publishes its done frame,
+// pack releases the lock and schedules on B); what B has to do is read
+// what A left.  So with ffk_writers() > 1 the segment is the store:
+// every account a microblock names is read from it under one hold of
+// its lock (ffk_rec_read_slot) and rides the request as a have=1 value,
+// which fd_exec_batch2 merges into the session before it executes.
+// `session_refreshed` counts those values.  With one writer none of
+// this runs.  Fully-native results still reach
 // Python through the same log (published=1 groups) because funk remains
 // the authoritative store for seal() and the Python lane.
 //
@@ -168,6 +180,15 @@ typedef int32_t (*ffk_txn_slot_t)(void* h, const u8* xid, int32_t xlen);
 typedef int32_t (*ffk_rec_insert_slot_t)(void* h, int32_t ti, const u8* key,
                                          int32_t klen, const u8* val,
                                          int32_t vlen);
+// the segment's writers' lock around a microblock's reads and around its
+// writes, the number of writers, and the read-through (fd_funk.cpp)
+typedef int32_t (*ffk_lock_t)(void* h);
+typedef void (*ffk_unlock_t)(void* h);
+typedef u32 (*ffk_writers_t)(void* h);
+typedef int32_t (*ffk_rec_read_slot_t)(void* h, int32_t ti, const u8* key,
+                                       int32_t klen, u8* out, i64 cap,
+                                       i64* vlen_out);
+static const int32_t FFK_ERR_RANGE = -7;
 
 static inline u16 rd16(const u8* p) { return (u16)(p[0] | (p[1] << 8)); }
 static inline u32 rd32(const u8* p) {
@@ -226,6 +247,10 @@ struct BankStageCtx {
   ffk_rec_insert_slot_t funk_insert;
   u64 funk_xid_len;
   u8 funk_xid[128];           // FFK_XID_MAX
+  ffk_lock_t funk_lock;       // with the funk plane, or all four null
+  ffk_unlock_t funk_unlock;
+  ffk_writers_t funk_writers;
+  ffk_rec_read_slot_t funk_read;
   u8* fkrecs; u64 fkrecs_cap; // stripped-record scratch
   // shm metrics plane (fdb_stage_set_metrics; null = dark): the SAME
   // plane fdr_sweep carries, so apply/publish brackets here land in
@@ -239,6 +264,8 @@ struct BankStageCtx {
   u64 mb_dropped;  // log arena OOM before anything committed (never-path)
   u64 funk_writes;  // records inserted into the native map in-crossing
   u64 funk_falls;   // groups that fell back to full-value logging
+  u64 session_refreshed;  // account values a session took from the segment
+                          // (a store other handles write too)
 };
 
 static int ensure_cap(u8** buf, u64* cap, u64 need) {
@@ -356,6 +383,18 @@ int fdb_stage_set_funk(void* p, void* funk, void* slot_fn, void* insert_fn,
   return st->funk_slot(st->funk, st->funk_xid, (int32_t)xid_len) >= 0 ? 1 : 2;
 }
 
+// The funk plane's four entry points for a store other processes write
+// too (by address from fd_funk.so, like the two above; all null: a
+// store this handle alone writes, and no lock is taken here).
+void fdb_stage_set_funk_shared(void* p, void* lock_fn, void* unlock_fn,
+                               void* writers_fn, void* read_fn) {
+  BankStageCtx* st = (BankStageCtx*)p;
+  st->funk_lock = (ffk_lock_t)lock_fn;
+  st->funk_unlock = (ffk_unlock_t)unlock_fn;
+  st->funk_writers = (ffk_writers_t)writers_fn;
+  st->funk_read = (ffk_rec_read_slot_t)read_fn;
+}
+
 // Arm/disarm the shm metrics plane (ISSUE 20).  The pointer is the
 // stage's own fdm_plane — the one its SweepDrainer already passes to
 // fdr_sweep — so the apply/publish accumulators bracketed below fold
@@ -463,24 +502,81 @@ int fdb_frag_cb(void* vctx, const u64* meta8, const u8* payload) {
     stash_raw(st, mb_seq, tsorig, payload, sz);
     return -1;
   }
-  u8* q = st->req;
-  wr32(q, REQ2_MAGIC);
-  wr32(q + 4, cnt);
-  std::memcpy(q + 8, st->hdr, st->hdr_sz);
-  q += 8 + st->hdr_sz;
-  for (u32 i = 0; i < cnt; i++) {
+  // a store other handles write: every account is read through the
+  // segment under one hold of its lock (the header's comment)
+  int shared = st->funk && st->funk_read && st->funk_writers(st->funk) > 1;
+  int32_t rti = -1;
+  if (shared) {
+    if (st->funk_lock(st->funk) != 0) {
+      // the lock's holder is dead: the Python lane's next call on the
+      // store raises, and names it
+      stash_raw(st, mb_seq, tsorig, payload, sz);
+      return -1;
+    }
+    rti = st->funk_slot(st->funk, st->funk_xid, (int32_t)st->funk_xid_len);
+  }
+  wr32(st->req, REQ2_MAGIC);
+  wr32(st->req + 4, cnt);
+  std::memcpy(st->req + 8, st->hdr, st->hdr_sz);
+  u64 qo = 8 + st->hdr_sz;
+  int read_ok = !shared || rti >= 0;
+  for (u32 i = 0; i < cnt && read_ok; i++) {
     const FragRef& r = st->refs[i];
     u64 dsz = r.len - 2 - r.psz;
     u8 acct_cnt = r.frag[r.psz + 8];
+    u8* q = st->req + qo;
     wr16(q, (u16)r.psz);
     wr16(q + 2, (u16)dsz);
     q[4] = acct_cnt;
     std::memcpy(q + 5, r.frag, r.psz + dsz);  // payload then desc, contiguous
-    q += 5 + r.psz + dsz;
-    std::memset(q, 0, acct_cnt);  // have=0: session overlay only (ov_only)
-    q += acct_cnt;
+    qo += 5 + r.psz + dsz;
+    if (!shared) {
+      std::memset(st->req + qo, 0, acct_cnt);  // have=0: the session's own
+      qo += acct_cnt;
+      continue;
+    }
+    u64 acct_off = rd16(r.frag + r.psz + 9);
+    if (acct_off + 32ull * acct_cnt > r.psz) {
+      // batch2 punts such a descriptor: nothing is read for it
+      std::memset(st->req + qo, 0, acct_cnt);
+      qo += acct_cnt;
+      continue;
+    }
+    for (u32 j = 0; j < acct_cnt && read_ok; j++) {
+      i64 vlen = 0;
+      int32_t rc;
+      u64 room = 512;
+      for (;;) {
+        // this flag and value, and a flag each for the accounts and the
+        // 5-byte heads of the transactions still to come
+        if (!ensure_cap(&st->req, &st->req_cap,
+                        req_bound + qo + 5 + room)) {
+          rc = -1;
+          break;
+        }
+        rc = st->funk_read(st->funk, rti, r.frag + acct_off + 32ull * j, 32,
+                           st->req + qo + 5, (i64)room, &vlen);
+        if (rc != FFK_ERR_RANGE) break;
+        room = (u64)vlen;
+      }
+      if (rc >= 0) {  // the value, 0 long where the key is not visible
+        st->req[qo] = 1;
+        wr32(st->req + qo + 1, (u32)vlen);
+        qo += 5 + (u64)vlen;
+        st->session_refreshed++;
+      } else {
+        read_ok = 0;
+      }
+    }
   }
-  u64 req_sz = (u64)(q - st->req);
+  if (shared) st->funk_unlock(st->funk);
+  if (!read_ok) {
+    // the fork does not resolve or a read failed: the Python lane reads
+    // through the store itself
+    stash_raw(st, mb_seq, tsorig, payload, sz);
+    return -1;
+  }
+  u64 req_sz = qo;
 
   // the session commit is irreversible: reserve log room for the worst
   // case (full response + raw frame) BEFORE executing, so the records
@@ -565,8 +661,10 @@ int fdb_frag_cb(void* vctx, const u64* meta8, const u8* payload) {
   u64 lrecs_sz = recs_sz;
   if (st->funk && n_done) {
     u64 t_apply = st->mplane ? fdm_now_ns() : 0;
-    int32_t ti = st->funk_slot(st->funk, st->funk_xid,
-                               (int32_t)st->funk_xid_len);
+    // one hold of the writers' lock over the group's records
+    int locked = st->funk_lock && st->funk_lock(st->funk) == 0;
+    int32_t ti = st->funk_lock && !locked ? -1 :
+        st->funk_slot(st->funk, st->funk_xid, (int32_t)st->funk_xid_len);
     int ok = ti >= 0 &&
              ensure_cap(&st->fkrecs, &st->fkrecs_cap, (u64)n_done * 10);
     if (ok) {
@@ -591,6 +689,7 @@ int fdb_frag_cb(void* vctx, const u64* meta8, const u8* payload) {
         }
       }
     }
+    if (locked) st->funk_unlock(st->funk);
     if (ok) {
       lrecs = st->fkrecs;
       lrecs_sz = (u64)n_done * 10;

@@ -10,7 +10,7 @@
 //     the ffk_rec_insert function pointer handed over at arm time) —
 //     no host-side re-apply per record;
 //   - the Python lane (funk/funk_native.py) is a thin view over the
-//     SAME map: zero-copy reads through the mapping base, batched
+//     SAME map: reads copied out inside the crossing, batched
 //     writes through one ffk_batch_apply crossing;
 //   - an uninvolved process can ffk_attach() the segment READ-ONLY and
 //     observe a consistent store through the seqlock (the seed of the
@@ -24,12 +24,25 @@
 // blocks; values are overwritten in place when the new length fits the
 // block's capacity (the common bank case: fixed-width account values).
 //
-// Concurrency: single writer, many readers.  Every mutating entry
-// point wraps itself in a seqlock (hdr->seq odd while writing, with
-// release/acquire ordering); readers in other processes retry on a
-// torn read.  Within the owning stage process the Python lane and the
-// native bank lane share one thread (the stage loop), so they never
-// interleave mid-operation.
+// Concurrency: many writers, many readers.  A handle that may write
+// (the creator's, or one of ffk_attach_rw) takes the segment's one
+// lock — a process-shared, robust pthread mutex in the header — around
+// every entry point that walks or changes the shared structure — the
+// bump allocator, the freelists, the hash chains, the txn table — and
+// advances the seqlock under it (hdr->seq odd while a mutation is
+// inside, release/acquire ordering).  The lock nests within one handle
+// (ffk_lock / ffk_unlock around a group of calls is one acquisition:
+// the bank sweep's a microblock).  A waiter sleeps in the kernel.  A
+// holder that died inside the lock is the kernel's to report
+// (EOWNERDEAD): the store may be torn, so the taker gives the mutex
+// back unrepaired, and that call and every later one of any handle
+// fails with FFK_ERR_LOCK and names the dead holder
+// (ffk_lock_failed_holder, from hdr->holder: the pid and writer id of
+// whoever took the lock last).  A handle of ffk_attach (read only)
+// cannot take the lock: ffk_rec_read copies a value out under the
+// seqlock and retries a torn read; its other calls are for a store at
+// rest.  Within one process the Python lane and the native bank lane
+// share one thread (the stage loop) and one handle.
 //
 // Error codes mirror funk/funk.py exactly (FunkError.code): the
 // binding re-raises them 1:1 so both lanes agree on failure shapes.
@@ -38,6 +51,9 @@
 #include <string.h>
 #include <stdio.h>
 #include <stdlib.h>
+#include <errno.h>
+#include <pthread.h>
+#include <time.h>
 
 #if defined(__linux__) || defined(__APPLE__)
 #include <fcntl.h>
@@ -83,6 +99,8 @@ enum {
   FFK_ERR_OOM = -5,     // arena exhausted
   FFK_ERR_RDONLY = -6,  // mutation through a read-only attach
   FFK_ERR_RANGE = -7,   // xid/key too long or output buffer too small
+  FFK_ERR_LOCK = -8,    // the lock's holder died inside it
+                        // (ffk_lock_failed_holder names it)
 };
 
 enum {
@@ -93,6 +111,8 @@ enum {
 };
 
 static const u64 FFK_MAGIC = ((u64)0x31 << 56) | (u64)FFK_MAGIC_LO;
+// the header's layout: 2 = with the writers' lock (attach checks it)
+static const u32 FFK_VERSION = 2;
 
 // --------------------------------------------------------------------------
 // in-segment structures (offset-based)
@@ -114,6 +134,10 @@ struct ffk_hdr {
   u64 rec_cnt_root;
   u64 free_heads[FFK_NCLASS];
   u8 last_pub[FFK_XID_MAX];
+  pthread_mutex_t lock;  // the writers': process-shared, robust
+  u64 holder;        // who took it last: (pid << 32) | writer id
+  u32 n_writers;     // handles that may write: the creator, + 1 an attach_rw
+  u32 ready;         // the creator's: its genesis is in, writers may attach
 };
 
 struct ffk_txn {
@@ -144,6 +168,13 @@ struct ffk_t {
   int writable;
   int owner;      // unlinks the shm name on close
   char name[96];
+  u32 writer_id;  // 1 the creator, 2.. in order of attach_rw; 0 read-only
+  u32 pid;
+  u32 lock_depth; // this handle's nesting: the lock is held while > 0
+  // this handle's own use of the lock (ffk_lock_stats)
+  u64 lk_acquires, lk_contended, lk_wait_ns;
+  u64 lk_long_waits, lk_long_ns, lk_long_holder;  // waits over 100 us
+  u64 lk_failed_holder;  // hdr->holder when FFK_ERR_LOCK gave up
 };
 
 static inline ffk_hdr* H(ffk_t* f) { return (ffk_hdr*)f->base; }
@@ -153,8 +184,84 @@ static inline ffk_txn* txns(ffk_t* f) { return (ffk_txn*)P(f, H(f)->txns_off); }
 static inline ffk_rec* rec_at(ffk_t* f, u64 off) { return (ffk_rec*)P(f, off); }
 static inline u8* rec_key(ffk_rec* r) { return (u8*)(r + 1); }
 
-// -- seqlock ----------------------------------------------------------------
+// -- the writers' lock and the seqlock ---------------------------------------
 
+static const u64 FFK_LOCK_LONG_NS = 100000;         // a wait worth a trace
+// a read-only handle's patience with a mutation that does not end
+static const u64 FFK_READ_LIMIT_NS = 5000000000ull;
+
+static inline u64 ffk_now_ns(void) {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (u64)ts.tv_sec * 1000000000ull + (u64)ts.tv_nsec;
+}
+
+static inline void cpu_relax(void) {
+#if defined(__x86_64__) || defined(__i386__)
+  __builtin_ia32_pause();
+#endif
+}
+
+static int lock_init(pthread_mutex_t* m) {
+  pthread_mutexattr_t a;
+  if (pthread_mutexattr_init(&a) != 0) return -1;
+  int rc = pthread_mutexattr_setpshared(&a, PTHREAD_PROCESS_SHARED);
+#if defined(__linux__)  // elsewhere a holder that dies inside is waited for
+  if (rc == 0) rc = pthread_mutexattr_setrobust(&a, PTHREAD_MUTEX_ROBUST);
+#endif
+  if (rc == 0) rc = pthread_mutex_init(m, &a);
+  pthread_mutexattr_destroy(&a);
+  return rc;
+}
+
+// 0 held (nested: counted once), FFK_ERR_LOCK a holder died inside it
+static i32 lock_take(ffk_t* f) {
+  if (f->lock_depth) { f->lock_depth++; return 0; }
+  ffk_hdr* h = H(f);
+  f->lk_acquires++;
+  int rc = pthread_mutex_trylock(&h->lock);
+  if (rc == EBUSY) {
+    f->lk_contended++;
+    u64 holder = __atomic_load_n(&h->holder, __ATOMIC_RELAXED);
+    u64 t0 = ffk_now_ns();
+    rc = pthread_mutex_lock(&h->lock);
+    u64 wait = ffk_now_ns() - t0;
+    f->lk_wait_ns += wait;
+    if (wait > FFK_LOCK_LONG_NS) {
+      f->lk_long_waits++;
+      f->lk_long_ns = wait;
+      f->lk_long_holder = holder;
+    }
+  }
+  if (rc != 0) {
+    // EOWNERDEAD: ours, of a holder that died inside — given back as it
+    // is (no pthread_mutex_consistent), so every later taker of any
+    // handle reads ENOTRECOVERABLE and ends here too
+    f->lk_failed_holder = __atomic_load_n(&h->holder, __ATOMIC_RELAXED);
+    if (rc == EOWNERDEAD) pthread_mutex_unlock(&h->lock);
+    return FFK_ERR_LOCK;
+  }
+  __atomic_store_n(&h->holder, ((u64)f->pid << 32) | f->writer_id,
+                   __ATOMIC_RELAXED);
+  f->lock_depth = 1;
+  return 0;
+}
+
+static void lock_drop(ffk_t* f) {
+  if (--f->lock_depth) return;
+  pthread_mutex_unlock(&H(f)->lock);
+}
+
+// an entry point's hold on the lock: a handle that may write takes it,
+// a read-only one (a store at rest, or ffk_rec_read's seqlock) does not
+struct Hold {
+  ffk_t* f;
+  i32 rc;
+  explicit Hold(ffk_t* f_) : f(f_), rc(f_->writable ? lock_take(f_) : 0) {}
+  ~Hold() { if (f->writable && rc == 0) lock_drop(f); }
+};
+
+// the seqlock, advanced under the lock
 static inline void wr_begin(ffk_t* f) {
   u64 s = __atomic_load_n(&H(f)->seq, __ATOMIC_RELAXED);
   __atomic_store_n(&H(f)->seq, s + 1, __ATOMIC_RELEASE);
@@ -216,14 +323,18 @@ static u64* chain_head(ffk_t* f, i32 slot, const u8* key, u32 klen) {
   return &buckets(f)[ffk_hash(slot, key, klen) & (H(f)->n_buckets - 1)];
 }
 
-// find rec for (slot, key); prev_out (optional) gets &link pointing at it
+// find rec for (slot, key); prev_out (optional) gets &link pointing at it.
+// An offset outside the mapping or a chain without end reads as "not
+// found": a read-only handle may walk a chain mid-mutation (its seqlock
+// check then discards the answer), and must not leave the mapping.
 static u64 rec_find(ffk_t* f, i32 slot, const u8* key, u32 klen,
                     u64** prev_out) {
   u64* link = chain_head(f, slot, key, klen);
   u64 off = *link;
-  while (off) {
+  u64 top = f->sz - sizeof(ffk_rec);
+  for (u32 steps = 0; off && off <= top && steps < (1u << 22); steps++) {
     ffk_rec* r = rec_at(f, off);
-    if (r->slot == slot && r->klen == klen &&
+    if (r->slot == slot && r->klen == klen && off + klen <= top &&
         memcmp(rec_key(r), key, klen) == 0) {
       if (prev_out) *prev_out = link;
       return off;
@@ -447,10 +558,18 @@ void* ffk_create(const char* name, u64 max_sz, i32 txn_cap) {
   f->sz = max_sz;
   f->writable = 1;
   f->owner = 1;
+  f->writer_id = 1;
+  f->pid = (u32)getpid();
   u64 n_buckets = 1u << 16;
   ffk_hdr* h = (ffk_hdr*)f->base;
   memset(h, 0, sizeof(*h));
-  h->version = 1;
+  h->version = FFK_VERSION;
+  if (lock_init(&h->lock) != 0) {
+    munmap(f->base, max_sz); close(f->fd); ffk_shm_unlinkx(f->name);
+    free(f);
+    return 0;
+  }
+  h->n_writers = 1;
   h->txn_cap = (u32)txn_cap;
   h->max_sz = max_sz;
   h->n_buckets = n_buckets;
@@ -466,44 +585,115 @@ void* ffk_create(const char* name, u64 max_sz, i32 txn_cap) {
 #endif
 }
 
-// read-only attach to an existing segment (the read-replica seed)
-void* ffk_attach(const char* name) {
-#if !FFK_HAVE_SHM
-  (void)name;
-  return 0;
-#else
+#if FFK_HAVE_SHM
+static ffk_t* attach(const char* name, int writable) {
   if (!name || !name[0]) return 0;
-  int fd = ffk_shm_openx(name, O_RDONLY, 0);
+  int fd = ffk_shm_openx(name, writable ? O_RDWR : O_RDONLY, 0);
   if (fd < 0) return 0;
   struct stat st;
   if (fstat(fd, &st) != 0 || st.st_size < (off_t)sizeof(ffk_hdr)) {
     close(fd);
     return 0;
   }
-  u8* base = (u8*)mmap(0, (size_t)st.st_size, PROT_READ, MAP_SHARED, fd, 0);
+  u8* base = (u8*)mmap(0, (size_t)st.st_size,
+                       writable ? PROT_READ | PROT_WRITE : PROT_READ,
+                       MAP_SHARED, fd, 0);
   if (base == MAP_FAILED) { close(fd); return 0; }
-  if (__atomic_load_n(&((ffk_hdr*)base)->magic, __ATOMIC_ACQUIRE)
-      != FFK_MAGIC) {
-    munmap(base, (size_t)st.st_size);
-    close(fd);
-    return 0;
-  }
-  ffk_t* f = (ffk_t*)calloc(1, sizeof(ffk_t));
+  ffk_hdr* h = (ffk_hdr*)base;
+  ffk_t* f = 0;
+  // a writer joins a store whose creator has said it is whole (ffk_set_ready)
+  if (__atomic_load_n(&h->magic, __ATOMIC_ACQUIRE) == FFK_MAGIC &&
+      h->version == FFK_VERSION && h->max_sz == (u64)st.st_size &&
+      (!writable || __atomic_load_n(&h->ready, __ATOMIC_ACQUIRE)))
+    f = (ffk_t*)calloc(1, sizeof(ffk_t));
   if (!f) { munmap(base, (size_t)st.st_size); close(fd); return 0; }
   f->base = base;
   f->sz = (u64)st.st_size;
   f->fd = fd;
-  f->writable = 0;
+  f->writable = writable;
   f->owner = 0;
+  f->pid = (u32)getpid();
   snprintf(f->name, sizeof(f->name), "%s", name);
+  if (writable) {
+    // the id is taken under the lock
+    if (lock_take(f) != 0) {
+      munmap(base, (size_t)st.st_size); close(fd); free(f);
+      return 0;
+    }
+    f->writer_id = __atomic_add_fetch(&h->n_writers, 1, __ATOMIC_ACQ_REL);
+    lock_drop(f);
+  }
   return f;
+}
 #endif
+
+// read-only attach to an existing segment (the read-replica seed)
+void* ffk_attach(const char* name) {
+#if !FFK_HAVE_SHM
+  (void)name;
+  return 0;
+#else
+  return attach(name, 0);
+#endif
+}
+
+// one more writer of an existing segment, once its creator has called
+// ffk_set_ready: NULL until then, so the caller asks again
+void* ffk_attach_rw(const char* name) {
+#if !FFK_HAVE_SHM
+  (void)name;
+  return 0;
+#else
+  return attach(name, 1);
+#endif
+}
+
+// the creator's word that what a joining writer expects is in the store
+void ffk_set_ready(void* h) {
+  __atomic_store_n(&H((ffk_t*)h)->ready, 1u, __ATOMIC_RELEASE);
+}
+
+// handles that may write this segment (the creator's is one)
+u32 ffk_writers(void* h) {
+  return __atomic_load_n(&H((ffk_t*)h)->n_writers, __ATOMIC_ACQUIRE);
+}
+
+u32 ffk_writer_id(void* h) { return ((ffk_t*)h)->writer_id; }
+
+// the lock around a group of calls (one acquisition; the calls inside
+// nest).  0 or FFK_ERR_LOCK / FFK_ERR_RDONLY.
+i32 ffk_lock(void* h) {
+  ffk_t* f = (ffk_t*)h;
+  return f->writable ? lock_take(f) : FFK_ERR_RDONLY;
+}
+
+void ffk_unlock(void* h) {
+  ffk_t* f = (ffk_t*)h;
+  if (f->writable && f->lock_depth) lock_drop(f);
+}
+
+// this handle's use of the lock: acquires, contended, wait_ns, waits
+// over 100 us, the last such wait's ns and its holder (pid<<32 | id)
+void ffk_lock_stats(void* h, u64* out6) {
+  ffk_t* f = (ffk_t*)h;
+  out6[0] = f->lk_acquires;
+  out6[1] = f->lk_contended;
+  out6[2] = f->lk_wait_ns;
+  out6[3] = f->lk_long_waits;
+  out6[4] = f->lk_long_ns;
+  out6[5] = f->lk_long_holder;
+}
+
+// the holder FFK_ERR_LOCK gave up on: (pid << 32) | writer id
+u64 ffk_lock_failed_holder(void* h) {
+  return ((ffk_t*)h)->lk_failed_holder;
 }
 
 void ffk_close(void* h, i32 unlink_shm) {
 #if FFK_HAVE_SHM
   ffk_t* f = (ffk_t*)h;
   if (!f) return;
+  if (f->writable && f->lock_depth) { f->lock_depth = 1; lock_drop(f); }
   if (f->base) munmap(f->base, f->sz);
   if (f->fd >= 0) close(f->fd);
   if (unlink_shm && f->owner) ffk_shm_unlinkx(f->name);
@@ -514,8 +704,6 @@ void ffk_close(void* h, i32 unlink_shm) {
 }
 
 const char* ffk_shm_name(void* h) { return ((ffk_t*)h)->name; }
-u64 ffk_base(void* h) { return (u64)(uintptr_t)((ffk_t*)h)->base; }
-u64 ffk_map_sz(void* h) { return ((ffk_t*)h)->sz; }
 u64 ffk_seq(void* h) {
   return __atomic_load_n(&H((ffk_t*)h)->seq, __ATOMIC_ACQUIRE);
 }
@@ -529,6 +717,8 @@ i32 ffk_txn_prepare(void* hh, const u8* pxid, i32 plen, const u8* xid,
   ffk_t* f = (ffk_t*)hh;
   if (!f->writable) return FFK_ERR_RDONLY;
   if (xlen <= 0 || xlen > FFK_XID_MAX) return FFK_ERR_RANGE;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   if (txn_find(f, xid, xlen) >= 0) return FFK_ERR_TXN;
   int pi = -1;
   if (plen >= 0) {
@@ -558,6 +748,8 @@ i32 ffk_txn_prepare(void* hh, const u8* pxid, i32 plen, const u8* xid,
 // 1 frozen, 0 not, FFK_ERR_TXN unknown
 i32 ffk_txn_is_frozen(void* hh, const u8* xid, i32 xlen) {
   ffk_t* f = (ffk_t*)hh;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   int ti = txn_find(f, xid, xlen);
   if (ti < 0) return FFK_ERR_TXN;
   return txns(f)[ti].child_cnt ? 1 : 0;
@@ -567,6 +759,8 @@ i32 ffk_txn_is_frozen(void* hh, const u8* xid, i32 xlen) {
 i32 ffk_txn_wcheck(void* hh, const u8* xid, i32 xlen) {
   ffk_t* f = (ffk_t*)hh;
   if (!f->writable) return FFK_ERR_RDONLY;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   int ti = txn_find(f, xid, xlen);
   if (ti < 0) return FFK_ERR_TXN;
   if (txns(f)[ti].child_cnt) return FFK_ERR_FROZEN;
@@ -579,6 +773,8 @@ i32 ffk_txn_cnt(void* hh) { return (i32)H((ffk_t*)hh)->txn_cnt; }
 // written, or the size needed when out == NULL, or FFK_ERR_*.
 i64 ffk_txn_ancestry(void* hh, const u8* xid, i32 xlen, u8* out, i64 cap) {
   ffk_t* f = (ffk_t*)hh;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   int ti = txn_find(f, xid, xlen);
   if (ti < 0) return FFK_ERR_TXN;
   ffk_txn* t = txns(f);
@@ -606,6 +802,8 @@ i64 ffk_txn_ancestry(void* hh, const u8* xid, i32 xlen, u8* out, i64 cap) {
 i32 ffk_txn_cancel(void* hh, const u8* xid, i32 xlen) {
   ffk_t* f = (ffk_t*)hh;
   if (!f->writable) return FFK_ERR_RDONLY;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   int ti = txn_find(f, xid, xlen);
   if (ti < 0) return FFK_ERR_TXN;
   wr_begin(f);
@@ -619,6 +817,8 @@ i32 ffk_txn_cancel(void* hh, const u8* xid, i32 xlen) {
 i32 ffk_txn_publish(void* hh, const u8* xid, i32 xlen) {
   ffk_t* f = (ffk_t*)hh;
   if (!f->writable) return FFK_ERR_RDONLY;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   int ti = txn_find(f, xid, xlen);
   if (ti < 0) return FFK_ERR_TXN;
   ffk_hdr* h = H(f);
@@ -687,6 +887,8 @@ i32 ffk_rec_insert(void* hh, const u8* xid, i32 xlen, const u8* key,
   ffk_t* f = (ffk_t*)hh;
   if (!f->writable) return FFK_ERR_RDONLY;
   if (klen < 0 || klen > FFK_KEY_MAX) return FFK_ERR_RANGE;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   i32 slot = 0;
   u64 toff = 0;
   if (xlen >= 0) {
@@ -709,6 +911,8 @@ i32 ffk_rec_remove(void* hh, const u8* xid, i32 xlen, const u8* key,
   ffk_t* f = (ffk_t*)hh;
   if (!f->writable) return FFK_ERR_RDONLY;
   if (klen < 0 || klen > FFK_KEY_MAX) return FFK_ERR_RANGE;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   if (xlen < 0) {
     u64 off = rec_find(f, 0, key, (u32)klen, 0);
     if (!off) return FFK_ERR_KEY;
@@ -741,35 +945,93 @@ i32 ffk_rec_remove(void* hh, const u8* xid, i32 xlen, const u8* key,
   return rc;
 }
 
-// nearest-overlay query.  Returns 1 found (voff/vlen set, voff relative
-// to ffk_base), 0 not visible, FFK_ERR_TXN unknown txn.
-i32 ffk_rec_query(void* hh, const u8* xid, i32 xlen, const u8* key,
-                  i32 klen, u64* voff_out, i64* vlen_out) {
-  ffk_t* f = (ffk_t*)hh;
-  if (klen < 0 || klen > FFK_KEY_MAX) return FFK_ERR_RANGE;
+// nearest-overlay lookup from fork `cur` (-1: root) down -> the record's
+// offset, 0 where the key is not visible (a tombstone hides ancestors)
+static u64 rec_visible(ffk_t* f, int cur, const u8* key, u32 klen) {
+  ffk_txn* t = txns(f);
+  u32 cap = H(f)->txn_cap;
+  for (u32 hops = 0; cur >= 0 && (u32)cur < cap && hops <= cap; hops++) {
+    u64 off = rec_find(f, cur + 1, key, klen, 0);
+    if (off) return rec_at(f, off)->vlen < 0 ? 0 : off;
+    cur = t[cur].parent;
+  }
+  return rec_find(f, 0, key, klen, 0);
+}
+
+// the value (fork `cur`, -1 the root, key) reads, into `out`: 1 copied
+// or 0 not visible (*vlen_out its length), FFK_ERR_RANGE `cap` is short
+// (*vlen_out what it takes)
+static i32 rec_copy(ffk_t* f, int cur, const u8* key, u32 klen, u8* out,
+                    i64 cap, i64* vlen_out) {
+  *vlen_out = 0;
+  u64 off = rec_visible(f, cur, key, klen);
+  if (!off) return 0;
+  ffk_rec* r = rec_at(f, off);
+  i64 vlen = r->vlen;
+  u64 voff = r->voff;
+  if (vlen < 0 || (vlen && (voff > f->sz || (u64)vlen > f->sz - voff)))
+    return 0;  // a torn read-only look: the seqlock check discards it
+  *vlen_out = vlen;
+  if (vlen > cap) return FFK_ERR_RANGE;
+  if (vlen) memcpy(out, P(f, voff), (size_t)vlen);
+  return 1;
+}
+
+static i32 rec_copy_xid(ffk_t* f, const u8* xid, i32 xlen, const u8* key,
+                        u32 klen, u8* out, i64 cap, i64* vlen_out) {
   int cur = -1;
   if (xlen >= 0) {
     cur = txn_find(f, xid, xlen);
     if (cur < 0) return FFK_ERR_TXN;
   }
-  ffk_txn* t = txns(f);
-  while (cur >= 0) {
-    u64 off = rec_find(f, cur + 1, key, (u32)klen, 0);
-    if (off) {
-      ffk_rec* r = rec_at(f, off);
-      if (r->vlen < 0) return 0;  // tombstone hides ancestors
-      *voff_out = r->voff;
-      *vlen_out = r->vlen;
-      return 1;
-    }
-    cur = t[cur].parent;
+  return rec_copy(f, cur, key, klen, out, cap, vlen_out);
+}
+
+// the copying query.  A handle that may write reads under the lock; a
+// read-only one reads under the seqlock and reads again when a writer
+// was inside (FFK_ERR_LOCK when one stays inside past the limit).
+i32 ffk_rec_read(void* hh, const u8* xid, i32 xlen, const u8* key, i32 klen,
+                 u8* out, i64 cap, i64* vlen_out) {
+  ffk_t* f = (ffk_t*)hh;
+  if (klen < 0 || klen > FFK_KEY_MAX) return FFK_ERR_RANGE;
+  if (f->writable) {
+    Hold hold(f);
+    if (hold.rc) return hold.rc;
+    return rec_copy_xid(f, xid, xlen, key, (u32)klen, out, cap, vlen_out);
   }
-  u64 off = rec_find(f, 0, key, (u32)klen, 0);
-  if (!off) return 0;
-  ffk_rec* r = rec_at(f, off);
-  *voff_out = r->voff;
-  *vlen_out = r->vlen;
-  return 1;
+  u64 t0 = 0;
+  for (u32 tries = 1;; tries++) {
+    u64 s0 = __atomic_load_n(&H(f)->seq, __ATOMIC_ACQUIRE);
+    if (!(s0 & 1)) {
+      i32 rc = rec_copy_xid(f, xid, xlen, key, (u32)klen, out, cap,
+                            vlen_out);
+      __atomic_thread_fence(__ATOMIC_ACQUIRE);
+      if (__atomic_load_n(&H(f)->seq, __ATOMIC_RELAXED) == s0) return rc;
+    }
+    cpu_relax();
+    if (tries & 1023) continue;
+    u64 t = ffk_now_ns();
+    if (!t0) t0 = t;
+    if (t - t0 > FFK_READ_LIMIT_NS) {
+      f->lk_failed_holder = __atomic_load_n(&H(f)->holder, __ATOMIC_RELAXED);
+      return FFK_ERR_LOCK;
+    }
+  }
+}
+
+// ffk_rec_read by the fork's index (ffk_txn_slot), for a handle that
+// may write: what a bank tile's session is given of an account before
+// it executes, where other handles write the store too.  The caller
+// holds the lock (ffk_lock) over a microblock's keys.
+i32 ffk_rec_read_slot(void* hh, i32 ti, const u8* key, i32 klen, u8* out,
+                      i64 cap, i64* vlen_out) {
+  ffk_t* f = (ffk_t*)hh;
+  if (ti < 0 || (u32)ti >= H(f)->txn_cap || txns(f)[ti].state != 1)
+    return FFK_ERR_TXN;
+  if (klen < 0 || klen > FFK_KEY_MAX) return FFK_ERR_RANGE;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
+  return rec_copy(f, ti, key, (u32)klen, out, cap, vlen_out);
 }
 
 i64 ffk_rec_cnt_root(void* hh) { return (i64)H((ffk_t*)hh)->rec_cnt_root; }
@@ -778,6 +1040,8 @@ i64 ffk_rec_cnt_root(void* hh) { return (i64)H((ffk_t*)hh)->rec_cnt_root; }
 // the byte size needed; else bytes written or FFK_ERR_RANGE.
 i64 ffk_root_keys(void* hh, u8* out, i64 cap) {
   ffk_t* f = (ffk_t*)hh;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   ffk_hdr* h = H(f);
   i64 need = 0;
   u64 nb = h->n_buckets;
@@ -808,6 +1072,8 @@ i64 ffk_root_keys(void* hh, u8* out, i64 cap) {
 // seal path's changed-accounts source.  out == NULL: size needed.
 i64 ffk_txn_keys(void* hh, const u8* xid, i32 xlen, u8* out, i64 cap) {
   ffk_t* f = (ffk_t*)hh;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   int ti = txn_find(f, xid, xlen);
   if (ti < 0) return FFK_ERR_TXN;
   ffk_txn* t = txns(f);
@@ -833,6 +1099,8 @@ i64 ffk_txn_keys(void* hh, const u8* xid, i32 xlen, u8* out, i64 cap) {
 // Returns the index or FFK_ERR_TXN / FFK_ERR_FROZEN.
 i32 ffk_txn_slot(void* hh, const u8* xid, i32 xlen) {
   ffk_t* f = (ffk_t*)hh;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   int ti = txn_find(f, xid, xlen);
   if (ti < 0) return FFK_ERR_TXN;
   if (txns(f)[ti].child_cnt) return FFK_ERR_FROZEN;
@@ -849,6 +1117,8 @@ i32 ffk_rec_insert_slot(void* hh, i32 ti, const u8* key, i32 klen,
   if (ti < 0 || (u32)ti >= H(f)->txn_cap || txns(f)[ti].state != 1)
     return FFK_ERR_TXN;
   if (klen < 0 || klen > FFK_KEY_MAX) return FFK_ERR_RANGE;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   wr_begin(f);
   i32 rc = rec_upsert(f, ti + 1, key, (u32)klen, val, vlen,
                       H(f)->txns_off + (u64)ti * sizeof(ffk_txn));
@@ -865,6 +1135,8 @@ i32 ffk_rec_insert_slot(void* hh, i32 ti, const u8* key, i32 klen,
 // FFK_ERR_*.
 i64 ffk_txn_diff(void* hh, const u8* xid, i32 xlen, u8* out, i64 cap) {
   ffk_t* f = (ffk_t*)hh;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   int ti = txn_find(f, xid, xlen);
   if (ti < 0) return FFK_ERR_TXN;
   ffk_txn* t = txns(f);
@@ -945,6 +1217,8 @@ i32 ffk_batch_apply(void* hh, const u8* xid, i32 xlen, const u8* buf,
                     i64 len, i32 n) {
   ffk_t* f = (ffk_t*)hh;
   if (!f->writable) return FFK_ERR_RDONLY;
+  Hold hold(f);
+  if (hold.rc) return hold.rc;
   i32 slot = 0;
   u64 toff = 0;
   if (xlen >= 0) {

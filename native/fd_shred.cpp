@@ -1108,8 +1108,16 @@ struct ShredStageCtx {
   // burst and its publish loop attribute apply/publish phases into the
   // sweep crossing's decomposition
   fdm_plane* mplane;
+  // the last burst's frames (offsets into the arena), of which the
+  // first frames_sent are on the ring: a ring that ran out of credits
+  // mid-burst gets the rest from the retry, and nothing is dropped
+  struct Frame { u64 off, sz, sig; };
+  Frame* frames;
+  u64 frames_cap, frames_n, frames_sent, frames_tsorig;
   // flags + counters Python reads off the struct (no FFI)
-  u64 pending_flush;  // batch closed for size but deferred for credits
+  u64 pending_flush;  // a batch closed but waits for credits, or a
+                      // burst's tail does: the stage holds its intake
+                      // and retries from after_credit
   u64 entries_in, entry_batches, fec_sets;
   u64 data_out, parity_out, frags_out, backpressure;
   u64 batches_dropped;  // batch outgrew the 256-set plan bound (8MB+)
@@ -1158,6 +1166,7 @@ void fds_stage_delete(void* p) {
   if (!st) return;
   std::free(st->buf);
   std::free(st->arena);
+  std::free(st->frames);
   std::free(st);
 }
 
@@ -1179,22 +1188,55 @@ void fds_stage_set_slot(void* p, u64 slot) {
 // the slot the next batch is shredded under (it follows poh's, below)
 u64 fds_stage_slot(void* p) { return ((ShredStageCtx*)p)->slot; }
 
+// the burst's frames that are not on the ring yet, as far as its
+// credits go.  -> 1 all out
+static int frames_out(ShredStageCtx* st) {
+  if (st->frames_sent == st->frames_n) return 1;
+  u64 t_pub = st->mplane ? fdm_now_ns() : 0;
+  while (st->frames_sent < st->frames_n) {
+    const ShredStageCtx::Frame& f = st->frames[st->frames_sent];
+    if (!st->publish(st->out_link, st->out_prod, st->arena + f.off, f.sz,
+                     f.sig, st->frames_tsorig))
+      break;
+    st->frames_sent++;
+    st->frags_out++;
+  }
+  if (st->mplane)
+    fdm_accum(st->mplane, FDM_PH_PUBLISH, fdm_now_ns() - t_pub);
+  return st->frames_sent == st->frames_n;
+}
+
 // shred + publish the accumulated batch.  Returns 1 on success, 0 when
 // deferred (credits below min_credits AND !force — pending_flush stays
-// set and the stage retries from after_credit).  An EXPLICIT flush
-// (ShredStage.flush, the slot-end path) forces: the Python lane's
-// flush() never credit-defers, so buffered entries must not survive
-// into the next slot's batch here either — frames past credit
-// exhaustion count as backpressure and are DROPPED set-whole (the
-// Python lane's publish_burst_out contract is per-frame; the _room()
-// pre-gate makes the mid-set case rare, and shreds are erasure-coded
-// by design).
+// set and the stage retries from after_credit).  A burst that outruns
+// the ring's credits leaves its tail in the arena with pending_flush
+// set: the stage takes no entry until the retry has put it out (the
+// store tile behind a slower ring backpressures the shred tile, and
+// through it poh, the banks and pack: nothing is dropped).  An EXPLICIT
+// flush (ShredStage.flush, the slot-end path) forces: the Python
+// lane's flush() never credit-defers, so buffered entries must not
+// survive into the next slot's batch here either; its own burst's tail
+// waits like any other, and only a forced flush that finds an EARLIER
+// burst's tail still waiting gives that tail up, counted as
+// backpressure (shreds are erasure-coded by design) — the held intake
+// keeps entries from arriving then, so that is an explicit flush over
+// a ring nobody drains.
 static int stage_flush(ShredStageCtx* st, int block_complete, int force) {
   // block_complete < 0 = "retry a deferred flush with its original
   // flag" (the after_credit path must not downgrade a pending flush);
   // a waiting block-complete survives any close that takes its bytes
   // (the Python lane's `block_complete or self._pending_bc`)
   block_complete = block_complete > 0 || st->pending_bc;
+  if (!frames_out(st)) {
+    // the arena still holds the last burst's tail
+    if (!force) {
+      st->pending_flush = 1;
+      st->pending_bc = (u64)block_complete;
+      return 0;
+    }
+    st->backpressure += st->frames_n - st->frames_sent;
+    st->frames_sent = st->frames_n;
+  }
   if (!st->buf_sz) {
     st->pending_flush = st->pending_bc = 0;
     return 1;
@@ -1252,31 +1294,41 @@ static int stage_flush(ShredStageCtx* st, int block_complete, int force) {
     return 1;
   }
   st->entry_batches++;
-  u64 t_pub = st->mplane ? fdm_now_ns() : 0;
+  // the burst's frame table, then as much of it as the ring takes
+  u64 n_frames = 0;
+  for (i64 s = 0; s < nsets; s++)
+    n_frames += set_meta[4 * s + 0] + set_meta[4 * s + 1];
+  if (n_frames > st->frames_cap) {
+    void* nf = std::realloc(st->frames,
+                            n_frames * sizeof(ShredStageCtx::Frame));
+    if (!nf) {  // OOM: dropped, counted
+      st->batches_dropped++;
+      if (heap_blk) std::free(heap_blk);
+      return 1;
+    }
+    st->frames = (ShredStageCtx::Frame*)nf;
+    st->frames_cap = n_frames;
+  }
+  ShredStageCtx::Frame* f = st->frames;
   for (i64 s = 0; s < nsets; s++) {
     u64 d = set_meta[4 * s + 0];
     u64 pcnt = set_meta[4 * s + 1];
     u64 fec_idx = set_meta[4 * s + 2];
-    const u8* base = st->arena + set_meta[4 * s + 3];
+    u64 base = set_meta[4 * s + 3];
     st->fec_sets++;
-    u64 done = 0;
     for (u64 i = 0; i < d; i++)
-      done += (u64)st->publish(st->out_link, st->out_prod,
-                               base + i * SHRED_MIN_SZ, SHRED_MIN_SZ, fec_idx,
-                               tsorig);
-    const u8* cbase = base + d * SHRED_MIN_SZ;
+      *f++ = {base + i * SHRED_MIN_SZ, SHRED_MIN_SZ, fec_idx};
+    u64 cbase = base + d * SHRED_MIN_SZ;
     for (u64 j = 0; j < pcnt; j++)
-      done += (u64)st->publish(st->out_link, st->out_prod,
-                               cbase + j * SHRED_MAX_SZ, SHRED_MAX_SZ, fec_idx,
-                               tsorig);
+      *f++ = {cbase + j * SHRED_MAX_SZ, SHRED_MAX_SZ, fec_idx};
     st->data_out += d;
     st->parity_out += pcnt;
-    st->frags_out += done;
-    st->backpressure += (d + pcnt) - done;
   }
-  if (st->mplane)
-    fdm_accum(st->mplane, FDM_PH_PUBLISH, fdm_now_ns() - t_pub);
+  st->frames_n = n_frames;
+  st->frames_sent = 0;
+  st->frames_tsorig = tsorig;
   if (heap_blk) std::free(heap_blk);
+  if (!frames_out(st)) st->pending_flush = 1;
   return 1;
 }
 
@@ -1348,7 +1400,9 @@ static void stage_entry(ShredStageCtx* st, const u8* payload, u64 sz,
 int fds_frag_cb(void* ctx, const u64* meta8, const u8* payload) {
   ShredStageCtx* st = (ShredStageCtx*)ctx;
   stage_entry(st, payload, meta8[3], meta8[5], meta8[1]);
-  return 0;
+  // a close or a burst's tail that waits for credits ends the sweep
+  // after this frag: the stage holds its intake until the retry is out
+  return st->pending_flush ? -1 : 0;
 }
 
 // per-frag fallback entry (mixed-lane/lossy path: Python's after_frag
