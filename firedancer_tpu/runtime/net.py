@@ -16,10 +16,12 @@ and counted, mirroring fd_quic's MTU policy.
 Native net lane (ISSUE 18): with `FDTPU_NATIVE_NET` on and the toolchain
 present, plain-UDP intake runs as a recvmmsg-style batched sweep in
 native/fd_net.cpp (one FFI crossing per burst) and QuicIngressStage
-routes every datagram through the native QUIC short-header fast path
-first — whatever the C side cannot fully own PUNTs back to the Python
-lane below in arrival order, so waltz/quic.py stays the single source of
-truth for the control plane.
+takes a sweep of datagrams off its socket in one crossing (ISSUE 46:
+one recvmmsg of up to `rx_burst`, then the native QUIC short-header
+fast path over them) and does its Python — event replay, ACK, publish,
+credit — once a sweep — whatever the C side cannot fully own PUNTs back
+to the Python lane below in arrival order, so waltz/quic.py stays the
+single source of truth for the control plane.
 """
 
 from __future__ import annotations
@@ -246,6 +248,11 @@ def send_stream_txn(
         s.close()
 
 
+# the C block's words a crossing's datagrams are counted from
+_C_WORDS = tuple(net_native.COUNTER_IDX[n]
+                 for n in ("rx_dgram", "rx_bytes", "consumed", "punt"))
+
+
 class QuicIngressStage(UdpIngressStage):
     """The real QUIC/TPU server position (fd_quic tile,
     /root/reference/src/app/fdctl/run/tiles/fd_quic.c): QUIC v1 packets
@@ -294,6 +301,8 @@ class QuicIngressStage(UdpIngressStage):
                      "whole transactions that waited for the ring behind")
             .gauge("txn_held", "whole transactions waiting now")
             .counter("streams_granted", "stream credit returned to senders")
+            .counter("ack_tx",
+                     "datagrams sent that carry nothing but an ACK frame")
             .gauge("rcvbuf_bytes", "the socket's receive buffer")
         )
 
@@ -326,9 +335,10 @@ class QuicIngressStage(UdpIngressStage):
         self._grant_quantum = max(1, stream_window // 4)
         self._tp = quic.encode_transport_params(
             {quic.TP_INITIAL_MAX_STREAMS_UNI: stream_window})
-        # whole transactions the ring behind had no credit for, in
-        # order: (payload, connection, stream id).  Bounded by the
-        # stream credit outstanding (connections x stream_window)
+        # the Python lane's whole transactions the ring behind had no
+        # credit for, in order: [payload, connection, stream id, native
+        # out rows that stood before it].  Bounded by the stream credit
+        # outstanding (connections x stream_window)
         self._held: deque = deque()
         self._held_counted = 0      # native out rows already counted held
         self._py_dup_stream = 0     # the Python lane's share of two counters
@@ -367,7 +377,9 @@ class QuicIngressStage(UdpIngressStage):
         # datagrams then never touch Python crypto.  The event drain
         # keeps the Python Connection authoritative (tracker, acks, rx
         # windows) so the control plane and every PUNT stay correct.
-        self._addr_ids: dict = {}     # src -> interned u32 addr id
+        self._peer_keys: dict = {}    # src -> peer key (the C side's form)
+        self._virtual_src: dict = {}  # peer key -> src, virtual sockets'
+        self._c_seen = [0, 0, 0, 0]   # C's rx_dgram, rx_bytes, consumed, punt
         self._native_idx: dict = {}   # local cid bytes -> native idx
         self._by_idx: dict = {}       # native idx -> Connection
         self._native_src: dict = {}   # native idx -> current home addr
@@ -377,6 +389,14 @@ class QuicIngressStage(UdpIngressStage):
                     max_conns=max_conns, reasm_depth=reasm_depth)
             except NativeUnavailable:
                 self._net_client = None
+        # the crossing receives for itself where the kernel's source
+        # addresses are ones it can compare (sockaddr_in); any other
+        # socket (a virtual one, IPv6) is read a datagram at a time and
+        # each datagram staged into the same sweep
+        self._sweeps_socket = (
+            self._net_client is not None
+            and isinstance(self.sock, socket.socket)
+            and self.sock.family == socket.AF_INET)
         if addr_file:
             # a tile in a process of its own: where its senders find it
             import json
@@ -401,8 +421,9 @@ class QuicIngressStage(UdpIngressStage):
         return c["loop_work_n"] != w0
 
     def _input_pending(self) -> bool:
-        if self._held or (self._net_client is not None
-                          and self._net_client.out_count()):
+        nc = self._net_client
+        if self._held or (nc is not None
+                          and (nc.out_count() or nc.rx_pending())):
             return True
         try:
             return bool(select.select([self.sock], [], [], 0)[0])
@@ -441,6 +462,15 @@ class QuicIngressStage(UdpIngressStage):
             budget[1] += len(dg)
         self.sock.sendto(dg, dst)
 
+    def _flush_conn(self, conn, dst) -> None:
+        """Send what the connection has to say now; a datagram that
+        carries nothing but an ACK frame counts under `ack_tx`."""
+        a0 = conn.ack_only_tx
+        for dg in conn.flush():
+            self._send(dg, dst)
+        if conn.ack_only_tx != a0:
+            self.metrics.inc("ack_tx", conn.ack_only_tx - a0)
+
     def before_credit(self) -> None:
         # credit for what the last sweep published goes back even when
         # the ring behind has just filled (after_credit is then skipped)
@@ -452,11 +482,13 @@ class QuicIngressStage(UdpIngressStage):
         # (queued, never dropped: a drain point that does not depend
         # on further ingress); while any of it waits, nothing more is
         # read, so the senders run out of stream credit and stop
-        drained = self._flush_held()
-        if self._net_client is not None:
-            drained = self._flush_native_txns() and drained
-        if drained:
-            super().after_credit()
+        if self._flush_waiting():
+            if self._net_client is None:
+                self._py_recv_loop()
+            elif self._sweeps_socket:
+                self._quic_sweep(self.sock.fileno())
+            elif self._quic_sweep(-1):   # what the arena still holds goes first
+                self._py_recv_loop()
         if self._grant:
             self._return_credit()
         # loss-recovery housekeeping: fire PTO retransmissions even when
@@ -464,42 +496,104 @@ class QuicIngressStage(UdpIngressStage):
         # handshake — fd_quic's service loop runs its timers the same way)
         for src, conn in list(self.conns.items()):
             conn.poll_timers()
-            for dg in conn.flush():
-                self._send(dg, src)
+            self._flush_conn(conn, src)
 
-    def _on_datagram(self, data: bytes, src) -> bool:
-        """Native-first dispatch: the C fast path either fully consumes
-        the datagram (short header, known conn, consumable frame mix),
-        drops it (auth/flow/frame violations — byte-for-byte the Python
-        lane's verdict), or PUNTs it to the Python lane below in arrival
-        order."""
-        c = self.metrics.counters
-        c["dgram_rx"] = c.get("dgram_rx", 0) + 1
-        c["dgram_rx_bytes"] = c.get("dgram_rx_bytes", 0) + len(data)
+    def _quic_sweep(self, fd: int) -> bool:
+        """The unit of the tile's work (ISSUE 46): ONE crossing takes
+        what the socket holds, up to `rx_burst` datagrams, and runs the
+        C fast path over them; then the Python is done once for all of
+        them (`_drain_native`: one event replay, one publish burst, the
+        credit, one ACK datagram a touched connection).  The C side
+        stops at a datagram it PUNTs: the sweep is drained as far as it
+        got, the Python lane runs on that datagram's exact bytes, and
+        the same sweep resumes behind it, so arrival order holds across
+        a punt.  A socket that holds one datagram gives a sweep of one.
+        `fd` -1: only what the receive arena already holds.
+        -> nothing waits for the ring behind."""
         nc = self._net_client
-        if nc is None:
-            return self._py_datagram(data, src)
         # lazy plane arm (ISSUE 20): the shm registry attaches after the
         # client exists, so re-arm whenever the stage's plane rebuilds
         plane = self._native_plane()
         if plane is not getattr(nc, "_plane", None):
             nc.set_metrics(plane)
-        rc = nc.datagram(data, self._intern_addr(src))
-        if rc == net_native.RC_CONSUMED:
-            self.metrics.inc("pkt_rx")
-            return self._drain_native(src)
-        if rc == net_native.RC_DROP:
-            self._drain_native(src)
-            self.metrics.inc("bad_packet")
-            return True
+        while True:
+            rc = nc.quic_sweep(fd, self.rx_burst)
+            fd = -1     # one receive a sweep: what follows resumes it
+            self._count_crossing()
+            ok = self._drain_native()
+            if rc >= 0:
+                data, key = nc.rx_datagram(rc)
+                self._punt(data, self._src_of(key))
+            elif rc == net_native.SWEEP_DONE or not ok:
+                # (SWEEP_FULL with the ring behind full too: the rest
+                # of the sweep waits in the arena for the next call)
+                return not self._held and not nc.out_count()
+
+    def _count_crossing(self) -> None:
+        """What the last crossing took, from the C block's own words:
+        per-sweep deltas, not four counter updates a datagram.  Every
+        datagram is consumed, punted (`_punt` counts what becomes of
+        it) or dropped as the Python lane would have dropped it."""
+        v = self._net_client.counters_view
+        seen = self._c_seen
+        now = [int(v[i]) for i in _C_WORDS]
+        rx = now[0] - seen[0]
+        if not rx:
+            return
+        self._c_seen = now
+        self._loop_worked = True    # the thread's ledger: a receive
+        consumed = now[2] - seen[2]
+        inc = self.metrics.inc
+        inc("dgram_rx", rx)
+        inc("dgram_rx_bytes", now[1] - seen[1])
+        if consumed:
+            inc("pkt_rx", consumed)
+        bad = rx - consumed - (now[3] - seen[3])
+        if bad:
+            inc("bad_packet", bad)
+
+    def _on_datagram(self, data: bytes, src) -> bool:
+        """One datagram that did not come through the crossing's own
+        receive (the Python lane; a virtual or non-IPv4 socket).  With
+        the C lane armed it is staged into the receive arena and swept:
+        a sweep of one, through the same code as a sweep of 64."""
+        nc = self._net_client
+        if nc is not None and nc.rx_stage(data, self._peer_key(src)):
+            return self._quic_sweep(-1)
+        # the Python lane — or, with the arena full of datagrams that
+        # wait for the ring behind, out of order as the network may
+        self.metrics.inc("dgram_rx")
+        self.metrics.inc("dgram_rx_bytes", len(data))
+        if nc is None:
+            return self._py_datagram(data, src)
         return self._punt(data, src)
 
-    def _intern_addr(self, src) -> int:
-        aid = self._addr_ids.get(src)
-        if aid is None:
-            aid = len(self._addr_ids) + 1
-            self._addr_ids[src] = aid
-        return aid
+    def _peer_key(self, src) -> bytes:
+        """`src` as the C side compares addresses (NET_PEER_KEY): what
+        recvmmsg's sockaddr_in gives for an IPv4 (host, port); an
+        interned id for a virtual socket's addresses."""
+        key = self._peer_keys.get(src)
+        if key is None:
+            try:
+                key = (_struct.pack("=H", socket.AF_INET)
+                       + _struct.pack("!H", src[1])
+                       + socket.inet_pton(socket.AF_INET, src[0])
+                       + bytes(12))
+            except (OSError, TypeError, ValueError, IndexError,
+                    _struct.error):
+                key = (b"\xff\xff"
+                       + _struct.pack("<Q", len(self._virtual_src) + 1)
+                       + bytes(10))
+                self._virtual_src[key] = src
+            self._peer_keys[src] = key
+        return key
+
+    def _src_of(self, key: bytes):
+        src = self._virtual_src.get(key)
+        if src is None:
+            src = (socket.inet_ntop(socket.AF_INET, key[4:8]),
+                   int.from_bytes(key[2:4], "big"))
+        return src
 
     def _punt(self, data: bytes, src) -> bool:
         """Python-lane handling for a datagram the native side declined,
@@ -536,7 +630,7 @@ class QuicIngressStage(UdpIngressStage):
         idx = self._native_idx.get(cid)
         if idx is not None:
             if self._native_src.get(idx) != src:
-                nc.conn_set_addr(idx, self._intern_addr(src))
+                nc.conn_set_addr(idx, self._peer_key(src))
                 self._native_src[idx] = src
             return
         keys = quic.export_rx_app_keys(conn)
@@ -545,7 +639,7 @@ class QuicIngressStage(UdpIngressStage):
         key, iv, hp = keys
         ranges = [(int(lo), int(hi))
                   for lo, hi in conn.recv[quic.APPLICATION].ranges]
-        idx = nc.conn_add(cid, self._intern_addr(src), key, iv, hp,
+        idx = nc.conn_add(cid, self._peer_key(src), key, iv, hp,
                           ranges, conn.rx_max_data, conn.rx_data_total)
         if idx >= 0:
             self._native_idx[cid] = idx
@@ -581,7 +675,7 @@ class QuicIngressStage(UdpIngressStage):
         nc.conn_streams(idx, conn.rx_max_streams_uni or 0,
                         conn.rx_fin_floor)
         if self.conns.get(src) is conn and self._native_src.get(idx) != src:
-            nc.conn_set_addr(idx, self._intern_addr(src))  # migrated
+            nc.conn_set_addr(idx, self._peer_key(src))  # migrated
             self._native_src[idx] = src
 
     def _native_remove(self, conn) -> None:
@@ -591,11 +685,15 @@ class QuicIngressStage(UdpIngressStage):
             self._by_idx.pop(idx, None)
             self._native_src.pop(idx, None)
 
-    def _drain_native(self, src) -> bool:
-        """Replay the C side's events into the authoritative Python
-        conns (tracker/ack/rtt/window state), publish completed txns
-        (credit-gated; the tail stays queued native-side), and flush the
-        per-conn ACK responses exactly as the Python lane would."""
+    def _drain_native(self) -> bool:
+        """Once a crossing: replay the C side's events into the
+        authoritative Python conns (tracker/ack/rtt/window state),
+        publish completed txns (credit-gated; the tail stays queued
+        native-side), return the stream credit they free, and flush
+        each touched connection once — one datagram a connection a
+        sweep, its ACK frame covering every packet number the sweep
+        admitted, sent only now that the packets' frames are applied
+        and their finished transactions stand in the out queue."""
         import time as _t
 
         from firedancer_tpu.waltz import quic
@@ -603,26 +701,22 @@ class QuicIngressStage(UdpIngressStage):
         nc = self._net_client
         now = _t.monotonic()
         nev = nc.event_count()
-        ev = nc.events
-        touched = set()
-        for i in range(nev):
-            idx = int(ev[i, 1])
+        ev = nc.events[:nev].tolist() if nev else ()
+        touched = {}
+        for typ, idx, a, b in ev:
             conn = self._by_idx.get(idx)
             if conn is None:
                 continue
-            typ = int(ev[i, 0])
-            a = int(ev[i, 2])
-            b = int(ev[i, 3])
             if typ == net_native.EV_PKT:
                 conn._processed_any = True
                 if b != 1:  # dup re-acks only, never re-adds
                     conn.recv[quic.APPLICATION].add(a)
                 if b in (0, 1):  # ack-eliciting or dup
                     conn.ack_pending.add(quic.APPLICATION)
-                touched.add(idx)
+                touched[idx] = conn
             elif typ == net_native.EV_ACK:
                 conn._on_ack(quic.APPLICATION, [(a - b, a)], now)
-                touched.add(idx)
+                touched[idx] = conn
             elif typ == net_native.EV_WIN:
                 conn.rx_consumed += a
                 conn.rx_data_total += b
@@ -636,7 +730,7 @@ class QuicIngressStage(UdpIngressStage):
                         + quic.varint_encode(conn.rx_max_data))
                     nc.conn_window(idx, conn.rx_max_data,
                                    conn.rx_data_total)
-                touched.add(idx)
+                touched[idx] = conn
             elif typ == net_native.EV_RETIRE:
                 # the stream ended without a transaction (counted:
                 # reasm_oversz / reasm_evicted): over here too, and
@@ -645,28 +739,55 @@ class QuicIngressStage(UdpIngressStage):
                 self._retire(conn)
         if nev:
             nc.events_clear()
-        ok = self._flush_native_txns()
-        for idx in touched:
-            conn = self._by_idx.get(idx)
-            if conn is None:
-                continue
-            home = self._native_src.get(idx, src)
-            for dg in conn.flush():
-                self._send(dg, home)
+        ok = self._flush_waiting()
+        if self._grant:
+            # before the flush below: a MAX_STREAMS frame rides the
+            # datagram that carries the sweep's ACK
+            self._return_credit()
+        for idx, conn in touched.items():
+            home = self._native_src.get(idx)
+            if home is not None:
+                self._flush_conn(conn, home)
         return ok
 
-    def _flush_native_txns(self) -> bool:
+    def _flush_waiting(self) -> bool:
+        """Publish what waits for the ring behind, oldest first, as far
+        as it has credit: the native out rows, and the Python lane's
+        transactions, each behind the native rows that stood before it.
+        -> none is left."""
+        held = self._held
+        while held:
+            txn, conn, sid, before = held[0]
+            if before:
+                done = self._publish_native(before)
+                for h in held:
+                    h[3] = max(h[3] - done, 0)
+                if done < before:
+                    return False
+            if not self.publish(0, txn, sig=self.metrics.get("txn_rx") + 1):
+                return False
+            held.popleft()
+            self.metrics.inc("txn_rx")
+            self._retire(conn)
+        nc = self._net_client
+        if nc is None:
+            return True
+        n = nc.out_count()
+        return self._publish_native(n) == n
+
+    def _publish_native(self, want: int) -> int:
+        """One burst of the first `want` native out rows -> how many
+        the ring behind took."""
+        if not want:
+            return 0
         nc = self._net_client
         n = nc.out_count()
-        if not n:
-            return True
-        if self._held:
-            return False    # the Python lane's tail is older: it goes first
-        base = self.metrics.get("txn_rx")
-        items = [(nc.out_txn(i), base + 1 + i, 0) for i in range(n)]
-        done = self.publish_burst_out(0, items)
-        for i in range(done):
-            ci, sid = nc.out_owner(i)
+        want = min(want, n)
+        base = self.metrics.get("txn_rx") + 1
+        rows = nc.out_rows(want)
+        done = self.publish_burst_out(
+            0, [(row[0], base + i, 0) for i, row in enumerate(rows)])
+        for _txn, ci, sid in rows[:done]:
             conn = self._by_idx.get(ci)
             if conn is not None:
                 conn.stream_finish(sid)
@@ -683,20 +804,7 @@ class QuicIngressStage(UdpIngressStage):
             self.metrics.inc("txn_held_for_credit", fresh)
             # (the counter's older name: it never counted a drop here)
             self.metrics.inc("txn_drop_backpressure", fresh)
-        return left == 0
-
-    def _flush_held(self) -> bool:
-        """Publish the Python lane's waiting transactions, in order,
-        as far as the ring behind has credit.  -> none is left."""
-        held = self._held
-        while held:
-            txn, conn, sid = held[0]
-            if not self.publish(0, txn, sig=self.metrics.get("txn_rx") + 1):
-                return False
-            held.popleft()
-            self.metrics.inc("txn_rx")
-            self._retire(conn)
-        return True
+        return done
 
     def _retire(self, conn, n: int = 1) -> None:
         """`n` of the connection's streams left this tile (published,
@@ -723,8 +831,7 @@ class QuicIngressStage(UdpIngressStage):
                 idx = self._native_idx.get(cid)
                 if idx is not None:
                     nc.conn_streams(idx, conn.rx_max_streams_uni)
-            for dg in conn.flush():
-                self._send(dg, home)
+            self._flush_conn(conn, home)
 
     def net_counters(self) -> dict:
         """The native lane's counter block ({} on the Python lane) —
@@ -834,7 +941,7 @@ class QuicIngressStage(UdpIngressStage):
                 self.identity_secret, transport_params=self._tp)
             conn.rx_max_streams_uni = self.stream_window
         was_established = conn.established
-        dup0, multi0 = conn.rx_dup_stream, conn.rx_multi_chunk
+        dup0 = conn.rx_dup_stream
         if src in self._addr_budget:
             self._addr_budget[src][0] += len(data)
             if conn is not None and conn.established:
@@ -891,13 +998,13 @@ class QuicIngressStage(UdpIngressStage):
                 if probe is not None:
                     self._send(probe, src)
                     self.metrics.inc("path_challenge_tx")
-        for dg in conn.flush():
-            self._send(dg, home)
+        self._flush_conn(conn, home)
         ok = True
         held = self._held
+        nc = self._net_client
         chunks = conn.receive_stream_events(events)
         self._py_dup_stream += conn.rx_dup_stream - dup0
-        self._py_multi_chunk += conn.rx_multi_chunk - multi0
+        multi = conn.rx_multi_sids
         for sid, chunk, fin in chunks:
             # every chunk feeds reassembly even under backpressure — the
             # datagram is already ACKed, so a skipped chunk would be a
@@ -908,11 +1015,12 @@ class QuicIngressStage(UdpIngressStage):
             txn = self.reasm.append((src, sid), chunk, fin=fin)
             if txn is None:
                 continue
-            if held or (self._net_client is not None
-                        and self._net_client.out_count()) \
-                    or not self.publish(
-                        0, txn, sig=self.metrics.get("txn_rx") + 1):
-                held.append((txn, conn, sid))
+            if sid in multi:    # of the PUBLISHED ones, as the C lane counts
+                self._py_multi_chunk += 1
+            before = nc.out_count() if nc is not None else 0
+            if held or before or not self.publish(
+                    0, txn, sig=self.metrics.get("txn_rx") + 1):
+                held.append([txn, conn, sid, before])
                 self.metrics.inc("txn_held_for_credit")
                 ok = False
                 continue

@@ -1,8 +1,9 @@
 """ctypes binding for the native net sweep client (native/fd_net.cpp).
 
 The ingress stage's QUIC short-header steady state in one FFI crossing
-per datagram (ISSUE 18): DCID -> connection lookup over the interned
-table, header-protection unmask, AES-128-GCM open (AES-NI + PCLMUL with
+per sweep of datagrams (ISSUE 18; a sweep since ISSUE 46: one recvmmsg
+takes what the socket holds, up to the stage's rx_burst, into a receive
+arena): DCID -> connection lookup over the interned table, header-protection unmask, AES-128-GCM open (AES-NI + PCLMUL with
 a scalar fallback, byte-identical to ops/aes.py), packet-number dedup,
 STREAM frame walk and fd_tpu_reasm-style reassembly.  Whole txns land in
 a reusable out arena with an (off, sz, sig, tsorig) table shaped for
@@ -41,10 +42,12 @@ _SO = os.path.join(os.path.dirname(_SRC), "fd_net.so")
 
 ENV_SWITCH = "FDTPU_NATIVE_NET"
 
-# fdn_datagram return codes (fd_net.cpp enum)
-RC_CONSUMED = 0
-RC_PUNT = 1
-RC_DROP = 2
+# fdn_quic_sweep's returns below zero (fd_net.cpp enum); >= 0 is the
+# receive-arena slot of a datagram the C lane punts
+SWEEP_DONE = -1   # every datagram of the arena processed
+SWEEP_FULL = -2   # stopped for want of headroom: drain, call again
+
+PEER_KEY_LEN = 20  # NET_PEER_KEY: family | port | address, zero-padded
 
 # event rows (type, conn_idx, a, b)
 EV_PKT = 1   # a = pn, b = flag (0 ack-eliciting, 1 dup, 2 bad-frame, 3 pure-ack)
@@ -67,22 +70,28 @@ def _load():
         u64 = ctypes.c_uint64
         i64 = ctypes.c_int64
         i32 = ctypes.c_int32
-        u32 = ctypes.c_uint32
         vp = ctypes.c_void_p
         cp = ctypes.c_char_p
         lib.fdn_new.argtypes = [i32, i32]
         lib.fdn_new.restype = vp
         lib.fdn_delete.argtypes = [vp]
-        lib.fdn_conn_add.argtypes = [vp, cp, u32, cp, cp, cp, i64p, i32,
+        lib.fdn_conn_add.argtypes = [vp, cp, cp, cp, cp, cp, i64p, i32,
                                      u64, u64]
         lib.fdn_conn_add.restype = i32
         lib.fdn_conn_remove.argtypes = [vp, i32]
-        lib.fdn_conn_set_addr.argtypes = [vp, i32, u32]
+        lib.fdn_conn_set_addr.argtypes = [vp, i32, cp]
         lib.fdn_conn_window.argtypes = [vp, i32, u64, u64]
         lib.fdn_conn_pn_add.argtypes = [vp, i32, i64]
         lib.fdn_conn_streams.argtypes = [vp, i32, u64, u64]
-        lib.fdn_datagram.argtypes = [vp, cp, i32, u32]
-        lib.fdn_datagram.restype = i32
+        lib.fdn_quic_sweep.argtypes = [vp, i32, i32]
+        lib.fdn_quic_sweep.restype = i32
+        lib.fdn_rx_stage.argtypes = [vp, cp, i32, cp]
+        lib.fdn_rx_stage.restype = i32
+        for name in ("fdn_rx_ptr", "fdn_rx_peer"):
+            getattr(lib, name).argtypes = [vp, i32]
+            getattr(lib, name).restype = vp
+        lib.fdn_rx_len.argtypes = [vp, i32]
+        lib.fdn_rx_len.restype = i32
         lib.fdn_udp_sweep.argtypes = [vp, i32, i32]
         lib.fdn_udp_sweep.restype = i32
         lib.fdn_udp_sweep_scalar.argtypes = [vp, i32, i32]
@@ -93,7 +102,7 @@ def _load():
             getattr(lib, name).argtypes = [vp]
             getattr(lib, name).restype = vp
         for name in ("fdn_counters_len", "fdn_events_count",
-                     "fdn_out_count"):
+                     "fdn_out_count", "fdn_rx_pending"):
             getattr(lib, name).argtypes = [vp]
             getattr(lib, name).restype = i32
         lib.fdn_events_clear.argtypes = [vp]
@@ -131,14 +140,14 @@ def available() -> bool:
 _COUNTERS = ("rx_dgram", "consumed", "punt", "dup", "bad_packet", "txn",
              "oversz", "evicted", "flow_violation", "auth_fail",
              "udp_pkts", "aesni", "pclmul", "tail_retained", "dup_stream",
-             "multi_chunk", "defer")
+             "multi_chunk", "defer", "rx_bytes")
 COUNTER_IDX = {name: i for i, name in enumerate(_COUNTERS)}
 
 
 class NetClient:
     """One ingress stage's native session: the interned connection
-    table, the per-datagram fast path, and the zero-FFI event/out/counter
-    views the stage drains after every crossing."""
+    table, the receive arena and the sweep over it, and the zero-FFI
+    event/out/counter views the stage drains after every crossing."""
 
     def __init__(self, *, max_conns: int, reasm_depth: int):
         lib = _load()
@@ -164,25 +173,26 @@ class NetClient:
 
     # -- connection table ----------------------------------------------------
 
-    def conn_add(self, dcid: bytes, addr_id: int, key: bytes, iv: bytes,
+    def conn_add(self, dcid: bytes, peer: bytes, key: bytes, iv: bytes,
                  hp: bytes, ranges: list[tuple[int, int]],
                  rx_max_data: int, rx_data_total: int) -> int:
-        """Install an ESTABLISHED connection's rx side; ranges seed the
-        pn dedup window from the Python tracker.  -1 = table full (the
-        conn simply stays on the Python lane)."""
+        """Install an ESTABLISHED connection's rx side; `peer` is its
+        home address as a peer key; ranges seed the pn dedup window
+        from the Python tracker.  -1 = table full (the conn simply
+        stays on the Python lane)."""
         flat = (ctypes.c_int64 * (2 * len(ranges)))()
         for i, (lo, hi) in enumerate(ranges):
             flat[2 * i] = lo
             flat[2 * i + 1] = hi
         return int(self._lib.fdn_conn_add(
-            self._h, bytes(dcid), addr_id, bytes(key), bytes(iv),
+            self._h, bytes(dcid), peer, bytes(key), bytes(iv),
             bytes(hp), flat, len(ranges), rx_max_data, rx_data_total))
 
     def conn_remove(self, idx: int) -> None:
         self._lib.fdn_conn_remove(self._h, idx)
 
-    def conn_set_addr(self, idx: int, addr_id: int) -> None:
-        self._lib.fdn_conn_set_addr(self._h, idx, addr_id)
+    def conn_set_addr(self, idx: int, peer: bytes) -> None:
+        self._lib.fdn_conn_set_addr(self._h, idx, peer)
 
     def conn_window(self, idx: int, rx_max_data: int,
                     rx_data_total: int) -> None:
@@ -200,16 +210,37 @@ class NetClient:
 
     # -- the hot path --------------------------------------------------------
 
-    def datagram(self, data: bytes, addr_id: int) -> int:
-        """One datagram through the C fast path; RC_CONSUMED /
-        RC_PUNT (run the Python lane on these bytes) / RC_DROP."""
-        return int(self._lib.fdn_datagram(self._h, data, len(data),
-                                          addr_id))
+    def quic_sweep(self, fd: int, max_pkts: int) -> int:
+        """The quic tile's crossing.  With the receive arena empty and
+        `fd` >= 0: one recvmmsg of up to `max_pkts` datagrams with
+        their source addresses; then the arena's datagrams through the
+        fast path in arrival order.  SWEEP_DONE, SWEEP_FULL (drain and
+        call again), or the arena slot of a datagram to run the Python
+        lane on (`rx_datagram`) before calling again; what lies behind
+        a stop stays in the arena."""
+        return int(self._lib.fdn_quic_sweep(self._h, fd, max_pkts))
+
+    def rx_stage(self, data: bytes, peer: bytes) -> bool:
+        """A virtual socket's datagram behind what the arena holds,
+        for the next `quic_sweep(-1, ...)`; False: the arena is full."""
+        return self._lib.fdn_rx_stage(self._h, data, len(data), peer) == 0
+
+    def rx_datagram(self, slot: int) -> tuple[bytes, bytes]:
+        """(bytes, peer key) of arena slot `slot`: a punted datagram."""
+        h = self._h
+        return (ctypes.string_at(self._lib.fdn_rx_ptr(h, slot),
+                                 self._lib.fdn_rx_len(h, slot)),
+                ctypes.string_at(self._lib.fdn_rx_peer(h, slot),
+                                 PEER_KEY_LEN))
+
+    def rx_pending(self) -> int:
+        """Datagrams the arena still holds unprocessed."""
+        return int(self._lib.fdn_rx_pending(self._h))
 
     def set_metrics(self, plane) -> None:
-        """Arm the shm metrics plane (ISSUE 20): socket sweeps observe
-        the drain phase and per-datagram decrypt+apply the callback
-        phase, straight from C.  `plane` None disarms."""
+        """Arm the shm metrics plane (ISSUE 20): plain-UDP sweeps
+        observe the drain phase and a quic sweep's decrypt+apply the
+        callback phase, straight from C.  `plane` None disarms."""
         self._plane = plane  # keepalive: C holds the raw pointer
         self._lib.fdn_set_metrics(
             self._h, plane.ptr if plane is not None else None)
@@ -245,10 +276,18 @@ class NetClient:
         sz = int(self.out_tbl[row, 1])
         return bytes(self.arena[off : off + sz])
 
-    def out_owner(self, row: int) -> tuple[int, int]:
-        """(connection idx, stream id) of out row `row`: whose stream
-        credit its publish returns."""
-        return int(self.out_tbl[row, 2]), int(self.out_tbl[row, 3])
+    def out_rows(self, n: int) -> list[tuple[bytes, int, int]]:
+        """The first `n` out rows, in one read of the table and one of
+        the arena: (transaction, connection idx, stream id) — whose
+        stream credit each publish returns."""
+        rows = self.out_tbl[:n].tolist()
+        if not rows:
+            return []
+        lo = rows[0][0]
+        blob = ctypes.string_at(self.arena_ptr + lo,
+                                rows[-1][0] + rows[-1][1] - lo)
+        return [(blob[off - lo:off - lo + sz], ci, sid)
+                for off, sz, ci, sid in rows]
 
     def counters(self) -> dict[str, int]:
         return {name: int(self.counters_view[i])

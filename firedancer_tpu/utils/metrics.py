@@ -677,14 +677,15 @@ def dedup_row(src) -> dict | None:
 # these names): the quic tile's datagrams, punts from the C
 # lane to Python, connections, the reassembler's outcomes, whole
 # transactions that waited for verify's ring and the stream credit
-# returned; a sender tile's transactions, datagrams, chunks sent again,
-# streams acknowledged and calls held by the peer's credit
+# returned, the datagrams it sent that carry nothing but an ACK; a
+# sender tile's transactions, datagrams, chunks sent again, streams
+# acknowledged and calls held by the peer's credit
 FRONT_COUNTERS = (
     # the quic tile
     "dgram_rx", "dgram_rx_bytes", "net_punts", "handshakes_done",
     "conn_active", "reasm_published", "reasm_multi_chunk", "reasm_evicted",
     "reasm_oversz", "reasm_cancelled", "reasm_dup_stream",
-    "txn_held_for_credit", "streams_granted",
+    "txn_held_for_credit", "streams_granted", "ack_tx",
     # a sender tile (dgram_rx is both's)
     "txn_tx", "dgram_tx", "dgram_rtx", "streams_acked",
     "send_blocked_credit",
@@ -695,16 +696,27 @@ def front_row(src) -> dict | None:
     """{name: count} of FRONT_COUNTERS' counters that the stage has,
     from its registry (the monitor) or a dict of its metrics
     (slotreport); None where the stage is neither a quic tile nor a
-    sender tile."""
+    sender tile.  A quic tile's row ends with the two ratios that say
+    whether it amortises its Python (docs/OPERATIONS.md): `dgram/
+    crossing` = dgram_rx / nsweep_crossings, datagrams a crossing into
+    C, and `ack/dgram` = ack_tx / dgram_rx."""
     if src is None:
         return None
+    names = FRONT_COUNTERS + ("nsweep_crossings",)
     if isinstance(src, MetricsRegistry):
-        have = {n: src.get(n) for n in FRONT_COUNTERS if n in src._off}
+        have = {n: src.get(n) for n in names if n in src._off}
     else:
-        have = {n: src[n] for n in FRONT_COUNTERS if n in src}
+        have = {n: src[n] for n in names if n in src}
     if "reasm_published" not in have and "txn_tx" not in have:
         return None
-    return {n: int(v or 0) for n, v in have.items()}
+    row = {n: int(v or 0) for n, v in have.items()}
+    crossings = row.pop("nsweep_crossings", 0)
+    rx = row.get("dgram_rx", 0)
+    if "ack_tx" in row and rx:
+        if crossings:
+            row["dgram/crossing"] = round(rx / crossings, 2)
+        row["ack/dgram"] = round(row["ack_tx"] / rx, 3)
+    return row
 
 
 def mesh_row(src) -> dict | None:

@@ -231,7 +231,12 @@ def export_tx_app_keys(conn: "Connection") -> tuple[bytes, bytes, bytes] | None:
 
 # -- packet sealing / opening -------------------------------------------------
 
-PN_LEN = 2  # fixed 2-byte encoded packet numbers (valid per §17.1)
+# fixed 2-byte encoded packet numbers (valid per §17.1): the wire
+# carries a packet number's low 16 bits, the nonce the whole of it, and
+# the receiver's decode_pn restores it — far fewer than 2**15 packets
+# are ever unacknowledged (a sender holds a stream window of them)
+PN_LEN = 2
+_PN_MASK = (1 << (8 * PN_LEN)) - 1
 
 
 def decode_pn(truncated: int, pn_nbits: int, largest: int) -> int:
@@ -256,14 +261,15 @@ def _long_header(ptype: int, dcid: bytes, scid: bytes, token: bytes,
     if ptype == LONG_INITIAL:
         hdr += varint_encode(len(token)) + token
     hdr += varint_encode(payload_len + PN_LEN + 16)  # + GCM tag
-    hdr += pn.to_bytes(PN_LEN, "big")
+    hdr += (pn & _PN_MASK).to_bytes(PN_LEN, "big")
     return hdr
 
 
 def seal_packet(keys: Keys, *, level: int, dcid: bytes, scid: bytes,
                 pn: int, payload: bytes, token: bytes = b"") -> bytes:
     if level == APPLICATION:
-        hdr = bytes([0x40 | (PN_LEN - 1)]) + dcid + pn.to_bytes(PN_LEN, "big")
+        hdr = (bytes([0x40 | (PN_LEN - 1)]) + dcid
+               + (pn & _PN_MASK).to_bytes(PN_LEN, "big"))
         pn_off = 1 + len(dcid)
     else:
         ptype = LONG_INITIAL if level == INITIAL else LONG_HANDSHAKE
@@ -899,11 +905,13 @@ class Connection:
         self.rx_fin_floor = 0
         self.rx_fin_set: set[int] = set()
         self.rx_dup_stream = 0
-        # of the streams delivered whole, those joined from chunks at
-        # more than one offset
-        self.rx_multi_chunk = 0
+        # of the streams the last receive_stream_events call delivered
+        # whole, those joined from chunks at more than one offset
+        self.rx_multi_sids: list[int] = []
         # our streams whose last chunk's packet the peer acknowledged
         self.streams_fin_acked = 0
+        # packets flush() sealed that carry nothing but an ACK frame
+        self.ack_only_tx = 0
         # per-packet payload budget of flush(): an owner that must keep
         # its datagrams under a size lowers it
         self.max_payload = MAX_FRAMES_PAYLOAD
@@ -1350,6 +1358,8 @@ class Connection:
                 if record:
                     self.sent[lvl][pn] = SentPacket(pn, now, record)
                     self.last_ae_time[lvl] = now  # re-arm the PTO timer
+                else:
+                    self.ack_only_tx += 1   # only an ACK frame records nothing
         return out
 
     def send_stream_packet(self, stream_id: int, offset: int, data: bytes,
@@ -1424,6 +1434,7 @@ class Connection:
         arriving ahead of a gap must not finalize a short stream."""
         out = []
         dirty: set[int] = set()
+        multi = self.rx_multi_sids = []
         peer_uni = 3 if self.is_client else 2
         for ev in events:
             tracked = ev.stream_id & 3 == peer_uni
@@ -1442,7 +1453,8 @@ class Connection:
             if ready or st.finished:
                 out.append((ev.stream_id, ready, st.finished))
             if tracked and st.finished:
-                self.rx_multi_chunk += len(st.offs) > 1
+                if len(st.offs) > 1:
+                    multi.append(ev.stream_id)
                 self.stream_finish(ev.stream_id)
         self._rx_window_updates(dirty)
         return out

@@ -30,6 +30,14 @@
 // -> flow-window deltas) the stage applies synchronously after every
 // crossing, so the Python Connection object remains authoritative.
 //
+// The unit of a crossing is a SWEEP of datagrams (fdn_quic_sweep): one
+// recvmmsg takes what the socket holds, up to the stage's rx_burst,
+// into a receive arena with the source addresses, and the fast path
+// runs over them in arrival order; it stops in front of the datagram
+// it punts (or when the event / out tables run short) and resumes
+// behind it on the next call, so the stage pays its Python once a
+// sweep and arrival order holds across a punt.
+//
 // Single-threaded by contract (one ingress stage owns one ctx); no
 // mutexes, no atomics — the sanitizer lanes (asan/ubsan/tsan twins) and
 // abi_check cover this translation unit like every other native hot path.
@@ -46,6 +54,7 @@
 
 #if defined(__linux__)
 #include <sys/socket.h>
+#include <netinet/in.h>
 #include <errno.h>
 #endif
 
@@ -403,6 +412,12 @@ static i64 decode_pn(u64 truncated, int pn_nbits, i64 largest) {
 #define NET_STREAM_LIMIT ((u64)1 << 18)   // quic.DEFAULT_MAX_STREAM_DATA
 #define NET_TXN_MTU 1232
 #define NET_FIN_WINDOW 4096
+// A peer address as both sides compare it: sa_family (host order) |
+// port (network order) | address, zero-padded to 16 bytes.  For
+// AF_INET the first 8 bytes of a sockaddr_in; a virtual socket's
+// addresses (the tests', the chaos wire's) are family 0xFFFF and an
+// id the stage interns
+#define NET_PEER_KEY 20
 
 struct PnWindow {
   i64 rng[NET_MAX_RANGES][2];  // ascending disjoint [lo, hi]
@@ -462,7 +477,7 @@ static inline i64 pn_largest(const PnWindow *w) {
 struct NetConn {
   u8 state;        // 0 free, 1 used, 2 tombstone (probe continuation)
   u8 gen;          // bumped per table-slot reuse: stale reasm slots die
-  u32 addr_id;
+  u8 peer[NET_PEER_KEY];  // the connection's home address (peer key)
   u64 dcid;        // the 8 raw DCID bytes, memcpy'd
   GcmKS pp;        // packet-protection (payload) key
   AesKS hp;        // header-protection key
@@ -535,12 +550,16 @@ enum { EV_PKT = 1, EV_ACK = 2, EV_WIN = 3, EV_RETIRE = 4 };
 #define EV_CAP 4096
 #define OUT_CAP 1024
 #define OUT_ARENA_SZ (OUT_CAP * (NET_TXN_MTU + 48))
+// the receive arena: one sweep's datagrams, as recvmmsg (or the stage,
+// for a virtual socket) left them, with their source addresses
+#define RX_CAP 64
+#define RX_SLOT 2048
 
 enum {
   C_RX_DGRAM = 0, C_CONSUMED, C_PUNT, C_DUP, C_BAD_PACKET, C_TXN,
   C_OVERSZ, C_EVICTED, C_FLOW_VIOLATION, C_AUTH_FAIL, C_UDP_PKTS,
   C_AESNI, C_PCLMUL, C_TAIL_RETAINED, C_DUP_STREAM, C_MULTI_CHUNK,
-  C_DEFER, C_COUNT,
+  C_DEFER, C_RX_BYTES, C_COUNT,
 };
 
 struct NetCtx {
@@ -557,22 +576,28 @@ struct NetCtx {
   u64 arena_used;
   u8 *arena;
   u64 counters[C_COUNT];
-  // shm metrics plane (fdn_set_metrics; null = dark): socket sweeps
-  // observe the drain phase, per-datagram decrypt+apply the callback
-  // phase — the publish phase rides the Python-side burst crossing
+  // shm metrics plane (fdn_set_metrics; null = dark): plain-UDP socket
+  // sweeps observe the drain phase, a quic sweep's decrypt+apply the
+  // callback phase — the publish phase rides the Python-side burst
+  // crossing
   fdm_plane *mplane;
   u8 scratch[2048];
+  // datagrams [rx_pos, rx_n) wait; the ones before were processed
+  i32 rx_n, rx_pos;
+  u32 rx_len[RX_CAP];
+  u8 rx_peer[RX_CAP][NET_PEER_KEY];
+  u8 *rx_buf;       // RX_CAP slots of RX_SLOT bytes
 };
 
-// Source-stage drain observe: net has no fdr_sweep epilogue, so the
-// socket sweep records its own crossing (drain hist + counters + the
-// decimated flight trail).
-static inline void net_obs_drain(NetCtx *c, u64 t0, i32 total) {
+// Source-stage observe: net has no fdr_sweep epilogue, so a crossing
+// records itself (its phase's histogram + counters + the decimated
+// flight trail): a plain-UDP socket sweep under the drain phase, a quic
+// sweep's decrypt+apply under the callback phase.
+static inline void net_obs_crossing(NetCtx *c, int phase, u64 t0, i32 total) {
   fdm_plane *pl = c->mplane;
   if (!pl || total <= 0) return;
   if (pl->flags & FDM_F_PH)
-    fdm_hist_obs(pl->met, &pl->ph[FDM_PH_DRAIN],
-                 (double)(fdm_now_ns() - t0));
+    fdm_hist_obs(pl->met, &pl->ph[phase], (double)(fdm_now_ns() - t0));
   fdm_ctr_add(pl, pl->c_frags_off, (u64)total);
   fdm_ctr_add(pl, pl->c_crossings_off, 1);
   if ((pl->crossings % FDM_FLIGHT_DECIMATE) == 0)
@@ -612,8 +637,10 @@ void *fdn_new(i32 max_conns, i32 reasm_depth) {
   c->depth = reasm_depth;
   c->slots = (Slot *)calloc((size_t)reasm_depth, sizeof(Slot));
   c->arena = (u8 *)malloc(OUT_ARENA_SZ);
-  if (!c->conns || !c->slots || !c->arena) {
-    free(c->conns); free(c->slots); free(c->arena); free(c);
+  c->rx_buf = (u8 *)malloc((size_t)RX_CAP * RX_SLOT);
+  if (!c->conns || !c->slots || !c->arena || !c->rx_buf) {
+    free(c->conns); free(c->slots); free(c->arena); free(c->rx_buf);
+    free(c);
     return NULL;
   }
   c->counters[C_AESNI] = (u64)g_aesni;
@@ -624,13 +651,14 @@ void *fdn_new(i32 max_conns, i32 reasm_depth) {
 void fdn_delete(void *ctx) {
   NetCtx *c = (NetCtx *)ctx;
   if (!c) return;
-  free(c->conns); free(c->slots); free(c->arena); free(c);
+  free(c->conns); free(c->slots); free(c->arena); free(c->rx_buf);
+  free(c);
 }
 
 // Install an ESTABLISHED connection's rx side.  ranges = 2*n_ranges i64
 // (the Python _RecvTracker state, so the dedup window starts coherent).
 // Returns the conn index, or -1 (table full / bad key).
-i32 fdn_conn_add(void *ctx, const u8 *dcid, u32 addr_id, const u8 *key,
+i32 fdn_conn_add(void *ctx, const u8 *dcid, const u8 *peer, const u8 *key,
                  const u8 *iv, const u8 *hp, const i64 *ranges,
                  i32 n_ranges, u64 rx_max_data, u64 rx_data_total) {
   NetCtx *c = (NetCtx *)ctx;
@@ -654,7 +682,7 @@ i32 fdn_conn_add(void *ctx, const u8 *dcid, u32 addr_id, const u8 *key,
   n->state = 1;
   n->gen = gen;
   n->dcid = k;
-  n->addr_id = addr_id;
+  memcpy(n->peer, peer, NET_PEER_KEY);
   n->rx_max_data = rx_max_data;
   n->rx_data_total = rx_data_total;
   n->rx_max_streams = 0;     // fdn_conn_streams sets both
@@ -681,10 +709,10 @@ void fdn_conn_remove(void *ctx, i32 idx) {
       c->slots[s].used = 0;
 }
 
-void fdn_conn_set_addr(void *ctx, i32 idx, u32 addr_id) {
+void fdn_conn_set_addr(void *ctx, i32 idx, const u8 *peer) {
   NetCtx *c = (NetCtx *)ctx;
   if (idx < 0 || idx >= c->cap || c->conns[idx].state != 1) return;
-  c->conns[idx].addr_id = addr_id;
+  memcpy(c->conns[idx].peer, peer, NET_PEER_KEY);
 }
 
 // Window sync from the authoritative Python conn: after an EV_WIN-driven
@@ -1062,21 +1090,18 @@ static int apply_frames(NetCtx *c, i32 ci, const u8 *p, size_t n,
 
 extern "C" {
 
-// One datagram, synchronously: 0 = consumed here (drain events/txns),
-// 1 = PUNT (run the Python lane on these exact bytes, in order),
-// 2 = dropped+counted here (dedup/bad packet — the Python lane would
-//     have dropped it the same way).
+// One datagram of a sweep: 0 = consumed here (its events and finished
+// transactions wait for the drain), 1 = PUNT (the stage runs the
+// Python lane on these exact bytes, in order), 2 = dropped+counted
+// here (dedup/bad packet — the Python lane would have dropped it the
+// same way).  The sweep has checked the headroom: a punt is decided
+// BEFORE any effect lands.
 static i32 fdn_datagram_inner(NetCtx *c, const u8 *data, i32 sz,
-                              u32 addr_id) {
+                              const u8 *peer) {
   c->counters[C_RX_DGRAM]++;
+  c->counters[C_RX_BYTES] += (u64)(sz > 0 ? sz : 0);
   if (sz <= 0) { c->counters[C_PUNT]++; return RC_PUNT; }
   if (data[0] & 0x80) {  // long header: handshake/control plane
-    c->counters[C_PUNT]++;
-    return RC_PUNT;
-  }
-  // headroom: a punt must be decidable BEFORE any effect lands
-  if (c->ev_n + 8 > EV_CAP || c->out_n + 8 > OUT_CAP ||
-      c->arena_used + 8 * NET_TXN_MTU > OUT_ARENA_SZ) {
     c->counters[C_PUNT]++;
     return RC_PUNT;
   }
@@ -1089,7 +1114,8 @@ static i32 fdn_datagram_inner(NetCtx *c, const u8 *data, i32 sz,
     return RC_PUNT;
   }
   NetConn *conn = &c->conns[ci];
-  if (conn->addr_id != addr_id) {  // migration: path validation is Python's
+  if (memcmp(conn->peer, peer, NET_PEER_KEY) != 0) {
+    // not the connection's home: migration, path validation is Python's
     c->counters[C_PUNT]++;
     return RC_PUNT;
   }
@@ -1162,24 +1188,101 @@ static i32 fdn_datagram_inner(NetCtx *c, const u8 *data, i32 sz,
   return RC_CONSUMED;
 }
 
-// One datagram, synchronously — the metrics-armed wrapper: the
-// decrypt+frame-apply span observes into the callback-phase histogram
-// (one crossing per datagram; this path already pays a syscall per
-// packet, so two clock reads are noise).
-i32 fdn_datagram(void *ctx, const u8 *data, i32 sz, u32 addr_id) {
+// A sweep's return: every datagram of the arena processed; or stopped
+// in front of one for want of headroom (drain, then call again); or
+// (>= 0) the arena slot of a datagram the C lane PUNTs, already
+// counted and stepped over: the stage drains, runs the Python lane on
+// that slot's bytes and calls again, so arrival order holds across a
+// punt.  The datagrams behind a stop stay in the arena, untouched.
+enum { SWEEP_DONE = -1, SWEEP_FULL = -2 };
+
+// The quic tile's crossing: with the arena empty and a socket given,
+// ONE recvmmsg takes what the socket holds, up to max_pkts (RX_CAP at
+// most), source addresses included; then the datagrams go through the
+// fast path in arrival order, leaving their events, finished
+// transactions and counters for ONE drain.  With datagrams still in
+// the arena (a resume; fd -1) nothing is received.  The decrypt+apply
+// span of the whole crossing observes into the callback-phase
+// histogram, as the per-datagram entry's did.
+i32 fdn_quic_sweep(void *ctx, i32 fd, i32 max_pkts) {
   NetCtx *c = (NetCtx *)ctx;
-  fdm_plane *pl = c->mplane;
-  if (!pl) return fdn_datagram_inner(c, data, sz, addr_id);
-  u64 t0 = fdm_now_ns();
-  i32 rc = fdn_datagram_inner(c, data, sz, addr_id);
-  if (pl->flags & FDM_F_PH)
-    fdm_hist_obs(pl->met, &pl->ph[FDM_PH_CB], (double)(fdm_now_ns() - t0));
-  fdm_ctr_add(pl, pl->c_frags_off, 1);
-  fdm_ctr_add(pl, pl->c_crossings_off, 1);
-  if ((pl->crossings % FDM_FLIGHT_DECIMATE) == 0)
-    fdm_flight(pl, FDM_EV_NSWEEP_DRAIN, 1);
-  pl->crossings++;
-  return rc;
+  if (c->rx_pos >= c->rx_n) {
+    c->rx_pos = c->rx_n = 0;
+#if defined(__linux__)
+    if (fd >= 0 && max_pkts > 0) {
+      struct mmsghdr msgs[RX_CAP];
+      struct iovec iovs[RX_CAP];
+      struct sockaddr_storage from[RX_CAP];
+      i32 want = max_pkts < RX_CAP ? max_pkts : RX_CAP;
+      memset(msgs, 0, sizeof(msgs[0]) * (size_t)want);
+      for (i32 i = 0; i < want; i++) {
+        iovs[i].iov_base = c->rx_buf + (size_t)i * RX_SLOT;
+        iovs[i].iov_len = RX_SLOT;
+        msgs[i].msg_hdr.msg_iov = &iovs[i];
+        msgs[i].msg_hdr.msg_iovlen = 1;
+        msgs[i].msg_hdr.msg_name = &from[i];
+        msgs[i].msg_hdr.msg_namelen = sizeof(from[i]);
+      }
+      i32 got = (i32)recvmmsg(fd, msgs, (unsigned)want, MSG_DONTWAIT, NULL);
+      for (i32 i = 0; i < got; i++) {
+        c->rx_len[i] = msgs[i].msg_len;  // a longer one is cut at RX_SLOT
+        u8 *k = c->rx_peer[i];
+        memset(k, 0, NET_PEER_KEY);
+        memcpy(k, &from[i].ss_family, 2);
+        if (from[i].ss_family == AF_INET)   // port | address follow
+          memcpy(k + 2, (const u8 *)&from[i] + 2, 6);
+      }
+      if (got > 0) c->rx_n = got;
+    }
+#else
+    (void)fd; (void)max_pkts;
+#endif
+  }
+  u64 t0 = c->mplane ? fdm_now_ns() : 0;
+  i32 first = c->rx_pos;
+  i32 ret = SWEEP_DONE;
+  while (c->rx_pos < c->rx_n) {
+    if (c->ev_n + 8 > EV_CAP || c->out_n + 8 > OUT_CAP ||
+        c->arena_used + 8 * NET_TXN_MTU > OUT_ARENA_SZ) {
+      ret = SWEEP_FULL;
+      break;
+    }
+    i32 i = c->rx_pos++;
+    if (fdn_datagram_inner(c, c->rx_buf + (size_t)i * RX_SLOT,
+                           (i32)c->rx_len[i], c->rx_peer[i]) == RC_PUNT) {
+      ret = i;
+      break;
+    }
+  }
+  net_obs_crossing(c, FDM_PH_CB, t0, c->rx_pos - first);
+  return ret;
+}
+
+// A virtual socket's datagram (the tests', the chaos wire's) put behind
+// what the arena holds, for the next fdn_quic_sweep(ctx, -1, ...).
+// -> 0, or -1: the arena is full.
+i32 fdn_rx_stage(void *ctx, const u8 *data, i32 sz, const u8 *peer) {
+  NetCtx *c = (NetCtx *)ctx;
+  if (c->rx_pos >= c->rx_n) c->rx_pos = c->rx_n = 0;
+  if (c->rx_n >= RX_CAP || sz < 0) return -1;
+  i32 i = c->rx_n++;
+  if (sz > RX_SLOT) sz = RX_SLOT;     // recvfrom(2048) cuts it the same
+  memcpy(c->rx_buf + (size_t)i * RX_SLOT, data, (size_t)sz);
+  c->rx_len[i] = (u32)sz;
+  memcpy(c->rx_peer[i], peer, NET_PEER_KEY);
+  return 0;
+}
+
+// Arena slot i: its bytes, length and peer key (a punted datagram's).
+u8 *fdn_rx_ptr(void *ctx, i32 i) {
+  return ((NetCtx *)ctx)->rx_buf + (size_t)i * RX_SLOT;
+}
+i32 fdn_rx_len(void *ctx, i32 i) { return (i32)((NetCtx *)ctx)->rx_len[i]; }
+u8 *fdn_rx_peer(void *ctx, i32 i) { return ((NetCtx *)ctx)->rx_peer[i]; }
+// datagrams the arena still holds unprocessed
+i32 fdn_rx_pending(void *ctx) {
+  NetCtx *c = (NetCtx *)ctx;
+  return c->rx_n - c->rx_pos;
 }
 
 // Arm/disarm the shm metrics plane (ISSUE 20).
@@ -1236,7 +1339,7 @@ i32 fdn_udp_sweep(void *ctx, i32 fd, i32 max_pkts) {
     total += got;
     if (got < want) break;  // socket drained mid-batch
   }
-  net_obs_drain(c, t0, total);
+  net_obs_crossing(c, FDM_PH_DRAIN, t0, total);
   return total;
 #else
   (void)ctx; (void)fd; (void)max_pkts;
@@ -1275,7 +1378,7 @@ i32 fdn_udp_sweep_scalar(void *ctx, i32 fd, i32 max_pkts) {
     memcpy(c->arena + c->arena_used, buf, (size_t)got);
     c->arena_used += (u64)got;
   }
-  net_obs_drain(c, t0, total);
+  net_obs_crossing(c, FDM_PH_DRAIN, t0, total);
   return total;
 #endif
 }

@@ -62,8 +62,9 @@ def _make_stage(native: bool, monkeypatch, **kw):
 
 class _Driver:
     """In-process QUIC client against a ChaosSock'd stage: datagrams are
-    injected straight into _on_datagram, responses read back off the
-    virtual socket — the chaos population's wire, without loss."""
+    injected straight into _on_datagram (on the native lane: staged into
+    the receive arena and swept, a sweep of one), responses read back
+    off the virtual socket — the chaos population's wire, without loss."""
 
     def __init__(self, stage, addr, *, mangle=None):
         from firedancer_tpu.ops.ref import ed25519_ref as ref
