@@ -6,7 +6,9 @@ action here: the benchmark is `python3 benchmarks/run.py` (BENCHMARK.json).
 
     run        build the leader pipeline from a TOML config and drive it
                (--processes: one supervised OS process per stage;
-               --sandbox: seccomp jail each stage); monitor table on exit
+               --sandbox: seccomp jail each stage); monitor table on exit.
+               [layout] replay_stage_count = 1 is the follower's verify
+               phase instead (config/replay-verify-v5e.toml)
     monitor    live per-stage TUI attached to a running topology
     ready      block until every stage of a running topology is RUN
     metrics    Prometheus scrape surface over a running topology's shm
@@ -59,6 +61,9 @@ def _load_cfg(args):
 
 
 def cmd_run(args) -> int:
+    cfg = _load_cfg(args)
+    if cfg.layout.replay_stage_count:
+        return _run_replay(args, cfg)
     if args.processes:
         # the launching parent never initialises a backend: the verify
         # child owns the chip (models/leader_topo.build_verify)
@@ -167,6 +172,72 @@ def _run_processes(args) -> int:
         h.halt()
         return 0 if ok and n_exec == args.txns else 1
     finally:
+        h.close()
+
+
+def _run_replay(args, cfg) -> int:
+    """The follower's verify phase (layout.replay_stage_count = 1):
+    replaysrc -> verify0 -> replayout over entry batches, from
+    build_replay_topology_from_config — a process a tile with
+    --processes, else the same topology's stages held on this process's
+    one thread (the cooperative form).  --txns is rounded up to whole
+    slots of replay.slot_txns.  Done: every slot has its verdict; exit
+    0 when the slots offered with a flipped signature bit, and no
+    others, are dead."""
+    from firedancer_tpu.models.leader_topo import (
+        build_replay_topology_from_config,
+    )
+    from firedancer_tpu.runtime import topo as ft
+
+    r = cfg.replay
+    n_slots = max(1, -(-args.txns // r.slot_txns))
+    n_dead = n_slots // r.dead_one_in_slots if r.dead_one_in_slots else 0
+    sandbox = {"rlimits": {"nofile": 512}} if args.sandbox else None
+    topo = build_replay_topology_from_config(
+        cfg, n_slots=n_slots, pool_size=min(args.txns, 4096),
+        n_payers=RUN_PAYERS, verify_cpu=args.cpu, sandbox=sandbox)
+    names = [s.name for s in topo.stages]
+    h = ft.launch(topo, held=() if args.processes else tuple(names))
+    stages: list = []
+    try:
+        print(f"# replay verify: {n_slots} slots of {r.slot_txns} txns, "
+              f"device batch {cfg.verify.batch}; "
+              + (f"{len(h.procs)} stage processes" if args.processes
+                 else "one thread") + f"; descriptor fdtpu_run_{h.uid}.json",
+              file=sys.stderr)
+        v = h.met_views["verify0"][0]
+
+        def verdicts() -> int:
+            return sum(v.get(k) for k in ("slots_live", "slots_dead_sig",
+                                          "slots_dead_poh",
+                                          "slots_dead_parse"))
+
+        t0 = time.time()
+        if args.processes:
+            ok = h.supervise(until=lambda h: verdicts() >= n_slots,
+                             timeout_s=1200, heartbeat_timeout_s=300)
+        else:
+            stages += [h.build_held(n) for n in names]
+            t_end = time.monotonic() + 1200
+            while verdicts() < n_slots and time.monotonic() < t_end:
+                for _ in range(64):
+                    for s in stages:
+                        s.run_once()
+                for s in stages:
+                    s.sync_counters()
+            ok = verdicts() >= n_slots
+        dt = time.time() - t0
+        print(h.format_monitor())
+        live, dead = v.get("slots_live"), v.get("slots_dead_sig")
+        print(f"# {live} slots live, {dead} dead by a signature, "
+              f"{v.get('entry_txn_out')} txns verified and handed on in "
+              f"{dt:.2f}s (boot and compile included)")
+        h.halt()
+        return 0 if ok and live == n_slots - n_dead and dead == n_dead else 1
+    finally:
+        for s in stages:        # held stages drop their views first
+            s.ins, s.outs = [], []
+            s.drop_native_views()
         h.close()
 
 

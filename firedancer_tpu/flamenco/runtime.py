@@ -1186,8 +1186,11 @@ def execute_block(
     status_cache=None,
     ancestors: set[int] | None = None,
     slot_hashes: list[tuple[int, bytes]] | None = None,
+    parsed: list | None = None,
 ) -> BlockResult:
     """Execute a block's txns on a fresh funk fork; compute the bank hash.
+    `parsed`, the (payload, Txn) pairs of `txns`, hands over a parse the
+    caller has made already (replay_block's).
 
     The fork stays in-prep (consensus decides) unless publish=True.
     status_cache (flamenco/blockstore.StatusCache) arms the two
@@ -1195,12 +1198,13 @@ def execute_block(
     age) and cross-slot duplicate-signature rejection (filtered by
     `ancestors` when given — fork awareness).  Executed signatures are
     recorded, and this slot's poh_hash registers as a usable blockhash."""
-    parsed = []
-    for p in txns:
-        t = ft.txn_parse(p)
-        if t is None:
-            raise ValueError("malformed txn in block")
-        parsed.append((p, t))
+    if parsed is None:
+        parsed = []
+        for p in txns:
+            t = ft.txn_parse(p)
+            if t is None:
+                raise ValueError("malformed txn in block")
+            parsed.append((p, t))
     sx = SlotExecution(
         funk, slot=slot, parent_bank_hash=parent_bank_hash,
         parent_xid=parent_xid, status_cache=status_cache,
@@ -1244,7 +1248,14 @@ def replay_block(
     execute the block (fd_replay's after_frag shape).  None = PoH fraud."""
     from firedancer_tpu.runtime import poh as fpoh
 
-    ok, _segments = fpoh.replay_entries(poh_seed, entries)
+    # one parse a transaction: its first signature for the chain's
+    # mixins, the rest for execution
+    parsed = [[(p, ft.txn_parse(p)) for p in txs] for _, _, txs in entries]
+    if any(t is None for ent in parsed for _, t in ent):
+        return None
+    ok, _segments = fpoh.replay_entries(
+        poh_seed, entries,
+        first_sigs=[[t.signatures(p)[0] for p, t in ent] for ent in parsed])
     if not ok:
         return None
     txns = [p for _, _, txs in entries for p in txs]
@@ -1262,4 +1273,5 @@ def replay_block(
         # the replayer's view of recent bank hashes — votes in this
         # block validate against it (empty would reject every vote)
         slot_hashes=slot_hashes,
+        parsed=[pt for ent in parsed for pt in ent],
     )

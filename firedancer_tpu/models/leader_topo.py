@@ -78,16 +78,25 @@ def build_benchg(links, cnc, *, pool_size, n_txns, n_payers=8, out="gv"):
 
 
 def build_verify(links, cnc, *, batch, max_msg_len=256, precomputed=False,
-                 cpu=False, batch_deadline_s=0.002):
+                 cpu=False, batch_deadline_s=0.002, replay=False):
+    """The verify tile; `replay`: the follower's, which takes entry
+    batches from "rv" and hands them on to "vo"
+    (runtime/replay_verify.py) where the leader's takes transactions
+    from "gv" and hands them to "vd"."""
     from firedancer_tpu.utils.platform import select_device
 
     dev = None if precomputed else select_device(cpu)
-    from firedancer_tpu.runtime.verify import VerifyStage
-
-    stage = VerifyStage(
+    if replay:
+        from firedancer_tpu.runtime.replay_verify import (
+            ReplayVerifyStage as cls,
+        )
+    else:
+        from firedancer_tpu.runtime.verify import VerifyStage as cls
+    lin, lout, lazy = ("rv", "vo", 8) if replay else ("gv", "vd", 32)
+    stage = cls(
         "verify0",
-        ins=[shm.make_consumer(links["gv"], lazy=32)],
-        outs=[shm.make_producer(links["vd"])],
+        ins=[shm.make_consumer(links[lin], lazy=lazy)],
+        outs=[shm.make_producer(links[lout])],
         cnc=cnc,
         batch=batch,
         max_msg_len=max_msg_len,
@@ -676,6 +685,86 @@ def build_quic_topology_from_config(
                ins=["gv"], outs=["vd"], schema=VerifyStage.metrics_schema())
     topo.stage("out", build_out, sandbox=sb, ins=["vd"],
                schema=OutStage.metrics_schema())
+    return topo
+
+
+def build_replay_source(links, cnc, *, pool_size, n_payers, n_slots,
+                        slot_txns, corrupt_slots, shape):
+    from firedancer_tpu.runtime.benchg import gen_transfer_pool
+    from firedancer_tpu.runtime.replay_verify import ReplaySourceStage
+
+    return ReplaySourceStage(
+        gen_transfer_pool(pool_size, n_payers=n_payers), "replaysrc",
+        outs=[shm.make_producer(links["rv"])], cnc=cnc, n_slots=n_slots,
+        slot_txns=slot_txns, corrupt_slots=corrupt_slots, shape=shape)
+
+
+def build_replay_out(links, cnc):
+    from firedancer_tpu.runtime.replay_verify import ReplayOutStage
+
+    return ReplayOutStage("replayout",
+                          ins=[shm.make_consumer(links["vo"], lazy=16)],
+                          cnc=cnc)
+
+
+def build_replay_topology_from_config(
+    cfg, *, n_slots: int = 2, pool_size: int = 64, n_payers: int = 8,
+    sandbox: dict | None = None, verify_precomputed: bool = False,
+    verify_cpu: bool = False,
+) -> ft.Topology:
+    """The follower's verify phase as a process topology, from the
+    typed Config (layout.replay_stage_count = 1):
+
+        replaysrc -> rv -> verify0 -> vo -> replayout
+
+    The source tile offers `n_slots` slots as [replay] and [poh]
+    describe them — replay.slot_txns transfers a slot (a pool of
+    `pool_size`, replayed) in entries of replay.txns_per_entry and
+    poh.ticks_per_slot ticks of poh.hashes_per_tick hashes, cut into
+    entry batches of replay.entries_per_batch entries, a frag each —
+    with one flipped signature bit in every
+    replay.dead_one_in_slots-th slot.  verify0 is the replay verify
+    stage (runtime/replay_verify.py) at [verify]'s device batch, row
+    bound and deadline: VerifyStage's batch life and the one program
+    behind an intake of entry batches.  replayout stands where a
+    replay tile executes: it counts entry batches and verdicts.  The
+    ring in front of verify0 is verify.receive_buffer_depth deep, the
+    one behind replay.out_depth, both replay.frag_mtu wide."""
+    from firedancer_tpu.runtime.replay_verify import (
+        ReplayOutStage, ReplaySourceStage, ReplayVerifyStage,
+    )
+
+    if cfg.layout.replay_stage_count != 1:
+        raise ValueError(
+            "the replay topology needs layout.replay_stage_count = 1 (0 is "
+            "the leader's side: build_leader_topology_from_config)")
+    if cfg.verify.devices != 1:
+        raise ValueError("replay with verify.devices > 1: the replay "
+                         "topology runs its verify tile on one chip")
+    r = cfg.replay
+    topo = ft.Topology()
+    topo.link("rv", depth=cfg.verify.receive_buffer_depth, mtu=r.frag_mtu)
+    topo.link("vo", depth=r.out_depth, mtu=r.frag_mtu)
+    every = r.dead_one_in_slots
+    topo.stage(
+        "replaysrc", build_replay_source, pool_size=pool_size,
+        n_payers=n_payers, n_slots=n_slots, slot_txns=r.slot_txns,
+        corrupt_slots=tuple(range(every - 1, n_slots, every)) if every
+        else (),
+        shape=dict(txns_per_entry=r.txns_per_entry,
+                   entries_per_batch=r.entries_per_batch,
+                   ticks_per_slot=cfg.poh.ticks_per_slot,
+                   hashes_per_tick=cfg.poh.hashes_per_tick),
+        sandbox=sandbox, outs=["rv"],
+        schema=ReplaySourceStage.metrics_schema())
+    topo.stage("verify0", build_verify, replay=True, batch=cfg.verify.batch,
+               max_msg_len=cfg.verify.max_msg_len, sandbox=sandbox,
+               precomputed=verify_precomputed, cpu=verify_cpu,
+               batch_deadline_s=cfg.verify.batch_deadline_ms / 1e3,
+               ins=["rv"], outs=["vo"],
+               schema=ReplayVerifyStage.metrics_schema())
+    topo.stage("replayout", build_replay_out, sandbox=sandbox, ins=["vo"],
+               schema=ReplayOutStage.metrics_schema())
     return topo
 
 

@@ -518,6 +518,17 @@ class Validator:
         self.maybe_publish()
 
     def replay_slot(self, slot: int, parent_slot: int) -> bool:
+        """Replay a stored slot on its parent's fork: the PoH chain over
+        its entries, then execution (flamenco/runtime.replay_block, one
+        parse a transaction for both).  The block's SIGNATURES are
+        still checked here by `ops/ref` in Python, one at a time as the
+        executor meets them — the cluster harness's toy sizes.  The
+        stage that does it at rate, before execution, is
+        runtime/replay_verify.ReplayVerifyStage (entry batches in,
+        every signature on the device in 16,384-lane batches, a slot
+        dead from its first failing entry batch; deployment
+        `replay-verify-v5e`): this method does not go through it yet
+        (ROADMAP Queue 2: execution behind the stage)."""
         parent = self.forks.get(parent_slot)
         entries = [parse_entry(e) for e in deshred_entry_batch(
             self.blockstore.entry_batch_bytes(slot))]

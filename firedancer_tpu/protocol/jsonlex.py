@@ -18,6 +18,9 @@ from __future__ import annotations
 MAX_DEPTH = 64
 MAX_LEN = 16 * 1024 * 1024
 MAX_NUMBER_DIGITS = 400  # int(text) past ~4300 digits raises ValueError
+# JSON's digits are ASCII: str.isdigit() also takes the likes of "²",
+# which int() then refuses with a ValueError no caller expects
+_DIGITS = "0123456789"
 # on CPython >= 3.11; the contract here is JsonError for any bad input
 
 _WS = " \t\n\r"
@@ -184,31 +187,31 @@ class _Parser:
         s = self.s
         if self.i < self.n and s[self.i] == "-":
             self.i += 1
-        if self.i >= self.n or not s[self.i].isdigit():
+        if self.i >= self.n or s[self.i] not in _DIGITS:
             self.err("bad number")
         if s[self.i] == "0":
             self.i += 1
-            if self.i < self.n and s[self.i].isdigit():
+            if self.i < self.n and s[self.i] in _DIGITS:
                 self.err("leading zero")
         else:
-            while self.i < self.n and s[self.i].isdigit():
+            while self.i < self.n and s[self.i] in _DIGITS:
                 self.i += 1
         is_float = False
         if self.i < self.n and s[self.i] == ".":
             is_float = True
             self.i += 1
-            if self.i >= self.n or not s[self.i].isdigit():
+            if self.i >= self.n or s[self.i] not in _DIGITS:
                 self.err("bad fraction")
-            while self.i < self.n and s[self.i].isdigit():
+            while self.i < self.n and s[self.i] in _DIGITS:
                 self.i += 1
         if self.i < self.n and s[self.i] in "eE":
             is_float = True
             self.i += 1
             if self.i < self.n and s[self.i] in "+-":
                 self.i += 1
-            if self.i >= self.n or not s[self.i].isdigit():
+            if self.i >= self.n or s[self.i] not in _DIGITS:
                 self.err("bad exponent")
-            while self.i < self.n and s[self.i].isdigit():
+            while self.i < self.n and s[self.i] in _DIGITS:
                 self.i += 1
         text = s[start : self.i]
         if len(text) > MAX_NUMBER_DIGITS:

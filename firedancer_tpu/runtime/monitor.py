@@ -330,6 +330,7 @@ class MonitorSession:
             row["funk"] = fm.funk_row(j.registry)
             row["dedup"] = fm.dedup_row(j.registry)
             row["front"] = fm.front_row(j.registry)
+            row["replay"] = fm.replay_row(j.registry)
             out.append(row)
         for logical, js in groups.items():
             sigs = [j.cnc.signal for j in js]
@@ -484,6 +485,14 @@ class MonitorSession:
                 # peer's credit held it (cumulative)
                 lines.append(f"{r['stage']}: front " + " ".join(
                     f"{k}={v:,}" for k, v in front.items()))
+            replay = r.get("replay")
+            if replay:
+                # the replay verify stage: entry batches in and out,
+                # the slots' verdicts by reason, what was skipped of
+                # dead slots and the lanes spent on it, the PoH check
+                # and the unpack as cumulative ns
+                lines.append(f"{r['stage']}: replay " + " ".join(
+                    f"{k}={v:,}" for k, v in replay.items()))
         # the bank tiles' one account store: the lock they meet at,
         # what each took from the segment after another tile's write,
         # and what pack gave each (cumulative)

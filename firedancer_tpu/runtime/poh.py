@@ -63,24 +63,51 @@ def verify_segments_host(
     return [poh_append(s, n) == e for s, n, e in zip(starts, counts, ends)]
 
 
+def entry_mixin(first_sigs: list[bytes]) -> bytes:
+    """A transaction entry's mixin: sha256 over its transactions' first
+    signatures (the bank stage's entry hash)."""
+    return hashlib.sha256(b"".join(first_sigs)).digest()
+
+
+def check_entry(h: bytes, num_hashes: int, expect: bytes,
+                first_sigs: list[bytes]) -> tuple[bool, bytes]:
+    """One entry of a received block against the chain standing at `h`:
+    `num_hashes` appends, the last of them the mixin over `first_sigs`
+    for a transaction entry (empty: a tick), compare.  -> (the entry's
+    hash follows, the chain after it).  A transaction entry consumes at
+    least its own mixin hash: `num_hashes` 0 would let a block deflate
+    the clock, and does not follow.  The per-entry check of the replay
+    verify stage (runtime/replay_verify.py), fed from its own parse."""
+    if first_sigs:
+        if num_hashes < 1:
+            return False, h
+        h = poh_mixin(poh_append(h, num_hashes - 1), entry_mixin(first_sigs))
+    else:
+        h = poh_append(h, num_hashes)
+    return h == expect, h
+
+
 def replay_entries(
-    seed: bytes, entries: list[tuple[int, bytes, list[bytes]]]
+    seed: bytes, entries: list[tuple[int, bytes, list[bytes]]],
+    first_sigs: list[list[bytes]] | None = None,
 ) -> tuple[bool, list[tuple[bytes, int, bytes]]]:
     """Re-run the PoH chain over wire entries (num_hashes, hash, txns) —
     the validation-side check that a received block's clock is honest
     (what the reference's replay does before executing a slot).
 
     The mixin for a txn entry is sha256 over the txns' first signatures
-    (matching the bank stage's entry hash).  Returns (ok, segments) where
-    segments are the pure append runs (start, n, end) suitable for batched
-    TPU verification via verify_segments_tpu.
+    (matching the bank stage's entry hash).  `first_sigs`, one list an
+    entry, hands them over from a parse the caller has made already
+    (flamenco/runtime.replay_block parses a block once, for this and
+    for execution); without it every transaction is parsed here.
+    Returns (ok, segments) where segments are the pure append runs
+    (start, n, end) suitable for batched TPU verification via
+    verify_segments_tpu.
     """
-    from firedancer_tpu.protocol import txn as ft
-
     h = seed
     segments = []
     ok = True
-    for num_hashes, expect, txns in entries:
+    for k, (num_hashes, expect, txns) in enumerate(entries):
         if txns and num_hashes < 1:
             # a txn entry consumes at least its own mixin hash; accepting
             # num_hashes=0 would let a block deflate the clock
@@ -91,13 +118,18 @@ def replay_entries(
         if n_append:
             segments.append((start, n_append, h))
         if txns:
-            sigs = []
-            for p in txns:
-                t = ft.txn_parse(p)
-                if t is None:
-                    return False, segments
-                sigs.append(t.signatures(p)[0])
-            h = poh_mixin(h, hashlib.sha256(b"".join(sigs)).digest())
+            if first_sigs is not None:
+                sigs = first_sigs[k]
+            else:
+                from firedancer_tpu.protocol import txn as ft
+
+                sigs = []
+                for p in txns:
+                    t = ft.txn_parse(p)
+                    if t is None:
+                        return False, segments
+                    sigs.append(t.signatures(p)[0])
+            h = poh_mixin(h, entry_mixin(sigs))
         if h != expect:
             ok = False
     return ok, segments

@@ -194,6 +194,11 @@ def _stage_block(mets: dict, records: list) -> dict:
     front = fm.front_row(mets)
     if front:
         block["front"] = front
+    # a replay verify stage: entry batches, verdicts, what dead slots
+    # cost, the PoH check's and the unpack's ns
+    replay = fm.replay_row(mets)
+    if replay:
+        block["replay"] = replay
     return block
 
 

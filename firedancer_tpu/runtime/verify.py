@@ -535,7 +535,8 @@ class VerifyStage(Stage):
         self._sweep_client = None
         # (slot, n_elems, n_txn, result, life)
         self._nv_inflight: list = []
-        self._nv_emit: list = []  # [slot, frame table, published idx, life]
+        # [slot, arena address, frame table, rows published, life]
+        self._nv_emit: list = []
         # the open batch (named by its C-side open stamp) that was kept
         # open past its deadline, and its _HELD_* marks
         self._nv_held = (0, 0)
@@ -545,8 +546,11 @@ class VerifyStage(Stage):
         # evidence that its thread leads the chip (_waits_for_place sets
         # it, the reap and _count_dispatch end it; _window_open reads it)
         self._full_waited = False
+        # unasked, the C intake arms for a class that brings one
+        # (VerifyStage's own; a subclass with an intake of its own); a
+        # test's subclass stays on the Python lane unless it asks
         want_native = (native_client if native_client is not None
-                       else type(self) is VerifyStage)
+                       else "_new_sweep_client" in vars(type(self)))
         if want_native:
             # structural preconditions, each named so native_client=True
             # (the "required" contract) can say exactly what blocked it
@@ -561,19 +565,16 @@ class VerifyStage(Stage):
                 blocker = "not every input is a native-ring consumer"
             elif type(self.outs[0]).__name__ != "NativeProducer":
                 blocker = "the output is not a native-ring producer"
-            elif self.outs[0].link.mtu < _NATIVE_FRAME_MTU:
+            elif self.outs[0].link.mtu < self._native_frame_mtu():
                 blocker = (f"out link mtu {self.outs[0].link.mtu} <"
-                           f" {_NATIVE_FRAME_MTU} (frame headroom)")
+                           f" {self._native_frame_mtu()} (frame headroom)")
             if blocker is None:
                 try:
                     if not vn.available():
                         raise vn.NativeUnavailable(
                             "toolchain missing or FDTPU_NATIVE_VERIFY=0")
-                    self._sweep_client = vn.StageClient(
-                        shard_idx=shard_idx, shard_cnt=shard_cnt,
-                        batch=batch, max_msg_len=max_msg_len,
-                        n_slots=self.max_inflight + 2,
-                    )
+                    self._sweep_client = self._new_sweep_client(
+                        self.max_inflight + 2)
                 except vn.NativeUnavailable as e:
                     if native_client:
                         raise RuntimeError(
@@ -583,6 +584,17 @@ class VerifyStage(Stage):
                 raise RuntimeError(
                     f"native_client=True but the stage cannot arm the"
                     f" sweep client: {blocker}")
+
+    # -- which C intake (a subclass with one of its own overrides both) -------
+
+    def _native_frame_mtu(self) -> int:
+        """The least mtu of the out link the C intake's frames need."""
+        return _NATIVE_FRAME_MTU
+
+    def _new_sweep_client(self, n_slots: int):
+        return vn.StageClient(
+            shard_idx=self.shard_idx, shard_cnt=self.shard_cnt,
+            batch=self.batch, max_msg_len=self.max_msg_len, n_slots=n_slots)
 
     # -- observability ------------------------------------------------------
 
@@ -768,9 +780,10 @@ class VerifyStage(Stage):
             return None
         return sigs, msg, signers, t, packed
 
-    def _accumulate(self, got, payload: bytes, tsorig: int) -> None:
+    def _accumulate(self, got, payload: bytes, tsorig: int) -> _Life:
         """Batch one intaken txn (the ONE accumulation implementation —
-        after_frag and the drain-table sweep_frags path both land here)."""
+        after_frag and the drain-table sweep_frags path both land here).
+        -> the stamps of the batch that took it (they name the batch)."""
         sigs, msg, signers, t, packed = got
         self.metrics.observe("msg_len", len(msg))
         slots = self._signer_slots(signers)
@@ -794,8 +807,10 @@ class VerifyStage(Stage):
         acc.payloads.append(payload)
         acc.descs.append((t, packed))
         acc.tsorigs.append(tsorig)
+        life = acc.life
         if len(acc.elems) >= self.batch:
             self._close_batch(acc)
+        return life
 
     def after_frag(self, in_idx: int, meta, payload: bytes) -> None:
         c = self._sweep_client
@@ -1277,7 +1292,6 @@ class VerifyStage(Stage):
         self._count_dispatch(n_elems, close, len(self._nv_inflight))
 
     def _nv_drain(self, block: bool) -> None:
-        c = self._sweep_client
         while self._nv_inflight:
             slot, n_elems, n_txn, result, life = self._nv_inflight[0]
             if not block and not self._mask_ready(result):
@@ -1288,31 +1302,56 @@ class VerifyStage(Stage):
                 self._nv_inflight.pop(0)
                 self._window_freed()
                 self.trace(fm.EV_BATCH_COMPLETE, n_elems)
-                views = c.slots[slot]
-                frames = views.frames[:n_txn]
-                if mask[:n_elems].all():
-                    tbl = frames
-                    kept = n_txn
-                else:
-                    ranges = views.ranges[:n_txn].astype(np.int64)
-                    ok_txn = np.minimum.reduceat(
-                        mask[:n_elems].astype(np.uint8), ranges[:, 0]
-                    ).astype(bool)
-                    tbl = np.ascontiguousarray(frames[ok_txn])
-                    kept = int(ok_txn.sum())
-                    self.metrics.inc("verify_fail", n_txn - kept)
-                    lanes = ranges[~ok_txn]
-                    self.metrics.inc(fm.VERIFY_FAIL_ELEMS,
-                                     int((lanes[:, 1] - lanes[:, 0]).sum()))
+                ent = self._nv_reaped(slot, n_elems, n_txn, mask, life)
             self._phase_end(life, PH_REAP)
-            if kept:
-                self.metrics.inc("txn_verified", kept)
-                self._nv_emit.append([slot, tbl, 0, life])
+            if ent is not None:
+                self._nv_emit.append(ent)
             else:
-                c.release(slot)
                 self._phase_end(life, PH_PUBLISH)
             if block:
                 break
+
+    @staticmethod
+    def _passed_txns(mask: np.ndarray, n_elems: int, ranges: np.ndarray):
+        """-> None when every real lane passed, else bool per txn: does
+        every element of its range ([start, end) rows of `ranges`)
+        pass (a txn fails whole)."""
+        if mask[:n_elems].all():
+            return None
+        return np.minimum.reduceat(
+            mask[:n_elems].astype(np.uint8),
+            ranges[:, 0].astype(np.int64)).astype(bool)
+
+    def _nv_reaped(self, slot: int, n_elems: int, n_txn: int,
+                   mask: np.ndarray, life: _Life):
+        """What a reaped batch's mask means, on the native lane: the
+        frames of the transactions that passed, as an entry of the emit
+        queue — [the slot whose arena holds them (released when they
+        are out), the arena's address, the frame table, rows published,
+        the batch's stamps] — or None when nothing leaves (the slot is
+        released here)."""
+        c = self._sweep_client
+        views = c.slots[slot]
+        tbl = views.frames[:n_txn]
+        kept = n_txn
+        ok_txn = self._passed_txns(mask, n_elems, views.ranges[:n_txn])
+        if ok_txn is not None:
+            tbl = np.ascontiguousarray(tbl[ok_txn])
+            kept = int(ok_txn.sum())
+            self.metrics.inc("verify_fail", n_txn - kept)
+            lanes = views.ranges[:n_txn][~ok_txn].astype(np.int64)
+            self.metrics.inc(fm.VERIFY_FAIL_ELEMS,
+                             int((lanes[:, 1] - lanes[:, 0]).sum()))
+        if not kept:
+            c.release(slot)
+            return None
+        self.metrics.inc("txn_verified", kept)
+        return [slot, views.arena_ptr, tbl, 0, life]
+
+    def _nv_published(self, slot) -> None:
+        """An emit-queue entry's last frame is out: its slot returns
+        to the intake ring."""
+        self._sweep_client.release(slot)
 
     def _nv_publish(self) -> None:
         """Publish reaped frame tables head-first (global emit order is
@@ -1322,7 +1361,6 @@ class VerifyStage(Stage):
         the intake ring only when its frames are fully out."""
         if not self._nv_emit or not self.outs:
             return
-        c = self._sweep_client
         p = self.outs[0]
         # the reap publishes OUTSIDE the sweep crossing: route the burst
         # through the metrics plane so its duration still lands in the
@@ -1330,18 +1368,18 @@ class VerifyStage(Stage):
         plane = self._native_plane()
         while self._nv_emit:
             ent = self._nv_emit[0]
-            slot, tbl, pos, life = ent
+            slot, arena_ptr, tbl, pos, life = ent
             sub = tbl[pos:]
             with self._span("verify.publish", life):
-                done = p.publish_burst_raw(c.slots[slot].arena_ptr,
-                                           sub, len(sub), plane)
+                done = p.publish_burst_raw(arena_ptr, sub, len(sub), plane)
             if done:
                 self.metrics.inc("frags_out", done)
-            ent[2] = pos + done
-            if ent[2] == len(tbl):
+            ent[3] = pos + done
+            if ent[3] == len(tbl):
                 self._nv_emit.pop(0)
-                c.release(slot)
-                self._phase_end(life, PH_PUBLISH)
+                self._nv_published(slot)
+                if life is not None:
+                    self._phase_end(life, PH_PUBLISH)
             else:
                 self.metrics.inc("backpressure", len(sub) - done)
                 break
@@ -1557,6 +1595,11 @@ class VerifyStage(Stage):
         self._deadline_close()
         self._pump_submits()
         self.trace(fm.EV_BATCH_COMPLETE, head.n_elems)
+        return self._reaped_txns(head, mask)
+
+    def _reaped_txns(self, head, mask: np.ndarray) -> list:
+        """What a reaped batch's mask means, on the Python lane: the
+        frames of the transactions that passed."""
         # honest traffic overwhelmingly passes whole batches: one
         # reduction over the fetched mask's real lanes decides the
         # common case instead of a numpy slice + reduction per txn

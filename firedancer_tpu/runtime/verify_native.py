@@ -73,6 +73,22 @@ def _load():
                      "fdv_slot_ranges", "fdv_slot_arena"):
             getattr(lib, name).argtypes = [vp, u64]
             getattr(lib, name).restype = vp
+        # the replay intake (entry batches in)
+        lib.fdv_replay_new.argtypes = [u64, u64, u64, vp, u64, u64]
+        lib.fdv_replay_new.restype = vp
+        lib.fdv_replay_cb.restype = ctypes.c_int  # resolved by ADDRESS only
+        lib.fdv_replay_append.argtypes = [vp, ctypes.c_char_p, u64, u64]
+        lib.fdv_replay_append.restype = ctypes.c_int
+        lib.fdv_replay_reap.argtypes = [vp, u64, vp, u64]
+        lib.fdv_replay_collect.argtypes = [vp, ctypes.POINTER(u64)]
+        lib.fdv_replay_collect.restype = u64
+        lib.fdv_replay_out_done.argtypes = [vp, u64]
+        lib.fdv_replay_held.argtypes = [vp]
+        lib.fdv_replay_held.restype = u64
+        for name in ("fdv_replay_arena", "fdv_replay_out_tbl",
+                     "fdv_replay_counters_ptr"):
+            getattr(lib, name).argtypes = [vp]
+            getattr(lib, name).restype = vp
         _lib = lib
     return _lib
 
@@ -114,6 +130,17 @@ _TAIL_FLAGS = 0
 _TAIL_OPEN_ELEMS = 1
 _TAIL_OPEN_NS = 2
 _TAIL_COUNTERS = 3
+
+# the replay intake's counters, in fd_verify.cpp's fdv_replay
+# declaration order; names match runtime/replay_verify's schema
+_REPLAY_COUNTERS = (
+    "entry_batches_in", "entries_in", "slots_live", "slots_dead_sig",
+    "slots_dead_poh", "slots_dead_parse", "dead_slot_txn_skipped",
+    "dead_slot_lanes_spent", "poh_hashes", "poh_check_ns",
+    "entry_unpack_ns", "entry_batches_out", "entry_txn_out",
+    "entry_txn_rejected", "verify_fail", "verify_fail_elems")
+_RP_OUT_CAP = 1024      # fd_verify.cpp RP_OUT_CAP: frame-table rows
+_RP_FRAG_MAX = 65536    # fd_verify.cpp RP_FRAG_MAX: the in link's mtu
 
 # (state, n_elems, n_txn, arena_off, opened_ns, sealed_ns, close) per slot
 _META_NCOL = 7
@@ -222,12 +249,11 @@ class StageClient:
         self.batch = batch
         self.max_msg_len = max_msg_len
         self.n_slots = n_slots
-        self._h = lib.fdv_stage_new(shard_idx, shard_cnt, batch,
-                                    max_msg_len, n_slots, _parse_fn())
+        self._h = self._new_stage(shard_idx, shard_cnt)
         if not self._h:
             raise NativeUnavailable("fdv_stage_new failed")
-        owner = _Owner(lib, self._h)
-        self.cb = ctypes.cast(lib.fdv_frag_cb, ctypes.c_void_p)
+        owner = self._owner = _Owner(lib, self._h)
+        self.cb = ctypes.cast(self._frag_cb(), ctypes.c_void_p)
         self.cb_ctx = ctypes.c_void_p(self._h)
         self.meta = np.frombuffer(
             (ctypes.c_uint64 * (n_slots * _META_NCOL)).from_address(
@@ -243,6 +269,14 @@ class StageClient:
         self.slots = [_SlotViews(lib, owner, i, batch, max_msg_len)
                       for i in range(n_slots)]
         self._next_dispatch = 0  # cyclic = the C acquire order
+
+    def _new_stage(self, shard_idx: int, shard_cnt: int):
+        return self._lib.fdv_stage_new(shard_idx, shard_cnt, self.batch,
+                                       self.max_msg_len, self.n_slots,
+                                       _parse_fn())
+
+    def _frag_cb(self):
+        return self._lib.fdv_frag_cb
 
     # -- intake surface ------------------------------------------------------
 
@@ -332,10 +366,95 @@ class StageClient:
         of them (_Owner), which a device copy in flight may still hold."""
         self.meta = self._tail = None
         self.slots = []
-        self._h = None
+        self._h = self._owner = None
 
     def __del__(self):
         try:
             self.close()
         except Exception:
             pass
+
+
+class ReplayClient(StageClient):
+    """The replay intake's client (fd_verify.cpp, "The replay intake"):
+    a frag is one entry batch of a received slot.  The device side is
+    StageClient's — the same slots of packed rows, sealed, taken and
+    released the same way; what differs is the door (fdv_replay_cb
+    walks, parses, checks the PoH chain and holds the frag) and the way
+    out (`reap` names the transactions a signature of which failed,
+    `collect` hands back fdr_publish_burst's frame table of the held
+    entry batches and verdict frames now due, in block order, over
+    `held_ptr`; `out_done` returns their bytes once they are out)."""
+
+    def __init__(self, *, batch: int, max_msg_len: int, n_slots: int):
+        # held frags: room for every lane of every slot at 512 B a
+        # lane (a 215 B transfer holds 218), at least 4 MiB; a record
+        # for each of those lanes and as many again on their way out
+        self._arena_sz = max(4 << 20, n_slots * batch * 512)
+        n_recs = 1024
+        while n_recs < 2 * n_slots * batch:
+            n_recs *= 2
+        self._n_recs = n_recs
+        super().__init__(shard_idx=0, shard_cnt=1, batch=batch,
+                         max_msg_len=max_msg_len, n_slots=n_slots)
+        lib, h = self._lib, self._h
+        self.held_ptr = int(lib.fdv_replay_arena(h))
+        buf = (ctypes.c_uint64 * (_RP_OUT_CAP * 4)).from_address(
+            int(lib.fdv_replay_out_tbl(h)))
+        buf._owner = self._owner
+        self._out_tbl = np.frombuffer(buf, dtype=np.uint64).reshape(
+            _RP_OUT_CAP, 4)
+        cbuf = (ctypes.c_uint64 * len(_REPLAY_COUNTERS)).from_address(
+            int(lib.fdv_replay_counters_ptr(h)))
+        cbuf._owner = self._owner
+        self._rp_counters = np.frombuffer(cbuf, dtype=np.uint64)
+        self._n_recs_out = ctypes.c_uint64(0)
+
+    def _new_stage(self, shard_idx: int, shard_cnt: int):
+        return self._lib.fdv_replay_new(self.batch, self.max_msg_len,
+                                        self.n_slots, _parse_fn(),
+                                        self._arena_sz, self._n_recs)
+
+    def _frag_cb(self):
+        return self._lib.fdv_replay_cb
+
+    def append(self, payload: bytes, tsorig: int) -> bool:
+        """Per-frag fallback: True = taken; False = the intake had no
+        room and the frag was dropped and counted (`intake_dropped`)."""
+        return self._lib.fdv_replay_append(self._h, payload, len(payload),
+                                           tsorig) == 0
+
+    def counters(self) -> dict[str, int]:
+        out = super().counters()
+        out.pop("dedup_dup")        # no tag cache on this path
+        out.update(zip(_REPLAY_COUNTERS, self._rp_counters.tolist()))
+        return out
+
+    def emit_ready(self) -> bool:
+        """The oldest held entry batch has its verdict: `collect` would
+        hand something back.  ONE u64 read."""
+        return bool(self._tail[_TAIL_FLAGS] & 4)
+
+    def reap(self, slot: int, bad: np.ndarray) -> None:
+        """Device batch `slot`'s mask is in: `bad` (uint32, ascending)
+        are the slot's transactions a signature of which failed."""
+        self._lib.fdv_replay_reap(self._h, slot, bad.ctypes.data, len(bad))
+
+    def collect(self) -> tuple[np.ndarray, int]:
+        """-> (a copy of the frame table of what is due out, how many
+        held records it covers)."""
+        n = self._lib.fdv_replay_collect(self._h,
+                                         ctypes.byref(self._n_recs_out))
+        return self._out_tbl[:n].copy(), int(self._n_recs_out.value)
+
+    def out_done(self, n_recs: int) -> None:
+        self._lib.fdv_replay_out_done(self._h, n_recs)
+
+    def held(self) -> int:
+        """Entry batches held: waiting for a verdict, or for room on
+        the ring behind."""
+        return int(self._lib.fdv_replay_held(self._h))
+
+    def close(self) -> None:
+        self._out_tbl = self._rp_counters = None
+        super().close()

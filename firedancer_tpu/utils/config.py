@@ -29,6 +29,11 @@ class LayoutConfig:
     # front-door topology benchs x n -> UDP/QUIC -> quic -> verify
     # (models/leader_topo.build_quic_topology_from_config)
     benchs_stage_count: int = 0
+    # replay verify tiles (runtime/replay_verify.py): 0 = the leader's
+    # side; 1 = the follower's verify phase, source -> verify0 -> out
+    # over entry batches, as [replay] describes it
+    # (models/leader_topo.build_replay_topology_from_config)
+    replay_stage_count: int = 0
 
 
 @dataclass
@@ -110,6 +115,27 @@ class QuicConfig:
 
 
 @dataclass
+class ReplayConfig:
+    # the follower's verify phase (layout.replay_stage_count = 1): the
+    # stage takes one entry batch of a received slot a frag and hands
+    # on, in block order, the entry batches all of whose signatures
+    # verified and whose entries' hashes follow, and a verdict a slot.
+    # Its device batch, row bound and deadline are [verify]'s; the ring
+    # in front is verify.receive_buffer_depth deep.
+    frag_mtu: int = 65536        # both rings' mtu: the largest entry batch
+    out_depth: int = 1024        # the ring behind the stage, in frags
+    # the blocks the source tile offers (a leader's here: pack's
+    # microblock of 31, the shred tile's batch_target_sz of two such
+    # entries; [poh] gives the ticks)
+    txns_per_entry: int = 31
+    entries_per_batch: int = 2
+    slot_txns: int = 39990       # transactions a slot
+    # one slot in this many is offered with a flipped signature bit
+    # (0: none): that slot is dead from the entry batch holding it
+    dead_one_in_slots: int = 16
+
+
+@dataclass
 class LedgerConfig:
     # empty = in-memory funk; a directory enables the write-ahead
     # journal + snapshot persistence (funk/persist.py)
@@ -166,6 +192,7 @@ class Config:
     shred: ShredConfig = field(default_factory=ShredConfig)
     net: NetConfig = field(default_factory=NetConfig)
     quic: QuicConfig = field(default_factory=QuicConfig)
+    replay: ReplayConfig = field(default_factory=ReplayConfig)
     ledger: LedgerConfig = field(default_factory=LedgerConfig)
     genesis: GenesisConfig = field(default_factory=GenesisConfig)
     development: DevelopmentConfig = field(default_factory=DevelopmentConfig)
@@ -226,6 +253,22 @@ def _validate(cfg: Config) -> None:
         raise ConfigError("layout.bank_stage_count must be in [1, 62]")
     if not 0 <= cfg.layout.benchs_stage_count <= 64:
         raise ConfigError("layout.benchs_stage_count must be in [0, 64]")
+    if not 0 <= cfg.layout.replay_stage_count <= 1:
+        raise ConfigError("layout.replay_stage_count must be 0 or 1 (the "
+                          "entry batches of a slot go to one tile)")
+    if cfg.layout.replay_stage_count and cfg.layout.benchs_stage_count:
+        raise ConfigError("layout.replay_stage_count and "
+                          "layout.benchs_stage_count select two topologies")
+    r = cfg.replay
+    if not 1024 <= r.frag_mtu <= 65536:
+        raise ConfigError("replay.frag_mtu must be in [1024, 65536]")
+    if r.out_depth < 1 or r.out_depth & (r.out_depth - 1):
+        raise ConfigError("replay.out_depth must be a power of 2")
+    if r.txns_per_entry < 1 or r.entries_per_batch < 1 or r.slot_txns < 1 \
+            or r.dead_one_in_slots < 0:
+        raise ConfigError("replay.txns_per_entry, replay.entries_per_batch "
+                          "and replay.slot_txns must be >= 1, "
+                          "replay.dead_one_in_slots >= 0")
     q = cfg.quic
     if q.reasm_depth < 1 or q.max_conns < 1 or q.stream_window < 1:
         raise ConfigError("quic.reasm_depth, quic.max_conns and "

@@ -673,6 +673,34 @@ def dedup_row(src) -> dict | None:
     return {k: int(src.get(n) or 0) for n, k in DEDUP_COUNTERS.items()}
 
 
+# What the replay verify stage counts beside a verify stage's own (the
+# monitor's `replay` line and slotreport's block show them by these
+# names; runtime/replay_verify.py has what each means): entry batches
+# and what they held, the slots' verdicts, what was skipped of dead
+# slots and the lanes spent on it, and the two spans (cumulative ns)
+REPLAY_COUNTERS = (
+    "entry_batches_in", "entries_in", "txn_in", "elems_in",
+    "slots_live", "slots_dead_sig", "slots_dead_poh", "slots_dead_parse",
+    "dead_slot_txn_skipped", "dead_slot_lanes_spent", "poh_hashes",
+    "poh_check_ns", "entry_unpack_ns", "entry_batches_out",
+    "entry_txn_out", "entry_txn_rejected",
+)
+
+
+def replay_row(src) -> dict | None:
+    """{name: count} of REPLAY_COUNTERS, from a stage's registry (the
+    monitor) or a dict of its metrics (slotreport); None where the
+    stage is no replay verify stage (a verify stage counts `txn_in`
+    too, and has no `entry_batches_in`)."""
+    if src is None:
+        return None
+    if isinstance(src, MetricsRegistry):
+        src = {n: src.get(n) for n in REPLAY_COUNTERS if n in src._off}
+    if "entry_batches_in" not in src:
+        return None
+    return {n: int(src.get(n) or 0) for n in REPLAY_COUNTERS}
+
+
 # What the front door counts (the monitor and slotreport show them by
 # these names): the quic tile's datagrams, punts from the C
 # lane to Python, connections, the reassembler's outcomes, whole

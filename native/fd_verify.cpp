@@ -29,6 +29,11 @@
 // the tcache matches tango/rings.TCache (depth-16 ring, tag 0 never
 // dedups), and a txn's elements always land in one batch.
 //
+// A second intake beside the per-frag one (below, "The replay
+// intake"): a follower's replay tile hands the stage ENTRY BATCHES of a
+// received block, not transactions; the slots, the fit rule, seal and
+// acquire are this file's one set, the door and the way out differ.
+//
 // Build: g++ -O2 -shared -fPIC -o fd_verify.so fd_verify.cpp
 
 #include <cstdint>
@@ -36,6 +41,7 @@
 #include <cstring>
 
 #include "fd_metrics.h"  // fdm_now_ns: the clock of time.monotonic_ns()
+#include "fd_sha256.h"   // the replay intake's PoH check
 
 namespace {
 
@@ -98,9 +104,12 @@ struct fdv_stash_ent {
   uint8_t buf[TXN_MTU];
 };
 
+struct fdv_replay;  // the entry-batch intake's state (below)
+
 struct fdv_stage {
   uint64_t shard_idx, shard_cnt, batch, mml, n_slots;
   fdv_parse_fn parse;
+  fdv_replay* rp;  // non-null: the stage takes entry batches, not txns
   uint64_t tc_ring[TC_DEPTH];
   uint64_t tc_oldest;
   fdv_slot* slots;
@@ -126,6 +135,11 @@ struct fdv_stage {
       c_batch_fit_pad_lanes;
 };
 
+void rp_note_acquire(fdv_stage* s, uint64_t slot_idx);
+bool rp_emit_ready(const fdv_stage* s);
+bool rp_room(const fdv_stage* s);
+bool rp_pending(const fdv_stage* s);
+
 inline void set_flags(fdv_stage* s) {
   // bit0: stash nonempty; bit1: intake has room (the sweep gate Python
   // reads as ONE word instead of scanning the slot table per iteration)
@@ -138,7 +152,17 @@ inline void set_flags(fdv_stage* s) {
       }
     }
   }
-  s->flags = (s->stash_n ? 1u : 0u) | ((!s->stash_n && room) ? 2u : 0u);
+  if (s->rp) {
+    // the replay intake: bit0 = an entry batch is part-way into the
+    // slots; bit1 = the next frag can be held and started; bit2 = the
+    // oldest held entry batch has its verdict (fdv_replay_collect)
+    bool pending = rp_pending(s);
+    s->flags = (pending ? 1u : 0u) |
+               ((!pending && room && rp_room(s)) ? 2u : 0u) |
+               (rp_emit_ready(s) ? 4u : 0u);
+  } else {
+    s->flags = (s->stash_n ? 1u : 0u) | ((!s->stash_n && room) ? 2u : 0u);
+  }
   s->open_elems = s->open >= 0 ? s->meta[s->open].n_elems : 0;
   s->open_ns = s->open_elems ? s->meta[s->open].opened_ns : 0;
 }
@@ -154,6 +178,7 @@ bool acquire_open(fdv_stage* s) {
   m->sealed_ns = 0;
   m->close = CLOSE_FULL;
   s->open = (int64_t)s->next_open;
+  if (s->rp) rp_note_acquire(s, s->next_open);
   s->next_open = (s->next_open + 1) % s->n_slots;
   return true;
 }
@@ -167,6 +192,26 @@ void seal_open(fdv_stage* s, uint64_t why) {
   m->state = SLOT_SEALED;
   s->open = -1;
   s->c_sealed_batches++;
+}
+
+// one txn's elements into the open slot's packed rows, a row a
+// signature, and its element range (both intakes' row writer)
+inline void put_rows(fdv_stage* s, fdv_slot* sl, const fdv_slot_meta* m,
+                     const uint8_t* payload, uint64_t sig_cnt,
+                     uint64_t sig_off, uint64_t msg_off, uint64_t acct_off,
+                     uint64_t msg_len) {
+  for (uint64_t i = 0; i < sig_cnt; i++) {
+    uint8_t* row = sl->rows + (m->n_elems + i) * (s->mml + ROW_TAIL);
+    uint8_t* tail = row + s->mml;
+    std::memcpy(row, payload + msg_off, msg_len);
+    std::memset(row + msg_len, 0, s->mml - msg_len);
+    std::memcpy(tail + ROW_SIG_OFF, payload + sig_off + 64 * i, 64);
+    std::memcpy(tail + ROW_PK_OFF, payload + acct_off + 32 * i, 32);
+    for (int k = 0; k < 4; k++)
+      tail[ROW_LEN_OFF + k] = (uint8_t)(msg_len >> (8 * k));
+  }
+  sl->ranges[2 * m->n_txn] = (uint32_t)m->n_elems;
+  sl->ranges[2 * m->n_txn + 1] = (uint32_t)(m->n_elems + sig_cnt);
 }
 
 // one txn through the guards + batch assembly; 0 = handled (accepted or
@@ -225,18 +270,7 @@ int ingest(fdv_stage* s, const uint8_t* payload, uint64_t sz,
     m = &s->meta[s->open];
   }
   fdv_slot* sl = &s->slots[s->open];
-  for (uint64_t i = 0; i < sig_cnt; i++) {
-    uint8_t* row = sl->rows + (m->n_elems + i) * (s->mml + ROW_TAIL);
-    uint8_t* tail = row + s->mml;
-    std::memcpy(row, payload + msg_off, msg_len);
-    std::memset(row + msg_len, 0, s->mml - msg_len);
-    std::memcpy(tail + ROW_SIG_OFF, payload + sig_off + 64 * i, 64);
-    std::memcpy(tail + ROW_PK_OFF, payload + acct_off + 32 * i, 32);
-    for (int k = 0; k < 4; k++)
-      tail[ROW_LEN_OFF + k] = (uint8_t)(msg_len >> (8 * k));
-  }
-  sl->ranges[2 * m->n_txn] = (uint32_t)m->n_elems;
-  sl->ranges[2 * m->n_txn + 1] = (uint32_t)(m->n_elems + sig_cnt);
+  put_rows(s, sl, m, payload, sig_cnt, sig_off, msg_off, acct_off, msg_len);
   uint64_t off = m->arena_off;
   std::memcpy(sl->arena + off, payload, sz);
   std::memcpy(sl->arena + off + sz, s->desc, (uint64_t)dn);
@@ -303,6 +337,404 @@ int append_one(fdv_stage* s, const uint8_t* payload, uint64_t sz,
   return r;
 }
 
+// ---------------------------------------------------------------------------
+// The replay intake (runtime/replay_verify.py): a follower's verify
+// phase.  A frag is one ENTRY BATCH of a received slot,
+//
+//   u64 slot | u32 batch idx | u32 flags | [32 B PoH seed iff SEED] |
+//   (u32 len | u32 num_hashes | 32 B hash | u16 cnt | (u16 len | txn)*)*
+//
+// and what leaves is the same frag, byte for byte, once every signature
+// of every transaction in it has passed on the device and every entry's
+// hash follows — in block order — and one verdict frame a slot.  The
+// device side is the per-frag intake's own: the same slots of packed
+// rows, the same fit rule, seal and acquire; a device batch is filled
+// across entry batches and slots.  No tag cache on this path: a
+// follower verifies a repeated transaction like any other.
+//
+// An entry batch is walked ONCE on arrival (structure, one parse a
+// transaction through the one parser, the PoH chain under fd_sha256.h),
+// copied into a ring arena of held frags, and its transactions' rows
+// are put into the slots from the table that walk made, resuming where
+// it stalled for want of a slot.  A held frag leaves (or is rejected,
+// or skipped) when the device batch that took its last lane has been
+// reaped: fdv_replay_reap marks what failed, fdv_replay_collect writes
+// the frame table of what is due out, in order, for fdr_publish_burst.
+//
+// A slot is dead from its first failing entry batch on (reason: a
+// signature, a hash that does not follow, a transaction that does not
+// parse): nothing of it at or after that batch leaves, what arrives of
+// it later is dropped at the door, what is held of it is skipped, and
+// both are counted (dead_slot_txn_skipped; dead_slot_lanes_spent for
+// the lanes already in a slot).  The next slot starts clean.
+
+constexpr uint64_t RP_HDR = 16;
+constexpr uint64_t RP_SEED = 32;
+constexpr uint64_t RP_VERDICT_SZ = 16;
+constexpr uint64_t RP_FRAG_MAX = 65536;  // the in link's mtu
+constexpr uint32_t RP_F_LAST = 1, RP_F_SEED = 2, RP_F_VERDICT = 4;
+constexpr uint64_t RP_SIG_VERDICT = 1ull << 63;
+// why a slot died (the verdict frame's reason byte; 0 = live)
+enum { RP_OK = 0, RP_SIG = 1, RP_POH = 2, RP_PARSE = 3 };
+constexpr uint64_t RP_MAX_TXN = 1024;  // 65,536 B / the shortest txn
+constexpr uint64_t RP_MAX_ENT = 2048;  // 65,536 B / an empty entry
+constexpr uint64_t RP_OUT_CAP = 1024;  // frame-table rows a collect
+
+struct rp_txn {  // one parsed transaction of the frag being put in
+  uint32_t off, sz;  // in the frag
+  uint16_t sig_cnt, sig_off, msg_off, acct_off;
+};
+
+struct rp_ent {
+  uint32_t num_hashes, hash_off, txn0, txn_n;
+};
+
+struct rp_rec {  // one held entry batch
+  uint64_t off, adv, sz;  // its bytes in the arena; ring bytes it took
+  uint64_t slot, tsorig;
+  uint64_t last_seq;  // the device batch that took its newest lane
+  uint32_t idx, flags, n_txn, n_lanes;
+  uint32_t fail;  // RP_*
+  uint32_t done;  // every lane it will ever get is in a slot
+};
+
+struct fdv_replay {
+  uint8_t* arena;
+  uint64_t cap, a_head, a_tail;  // ring positions, monotonic
+  rp_rec* recs;
+  uint64_t rec_mask, r_head, r_emit, r_tail;
+  // the slot the door is in
+  uint64_t cur_slot, next_idx;
+  uint32_t have_slot, cur_dead;
+  uint8_t chain[32];
+  // the frag part-way into the slots (r_head - 1 while `pending`)
+  uint32_t pending;
+  uint64_t cur_txn, n_txns, n_ents;
+  rp_txn txns[RP_MAX_TXN];
+  rp_ent ents[RP_MAX_ENT];
+  // device batches in acquire order (which is dispatch and reap order)
+  uint64_t acq_seq, reaped_seq;
+  uint64_t* slot_seq;  // n_slots
+  uint32_t** rec_of;   // n_slots x batch: the txn's record (ring index)
+  // the slot being skipped on the way out
+  uint64_t emit_dead_slot;
+  uint32_t emit_dead;
+  uint64_t out_tbl[RP_OUT_CAP * 4];
+  // counters, contiguous u64s for the Python view — keep declaration
+  // order in sync with runtime/verify_native._REPLAY_COUNTERS
+  uint64_t c_entry_batches_in, c_entries_in, c_slots_live, c_slots_dead_sig,
+      c_slots_dead_poh, c_slots_dead_parse, c_dead_slot_txn_skipped,
+      c_dead_slot_lanes_spent, c_poh_hashes, c_poh_check_ns,
+      c_entry_unpack_ns, c_entry_batches_out, c_entry_txn_out,
+      c_entry_txn_rejected, c_verify_fail, c_verify_fail_elems;
+};
+
+inline uint32_t rd32(const uint8_t* p) {
+  return (uint32_t)p[0] | (uint32_t)p[1] << 8 | (uint32_t)p[2] << 16 |
+         (uint32_t)p[3] << 24;
+}
+inline uint64_t rd64(const uint8_t* p) {
+  return (uint64_t)rd32(p) | (uint64_t)rd32(p + 4) << 32;
+}
+inline void wr32(uint8_t* p, uint32_t v) {
+  for (int k = 0; k < 4; k++) p[k] = (uint8_t)(v >> (8 * k));
+}
+inline void wr64(uint8_t* p, uint64_t v) {
+  wr32(p, (uint32_t)v);
+  wr32(p + 4, (uint32_t)(v >> 32));
+}
+
+inline rp_rec* rp_at(fdv_replay* r, uint64_t i) {
+  return &r->recs[i & r->rec_mask];
+}
+
+void rp_note_acquire(fdv_stage* s, uint64_t slot_idx) {
+  s->rp->slot_seq[slot_idx] = ++s->rp->acq_seq;
+}
+
+bool rp_pending(const fdv_stage* s) { return s->rp->pending != 0; }
+
+bool rp_room(const fdv_stage* s) {
+  // a frag of the largest size can be held wherever the ring's head
+  // stands (a wrap pads at most a frag's worth), and has a record
+  const fdv_replay* r = s->rp;
+  return r->cap - (r->a_head - r->a_tail) >=
+             2 * (RP_FRAG_MAX + RP_VERDICT_SZ) &&
+         r->r_head - r->r_tail <= r->rec_mask;
+}
+
+bool rp_emit_ready(const fdv_stage* s) {
+  const fdv_replay* r = s->rp;
+  if (r->r_emit == r->r_head) return false;
+  const rp_rec* rec = &r->recs[r->r_emit & r->rec_mask];
+  return rec->done && rec->last_seq <= r->reaped_seq;
+}
+
+// is slot `slot` known dead to a record at ring position `at`: an
+// earlier held record of it failed, or its verdict is out already
+bool rp_slot_dead(fdv_replay* r, uint64_t slot, uint64_t at) {
+  if (r->emit_dead && r->emit_dead_slot == slot) return true;
+  for (uint64_t i = r->r_emit; i < at; i++) {
+    rp_rec* e = rp_at(r, i);
+    if (e->slot == slot && e->fail) return true;
+  }
+  return false;
+}
+
+// transactions the entries of `p` claim (the count fields alone): what
+// a frag dropped at the door is counted by
+uint64_t rp_claimed_txns(const uint8_t* p, uint64_t sz) {
+  uint64_t o = 0, n = 0;
+  while (o + 4 <= sz) {
+    uint64_t len = rd32(p + o);
+    o += 4;
+    if (len < 38 || o + len > sz) break;
+    n += (uint64_t)p[o + 36] | (uint64_t)p[o + 37] << 8;
+    o += len;
+  }
+  return n;
+}
+
+// the walk: structure, one parse a transaction, the guards -> the txn
+// and entry tables.  -> RP_OK or RP_PARSE.
+int rp_unpack(fdv_stage* s, const uint8_t* f, uint64_t o, uint64_t sz) {
+  fdv_replay* r = s->rp;
+  r->n_txns = r->n_ents = 0;
+  while (o < sz) {
+    if (o + 4 > sz) return RP_PARSE;
+    uint64_t len = rd32(f + o);
+    o += 4;
+    if (len < 38 || o + len > sz || r->n_ents >= RP_MAX_ENT) return RP_PARSE;
+    const uint8_t* e = f + o;
+    rp_ent* en = &r->ents[r->n_ents++];
+    en->num_hashes = rd32(e);
+    en->hash_off = (uint32_t)(o + 4);
+    en->txn0 = (uint32_t)r->n_txns;
+    uint64_t cnt = (uint64_t)e[36] | (uint64_t)e[37] << 8;
+    uint64_t q = 38;
+    for (uint64_t k = 0; k < cnt; k++) {
+      if (q + 2 > len) return RP_PARSE;
+      uint64_t tl = (uint64_t)e[q] | (uint64_t)e[q + 1] << 8;
+      q += 2;
+      if (q + tl > len || tl > TXN_MTU || r->n_txns >= RP_MAX_TXN)
+        return RP_PARSE;
+      int64_t dn = s->parse(e + q, tl, s->desc, DESC_CAP);
+      if (dn < 0) {
+        s->c_parse_fail++;
+        return RP_PARSE;
+      }
+      const uint8_t* d = s->desc;
+      rp_txn* t = &r->txns[r->n_txns];
+      t->off = (uint32_t)(o + q);
+      t->sz = (uint32_t)tl;
+      t->sig_cnt = d[1];
+      t->sig_off = (uint16_t)(d[2] | d[3] << 8);
+      t->msg_off = (uint16_t)(d[4] | d[5] << 8);
+      t->acct_off = (uint16_t)(d[9] | d[10] << 8);
+      // a transaction the device cannot be given is one the slot
+      // cannot be verified with
+      if (tl - t->msg_off > s->mml) {
+        s->c_msg_too_long++;
+        return RP_PARSE;
+      }
+      if (t->sig_cnt > s->batch) {
+        s->c_too_many_sigs++;
+        return RP_PARSE;
+      }
+      r->n_txns++;
+      q += tl;
+    }
+    if (q != len) return RP_PARSE;
+    en->txn_n = (uint32_t)r->n_txns - en->txn0;
+    o += len;
+  }
+  return RP_OK;
+}
+
+// the chain over the walked entries: num_hashes appends (the last of
+// them the mixin for a transaction entry: sha256 over the entry's
+// first signatures), compare.  A transaction entry with num_hashes 0
+// does not follow.  -> RP_OK or RP_POH; the chain is left at the last
+// entry's hash.
+int rp_poh(fdv_stage* s, const uint8_t* f) {
+  fdv_replay* r = s->rp;
+  uint8_t h[32];
+  std::memcpy(h, r->chain, 32);
+  for (uint64_t i = 0; i < r->n_ents; i++) {
+    const rp_ent* en = &r->ents[i];
+    uint64_t n = en->num_hashes;
+    if (en->txn_n) {
+      if (n < 1) return RP_POH;
+      n--;
+    }
+    for (uint64_t k = 0; k < n; k++) {
+      fdsha::Sha256 a;
+      a.update(h, 32);
+      a.final(h);
+    }
+    r->c_poh_hashes += n;
+    if (en->txn_n) {
+      uint8_t mix[32];
+      fdsha::Sha256 m;
+      for (uint64_t k = 0; k < en->txn_n; k++) {
+        const rp_txn* t = &r->txns[en->txn0 + k];
+        m.update(f + t->off + t->sig_off, 64);
+      }
+      m.final(mix);
+      fdsha::Sha256 a;
+      a.update(h, 32);
+      a.update(mix, 32);
+      a.final(h);
+      r->c_poh_hashes++;
+    }
+    if (std::memcmp(h, f + en->hash_off, 32) != 0) return RP_POH;
+  }
+  std::memcpy(r->chain, h, 32);
+  return RP_OK;
+}
+
+// the pending frag's transactions into the slots, from where it
+// stalled; 0 = all in (or its slot died meanwhile), 1 = no slot free
+int rp_ingest(fdv_stage* s) {
+  fdv_replay* r = s->rp;
+  rp_rec* rec = rp_at(r, r->r_head - 1);
+  const uint8_t* f = r->arena + rec->off;
+  while (r->cur_txn < r->n_txns && !rec->fail &&
+         !(r->cur_dead && rec->slot == r->cur_slot)) {
+    const rp_txn* t = &r->txns[r->cur_txn];
+    uint64_t sig_cnt = t->sig_cnt;
+    bool need_new =
+        s->open < 0 || s->meta[s->open].n_elems + sig_cnt > s->batch;
+    if (need_new && s->meta[s->next_open].state != SLOT_FREE) return 1;
+    if (s->open < 0) acquire_open(s);
+    fdv_slot_meta* m = &s->meta[s->open];
+    if (m->n_elems + sig_cnt > s->batch) {
+      s->c_batch_fit_pad_lanes += s->batch - m->n_elems;
+      seal_open(s, CLOSE_FULL);
+      acquire_open(s);
+      m = &s->meta[s->open];
+    }
+    put_rows(s, &s->slots[s->open], m, f + t->off, sig_cnt, t->sig_off,
+             t->msg_off, t->acct_off, t->sz - t->msg_off);
+    r->rec_of[s->open][m->n_txn] = (uint32_t)((r->r_head - 1) & r->rec_mask);
+    m->n_txn++;
+    m->n_elems += sig_cnt;
+    s->c_txn_in++;
+    s->c_elems_in += sig_cnt;
+    rec->n_lanes += (uint32_t)sig_cnt;
+    rec->last_seq = r->slot_seq[s->open];
+    if (m->n_elems >= s->batch) seal_open(s, CLOSE_FULL);
+    r->cur_txn++;
+  }
+  rec->done = 1;
+  r->pending = 0;
+  return 0;
+}
+
+// a record for the frag (its bytes held when they may still leave)
+rp_rec* rp_hold(fdv_replay* r, const uint8_t* f, uint64_t sz, uint64_t slot,
+                uint32_t idx, uint32_t flags, uint64_t tsorig, bool bytes) {
+  rp_rec* rec = rp_at(r, r->r_head++);
+  std::memset(rec, 0, sizeof(*rec));
+  uint64_t want = (bytes ? sz : 0) + RP_VERDICT_SZ;
+  uint64_t pos = r->a_head % r->cap;
+  uint64_t pad = pos + want > r->cap ? r->cap - pos : 0;
+  rec->off = pad ? 0 : pos;
+  rec->adv = pad + want;
+  rec->sz = bytes ? sz : 0;
+  r->a_head += rec->adv;
+  if (bytes) std::memcpy(r->arena + rec->off, f, sz);
+  rec->slot = slot;
+  rec->idx = idx;
+  rec->flags = flags;
+  rec->tsorig = tsorig;
+  return rec;
+}
+
+// one frag at the door.  The caller saw room (set_flags bit1).
+void rp_frag(fdv_stage* s, const uint8_t* f, uint64_t sz, uint64_t tsorig) {
+  fdv_replay* r = s->rp;
+  uint64_t t0 = fdm_now_ns();
+  s->c_frags_in++;
+  r->c_entry_batches_in++;
+  uint64_t slot = 0;
+  uint32_t idx = 0, flags = 0;
+  uint64_t body = RP_HDR;
+  bool framed = sz >= RP_HDR && sz <= RP_FRAG_MAX;
+  if (framed) {
+    slot = rd64(f);
+    idx = rd32(f + 8);
+    flags = rd32(f + 12);
+    if (flags & RP_F_SEED) body += RP_SEED;
+    framed = body <= sz && !(flags & RP_F_VERDICT) &&
+             ((flags & RP_F_SEED) != 0) == (idx == 0);
+  }
+  if (framed && idx == 0) {  // a slot starts, clean
+    r->cur_slot = slot;
+    r->next_idx = 0;
+    r->have_slot = 1;
+    r->cur_dead = 0;
+    std::memcpy(r->chain, f + RP_HDR, 32);
+  }
+  if (r->have_slot && r->cur_dead && (!framed || slot == r->cur_slot)) {
+    // of a slot that is dead already: dropped at the door, counted
+    r->c_dead_slot_txn_skipped +=
+        framed ? rp_claimed_txns(f + body, sz - body) : 0;
+    r->c_entry_unpack_ns += fdm_now_ns() - t0;
+    return;
+  }
+  int fail = RP_OK;
+  if (!framed || !r->have_slot || slot != r->cur_slot || idx != r->next_idx) {
+    // not the frag that follows: the stream does not parse here
+    fail = RP_PARSE;
+    if (!r->have_slot) {
+      r->cur_slot = framed ? slot : 0;
+      r->have_slot = 1;
+    }
+    slot = r->cur_slot;
+    idx = (uint32_t)r->next_idx;
+    flags = 0;
+    r->n_txns = r->n_ents = 0;
+  } else {
+    fail = rp_unpack(s, f, body, sz);
+  }
+  uint64_t t1 = fdm_now_ns();
+  r->c_entry_unpack_ns += t1 - t0;
+  if (!fail) {
+    fail = rp_poh(s, f);
+    uint64_t t2 = fdm_now_ns();
+    r->c_poh_check_ns += t2 - t1;
+    t1 = t2;
+  }
+  r->c_entries_in += r->n_ents;
+  r->next_idx = (uint64_t)idx + 1;
+  rp_rec* rec = rp_hold(r, f, sz, slot, idx, flags, tsorig, !fail);
+  rec->n_txn = fail ? (uint32_t)rp_claimed_txns(f + body, framed ? sz - body : 0)
+                    : (uint32_t)r->n_txns;
+  rec->last_seq = 0;
+  if (fail) {
+    // dead here: nothing of the frag goes to the device
+    rec->fail = (uint32_t)fail;
+    rec->done = 1;
+    r->cur_dead = 1;
+    return;
+  }
+  r->pending = 1;
+  r->cur_txn = 0;
+  rp_ingest(s);
+  r->c_entry_unpack_ns += fdm_now_ns() - t1;
+}
+
+void rp_pump(fdv_stage* s) {
+  fdv_replay* r = s->rp;
+  if (r->pending) {
+    uint64_t t0 = fdm_now_ns();
+    rp_ingest(s);
+    r->c_entry_unpack_ns += fdm_now_ns() - t0;
+  }
+  set_flags(s);
+}
+
 }  // namespace
 
 extern "C" {
@@ -346,6 +778,14 @@ void fdv_stage_delete(void* ctx) {
   }
   std::free(s->slots);
   std::free(s->meta);
+  if (s->rp) {
+    for (uint64_t i = 0; i < s->n_slots; i++) std::free(s->rp->rec_of[i]);
+    std::free(s->rp->rec_of);
+    std::free(s->rp->slot_seq);
+    std::free(s->rp->recs);
+    std::free(s->rp->arena);
+    std::free(s->rp);
+  }
   std::free(s);
 }
 
@@ -381,14 +821,18 @@ void fdv_seal(void* ctx, uint64_t why) {
 }
 
 // Retry stashed frags (the reap side calls this after releasing a slot).
-void fdv_pump(void* ctx) { pump((fdv_stage*)ctx); }
+void fdv_pump(void* ctx) {
+  fdv_stage* s = (fdv_stage*)ctx;
+  if (s->rp) rp_pump(s);
+  else pump(s);
+}
 
 // A dispatched+published slot returns to the ring.
 void fdv_slot_release(void* ctx, uint64_t idx) {
   fdv_stage* s = (fdv_stage*)ctx;
   if (idx >= s->n_slots) return;
   s->meta[idx].state = SLOT_FREE;
-  pump(s);
+  fdv_pump(s);
 }
 
 // zero-FFI view pointers (called once at construction from Python)
@@ -405,6 +849,174 @@ void* fdv_slot_ranges(void* ctx, uint64_t i) {
 }
 void* fdv_slot_arena(void* ctx, uint64_t i) {
   return ((fdv_stage*)ctx)->slots[i].arena;
+}
+
+// -- the replay intake (entry batches in; runtime/replay_verify.py) ---------
+
+// A stage whose frags are entry batches.  `arena_sz` is the ring of
+// held frags (>= 4 frags of the largest size), `n_recs` a power of two.
+void* fdv_replay_new(uint64_t batch, uint64_t max_msg_len, uint64_t n_slots,
+                     void* parse_fn, uint64_t arena_sz, uint64_t n_recs) {
+  if (arena_sz < 4 * (RP_FRAG_MAX + RP_VERDICT_SZ) || !n_recs ||
+      (n_recs & (n_recs - 1)))
+    return nullptr;
+  fdv_stage* s =
+      (fdv_stage*)fdv_stage_new(0, 1, batch, max_msg_len, n_slots, parse_fn);
+  if (!s) return nullptr;
+  fdv_replay* r = (fdv_replay*)std::calloc(1, sizeof(fdv_replay));
+  if (!r) return nullptr;
+  r->cap = arena_sz;
+  r->arena = (uint8_t*)std::malloc(arena_sz);
+  r->recs = (rp_rec*)std::calloc(n_recs, sizeof(rp_rec));
+  r->rec_mask = n_recs - 1;
+  r->slot_seq = (uint64_t*)std::calloc(n_slots, sizeof(uint64_t));
+  r->rec_of = (uint32_t**)std::calloc(n_slots, sizeof(uint32_t*));
+  if (!r->arena || !r->recs || !r->slot_seq || !r->rec_of) return nullptr;
+  for (uint64_t i = 0; i < n_slots; i++) {
+    r->rec_of[i] = (uint32_t*)std::calloc(batch, sizeof(uint32_t));
+    if (!r->rec_of[i]) return nullptr;
+  }
+  s->rp = r;
+  set_flags(s);
+  return s;
+}
+
+// The fdr_sweep callback of the replay intake: one entry batch a frag.
+// -1 (stop the sweep) when the NEXT frag could not be taken: one is
+// part-way into the slots, or the ring of held frags is full.
+int fdv_replay_cb(void* ctx, const uint64_t* meta8, const uint8_t* payload) {
+  fdv_stage* s = (fdv_stage*)ctx;
+  rp_frag(s, payload, meta8[3], meta8[5]);
+  set_flags(s);
+  return (s->flags & 2u) ? 0 : -1;
+}
+
+// Per-frag fallback surface: 0 = taken; -1 = no room, dropped and
+// counted (only a dead/wedged consumer gets here: the sweep path never
+// polls a frag it cannot take).
+int fdv_replay_append(void* ctx, const uint8_t* payload, uint64_t sz,
+                      uint64_t tsorig) {
+  fdv_stage* s = (fdv_stage*)ctx;
+  rp_pump(s);
+  if (!(s->flags & 2u)) {
+    s->c_frags_in++;
+    s->c_intake_dropped++;
+    return -1;
+  }
+  rp_frag(s, payload, sz, tsorig);
+  set_flags(s);
+  return 0;
+}
+
+// The reap of device batch slot `idx`: `bad` names the `n_bad`
+// transactions (indices into the slot, ascending) a signature of which
+// failed.  The first such transaction of a slot not known dead kills
+// it at its entry batch; the rest of that slot's are lanes spent.
+void fdv_replay_reap(void* ctx, uint64_t idx, const uint32_t* bad,
+                     uint64_t n_bad) {
+  fdv_stage* s = (fdv_stage*)ctx;
+  fdv_replay* r = s->rp;
+  if (idx >= s->n_slots) return;
+  for (uint64_t k = 0; k < n_bad; k++) {
+    uint32_t t = bad[k];
+    if (t >= s->meta[idx].n_txn) continue;
+    uint64_t ring = r->rec_of[idx][t];
+    // the record's ring position, from its ring index
+    uint64_t at = r->r_emit + ((ring - r->r_emit) & r->rec_mask);
+    if (at >= r->r_head) continue;
+    rp_rec* rec = rp_at(r, at);
+    if (rec->fail || rp_slot_dead(r, rec->slot, at)) continue;
+    rec->fail = RP_SIG;
+    r->c_verify_fail++;
+    r->c_verify_fail_elems += s->slots[idx].ranges[2 * t + 1] -
+                              s->slots[idx].ranges[2 * t];
+    if (r->have_slot && rec->slot == r->cur_slot) r->cur_dead = 1;
+  }
+  r->reaped_seq = r->slot_seq[idx];
+  rp_pump(s);
+}
+
+// What is due out, in block order, as fdr_publish_burst's frame table
+// (rows of arena offset, size, sig, tsorig over fdv_replay_arena): the
+// held entry batches whose lanes have all been reaped — left, rejected
+// with the slot's verdict frame, or skipped — up to the table's room.
+// -> rows written; *n_recs = held records they cover (what
+// fdv_replay_out_done frees once the rows are published).
+uint64_t fdv_replay_collect(void* ctx, uint64_t* n_recs) {
+  fdv_stage* s = (fdv_stage*)ctx;
+  fdv_replay* r = s->rp;
+  uint64_t rows = 0, recs = 0;
+  while (r->r_emit < r->r_head && rows + 2 <= RP_OUT_CAP) {
+    rp_rec* rec = rp_at(r, r->r_emit);
+    if (!rec->done || rec->last_seq > r->reaped_seq) break;
+    uint8_t* v = r->arena + rec->off + rec->sz;  // its verdict's 16 B
+    int verdict = -1;
+    if (r->emit_dead && rec->slot == r->emit_dead_slot) {
+      r->c_dead_slot_txn_skipped += rec->n_txn;
+      r->c_dead_slot_lanes_spent += rec->n_lanes;
+    } else if (rec->fail) {
+      verdict = (int)rec->fail;
+      r->emit_dead = 1;
+      r->emit_dead_slot = rec->slot;
+      r->c_entry_txn_rejected += rec->n_txn;
+      if (rec->fail == RP_SIG) r->c_slots_dead_sig++;
+      else if (rec->fail == RP_POH) r->c_slots_dead_poh++;
+      else r->c_slots_dead_parse++;
+    } else {
+      uint64_t* row = r->out_tbl + 4 * rows++;
+      row[0] = rec->off;
+      row[1] = rec->sz;
+      row[2] = ((rec->slot & 0x7FFFFFFFull) << 32) | rec->idx;
+      row[3] = rec->tsorig;
+      r->c_entry_batches_out++;
+      r->c_entry_txn_out += rec->n_txn;
+      if (rec->flags & RP_F_LAST) {
+        verdict = RP_OK;
+        r->c_slots_live++;
+      }
+    }
+    if (verdict >= 0) {
+      // dead: the first failing entry batch; live: how many it had
+      uint32_t vi = verdict ? rec->idx : rec->idx + 1;
+      wr64(v, rec->slot);
+      wr32(v + 8, vi);
+      wr32(v + 12, RP_F_VERDICT | ((uint32_t)verdict << 8));
+      uint64_t* row = r->out_tbl + 4 * rows++;
+      row[0] = rec->off + rec->sz;
+      row[1] = RP_VERDICT_SZ;
+      row[2] = RP_SIG_VERDICT | ((rec->slot & 0x7FFFFFFFull) << 32) | vi;
+      row[3] = rec->tsorig;
+    }
+    r->r_emit++;
+    recs++;
+  }
+  *n_recs = recs;
+  set_flags(s);
+  return rows;
+}
+
+// The oldest `n_recs` collected records' rows are out: their bytes and
+// records return to the rings.
+void fdv_replay_out_done(void* ctx, uint64_t n_recs) {
+  fdv_stage* s = (fdv_stage*)ctx;
+  fdv_replay* r = s->rp;
+  while (n_recs-- && r->r_tail < r->r_emit) {
+    r->a_tail += rp_at(r, r->r_tail)->adv;
+    r->r_tail++;
+  }
+  rp_pump(s);
+}
+
+// held records not yet collected, and collected not yet freed
+uint64_t fdv_replay_held(void* ctx) {
+  fdv_replay* r = ((fdv_stage*)ctx)->rp;
+  return r->r_head - r->r_tail;
+}
+
+void* fdv_replay_arena(void* ctx) { return ((fdv_stage*)ctx)->rp->arena; }
+void* fdv_replay_out_tbl(void* ctx) { return ((fdv_stage*)ctx)->rp->out_tbl; }
+void* fdv_replay_counters_ptr(void* ctx) {
+  return &((fdv_stage*)ctx)->rp->c_entry_batches_in;
 }
 
 }  // extern "C"

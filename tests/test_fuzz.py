@@ -314,7 +314,7 @@ def test_fuzz_shred_parse(data):
 
     s = sh.parse(data)
     if s is not None:
-        assert s.index >= 0
+        assert s.idx >= 0
 
 
 # -- bincode types (snapshot/gossip fidelity layer) ---------------------------
